@@ -9,8 +9,8 @@ Here the monitor runs as an async task inside the raylet (no extra
 process): it scans `{session_dir}/logs/worker-*.out` and `worker-*.err`,
 remembers a byte offset per file, and publishes batches of complete
 lines on the "logs" pubsub channel (stderr batches carry ``is_err`` so
-the driver renders them distinctly). Runtime noise (jax backend preload
-warnings every worker emits at import) is filtered before publishing.
+the driver renders them distinctly). Runtime noise (backend warnings jax
+may emit at import) is filtered before publishing.
 
 Per-task attribution: workers bracket each executing task with marker
 lines (``task_marker``/``task_end_marker``) in their own log stream.
@@ -26,8 +26,8 @@ import os
 import re
 from typing import Callable, Dict, List, Optional, Tuple
 
-# Lines every spawned worker emits on interpreter start that carry no
-# user signal; echoing them once per worker would drown the driver.
+# Lines a worker may emit on interpreter start that carry no user
+# signal; echoing them once per worker would drown the driver.
 _NOISE = [
     re.compile(rb"WARNING:.*xla_bridge.*experimental"),
     re.compile(rb"^\s*$"),

@@ -1,13 +1,20 @@
 """ctypes binding for the native arena store (native/arena_store.cpp).
 
 The .so builds on first use with the in-image g++ (no pybind11 — plain
-C ABI). `load()` returns None when the toolchain is unavailable, and the
-store falls back to the file-per-object backend.
+C ABI). Staleness is decided by a content hash of the source kept next to
+the .so, never by mtime: a copy or a fresh checkout does not preserve
+mtimes, and `native/build/` is git-ignored, so the library is always
+built from what git would commit. `load()` returns None when the build
+or the dlopen fails and the store falls back to the file-per-object
+backend; `load_error()` says why, so a caller that must not run on the
+slow path (chip_smoke.py) can refuse to.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,9 +24,10 @@ _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), "native")
 _SO_PATH = os.path.join(_NATIVE_DIR, "build", "libarena_store.so")
+_STAMP_PATH = _SO_PATH + ".src-sha256"
 _BUILD_LOCK = threading.Lock()
 _LIB = None
-_LOAD_FAILED = False
+_LOAD_ERROR: Optional[str] = None
 
 
 def _configure(lib) -> None:
@@ -49,26 +57,57 @@ def _configure(lib) -> None:
     lib.rtpu_store_stats.argtypes = [ctypes.c_void_p, u64 * 4]
 
 
+def _source_digest() -> str:
+    with open(os.path.join(_NATIVE_DIR, "arena_store.cpp"), "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _build_if_stale() -> None:
+    digest = _source_digest()
+    os.makedirs(os.path.dirname(_SO_PATH), exist_ok=True)
+    # Raylet and workers may all get here at once: one builds, the
+    # others wait on the lock and then find the stamp current.
+    with open(_SO_PATH + ".lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        try:
+            with open(_STAMP_PATH) as f:
+                current = f.read().strip() == digest
+        except OSError:
+            current = False
+        if current and os.path.exists(_SO_PATH):
+            return
+        subprocess.run(["make", "-B", "-C", _NATIVE_DIR],
+                       check=True, capture_output=True, timeout=120)
+        with open(_STAMP_PATH, "w") as f:
+            f.write(digest)
+
+
 def load():
-    """Build (once) + dlopen the arena store; None if unavailable."""
-    global _LIB, _LOAD_FAILED
-    if _LIB is not None or _LOAD_FAILED:
+    """Build (once) + dlopen the arena store; None if unavailable (see
+    `load_error()` for the reason)."""
+    global _LIB, _LOAD_ERROR
+    if _LIB is not None or _LOAD_ERROR is not None:
         return _LIB
     with _BUILD_LOCK:
-        if _LIB is not None or _LOAD_FAILED:
+        if _LIB is not None or _LOAD_ERROR is not None:
             return _LIB
         try:
-            src = os.path.join(_NATIVE_DIR, "arena_store.cpp")
-            if (not os.path.exists(_SO_PATH)
-                    or os.path.getmtime(_SO_PATH) < os.path.getmtime(src)):
-                subprocess.run(["make", "-C", _NATIVE_DIR],
-                               check=True, capture_output=True, timeout=120)
+            _build_if_stale()
             lib = ctypes.CDLL(_SO_PATH)
             _configure(lib)
             _LIB = lib
-        except Exception:
-            _LOAD_FAILED = True
+        except (OSError, subprocess.SubprocessError, AttributeError) as e:
+            detail = getattr(e, "stderr", None)
+            if isinstance(detail, bytes):
+                detail = detail.decode(errors="replace")[-2000:]
+            _LOAD_ERROR = f"{type(e).__name__}: {e}" + (
+                f"\n{detail}" if detail else "")
     return _LIB
+
+
+def load_error() -> Optional[str]:
+    """Why the last `load()` returned None (None if it has not failed)."""
+    return _LOAD_ERROR
 
 
 _UINT64_MAX = 2 ** 64 - 1

@@ -2704,12 +2704,10 @@ class Worker:
         self._mark_log_task(spec)
         self._ctx.task_id = spec.task_id
         self._ctx.task_name = spec.name
+        # The chips themselves were fixed by this worker's spawn
+        # environment (raylet._worker_env): mutating TPU_VISIBLE_CHIPS
+        # here would come after JAX may have initialised.
         self._ctx.tpu_ids = list(tpu_ids or [])
-        if tpu_ids:
-            from ray_tpu.accelerators.tpu import TPUAcceleratorManager
-
-            TPUAcceleratorManager.set_current_process_visible_accelerator_ids(
-                [str(i) for i in tpu_ids])
         tid = spec.task_id.binary()
         self._executing_tids[tid] = threading.get_ident()
         self._thread_task[threading.get_ident()] = tid

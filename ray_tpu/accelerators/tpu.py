@@ -9,9 +9,14 @@ quantity enforcement at `:144`).
 Detection priority:
 1. ``RAY_TPU_FAKE_CHIPS`` env (tests: fake N chips without hardware),
 2. ``/dev/accel*`` device files (PCI TPU VM),
-3. ``/sys/class/vfio`` entries (newer TPU VM images),
-4. jax device enumeration if jax is already initialized on a TPU platform,
-5. GCE metadata server (pod topology / accelerator type).
+3. ``/dev/vfio`` entries (newer TPU VM images),
+4. GCE metadata server (pod topology / accelerator type).
+
+Detection never touches JAX: asking JAX for devices opens the TPU backend,
+and the process that does so holds the chip until it exits — the node
+daemons and the driver must stay off it so a leased worker can own it.  A
+process that owns its chips (a leased worker, a single-process launcher)
+counts them with ``jax.devices()`` itself.
 """
 
 from __future__ import annotations
@@ -71,31 +76,6 @@ class TPUAcceleratorManager(AcceleratorManager):
         vfio = glob.glob("/dev/vfio/[0-9]*")
         if vfio:
             return len(vfio)
-        # If jax has already INITIALIZED a backend in this process and it
-        # is a TPU, trust it. Merely-imported jax is not enough: calling
-        # jax.devices() would trigger backend init here, and when the
-        # accelerator transport is down that call hangs — wedging
-        # ray_tpu.init() itself (the round-4 dryrun lost its signal to
-        # exactly this; jax is pre-imported in some environments).
-        try:
-            import sys
-
-            jax = sys.modules.get("jax")
-            if jax is not None:
-                from jax._src import xla_bridge
-
-                if not getattr(
-                        xla_bridge, "backends_are_initialized",
-                        lambda: bool(getattr(xla_bridge, "_backends",
-                                             None)))():
-                    return 0
-                devs = jax.devices()
-                if devs and "tpu" in devs[0].platform.lower() or (
-                        devs and "TPU" in getattr(devs[0], "device_kind", "")):
-                    return len([d for d in devs
-                                if "TPU" in getattr(d, "device_kind", "")])
-        except Exception:
-            pass
         return 0
 
     @staticmethod
@@ -141,19 +121,10 @@ class TPUAcceleratorManager(AcceleratorManager):
 
     @staticmethod
     def set_current_process_visible_accelerator_ids(ids: List[str]) -> None:
-        os.environ[TPU_VISIBLE_CHIPS_ENV] = ",".join(str(i) for i in ids)
-        # Single-chip processes must also shrink the host bounds so the TPU
-        # runtime doesn't try to grab the full host (reference tpu.py:158).
-        n = len(ids)
-        if n == 1:
-            os.environ[TPU_CHIPS_PER_HOST_BOUNDS_ENV] = "1,1,1"
-            os.environ[TPU_HOST_BOUNDS_ENV] = "1,1,1"
-        elif n == 2:
-            os.environ[TPU_CHIPS_PER_HOST_BOUNDS_ENV] = "1,2,1"
-            os.environ[TPU_HOST_BOUNDS_ENV] = "1,1,1"
-        else:
-            os.environ.pop(TPU_CHIPS_PER_HOST_BOUNDS_ENV, None)
-            os.environ.pop(TPU_HOST_BOUNDS_ENV, None)
+        """Only effective before this process initialises a JAX backend;
+        the raylet applies the same variables to a leased worker's spawn
+        environment (`apply_visible_chips`) for exactly that reason."""
+        apply_visible_chips(os.environ, ids)
 
     @staticmethod
     def get_current_node_extra_resources() -> Dict[str, float]:
@@ -177,6 +148,24 @@ class TPUAcceleratorManager(AcceleratorManager):
         if worker_id is not None and str(worker_id).strip() == "0":
             out[f"TPU-{accel_type}-head"] = 1
         return out
+
+
+def apply_visible_chips(env, ids) -> None:
+    """Make the process that runs under `env` (a spawn environment, or
+    ``os.environ`` before JAX initialises) see exactly chips `ids`.
+    Single- and two-chip processes must also shrink the host bounds so the
+    TPU runtime doesn't try to grab the full host (reference tpu.py:158)."""
+    env[TPU_VISIBLE_CHIPS_ENV] = ",".join(str(i) for i in ids)
+    n = len(ids)
+    if n == 1:
+        env[TPU_CHIPS_PER_HOST_BOUNDS_ENV] = "1,1,1"
+        env[TPU_HOST_BOUNDS_ENV] = "1,1,1"
+    elif n == 2:
+        env[TPU_CHIPS_PER_HOST_BOUNDS_ENV] = "1,2,1"
+        env[TPU_HOST_BOUNDS_ENV] = "1,1,1"
+    else:
+        env.pop(TPU_CHIPS_PER_HOST_BOUNDS_ENV, None)
+        env.pop(TPU_HOST_BOUNDS_ENV, None)
 
 
 def _accel_version(accel_type: str) -> Optional[str]:
@@ -214,3 +203,4 @@ def get_current_pod_name() -> Optional[str]:
 
 def get_num_tpu_chips_on_node() -> int:
     return TPUAcceleratorManager.get_current_node_num_accelerators()
+

@@ -21,14 +21,17 @@ Published peaks (bf16 dense matmul FLOP/s and HBM bandwidth):
 Rules of the table:
 
 - ``lookup`` normalizes the strings jax reports as ``device_kind``
-  ("TPU v5 lite" -> v5e, "TPU v5p"/"TPU v5" -> v5p, ...).
+  ("TPU v5 lite" -> v5e, "TPU v5p" -> v5p, ...).
 - CPU backends resolve to a *nominal* spec tagged
   ``measurement="cpu"``: the plumbing (rows, ratios, summaries) works
   identically in tier-1 CPU tests, but every consumer can see the
   ratios prove wiring, not performance.
-- Unknown kinds degrade to ``spec="unknown"`` with **no** peaks
-  (``peak_flops is None``) — MFU/MBU for such rows is ``None``, never
-  a number fabricated from a guessed denominator.
+- A device kind that is not in the table is an **error**
+  (`UnknownChipError`), not a default: a guessed denominator turns
+  every ratio derived from it into a fabricated number.  Add the row,
+  with the source of its peaks.  :data:`UNKNOWN` (``peak_flops is
+  None``, MFU/MBU ``None``) stands only for "no single kind to name":
+  no device, or a mesh of mixed generations that share no roofline.
 """
 
 from __future__ import annotations
@@ -73,44 +76,48 @@ _SPECS = {
 UNKNOWN = ChipSpec("unknown", None, None, measurement="unknown")
 
 # device_kind substrings -> canonical generation, checked in order
-# (first match wins, so "v5 lite"/"v5e" must precede the bare "v5"
-# that v5p hosts sometimes report).
+# (first match wins). A bare "v5" names no generation and matches nothing.
 _KIND_PATTERNS = (
     ("v5 lite", "v5e"),
     ("v5litepod", "v5e"),
     ("v5e", "v5e"),
     ("v5p", "v5p"),
-    ("v5", "v5p"),
     ("v4", "v4"),
     ("cpu", "cpu"),
 )
 
 
+class UnknownChipError(ValueError):
+    """A device kind with no row in the peak table."""
+
+
 def lookup(device_kind: Optional[str]) -> ChipSpec:
     """Resolve a jax ``device_kind`` (or mesh-inventory chip string) to
-    its :class:`ChipSpec`. Unknown kinds return :data:`UNKNOWN` rather
-    than fabricating peaks."""
+    its :class:`ChipSpec`. No kind at all (or the literal "unknown" an
+    inventory row carries) is :data:`UNKNOWN`; a kind that is not in
+    the table raises `UnknownChipError`."""
     if not device_kind:
         return UNKNOWN
     kind = str(device_kind).strip().lower()
+    if kind == UNKNOWN.spec:
+        return UNKNOWN
     for pattern, gen in _KIND_PATTERNS:
         if pattern in kind:
             return _SPECS[gen]
-    return UNKNOWN
+    raise UnknownChipError(
+        f"device kind {device_kind!r} has no row in the chip peak table "
+        "(observability/chipspec.py): add one, with the source of its "
+        "peaks, rather than guessing a denominator")
 
 
 def local_spec() -> ChipSpec:
     """Spec of this process's default jax backend (first local device)."""
-    try:
-        import jax
+    import jax
 
-        devices = jax.local_devices()
-        if not devices:
-            return UNKNOWN
-        dev = devices[0]
-        kind = getattr(dev, "device_kind", None) or dev.platform
-        if dev.platform == "cpu":
-            return _SPECS["cpu"]
-        return lookup(kind)
-    except Exception:
+    devices = jax.local_devices()
+    if not devices:
         return UNKNOWN
+    dev = devices[0]
+    if dev.platform == "cpu":
+        return _SPECS["cpu"]
+    return lookup(getattr(dev, "device_kind", None) or dev.platform)

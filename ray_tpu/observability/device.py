@@ -8,9 +8,10 @@ count that doesn't match the slice topology are the first things to
 check when a TPU job underperforms.
 
 Deliberately conservative about initialization: sampling NEVER
-initializes a jax backend (that can cost seconds over a tunneled TPU
-connection, in processes that never run device code) — it only reads
-from backends that are already live.
+initializes a jax backend — a process that opens the TPU backend holds
+the chip until it exits, and most processes that flush metrics (driver,
+daemons, CPU workers) must stay off it — it only reads from backends
+that are already live.
 """
 
 from __future__ import annotations

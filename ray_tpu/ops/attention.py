@@ -334,10 +334,9 @@ def _bhsd_bwd(q, k, v, do, o, lse, causal, block_q, block_k, scale):
 # ---------------------------------------------------------------------------
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    # No exception handling here on purpose: a backend that fails to
+    # initialise must fail the caller, not quietly select XLA attention.
+    return jax.default_backend() == "tpu"
 
 
 def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
@@ -380,6 +379,11 @@ def _blocks(S: int, Sk: int, causal: bool = True) -> Tuple[int, int]:
 
 
 def _use_kernel(q, k) -> bool:
+    """The one documented size rule, the same on every backend: under 128
+    positions a tile would be mostly padding, and `flash_attention` IS
+    `xla_attention`. From 128 on, a TPU always gets the compiled kernel
+    (never interpret mode, never XLA attention); off-TPU the kernel runs
+    only when a test forces the interpreter."""
     if q.shape[1] < 128 or k.shape[1] < 128:
         return False
     return _on_tpu() or FORCE_PALLAS_INTERPRET

@@ -48,29 +48,7 @@ def _axis_size(axis_name: str) -> Optional[int]:
     """Static size of a bound mesh axis, or None when unbound."""
     try:
         return lax.axis_size(axis_name)
-    except (NameError, KeyError, ValueError, TypeError, AttributeError):
-        # AttributeError: lax.axis_size itself is absent on older jax
-        # (0.4.x spellings handled below).
-        pass
-    try:
-        # psum of a python scalar folds to a static int when the axis is
-        # bound and raises NameError when it is not — works on every jax
-        # this repo supports (0.4.x included, where the lookups below
-        # return ints or are missing entirely).
-        size = lax.psum(1, axis_name)
-        if isinstance(size, int):
-            return size
-    except Exception:
-        pass
-    try:  # older spellings
-        frame = jax.core.axis_frame(axis_name)  # type: ignore
-        return frame if isinstance(frame, int) else frame.size
-    except Exception:
-        pass
-    try:
-        frame = jax.core.get_axis_env().axis_frame(axis_name)  # type: ignore
-        return frame.size
-    except Exception:
+    except NameError:
         return None
 
 
@@ -153,11 +131,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         # shard_map vma typing: carries computed from axis_index become
         # "varying" over the axis; the zero-init carries must be cast to
         # match or lax.scan rejects the body signature.
-        if hasattr(lax, "pcast"):
-            return lax.pcast(x, (axis_name,), to="varying")
-        if hasattr(lax, "pvary"):
-            return lax.pvary(x, (axis_name,))
-        return x
+        return lax.pcast(x, (axis_name,), to="varying")
 
     m0 = _vary(jnp.full((B, H, Sl), _NEG, jnp.float32))
     l0 = _vary(jnp.zeros((B, H, Sl), jnp.float32))
@@ -179,19 +153,11 @@ def ring_attention_global(q: jax.Array, k: jax.Array, v: jax.Array,
     ring runs under ``shard_map``."""
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map  # jax >= 0.7 spelling
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map  # type: ignore
-
     spec = P(None, seq_axis, None, None)
-    # check_rep off: Pallas kernels are opaque to the replication checker,
-    # and on jax 0.4.x even the lax ring trips its scan-carry vma typing
-    # (the axis_index-derived carries).  Correctness is covered by the
-    # parity tests, not the static checker.
-    fn = shard_map(
+    # check_vma off: Pallas kernels are opaque to the varying-axes checker.
+    fn = jax.shard_map(
         partial(ring_attention, causal=causal, axis_name=seq_axis,
                 impl=impl),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     return fn(q, k, v)

@@ -76,13 +76,12 @@ def ulysses_attention_global(q, k, v, mesh, causal: bool = True,
                              seq_axis: str = "sp"):
     """Apply the shard_map over `mesh[seq_axis]` for global [B, S, H, D]
     inputs sharded on the sequence dimension."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(None, seq_axis, None, None)
-    return shard_map(
+    return jax.shard_map(
         lambda a, b, c: ulysses_attention(a, b, c, causal=causal,
                                           axis_name=seq_axis),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )(q, k, v)

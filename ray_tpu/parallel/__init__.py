@@ -4,6 +4,7 @@ from ray_tpu.parallel.mesh import (
 )
 from ray_tpu.parallel.sharding import (
     batch_sharding, batch_spec, context_parallel_attention,
+    sharded_flash_attention,
     llama_param_shardings, llama_param_specs, replicated, shard_params,
 )
 from ray_tpu.parallel.train_step import (
@@ -18,7 +19,7 @@ from ray_tpu.parallel.zero import (
 __all__ = [
     "make_mesh", "data_parallel_mesh", "discover_devices",
     "fsdp_mesh", "mesh_axis_size",
-    "context_parallel_attention",
+    "context_parallel_attention", "sharded_flash_attention",
     "llama_param_specs", "llama_param_shardings", "batch_spec",
     "batch_sharding", "shard_params", "replicated", "TrainState",
     "create_train_state", "build_train_step", "build_eval_step",

@@ -27,9 +27,12 @@ AXIS_ORDER = ("data", "fsdp", "pipe", "seq", "tensor")
 # Env vars whose presence marks a multi-host launch (TPU pod slice /
 # multi-process GPU): a coordinator exists, so the GLOBAL device list is
 # only visible after joining jax.distributed.
+# Variables that name a coordinator to join. TPU_WORKER_HOSTNAMES is NOT
+# one of them: a single-host TPU VM sets it too, and an argument-less
+# `jax.distributed.initialize()` there goes looking for a metadata server.
 _COORDINATOR_VARS = (
     "JAX_COORDINATOR_ADDRESS", "COORDINATOR_ADDRESS",
-    "MEGASCALE_COORDINATOR_ADDRESS", "TPU_WORKER_HOSTNAMES",
+    "MEGASCALE_COORDINATOR_ADDRESS",
 )
 
 _distributed_join_attempted = False
@@ -46,11 +49,13 @@ def _maybe_join_distributed() -> None:
     Under a multi-host launch, the local backend alone discovers only
     this process's chips — `jax.devices()` then reports e.g. 1 of 8
     devices and every multi-axis mesh request fails its divisibility
-    check (MULTICHIP_r05: `1 devices not divisible by 4`). The fix is
-    ordering: `jax.distributed.initialize()` must run before the first
-    backend touch, after which `jax.devices()` is the global list. On
+    check (`1 devices not divisible by 4`). The fix is ordering:
+    `jax.distributed.initialize()` must run before the first backend
+    touch, after which `jax.devices()` is the global list. On
     single-host setups (no coordinator vars) this is a no-op — tests
     and laptops never pay for or hang on an unreachable coordinator.
+    A join that fails raises: a mesh silently built from one process's
+    chips trains a different job than the one that was launched.
     """
     global _distributed_join_attempted
     if _distributed_join_attempted:
@@ -60,22 +65,11 @@ def _maybe_join_distributed() -> None:
         return
     import jax
 
-    try:
-        from jax._src import distributed as _dist
-
-        if getattr(_dist.global_state, "client", None) is not None:
-            return                      # someone already joined
-    except Exception:
-        pass
-    try:
-        # Coordinator address / process id / num_processes all come from
-        # the environment (jax reads the standard vars itself).
-        jax.distributed.initialize()
-    except Exception:
-        # Best effort: a failed join leaves local-only discovery, and
-        # make_mesh's inventory message reports the process topology so
-        # the failure is diagnosable rather than a bare count mismatch.
-        pass
+    if jax.distributed.is_initialized():
+        return                          # someone already joined
+    # Coordinator address / process id / num_processes all come from
+    # the environment (jax reads the standard vars itself).
+    jax.distributed.initialize()
 
 
 def discover_devices() -> List:
@@ -92,8 +86,9 @@ def device_inventory(devices: Optional[Sequence] = None
                      ) -> Dict[str, object]:
     """Structured accelerator inventory: count, platforms, chip
     generation/kind, and the chip-spec peaks the XLA attribution plane
-    divides by (observability/chipspec.py). Unknown kinds degrade to
-    ``spec: "unknown"`` with no peaks — never fabricated numbers."""
+    divides by (observability/chipspec.py). A device kind with no row
+    there raises — never fabricated numbers; only a mesh of mixed kinds,
+    which share no roofline, reports ``spec: "unknown"`` with no peaks."""
     from ray_tpu.observability import chipspec
 
     devices = list(devices if devices is not None else discover_devices())

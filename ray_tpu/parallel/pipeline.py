@@ -20,10 +20,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map  # jax >= 0.7 spelling
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -49,16 +46,10 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
         n_ticks = m + n_stages - 1
 
         def varying(v):
-            # New-style shard_map tracks "varying manual axes": the scan
-            # carry becomes pipe-varying inside the loop, so the initial
-            # value must be marked varying too (no-op data-wise).
-            pcast = getattr(jax.lax, "pcast", None)
-            if pcast is None:
-                return v
-            try:
-                return pcast(v, (axis,), to="varying")
-            except Exception:
-                return v
+            # shard_map tracks "varying manual axes": the scan carry
+            # becomes pipe-varying inside the loop, so the initial value
+            # must be marked varying too (no-op data-wise).
+            return jax.lax.pcast(v, (axis,), to="varying")
 
         zero = varying(jnp.zeros_like(xs[0]))
         ys = varying(jnp.zeros_like(xs))

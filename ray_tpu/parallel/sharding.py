@@ -104,6 +104,30 @@ def replicated(mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
+def sharded_flash_attention(mesh):
+    """Flash attention callable for a GSPMD-partitioned step on `mesh`.
+
+    The compiler cannot partition a Mosaic kernel by itself ("Mosaic
+    kernels cannot be automatically partitioned"), so on a mesh of more
+    than one device the kernel runs under `shard_map`: attention is
+    independent across the batch (the data-like axes) and across heads
+    (the ``tensor`` axis), which is exactly how the surrounding program
+    shards q, k and v. Plug into ``forward(attn_impl=...)`` /
+    ``loss_fn(attn_impl=...)``."""
+    from ray_tpu.ops.attention import flash_attention
+
+    batch_axes = tuple(batch_spec(mesh)) or (None,)
+    spec = P(*batch_axes, None, _ax(mesh, "tensor"), None)
+
+    def attn(q, k, v, causal=True):
+        return jax.shard_map(
+            lambda q, k, v: flash_attention(q, k, v, causal),
+            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False)(q, k, v)
+
+    return attn
+
+
 def context_parallel_attention(mesh, seq_axis: str = "seq",
                                impl: str = "ring"):
     """Attention callable for context-parallel training (SURVEY §7 M11):

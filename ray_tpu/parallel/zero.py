@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import optax
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.flatten_util import ravel_pytree
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -121,7 +121,7 @@ def create_zero_state(params, optimizer, mesh, axis_name: str = "data",
 
     opt_state = tracked_jit(shard_map(
         init_shard, mesh=mesh, in_specs=P(),
-        out_specs=out_specs, check_rep=False),
+        out_specs=out_specs, check_vma=False),
         name="zero_init_shard")(flat)
     ef = None
     if error_feedback:
@@ -324,7 +324,7 @@ def build_zero_train_step(
                 step_fn, mesh=mesh,
                 in_specs=(state_specs, batch_specs),
                 out_specs=(state_specs, metric_specs),
-                check_rep=False), name="zero_train_step",
+                check_vma=False), name="zero_train_step",
                 donate_argnums=(0,))
             jitted_cache[cache_key] = fn
         return fn(state, batch)

@@ -12,7 +12,7 @@ donated XLA call; the N-action Q evaluations batch as one big matmul
 
 Offline ingestion streams from `ray_tpu.data` (parquet shards via
 `offline.DatasetReader`) or an in-memory row list — closing the
-JSONL-only gap (VERDICT r4 weak-7).
+JSONL-only gap.
 """
 
 from __future__ import annotations

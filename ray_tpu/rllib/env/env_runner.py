@@ -42,6 +42,9 @@ class EnvRunner:
                  inference_server=None, weight_store=None):
         import jax
 
+        # An env runner holds no TPU lease, so the raylet spawned this
+        # worker with JAX_PLATFORMS=cpu: the query below can only
+        # initialise the CPU backend, never open a chip.
         self._cpu = jax.devices("cpu")[0]
         self._server = inference_server
         self._weight_store = weight_store

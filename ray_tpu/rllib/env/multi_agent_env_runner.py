@@ -28,6 +28,9 @@ class MultiAgentEnvRunner:
                  num_envs: int = 1, seed: int = 0):
         import jax
 
+        # An env runner holds no TPU lease, so the raylet spawned this
+        # worker with JAX_PLATFORMS=cpu: the query below can only
+        # initialise the CPU backend, never open a chip.
         self._cpu = jax.devices("cpu")[0]
         self._mapping = policy_mapping_fn or default_policy_mapping_fn
         self._envs = [make_env(env_spec, seed=seed * 10007 + i)

@@ -153,7 +153,7 @@ class DatasetReader:
     """Stream transition batches out of a `ray_tpu.data.Dataset` —
     offline training ingests Data pipelines (parquet shards, any Data
     source) directly instead of JSONL-only (reference: `rllib/offline/`
-    new-stack readers are Ray Data datasets; VERDICT r4 weak-7).
+    new-stack readers are Ray Data datasets).
 
     `path_or_dataset`: a Dataset, or a path read via
     `data.read_parquet`. `batches(batch_size)` yields numpy dicts with

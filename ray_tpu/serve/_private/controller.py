@@ -14,6 +14,7 @@ import time
 from typing import Any, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu.exceptions import GetTimeoutError
 
 CONTROLLER_NAME = "SERVE_CONTROLLER"
 
@@ -122,11 +123,20 @@ class ServeController:
                     changed = True
                 self._replica_hash[key] = spec_hash
                 probe = list(replicas)
-            # Drop dead replicas (health probe).
+            # Drop dead replicas (health probe). Slow is not dead: a
+            # replica still constructing its model (weights, cold
+            # compiles: minutes on a chip) answers no probe until its
+            # constructor returns, yet it holds its lease — its chip above
+            # all — until it exits, so a replacement spawned for it could
+            # never start and would only strand the handle on a pending
+            # actor. A probe that times out keeps the replica; only an
+            # error (the actor died, or check_health raised) drops it.
             live = []
             for r in probe:
                 try:
                     ray_tpu.get(r.check_health.remote(), timeout=30)
+                    live.append(r)
+                except GetTimeoutError:
                     live.append(r)
                 except Exception:
                     changed = True

@@ -27,8 +27,9 @@ class LLMServer:
     the replica process, never on the serialization path).
 
     ``quantize`` defaults to ``"int8"`` — weight-only int8 decode
-    measured 1.28x decode throughput (BENCH_r05: 2158 vs 1683 tok/s) at
-    matched quality on the serving path, so it is the serve default;
+    measured 1.28x decode throughput (2158 vs 1683 tok/s, taken before
+    this round on an installation that no longer exists) at matched
+    quality on the serving path, so it is the serve default;
     pass ``quantize="bf16"`` to opt out (e.g. for bit-parity against an
     offline bf16 reference). The legacy ``quantize_int8=True`` flag is
     honored as a synonym for ``quantize="int8"``.
@@ -58,7 +59,7 @@ class LLMServer:
             engine_config = EngineConfig(**engine_config)
 
         if quantize is None:
-            quantize = "int8"           # serve default (BENCH_r05)
+            quantize = "int8"           # serve default (see class doc)
         if quantize not in ("int8", "bf16"):
             raise ValueError(
                 f"quantize must be 'int8' or 'bf16', got {quantize!r}")
@@ -213,8 +214,23 @@ class LLMServer:
     def stats(self) -> Dict[str, Any]:
         from ray_tpu.observability import jit_stats
 
+        import jax
+
+        from ray_tpu._private import compile_cache
+
         out = self._engine.stats()
         out["quantize"] = self.quantize
+        # Where this replica actually runs: a caller that leased a chip
+        # checks it got one (chip_smoke.py fails on anything but "tpu").
+        import os
+
+        dev = jax.devices()[0]
+        peak = (dev.memory_stats() or {}).get("peak_bytes_in_use", 0)
+        out["device"] = {"platform": dev.platform, "kind": dev.device_kind,
+                         "count": jax.device_count(), "pid": os.getpid(),
+                         "peak_hbm_gib": round(peak / 2 ** 30, 3)}
+        out["compile_cache"] = {"dir": compile_cache.cache_dir(),
+                                **compile_cache.stats()}
         out["jit"] = {k: v for k, v in jit_stats().items()
                       if k.startswith("llm_engine_")}
         return out
@@ -232,7 +248,7 @@ class LLMServer:
 
 def build_llm_app(model_config: Any = None, engine_config: Any = None,
                   *, name: str = "llm", num_replicas: int = 1,
-                  num_tpus: float = 0, max_ongoing_requests: int = 32,
+                  num_tpus: float, max_ongoing_requests: int = 32,
                   init_seed: int = 0, quantize: Optional[str] = None,
                   quantize_int8: bool = False,
                   params_loader: Optional[Any] = None):
@@ -240,7 +256,9 @@ def build_llm_app(model_config: Any = None, engine_config: Any = None,
     `max_ongoing_requests` concurrent submitters feeding its slot pool.
     Pass configs as dicts (e.g. ``{"num_slots": 8}``) or dataclasses.
     ``quantize`` defaults to the int8 serve config; pass "bf16" to opt
-    out. For N replicas behind a queue-depth-aware router, use
+    out. ``num_tpus`` is each replica's chip lease and has no default:
+    a replica without a lease runs in a worker that can only see the
+    CPU (pass ``num_tpus=0`` to ask for exactly that). For N replicas behind a queue-depth-aware router, use
     ``serve.llm.build_routed_llm_app`` instead."""
     from ray_tpu import serve
 

@@ -23,7 +23,7 @@ def build_disagg_llm_app(model_config: Any = None,
                          prefill_engine_config: Any = None,
                          prefill_threshold: int = 256,
                          speculative: Any = None,
-                         num_tpus: float = 0,
+                         num_tpus: float,
                          max_ongoing_requests: int = 32,
                          init_seed: int = 0,
                          quantize: Optional[str] = None,

@@ -65,6 +65,15 @@ def p2c_pick(replicas: Sequence[Any], load: Dict[Any, float],
     return a if load.get(a, 0.0) <= load.get(b, 0.0) else b
 
 
+def _replica_tag(replica) -> str:
+    """Metric-tag-safe replica identity: an actor id is raw bytes, whose
+    `str()` is a repr that can contain a comma (tag values may not)."""
+    rid = getattr(replica, "_actor_id", None)
+    if isinstance(rid, (bytes, bytearray)):
+        return bytes(rid).hex()
+    return str(rid if rid is not None else id(replica))
+
+
 class LLMRouter:
     """Deployment callable fronting the ``LLMServer`` deployment.
 
@@ -222,10 +231,10 @@ class LLMRouter:
                         self._depth[r] = depth
                     if index_id:
                         self._index_id[r] = str(index_id)
-                rid = getattr(r, "_actor_id", id(r))
+                rid = _replica_tag(r)
                 if depth != float("inf"):
                     self._metrics.router_queue_depth.set(
-                        depth, tags={"replica": str(rid)})
+                        depth, tags={"replica": rid})
             for r in pre:
                 depth, _ = self._probe_one(r)
                 with self._lock:
@@ -440,7 +449,7 @@ class LLMRouter:
         two_hop = (self._pre_app is not None
                    and len(prompt) >= self._pre_threshold)
         chosen, expected, outcome = self._pick_cached(prompt)
-        rid = str(getattr(chosen, "_actor_id", id(chosen)))
+        rid = _replica_tag(chosen)
         pre = self._pick("prefill") if two_hop else None
         with self._lock:
             self._inflight[chosen] = self._inflight.get(chosen, 0) + 1
@@ -553,7 +562,7 @@ def build_routed_llm_app(model_config: Any = None,
                          name: str = "llm",
                          num_replicas: Any = 2,
                          autoscaling_config: Optional[Dict[str, Any]] = None,
-                         num_tpus: float = 0,
+                         num_tpus: float,
                          max_ongoing_requests: int = 32,
                          init_seed: int = 0,
                          quantize: Optional[str] = None,

@@ -53,22 +53,13 @@ def _setup_jax_distributed(coordinator: Optional[str], world_size: int,
                            num_cpu_devices: Optional[int]) -> int:
     import jax
 
+    from ray_tpu._private import compile_cache
+
+    compile_cache.configure()
     if platform:
         jax.config.update("jax_platforms", platform)
     if num_cpu_devices and (platform == "cpu"):
-        try:
-            jax.config.update("jax_num_cpu_devices", num_cpu_devices)
-        except AttributeError:
-            # jax < 0.5 has no jax_num_cpu_devices; the XLA flag is the
-            # same knob but is only read at backend init, so it must land
-            # in the environment before the first device query.
-            import os
-
-            flag = ("--xla_force_host_platform_device_count="
-                    f"{num_cpu_devices}")
-            existing = os.environ.get("XLA_FLAGS", "")
-            if "xla_force_host_platform_device_count" not in existing:
-                os.environ["XLA_FLAGS"] = f"{existing} {flag}".strip()
+        jax.config.update("jax_num_cpu_devices", num_cpu_devices)
     if world_size > 1:
         jax.distributed.initialize(
             coordinator_address=coordinator,
@@ -141,13 +132,17 @@ def run_pod_training(model_config=None, mesh_axes=None, steps: int = 4,
                      weight_update: str = "replicated",
                      learning_rate: float = 1e-3, seed: int = 0,
                      overlap: bool = False, n_chunks: int = 4,
-                     collective: str = "auto", report=None) -> dict:
+                     collective: str = "auto", report=None,
+                     devices=None) -> dict:
     """Run `steps` sharded Llama train steps; returns throughput metrics.
 
     The returned dict carries ``tokens_per_sec`` / ``tokens_per_sec_per_chip``
     measured over the post-compile steps (step 0 is the compile+warmup step
-    and is excluded), which is what MULTICHIP_rXX.json and ROADMAP item 1
-    compare against the single-chip figure.
+    and is excluded). ``devices`` restricts the mesh to a subset of
+    ``jax.devices()`` (default: all of them) — how one process compares a
+    sharded run with the same steps on a one-device mesh. The summary's
+    ``state_bytes_per_device`` says where parameters and optimizer state
+    actually live after the last step.
 
     ``overlap=True`` routes the loop through the explicit chunked
     split-phase ZeRO step (`parallel.zero.build_zero_train_step` with
@@ -173,6 +168,7 @@ def run_pod_training(model_config=None, mesh_axes=None, steps: int = 4,
     import numpy as np
     import optax
 
+    from ray_tpu._private import compile_cache
     from ray_tpu._private.config import GlobalConfig
     from ray_tpu.observability.goodput import (
         GoodputLedger, StepPhases, goodput_metrics, publish_train_done,
@@ -183,14 +179,16 @@ def run_pod_training(model_config=None, mesh_axes=None, steps: int = 4,
     from ray_tpu.parallel import (
         batch_sharding, build_train_step, build_zero_train_step,
         create_train_state, create_zero_state, llama_param_shardings,
-        make_mesh, shard_params,
+        make_mesh, shard_params, sharded_flash_attention,
     )
 
+    compile_cache.configure()          # before the step's first compile
     if model_config is None:
         model_config = LlamaConfig(
             vocab_size=512, dim=128, n_layers=4, n_heads=8, n_kv_heads=4,
             hidden_dim=256, max_seq_len=128)
-    mesh = make_mesh(dict(mesh_axes) if mesh_axes else {"data": -1})
+    mesh = make_mesh(dict(mesh_axes) if mesh_axes else {"data": -1},
+                     devices)
     n_devices = int(np.prod(mesh.devices.shape))
 
     if overlap:
@@ -217,9 +215,14 @@ def run_pod_training(model_config=None, mesh_axes=None, steps: int = 4,
             n_chunks=n_chunks)
         state = create_zero_state(params, optimizer, mesh)
     else:
+        # A Mosaic kernel is not partitioned by GSPMD: on more than one
+        # device the flash kernel runs under shard_map over batch/heads.
+        attn = (sharded_flash_attention(mesh)
+                if model_config.attn_impl == "flash" and n_devices > 1
+                else None)
         step = build_train_step(
-            lambda p, b: loss_fn(p, b, model_config), optimizer, mesh,
-            shardings, bsh, weight_update=weight_update,
+            lambda p, b: loss_fn(p, b, model_config, attn), optimizer,
+            mesh, shardings, bsh, weight_update=weight_update,
             params_shape=params_shape)
         state = create_train_state(shard_params(params, shardings),
                                    optimizer)
@@ -285,6 +288,11 @@ def run_pod_training(model_config=None, mesh_axes=None, steps: int = 4,
     jax.block_until_ready(metrics["loss"])
     elapsed = time.perf_counter() - t0
     loss = float(metrics["loss"])
+    # How many Mosaic kernels the compiled step holds (the tracked step
+    # keeps its AOT artifact): 0 with attn_impl="flash" on a TPU would
+    # mean attention quietly became XLA attention.
+    compiled = step.compiled(state, batch) \
+        if hasattr(step, "compiled") else None
     tokens_per_sec = tokens_per_step * steps / max(elapsed, 1e-9)
     extra = {}
     if ledger is not None:
@@ -299,6 +307,12 @@ def run_pod_training(model_config=None, mesh_axes=None, steps: int = 4,
         publish_train_done(worker_label)
     return {
         **extra,
+        "step_tpu_custom_calls": (
+            None if compiled is None
+            else compiled.as_text().count("tpu_custom_call")),
+        "state_bytes_per_device": {
+            "params": _bytes_per_device(state.params),
+            "opt_state": _bytes_per_device(state.opt_state)},
         "n_devices": n_devices,
         "mesh": {name: int(size) for name, size
                  in zip(mesh.axis_names, mesh.devices.shape)},
@@ -312,6 +326,19 @@ def run_pod_training(model_config=None, mesh_axes=None, steps: int = 4,
         "tokens_per_sec": tokens_per_sec,
         "tokens_per_sec_per_chip": tokens_per_sec / max(n_devices, 1),
     }
+
+
+def _bytes_per_device(tree) -> dict:
+    """{device id: bytes of `tree` resident there}, from each array's
+    addressable shards (a replicated array counts once per holder)."""
+    import jax
+
+    out: dict = {}
+    for leaf in jax.tree.leaves(tree):
+        for shard in getattr(leaf, "addressable_shards", ()):
+            out[shard.device.id] = (out.get(shard.device.id, 0)
+                                    + shard.data.nbytes)
+    return out
 
 
 def pod_train_loop(config: Optional[dict] = None) -> None:

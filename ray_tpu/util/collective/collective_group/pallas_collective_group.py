@@ -70,7 +70,7 @@ class PallasGroup(XLAGroup):
     def _ring_fn(self, kind: str, axis_name: str, op: str, shape_key):
         """jit(shard_map(ring kernel)) over the group's 1-D device mesh,
         cached per (kind, op, shape/dtype) to avoid retraces."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         import jax
 
@@ -101,7 +101,7 @@ class PallasGroup(XLAGroup):
             else P(axis_name)
         wrapped = jax.jit(shard_map(
             fn, mesh=mesh, in_specs=P(axis_name),
-            out_specs=out_specs, check_rep=False))
+            out_specs=out_specs, check_vma=False))
         self._fn_cache[key] = wrapped
         return wrapped
 

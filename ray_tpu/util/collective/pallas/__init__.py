@@ -1,7 +1,7 @@
 """Pallas ICI ring collectives.
 
 Hand-written TPU collective kernels built on `pltpu.make_async_remote_copy`
-double-buffered rings, runnable under `shard_map` on a mesh axis.  Every
+remote-copy hops, runnable under `shard_map` on a mesh axis.  Every
 kernel has an `interpret=True` path so the exact same code is testable on
 CPU virtual devices, and every public entry point degrades to the
 corresponding `jax.lax` collective when Pallas is not viable (non-TPU

@@ -1,13 +1,20 @@
-"""EQuARX-style fused quantized ring allreduce.
+"""EQuARX-style quantized ring allreduce.
 
 PAPERS.md ("EQuARX: Efficient Quantized AllReduce in XLA") shows that on
 slow links an int8 allreduce with per-block scales buys ~2x wire time for a
-small accuracy cost.  This kernel fuses the whole thing: at every ring hop
-the outgoing chunk (a running f32 partial sum) is re-quantized to int8 with
-one f32 scale, the wire carries `chunk/4` the bytes, and the receiver
-dequantizes into its f32 accumulator.  Error therefore grows with hop
-count, not ring size squared — each hop contributes at most
-``max|chunk| / 254`` per element (symmetric round-to-nearest, 8 bits).
+small accuracy cost.  Here every ring hop re-quantizes the outgoing chunk
+(a running f32 partial sum) to int8 with one f32 scale, the wire carries
+`chunk/4` the bytes, and the receiver dequantizes into its f32 accumulator.
+Error therefore grows with hop count, not ring size squared — each hop
+contributes at most ``max|chunk| / 254`` per element (symmetric
+round-to-nearest, 8 bits).
+
+The wire leg is the same remote-copy kernel as the exact ring
+(`ring._permute_block`, HBM to HBM): quantize and dequantize are XLA
+fusions on either side of it, and the scale rides in a trailing int8 tile
+of the payload so a hop stays one DMA.  The requantization of running
+partial sums therefore still happens per hop and the wire only ever
+carries int8.
 
 Fallback ladder (mirrors `ring.select_impl`):
 
@@ -20,24 +27,23 @@ Fallback ladder (mirrors `ring.select_impl`):
 
 from __future__ import annotations
 
-import functools
 import os
 
-import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.util.collective.pallas import ring
 from ray_tpu.util.collective.pallas.ring import (
-    LANES, SplitPhaseHandle, _cap_signal, _cap_wait, _from_block,
-    _numel, _to_block, select_impl,
+    LANES, SplitPhaseHandle, _COMBINE, _ag_hop, _check_divisible, _chunk,
+    _from_block, _rs_hop, _sender, _slabs_to_block, _to_block, select_impl,
 )
 
 # Below this many elements the scale traffic dominates any wire savings.
 _MIN_QUANT_ELEMS = int(os.environ.get("RAY_TPU_QAR_MIN_ELEMS", "1024"))
 _QMAX = 127.0
+_add = _COMBINE["sum"]
+# One int8 tile (32 sublanes) appended to the payload carries the scale.
+_SCALE_ROWS = 32
 
 
 def _quantize(chunk):
@@ -46,141 +52,23 @@ def _quantize(chunk):
     return q, scale
 
 
-def _qar_kernel(n, axis_name, interpret,
-                in_ref, out_ref,
-                qcomm_ref, scomm_ref, qstage_ref, sstage_ref,
-                qsend_sems, qrecv_sems, ssend_sems, srecv_sems, cap_sems):
-    my = lax.axis_index(axis_name)
-    right = lax.rem(my + 1, n)
-    left = lax.rem(my + n - 1, n)
-    chunk = out_ref.shape[0] // n
-    total = 2 * (n - 1)
+def _quantized_sender(axis_name, n, impl):
+    """`send(chunk)` for the ring hop schedules: quantize the outgoing f32
+    chunk, move int8 payload + scale to the right neighbour in one hop,
+    dequantize what arrived from the left."""
+    send = _sender(axis_name, n, impl)
 
-    out_ref[...] = in_ref[...]
+    def qsend(chunk):
+        q, scale = _quantize(chunk)
+        scale_bytes = lax.bitcast_convert_type(scale, jnp.int8)  # (4,)
+        tile = jnp.zeros((_SCALE_ROWS * LANES,), jnp.int8)
+        tile = tile.at[:4].set(scale_bytes).reshape(_SCALE_ROWS, LANES)
+        got = send(jnp.concatenate([q, tile], axis=0))
+        rows = chunk.shape[0]
+        scale_in = lax.bitcast_convert_type(got[rows, :4], jnp.float32)
+        return got[:rows].astype(chunk.dtype) * scale_in
 
-    def hop(t, send_idx, recv_idx, accumulate):
-        slot = t % 2
-        q, scale = _quantize(out_ref[pl.ds(send_idx * chunk, chunk)])
-        qstage_ref[...] = q
-        sstage_ref[0, 0] = scale
-        _cap_wait(cap_sems, slot, t, interpret)
-        qrdma = pltpu.make_async_remote_copy(
-            src_ref=qstage_ref, dst_ref=qcomm_ref.at[slot],
-            send_sem=qsend_sems.at[slot], recv_sem=qrecv_sems.at[slot],
-            device_id=right, device_id_type=pltpu.DeviceIdType.LOGICAL)
-        srdma = pltpu.make_async_remote_copy(
-            src_ref=sstage_ref, dst_ref=scomm_ref.at[slot],
-            send_sem=ssend_sems.at[slot], recv_sem=srecv_sems.at[slot],
-            device_id=right, device_id_type=pltpu.DeviceIdType.LOGICAL)
-        qrdma.start()
-        srdma.start()
-        qrdma.wait()
-        srdma.wait()
-        deq = qcomm_ref[slot].astype(out_ref.dtype) * scomm_ref[slot, 0, 0]
-        if accumulate:
-            out_ref[pl.ds(recv_idx * chunk, chunk)] = (
-                out_ref[pl.ds(recv_idx * chunk, chunk)] + deq)
-        else:
-            out_ref[pl.ds(recv_idx * chunk, chunk)] = deq
-        _cap_signal(cap_sems, slot, t, total, left, interpret)
-
-    t = 0
-    for s in range(n - 1):  # reduce-scatter sweep over quantized partials
-        hop(t, lax.rem(my - s + n, n), lax.rem(my - s - 1 + n, n),
-            accumulate=True)
-        t += 1
-    for s in range(n - 1):  # allgather sweep of the reduced chunks
-        hop(t, lax.rem(my - s + 1 + n, n), lax.rem(my - s + n, n),
-            accumulate=False)
-        t += 1
-
-
-def _qar_block(x, axis_name, n, interpret):
-    chunk = x.shape[0] // n
-    kernel = functools.partial(_qar_kernel, n, axis_name, interpret)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, chunk) + x.shape[1:], jnp.int8),   # qcomm
-            pltpu.VMEM((2, 1, 1), jnp.float32),               # scomm
-            pltpu.VMEM((chunk,) + x.shape[1:], jnp.int8),     # qstage
-            pltpu.VMEM((1, 1), jnp.float32),                  # sstage
-            pltpu.SemaphoreType.DMA((2,)),                    # q send
-            pltpu.SemaphoreType.DMA((2,)),                    # q recv
-            pltpu.SemaphoreType.DMA((2,)),                    # s send
-            pltpu.SemaphoreType.DMA((2,)),                    # s recv
-            pltpu.SemaphoreType.REGULAR((2,)),                # capacity
-        ],
-        interpret=interpret,
-        compiler_params=None if interpret else pltpu.TPUCompilerParams(
-            collective_id=3),
-    )(x)
-
-
-def _qhop_kernel(n, axis_name, in_ref, out_ref,
-                 qstage_ref, sstage_ref, qcomm_ref, scomm_ref,
-                 qsend, qrecv, ssend, srecv):
-    """One fused quantized ring hop: quantize the outgoing f32 block to
-    int8 *inside the kernel*, DMA payload+scale to the right neighbour,
-    dequantize the incoming pair into f32.  The requantization of running
-    partial sums lives in the DMA loop (EQuARX), not as a host pre-pass —
-    the wire only ever carries int8."""
-    my = lax.axis_index(axis_name)
-    right = lax.rem(my + 1, n)
-    q, scale = _quantize(in_ref[...])
-    qstage_ref[...] = q
-    sstage_ref[0, 0] = scale
-    qrdma = pltpu.make_async_remote_copy(
-        src_ref=qstage_ref, dst_ref=qcomm_ref,
-        send_sem=qsend, recv_sem=qrecv,
-        device_id=right, device_id_type=pltpu.DeviceIdType.LOGICAL)
-    srdma = pltpu.make_async_remote_copy(
-        src_ref=sstage_ref, dst_ref=scomm_ref,
-        send_sem=ssend, recv_sem=srecv,
-        device_id=right, device_id_type=pltpu.DeviceIdType.LOGICAL)
-    qrdma.start()
-    srdma.start()
-    qrdma.wait()
-    srdma.wait()
-    out_ref[...] = qcomm_ref[...].astype(out_ref.dtype) * scomm_ref[0, 0]
-
-
-def _qhop_block(x, axis_name, n, interpret):
-    kernel = functools.partial(_qhop_kernel, n, axis_name)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        scratch_shapes=[
-            pltpu.VMEM(x.shape, jnp.int8),       # qstage
-            pltpu.VMEM((1, 1), jnp.float32),     # sstage
-            pltpu.VMEM(x.shape, jnp.int8),       # qcomm
-            pltpu.VMEM((1, 1), jnp.float32),     # scomm
-            pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA(()),
-        ],
-        interpret=interpret,
-        compiler_params=None if interpret else pltpu.TPUCompilerParams(
-            collective_id=5),
-    )(x)
-
-
-def _qrs_hop(block, t, n, axis_name, interpret):
-    """One host-level quantized reduce-scatter hop: same index schedule as
-    `ring._reduce_scatter_kernel` step `t`, with the wire leg replaced by
-    the fused quantize→DMA→dequantize kernel."""
-    my = lax.axis_index(axis_name)
-    chunk = block.shape[0] // n
-    send_idx = lax.rem(my - t - 1 + n, n)
-    recv_idx = lax.rem(my - t - 2 + 2 * n, n)
-    sent = lax.dynamic_slice(
-        block, (send_idx * chunk, 0), (chunk,) + block.shape[1:])
-    deq = _qhop_block(sent, axis_name, n, interpret)
-    cur = lax.dynamic_slice(
-        block, (recv_idx * chunk, 0), (chunk,) + block.shape[1:])
-    return lax.dynamic_update_slice(block, cur + deq, (recv_idx * chunk, 0))
+    return qsend
 
 
 def start_quantized_ring_reduce_scatter(x, axis_name: str, *, n: int,
@@ -199,10 +87,7 @@ def start_quantized_ring_reduce_scatter(x, axis_name: str, *, n: int,
     if op.lower() not in ("sum", "avg", "mean"):
         raise ValueError(
             f"quantized reduce-scatter supports sum/avg, got {op!r}")
-    if x.shape[0] % n:
-        raise ValueError(
-            f"reduce_scatter leading dim {x.shape[0]} not divisible by "
-            f"ring size {n}")
+    _check_divisible(x, n)
     impl = select_impl(impl)
     op = "avg" if op.lower() in ("avg", "mean") else "sum"
     wants_bf16 = (
@@ -217,16 +102,11 @@ def start_quantized_ring_reduce_scatter(x, axis_name: str, *, n: int,
         h.meta = ("bf16", x.dtype)
         h.buf = x.astype(jnp.bfloat16)
         return h
-    shard_shape = (x.shape[0] // n,) + x.shape[1:]
-    per_shard = _numel(shard_shape)
-    slabs = x.astype(jnp.float32).reshape(n, per_shard)
-    padded = ((per_shard + LANES - 1) // LANES) * LANES
-    if padded != per_shard:
-        slabs = jnp.pad(slabs, ((0, 0), (0, padded - per_shard)))
-    block = slabs.reshape(n * (padded // LANES), LANES)
-    interpret = impl == "pallas_interpret"
+    block, shard_shape, per_shard = _slabs_to_block(
+        x.astype(jnp.float32), n)
     h.meta = ("int8", x.dtype, shard_shape, per_shard)
-    h.buf = _qrs_hop(block, 0, n, axis_name, interpret)
+    h.buf = _rs_hop(block, 0, n, axis_name, _add,
+                    _quantized_sender(axis_name, n, impl))
     h.hops_done = 1
     return h
 
@@ -239,14 +119,11 @@ def wait_quantized_ring_reduce_scatter(h: SplitPhaseHandle):
         out = ring.ring_reduce_scatter(h.buf, axis_name, n=n, op=op,
                                        impl=h.impl)
         return out.astype(orig_dtype)
-    interpret = h.impl == "pallas_interpret"
+    qsend = _quantized_sender(axis_name, n, h.impl)
     block = h.buf
     for t in range(h.hops_done, n - 1):
-        block = _qrs_hop(block, t, n, axis_name, interpret)
-    my = lax.axis_index(axis_name)
-    chunk = block.shape[0] // n
-    mine = lax.dynamic_slice(
-        block, (my * chunk, 0), (chunk,) + block.shape[1:])
+        block = _rs_hop(block, t, n, axis_name, _add, qsend)
+    mine = _chunk(block, lax.axis_index(axis_name), n)
     _, orig_dtype, shard_shape, per_shard = h.meta
     result = mine.reshape(-1)[:per_shard].reshape(shard_shape)
     if op == "avg":
@@ -316,10 +193,13 @@ def quantized_ring_allreduce(x, axis_name: str, *, n: int, op: str = "sum",
     )
     if impl == "lax" or n == 1 or wants_bf16:
         return _bf16_fallback(x, axis_name, n, op, impl)
+    qsend = _quantized_sender(axis_name, n, impl)
     block, shape, size = _to_block(x.astype(jnp.float32), n)
-    out = _qar_block(block, axis_name, n,
-                     interpret=(impl == "pallas_interpret"))
-    result = _from_block(out, shape, size).astype(x.dtype)
+    for t in range(n - 1):  # reduce-scatter sweep over quantized partials
+        block = _rs_hop(block, t, n, axis_name, _add, qsend)
+    for t in range(n - 1):  # allgather sweep of the reduced chunks
+        block = _ag_hop(block, t, n, axis_name, qsend)
+    result = _from_block(block, shape, size).astype(x.dtype)
     if op.lower() in ("avg", "mean"):
         result = result / n
     return result

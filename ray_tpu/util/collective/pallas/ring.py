@@ -1,34 +1,44 @@
-"""Double-buffered ICI ring collectives as Pallas TPU kernels.
+"""ICI ring collectives built on one Pallas TPU remote-copy kernel.
 
-Each kernel runs per-device under `shard_map` over one mesh axis and moves
-data to its right neighbour with `pltpu.make_async_remote_copy` (the ICI
-RDMA primitive, SNIPPETS [1][2]).  Communication is double-buffered: step
-`t` lands in comm slot `t % 2` while the previous slot is still being
-consumed, and a reverse-direction capacity semaphore stops a fast sender
-from clobbering a slot its right neighbour has not drained yet (skew around
-a ring is bounded only by its circumference, so two slots alone are not a
-proof).  The capacity handshake uses `pltpu.semaphore_signal`, which the
-CPU interpreter does not model — interpret mode runs devices in lockstep,
-so the handshake is compiled out there (`interpret=True` ⇒ no remote
-regular-semaphore ops).
+Every collective here is a schedule of single ring hops.  A hop is one
+`pallas_call` (`_permute_block`) that runs per-device under `shard_map`
+over one mesh axis and moves a block to its right neighbour with
+`pltpu.make_async_remote_copy` (the ICI RDMA primitive, SNIPPETS [1][2]).
+Source and destination stay in HBM (`memory_space=pl.ANY`): the DMA
+engine copies HBM to remote HBM directly, so a hop uses no VMEM and its
+message size is bounded by HBM alone.  The reduction between hops is
+plain XLA (`dynamic_slice` + combine + `dynamic_update_slice`), which the
+TPU compiler runs at HBM bandwidth without any staging the kernel would
+have to size by hand.
 
-Layout contract: kernels see a 2-D `(rows, LANES)` f32/bf16/int block whose
-row count divides the ring size; the public wrappers flatten, pad and
-restore arbitrary pytree-leaf shapes around that.
+Hand-shake: before a device writes into its right neighbour's output
+buffer it waits, on the kernel's barrier semaphore, for that neighbour's
+"entered" signal (each device signals its LEFT neighbour once per call).
+Without it a fast sender could land data in memory the neighbour's
+previous program still owns.  The signal is one-directional on purpose:
+credits then come from exactly one device, in kernel order, so a
+neighbour that is already one call ahead cannot satisfy this call's wait.
+The exit condition (own send drained, own receive landed) needs no second
+barrier.  The old interpreter does not model barrier semaphores and runs
+devices in lockstep, so the hand-shake is compiled out under
+``interpret=True``.
+
+Layout contract: hops see a 2-D `(rows, LANES)` block; the public
+wrappers flatten, pad and restore arbitrary pytree-leaf shapes around
+that.
 """
 
 from __future__ import annotations
 
 import functools
 import os
-from typing import Any, Callable, Optional
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 # TPU vector lane count — the minor dim of every kernel block (pallas guide:
@@ -69,189 +79,61 @@ def select_impl(requested: str = "auto") -> str:
 
 
 # ---------------------------------------------------------------------------
-# Kernels.  Shared structure: a global step counter `t` indexes the comm
-# slot; `_send_recv` issues one RDMA hop to the right neighbour and blocks
-# until both the outgoing DMA drained and the incoming chunk (from the left
-# neighbour's symmetric send) landed.
+# The one kernel: a single ring hop, HBM to the right neighbour's HBM.
 # ---------------------------------------------------------------------------
 
-def _send_recv(src, dst, send_sems, recv_sems, slot, right):
+# Kernels that share a `collective_id` share one barrier semaphore.  Every
+# hop is the same kernel, issued in the same order on every device, so one
+# id serves them all (see the hand-shake argument in the module docstring).
+_COLLECTIVE_ID = 0
+
+
+def _permute_kernel(n, axis_name, interpret, in_ref, out_ref,
+                    send_sem, recv_sem):
+    """One ring hop: send the whole block to the right neighbour, return
+    what the left neighbour sent (the SNIPPETS [2] right-permute shape).
+    Neighbours are addressed by mesh coordinate along `axis_name`, so the
+    hop stays inside its own ring on a multi-axis mesh."""
+    my = lax.axis_index(axis_name)
+    right = lax.rem(my + 1, n)
+    if interpret:
+        # The interpreter discharges a remote copy over the one bound
+        # axis and takes the neighbour's index on it.
+        device_id, id_type = right, pltpu.DeviceIdType.LOGICAL
+    else:
+        device_id, id_type = {axis_name: right}, pltpu.DeviceIdType.MESH
+        left = lax.rem(my + n - 1, n)
+        barrier = pltpu.get_barrier_semaphore()
+        pltpu.semaphore_signal(barrier, inc=1, device_id={axis_name: left},
+                               device_id_type=pltpu.DeviceIdType.MESH)
+        pltpu.semaphore_wait(barrier, 1)
     rdma = pltpu.make_async_remote_copy(
-        src_ref=src,
-        dst_ref=dst,
-        send_sem=send_sems.at[slot],
-        recv_sem=recv_sems.at[slot],
-        device_id=right,
-        device_id_type=pltpu.DeviceIdType.LOGICAL,
+        src_ref=in_ref,
+        dst_ref=out_ref,
+        send_sem=send_sem,
+        recv_sem=recv_sem,
+        device_id=device_id,
+        device_id_type=id_type,
     )
     rdma.start()
     rdma.wait()
 
 
-def _cap_wait(cap_sems, slot, t, interpret):
-    # Slot reuse starts at t == 2; before sending, wait for the right
-    # neighbour's "drained" signal.  Not modelled by the interpreter.
-    if not interpret and t >= 2:
-        pltpu.semaphore_wait(cap_sems.at[slot], 1)
-
-
-def _cap_signal(cap_sems, slot, t, total, left, interpret):
-    # After consuming comm[slot], tell the left neighbour it may reuse it.
-    # The last two steps never get reused, so skip the dangling signals.
-    if not interpret and t < total - 2:
-        pltpu.semaphore_signal(
-            cap_sems.at[slot], inc=1, device_id=left,
-            device_id_type=pltpu.DeviceIdType.LOGICAL)
-
-
-def _allreduce_kernel(n, axis_name, op, interpret,
-                      in_ref, out_ref, comm_ref,
-                      send_sems, recv_sems, cap_sems):
-    """Ring allreduce = reduce-scatter sweep + allgather sweep (2(n-1) hops,
-    each moving 1/n of the block: bandwidth-optimal)."""
-    my = lax.axis_index(axis_name)
-    right = lax.rem(my + 1, n)
-    left = lax.rem(my + n - 1, n)
-    chunk = out_ref.shape[0] // n
-    combine = _COMBINE[op]
-    total = 2 * (n - 1)
-
-    out_ref[...] = in_ref[...]
-
-    t = 0
-    for s in range(n - 1):  # reduce-scatter sweep: accumulate partials
-        slot = t % 2
-        send_idx = lax.rem(my - s + n, n)
-        recv_idx = lax.rem(my - s - 1 + n, n)
-        _cap_wait(cap_sems, slot, t, interpret)
-        _send_recv(out_ref.at[pl.ds(send_idx * chunk, chunk)],
-                   comm_ref.at[slot], send_sems, recv_sems, slot, right)
-        out_ref[pl.ds(recv_idx * chunk, chunk)] = combine(
-            out_ref[pl.ds(recv_idx * chunk, chunk)], comm_ref[slot])
-        _cap_signal(cap_sems, slot, t, total, left, interpret)
-        t += 1
-
-    for s in range(n - 1):  # allgather sweep: circulate reduced chunks
-        slot = t % 2
-        send_idx = lax.rem(my - s + 1 + n, n)
-        recv_idx = lax.rem(my - s + n, n)
-        _cap_wait(cap_sems, slot, t, interpret)
-        _send_recv(out_ref.at[pl.ds(send_idx * chunk, chunk)],
-                   comm_ref.at[slot], send_sems, recv_sems, slot, right)
-        out_ref[pl.ds(recv_idx * chunk, chunk)] = comm_ref[slot]
-        _cap_signal(cap_sems, slot, t, total, left, interpret)
-        t += 1
-
-
-def _allgather_kernel(n, axis_name, interpret,
-                      in_ref, out_ref, comm_ref,
-                      send_sems, recv_sems, cap_sems):
-    """Ring allgather: each shard takes n-1 hops around the ring."""
-    my = lax.axis_index(axis_name)
-    right = lax.rem(my + 1, n)
-    left = lax.rem(my + n - 1, n)
-    rows = in_ref.shape[0]
-    total = n - 1
-
-    out_ref[pl.ds(my * rows, rows)] = in_ref[...]
-
-    for t in range(n - 1):
-        slot = t % 2
-        send_idx = lax.rem(my - t + n, n)
-        recv_idx = lax.rem(my - t - 1 + n, n)
-        _cap_wait(cap_sems, slot, t, interpret)
-        _send_recv(out_ref.at[pl.ds(send_idx * rows, rows)],
-                   comm_ref.at[slot], send_sems, recv_sems, slot, right)
-        out_ref[pl.ds(recv_idx * rows, rows)] = comm_ref[slot]
-        _cap_signal(cap_sems, slot, t, total, left, interpret)
-
-
-def _reduce_scatter_kernel(n, axis_name, op, interpret,
-                           in_ref, out_ref, acc_ref, comm_ref,
-                           send_sems, recv_sems, cap_sems):
-    """Ring reduce-scatter: after n-1 hops every device holds the fully
-    reduced chunk it owns (chunk `my`, matching `lax.psum_scatter`)."""
-    my = lax.axis_index(axis_name)
-    right = lax.rem(my + 1, n)
-    left = lax.rem(my + n - 1, n)
-    chunk = in_ref.shape[0] // n
-    combine = _COMBINE[op]
-    total = n - 1
-
-    acc_ref[...] = in_ref[...]
-
-    # Schedule shifted by -1 vs the allreduce sweep so the last chunk a
-    # device accumulates (the fully reduced one) is its *own* chunk `my`,
-    # matching `lax.psum_scatter` ownership.
-    for t in range(n - 1):
-        slot = t % 2
-        send_idx = lax.rem(my - t - 1 + n, n)
-        recv_idx = lax.rem(my - t - 2 + 2 * n, n)
-        _cap_wait(cap_sems, slot, t, interpret)
-        _send_recv(acc_ref.at[pl.ds(send_idx * chunk, chunk)],
-                   comm_ref.at[slot], send_sems, recv_sems, slot, right)
-        acc_ref[pl.ds(recv_idx * chunk, chunk)] = combine(
-            acc_ref[pl.ds(recv_idx * chunk, chunk)], comm_ref[slot])
-        _cap_signal(cap_sems, slot, t, total, left, interpret)
-
-    out_ref[...] = acc_ref[pl.ds(my * chunk, chunk)]
-
-
-# ---------------------------------------------------------------------------
-# pallas_call wrappers over canonical 2-D (rows, LANES) blocks.
-# ---------------------------------------------------------------------------
-
-def _sems(interpret):
-    return [
-        pltpu.SemaphoreType.DMA((2,)),
-        pltpu.SemaphoreType.DMA((2,)),
-        pltpu.SemaphoreType.REGULAR((2,)),
-    ]
-
-
-def _allreduce_block(x, axis_name, n, op, interpret):
-    chunk = x.shape[0] // n
-    kernel = functools.partial(_allreduce_kernel, n, axis_name, op,
-                               interpret)
+def _permute_block(x, axis_name, n, interpret):
+    kernel = functools.partial(_permute_kernel, n, axis_name, interpret)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        scratch_shapes=[pltpu.VMEM((2, chunk) + x.shape[1:], x.dtype)]
-        + _sems(interpret),
-        interpret=interpret,
-        compiler_params=None if interpret else pltpu.TPUCompilerParams(
-            collective_id=0),
-    )(x)
-
-
-def _allgather_block(x, axis_name, n, interpret):
-    rows = x.shape[0]
-    kernel = functools.partial(_allgather_kernel, n, axis_name, interpret)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((n * rows,) + x.shape[1:], x.dtype),
-        scratch_shapes=[pltpu.VMEM((2, rows) + x.shape[1:], x.dtype)]
-        + _sems(interpret),
-        interpret=interpret,
-        compiler_params=None if interpret else pltpu.TPUCompilerParams(
-            collective_id=1),
-    )(x)
-
-
-def _reduce_scatter_block(x, axis_name, n, op, interpret):
-    chunk = x.shape[0] // n
-    kernel = functools.partial(_reduce_scatter_kernel, n, axis_name, op,
-                               interpret)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((chunk,) + x.shape[1:], x.dtype),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
-            pltpu.VMEM(x.shape, x.dtype),
-            pltpu.VMEM((2, chunk) + x.shape[1:], x.dtype),
-        ] + _sems(interpret),
+            pltpu.SemaphoreType.DMA(()),
+            pltpu.SemaphoreType.DMA(()),
+        ],
         interpret=interpret,
-        compiler_params=None if interpret else pltpu.TPUCompilerParams(
-            collective_id=2),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            collective_id=_COLLECTIVE_ID),
+        name="ring_hop",
     )(x)
 
 
@@ -274,84 +156,19 @@ def _from_block(block, shape, size):
     return block.reshape(-1)[:size].reshape(shape)
 
 
-def _norm_op(op: str) -> str:
-    op = op.lower()
-    if op == "mean":
-        op = "avg"
-    if op not in ("sum", "avg", "max", "min", "prod"):
-        raise ValueError(f"unsupported reduce op {op!r}")
-    return op
-
-
-def ring_allreduce(x, axis_name: str, *, n: int, op: str = "sum",
-                   impl: str = "auto"):
-    """`lax.psum`-shaped allreduce over mesh axis `axis_name` (size `n`,
-    required statically for the ring schedule).  Call under `shard_map`."""
-    op = _norm_op(op)
-    impl = select_impl(impl)
-    if impl == "lax" or n == 1:
-        return _lax_allreduce(x, axis_name, op)
-    kernel_op = "sum" if op == "avg" else op
-    block, shape, size = _to_block(x, n)
-    out = _allreduce_block(block, axis_name, n, kernel_op,
-                           interpret=(impl == "pallas_interpret"))
-    out = _from_block(out, shape, size)
-    if op == "avg":
-        out = out / n
-    return out
-
-
-def ring_allgather(x, axis_name: str, *, n: int, impl: str = "auto"):
-    """`lax.all_gather`-shaped allgather: per-rank shards stacked along a
-    new leading axis of size `n`."""
-    impl = select_impl(impl)
-    if impl == "lax" or n == 1:
-        return lax.all_gather(x, axis_name, tiled=False)
-    block, shape, size = _to_block(x, 1)
-    out = _allgather_block(block, axis_name, n,
-                           interpret=(impl == "pallas_interpret"))
-    rows = block.shape[0]
-    pieces = [
-        _from_block(out[i * rows:(i + 1) * rows], shape, size)
-        for i in range(n)
-    ]
-    return jnp.stack(pieces, axis=0)
-
-
-def ring_reduce_scatter(x, axis_name: str, *, n: int, op: str = "sum",
-                        impl: str = "auto"):
-    """`lax.psum_scatter(..., tiled=True)`-shaped reduce-scatter along the
-    leading dim, which must be divisible by `n`: rank `i` gets the reduced
-    slab ``x[i*rows:(i+1)*rows]``."""
-    op = _norm_op(op)
-    if x.shape[0] % n:
-        raise ValueError(
-            f"reduce_scatter leading dim {x.shape[0]} not divisible by "
-            f"ring size {n}")
-    impl = select_impl(impl)
-    if impl == "lax" or n == 1:
-        out = lax.psum_scatter(x, axis_name, scatter_dimension=0,
-                               tiled=True)
-        if op == "avg":
-            out = out / n
-        return out
-    kernel_op = "sum" if op == "avg" else op
+def _slabs_to_block(x, n):
+    """Pad each of the `n` leading-dim slabs of `x` independently so ring
+    chunk `i` is exactly slab `i` (+ trailing zeros) — repacking across
+    slab boundaries would hand rank i the wrong elements.  Returns the
+    block and (shard_shape, per_shard) to undo it."""
     shard_shape = (x.shape[0] // n,) + x.shape[1:]
     per_shard = _numel(shard_shape)
-    # Pad each leading-dim slab independently so ring chunk `i` is exactly
-    # slab `i` (+ trailing zeros) — repacking across slab boundaries would
-    # hand rank i the wrong elements.
     slabs = x.reshape(n, per_shard)
     padded = ((per_shard + LANES - 1) // LANES) * LANES
     if padded != per_shard:
         slabs = jnp.pad(slabs, ((0, 0), (0, padded - per_shard)))
-    block = slabs.reshape(n * (padded // LANES), LANES)
-    out = _reduce_scatter_block(block, axis_name, n, kernel_op,
-                                interpret=(impl == "pallas_interpret"))
-    result = out.reshape(-1)[:per_shard].reshape(shard_shape)
-    if op == "avg":
-        result = result / n
-    return result
+    return slabs.reshape(n * (padded // LANES), LANES), shard_shape, \
+        per_shard
 
 
 def _numel(shape) -> int:
@@ -361,51 +178,34 @@ def _numel(shape) -> int:
     return size
 
 
+def _norm_op(op: str) -> str:
+    op = op.lower()
+    if op == "mean":
+        op = "avg"
+    if op not in ("sum", "avg", "max", "min", "prod"):
+        raise ValueError(f"unsupported reduce op {op!r}")
+    return op
+
+
+def _check_divisible(x, n):
+    if x.shape[0] % n:
+        raise ValueError(
+            f"reduce_scatter leading dim {x.shape[0]} not divisible by "
+            f"ring size {n}")
+
+
 # ---------------------------------------------------------------------------
-# Split-phase entry points: one ring hop per kernel call, so a collective
-# can be ISSUED early (``start_*``: places hop 0 in the graph depending
-# only on its payload) and AWAITED late (``wait_*``: runs the remaining
-# hops and materializes the result).  Compute traced between the two calls
-# has no data dependency on the in-flight hops, which is exactly the
-# freedom XLA's latency-hiding scheduler needs to run DMA under compute —
-# the monolithic kernels above are one opaque op and expose their whole
-# wire time.  Hop schedules mirror the monolithic kernels element-for-
-# element, so start+wait is numerically identical to the single call
-# (tier-1 asserts it).  Handles are trace-scoped Python objects, not
-# pytrees: start and wait must happen inside the same traced function.
+# Hop schedules.  A collective can be ISSUED early (``start_*``: places hop
+# 0 in the graph depending only on its payload) and AWAITED late
+# (``wait_*``: runs the remaining hops and materializes the result).
+# Compute traced between the two calls has no data dependency on the
+# in-flight hops, which is exactly the freedom XLA's latency-hiding
+# scheduler needs to run DMA under compute.  The monolithic entry points
+# below are start immediately followed by wait, so the two spellings are
+# the same hops in the same order and agree bit for bit (tier-1 asserts
+# it).  Handles are trace-scoped Python objects, not pytrees: start and
+# wait must happen inside the same traced function.
 # ---------------------------------------------------------------------------
-
-def _permute_kernel(n, axis_name, in_ref, out_ref, send_sem, recv_sem):
-    """One ring hop: send the whole block to the right neighbour, return
-    what the left neighbour sent (the SNIPPETS [2] right-permute shape)."""
-    my = lax.axis_index(axis_name)
-    right = lax.rem(my + 1, n)
-    rdma = pltpu.make_async_remote_copy(
-        src_ref=in_ref,
-        dst_ref=out_ref,
-        send_sem=send_sem,
-        recv_sem=recv_sem,
-        device_id=right,
-        device_id_type=pltpu.DeviceIdType.LOGICAL,
-    )
-    rdma.start()
-    rdma.wait()
-
-
-def _permute_block(x, axis_name, n, interpret):
-    kernel = functools.partial(_permute_kernel, n, axis_name)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        scratch_shapes=[
-            pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA(()),
-        ],
-        interpret=interpret,
-        compiler_params=None if interpret else pltpu.TPUCompilerParams(
-            collective_id=4),
-    )(x)
-
 
 class SplitPhaseHandle:
     """An in-flight split-phase ring collective.
@@ -431,22 +231,41 @@ class SplitPhaseHandle:
         self.meta = None
 
 
-def _rs_hop(block, t, n, axis_name, op, interpret):
-    """One host-level reduce-scatter hop: identical index schedule to
-    `_reduce_scatter_kernel` step `t`, so the float-add order (and hence
-    the bits) match the monolithic kernel."""
+def _chunk(block, idx, n):
+    rows = block.shape[0] // n
+    return lax.dynamic_slice(
+        block, (idx * rows, 0), (rows,) + block.shape[1:])
+
+
+def _rs_hop(block, t, n, axis_name, combine, send):
+    """Reduce-scatter hop `t`: chunk ``my-t-1`` goes right through
+    `send`, the chunk arriving from the left is combined into
+    ``my-t-2``.  The schedule ends with the fully reduced chunk `my` on
+    device `my`, matching `lax.psum_scatter` ownership."""
     my = lax.axis_index(axis_name)
-    chunk = block.shape[0] // n
-    combine = _COMBINE[op]
+    rows = block.shape[0] // n
     send_idx = lax.rem(my - t - 1 + n, n)
     recv_idx = lax.rem(my - t - 2 + 2 * n, n)
-    sent = lax.dynamic_slice(
-        block, (send_idx * chunk, 0), (chunk,) + block.shape[1:])
-    received = _permute_block(sent, axis_name, n, interpret)
-    cur = lax.dynamic_slice(
-        block, (recv_idx * chunk, 0), (chunk,) + block.shape[1:])
+    received = send(_chunk(block, send_idx, n))
     return lax.dynamic_update_slice(
-        block, combine(cur, received), (recv_idx * chunk, 0))
+        block, combine(_chunk(block, recv_idx, n), received),
+        (recv_idx * rows, 0))
+
+
+def _ag_hop(out, t, n, axis_name, send):
+    """Allgather hop `t`: chunk ``my-t`` goes right, the arriving chunk
+    lands at ``my-t-1``."""
+    my = lax.axis_index(axis_name)
+    rows = out.shape[0] // n
+    send_idx = lax.rem(my - t + n, n)
+    recv_idx = lax.rem(my - t - 1 + n, n)
+    received = send(_chunk(out, send_idx, n))
+    return lax.dynamic_update_slice(out, received, (recv_idx * rows, 0))
+
+
+def _sender(axis_name, n, impl):
+    return functools.partial(_permute_block, axis_name=axis_name, n=n,
+                             interpret=(impl == "pallas_interpret"))
 
 
 def start_ring_reduce_scatter(x, axis_name: str, *, n: int,
@@ -456,26 +275,17 @@ def start_ring_reduce_scatter(x, axis_name: str, *, n: int,
     leading dim divisible by `n`, rank `i` receives slab `i`).  Hop 0 is
     placed in the graph now; the rest run at `wait_ring_reduce_scatter`."""
     op = _norm_op(op)
-    if x.shape[0] % n:
-        raise ValueError(
-            f"reduce_scatter leading dim {x.shape[0]} not divisible by "
-            f"ring size {n}")
+    _check_divisible(x, n)
     impl = select_impl(impl)
     h = SplitPhaseHandle("reduce_scatter", axis_name, n, op, impl)
     if impl == "lax" or n == 1:
         h.buf = x
         return h
-    shard_shape = (x.shape[0] // n,) + x.shape[1:]
-    per_shard = _numel(shard_shape)
-    slabs = x.reshape(n, per_shard)
-    padded = ((per_shard + LANES - 1) // LANES) * LANES
-    if padded != per_shard:
-        slabs = jnp.pad(slabs, ((0, 0), (0, padded - per_shard)))
-    block = slabs.reshape(n * (padded // LANES), LANES)
-    interpret = impl == "pallas_interpret"
-    kernel_op = "sum" if op == "avg" else op
+    block, shard_shape, per_shard = _slabs_to_block(x, n)
     h.meta = (shard_shape, per_shard)
-    h.buf = _rs_hop(block, 0, n, axis_name, kernel_op, interpret)
+    h.buf = _rs_hop(block, 0, n, axis_name,
+                    _COMBINE["sum" if op == "avg" else op],
+                    _sender(axis_name, n, impl))
     h.hops_done = 1
     return h
 
@@ -490,32 +300,17 @@ def wait_ring_reduce_scatter(h: SplitPhaseHandle):
         if op == "avg":
             out = out / n
         return out
-    interpret = h.impl == "pallas_interpret"
-    kernel_op = "sum" if op == "avg" else op
+    combine = _COMBINE["sum" if op == "avg" else op]
+    send = _sender(axis_name, n, h.impl)
     block = h.buf
     for t in range(h.hops_done, n - 1):
-        block = _rs_hop(block, t, n, axis_name, kernel_op, interpret)
-    my = lax.axis_index(axis_name)
-    chunk = block.shape[0] // n
-    mine = lax.dynamic_slice(
-        block, (my * chunk, 0), (chunk,) + block.shape[1:])
+        block = _rs_hop(block, t, n, axis_name, combine, send)
+    mine = _chunk(block, lax.axis_index(axis_name), n)
     shard_shape, per_shard = h.meta
     result = mine.reshape(-1)[:per_shard].reshape(shard_shape)
     if op == "avg":
         result = result / n
     return result
-
-
-def _ag_hop(out, t, n, axis_name, interpret):
-    """One host-level allgather hop mirroring `_allgather_kernel` step `t`."""
-    my = lax.axis_index(axis_name)
-    rows = out.shape[0] // n
-    send_idx = lax.rem(my - t + n, n)
-    recv_idx = lax.rem(my - t - 1 + n, n)
-    sent = lax.dynamic_slice(
-        out, (send_idx * rows, 0), (rows,) + out.shape[1:])
-    received = _permute_block(sent, axis_name, n, interpret)
-    return lax.dynamic_update_slice(out, received, (recv_idx * rows, 0))
 
 
 def start_ring_allgather(x, axis_name: str, *, n: int,
@@ -529,12 +324,11 @@ def start_ring_allgather(x, axis_name: str, *, n: int,
         return h
     block, shape, size = _to_block(x, 1)
     rows = block.shape[0]
-    interpret = impl == "pallas_interpret"
     my = lax.axis_index(axis_name)
     out = jnp.zeros((n * rows,) + block.shape[1:], block.dtype)
     out = lax.dynamic_update_slice(out, block, (my * rows, 0))
     h.meta = (shape, size, rows)
-    h.buf = _ag_hop(out, 0, n, axis_name, interpret)
+    h.buf = _ag_hop(out, 0, n, axis_name, _sender(axis_name, n, impl))
     h.hops_done = 1
     return h
 
@@ -544,10 +338,10 @@ def wait_ring_allgather(h: SplitPhaseHandle):
     n, axis_name = h.n, h.axis_name
     if h.impl == "lax" or n == 1:
         return lax.all_gather(h.buf, axis_name, tiled=False)
-    interpret = h.impl == "pallas_interpret"
+    send = _sender(axis_name, n, h.impl)
     out = h.buf
     for t in range(h.hops_done, n - 1):
-        out = _ag_hop(out, t, n, axis_name, interpret)
+        out = _ag_hop(out, t, n, axis_name, send)
     shape, size, rows = h.meta
     pieces = [
         _from_block(out[i * rows:(i + 1) * rows], shape, size)
@@ -567,7 +361,6 @@ def start_ring_permute(x, axis_name: str, *, n: int,
     if n == 1:
         h.buf = x
         h.impl = "lax"  # identity; wait returns buf as-is
-        h.meta = None
         return h
     if impl == "lax":
         perm = [(i, (i + 1) % n) for i in range(n)]
@@ -575,8 +368,7 @@ def start_ring_permute(x, axis_name: str, *, n: int,
         return h
     block, shape, size = _to_block(x, 1)
     h.meta = (shape, size)
-    h.buf = _permute_block(block, axis_name, n,
-                           interpret=(impl == "pallas_interpret"))
+    h.buf = _sender(axis_name, n, impl)(block)
     return h
 
 
@@ -586,6 +378,49 @@ def wait_ring_permute(h: SplitPhaseHandle):
         return h.buf
     shape, size = h.meta
     return _from_block(h.buf, shape, size)
+
+
+# ---------------------------------------------------------------------------
+# Monolithic entry points: the same hops, issued and awaited in one call.
+# ---------------------------------------------------------------------------
+
+def ring_reduce_scatter(x, axis_name: str, *, n: int, op: str = "sum",
+                        impl: str = "auto"):
+    """`lax.psum_scatter(..., tiled=True)`-shaped reduce-scatter along the
+    leading dim, which must be divisible by `n`: rank `i` gets the reduced
+    slab ``x[i*rows:(i+1)*rows]``."""
+    return wait_ring_reduce_scatter(
+        start_ring_reduce_scatter(x, axis_name, n=n, op=op, impl=impl))
+
+
+def ring_allgather(x, axis_name: str, *, n: int, impl: str = "auto"):
+    """`lax.all_gather`-shaped allgather: per-rank shards stacked along a
+    new leading axis of size `n`."""
+    return wait_ring_allgather(
+        start_ring_allgather(x, axis_name, n=n, impl=impl))
+
+
+def ring_allreduce(x, axis_name: str, *, n: int, op: str = "sum",
+                   impl: str = "auto"):
+    """`lax.psum`-shaped allreduce over mesh axis `axis_name` (size `n`,
+    required statically for the ring schedule).  Call under `shard_map`.
+    Reduce-scatter sweep + allgather sweep over one padded block: 2(n-1)
+    hops, each moving 1/n of it (bandwidth-optimal)."""
+    op = _norm_op(op)
+    impl = select_impl(impl)
+    if impl == "lax" or n == 1:
+        return _lax_allreduce(x, axis_name, op)
+    combine = _COMBINE["sum" if op == "avg" else op]
+    send = _sender(axis_name, n, impl)
+    block, shape, size = _to_block(x, n)
+    for t in range(n - 1):
+        block = _rs_hop(block, t, n, axis_name, combine, send)
+    for t in range(n - 1):
+        block = _ag_hop(block, t, n, axis_name, send)
+    out = _from_block(block, shape, size)
+    if op == "avg":
+        out = out / n
+    return out
 
 
 def _lax_allreduce(x, axis_name, op):
@@ -609,8 +444,8 @@ def _lax_allreduce(x, axis_name, op):
 def shard_map_collective(fn: Callable[..., Any], mesh: Mesh,
                          axis_name: str) -> Callable[..., Any]:
     """Wrap a per-shard collective `fn(x)` for global arrays sharded over
-    `axis_name` (jit + shard_map with replication checks off, since Pallas
-    kernels are opaque to the rep checker)."""
+    `axis_name` (jit + shard_map with the varying-axes check off, since
+    Pallas kernels are opaque to it)."""
     return jax.jit(shard_map(
         fn, mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
-        check_rep=False))
+        check_vma=False))

@@ -1,0 +1,263 @@
+"""Deviceless v5e compiles of the main path's programs, at real widths.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (on-chip-measurement guide §2, step 3). These
+tests hand the jitted functions `ShapeDtypeStruct`s placed on described
+v5e devices, so the compiler refuses here — at no chip time — what it
+would refuse on the machine: an API the installed JAX dropped, a kernel
+that cannot be lowered or partitioned, more VMEM than a kernel may use, a
+program that does not fit HBM. Nothing runs: a compile that passes says
+nothing about results or speed.
+
+Rules this file keeps (the guide explains each): the topology is described
+only inside the module-scoped, non-autouse fixture below — never at
+import, in a `skipif` or in `parametrize` — because only one process may
+load the TPU library; every compile happens in the test's own process;
+the persistent compilation cache is off around them (a deviceless
+executable can be written to it but not read back); code that asks
+`jax.default_backend()` is steered from the test (`monkeypatch`), not
+through an option of the program; all of it lives in this one file.
+"""
+
+import functools
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax import shard_map
+from jax.sharding import (
+    Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding,
+)
+
+GIB = 2 ** 30
+V5E_HBM_GIB = 15.75     # what the v5e compiler itself reports as capacity
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to test with
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def ring_mesh(topo):
+    return Mesh(np.asarray(topo.devices).reshape(4), ("x",))
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """`ops.attention` picks kernel-vs-XLA and compiled-vs-interpret from
+    the default backend, which is the CPU here: answer for the chip."""
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+
+
+def _placed(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _hbm_gib(compiled) -> float:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes) / GIB
+
+
+# ------------------------------------------------------------ flash attention
+
+# (batch, seq, kv heads): the bench shape, and the smoke's train shape with
+# Llama-3's 8 KV heads widened to 32 the way models/llama.py::_layer does.
+FLASH_SHAPES = {"B16_S1024_H32": (16, 1024, 32), "B2_S1024_kv8": (2, 1024, 8)}
+
+
+def _flash(q, k, v):
+    from ray_tpu.models.llama import _repeat_kv
+    from ray_tpu.ops.attention import flash_attention
+
+    rep = q.shape[2] // k.shape[2]
+    return flash_attention(q, _repeat_kv(k, rep), _repeat_kv(v, rep),
+                           causal=True)
+
+
+@pytest.mark.parametrize("shape", sorted(FLASH_SHAPES))
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_flash_attention_compiles_for_v5e(one_chip, on_tpu, shape,
+                                          direction):
+    B, S, kv = FLASH_SHAPES[shape]
+    q = jax.ShapeDtypeStruct((B, S, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((B, S, kv, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    fn = _flash if direction == "forward" else jax.grad(
+        lambda q, k, v: _flash(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(q, k, k).compile().as_text()
+    # forward is one kernel; backward re-runs it and adds dk/dv and dq
+    assert text.count("tpu_custom_call") >= (
+        1 if direction == "forward" else 3)
+
+
+# ------------------------------------------------------------ ring collectives
+
+def _ring_fns(n):
+    from ray_tpu.util.collective import pallas as rk
+
+    kw = dict(n=n, impl="pallas")
+    return {
+        "allreduce": (lambda x: rk.ring_allreduce(x, "x", **kw), P("x")),
+        "reduce_scatter": (
+            lambda x: rk.ring_reduce_scatter(x, "x", **kw), P("x")),
+        "allgather": (
+            lambda x: rk.ring_allgather(x, "x", **kw), P(None, "x")),
+        "permute": (
+            lambda x: rk.wait_ring_permute(
+                rk.start_ring_permute(x, "x", **kw)), P("x")),
+        "quantized_allreduce": (
+            lambda x: rk.quantized_ring_allreduce(x, "x", **kw), P("x")),
+        "quantized_reduce_scatter": (
+            lambda x: rk.wait_quantized_ring_reduce_scatter(
+                rk.start_quantized_ring_reduce_scatter(x, "x", **kw)),
+            P("x")),
+    }
+
+
+# hops a collective takes on a ring of 4 (each is one `ring_hop` kernel)
+RING_HOPS = {"allreduce": 6, "reduce_scatter": 3, "allgather": 3,
+             "permute": 1, "quantized_allreduce": 6,
+             "quantized_reduce_scatter": 3}
+
+
+@pytest.mark.parametrize("mib_per_shard", [1, 64])
+@pytest.mark.parametrize("kind", sorted(RING_HOPS))
+def test_ring_collective_compiles_for_v5e(ring_mesh, kind, mib_per_shard):
+    """1 MiB and a gradient-sized 64 MiB per shard: the hop kernel keeps
+    its operands in HBM, so no message size can exhaust VMEM."""
+    n = 4
+    fn, out_spec = _ring_fns(n)[kind]
+    rows = mib_per_shard * 2 ** 20 // 4 // 128
+    x = jax.ShapeDtypeStruct(
+        (n * rows, 128), jnp.float32,
+        sharding=NamedSharding(ring_mesh, P("x")))
+    compiled = jax.jit(shard_map(
+        fn, mesh=ring_mesh, in_specs=P("x"), out_specs=out_spec,
+        check_vma=False)).lower(x).compile()
+    assert compiled.as_text().count("tpu_custom_call") == RING_HOPS[kind]
+
+
+def test_ring_hop_on_one_axis_of_a_multi_axis_mesh(topo):
+    """ZeRO's ring runs over `data` inside data x tensor: neighbours are
+    addressed by mesh coordinate, which only the real lowering checks."""
+    from ray_tpu.util.collective import pallas as rk
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "tensor"))
+    x = jax.ShapeDtypeStruct((2 * 2048, 128), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("data")))
+    compiled = jax.jit(shard_map(
+        lambda a: rk.ring_reduce_scatter(a, "data", n=2, impl="pallas"),
+        mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+        check_vma=False)).lower(x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ring_attention_pallas_permute_compiles_for_v5e(topo):
+    from ray_tpu.ops.ring_attention import ring_attention_global
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("sp",))
+    q = jax.ShapeDtypeStruct(
+        (2, 4 * 1024, 8, 128), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(None, "sp", None, None)))
+    compiled = jax.jit(functools.partial(
+        ring_attention_global, mesh=mesh, impl="pallas")
+    ).lower(q, q, q).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ------------------------------------------------ the smoke's whole programs
+
+def _smoke_config(phase):
+    import chip_smoke
+
+    return chip_smoke._model_config(chip_smoke.chip_spec(0)[phase]["model"])
+
+
+def test_paged_decode_program_fits_one_v5e(one_chip):
+    """The engine's decode program at chip_smoke.py's serve widths, depth
+    and pool: compiles, and weights + pool + the program's own temporaries
+    (a second pool: the layer scan writes a fresh stacked one) fit HBM."""
+    import chip_smoke
+    from ray_tpu.models import llama
+
+    engine = chip_smoke.chip_spec(0)["serve"]["engine"]
+    config = _smoke_config("serve")
+    B, bs = engine["num_slots"], engine["kv_block_size"]
+    params = _placed(jax.eval_shape(
+        lambda: llama.init_params(config, jax.random.key(0))), one_chip)
+    pools = _placed(jax.eval_shape(lambda: llama.init_paged_kv_cache(
+        config, engine["num_kv_blocks"], bs)), one_chip)
+    ints = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                             sharding=one_chip)
+    compiled = jax.jit(
+        lambda p, pools, tables, tok, pos, active: llama.decode_step_paged(
+            p, pools, tables, tok, pos, config, active),
+        donate_argnums=(1,),
+    ).lower(params, pools, ints((B, engine["max_seq_len"] // bs)),
+            ints((B,)), ints((B,)),
+            jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
+            ).compile()
+    assert _hbm_gib(compiled) < V5E_HBM_GIB - 0.5
+
+
+def test_train_step_holds_flash_kernel_and_fits_one_v5e(topo, on_tpu):
+    """One whole `build_train_step` program at chip_smoke.py's train
+    widths, depth and batch, on a one-device mesh."""
+    import optax
+
+    import chip_smoke
+    from ray_tpu.models.llama import init_params, loss_fn
+    from ray_tpu.parallel import (
+        batch_sharding, build_train_step, create_train_state,
+        llama_param_shardings,
+    )
+
+    train = chip_smoke.chip_spec(0)["train"]
+    config = _smoke_config("train")
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    optimizer = optax.adamw(1e-3)
+    params_shape = jax.eval_shape(
+        lambda: init_params(config, jax.random.key(0)))
+    step = build_train_step(
+        lambda p, b: loss_fn(p, b, config), optimizer, mesh,
+        llama_param_shardings(config, mesh), batch_sharding(mesh),
+        params_shape=params_shape)
+    state = _placed(jax.eval_shape(
+        lambda p: create_train_state(p, optimizer), params_shape),
+        NamedSharding(mesh, P()))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (train["batch_size"], train["seq_len"]), jnp.int32,
+        sharding=batch_sharding(mesh))}
+    compiled = step.lower(state, batch).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert _hbm_gib(compiled) < V5E_HBM_GIB - 0.5

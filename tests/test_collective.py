@@ -141,7 +141,7 @@ class TestXLABackend:
             def jit_psum(self, group_name):
                 import jax
                 import jax.numpy as jnp
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
                 from jax.sharding import NamedSharding, PartitionSpec as P
 
                 from ray_tpu.util import collective as col

@@ -112,7 +112,7 @@ def test_union_and_zip(ray_start_regular):
 def test_row_ops_honor_resource_options(ray_start_regular):
     """map/filter/flat_map honor concurrency/num_cpus by routing through
     the distributed map_batches machinery, and RAISE on unknown kwargs —
-    the old **_ignored silently ran serial (VERDICT r4 weak-5)."""
+    the old **_ignored silently ran serial."""
     import os
 
     out = rdata.range(16, override_num_blocks=4).map(

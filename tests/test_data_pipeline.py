@@ -1,6 +1,6 @@
 """Concurrent operator execution + new training-ingest sources.
 
-Covers VERDICT round-3 item 6: stage-2 tasks running while stage-1 still
+Covers an early review finding: stage-2 tasks running while stage-1 still
 produces (concurrent scheduler), per-op budgets/backpressure plumbing,
 and TFRecord / WebDataset ingest."""
 

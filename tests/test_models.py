@@ -147,9 +147,9 @@ class TestMakeMeshErrors:
 
 class TestMultiHostDiscovery:
     """discover_devices joins jax.distributed exactly once, and only
-    when coordinator env vars mark a multi-host launch (MULTICHIP_r05:
-    make_mesh saw 1 local device and rejected fsdp=4 because the global
-    list is only visible after the join)."""
+    when coordinator env vars mark a multi-host launch (seen in the
+    field: make_mesh saw 1 local device and rejected fsdp=4 because the
+    global list is only visible after the join)."""
 
     def _reset(self, monkeypatch):
         from ray_tpu.parallel import mesh as mesh_mod
@@ -161,6 +161,9 @@ class TestMultiHostDiscovery:
 
     def test_single_host_never_initializes(self, monkeypatch):
         mesh_mod = self._reset(monkeypatch)
+        # A single-host TPU VM sets this too: it names no coordinator,
+        # and an argument-less join there would go looking for one.
+        monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
         calls = []
         monkeypatch.setattr(jax.distributed, "initialize",
                             lambda *a, **k: calls.append(1))
@@ -177,7 +180,9 @@ class TestMultiHostDiscovery:
         mesh_mod.discover_devices()          # once-guard
         assert len(calls) == 1
 
-    def test_failed_join_falls_back_to_local(self, monkeypatch):
+    def test_failed_join_raises(self, monkeypatch):
+        """A join that was asked for and failed must not leave a mesh
+        quietly built from this process's devices alone."""
         mesh_mod = self._reset(monkeypatch)
         monkeypatch.setenv("COORDINATOR_ADDRESS", "10.0.0.1:8476")
 
@@ -185,8 +190,8 @@ class TestMultiHostDiscovery:
             raise RuntimeError("unreachable coordinator")
 
         monkeypatch.setattr(jax.distributed, "initialize", boom)
-        assert len(mesh_mod.discover_devices()) == 8
-        assert make_mesh({"data": -1}).devices.size == 8
+        with pytest.raises(RuntimeError, match="unreachable coordinator"):
+            mesh_mod.discover_devices()
 
     def test_make_mesh_uses_global_discovery(self, monkeypatch):
         """The multi-axis request that failed in the field must work

@@ -93,8 +93,9 @@ def test_node_store_spills_pinned_under_pressure(tmp_path):
                if e.spilled_path is not None]
     assert spilled
     victim = spilled[0].object_id
-    path, size, offset = asyncio.get_event_loop().run_until_complete(
-        store.get(victim, timeout=5))
+    # asyncio.run, not get_event_loop(): an earlier test in the same
+    # xdist worker may have closed the thread's default loop.
+    path, size, offset = asyncio.run(store.get(victim, timeout=5))
     assert size == 60 * 1024
     assert store.num_restores >= 1
     store.cleanup()

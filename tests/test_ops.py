@@ -2,7 +2,7 @@
 
 The public ops fall back to XLA off-TPU, so these tests force the pallas
 kernel bodies through `pl.pallas_call(..., interpret=True)` and check values
-AND gradients against the reference `xla_attention`.  (VERDICT round 1: the
+AND gradients against the reference `xla_attention`.  (An early review: the
 hand-written backward had never executed before the bench.)
 """
 

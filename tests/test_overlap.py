@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import optax
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -53,7 +53,7 @@ class TestSplitPhaseParity:
 
     def _run(self, fn, x, out_specs=P("data")):
         g = jax.jit(shard_map(fn, mesh=_mesh(self.N), in_specs=P(),
-                              out_specs=out_specs, check_rep=False))
+                              out_specs=out_specs, check_vma=False))
         return np.asarray(g(x))
 
     def test_reduce_scatter_bitwise(self):

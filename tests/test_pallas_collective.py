@@ -13,7 +13,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -32,7 +32,7 @@ def _mesh(n=N) -> Mesh:
 
 def _run(fn, x, n=N, out_specs=P("x")):
     g = jax.jit(shard_map(fn, mesh=_mesh(n), in_specs=P("x"),
-                          out_specs=out_specs, check_rep=False))
+                          out_specs=out_specs, check_vma=False))
     return np.asarray(g(x))
 
 
@@ -134,10 +134,20 @@ class TestBackendFallback:
 
 
 class TestZeroShardedUpdate:
-    def test_bitwise_parity_vs_replicated_adam(self):
+    def test_parity_vs_replicated_adam_one_ulp_per_step(self):
         """reduce-scatter grads -> shard-local Adam -> allgather params
-        must be BITWISE identical to allreduce grads -> replicated Adam
-        on a 2-way mesh (one commutative float add per element)."""
+        against allreduce grads -> replicated Adam on a 2-way mesh.
+
+        The gradient exchange is one commutative float add per element,
+        so it is bitwise; step 0 (zero moments, nothing to contract)
+        must therefore match bit for bit.  From step 1 on the moment
+        update ``b*m + (1-b)*g`` is an FMA candidate, and XLA contracts
+        it differently in the flat 128-lane shard program and in the
+        (13, 7)-shaped reference (with FMA disabled via
+        ``--xla_cpu_max_isa=SSE4_2`` the runs are bitwise equal).  One
+        differently rounded product moves an update far below half a
+        parameter ulp, so a parameter can flip by at most one ulp per
+        such step: the bound asserted is steps-1 ulp."""
         import optax
 
         from ray_tpu.parallel.zero import (
@@ -164,8 +174,12 @@ class TestZeroShardedUpdate:
         state = create_zero_state(params0, opt, mesh, "data")
         step = build_zero_train_step(loss_fn, opt, mesh, "data",
                                      collective=IMPL)
-        for _ in range(3):
+        steps = 3
+        zero_params = []
+        for _ in range(steps):
             state, metrics = step(state, batch)
+            zero_params.append(
+                jax.tree.map(lambda x: np.array(x), state.params))
 
         opt_shape = jax.eval_shape(lambda p: opt.init(p), params)
 
@@ -180,14 +194,17 @@ class TestZeroShardedUpdate:
             in_specs=(P(), jax.tree.map(lambda _: P(), opt_shape),
                       {"x": P("data"), "y": P("data")}),
             out_specs=(P(), jax.tree.map(lambda _: P(), opt_shape), P()),
-            check_rep=False))
+            check_vma=False))
         rp, ro = params, opt.init(params)
-        for _ in range(3):
+        for i in range(steps):
             rp, ro, _ = ref_jit(rp, ro, batch)
-
-        for k in params:
-            np.testing.assert_array_equal(np.asarray(state.params[k]),
-                                          np.asarray(rp[k]))
+            for k in params:
+                if i == 0:
+                    np.testing.assert_array_equal(zero_params[i][k],
+                                                  np.asarray(rp[k]))
+                else:
+                    np.testing.assert_array_max_ulp(
+                        zero_params[i][k], np.asarray(rp[k]), maxulp=i)
         assert np.isfinite(float(metrics["loss"]))
 
     def test_weight_update_knob_validated(self):

@@ -120,7 +120,7 @@ def test_learner_group_update_improves_loss(ray_start_regular, num_learners):
 
 
 def test_ppo_cartpole_reaches_target(ray_start_regular):
-    """PPO solves CartPole-v1: mean episode return >= 475 (VERDICT #6)."""
+    """PPO solves CartPole-v1: mean episode return >= 475."""
     config = (
         PPOConfig()
         .environment("CartPole-v1")

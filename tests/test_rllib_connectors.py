@@ -133,8 +133,7 @@ def test_pipeline_composition_and_state_roundtrip():
 def test_ppo_learns_through_three_stage_pipeline(conn_cluster):
     """PPO CartPole through ObsNormalizer -> FrameStack(2) -> ClipObs:
     the module's input is the WIDENED, normalized view, preprocessing is
-    pipeline config (no runner edits), and learning still works
-    (VERDICT r4 next-4)."""
+    pipeline config (no runner edits), and learning still works."""
     from ray_tpu.rllib.algorithms.ppo import PPOConfig
 
     config = (

@@ -302,8 +302,9 @@ def test_paged_pool_exhaustion_queues_not_crash():
 
 
 def test_llm_server_quantize_default_and_optout():
-    """The serve config defaults to weight-only int8 decode (BENCH_r05:
-    1.28x decode throughput); "bf16" opts out; anything else is
+    """The serve config defaults to weight-only int8 decode (1.28x decode
+    throughput, measured before this round on an installation that no
+    longer exists); "bf16" opts out; anything else is
     rejected before weights load."""
     from ray_tpu.serve.llm.deployment import LLMServer
 
@@ -368,7 +369,8 @@ def test_routed_llm_two_replicas_smoke(ray_start_regular):
             model_config=config,
             engine_config={"num_slots": 2, "max_seq_len": 64,
                            "prefill_buckets": (8, 16)},
-            num_replicas=2, quantize="bf16", max_ongoing_requests=8,
+            num_replicas=2, num_tpus=0, quantize="bf16",
+            max_ongoing_requests=8,
             probe_interval_s=0.1), name="llm-routed")
         rng = np.random.RandomState(4)       # same trace as the plain
         prompts = [rng.randint(0, config.vocab_size,  # smoke: refs cached
@@ -401,7 +403,8 @@ def test_serve_llm_deployment_smoke(ray_start_regular):
             model_config=config,
             engine_config={"num_slots": 4, "max_seq_len": 64,
                            "prefill_buckets": (8, 16)},
-            init_seed=0, quantize="bf16", max_ongoing_requests=8),
+            num_tpus=0, init_seed=0, quantize="bf16",
+            max_ongoing_requests=8),
             name="llm")
         rng = np.random.RandomState(4)
         prompts = [rng.randint(0, config.vocab_size,

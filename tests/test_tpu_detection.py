@@ -2,8 +2,7 @@
 helper (reference: `python/ray/tests/accelerators/test_tpu.py:14-264`).
 
 Each detection tier is exercised by mocking its probe surface: env fakes,
-/dev/accel* and vfio globs, an already-initialized jax, and the GCE
-metadata server — no TPU (or network) required."""
+/dev/accel* and vfio globs, and the GCE metadata server — no TPU (or network) required."""
 
 import sys
 import types
@@ -63,15 +62,18 @@ def test_chip_count_vfio(monkeypatch):
 
 
 def test_chip_count_jax_enumeration(monkeypatch):
+    """Node detection never asks JAX: a device query opens the TPU
+    backend, and the asking process (driver, raylet) would then hold
+    the chip a leased worker needs. Even an imported jax that reports
+    TPU devices is left alone."""
     _mock_globs(monkeypatch)
 
-    class Dev:
-        platform = "tpu"
-        device_kind = "TPU v5 lite"
+    def devices():
+        raise AssertionError("node detection must not query jax devices")
 
-    fake_jax = types.SimpleNamespace(devices=lambda: [Dev(), Dev()])
+    fake_jax = types.SimpleNamespace(devices=devices)
     monkeypatch.setitem(sys.modules, "jax", fake_jax)
-    assert TPUAcceleratorManager.get_current_node_num_accelerators() == 2
+    assert TPUAcceleratorManager.get_current_node_num_accelerators() == 0
 
 
 def test_chip_count_nothing_found(monkeypatch):

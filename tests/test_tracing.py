@@ -364,7 +364,8 @@ def test_routed_llm_tracing_e2e(traced_cluster):
             model_config=config,
             engine_config={"num_slots": 2, "max_seq_len": 64,
                            "prefill_buckets": (8, 16)},
-            num_replicas=2, quantize="bf16", max_ongoing_requests=8,
+            num_replicas=2, num_tpus=0, quantize="bf16",
+            max_ongoing_requests=8,
             probe_interval_s=0.1), name="llm-traced")
         rng = np.random.RandomState(7)
         prompts = [rng.randint(0, config.vocab_size,

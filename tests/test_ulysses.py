@@ -76,7 +76,7 @@ def test_ulysses_gqa_and_grads():
 
 
 def test_ulysses_head_divisibility_enforced():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _mesh()
@@ -87,7 +87,7 @@ def test_ulysses_head_divisibility_enforced():
         shard_map(lambda a, b, c: ulysses_attention(a, b, c,
                                                     axis_name="sp"),
                   mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
-                  check_rep=False)(q, q, q)
+                  check_vma=False)(q, q, q)
 
 
 def test_unbound_axis_falls_back_exact():

@@ -39,9 +39,10 @@ class TestChipSpec:
         assert chipspec.lookup("TPU v5 lite").spec == "v5e"
         assert chipspec.lookup("TPU v5e").spec == "v5e"
         assert chipspec.lookup("TPU v5p").spec == "v5p"
-        # Bare "v5" is what some v5p hosts report; the v5e patterns
-        # must win before it.
-        assert chipspec.lookup("TPU v5").spec == "v5p"
+        # A bare "v5" names no generation: guessing v5p peaks for it
+        # would fabricate every ratio derived from them.
+        with pytest.raises(chipspec.UnknownChipError):
+            chipspec.lookup("TPU v5")
         assert chipspec.lookup("TPU v4").spec == "v4"
         v5e = chipspec.lookup("TPU v5 lite")
         assert v5e.peak_flops == pytest.approx(197e12)
@@ -57,11 +58,15 @@ class TestChipSpec:
         # to the nominal cpu row, never to unknown.
         assert chipspec.local_spec().measurement == "cpu"
 
-    def test_unknown_degrades_without_fabricating_peaks(self):
+    def test_unknown_kind_is_an_error_not_a_default(self):
         from ray_tpu.observability import chipspec
 
-        spec = chipspec.lookup("Gaudi 3")
-        assert spec.spec == "unknown" and not spec.known
+        with pytest.raises(chipspec.UnknownChipError, match="Gaudi 3"):
+            chipspec.lookup("Gaudi 3")
+        # "No kind to name" (no device, a mixed mesh) is not a guess:
+        # it resolves to the peak-less UNKNOWN row.
+        spec = chipspec.lookup("unknown")
+        assert spec is chipspec.UNKNOWN and not spec.known
         assert spec.peak_flops is None
         assert spec.peak_hbm_bytes_per_s is None
         assert chipspec.lookup(None) is chipspec.UNKNOWN
@@ -97,9 +102,10 @@ class TestDeviceInventory:
     def test_unknown_and_heterogeneous_degrade(self):
         from ray_tpu.parallel.mesh import device_inventory
 
-        inv = device_inventory([_FakeDev("xpu", "Gaudi 3")] * 2)
-        assert inv["spec"] == "unknown"
-        assert inv["peak_flops"] is None
+        from ray_tpu.observability import chipspec
+
+        with pytest.raises(chipspec.UnknownChipError):
+            device_inventory([_FakeDev("xpu", "Gaudi 3")] * 2)
         # Mixed generations share no roofline: degrade, never average.
         mixed = device_inventory([_FakeDev("tpu", "TPU v4"),
                                   _FakeDev("tpu", "TPU v5e")])
