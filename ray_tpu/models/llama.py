@@ -311,17 +311,26 @@ def _layer(config: LlamaConfig, cos, sin, attn_fn, x, layer_params):
     B, S, _ = x.shape
     kd = c.head_dim
 
-    h = rms_norm(x, p["attn_norm"], c.norm_eps)
-    q = (h @ p["wq"].astype(c.dtype)).reshape(B, S, c.n_heads, kd)
-    k = (h @ p["wk"].astype(c.dtype)).reshape(B, S, c.n_kv_heads, kd)
-    v = (h @ p["wv"].astype(c.dtype)).reshape(B, S, c.n_kv_heads, kd)
-    q = apply_rope(q, cos[:S], sin[:S])
-    k = apply_rope(k, cos[:S], sin[:S])
-    k = _repeat_kv(k, c.n_heads // c.n_kv_heads)
-    v = _repeat_kv(v, c.n_heads // c.n_kv_heads)
-    attn = attn_fn(q, k, v, causal=True)
-    x = x + attn.reshape(B, S, -1) @ p["wo"].astype(c.dtype)
+    # Scope names (`attn`, `mlp`, ...) reach each operation's `op_name`
+    # and so a device trace; they change nothing in the compiled program.
+    with jax.named_scope("attn"):
+        h = rms_norm(x, p["attn_norm"], c.norm_eps)
+        q = (h @ p["wq"].astype(c.dtype)).reshape(B, S, c.n_heads, kd)
+        k = (h @ p["wk"].astype(c.dtype)).reshape(B, S, c.n_kv_heads, kd)
+        v = (h @ p["wv"].astype(c.dtype)).reshape(B, S, c.n_kv_heads, kd)
+        q = apply_rope(q, cos[:S], sin[:S])
+        k = apply_rope(k, cos[:S], sin[:S])
+        k = _repeat_kv(k, c.n_heads // c.n_kv_heads)
+        v = _repeat_kv(v, c.n_heads // c.n_kv_heads)
+        attn = attn_fn(q, k, v, causal=True)
+        x = x + attn.reshape(B, S, -1) @ p["wo"].astype(c.dtype)
 
+    with jax.named_scope("mlp"):
+        return _ffn(c, x, p)
+
+
+def _ffn(c: LlamaConfig, x, p):
+    """A layer's feed-forward half, SwiGLU or experts: (x + delta, aux)."""
     h = rms_norm(x, p["ffn_norm"], c.norm_eps)
     if c.n_experts:
         from ray_tpu.models.moe import MoEConfig, moe_layer
@@ -371,7 +380,10 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
     def scan_body(x, layer_params):
         return layer_fn(x, layer_params)
 
-    x, aux = lax.scan(scan_body, x, params["layers"])
+    # `layers` names what the scan itself does around the body: slicing
+    # a layer's weights out of the stack, stacking what the backward needs.
+    with jax.named_scope("layers"):
+        x, aux = lax.scan(scan_body, x, params["layers"])
     x = rms_norm(x, params["norm_f"], c.norm_eps)
     return x, jnp.sum(aux)
 
@@ -387,9 +399,10 @@ def forward(params: Dict[str, Any], tokens: jax.Array,
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     # bf16 matmul on the MXU (fp32 here costs ~4x), fp32 accumulation for
     # the softmax/loss that follows.
-    logits = jax.lax.dot_general(
-        x, head.astype(c.dtype), (((2,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = jax.lax.dot_general(
+            x, head.astype(c.dtype), (((2,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
     if return_aux:
         return logits, aux
     return logits
@@ -417,20 +430,22 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
         hidden, aux = forward_hidden(params, tokens[:, :-1], config,
                                      attn_impl)
         c = config
-        head = (params["embed"].T if c.tie_embeddings
-                else params["lm_head"]).astype(c.dtype)
-        b, s, d = hidden.shape
-        nll = blockwise_xent(hidden.reshape(b * s, d), head,
-                             targets.reshape(-1)).reshape(b, s)
+        with jax.named_scope("loss_head"):
+            head = (params["embed"].T if c.tie_embeddings
+                    else params["lm_head"]).astype(c.dtype)
+            b, s, d = hidden.shape
+            nll = blockwise_xent(hidden.reshape(b * s, d), head,
+                                 targets.reshape(-1)).reshape(b, s)
     else:
         logits, aux = forward(params, tokens[:, :-1], config, attn_impl,
                               return_aux=True)
         # NLL via logsumexp - target_logit: one [B,S,V] reduction instead
         # of a materialized log_softmax plus gather.
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, targets[..., None],
-                                  axis=-1)[..., 0]
-        nll = lse - tgt
+        with jax.named_scope("loss_head"):
+            lse = jax.scipy.special.logsumexp(logits, axis=-1)
+            tgt = jnp.take_along_axis(logits, targets[..., None],
+                                      axis=-1)[..., 0]
+            nll = lse - tgt
     mask = batch.get("mask")
     if mask is not None:
         m = mask[:, 1:].astype(jnp.float32)
@@ -463,14 +478,18 @@ def _decode_attention(q, k_cache, v_cache, pos):
     B, S, KVH, D = k_cache.shape
     H = q.shape[2]
     rep = H // KVH
-    k = jnp.repeat(k_cache, rep, axis=2)
-    v = jnp.repeat(v_cache, rep, axis=2)
-    scale = 1.0 / math.sqrt(D)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
-    mask = jnp.arange(S)[None, None, None, :] <= pos[:, None, None, None]
-    scores = jnp.where(mask, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    with jax.named_scope("kv_gather"):      # the GQA repeat of the rows
+        k = jnp.repeat(k_cache, rep, axis=2)
+        v = jnp.repeat(v_cache, rep, axis=2)
+    with jax.named_scope("attn"):
+        scale = 1.0 / math.sqrt(D)
+        scores = jnp.einsum(
+            "bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+        mask = jnp.arange(S)[None, None, None, :] \
+            <= pos[:, None, None, None]
+        scores = jnp.where(mask, scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
 def decode_step(params: Dict[str, Any], cache: Dict[str, jax.Array],
@@ -601,32 +620,46 @@ def decode_step_paged(params: Dict[str, Any], pools: Dict[str, jax.Array],
     def layer(carry, inputs):
         x = carry
         p, k_pool, v_pool = inputs
-        h = rms_norm(x, p["attn_norm"], c.norm_eps)
-        q = (h @ _weight(p, "wq", c.dtype)).reshape(B, 1, c.n_heads, kd)
-        k = (h @ _weight(p, "wk", c.dtype)).reshape(B, 1, c.n_kv_heads, kd)
-        v = (h @ _weight(p, "wv", c.dtype)).reshape(B, 1, c.n_kv_heads, kd)
-        q, k = rope1(q), rope1(k)
-        k_pool = k_pool.at[phys, off].set(k[:, 0].astype(k_pool.dtype))
-        v_pool = v_pool.at[phys, off].set(v[:, 0].astype(v_pool.dtype))
+        with jax.named_scope("attn"):
+            h = rms_norm(x, p["attn_norm"], c.norm_eps)
+            q = (h @ _weight(p, "wq", c.dtype)).reshape(
+                B, 1, c.n_heads, kd)
+            k = (h @ _weight(p, "wk", c.dtype)).reshape(
+                B, 1, c.n_kv_heads, kd)
+            v = (h @ _weight(p, "wv", c.dtype)).reshape(
+                B, 1, c.n_kv_heads, kd)
+            q, k = rope1(q), rope1(k)
+        with jax.named_scope("kv_write"):
+            k_pool = k_pool.at[phys, off].set(k[:, 0].astype(k_pool.dtype))
+            v_pool = v_pool.at[phys, off].set(v[:, 0].astype(v_pool.dtype))
         # Per-sequence dense view via the block table (gather AFTER the
         # write so this token's own row is attendable at `positions`).
-        k_dense = k_pool[block_tables].reshape(B, S_pad, c.n_kv_heads, kd)
-        v_dense = v_pool[block_tables].reshape(B, S_pad, c.n_kv_heads, kd)
+        with jax.named_scope("kv_gather"):
+            k_dense = k_pool[block_tables].reshape(
+                B, S_pad, c.n_kv_heads, kd)
+            v_dense = v_pool[block_tables].reshape(
+                B, S_pad, c.n_kv_heads, kd)
         attn = _decode_attention(q, k_dense, v_dense, positions)
-        x = x + attn.reshape(B, 1, -1) @ _weight(p, "wo", c.dtype)
-        h = rms_norm(x, p["ffn_norm"], c.norm_eps)
-        gate = jax.nn.silu(h @ _weight(p, "w_gate", c.dtype))
-        up = h @ _weight(p, "w_up", c.dtype)
-        x = x + (gate * up) @ _weight(p, "w_down", c.dtype)
+        with jax.named_scope("attn"):
+            x = x + attn.reshape(B, 1, -1) @ _weight(p, "wo", c.dtype)
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, p["ffn_norm"], c.norm_eps)
+            gate = jax.nn.silu(h @ _weight(p, "w_gate", c.dtype))
+            up = h @ _weight(p, "w_up", c.dtype)
+            x = x + (gate * up) @ _weight(p, "w_down", c.dtype)
         return x, (k_pool, v_pool)
 
-    x, (new_k, new_v) = lax.scan(
-        layer, x, (params["layers"], pools["k"], pools["v"]))
-    x = rms_norm(x, params["norm_f"], c.norm_eps)
-    head = lm_head_weight(params, c)
-    logits = jax.lax.dot_general(
-        x[:, 0], head, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    # `layers`: the scan's own slicing of a layer's weights and pool out
+    # of the stacks, and writing the pool back.
+    with jax.named_scope("layers"):
+        x, (new_k, new_v) = lax.scan(
+            layer, x, (params["layers"], pools["k"], pools["v"]))
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["norm_f"], c.norm_eps)
+        head = lm_head_weight(params, c)
+        logits = jax.lax.dot_general(
+            x[:, 0], head, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
     return logits, {"k": new_k, "v": new_v}
 
 

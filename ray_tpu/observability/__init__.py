@@ -182,6 +182,7 @@ from ray_tpu.observability.profiling import (  # noqa: F401
     merge_counts,
     observe_sched_phases,
     render_speedscope,
+    trace_span,
 )
 from ray_tpu.observability.rl import rl_metrics  # noqa: F401
 from ray_tpu.observability.serve import serve_metrics  # noqa: F401
@@ -205,6 +206,7 @@ __all__ = [
     "SCHED_PHASES", "SCHED_SEGMENT_LABELS", "StackSampler",
     "capture_thread_stacks", "collapse", "format_thread_stacks",
     "merge_counts", "observe_sched_phases", "render_speedscope",
+    "trace_span",
     "GOODPUT_CAUSES", "TRAIN_PHASES", "GoodputLedger", "StepPhases",
     "StragglerDetector", "classify_phase", "goodput_enabled",
     "goodput_metrics", "publish_train_done", "publish_train_step",

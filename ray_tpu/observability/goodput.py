@@ -45,6 +45,8 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, Optional
 
+from ray_tpu.observability.profiling import trace_span
+
 # Per-step phase vocabulary (display order). The classification below
 # maps each phase into the goodput ledger's buckets: an accelerator
 # doing optimizer math is productive; one waiting on the input
@@ -269,6 +271,10 @@ class StepPhases:
         self._exposed = 0.0
         self._start_ts = time.time()
         self._t0 = time.perf_counter()
+        # `train.step` / `train.<phase>` in the profiler's trace, on
+        # the device's clock; closed by finish().
+        self._span = trace_span("train.step", step=self.step)
+        self._span.__enter__()
         with _lock:
             _active_step = self
 
@@ -276,7 +282,8 @@ class StepPhases:
     def phase(self, name: str):
         t0 = time.perf_counter()
         try:
-            yield
+            with trace_span("train." + name):
+                yield
         finally:
             self.add(name, time.perf_counter() - t0)
 
@@ -293,6 +300,7 @@ class StepPhases:
     def finish(self, publish: bool = True) -> Dict[str, Any]:
         global _active_step
         wall = time.perf_counter() - self._t0
+        self._span.__exit__(None, None, None)
         with _lock:
             if _active_step is self:
                 _active_step = None

@@ -35,6 +35,7 @@ is fenced with ``block_until_ready`` to sample an honest execution wall
 
 from __future__ import annotations
 
+import re
 import threading
 import time
 import warnings
@@ -143,6 +144,9 @@ class TrackedJit:
                     st["traces"] += 1
             return fn(*args, **kwargs)
 
+        # The trace names a program after the jitted callable
+        # (`jit_llm_engine_tick(<fingerprint>)` on `XLA Modules`).
+        probe.__name__ = probe.__qualname__ = re.sub(r"\W", "_", self.name)
         self._jitted = jax.jit(probe, **jit_kwargs)
 
     def __call__(self, *args, **kwargs):
@@ -183,8 +187,13 @@ class TrackedJit:
         except Exception:
             pass
         try:
+            from ray_tpu.observability.profiling import trace_span
             from ray_tpu.util.tracing import record_span
 
+            # An instant at the end of the compiling call: in a device
+            # trace it stands beside the gap the compile caused.
+            with trace_span("jit.compile", fn=self.name, seconds=seconds):
+                pass
             record_span("jit_compile", time.time() - seconds, seconds,
                         attrs={"fn": self.name, "traces": self.traces})
         except Exception:

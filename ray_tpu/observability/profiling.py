@@ -318,6 +318,20 @@ def render_speedscope(counts: Dict[str, Dict[str, int]],
 # traces come from the same util.state API)
 # ---------------------------------------------------------------------------
 
+def trace_span(name: str, **args):
+    """A host span in the profiler's own trace, on the device's clock:
+    ``with trace_span("llm_engine.spill", evicted_blocks=n): ...`` puts
+    an event on the host plane of the ``.xplane.pb`` that
+    :func:`capture_tpu_trace` (or any ``jax.profiler`` session) writes,
+    beside the device's ``XLA Ops``. Names are fixed ``<component>.
+    <phase>`` strings; counts go in ``args`` (or, known only at the
+    end, through the returned object's ``set_metadata``). With no
+    session live an entry is one flag test."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
 def capture_tpu_trace(duration_s: float,
                       trace_dir: Optional[str] = None) -> Dict[str, Any]:
     """Run ``jax.profiler.start_trace``/``stop_trace`` for ``duration_s``

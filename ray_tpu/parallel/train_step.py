@@ -138,13 +138,14 @@ def build_train_step(
     def step_fn(state: TrainState, batch) -> Tuple[TrainState, Dict]:
         loss, grads = _loss_and_grads(state.params, batch)
         grad_norm = optax.global_norm(grads)
-        updates, new_opt = optimizer.update(grads, state.opt_state,
-                                            state.params)
-        if moment_sh is not None:
-            from ray_tpu.parallel.zero import constrain_opt_state
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(grads, state.opt_state,
+                                                state.params)
+            if moment_sh is not None:
+                from ray_tpu.parallel.zero import constrain_opt_state
 
-            new_opt = constrain_opt_state(new_opt, moment_sh)
-        new_params = optax.apply_updates(state.params, updates)
+                new_opt = constrain_opt_state(new_opt, moment_sh)
+            new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(new_params, new_opt, state.step + 1)
         return new_state, {"loss": loss, "grad_norm": grad_norm,
                            "step": new_state.step}

@@ -50,6 +50,8 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from ray_tpu.observability.profiling import trace_span
+
 
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
@@ -594,10 +596,11 @@ class LLMEngine:
             logits, pools = decode_step_paged(
                 params, pools, tables, tok, pos, self.model_config,
                 active=active)
-            key, sub = jax.random.split(key)
-            nxt = _sample(logits, temp, sub)
-            tok = jnp.where(active, nxt, tok)
-            pos = jnp.where(active, jnp.minimum(pos + 1, S - 1), pos)
+            with jax.named_scope("sample"):
+                key, sub = jax.random.split(key)
+                nxt = _sample(logits, temp, sub)
+                tok = jnp.where(active, nxt, tok)
+                pos = jnp.where(active, jnp.minimum(pos + 1, S - 1), pos)
             return (pools, tok, pos, key), tok
 
         (pools, tok, pos, key), toks = jax.lax.scan(
@@ -967,8 +970,6 @@ class LLMEngine:
         admissions (KV lands in the prefix cache, the slot is reused
         immediately) rate-limited to one chunk per step so interactive
         admissions interleave with a long prefill."""
-        import numpy as np
-
         inserted: List[Tuple[int, bool]] = []
         chunk_budget = 1
         while self._free:
@@ -989,8 +990,10 @@ class LLMEngine:
                 end = handle._chunk_ends[handle._chunk_idx]
                 slot = self._free[0]
                 t_chunk = time.monotonic()
-                if not self._admit_paged(handle, slot, upto=end,
-                                         throwaway=True):
+                with trace_span("llm_engine.admit_one", chunk=1):
+                    ok = self._admit_paged(handle, slot, upto=end,
+                                           throwaway=True)
+                if not ok:
                     self._requeue(handle)
                     if req.slo == "interactive":
                         self._admit_blocked = True
@@ -1005,22 +1008,8 @@ class LLMEngine:
             slot = self._free.popleft()
             fresh = handle.kv_state is None
             t_admit = time.monotonic()
-            if not fresh:
-                ok = self._admit_adopted(handle, slot)
-            elif self._paged:
-                ok = self._admit_paged(handle, slot)
-            else:
-                P = len(req.prompt)
-                bucket = self._bucket_for(P)
-                padded = np.zeros((bucket,), np.int32)
-                padded[:P] = np.asarray(req.prompt, np.int32)
-                self._cache, self._tok, self._pos, self._key = \
-                    self._jit_insert(
-                        self.params, self._cache, self._tok, self._pos,
-                        padded, np.int32(P), np.int32(slot),
-                        np.float32(req.temperature), self._key)
-                handle.prefilled_tokens += P
-                ok = True
+            with trace_span("llm_engine.admit_one"):
+                ok = self._admit_one(handle, slot, fresh)
             if not ok:
                 self._free.appendleft(slot)
                 if req.slo == "interactive":
@@ -1053,6 +1042,30 @@ class LLMEngine:
             self._temp[slot] = req.temperature
             inserted.append((slot, fresh))
         return inserted
+
+    def _admit_one(self, handle: RequestHandle, slot: int,
+                   fresh: bool) -> bool:
+        """One request into `slot`: adopt its checkpoint, or prefill
+        it (paged or dense). False when the pool cannot cover it."""
+        import numpy as np
+
+        if not fresh:
+            return self._admit_adopted(handle, slot)
+        if self._paged:
+            return self._admit_paged(handle, slot)
+        req = handle.request
+        P = len(req.prompt)
+        bucket = self._bucket_for(P)
+        padded = np.zeros((bucket,), np.int32)
+        padded[:P] = np.asarray(req.prompt, np.int32)
+        with trace_span("llm_engine.insert_dispatch", bucket=bucket):
+            self._cache, self._tok, self._pos, self._key = \
+                self._jit_insert(
+                    self.params, self._cache, self._tok, self._pos,
+                    padded, np.int32(P), np.int32(slot),
+                    np.float32(req.temperature), self._key)
+        handle.prefilled_tokens += P
+        return True
 
     def _admit_paged(self, handle: RequestHandle, slot: int,
                      upto: Optional[int] = None,
@@ -1141,7 +1154,9 @@ class LLMEngine:
             n_new = max(need_total - n_hit, n_pro + bucket // bs)
             new_blocks = self._allocator.alloc(n_new)
             if new_blocks is None and self._prefix is not None:
-                self._prefix.evict(n_new - self._allocator.free_blocks)
+                want = n_new - self._allocator.free_blocks
+                with trace_span("llm_engine.evict", blocks=want):
+                    self._prefix.evict(want)
                 new_blocks = self._allocator.alloc(n_new)
             if new_blocks is not None or not promote:
                 break
@@ -1170,18 +1185,20 @@ class LLMEngine:
         if promote:
             # Land the tier links in new_blocks[:n_pro] BEFORE the
             # insert below reads them as history.
-            self._promote_tier_hits(promote, new_blocks[:n_pro], slot,
-                                    handle=handle)
+            with trace_span("llm_engine.promote", blocks=n_pro):
+                self._promote_tier_hits(promote, new_blocks[:n_pro],
+                                        slot, handle=handle)
         padded = np.zeros((bucket,), np.int32)
         padded[:suffix_len] = np.asarray(prompt[hist_len:], np.int32)
         scatter_ids = np.asarray(new_blocks[n_pro:n_pro + bucket // bs],
                                  np.int32)
-        self._cache, self._tok, self._pos, self._key = \
-            self._jit_insert(
-                self.params, self._cache, self._tok, self._pos,
-                row, np.int32(hist_len), padded, np.int32(suffix_len),
-                scatter_ids, np.int32(slot),
-                np.float32(req.temperature), self._key)
+        with trace_span("llm_engine.insert_dispatch", bucket=bucket):
+            self._cache, self._tok, self._pos, self._key = \
+                self._jit_insert(
+                    self.params, self._cache, self._tok, self._pos,
+                    row, np.int32(hist_len), padded, np.int32(suffix_len),
+                    scatter_ids, np.int32(slot),
+                    np.float32(req.temperature), self._key)
         handle.prefilled_tokens += suffix_len
         if self._prefix is not None:
             # Register the prompt's FULL blocks (all rows real) so the
@@ -1441,18 +1458,22 @@ class LLMEngine:
             return 0
         nb = c.max_blocks_per_slot
         prefixes: List[Any] = []
-        for i in range(0, len(ents), nb):
-            chunk = ents[i:i + nb]
-            row = np.zeros((nb,), np.int32)
-            row[:len(chunk)] = [e.block for e in chunk]
-            kb, vb = self._jit_export(self._cache, row)
-            kb, vb = np.asarray(kb), np.asarray(vb)
-            for j, e in enumerate(chunk):
-                prefixes.append(KVPrefix(
-                    tokens=e.tokens, block_size=bs,
-                    k_blocks=kb[:, j:j + 1].copy(),
-                    v_blocks=vb[:, j:j + 1].copy()))
-        return self._tiers.spill(prefixes)
+        copied = 0
+        with trace_span("llm_engine.spill", evicted_blocks=len(ents)) as sp:
+            for i in range(0, len(ents), nb):
+                chunk = ents[i:i + nb]
+                row = np.zeros((nb,), np.int32)
+                row[:len(chunk)] = [e.block for e in chunk]
+                kb, vb = self._jit_export(self._cache, row)
+                kb, vb = np.asarray(kb), np.asarray(vb)
+                copied += kb.nbytes + vb.nbytes
+                for j, e in enumerate(chunk):
+                    prefixes.append(KVPrefix(
+                        tokens=e.tokens, block_size=bs,
+                        k_blocks=kb[:, j:j + 1].copy(),
+                        v_blocks=vb[:, j:j + 1].copy()))
+            sp.set_metadata(bytes=copied)
+            return self._tiers.spill(prefixes)
 
     def _promote_tier_hits(self, hits: List[Any],
                            dst_blocks: List[int], slot: int,
@@ -1813,76 +1834,81 @@ class LLMEngine:
         with their checkpoint), then one decode tick — speculative when
         every live slot qualifies, plain otherwise — for every live
         slot. Returns True if any work was done."""
+        with trace_span("llm_engine.step"):
+            return self._step()
+
+    def _step(self) -> bool:
+        """`step`'s body. Its phases stand in a profiler trace as
+        `llm_engine.<phase>` spans that together cover the step."""
         import numpy as np
 
-        did_cancel = bool(self._cancelled)
-        did_ctrl = self._process_ctrl()
-        self._process_cancels()
-        self._maybe_preempt()
+        with trace_span("llm_engine.ctrl"):
+            did_cancel = bool(self._cancelled)
+            did_ctrl = self._process_ctrl()
+            self._process_cancels()
+            self._maybe_preempt()
         self._admit_blocked = False
-        inserted = self._admit()
+        with trace_span("llm_engine.admit") as sp:
+            inserted = self._admit()
+            sp.set_metadata(admitted=len(inserted))
         if inserted:
             # First generated token per freshly-prefilled slot (before
             # the tick below overwrites it with the second). Adopted
             # slots skip this: their pending token was emitted by the
             # exporting engine already.
-            tok_host = np.asarray(self._tok)
-            for slot, fresh in inserted:
-                if not fresh:
-                    continue
-                if self._slots[slot].handle.request.prefill_only:
-                    self._finish_prefill(slot, int(tok_host[slot]))
-                else:
-                    self._emit(slot, int(tok_host[slot]))
+            with trace_span("llm_engine.first_token_wait"):
+                tok_host = np.asarray(self._tok)
+                for slot, fresh in inserted:
+                    if not fresh:
+                        continue
+                    if self._slots[slot].handle.request.prefill_only:
+                        self._finish_prefill(slot, int(tok_host[slot]))
+                    else:
+                        self._emit(slot, int(tok_host[slot]))
         if not self._active.any():
-            self._update_gauges()
+            with trace_span("llm_engine.gauges"):
+                self._update_gauges()
             return bool(inserted) or did_cancel or did_ctrl
         live = np.nonzero(self._active)[0]
-        if self._spec_ready(live):
+        with trace_span("llm_engine.tick_dispatch", live=len(live)):
+            spec = self._spec_ready(live)
             t_tick = time.monotonic()
-            toks_host, n_emit = self._spec_tick()
+            if spec:
+                out = self._spec_dispatch()
+            elif self._paged:
+                self._cache, self._tok, self._pos, self._key, out = \
+                    self._jit_tick(
+                        self.params, self._cache, self._tables.copy(),
+                        self._tok, self._pos, self._active.copy(),
+                        self._temp.copy(), self._key)
+            else:
+                self._cache, self._tok, self._pos, self._key, out = \
+                    self._jit_tick(
+                        self.params, self._cache, self._tok, self._pos,
+                        self._active.copy(), self._temp.copy(), self._key)
+        with trace_span("llm_engine.tick_wait"):
+            if spec:
+                toks_host, n_emit = self._spec_wait(*out)
+            else:
+                toks_host = np.asarray(out)         # [K, B]
+        with trace_span("llm_engine.emit"):
             self._credit_decode(live, time.monotonic() - t_tick)
-            if self._acct:
-                # Per-slot speculative accounting: a live slot's round
-                # proposed spec_k - 1 drafts and accepted n_emit - 1.
-                k_prop = self.config.spec_k - 1
-                for slot in live:
-                    s = int(slot)
-                    h = self._slots[s].handle
-                    if h is not None and h.meter is not None \
-                            and int(n_emit[s]) > 0:
-                        h.meter.note_spec(k_prop, int(n_emit[s]) - 1)
             for slot in live:
                 s = int(slot)
-                for k in range(int(n_emit[s])):
+                n = int(n_emit[s]) if spec else toks_host.shape[0]
+                if spec and n > 0:
+                    # Per-slot speculative accounting: a live slot's
+                    # round proposed spec_k - 1 drafts, accepted n - 1.
+                    h = self._slots[s].handle
+                    if h is not None and h.meter is not None:
+                        h.meter.note_spec(self.config.spec_k - 1, n - 1)
+                for k in range(n):
                     if self._slots[s].handle is None:
-                        break      # finished earlier in the round —
+                        break      # finished earlier in the block —
                         #            remaining tokens were speculative
                     self._emit(s, int(toks_host[k, s]))
+        with trace_span("llm_engine.gauges"):
             self._update_gauges()
-            return True
-        t_tick = time.monotonic()
-        if self._paged:
-            self._cache, self._tok, self._pos, self._key, toks = \
-                self._jit_tick(
-                    self.params, self._cache, self._tables.copy(),
-                    self._tok, self._pos, self._active.copy(),
-                    self._temp.copy(), self._key)
-        else:
-            self._cache, self._tok, self._pos, self._key, toks = \
-                self._jit_tick(
-                    self.params, self._cache, self._tok, self._pos,
-                    self._active.copy(), self._temp.copy(), self._key)
-        toks_host = np.asarray(toks)                # [K, B]
-        self._credit_decode(live, time.monotonic() - t_tick)
-        for slot in live:
-            s = int(slot)
-            for k in range(toks_host.shape[0]):
-                if self._slots[s].handle is None:
-                    break          # finished earlier in the block —
-                    #                remaining tokens were speculative
-                self._emit(s, int(toks_host[k, s]))
-        self._update_gauges()
         return True
 
     def _credit_decode(self, live, dt: float) -> None:
@@ -1918,17 +1944,20 @@ class LLMEngine:
         return bool((pos_host[live] <= self.config.max_seq_len
                      - self.config.spec_k).all())
 
-    def _spec_tick(self):
-        """Run one speculative round and return (tokens [K, B] host,
-        n_emit [B] host); the caller emits tokens[0:n_emit[s], s] per
-        slot."""
-        import numpy as np
-
+    def _spec_dispatch(self):
+        """Dispatch one speculative round; `_spec_wait` reads it."""
         (self._cache, self._draft_cache, self._tok, self._pos,
          t, n_emit) = self._jit_spec(
             self.params, self._draft, self._cache, self._draft_cache,
             self._tables.copy(), self._tok, self._pos,
             self._active.copy())
+        return t, n_emit
+
+    def _spec_wait(self, t, n_emit):
+        """Read a speculative round back: (tokens [K, B] host, n_emit
+        [B] host); the caller emits tokens[0:n_emit[s], s] per slot."""
+        import numpy as np
+
         n_host = np.asarray(n_emit)
         live = int((n_host > 0).sum())
         self._spec_rounds += 1

@@ -261,3 +261,73 @@ def test_train_step_holds_flash_kernel_and_fits_one_v5e(topo, on_tpu):
     compiled = step.lower(state, batch).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 3
     assert _hbm_gib(compiled) < V5E_HBM_GIB - 0.5
+
+
+def test_scopes_change_only_names_in_the_v5e_train_step(topo, on_tpu,
+                                                         monkeypatch):
+    """`jax.named_scope` in the model and the step builder (PR 24) must
+    leave the chip's program alone.  With the flash kernels in it, the
+    compiled text with and without the scopes differs in names only: the
+    per-instruction metadata, the kernel calls' instruction names
+    (`%closed_call.6` becomes `%attn.39`) and the debug locations inside
+    each kernel's serialized module; the kernels themselves are equal."""
+    import base64
+    import contextlib
+    import re
+
+    import optax
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    from ray_tpu.models.llama import LlamaConfig, init_params, loss_fn
+    from ray_tpu.parallel import build_train_step, create_train_state
+
+    config = LlamaConfig(vocab_size=2048, dim=512, n_layers=2, n_heads=4,
+                         n_kv_heads=2, hidden_dim=1024, max_seq_len=1024,
+                         attn_impl="flash", remat="dots")
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    optimizer = optax.adamw(1e-3)
+    params_shape = jax.eval_shape(
+        lambda: init_params(config, jax.random.key(0)))
+    state = _placed(jax.eval_shape(
+        lambda p: create_train_state(p, optimizer), params_shape),
+        NamedSharding(mesh, P()))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (2, 1025), jnp.int32, sharding=NamedSharding(mesh, P()))}
+
+    def compiled_text():
+        step = build_train_step(lambda p, b: loss_fn(p, b, config),
+                                optimizer, mesh, None,
+                                NamedSharding(mesh, P()))
+        return step.lower(state, batch).compile().as_text()
+
+    scoped = compiled_text()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = compiled_text()
+    assert "/optimizer/" in scoped and "/optimizer/" not in bare
+
+    def kernels(text):
+        out = []
+        for body in re.findall(r'"body":"([^"]+)"', text):
+            ctx = jax_mlir.make_ir_context()
+            tpu.register_dialect(ctx)
+            ctx.allow_unregistered_dialects = True   # `stable_mosaic`
+            with ctx:
+                out.append(ir.Module.parse(base64.b64decode(body))
+                           .operation.get_asm(enable_debug_info=False))
+        return out
+
+    assert len(kernels(scoped)) >= 3 and kernels(scoped) == kernels(bare)
+
+    def rest(text):
+        text = re.sub(r", metadata=\{[^}]*\}", "", text)
+        text = re.sub(r"\n(FileNames|FunctionNames|FileLocations|"
+                      r"StackFrames)\n(.+\n)*", "\n", text)
+        text = re.sub(r'"body":"[^"]+"', '"body":""', text)
+        return re.sub(r"%(attn|closed_call|rematted_computation|checkpoint)"
+                      r"\.\d+", "%kernel", text)
+
+    assert rest(scoped) == rest(bare)
