@@ -473,23 +473,28 @@ def init_kv_cache(config: LlamaConfig, batch_size: int,
     return {"k": jnp.zeros(shape, c.dtype), "v": jnp.zeros(shape, c.dtype)}
 
 
-def _decode_attention(q, k_cache, v_cache, pos):
-    """q [B,1,H,D]; caches [B,S,kvH,D]; attends to positions <= pos."""
+def _decode_attention(q, k_cache, v_cache, qpos):
+    """q [B,Q,H,D] at absolute positions qpos [B,Q]; caches [B,S,kvH,D].
+    Query j of row b attends to keys at positions <= qpos[b, j].
+
+    Grouped-query form: head h belongs to KV group h // rep (the order
+    `_repeat_kv` lays them), so the rep query heads of a group contract
+    against that group's K and V rows as they lie in the cache and each
+    row is read once -- no rep-fold copy of the cache is built. rep == 1
+    (MHA) and kvH == 1 (MQA) are the same contraction at other shapes.
+    """
     B, S, KVH, D = k_cache.shape
-    H = q.shape[2]
-    rep = H // KVH
-    with jax.named_scope("kv_gather"):      # the GQA repeat of the rows
-        k = jnp.repeat(k_cache, rep, axis=2)
-        v = jnp.repeat(v_cache, rep, axis=2)
+    Q, H = q.shape[1], q.shape[2]
     with jax.named_scope("attn"):
-        scale = 1.0 / math.sqrt(D)
-        scores = jnp.einsum(
-            "bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
-        mask = jnp.arange(S)[None, None, None, :] \
-            <= pos[:, None, None, None]
+        qg = q.reshape(B, Q, KVH, H // KVH, D)
+        scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_cache).astype(
+            jnp.float32) * (1.0 / math.sqrt(D))
+        mask = jnp.arange(S)[None, None, None, None, :] \
+            <= qpos[:, None, None, :, None]
         scores = jnp.where(mask, scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+        return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_cache).reshape(
+            B, Q, H, D)
 
 
 def decode_step(params: Dict[str, Any], cache: Dict[str, jax.Array],
@@ -542,7 +547,7 @@ def decode_step(params: Dict[str, Any], cache: Dict[str, jax.Array],
             write_pos = jnp.where(active, positions, k_cache.shape[1])
         k_cache = k_cache.at[bidx, write_pos].set(k[:, 0])
         v_cache = v_cache.at[bidx, write_pos].set(v[:, 0])
-        attn = _decode_attention(q, k_cache, v_cache, positions)
+        attn = _decode_attention(q, k_cache, v_cache, positions[:, None])
         x = x + attn.reshape(B, 1, -1) @ _weight(p, "wo", c.dtype)
         h = rms_norm(x, p["ffn_norm"], c.norm_eps)
         gate = jax.nn.silu(h @ _weight(p, "w_gate", c.dtype))
@@ -585,7 +590,8 @@ def decode_step_paged(params: Dict[str, Any], pools: Dict[str, jax.Array],
     Token-exact with `decode_step` on a dense cache holding the same
     logical contents: the gather assembles each sequence's dense
     [S_pad] view (S_pad = max_blocks * block_size), the write lands at
-    (table[pos // bs], pos % bs), and the same masked softmax drops
+    (table[pos // bs], pos % bs), and the same `_decode_attention` reads
+    that view as it lies ([B, S_pad, kvH, D], no GQA repeat) and drops
     padding/stale rows to exact zeros. ``active`` masks the pool write
     by pushing the physical block index out of bounds (scatter drops
     it), mirroring the dense path's out-of-bounds position trick.
@@ -639,7 +645,7 @@ def decode_step_paged(params: Dict[str, Any], pools: Dict[str, jax.Array],
                 B, S_pad, c.n_kv_heads, kd)
             v_dense = v_pool[block_tables].reshape(
                 B, S_pad, c.n_kv_heads, kd)
-        attn = _decode_attention(q, k_dense, v_dense, positions)
+        attn = _decode_attention(q, k_dense, v_dense, positions[:, None])
         with jax.named_scope("attn"):
             x = x + attn.reshape(B, 1, -1) @ _weight(p, "wo", c.dtype)
         with jax.named_scope("mlp"):
@@ -674,7 +680,8 @@ def verify_kv_paged(params: Dict[str, Any], pools: Dict[str, jax.Array],
     Row j's logits are the target model's distribution for the token
     FOLLOWING input j — exactly what ``decode_step_paged`` would produce
     after consuming inputs 0..j one at a time, because every op here is
-    row-independent (per-position matmuls, per-query masked softmax):
+    row-independent (per-position matmuls, and `_decode_attention`, the
+    decode step's own helper, with K queries a row instead of one):
     running K queries through one program instead of K programs changes
     batching, not values. The engine exploits this for draft
     verification: accept the longest prefix where the target's argmax
@@ -719,7 +726,6 @@ def verify_kv_paged(params: Dict[str, Any], pools: Dict[str, jax.Array],
     if active is not None:
         phys = jnp.where(active[:, None], phys, NB)  # OOB scatter drop
     off = qpos % bs
-    scale = 1.0 / math.sqrt(kd)
 
     def layer(carry, inputs):
         x = carry
@@ -732,19 +738,10 @@ def verify_kv_paged(params: Dict[str, Any], pools: Dict[str, jax.Array],
         k_pool = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
         v_pool = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
         # Dense per-sequence view gathered AFTER all K writes: query j
-        # sees queries i < j through the position mask below.
+        # sees queries i < j through `_decode_attention`'s position mask.
         k_dense = k_pool[block_tables].reshape(B, S_pad, c.n_kv_heads, kd)
         v_dense = v_pool[block_tables].reshape(B, S_pad, c.n_kv_heads, kd)
-        rep = c.n_heads // c.n_kv_heads
-        kr = _repeat_kv(k_dense.astype(c.dtype), rep)
-        vr = _repeat_kv(v_dense.astype(c.dtype), rep)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, kr).astype(
-            jnp.float32) * scale
-        mask = (qpos[:, None, :, None]
-                >= jnp.arange(S_pad)[None, None, None, :])
-        scores = jnp.where(mask, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vr)
+        attn = _decode_attention(q, k_dense, v_dense, qpos)
         x = x + attn.reshape(B, K, -1) @ _weight(p, "wo", c.dtype)
         h = rms_norm(x, p["ffn_norm"], c.norm_eps)
         gate = jax.nn.silu(h @ _weight(p, "w_gate", c.dtype))

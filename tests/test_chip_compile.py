@@ -203,15 +203,11 @@ def _smoke_config(phase):
     return chip_smoke._model_config(chip_smoke.chip_spec(0)[phase]["model"])
 
 
-def test_paged_decode_program_fits_one_v5e(one_chip):
-    """The engine's decode program at chip_smoke.py's serve widths, depth
-    and pool: compiles, and weights + pool + the program's own temporaries
-    (a second pool: the layer scan writes a fresh stacked one) fit HBM."""
-    import chip_smoke
+def _compiled_paged_tick(config, engine, one_chip):
+    """`decode_step_paged` as the engine jits it (pools donated), at
+    `engine`'s slots, row length, block size and pool."""
     from ray_tpu.models import llama
 
-    engine = chip_smoke.chip_spec(0)["serve"]["engine"]
-    config = _smoke_config("serve")
     B, bs = engine["num_slots"], engine["kv_block_size"]
     params = _placed(jax.eval_shape(
         lambda: llama.init_params(config, jax.random.key(0))), one_chip)
@@ -227,7 +223,51 @@ def test_paged_decode_program_fits_one_v5e(one_chip):
             ints((B,)), ints((B,)),
             jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
             ).compile()
+    return compiled, params, pools
+
+
+def test_paged_decode_program_fits_one_v5e(one_chip):
+    """The engine's decode program at chip_smoke.py's serve widths, depth
+    and pool: compiles, and weights + pool + the program's own temporaries
+    (a second pool: the layer scan writes a fresh stacked one) fit HBM."""
+    import chip_smoke
+
+    compiled, _, _ = _compiled_paged_tick(
+        _smoke_config("serve"), chip_smoke.chip_spec(0)["serve"]["engine"],
+        one_chip)
     assert _hbm_gib(compiled) < V5E_HBM_GIB - 0.5
+
+
+def test_benchmark_decode_tick_builds_no_repeated_kv(one_chip):
+    """The tick of the benchmark's `chat-decode` cell (Mistral-7B-v0.3
+    widths, 20 layers of bf16 weights, 32 slots x 2048, 1800 blocks of
+    16): grouped-query attention reads the gathered K/V rows as they are,
+    so nothing the size of their `n_heads / n_kv_heads`-fold repeat
+    exists in the compiled program, and its temporaries show it (4.5-4.7
+    GiB with the repeat, 2.67 without)."""
+    import math
+    import re
+
+    from ray_tpu.models.llama import LlamaConfig
+
+    config = LlamaConfig(
+        vocab_size=32768, dim=4096, n_layers=20, n_heads=32, n_kv_heads=8,
+        hidden_dim=14336, max_seq_len=2048, rope_theta=1e6, norm_eps=1e-5,
+        param_dtype=jnp.bfloat16)
+    B, S_pad = 32, 2048
+    compiled, params, pools = _compiled_paged_tick(
+        config, dict(num_slots=B, max_seq_len=S_pad, kv_block_size=16,
+                     num_kv_blocks=1800), one_chip)
+    text = compiled.as_text()
+    held = {x.shape for x in jax.tree.leaves((params, pools))}
+    repeated = B * S_pad * config.n_heads * config.head_dim
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"\b[a-z]+\d+\[([\d,]+)\]", text)}
+    assert (B, S_pad, config.n_kv_heads, config.head_dim) in shapes  # parsed
+    # in particular no [32,2048,8,4,128] and no [32,2048,32,128]
+    assert not {s for s in shapes
+                if math.prod(s) >= repeated and s not in held}
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.0 * GIB
 
 
 def test_train_step_holds_flash_kernel_and_fits_one_v5e(topo, on_tpu):

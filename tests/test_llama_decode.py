@@ -154,3 +154,94 @@ def test_paged_decode_matches_dense():
         np.testing.assert_allclose(np.asarray(dl), np.asarray(pl_),
                                    rtol=2e-2, atol=2e-2)
         toks = jnp.argmax(dl, -1).astype(jnp.int32)
+
+
+# (n_heads, n_kv_heads): MHA, the tiny config's rep 2, the benchmark's
+# rep 4, and MQA
+GQA_SHAPES = {"mha": (4, 4), "rep2": (4, 2), "rep4": (8, 2), "mqa": (4, 1)}
+
+
+@pytest.mark.parametrize("n_queries", [1, 3])
+@pytest.mark.parametrize("heads", sorted(GQA_SHAPES))
+def test_decode_attention_matches_explicit_repeat(heads, n_queries):
+    """The grouped contraction of `_decode_attention` against the form it
+    replaced, written out here: repeat K and V `rep`-fold along the head
+    axis, then contract head by head. Rows sit at position 0 (every key
+    but one masked), mid-sequence and S-1."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import _decode_attention
+
+    H, KVH = GQA_SHAPES[heads]
+    B, S, D, Q = 3, 32, 16, n_queries
+    kq, kk, kv = jax.random.split(jax.random.key(H * 10 + KVH), 3)
+    q = jax.random.normal(kq, (B, Q, H, D), jnp.bfloat16)
+    k = jax.random.normal(kk, (B, S, KVH, D), jnp.bfloat16)
+    v = jax.random.normal(kv, (B, S, KVH, D), jnp.bfloat16)
+    # query j of a row sits at pos + j; the last row ends at S-1
+    qpos = (jnp.asarray([0, S // 2, S - Q], jnp.int32)[:, None]
+            + jnp.arange(Q)[None, :])
+
+    def reference(q, k, v, qpos):
+        kr = jnp.repeat(k, H // KVH, axis=2)
+        vr = jnp.repeat(v, H // KVH, axis=2)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, kr).astype(
+            jnp.float32) / np.sqrt(D)
+        mask = jnp.arange(S)[None, None, None, :] <= qpos[:, None, :, None]
+        probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), vr)
+
+    got = jax.jit(_decode_attention)(q, k, v, qpos)
+    want = jax.jit(reference)(q, k, v, qpos)
+    assert got.shape == (B, Q, H, D) and got.dtype == jnp.bfloat16
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+    np.testing.assert_array_equal(got.argmax(-1), want.argmax(-1))
+    # one live key: head h reads row 0 of its own group h // rep, exactly
+    np.testing.assert_array_equal(
+        got[0, 0], np.repeat(np.asarray(v[0, 0], np.float32),
+                             H // KVH, axis=0))
+
+
+def test_verify_kv_paged_matches_successive_decode_steps():
+    """`verify_kv_paged` over K tokens gives, row by row, the logits that
+    K successive `decode_step_paged` calls give on a GQA config (rep 4):
+    the two run the same attention helper at other query counts."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import (
+        LlamaConfig, decode_step_paged, init_paged_kv_cache, init_params,
+        verify_kv_paged,
+    )
+
+    config = LlamaConfig.tiny(n_heads=8, n_kv_heads=2)
+    params = init_params(config, jax.random.key(4))
+    B, bs, K, start = 2, 4, 4, 5
+    tables = jnp.asarray([[3, 6, 1, 8], [0, 5, 9, 2]], jnp.int32)
+    step = jax.jit(lambda pl, t, p: decode_step_paged(
+        params, pl, tables, t, p, config))
+    verify = jax.jit(lambda pl, t, p: verify_kv_paged(
+        params, pl, tables, t, p, config))
+    rng = np.random.RandomState(5)
+    toks = jnp.asarray(
+        rng.randint(0, config.vocab_size, (B, start + K)), jnp.int32)
+    pools = init_paged_kv_cache(config, num_blocks=12, block_size=bs)
+    for i in range(start):              # rows start at different lengths
+        pos = jnp.asarray([i, i + 2], jnp.int32)
+        _, pools = step(pools, toks[:, i], pos)
+    base = jnp.asarray([start, start + 2], jnp.int32)
+    v_logits, v_pools = verify(pools, toks[:, start:], base)
+    for j in range(K):
+        s_logits, pools = step(pools, toks[:, start + j], base + j)
+        np.testing.assert_array_equal(
+            np.argmax(np.asarray(s_logits), -1),
+            np.argmax(np.asarray(v_logits[:, j]), -1))
+        np.testing.assert_allclose(np.asarray(s_logits),
+                                   np.asarray(v_logits[:, j]),
+                                   rtol=2e-2, atol=2e-2)
+    for name in ("k", "v"):
+        np.testing.assert_allclose(
+            np.asarray(pools[name], np.float32),
+            np.asarray(v_pools[name], np.float32), rtol=2e-2, atol=2e-2)
