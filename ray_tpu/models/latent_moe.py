@@ -1,0 +1,415 @@
+"""Latent-attention decoder with dropless sigmoid-routed experts.
+
+The block of the DeepSeek-V3 family as published (`model_type`
+`deepseek_v3`, no query compression), written once over a cache
+interface:
+
+- **Latent attention (MLA).** A token's key/value state is ONE row per
+  layer, `c ‖ k_rope`: the normed latent (`kv_lora_rank` wide) and one
+  rotary key shared by all heads, after rotary.  Two forms of the same
+  function read it:
+  the *expanded* form rebuilds per-head keys and values from the latent
+  (`c @ wkv_b`: prefill, many queries a key), the *absorbed* form folds
+  `wkv_b` into the query and the output instead (decode: one query, the
+  rows are read as they lie; multi-query attention over one wide head).
+  Rotary pairs are the published interleaved ones, (x[2i], x[2i+1]) at
+  frequency i; the program stores q_rope and k_rope de-interleaved
+  (evens, then odds), which leaves every score as it was because both
+  sides are laid out alike.
+- **Layers.** `n_dense_layers` leading SwiGLU layers, then expert
+  layers: `models/moe.py::dropless_moe` under the sigmoid-with-bias
+  routing rule, plus shared experts every token passes through.
+  Parameters are a LIST of per-layer dicts and the layer loop is
+  unrolled: a layer's expert weights are then buffers of their own that
+  the grouped products read in place (a `lax.scan` over stacked weights
+  copies each layer's slice out first), and the paged pool is written
+  and gathered at a static layer index, in place.
+- **One definition of a layer** (`_layer`) over three caches: none
+  (`forward`: scoring and tests), history + write-back (`prefill_paged`:
+  bucketed and chunked prefill with a prefix history), paged decode
+  (`decode_step_paged`).
+
+Every size comes from `LatentMoEConfig`; there is no knob beside it.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, List, Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.models.llama import embed_lookup, rms_norm
+from ray_tpu.models.moe import dropless_moe, sigmoid_bias_top_k
+from ray_tpu.models.serving import ServingFns
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentMoEConfig:
+    vocab_size: int = 128256
+    dim: int = 2048
+    n_layers: int = 48
+    n_dense_layers: int = 1
+    n_heads: int = 32
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    dense_hidden_dim: int = 6144
+    expert_hidden_dim: int = 768
+    n_experts: int = 128
+    top_k: int = 6
+    n_shared_experts: int = 2
+    routed_scaling_factor: float = 2.448
+    max_seq_len: int = 32768
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16   # activation/matmul dtype
+    param_dtype: Any = jnp.bfloat16
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def cache_row(self) -> int:
+        """Width of a cache row: latent ‖ rotary key, then zeros up to a
+        whole number of the chip's 128-lane tiles.  The tiled layout pads
+        the minor dimension to that anyway; a pool declared 576 wide made
+        the v5e compiler turn it round (blocks minor-most) and copy it
+        whole on the way in and out of every tick."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers
+
+    @staticmethod
+    def tiny(**overrides) -> "LatentMoEConfig":
+        """Test-size config: runs on the CPU in milliseconds."""
+        return LatentMoEConfig(**{**dict(
+            vocab_size=512, dim=64, n_layers=3, n_dense_layers=1, n_heads=4,
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, dense_hidden_dim=128, expert_hidden_dim=32,
+            n_experts=8, top_k=2, n_shared_experts=2, max_seq_len=128),
+            **overrides})
+
+    def serving(self):
+        """This model's functions for `serve/llm/engine.py`
+        (models/serving.py)."""
+        return _SERVING
+
+
+# ---------------------------------------------------------------------------
+# Init
+# ---------------------------------------------------------------------------
+
+def init_params(config: LatentMoEConfig, key: jax.Array,
+                bias_scale: float = 0.01) -> Dict[str, Any]:
+    """normal(0, 0.02) matrices, unit norms, and a selection bias drawn
+    at `bias_scale` (a trained checkpoint's is not zero)."""
+    c = config
+    dt = c.param_dtype
+    H, D = c.n_heads, c.dim
+    k_embed, k_out, k_layers = jax.random.split(key, 3)
+
+    def draw(key, *shape):
+        return jax.nn.initializers.normal(0.02)(key, shape, dt)
+
+    layers: List[Dict[str, jax.Array]] = []
+    for i, lk in enumerate(jax.random.split(k_layers, c.n_layers)):
+        ks = jax.random.split(lk, 12)
+        p = {
+            "attn_norm": jnp.ones((D,), dt),
+            "wq": draw(ks[0], D, H * c.qk_head_dim),
+            "wkv_a": draw(ks[1], D, c.kv_lora_rank + c.qk_rope_head_dim),
+            "kv_norm": jnp.ones((c.kv_lora_rank,), dt),
+            "wkv_b": draw(ks[2], c.kv_lora_rank,
+                          H * (c.qk_nope_head_dim + c.v_head_dim)),
+            "wo": draw(ks[3], H * c.v_head_dim, D),
+            "ffn_norm": jnp.ones((D,), dt),
+        }
+        if i < c.n_dense_layers:
+            F = c.dense_hidden_dim
+            p.update(w_gate=draw(ks[4], D, F), w_up=draw(ks[5], D, F),
+                     w_down=draw(ks[6], F, D))
+        else:
+            E, F = c.n_experts, c.expert_hidden_dim
+            Fs = c.n_shared_experts * F
+            p.update(
+                router=draw(ks[4], D, E),
+                router_bias=jax.random.normal(ks[5], (E,), jnp.float32)
+                * bias_scale,
+                w_gate=draw(ks[6], E, D, F), w_up=draw(ks[7], E, D, F),
+                w_down=draw(ks[8], E, F, D),
+                ws_gate=draw(ks[9], D, Fs), ws_up=draw(ks[10], D, Fs),
+                ws_down=draw(ks[11], Fs, D))
+        layers.append(p)
+    return {"embed": draw(k_embed, c.vocab_size, D), "layers": layers,
+            "norm_f": jnp.ones((D,), dt),
+            "lm_head": draw(k_out, D, c.vocab_size)}
+
+
+def lm_head_weight(params: Dict[str, Any], config: LatentMoEConfig):
+    return params["lm_head"].astype(config.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention: one row a token, two forms of one function
+# ---------------------------------------------------------------------------
+
+def _rope_interleaved(x: jax.Array, cos: jax.Array, sin: jax.Array):
+    """x [..., r] with pairs (x[2i], x[2i+1]); cos/sin broadcast against
+    [..., r/2].  Returns the rotated pairs de-interleaved: all first
+    elements, then all second ones."""
+    x = x.astype(jnp.float32)
+    a, b = x[..., 0::2], x[..., 1::2]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def _masked_softmax(scores, qpos, n_keys, dtype):
+    """scores [B, H, Q, K] float32; query j of row b sees keys at
+    positions <= qpos[b, j]."""
+    mask = jnp.arange(n_keys)[None, None, None, :] \
+        <= qpos[:, None, :, None]
+    return jax.nn.softmax(jnp.where(mask, scores, -1e30),
+                          axis=-1).astype(dtype)
+
+
+def attend_expanded(c: LatentMoEConfig, wkv_b, q_nope, q_rope, rows, qpos):
+    """Prefill form.  q_nope [B, Q, H, n], q_rope [B, Q, H, r]; cache
+    rows [B, K, cache_row].  Per-head keys and values are rebuilt from
+    the latent.  Returns [B, Q, H * v]."""
+    B, K, _ = rows.shape
+    n, v, rank = c.qk_nope_head_dim, c.v_head_dim, c.kv_lora_rank
+    r = c.qk_rope_head_dim
+    kv = (rows[..., :rank] @ wkv_b).reshape(B, K, c.n_heads, n + v)
+    scores = (jnp.einsum("bqhn,bkhn->bhqk", q_nope, kv[..., :n])
+              + jnp.einsum("bqhr,bkr->bhqk", q_rope,
+                           rows[..., rank:rank + r])
+              ).astype(jnp.float32) * (1.0 / math.sqrt(c.qk_head_dim))
+    probs = _masked_softmax(scores, qpos, K, q_nope.dtype)
+    out = jnp.einsum("bhqk,bkhv->bqhv", probs, kv[..., n:])
+    return out.reshape(B, -1, c.n_heads * v)
+
+
+def attend_absorbed(c: LatentMoEConfig, wkv_b, q_nope, q_rope, rows, qpos):
+    """Decode form: `wkv_b`'s key part goes into the query and its value
+    part onto the output, so the rows are read as they lie: multi-query
+    attention with ONE key/value head a row wide and all H query heads
+    on it.  The weighted sum runs over whole rows and the columns past
+    the latent are dropped from its result: a quarter more
+    multiply-adds, and no copy of the rows without them."""
+    B, K, row = rows.shape
+    n, v, rank = c.qk_nope_head_dim, c.v_head_dim, c.kv_lora_rank
+    w = wkv_b.reshape(rank, c.n_heads, n + v)
+    q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, w[..., :n])
+    q_row = jnp.concatenate(
+        [q_lat, q_rope, jnp.zeros(q_lat.shape[:-1] + (
+            row - rank - c.qk_rope_head_dim,), q_lat.dtype)], axis=-1)
+    scores = jnp.einsum("bqhr,bkr->bhqk", q_row, rows).astype(
+        jnp.float32) * (1.0 / math.sqrt(c.qk_head_dim))
+    probs = _masked_softmax(scores, qpos, K, q_nope.dtype)
+    o_lat = jnp.einsum("bhqk,bkr->bqhr", probs, rows)[..., :rank]
+    out = jnp.einsum("bqhr,rhv->bqhv", o_lat, w[..., n:])
+    return out.reshape(B, -1, c.n_heads * v)
+
+
+# ---------------------------------------------------------------------------
+# The cache interface: where a layer's new rows go and which rows its
+# queries see.  `update(l, new [B, S, cache_row])` -> rows [B, K, cache_row].
+# ---------------------------------------------------------------------------
+
+class _NoCache:
+    """The sequence's own rows are its keys (scoring, tests)."""
+    attend = staticmethod(attend_expanded)
+
+    def update(self, l, new):
+        return new
+
+
+class _History:
+    """One sequence with a gathered history `hist` [L, S_pad, cache_row]:
+    the new rows land at `start` of the layer's history, and are kept
+    for the engine to scatter into the pool."""
+    attend = staticmethod(attend_expanded)
+
+    def __init__(self, hist, start):
+        self.hist, self.start = hist, start
+        self.rows: List[jax.Array] = []
+
+    def update(self, l, new):
+        self.rows.append(new[0].astype(self.hist.dtype))
+        return lax.dynamic_update_slice(
+            self.hist[l], self.rows[-1], (self.start, 0))[None].astype(
+                new.dtype)
+
+
+class _PagedDecode:
+    """One new row a sequence: written into the pool at its block-table
+    position, then every sequence's rows gathered through the tables."""
+    attend = staticmethod(attend_absorbed)
+
+    def __init__(self, pool, tables, phys, off):
+        self.pool = pool
+        self.tables, self.phys, self.off = tables, phys, off
+
+    def update(self, l, new):
+        B, nb = self.tables.shape
+        pool = self.pool
+        with jax.named_scope("kv_write"):
+            pool = pool.at[l, self.phys, self.off].set(
+                new[:, 0].astype(pool.dtype))
+        with jax.named_scope("kv_gather"):
+            rows = pool[l, self.tables].reshape(
+                B, nb * pool.shape[2], pool.shape[3]).astype(new.dtype)
+        self.pool = pool
+        return rows
+
+
+# ---------------------------------------------------------------------------
+# One layer, one stack
+# ---------------------------------------------------------------------------
+
+def _swiglu(h, w_gate, w_up, w_down, dt):
+    return (jax.nn.silu(h @ w_gate.astype(dt)) * (h @ w_up.astype(dt))) \
+        @ w_down.astype(dt)
+
+
+def _layer(c: LatentMoEConfig, l: int, p, x, qpos, cos, sin, cache,
+           live=None):
+    """x [B, S, D] at absolute positions qpos [B, S]; cos/sin
+    [B, S, r/2].  Returns (x, tokens routed to each expert or None)."""
+    B, S, D = x.shape
+    dt, H = c.dtype, c.n_heads
+    n, r, rank = c.qk_nope_head_dim, c.qk_rope_head_dim, c.kv_lora_rank
+    with jax.named_scope("attn"):
+        h = rms_norm(x, p["attn_norm"], c.norm_eps)
+        q = (h @ p["wq"].astype(dt)).reshape(B, S, H, n + r)
+        q_rope = _rope_interleaved(q[..., n:], cos[:, :, None],
+                                   sin[:, :, None]).astype(dt)
+        ckr = h @ p["wkv_a"].astype(dt)
+        new = jnp.concatenate(
+            [rms_norm(ckr[..., :rank], p["kv_norm"], c.norm_eps),
+             _rope_interleaved(ckr[..., rank:], cos, sin).astype(dt),
+             jnp.zeros((B, S, c.cache_row - rank - r), dt)], -1)
+    rows = cache.update(l, new)
+    with jax.named_scope("attn"):
+        attn = cache.attend(c, p["wkv_b"].astype(dt), q[..., :n], q_rope,
+                            rows, qpos)
+        x = x + attn @ p["wo"].astype(dt)
+    if "router" not in p:
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, p["ffn_norm"], c.norm_eps)
+            return x + _swiglu(h, p["w_gate"], p["w_up"], p["w_down"],
+                               dt), None
+    with jax.named_scope("moe"):
+        h = rms_norm(x, p["ffn_norm"], c.norm_eps)
+        y, sizes = dropless_moe(
+            h.reshape(B * S, D), p,
+            sigmoid_bias_top_k(c.top_k, c.routed_scaling_factor),
+            live=None if live is None else live.reshape(B * S))
+        with jax.named_scope("shared"):
+            y = y.reshape(B, S, D) + _swiglu(
+                h, p["ws_gate"], p["ws_up"], p["ws_down"], dt)
+        return x + y, sizes
+
+
+def _stack(c: LatentMoEConfig, params, tokens, qpos, cache, live=None):
+    """Embedding, every layer, final norm: tokens [B, S] at qpos [B, S]
+    -> (normed hidden [B, S, D], routed tokens [n_moe_layers, E])."""
+    r = c.qk_rope_head_dim
+    inv = 1.0 / (c.rope_theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r))
+    freqs = qpos.astype(jnp.float32)[..., None] * inv       # [B, S, r/2]
+    cos, sin = jnp.cos(freqs), jnp.sin(freqs)
+    x = embed_lookup(params["embed"].astype(c.dtype), tokens)
+    routed = []
+    for l, p in enumerate(params["layers"]):
+        x, sizes = _layer(c, l, p, x, qpos, cos, sin, cache, live)
+        if sizes is not None:
+            routed.append(sizes)
+    return rms_norm(x, params["norm_f"], c.norm_eps), jnp.stack(routed)
+
+
+def _head(c, params, x):
+    with jax.named_scope("lm_head"):
+        return lax.dot_general(
+            x, lm_head_weight(params, c),
+            (((x.ndim - 1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+
+def forward(params: Dict[str, Any], tokens: jax.Array,
+            config: LatentMoEConfig) -> jax.Array:
+    """tokens [B, S] -> logits [B, S, V] float32; no cache."""
+    B, S = tokens.shape
+    qpos = jnp.broadcast_to(jnp.arange(S), (B, S))
+    x, _ = _stack(config, params, tokens, qpos, _NoCache())
+    return _head(config, params, x)
+
+
+# ---------------------------------------------------------------------------
+# The paged cache: one row of latent ‖ rotary key a token a layer
+# ---------------------------------------------------------------------------
+
+def init_paged_pool(config: LatentMoEConfig, num_blocks: int,
+                    block_size: int) -> Dict[str, jax.Array]:
+    c = config
+    return {"latent": jnp.zeros(
+        (c.n_layers, num_blocks, block_size, c.cache_row), c.dtype)}
+
+
+def prefill_paged(params, tokens, start, hist, config: LatentMoEConfig,
+                  n_real):
+    """Suffix prefill of ONE sequence with history (models/serving.py):
+    tokens [1, Pb] at start..start+Pb-1, of which the first `n_real`
+    are real (padding goes through no expert)."""
+    Pb = tokens.shape[1]
+    qpos = (start + jnp.arange(Pb))[None]
+    cache = _History(hist["latent"], start)
+    x, _ = _stack(config, params, tokens, qpos, cache,
+                  live=(jnp.arange(Pb) < n_real)[None])
+    return x, {"latent": jnp.stack(cache.rows)}
+
+
+def decode_step_paged(params, pools, tables, tokens, positions,
+                      config: LatentMoEConfig,
+                      active: Optional[jax.Array] = None):
+    """One token a sequence against the paged pool (models/serving.py):
+    tokens [B] at positions [B], tables [B, max_blocks].  A dead slot
+    writes out of bounds (dropped) and goes through no expert.  Returns
+    (logits [B, V], pools, counts): tokens routed to each expert of each
+    expert layer, the distinct experts touched summed over those layers,
+    and 1 for the tick."""
+    pool = pools["latent"]
+    bs = pool.shape[2]
+    B = tokens.shape[0]
+    phys = tables[jnp.arange(B), positions // bs]
+    if active is not None:
+        phys = jnp.where(active, phys, pool.shape[1])
+    cache = _PagedDecode(pool, tables, phys, positions % bs)
+    x, routed = _stack(config, params, tokens[:, None], positions[:, None],
+                       cache, live=None if active is None
+                       else active[:, None])
+    counts = {"expert_tokens": routed,
+              "experts_touched": jnp.sum(routed > 0, dtype=jnp.int32),
+              "ticks": jnp.ones((), jnp.int32)}
+    return _head(config, params, x[:, 0]), {"latent": cache.pool}, counts
+
+
+def init_counts(config: LatentMoEConfig) -> Dict[str, jax.Array]:
+    """Zeros of what `decode_step_paged` counts."""
+    return {"expert_tokens": jnp.zeros(
+                (config.n_moe_layers, config.n_experts), jnp.int32),
+            "experts_touched": jnp.zeros((), jnp.int32),
+            "ticks": jnp.zeros((), jnp.int32)}
+
+
+_SERVING = ServingFns(
+    name="latent attention + dropless experts (models/latent_moe.py)",
+    init_params=init_params, init_pool=init_paged_pool,
+    prefill=prefill_paged, decode=decode_step_paged,
+    head_weight=lm_head_weight, init_counts=init_counts)

@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.models.serving import DenseFns, ServingFns
+
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
@@ -89,6 +91,11 @@ class LlamaConfig:
                      + 2 * d)                                      # norms
         out_head = 0 if self.tie_embeddings else d * v
         return v * d + self.n_layers * per_layer + d + out_head
+
+    def serving(self):
+        """This model's functions for `serve/llm/engine.py`
+        (models/serving.py)."""
+        return _SERVING
 
 
 # ---------------------------------------------------------------------------
@@ -911,3 +918,30 @@ def generate(params: Dict[str, Any], prompt: jax.Array,
     (_, _, _), toks = lax.scan(
         body, (cache, logits, rng), jnp.arange(max_new_tokens))
     return toks.T  # [B, max_new_tokens]
+
+
+# ---------------------------------------------------------------------------
+# The serving engine's view of this model (models/serving.py)
+# ---------------------------------------------------------------------------
+
+def _serve_prefill(params, tokens, start, hist, config, n_real):
+    del n_real              # padding rows are masked as keys, not skipped
+    x, ks, vs = prefill_kv_paged(params, tokens, start, hist["k"],
+                                 hist["v"], config)
+    return x, {"k": ks[:, 0].astype(config.dtype),
+               "v": vs[:, 0].astype(config.dtype)}
+
+
+def _serve_decode(params, pools, tables, tok, pos, config, active):
+    logits, pools = decode_step_paged(params, pools, tables, tok, pos,
+                                      config, active=active)
+    return logits, pools, {}
+
+
+_SERVING = ServingFns(
+    name="dense decoder (models/llama.py)",
+    init_params=init_params, init_pool=init_paged_kv_cache,
+    prefill=_serve_prefill, decode=_serve_decode,
+    head_weight=lm_head_weight, quantize_int8=quantize_weights_int8,
+    dense=DenseFns(init_kv_cache, prefill_kv, decode_step),
+    verify=verify_kv_paged)

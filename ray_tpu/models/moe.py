@@ -15,12 +15,21 @@ automatically. No hand-written collectives; the mesh does EP.
 Sharding recipe (see `moe_param_specs`): experts [E, ...] sharded
 P("expert", ...); token tensors data-sharded; jit with those out/in
 shardings and GSPMD places dispatch/combine all-to-alls on the ICI ring.
+
+Two expert layers live here.  `dropless_moe` is THE dropless layer: the
+assignments are sorted by expert and three grouped matrix products
+(`jax.lax.ragged_dot`) run over the experts, so every token gets all of
+its experts whatever the load and an expert nobody picked is not read.
+Its routing rule is an argument (`softmax_top_k`, `sigmoid_bias_top_k`).
+`moe_layer` below is the older capacity-dispatch layer that
+`LlamaConfig.n_experts` trains with; it DROPS tokens past an expert's
+capacity and is due for folding into the dropless one (ROADMAP Design).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -136,3 +145,83 @@ def moe_layer(x: jax.Array, params: Dict[str, jax.Array], cfg: MoEConfig
                             params["w_down"].astype(cfg.dtype))
     out = jnp.einsum("tec,ecd->td", combine, expert_out)
     return out.reshape(B, S, D), aux
+
+
+# ---------------------------------------------------------------------------
+# The dropless layer: sort by expert, grouped matrix products, unsort
+# ---------------------------------------------------------------------------
+
+Routing = Callable[[jax.Array, Dict[str, jax.Array]],
+                   Tuple[jax.Array, jax.Array]]
+
+
+def softmax_top_k(k: int) -> Routing:
+    """The k largest softmax probabilities, as they are."""
+
+    def route(logits, params):
+        w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        return idx, w
+
+    return route
+
+
+def sigmoid_bias_top_k(k: int, scale: float = 1.0) -> Routing:
+    """Sigmoid scores; the k experts with the largest score PLUS the
+    selection bias (`router_bias`, a buffer, not a weight) are chosen,
+    and weighted by their scores WITHOUT it, renormalised to sum to
+    `scale` (the `noaux_tc` rule with one group)."""
+
+    def route(logits, params):
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(
+            s + params["router_bias"].astype(jnp.float32), k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        return idx, w / (w.sum(-1, keepdims=True) + 1e-20) * scale
+
+    return route
+
+
+def dropless_moe(x: jax.Array, params: Dict[str, jax.Array],
+                 routing: Routing, live: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """x [T, D] -> (y [T, D], tokens routed to each expert [E] int32).
+
+    params: `router` [D, E], what `routing` reads beside it, and the
+    experts' SwiGLU weights `w_gate` / `w_up` [E, D, F], `w_down`
+    [E, F, D].  Router logits, scores and selection are float32 (a
+    float32 matrix product, not the chip's one-pass default); the expert
+    products run in x's dtype.  No capacity: the T * k assignments are
+    sorted by expert, each expert's rows are one group of three grouped
+    products, and the results go back to their tokens by the inverse
+    permutation.  `live` [T] bool takes rows out altogether (padding, a
+    dead decode slot): they are in no group, add nothing to the counts
+    and get y = 0, so an expert only they picked is not read."""
+    T, D = x.shape
+    E = params["router"].shape[-1]
+    with jax.named_scope("router"):
+        logits = jnp.dot(x.astype(jnp.float32),
+                         params["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        idx, w = routing(logits, params)                    # [T, k]
+        if live is not None:
+            idx = jnp.where(live[:, None], idx, E)          # sorts last
+            w = jnp.where(live[:, None], w, 0.0)
+    k = idx.shape[-1]
+    with jax.named_scope("experts"):
+        flat = idx.reshape(T * k)
+        order = jnp.argsort(flat)                           # stable
+        sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
+        xs = x[order // k]                                  # [T * k, D]
+        dt = x.dtype
+        gate = jax.lax.ragged_dot(xs, params["w_gate"].astype(dt), sizes)
+        up = jax.lax.ragged_dot(xs, params["w_up"].astype(dt), sizes)
+        ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up,
+                                params["w_down"].astype(dt), sizes)
+        # rows past the last group belong to no expert: whatever the
+        # grouped product left there must not reach a token
+        ys = jnp.where((jnp.arange(T * k) < sizes.sum())[:, None], ys, 0)
+        back = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32))
+        y = jnp.einsum("tkd,tk->td",
+                       ys[back].reshape(T, k, D).astype(jnp.float32), w)
+    return y.astype(dt), sizes

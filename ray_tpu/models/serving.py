@@ -1,0 +1,51 @@
+"""What a model hands the serving engine: its functions over a paged
+cache, reached through its config object (`config.serving()`), so that
+`serve/llm/engine.py` names no model module.
+
+The pool is a small tree (a flat dict) of leaves `[L, NB, bs, ...]` that
+the model names: `k` and `v` rows per KV head for the dense decoder,
+one `latent` row (latent ‖ rotary key) for latent attention.  The engine
+and the cache manager move whole blocks of every leaf (insert scatter,
+export, adopt, spill, promote) and never look inside a row.
+
+    init_pool(config, num_blocks, block_size) -> {leaf: [L, NB, bs, ...]}
+    prefill(params, tokens [1, Pb], start, hist, config, n_real)
+        -> (normed hidden [1, Pb, D], rows {leaf: [L, Pb, ...]})
+        `hist` {leaf: [L, S_pad, ...]} is the slot's gathered history
+        (rows >= start are stale); the Pb tokens sit at start..; only
+        the first `n_real` of them are real.
+    decode(params, pools, tables, tok [B], pos [B], config, active)
+        -> (logits [B, V], pools, counts)
+        `counts` is a dict of per-call integer counters that the engine
+        sums on the device, from `init_counts(config)`'s zeros, and
+        shows under `stats()["counters"]` (empty, and `init_counts`
+        None, for a model that counts nothing).
+    head_weight(params, config) -> [D, V]
+    init_params(config, key), and what only some models have (None
+    where a model has none, and the engine refuses by name):
+    quantize_int8(params), dense (init_cache, prefill, decode: the
+    dense layout and the speculative draft), verify (speculation).
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable, NamedTuple, Optional
+
+
+class DenseFns(NamedTuple):
+    init_cache: Callable[..., Any]
+    prefill: Callable[..., Any]
+    decode: Callable[..., Any]
+
+
+class ServingFns(NamedTuple):
+    name: str
+    init_params: Callable[..., Any]
+    init_pool: Callable[..., Any]
+    prefill: Callable[..., Any]
+    decode: Callable[..., Any]
+    head_weight: Callable[..., Any]
+    init_counts: Optional[Callable[..., Any]] = None
+    quantize_int8: Optional[Callable[..., Any]] = None
+    dense: Optional[DenseFns] = None
+    verify: Optional[Callable[..., Any]] = None

@@ -44,9 +44,7 @@ class LLMServer:
                  speculative: Any = None):
         import jax
 
-        from ray_tpu.models.llama import (
-            LlamaConfig, init_params, quantize_weights_int8,
-        )
+        from ray_tpu.models.llama import LlamaConfig
         from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
 
         if model_config is None:
@@ -64,13 +62,19 @@ class LLMServer:
             raise ValueError(
                 f"quantize must be 'int8' or 'bf16', got {quantize!r}")
         self.quantize = quantize
+        model = model_config.serving()
+        if quantize == "int8" and model.quantize_int8 is None:
+            raise ValueError(
+                f"{model.name} has no int8 weight-only path: pass "
+                f"quantize='bf16'")
 
         if params_loader is not None:
             params = params_loader()
         else:
-            params = init_params(model_config, jax.random.key(init_seed))
+            params = model.init_params(model_config,
+                                       jax.random.key(init_seed))
         if quantize == "int8":
-            params = quantize_weights_int8(params)
+            params = model.quantize_int8(params)
 
         # Speculative decoding (disagg/spec.py): ``speculative`` is
         # True (default draft geometry), a dict of draft kwargs

@@ -280,6 +280,11 @@ class RequestHandle:
     def done(self) -> bool:
         return self._done.is_set()
 
+    @property
+    def engine(self) -> Optional["LLMEngine"]:
+        """The engine this request was submitted to (its `stats()`)."""
+        return self._engine
+
     def cancel(self) -> bool:
         """Cancel the request: queued handles finish immediately with
         finish_reason "cancelled"; a handle live in a decode slot is
@@ -324,7 +329,9 @@ class _Slot:
 
 
 class LLMEngine:
-    """Slot-based continuous-batching engine over a Llama param set.
+    """Slot-based continuous-batching engine over one model's
+    parameters; the model's functions come from its config object
+    (`model_config.serving()`, models/serving.py).
 
     Host-side scheduler + two families of jitted device programs
     (`_insert` per prefill bucket, `_tick` for the decode step). Thread
@@ -343,7 +350,6 @@ class LLMEngine:
         import numpy as np
 
         from ray_tpu._private import compile_cache
-        from ray_tpu.models.llama import init_kv_cache, init_paged_kv_cache
 
         compile_cache.configure()      # before this engine's first compile
         self.params = params
@@ -351,6 +357,16 @@ class LLMEngine:
         self.config = engine_config or EngineConfig()
         c = self.config
         B = c.num_slots
+        # The model's own functions over its cache (models/serving.py):
+        # the engine names no model module.
+        self._model = model = model_config.serving()
+        if c.kv_layout == "dense" and model.dense is None:
+            raise ValueError(
+                f"{model.name} is served through kv_layout='paged' "
+                f"only: it has no dense-layout cache")
+        if draft_params is not None and model.verify is None:
+            raise ValueError(
+                f"{model.name} has no speculative verify step")
 
         # Device state (fixed shapes for the engine's whole lifetime).
         self._paged = c.kv_layout == "paged"
@@ -360,13 +376,16 @@ class LLMEngine:
                                                     PrefixCache,
                                                     PromoteCostModel)
 
-            self._cache = init_paged_kv_cache(
+            # The pool: a flat dict of [L, NB, bs, ...] leaves that the
+            # model names; the engine moves whole blocks of every leaf
+            # and never looks inside a row.
+            self._cache = model.init_pool(
                 model_config, c.pool_blocks, c.kv_block_size)
-            # HBM bytes per block (k + v rows across all layers) — the
-            # byte-accounting basis for allocator/prefix/tier stats.
-            block_bytes = int(
-                (self._cache["k"].nbytes + self._cache["v"].nbytes)
-                // self._cache["k"].shape[1])
+            # HBM bytes per block (every leaf's rows across all layers)
+            # — the byte-accounting basis for allocator/prefix/tier
+            # stats.
+            block_bytes = sum(int(x.nbytes) for x in
+                              self._cache.values()) // c.pool_blocks
             self._allocator = BlockAllocator(c.pool_blocks,
                                              c.kv_block_size,
                                              block_bytes=block_bytes)
@@ -392,13 +411,19 @@ class LLMEngine:
                     put_fn=_tier_store_put, get_fn=_tier_store_get)
                 self._prefix.spill_fn = self._spill_evicted
         else:
-            self._cache = init_kv_cache(model_config, B, c.max_seq_len)
+            self._cache = model.dense.init_cache(model_config, B,
+                                                 c.max_seq_len)
             self._allocator = None
             self._prefix = None
             self._tiers = None
         self._tok = jnp.zeros((B,), jnp.int32)
         self._pos = jnp.zeros((B,), jnp.int32)
         self._key = jax.random.key(rng_seed)
+        # What the model's decode step counts (models/serving.py),
+        # summed on the device tick by tick; `stats()` reads it. Not
+        # donated: `stats()` may read it from another thread.
+        self._counters = (model.init_counts(model_config)
+                          if self._paged and model.init_counts else {})
         # Host-side mirrors fed into each program call (tiny transfers).
         self._active = np.zeros((B,), bool)
         self._temp = np.zeros((B,), np.float32)
@@ -456,8 +481,9 @@ class LLMEngine:
             if draft_config is None:
                 raise ValueError("draft_params given without "
                                  "draft_config")
-            self._draft_cache = init_kv_cache(draft_config, B,
-                                              c.max_seq_len)
+            self._draft_model = draft_config.serving().dense
+            self._draft_cache = self._draft_model.init_cache(
+                draft_config, B, c.max_seq_len)
 
         # Compile tracking through the shared telemetry plane: the
         # TrackedJit probe runs ONLY when jax traces a new program, so
@@ -527,8 +553,7 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models.llama import decode_step
-
+        decode_step = self._model.dense.decode
         S = self.config.max_seq_len
 
         def body(carry, _):
@@ -556,10 +581,9 @@ class LLMEngine:
         import jax.numpy as jnp
         from jax import lax
 
-        from ray_tpu.models.llama import lm_head_weight, prefill_kv
-
         c = self.model_config
-        hidden, ks, vs = prefill_kv(params, padded_prompt[None], c)
+        hidden, ks, vs = self._model.dense.prefill(
+            params, padded_prompt[None], c)
         # ks/vs: [L, 1, Pb, n_kv, hd] -> rows [0, Pb) of this slot.
         cache = {
             "k": lax.dynamic_update_slice(
@@ -570,7 +594,7 @@ class LLMEngine:
         x_last = lax.dynamic_index_in_dim(
             hidden[0], prompt_len - 1, axis=0, keepdims=False)
         logits = jax.lax.dot_general(
-            x_last[None], lm_head_weight(params, c),
+            x_last[None], self._model.head_weight(params, c),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)       # [1, V]
         key, sub = jax.random.split(key)
@@ -580,33 +604,35 @@ class LLMEngine:
         return cache, tok, pos, key
 
     def _tick_fn_paged(self, params, pools, tables, tok, pos, active,
-                       temp, key):
+                       temp, key, counters=None):
         """Paged twin of `_tick_fn`: same scan, same sampling, but the
         KV write/read goes through the block tables (data, so still ONE
-        compiled program regardless of who owns which block)."""
+        compiled program regardless of who owns which block). What the
+        model's step counts is added to `counters` (an empty tree for a
+        model that counts nothing: no operation, no argument)."""
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models.llama import decode_step_paged
-
+        decode = self._model.decode
         S = self.config.max_seq_len
 
         def body(carry, _):
-            pools, tok, pos, key = carry
-            logits, pools = decode_step_paged(
+            pools, tok, pos, key, counters = carry
+            logits, pools, counts = decode(
                 params, pools, tables, tok, pos, self.model_config,
-                active=active)
+                active)
+            counters = jax.tree.map(jnp.add, counters, counts)
             with jax.named_scope("sample"):
                 key, sub = jax.random.split(key)
                 nxt = _sample(logits, temp, sub)
                 tok = jnp.where(active, nxt, tok)
                 pos = jnp.where(active, jnp.minimum(pos + 1, S - 1), pos)
-            return (pools, tok, pos, key), tok
+            return (pools, tok, pos, key, counters), tok
 
-        (pools, tok, pos, key), toks = jax.lax.scan(
-            body, (pools, tok, pos, key), None,
+        (pools, tok, pos, key, counters), toks = jax.lax.scan(
+            body, (pools, tok, pos, key, counters or {}), None,
             length=self.config.decode_block)
-        return pools, tok, pos, key, toks          # toks: [K, B]
+        return pools, tok, pos, key, toks, counters   # toks: [K, B]
 
     def _insert_fn_paged(self, params, pools, tok, pos, table_row,
                          hist_len, padded_suffix, suffix_len,
@@ -624,34 +650,30 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models.llama import lm_head_weight, prefill_kv_paged
-
         c = self.model_config
         bs = self.config.kv_block_size
-        L = pools["k"].shape[0]
-        n_kv, hd = pools["k"].shape[3], pools["k"].shape[4]
         S_pad = self.config.max_blocks_per_slot * bs
         Pb = padded_suffix.shape[0]
-        # History view: this slot's dense [S_pad] gather. Rows at and
-        # past hist_len are stale — masked inside prefill_kv_paged.
-        hist_k = pools["k"][:, table_row].reshape(L, S_pad, n_kv, hd)
-        hist_v = pools["v"][:, table_row].reshape(L, S_pad, n_kv, hd)
-        hidden, ks, vs = prefill_kv_paged(
-            params, padded_suffix[None], hist_len, hist_k, hist_v, c)
-        # ks/vs: [L, 1, Pb, n_kv, hd] -> whole blocks into the pool at
+        # History view: this slot's dense [S_pad] gather of every leaf.
+        # Rows at and past hist_len are stale — masked inside the
+        # model's prefill.
+        hist = {name: pool[:, table_row].reshape(
+            (pool.shape[0], S_pad) + pool.shape[3:])
+            for name, pool in pools.items()}
+        hidden, rows = self._model.prefill(
+            params, padded_suffix[None], hist_len, hist, c, suffix_len)
+        # rows: {leaf: [L, Pb, ...]} -> whole blocks into the pool at
         # the slot's new physical ids (padding rows ride along; decode
         # overwrites each before attending, exactly like the dense path
         # tolerates stale rows).
-        kb = ks[:, 0].astype(c.dtype).reshape(L, Pb // bs, bs, n_kv, hd)
-        vb = vs[:, 0].astype(c.dtype).reshape(L, Pb // bs, bs, n_kv, hd)
-        pools = {
-            "k": pools["k"].at[:, new_block_ids].set(kb),
-            "v": pools["v"].at[:, new_block_ids].set(vb),
-        }
+        pools = {name: pool.at[:, new_block_ids].set(
+            rows[name].astype(pool.dtype).reshape(
+                (pool.shape[0], Pb // bs, bs) + pool.shape[3:]))
+            for name, pool in pools.items()}
         x_last = jax.lax.dynamic_index_in_dim(
             hidden[0], suffix_len - 1, axis=0, keepdims=False)
         logits = jax.lax.dot_general(
-            x_last[None], lm_head_weight(params, c),
+            x_last[None], self._model.head_weight(params, c),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)       # [1, V]
         key, sub = jax.random.split(key)
@@ -661,23 +683,21 @@ class LLMEngine:
         return pools, tok, pos, key
 
     def _export_fn(self, pools, table_row):
-        """Gather one slot's blocks into dense [L, max_blocks, bs,
-        n_kv, hd] arrays (the host slices the valid prefix). Read-only
+        """Gather one slot's blocks into dense {leaf: [L, max_blocks,
+        bs, ...]} arrays (the host slices the valid prefix). Read-only
         on the pool; ONE trace regardless of how many blocks are live
         (the table row is data)."""
-        return pools["k"][:, table_row], pools["v"][:, table_row]
+        return {name: pool[:, table_row] for name, pool in pools.items()}
 
-    def _adopt_fn(self, pools, tok, pos, kb, vb, scatter_ids, slot,
+    def _adopt_fn(self, pools, tok, pos, blocks, scatter_ids, slot,
                   new_tok, new_pos):
         """Scatter an imported KVState's blocks into the pool at this
         engine's freshly-allocated ids and seed the slot's token /
         position. ``scatter_ids`` is padded to max_blocks with the pool
         size (out-of-bounds scatters are dropped under jit), so ONE
         compiled program serves every valid-block count."""
-        pools = {
-            "k": pools["k"].at[:, scatter_ids].set(kb),
-            "v": pools["v"].at[:, scatter_ids].set(vb),
-        }
+        pools = {name: pool.at[:, scatter_ids].set(blocks[name])
+                 for name, pool in pools.items()}
         tok = tok.at[slot].set(new_tok)
         pos = pos.at[slot].set(new_pos)
         return pools, tok, pos
@@ -690,10 +710,9 @@ class LLMEngine:
         insert). One trace per prompt bucket."""
         from jax import lax
 
-        from ray_tpu.models.llama import prefill_kv
-
         dc = self.draft_config
-        _, ks, vs = prefill_kv(draft_params, padded_prompt[None], dc)
+        _, ks, vs = self._draft_model.prefill(
+            draft_params, padded_prompt[None], dc)
         return {
             "k": lax.dynamic_update_slice(
                 dcache["k"], ks.astype(dc.dtype), (0, slot, 0, 0, 0)),
@@ -716,8 +735,8 @@ class LLMEngine:
         import jax.numpy as jnp
         from jax import lax
 
-        from ray_tpu.models.llama import decode_step, verify_kv_paged
-
+        decode_step = self._draft_model.decode
+        verify_kv_paged = self._model.verify
         c = self.config
         K = c.spec_k
         S = c.max_seq_len
@@ -1220,7 +1239,7 @@ class LLMEngine:
         block the sequence can ever need is allocated (evicting cold
         prefix entries if that closes the gap) and the scatter runs, or
         nothing changes and the request stays queued. ONE adopt trace
-        serves every valid-block count — kb/vb are zero-padded to
+        serves every valid-block count — the blocks are zero-padded to
         max_blocks_per_slot and the scatter ids of padding rows point
         one past the pool (out-of-bounds writes drop under jit)."""
         import numpy as np
@@ -1248,13 +1267,9 @@ class LLMEngine:
         # Padding rows scatter to pool_blocks (out of bounds → dropped).
         ids = np.full((nb,), c.pool_blocks, np.int32)
         ids[:n_valid] = blocks[:n_valid]
-        kb = np.zeros((st.k_blocks.shape[0], nb) + st.k_blocks.shape[2:],
-                      st.k_blocks.dtype)
-        vb = np.zeros_like(kb)
-        kb[:, :n_valid] = st.k_blocks
-        vb[:, :n_valid] = st.v_blocks
         self._cache, self._tok, self._pos = self._jit_adopt(
-            self._cache, self._tok, self._pos, kb, vb, ids,
+            self._cache, self._tok, self._pos,
+            _padded_blocks(st.blocks, nb), ids,
             np.int32(slot), np.int32(st.next_tok), np.int32(st.pos))
         if self._prefix is not None:
             # Shared prompts stay warm across the migration: register
@@ -1420,8 +1435,7 @@ class LLMEngine:
         pos = int(np.asarray(self._pos)[slot])
         next_tok = int(np.asarray(self._tok)[slot])
         n_valid = -(-pos // bs)
-        kb, vb = self._jit_export(self._cache,
-                                  self._tables[slot].copy())
+        row = self._jit_export(self._cache, self._tables[slot].copy())
         state = KVState(
             prompt=list(req.prompt),
             tokens=list(handle.tokens),
@@ -1429,8 +1443,8 @@ class LLMEngine:
             pos=pos,
             temperature=req.temperature,
             block_size=bs,
-            k_blocks=np.asarray(kb)[:, :n_valid].copy(),
-            v_blocks=np.asarray(vb)[:, :n_valid].copy(),
+            blocks={name: np.asarray(x)[:, :n_valid].copy()
+                    for name, x in row.items()},
         )
         state.validate()
         return state
@@ -1464,14 +1478,14 @@ class LLMEngine:
                 chunk = ents[i:i + nb]
                 row = np.zeros((nb,), np.int32)
                 row[:len(chunk)] = [e.block for e in chunk]
-                kb, vb = self._jit_export(self._cache, row)
-                kb, vb = np.asarray(kb), np.asarray(vb)
-                copied += kb.nbytes + vb.nbytes
+                got = {name: np.asarray(x) for name, x in
+                       self._jit_export(self._cache, row).items()}
+                copied += sum(x.nbytes for x in got.values())
                 for j, e in enumerate(chunk):
                     prefixes.append(KVPrefix(
                         tokens=e.tokens, block_size=bs,
-                        k_blocks=kb[:, j:j + 1].copy(),
-                        v_blocks=vb[:, j:j + 1].copy()))
+                        blocks={name: x[:, j:j + 1].copy()
+                                for name, x in got.items()}))
             sp.set_metadata(bytes=copied)
             return self._tiers.spill(prefixes)
 
@@ -1493,15 +1507,13 @@ class LLMEngine:
         nb = c.max_blocks_per_slot
         ids = np.full((nb,), c.pool_blocks, np.int32)
         ids[:len(dst_blocks)] = dst_blocks
-        proto = hits[0].prefix.k_blocks
-        kb = np.zeros((proto.shape[0], nb) + proto.shape[2:],
-                      proto.dtype)
-        vb = np.zeros_like(kb)
-        for j, h in enumerate(hits):
-            kb[:, j] = h.prefix.k_blocks[:, -1]
-            vb[:, j] = h.prefix.v_blocks[:, -1]
+        # each hit carries its chain link as its payload's LAST block
+        last = {name: np.concatenate(
+            [h.prefix.blocks[name][:, -1:] for h in hits], axis=1)
+            for name in hits[0].prefix.blocks}
         self._cache, self._tok, self._pos = self._jit_adopt(
-            self._cache, self._tok, self._pos, kb, vb, ids,
+            self._cache, self._tok, self._pos,
+            _padded_blocks(last, nb), ids,
             np.int32(slot), np.int32(0), np.int32(0))
         self._tiers.pop(hits)
         self._promoted_blocks += len(hits)
@@ -1575,14 +1587,14 @@ class LLMEngine:
             n = min(len(hit), nb)
             row = np.zeros((nb,), np.int32)
             row[:n] = hit[:n]
-            kb, vb = self._jit_export(self._cache, row)
-            kb, vb = np.asarray(kb), np.asarray(vb)
+            got = {name: np.asarray(x) for name, x in
+                   self._jit_export(self._cache, row).items()}
             for j in range(n):
                 out.append(KVPrefix(
                     tokens=tuple(tokens[: (j + 1) * bs]),
                     block_size=bs,
-                    k_blocks=kb[:, j:j + 1].copy(),
-                    v_blocks=vb[:, j:j + 1].copy()))
+                    blocks={name: x[:, j:j + 1].copy()
+                            for name, x in got.items()}))
             self._allocator.free(hit)       # match increfed for us
         if self._tiers is not None and len(out) < cap:
             for h in self._tiers.lookup(tokens, bs,
@@ -1876,11 +1888,11 @@ class LLMEngine:
             if spec:
                 out = self._spec_dispatch()
             elif self._paged:
-                self._cache, self._tok, self._pos, self._key, out = \
-                    self._jit_tick(
-                        self.params, self._cache, self._tables.copy(),
-                        self._tok, self._pos, self._active.copy(),
-                        self._temp.copy(), self._key)
+                (self._cache, self._tok, self._pos, self._key, out,
+                 self._counters) = self._jit_tick(
+                    self.params, self._cache, self._tables.copy(),
+                    self._tok, self._pos, self._active.copy(),
+                    self._temp.copy(), self._key, self._counters)
             else:
                 self._cache, self._tok, self._pos, self._key, out = \
                     self._jit_tick(
@@ -2115,6 +2127,13 @@ class LLMEngine:
                     self._tiers.stats(),
                     promoted_blocks=self._promoted_blocks,
                     promote_skips=self._promote_skips)
+        if self._counters:
+            # the model's own counters, summed on the device since
+            # start and read here (waits for the tick in flight)
+            import numpy as np
+
+            out["counters"] = {name: np.asarray(x) for name, x in
+                               self._counters.items()}
         if self._draft is not None or self._spec_rounds:
             denom = max(self._spec_proposed, 1)
             out["spec"] = {
@@ -2144,6 +2163,19 @@ def _tier_store_get(ref):
     import ray_tpu
 
     return ray_tpu.get(ref, timeout=30.0)
+
+
+def _padded_blocks(blocks, n_blocks):
+    """{leaf: [L, n, bs, ...]} host blocks -> the adopt program's fixed
+    shape {leaf: [L, n_blocks, bs, ...]}, zeros after the n that are
+    there (their scatter ids point past the pool)."""
+    import numpy as np
+
+    out = {}
+    for name, x in blocks.items():
+        out[name] = np.zeros((x.shape[0], n_blocks) + x.shape[2:], x.dtype)
+        out[name][:, :x.shape[1]] = x
+    return out
 
 
 def _sample(logits, temp, key):

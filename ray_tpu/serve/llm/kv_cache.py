@@ -1,9 +1,10 @@
 """Paged KV-cache bookkeeping: block allocator + token-prefix cache.
 
-The HBM side lives in :mod:`ray_tpu.models.llama` (`init_paged_kv_cache`
-allocates one fixed pool of ``[block_size]``-row KV blocks per layer;
-`decode_step_paged` / `prefill_kv_paged` read and write it through
-per-sequence *block tables*). This module is the host side: which
+The HBM side lives with the model (`config.serving().init_pool`
+allocates one fixed pool of ``[block_size]``-row blocks per layer, a
+small dict of leaves the model names; its paged decode / prefill read
+and write it through per-sequence *block tables*:
+:mod:`ray_tpu.models.serving`). This module is the host side: which
 physical block belongs to whom, and which prompt prefixes are already
 resident so admission can skip their prefill entirely.
 
@@ -64,8 +65,10 @@ class KVState:
     """A sequence's paged KV checkpoint, detached from any engine.
 
     The unit of KV migration: `LLMEngine._export_state` densifies the
-    slot's live blocks into plain ndarrays ([L, n_valid, bs, n_kv, hd],
-    zero-copy through the object store), and `LLMEngine.submit_adopted`
+    slot's live blocks into plain ndarrays (one per leaf of the model's
+    pool, [L, n_valid, bs, ...]: `k` and `v` for the dense decoder, one
+    `latent` row for latent attention; zero-copy through the object
+    store), and `LLMEngine.submit_adopted`
     scatters them into another engine's pool. Produced by the
     disaggregated prefill tier (serve/llm/disagg) and by batch-lane
     preemption (the checkpoint that lets a preempted decode resume).
@@ -84,16 +87,15 @@ class KVState:
     pos: int
     temperature: float
     block_size: int
-    k_blocks: object        # np [L, n_valid, bs, n_kv, head_dim]
-    v_blocks: object
+    blocks: Dict[str, Any]  # leaf name -> np [L, n_valid, bs, ...]
 
     @property
     def n_blocks(self) -> int:
-        return int(self.k_blocks.shape[1])
+        return _n_blocks(self.blocks)
 
     @property
     def payload_bytes(self) -> int:
-        return int(self.k_blocks.nbytes + self.v_blocks.nbytes)
+        return sum(int(x.nbytes) for x in self.blocks.values())
 
     def validate(self) -> None:
         bs = self.block_size
@@ -102,8 +104,7 @@ class KVState:
             raise ValueError(
                 f"KVState holds {self.n_blocks} blocks but pos="
                 f"{self.pos} at block_size={bs} needs {need}")
-        if self.k_blocks.shape != self.v_blocks.shape:
-            raise ValueError("k/v block shape mismatch")
+        _check_leaves(self.blocks, bs)
         if not self.tokens or self.tokens[-1] != self.next_tok:
             raise ValueError(
                 "next_tok must be the last emitted token (sampled but "
@@ -113,6 +114,20 @@ class KVState:
                 f"pos={self.pos} inconsistent with prompt "
                 f"{len(self.prompt)} + emitted {len(self.tokens)} "
                 f"(expected prompt + emitted - 1 consumed tokens)")
+
+
+def _n_blocks(blocks: Dict[str, Any]) -> int:
+    return int(next(iter(blocks.values())).shape[1])
+
+
+def _check_leaves(blocks: Dict[str, Any], block_size: int) -> None:
+    """Every leaf holds the same blocks of `block_size` rows."""
+    lead = {tuple(x.shape[1:3]) for x in blocks.values()}
+    if len(lead) != 1 or next(iter(lead))[1] != block_size:
+        raise ValueError(
+            f"pool leaves disagree on blocks x rows (block_size "
+            f"{block_size}): "
+            f"{ {k: tuple(x.shape) for k, x in blocks.items()} }")
 
 
 def hash_prefix(tokens: Sequence[int]) -> int:
@@ -482,16 +497,15 @@ class KVPrefix:
 
     tokens: Tuple[int, ...]
     block_size: int
-    k_blocks: object        # np [L, n_blocks, bs, n_kv, head_dim]
-    v_blocks: object
+    blocks: Dict[str, Any]  # leaf name -> np [L, n_blocks, bs, ...]
 
     @property
     def n_blocks(self) -> int:
-        return int(self.k_blocks.shape[1])
+        return _n_blocks(self.blocks)
 
     @property
     def payload_bytes(self) -> int:
-        return int(self.k_blocks.nbytes + self.v_blocks.nbytes)
+        return sum(int(x.nbytes) for x in self.blocks.values())
 
     def validate(self) -> None:
         if not self.tokens or len(self.tokens) % self.block_size:
@@ -503,8 +517,7 @@ class KVPrefix:
             raise ValueError(
                 f"KVPrefix holds {self.n_blocks} blocks but the "
                 f"covered prefix is only {len(self.tokens)} tokens")
-        if self.k_blocks.shape != self.v_blocks.shape:
-            raise ValueError("k/v block shape mismatch")
+        _check_leaves(self.blocks, self.block_size)
 
 
 @dataclass

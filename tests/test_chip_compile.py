@@ -20,6 +20,7 @@ through an option of the program; all of it lives in this one file.
 """
 
 import functools
+import math
 import os
 
 import numpy as np
@@ -268,6 +269,79 @@ def test_benchmark_decode_tick_builds_no_repeated_kv(one_chip):
     assert not {s for s in shapes
                 if math.prod(s) >= repeated and s not in held}
     assert compiled.memory_analysis().temp_size_in_bytes < 3.0 * GIB
+
+
+@pytest.mark.parametrize("program", ["tick", "insert"])
+def test_latent_moe_cell_programs_fit_one_v5e(one_chip, program):
+    """The engine's decode tick and its largest insert at the geometry of
+    the benchmark's `assistant-decode-moe` cell (latent attention and
+    dropless experts at kanana-2-30b-a3b's published widths, the depth,
+    slots, row length, buckets and pool its files state): they compile
+    for v5e, the grouped products are the chip's own grouped-matmul
+    calls, the pool is updated in place (unrolled layers: no second
+    pool), and arguments + temporaries fit HBM.  These readings sized
+    the configuration's depth and the cell's pool."""
+    import json
+    import sys
+    import types
+
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    sys.path.insert(0, bench)
+    from families import latent_moe_decoder as family
+
+    with open(os.path.join(bench, "configs",
+                           "kanana-2-30b-a3b-serve.json")) as f:
+        published = json.load(f)
+    with open(os.path.join(bench, "workloads",
+                           "assistant-decode-moe.json")) as f:
+        ec = EngineConfig(**json.load(f)["engine"])
+    assert (published["num_hidden_layers"], published["hidden_size"],
+            published["n_routed_experts"], published["vocab_size"]) \
+        == (8, 2048, 128, 128256)
+    mc = family.model_config(published, max_seq_len=ec.max_seq_len,
+                             compute_dtype="bfloat16",
+                             param_dtype="bfloat16")
+    model = mc.serving()
+    eng = types.SimpleNamespace(_model=model, model_config=mc, config=ec)
+    params = _placed(jax.eval_shape(
+        lambda: model.init_params(mc, jax.random.key(0))), one_chip)
+    pools = _placed(jax.eval_shape(lambda: model.init_pool(
+        mc, ec.pool_blocks, ec.kv_block_size)), one_chip)
+    key = _placed(jax.eval_shape(lambda: jax.random.key(0)), one_chip)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    B, nb = ec.num_slots, ec.max_blocks_per_slot
+    if program == "tick":
+        compiled = jax.jit(
+            functools.partial(LLMEngine._tick_fn_paged, eng),
+            donate_argnums=(1, 3, 4)).lower(
+            params, pools, arg(jnp.int32, B, nb), arg(jnp.int32, B),
+            arg(jnp.int32, B), arg(jnp.bool_, B), arg(jnp.float32, B), key,
+            _placed(jax.eval_shape(lambda: model.init_counts(mc)),
+                    one_chip)).compile()
+        n_moe = mc.n_layers - mc.n_dense_layers
+        assert compiled.as_text().count(
+            'custom_call_target="tpu_custom_call"') >= 3 * n_moe
+    else:
+        Pb = ec.prefill_buckets[-1]
+        compiled = jax.jit(
+            functools.partial(LLMEngine._insert_fn_paged, eng),
+            donate_argnums=(1, 2, 3)).lower(
+            params, pools, arg(jnp.int32, B), arg(jnp.int32, B),
+            arg(jnp.int32, nb), arg(jnp.int32), arg(jnp.int32, Pb),
+            arg(jnp.int32), arg(jnp.int32, Pb // ec.kv_block_size),
+            arg(jnp.int32), arg(jnp.float32), key).compile()
+    m = compiled.memory_analysis()
+    pool_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                     for x in pools.values())
+    assert m.alias_size_in_bytes >= pool_bytes          # in place
+    assert m.temp_size_in_bytes < 1.5 * GIB
+    assert _hbm_gib(compiled) < V5E_HBM_GIB - 2.0
 
 
 def test_train_step_holds_flash_kernel_and_fits_one_v5e(topo, on_tpu):

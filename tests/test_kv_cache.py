@@ -222,7 +222,7 @@ def _prefix(tokens, bs=4, n_blocks=None, fill=1.0):
     nb = 1 if n_blocks is None else n_blocks
     kb = np.full((2, nb, bs, 1, 2), fill, np.float32)
     return KVPrefix(tokens=tokens, block_size=bs,
-                    k_blocks=kb, v_blocks=kb * 2)
+                    blocks={"k": kb, "v": kb * 2})
 
 
 class TestKVTierManager:
@@ -236,8 +236,8 @@ class TestKVTierManager:
         assert [h.tier for h in hits] == ["host"] * 3
         assert [len(h.prefix.tokens) for h in hits] == [4, 8, 12]
         # payloads come back bitwise
-        assert np.array_equal(hits[1].prefix.k_blocks,
-                              chain[1].k_blocks)
+        assert np.array_equal(hits[1].prefix.blocks["k"],
+                              chain[1].blocks["k"])
         # lookup is non-destructive; pop commits consumption
         assert len(tm) == 3
         tm.pop(hits[:2])

@@ -73,7 +73,7 @@ def test_export_import_roundtrip_parity():
     state = h.kv_state
     assert state is not None
     state.validate()
-    assert state.payload_bytes == state.k_blocks.nbytes * 2
+    assert state.payload_bytes == state.blocks["k"].nbytes * 2
 
     de = _engine()
     h2 = de.submit_adopted(Request(prompt=_PROMPT, max_tokens=12), state)
