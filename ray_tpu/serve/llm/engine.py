@@ -104,8 +104,9 @@ class EngineConfig:
     # when a cluster is attached) and re-admissions promote them back
     # through the adopt scatter when the PromoteCostModel favors the
     # transfer over recompute. None -> on for paged + prefix_cache
-    # engines (both migration programs already exist; spill adds no
-    # trace). Forced off otherwise.
+    # engines (both migration programs already exist; the spill runs
+    # the export gather at the row lengths of `export_rows`, one trace
+    # each, all compiled by `warmup()`). Forced off otherwise.
     kv_spill: Optional[bool] = None
     kv_host_tier_bytes: Optional[int] = None    # None -> GlobalConfig
     # PromoteCostModel knobs, milliseconds; None -> GlobalConfig
@@ -192,6 +193,19 @@ class EngineConfig:
     @property
     def max_blocks_per_slot(self) -> int:
         return self.max_seq_len // self.kv_block_size
+
+    @property
+    def export_rows(self) -> Tuple[int, ...]:
+        """Row lengths (in blocks) the export gather is traced at:
+        powers of two from 16 below `max_blocks_per_slot`, then
+        `max_blocks_per_slot` itself. An export pads its block ids to
+        the smallest of these that holds them, so it moves at most
+        twice the blocks it was asked for (and never under 16)."""
+        nb, n, rows = self.max_blocks_per_slot, 16, []
+        while n < nb:
+            rows.append(n)
+            n *= 2
+        return tuple(rows) + (nb,)
 
     @property
     def pool_blocks(self) -> int:
@@ -447,6 +461,15 @@ class LLMEngine:
         self._migrated_bytes = 0
         self._promoted_blocks = 0       # tier blocks re-adopted to HBM
         self._promote_skips = 0         # cost model chose recompute
+        # Spills whose export is dispatched and whose copy to the host
+        # is under way: (device row, the victims' tokens). `_step` lands
+        # them behind the dispatched tick; none outlives the step that
+        # made it. Scheduler thread only.
+        self._pending_spills: List[Tuple[Dict[str, Any],
+                                         List[Tuple[int, ...]]]] = []
+        self._in_step = False
+        self._spill_lands = 0           # landings, and those that had
+        self._spill_lands_waited = 0    # to wait for the transfer
         self._tier_seen = {t: {"hits": 0, "misses": 0, "spills": 0,
                                "promotes": 0}
                            for t in ("host", "store")}
@@ -502,11 +525,12 @@ class LLMEngine:
                 self._insert_fn_paged, name="llm_engine_insert",
                 trace_budget=len(c.prefill_buckets),
                 donate_argnums=(1, 2, 3))
-            # KV migration programs (ONE trace each: block counts are
-            # data — padded ids, out-of-bounds scatters dropped).
+            # KV migration programs: block counts are data (padded
+            # ids, out-of-bounds scatters dropped), so the adopt is ONE
+            # trace and the export one per row length of `export_rows`.
             self._jit_export = tracked_jit(
                 self._export_fn, name="llm_engine_export",
-                trace_budget=1)
+                trace_budget=len(c.export_rows))
             self._jit_adopt = tracked_jit(
                 self._adopt_fn, name="llm_engine_adopt",
                 trace_budget=1, donate_argnums=(0, 1, 2))
@@ -683,10 +707,11 @@ class LLMEngine:
         return pools, tok, pos, key
 
     def _export_fn(self, pools, table_row):
-        """Gather one slot's blocks into dense {leaf: [L, max_blocks,
-        bs, ...]} arrays (the host slices the valid prefix). Read-only
-        on the pool; ONE trace regardless of how many blocks are live
-        (the table row is data)."""
+        """Gather the blocks `table_row` names into dense {leaf: [L,
+        len(table_row), bs, ...]} arrays (the host slices the valid
+        prefix). Read-only on the pool; the ids are data and the row's
+        length is a shape: one trace per length of
+        `EngineConfig.export_rows` (see `_export_blocks`)."""
         return {name: pool[:, table_row] for name, pool in pools.items()}
 
     def _adopt_fn(self, pools, tok, pos, blocks, scatter_ids, slot,
@@ -1148,6 +1173,9 @@ class LLMEngine:
         if self._tiers is not None and self._prefix is not None:
             cap = (P - 1) // bs - n_hit
             if cap > 0:
+                # what an earlier admission of this step evicted is
+                # found here as it would be one step later
+                self._land_spills()
                 promote = self._tiers.lookup(prompt, bs,
                                              start_depth=n_hit,
                                              max_blocks=cap)
@@ -1422,9 +1450,9 @@ class LLMEngine:
     def _export_state(self, slot: int) -> Any:
         """Snapshot a live slot's sequence as a host-side KVState:
         dense copies of its valid KV blocks + the resume bookkeeping
-        (consumed position, pending sampled token). ONE gather trace
-        for every block count — the table row is data; the host slices
-        the valid prefix."""
+        (consumed position, pending sampled token). The gather runs
+        at the smallest row of `export_rows` that holds the valid
+        blocks; the host slices them off."""
         import numpy as np
 
         from ray_tpu.serve.llm.kv_cache import KVState
@@ -1435,7 +1463,7 @@ class LLMEngine:
         pos = int(np.asarray(self._pos)[slot])
         next_tok = int(np.asarray(self._tok)[slot])
         n_valid = -(-pos // bs)
-        row = self._jit_export(self._cache, self._tables[slot].copy())
+        row = self._export_blocks(self._tables[slot, :n_valid])
         state = KVState(
             prompt=list(req.prompt),
             tokens=list(handle.tokens),
@@ -1451,43 +1479,84 @@ class LLMEngine:
 
     # ------------------------------------------------------- KV tiering
 
+    def _export_blocks(self, ids: Sequence[int]) -> Dict[str, Any]:
+        """Dispatch the export gather over `ids` (at most
+        `max_blocks_per_slot` of them), padded with block 0 to the
+        smallest row of `export_rows` that holds them. Returns the
+        device row {leaf: [L, row, bs, ...]}; its first len(ids)
+        blocks are the ones asked for."""
+        import numpy as np
+
+        n = next(r for r in self.config.export_rows if r >= len(ids))
+        row = np.zeros((n,), np.int32)
+        row[:len(ids)] = ids
+        return self._jit_export(self._cache, row)
+
     def _spill_evicted(self, victims: List[Any]) -> int:
         """PrefixCache eviction hook: gather the victims' HBM rows
         (still cache-owned at this point — the free happens after we
-        return) and park them in the tier manager as one single-block
-        KVPrefix per chain link. Batched through the existing export
-        program — the padded id row is data, so a spill adds ZERO new
-        traces. Runs on the scheduler thread (eviction only happens
-        there)."""
-        import numpy as np
-
-        from ray_tpu.serve.llm.kv_cache import KVPrefix
-
+        return) through the export program, start their copy to the
+        host, and keep the device row with the victims' tokens as a
+        pending spill. Returns the blocks so exported. The programs
+        dispatched after this one (the insert that overwrites the
+        freed blocks, the tick) run after it on the chip, so the row
+        holds the blocks as they were; `_land_spills` parks them in
+        the tier manager — behind the dispatched tick when the
+        eviction came from inside a step, at once otherwise. Runs on
+        the scheduler thread (eviction only happens there)."""
         if self._tiers is None:
             return 0
-        c = self.config
-        bs = c.kv_block_size
         ents = [e for e in victims if e.tokens]
         if not ents:
             return 0
-        nb = c.max_blocks_per_slot
-        prefixes: List[Any] = []
-        copied = 0
+        # one eviction's rows on the device at a time
+        self._land_spills()
+        nb = self.config.max_blocks_per_slot
+        exported = 0
         with trace_span("llm_engine.spill", evicted_blocks=len(ents)) as sp:
             for i in range(0, len(ents), nb):
                 chunk = ents[i:i + nb]
-                row = np.zeros((nb,), np.int32)
-                row[:len(chunk)] = [e.block for e in chunk]
-                got = {name: np.asarray(x) for name, x in
-                       self._jit_export(self._cache, row).items()}
-                copied += sum(x.nbytes for x in got.values())
-                for j, e in enumerate(chunk):
-                    prefixes.append(KVPrefix(
-                        tokens=e.tokens, block_size=bs,
-                        blocks={name: x[:, j:j + 1].copy()
-                                for name, x in got.items()}))
-            sp.set_metadata(bytes=copied)
-            return self._tiers.spill(prefixes)
+                row = self._export_blocks([e.block for e in chunk])
+                for x in row.values():
+                    x.copy_to_host_async()
+                    exported += x.nbytes
+                self._pending_spills.append(
+                    (row, [e.tokens for e in chunk]))
+            sp.set_metadata(bytes=exported)
+        if not self._in_step:
+            self._land_spills()
+        return len(ents)
+
+    def _land_spills(self) -> None:
+        """Park every pending spill in the tier manager: read the
+        exported rows on the host (the transfer `_spill_evicted`
+        started), one single-block KVPrefix per chain link. Every
+        reader of the tier on the scheduler thread calls this first. A
+        landing that fails is a spill that failed: counted, and the
+        eviction it came from has long gone through."""
+        import numpy as np
+
+        if not self._pending_spills:
+            return
+        pending, self._pending_spills = self._pending_spills, []
+        bs = self.config.kv_block_size
+        n_blocks = sum(len(toks) for _, toks in pending)
+        with trace_span("llm_engine.spill_land", blocks=n_blocks) as sp:
+            t0 = time.monotonic()
+            landed, waited = 0, True
+            try:
+                rows = [({name: np.asarray(x) for name, x in row.items()},
+                         toks) for row, toks in pending]
+                waited = time.monotonic() - t0 > _SPILL_READY_S
+                landed = sum(x.nbytes for got, _ in rows
+                             for x in got.values())
+                self._tiers.spill([p for got, toks in rows
+                                   for p in _block_prefixes(got, toks, bs)])
+            except Exception:
+                self._prefix.spill_failed(n_blocks)
+            self._spill_lands += 1
+            self._spill_lands_waited += waited
+            sp.set_metadata(bytes=landed, ready=int(not waited))
 
     def _promote_tier_hits(self, hits: List[Any],
                            dst_blocks: List[int], slot: int,
@@ -1569,8 +1638,6 @@ class LLMEngine:
         :meth:`call_on_scheduler` from anywhere else."""
         import numpy as np
 
-        from ray_tpu.serve.llm.kv_cache import KVPrefix
-
         if not self._paged or self._prefix is None:
             return []
         c = self.config
@@ -1581,20 +1648,14 @@ class LLMEngine:
         if cap <= 0:
             return []
         out: List[Any] = []
+        self._land_spills()
         hit = self._prefix.match(tokens, max_blocks=cap)
         if hit:
-            nb = c.max_blocks_per_slot
-            n = min(len(hit), nb)
-            row = np.zeros((nb,), np.int32)
-            row[:n] = hit[:n]
+            n = min(len(hit), c.max_blocks_per_slot)
             got = {name: np.asarray(x) for name, x in
-                   self._jit_export(self._cache, row).items()}
-            for j in range(n):
-                out.append(KVPrefix(
-                    tokens=tuple(tokens[: (j + 1) * bs]),
-                    block_size=bs,
-                    blocks={name: x[:, j:j + 1].copy()
-                            for name, x in got.items()}))
+                   self._export_blocks(hit[:n]).items()}
+            out = _block_prefixes(
+                got, [tuple(tokens[: (j + 1) * bs]) for j in range(n)], bs)
             self._allocator.free(hit)       # match increfed for us
         if self._tiers is not None and len(out) < cap:
             for h in self._tiers.lookup(tokens, bs,
@@ -1847,7 +1908,12 @@ class LLMEngine:
         every live slot qualifies, plain otherwise — for every live
         slot. Returns True if any work was done."""
         with trace_span("llm_engine.step"):
-            return self._step()
+            self._in_step = True
+            try:
+                return self._step()
+            finally:
+                self._in_step = False
+                self._land_spills()     # only a step that raised
 
     def _step(self) -> bool:
         """`step`'s body. Its phases stand in a profiler trace as
@@ -1878,6 +1944,7 @@ class LLMEngine:
                     else:
                         self._emit(slot, int(tok_host[slot]))
         if not self._active.any():
+            self._land_spills()         # no tick to land behind
             with trace_span("llm_engine.gauges"):
                 self._update_gauges()
             return bool(inserted) or did_cancel or did_ctrl
@@ -1898,6 +1965,9 @@ class LLMEngine:
                     self._jit_tick(
                         self.params, self._cache, self._tok, self._pos,
                         self._active.copy(), self._temp.copy(), self._key)
+        # What this step's admissions evicted lands while the chip
+        # runs their inserts and the tick.
+        self._land_spills()
         with trace_span("llm_engine.tick_wait"):
             if spec:
                 toks_host, n_emit = self._spec_wait(*out)
@@ -2045,12 +2115,14 @@ class LLMEngine:
 
     def warmup(self) -> None:
         """Compile every program the engine can run — the decode tick
-        plus one insert per prefill bucket — before real traffic. The
-        paged layout bypasses the prefix cache while warming: a warm
-        hit shrinks the padded suffix to a SMALLER bucket, leaving the
-        larger bucket's insert uncompiled until a cache-miss request
-        pays the compile inside its own latency. Synchronous; call
-        before starting a run() thread."""
+        plus one insert per prefill bucket, and the paged layout's
+        export gather at every row length (`export_rows`; a spill, a
+        checkpoint or a peer pull picks one by its block count) —
+        before real traffic. The paged layout bypasses the prefix
+        cache while warming: a warm hit shrinks the padded suffix to a
+        SMALLER bucket, leaving the larger bucket's insert uncompiled
+        until a cache-miss request pays the compile inside its own
+        latency. Synchronous; call before starting a run() thread."""
         prefix, self._prefix = self._prefix, None
         draft, self._draft = self._draft, None
         try:
@@ -2074,6 +2146,11 @@ class LLMEngine:
         finally:
             self._prefix = prefix
             self._draft = draft
+        if self._paged:
+            import jax
+
+            for n in self.config.export_rows:   # one row alive at a time
+                jax.block_until_ready(self._export_blocks([0] * n))
 
     # ------------------------------------------------------------ inspection
 
@@ -2081,8 +2158,9 @@ class LLMEngine:
     def trace_count(self) -> int:
         """Number of engine XLA programs traced so far (compile guard:
         bounded by the per-family trace budgets under any workload —
-        len(buckets) inserts + 1 tick, plus at most 1 export, 1 adopt,
-        1 spec round, and len(buckets) draft inserts when wired)."""
+        len(buckets) inserts + 1 tick, plus at most len(export_rows)
+        exports, 1 adopt, 1 spec round, and len(buckets) draft inserts
+        when wired)."""
         n = self._jit_tick.traces + self._jit_insert.traces
         for name in ("_jit_export", "_jit_adopt", "_jit_spec",
                      "_jit_draft_insert"):
@@ -2126,7 +2204,9 @@ class LLMEngine:
                 out["kv_tiers"] = dict(
                     self._tiers.stats(),
                     promoted_blocks=self._promoted_blocks,
-                    promote_skips=self._promote_skips)
+                    promote_skips=self._promote_skips,
+                    spill_lands=self._spill_lands,
+                    spill_lands_waited=self._spill_lands_waited)
         if self._counters:
             # the model's own counters, summed on the device since
             # start and read here (waits for the tick in flight)
@@ -2143,6 +2223,11 @@ class LLMEngine:
                 "accept_ratio": self._spec_accepted / denom,
             }
         return out
+
+
+# A landing whose rows were all on the host reads them in microseconds;
+# one that blocks longer than this waited for the transfer.
+_SPILL_READY_S = 1e-3
 
 
 def _tier_store_put(prefix):
@@ -2163,6 +2248,18 @@ def _tier_store_get(ref):
     import ray_tpu
 
     return ray_tpu.get(ref, timeout=30.0)
+
+
+def _block_prefixes(got, keys, block_size):
+    """One single-block KVPrefix per key: block j of the exported host
+    row `got` ({leaf: [L, row, bs, ...]}) under the covered prefix
+    `keys[j]`, each a copy of its own (a view would keep the row)."""
+    from ray_tpu.serve.llm.kv_cache import KVPrefix
+
+    return [KVPrefix(tokens=tokens, block_size=block_size,
+                     blocks={name: x[:, j:j + 1].copy()
+                             for name, x in got.items()})
+            for j, tokens in enumerate(keys)]
 
 
 def _padded_blocks(blocks, n_blocks):

@@ -425,8 +425,11 @@ class PrefixCache:
         If the engine installed :attr:`spill_fn`, the victims are
         offered to it *before* their refs drop — at that point the
         cache still owns the blocks, so the hook may gather their HBM
-        rows and park them in a lower tier. Spill failures are counted
-        and never block the eviction itself (the pool must grow)."""
+        rows and park them in a lower tier (the engine's hook
+        dispatches the gather here and lands its host copy later in
+        the same step: the gather is ordered before whatever overwrites
+        the freed blocks). Spill failures are counted and never block
+        the eviction itself (the pool must grow)."""
         victims: List[_Entry] = []
         with self._lock:
             # LRU order with chain-tail preference: scan from coldest,
@@ -448,6 +451,14 @@ class PrefixCache:
                 self.spill_errors += 1
         self.allocator.free([e.block for e in victims])
         return len(victims)
+
+    def spill_failed(self, n_blocks: int) -> None:
+        """A hook that defers its copy (the engine lands it behind the
+        next tick) reports here what it had counted as spilled and
+        then lost: the same one error a raising hook counts."""
+        self.spill_errors += 1
+        self.spilled -= n_blocks
+        self.spilled_bytes -= n_blocks * self.allocator.block_bytes
 
     def clear(self) -> None:
         self.evict(len(self._entries))

@@ -271,6 +271,94 @@ def test_benchmark_decode_tick_builds_no_repeated_kv(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 3.0 * GIB
 
 
+def _serving_cell(cell, one_chip):
+    """The engine of one of the benchmark's serving cells as shapes on
+    the described chip: what `LLMEngine`'s program functions read of
+    `self` (`_model`, `model_config`, `config`), and beside it the
+    configuration's file (`published`) and the model's parameters,
+    pool and key."""
+    import json
+    import sys
+    import types
+
+    from ray_tpu.serve.llm.engine import EngineConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import importlib
+
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        declared = json.load(f)
+    name = next(w["config"] for w in declared["workloads"]
+                if w["name"] == cell)
+    with open(os.path.join(root, next(
+            c["file"] for c in declared["configs"]
+            if c["name"] == name))) as f:
+        published = json.load(f)
+    with open(os.path.join(bench, "workloads", cell + ".json")) as f:
+        ec = EngineConfig(**json.load(f)["engine"])
+    family = importlib.import_module("families." + published["family"])
+    mc = family.model_config(published, max_seq_len=ec.max_seq_len,
+                             compute_dtype="bfloat16",
+                             param_dtype="bfloat16")
+    model = mc.serving()
+    return types.SimpleNamespace(
+        _model=model, model_config=mc, config=ec, published=published,
+        params=_placed(jax.eval_shape(
+            lambda: model.init_params(mc, jax.random.key(0))), one_chip),
+        pools=_placed(jax.eval_shape(lambda: model.init_pool(
+            mc, ec.pool_blocks, ec.kv_block_size)), one_chip),
+        key=_placed(jax.eval_shape(lambda: jax.random.key(0)), one_chip))
+
+
+def _compiled_insert(eng, one_chip):
+    """`LLMEngine._insert_fn_paged` at the cell's largest bucket."""
+    from ray_tpu.serve.llm.engine import LLMEngine
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ec = eng.config
+    B, Pb = ec.num_slots, ec.prefill_buckets[-1]
+    return jax.jit(
+        functools.partial(LLMEngine._insert_fn_paged, eng),
+        donate_argnums=(1, 2, 3)).lower(
+        eng.params, eng.pools, arg(jnp.int32, B), arg(jnp.int32, B),
+        arg(jnp.int32, ec.max_blocks_per_slot), arg(jnp.int32),
+        arg(jnp.int32, Pb), arg(jnp.int32),
+        arg(jnp.int32, Pb // ec.kv_block_size), arg(jnp.int32),
+        arg(jnp.float32), eng.key).compile()
+
+
+@pytest.mark.parametrize("cell, rows", [
+    ("chat-decode", (16, 32, 64, 128)),
+    ("assistant-decode-moe", (16, 32, 64, 128, 256))])
+def test_export_rows_compile_and_fit_beside_the_insert(one_chip, cell, rows):
+    """The export gather at every row length a serving cell's engine can
+    pick (`EngineConfig.export_rows`: a spill pads its victims to the
+    smallest), and the cell's largest insert with the largest export row
+    still alive beside it (a spill's row is pending while the admission's
+    insert runs): all compile for v5e and fit its HBM."""
+    from ray_tpu.serve.llm.engine import LLMEngine
+
+    eng = _serving_cell(cell, one_chip)
+    ec = eng.config
+    assert ec.export_rows == rows and len(rows) <= 6
+    block_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in eng.pools.values()) // ec.pool_blocks
+    export = jax.jit(functools.partial(LLMEngine._export_fn, eng))
+    for n in rows:
+        m = export.lower(eng.pools, jax.ShapeDtypeStruct(
+            (n,), jnp.int32, sharding=one_chip)).compile().memory_analysis()
+        # the row's leaves and a few hundred bytes of tuple table
+        assert 0 <= m.output_size_in_bytes - n * block_bytes < 4096
+        assert m.alias_size_in_bytes == 0       # reads the pool, keeps it
+    insert = _compiled_insert(eng, one_chip)
+    assert _hbm_gib(insert) + rows[-1] * block_bytes / GIB < V5E_HBM_GIB
+
+
 @pytest.mark.parametrize("program", ["tick", "insert"])
 def test_latent_moe_cell_programs_fit_one_v5e(one_chip, program):
     """The engine's decode tick and its largest insert at the geometry of
@@ -281,36 +369,15 @@ def test_latent_moe_cell_programs_fit_one_v5e(one_chip, program):
     calls, the pool is updated in place (unrolled layers: no second
     pool), and arguments + temporaries fit HBM.  These readings sized
     the configuration's depth and the cell's pool."""
-    import json
-    import sys
-    import types
+    from ray_tpu.serve.llm.engine import LLMEngine
 
-    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
-
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks")
-    sys.path.insert(0, bench)
-    from families import latent_moe_decoder as family
-
-    with open(os.path.join(bench, "configs",
-                           "kanana-2-30b-a3b-serve.json")) as f:
-        published = json.load(f)
-    with open(os.path.join(bench, "workloads",
-                           "assistant-decode-moe.json")) as f:
-        ec = EngineConfig(**json.load(f)["engine"])
+    eng = _serving_cell("assistant-decode-moe", one_chip)
+    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+                                eng.published)
+    params, pools, key = eng.params, eng.pools, eng.key
     assert (published["num_hidden_layers"], published["hidden_size"],
             published["n_routed_experts"], published["vocab_size"]) \
         == (8, 2048, 128, 128256)
-    mc = family.model_config(published, max_seq_len=ec.max_seq_len,
-                             compute_dtype="bfloat16",
-                             param_dtype="bfloat16")
-    model = mc.serving()
-    eng = types.SimpleNamespace(_model=model, model_config=mc, config=ec)
-    params = _placed(jax.eval_shape(
-        lambda: model.init_params(mc, jax.random.key(0))), one_chip)
-    pools = _placed(jax.eval_shape(lambda: model.init_pool(
-        mc, ec.pool_blocks, ec.kv_block_size)), one_chip)
-    key = _placed(jax.eval_shape(lambda: jax.random.key(0)), one_chip)
 
     def arg(dtype, *shape):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -328,14 +395,7 @@ def test_latent_moe_cell_programs_fit_one_v5e(one_chip, program):
         assert compiled.as_text().count(
             'custom_call_target="tpu_custom_call"') >= 3 * n_moe
     else:
-        Pb = ec.prefill_buckets[-1]
-        compiled = jax.jit(
-            functools.partial(LLMEngine._insert_fn_paged, eng),
-            donate_argnums=(1, 2, 3)).lower(
-            params, pools, arg(jnp.int32, B), arg(jnp.int32, B),
-            arg(jnp.int32, nb), arg(jnp.int32), arg(jnp.int32, Pb),
-            arg(jnp.int32), arg(jnp.int32, Pb // ec.kv_block_size),
-            arg(jnp.int32), arg(jnp.float32), key).compile()
+        compiled = _compiled_insert(eng, one_chip)
     m = compiled.memory_analysis()
     pool_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
                      for x in pools.values())

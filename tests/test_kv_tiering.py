@@ -104,8 +104,11 @@ class TestTieredPromote:
         assert h2.prefilled_tokens == len(_PROMPT) - 3 * 8
         # Trace budget: tick + per-bucket inserts + the two migration
         # programs the hierarchy reuses (export gather for the spill,
-        # adopt scatter for the promote) — nothing per-request.
-        assert eng.trace_count <= len(_GEO["prefill_buckets"]) + 3
+        # one trace per row length of `export_rows`; adopt scatter for
+        # the promote) — nothing per-request.
+        assert eng.trace_count <= (len(_GEO["prefill_buckets"]) + 2
+                                   + len(eng.config.export_rows))
+        assert eng.stats()["traces"]["export"] == 1     # one row used
 
     def test_promote_all_or_nothing_under_exhaustion(self):
         """A promote the pool cannot cover is dropped ENTIRELY — tier
@@ -161,6 +164,338 @@ class TestTieredPromote:
         cross = next(n for n in range(1, 65) if cm.should_promote(n, 16))
         assert cross == 3
         assert all(cm.should_promote(n, 16) for n in range(cross, 65))
+
+
+# ------------------------------------------ the spill off the critical path
+#
+# PR 27: `_spill_evicted` exports the smallest row of `export_rows` that
+# holds the victims, starts its copy to the host and keeps it pending;
+# `_step` lands it behind the dispatched tick. 40 blocks a slot give the
+# rows (16, 32, 40).
+
+_ROWS_GEO = dict(num_slots=2, max_seq_len=320, prefill_buckets=(16,),
+                 kv_layout="paged", kv_block_size=8, decode_block=1)
+
+
+def _model_of(kind):
+    if kind == "dense":
+        return _model()
+    if "latent" not in _CACHE:
+        import jax
+
+        from ray_tpu.models.latent_moe import LatentMoEConfig
+
+        config = LatentMoEConfig.tiny()
+        _CACHE["latent"] = (config, config.serving().init_params(
+            config, jax.random.key(0)))
+    return _CACHE["latent"]
+
+
+def _engine_of(kind, **geo):
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+    config, params = _model_of(kind)
+    return LLMEngine(params, config, EngineConfig(**geo))
+
+
+def _random_pool(eng, seed=0):
+    """Every row of the pool distinct, so a block read from the wrong
+    place or at the wrong time cannot compare equal."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    rng = np.random.RandomState(seed)
+    eng._cache = {name: jnp.asarray(rng.standard_normal(x.shape), x.dtype)
+                  for name, x in eng._cache.items()}
+    return {name: np.asarray(x) for name, x in eng._cache.items()}
+
+
+class _Spans:
+    """Stands in for `trace_span` in the engine module: every span as a
+    dict of its name and arguments, in the order they opened."""
+
+    def __init__(self, monkeypatch):
+        from ray_tpu.serve.llm import engine as E
+
+        self.rows = []
+        monkeypatch.setattr(E, "trace_span", self._open)
+
+    def _open(self, name, **args):
+        rows = self.rows
+
+        class _Span:
+            def __enter__(self):
+                self.row = dict(args, name=name)
+                rows.append(self.row)
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+            def set_metadata(self, **kw):
+                self.row.update(kw)
+
+        return _Span()
+
+    def named(self, name):
+        return [r for r in self.rows if r["name"] == "llm_engine." + name]
+
+
+def _tier_blocks(eng, tokens):
+    """{leaf: [L, n, bs, ...]} of the chain links of `tokens` that the
+    tier holds, in depth order (lookup stops at the first miss)."""
+    import numpy as np
+
+    hits = eng._tiers.lookup(tokens, eng.config.kv_block_size)
+    if not hits:
+        return 0, {}
+    return len(hits), {name: np.concatenate(
+        [h.prefix.blocks[name] for h in hits], axis=1)
+        for name in hits[0].prefix.blocks}
+
+
+@pytest.mark.parametrize("kind", ["dense", "latent"])
+@pytest.mark.parametrize("victims, row", [(1, 16), (16, 16), (17, 32),
+                                          (40, 40)])
+def test_spill_exports_smallest_row_and_lands_victims_bitwise(
+        monkeypatch, kind, victims, row):
+    """1, a ladder edge, an edge + 1 and `max_blocks_per_slot` victims:
+    the export row is the smallest that holds them, `llm_engine.spill`'s
+    bytes are that row's, and the tier holds exactly the victims, each
+    leaf bitwise what the pool held."""
+    import numpy as np
+
+    eng = _engine_of(kind, **_ROWS_GEO)
+    assert eng.config.export_rows == (16, 32, 40)
+    spans = _Spans(monkeypatch)
+    pool = _random_pool(eng, seed=victims)
+    bs = eng.config.kv_block_size
+    # one chain of 40 links in a cache of 80 blocks; evict() takes the
+    # coldest first, which is the chain's head
+    tokens = [1 + (i * 7) % 250 for i in range(40 * bs)]
+    blocks = eng._allocator.alloc(40)
+    eng._prefix.insert(tokens, blocks)
+    eng._allocator.free(blocks)
+
+    assert eng._prefix.evict(victims) == victims    # outside a step:
+    assert eng._pending_spills == []                # landed at once
+    row_bytes = row * eng._allocator.block_bytes
+    (sp,), (ld,) = spans.named("spill"), spans.named("spill_land")
+    assert (sp["evicted_blocks"], sp["bytes"]) == (victims, row_bytes)
+    assert (ld["blocks"], ld["bytes"]) == (victims, row_bytes)
+    assert ld["ready"] in (0, 1)
+    n, got = _tier_blocks(eng, tokens)
+    assert n == victims == eng.stats()["kv_tiers"]["host"]["blocks"]
+    assert set(got) == set(pool)
+    for name, x in pool.items():
+        assert got[name].dtype == x.dtype
+        assert np.array_equal(got[name], x[:, blocks[:victims]]), name
+    st = eng.stats()
+    assert st["traces"]["export"] == 1
+    assert st["prefix_cache"]["spilled"] == victims
+    assert st["kv_tiers"]["spill_lands"] == 1
+
+
+# A pool of 9 blocks in which four served prompts leave 8 cached links
+# (two each) and one free block: the fifth admission (3 blocks) has to
+# evict two, and writes where the victims were.
+_TIGHT_GEO = dict(num_slots=2, max_seq_len=64, prefill_buckets=(16,),
+                  kv_layout="paged", kv_block_size=8, num_kv_blocks=9,
+                  decode_block=1)
+
+
+def _prompt16(i):
+    return [1 + (37 * i + 3 * j) % 250 for j in range(16)]
+
+
+def _fill_tight(eng):
+    from ray_tpu.serve.llm.engine import Request
+
+    for i in range(4):
+        eng.submit(Request(prompt=_prompt16(i), max_tokens=2))
+        eng.drain()
+    assert len(eng._prefix) == 8 and eng._allocator.free_blocks == 1
+
+
+@pytest.mark.parametrize("kind", ["dense", "latent"])
+def test_insert_over_evicted_blocks_lands_what_they_held(monkeypatch, kind):
+    """The admission that evicts frees the victims' blocks and its own
+    insert (and the tick) write into them in the same step, before the
+    landing reads the row: the tier still gets what the blocks held at
+    eviction, for every leaf."""
+    import numpy as np
+
+    from ray_tpu.serve.llm.engine import Request
+
+    eng = _engine_of(kind, **_TIGHT_GEO)
+    _fill_tight(eng)
+    before = {name: np.asarray(x) for name, x in eng._cache.items()}
+    held = {e.tokens: e.block for e in eng._prefix._entries.values()}
+    spans = _Spans(monkeypatch)
+    eng.submit(Request(prompt=_prompt16(9), max_tokens=4))
+    assert eng.step()
+    assert eng._pending_spills == []
+    order = [r["name"].split(".", 1)[1] for r in spans.rows]
+    assert [n for n in order if n in (
+        "spill", "insert_dispatch", "tick_dispatch", "spill_land",
+        "tick_wait")] == ["spill", "insert_dispatch", "tick_dispatch",
+                          "spill_land", "tick_wait"]
+    after = {name: np.asarray(x) for name, x in eng._cache.items()}
+    # 3 blocks for 16 + 4 tokens, one free: both links of prompt 0 go
+    victims = [t for t in held if t not in
+               {e.tokens for e in eng._prefix._entries.values()}]
+    assert len(victims) == 2 == spans.named("spill_land")[0]["blocks"]
+    overwritten = 0
+    for tokens in victims:
+        hit = eng._tiers.lookup(tokens, 8, start_depth=len(tokens) // 8 - 1)
+        assert len(hit) == 1
+        for name, x in before.items():
+            assert np.array_equal(hit[0].prefix.blocks[name][:, 0],
+                                  x[:, held[tokens]]), name
+            overwritten += not np.array_equal(after[name][:, held[tokens]],
+                                              x[:, held[tokens]])
+    assert overwritten >= len(before)        # a victim's block, each leaf
+
+
+def test_lookup_in_the_evicting_step_finds_and_promotes(monkeypatch):
+    """Two admissions in ONE step: the first evicts the whole chain of
+    `_PROMPT` (and writes over its blocks), the second is `_PROMPT`
+    again: its lookup lands the pending spill first, finds the three
+    links and promotes them. Both streams are bitwise the reference's."""
+    from ray_tpu.serve.llm.engine import Request
+
+    other = [[9 + (i * 5 + 17 * k) % 180 for i in range(28)]
+             for k in range(3)]
+    big = other[2]
+    refs = [_reference(_PROMPT, 12), _reference(big, 52)]
+    eng = _engine(num_kv_blocks=16, kv_prefill_cost_per_token_ms=50.0)
+    for p in (_PROMPT, other[0], other[1]):     # 3 cached links each
+        _run(eng, p, 12)
+    assert len(eng._prefix) == 9 and eng._allocator.free_blocks == 7
+    spans = _Spans(monkeypatch)
+    h_big = eng.submit(Request(prompt=big, max_tokens=52))   # 10 blocks
+    h_again = eng.submit(Request(prompt=list(_PROMPT), max_tokens=12))
+    assert eng.step()
+    assert spans.named("admit")[0]["admitted"] == 2
+    lands = spans.named("spill_land")
+    # the first landing stands before the second admission's promote,
+    # the second (what that admission evicted) behind the tick
+    order = [r["name"].split(".", 1)[1] for r in spans.rows]
+    assert [lands[0]["blocks"], lands[1]["blocks"]] == [3, 5]
+    assert order.index("spill_land") < order.index("promote") \
+        < order.index("tick_dispatch") < len(order) - 1 - order[::-1].index(
+            "spill_land")
+    assert eng._pending_spills == []
+    eng.drain()
+    assert [h_again.tokens, h_big.tokens] == refs
+    st = eng.stats()["kv_tiers"]
+    assert st["promoted_blocks"] == 3
+    assert h_again.prefilled_tokens == len(_PROMPT) - 3 * 8
+    assert st["spill_lands"] == 2
+
+
+@pytest.mark.parametrize("max_tokens, ticks", [(4, True), (1, False)])
+def test_no_pending_spill_outlives_its_step(monkeypatch, max_tokens, ticks):
+    """A step that ticks lands behind the dispatched tick; one whose
+    only request ends at its first token dispatches no tick and lands
+    before it returns. Either way the tier holds the victims when
+    `step()` is back, and a step that admitted nothing lands nothing."""
+    from ray_tpu.serve.llm.engine import Request
+
+    eng = _engine_of("dense", **_TIGHT_GEO)
+    _fill_tight(eng)
+    spans = _Spans(monkeypatch)
+    h = eng.submit(Request(prompt=_prompt16(9), max_tokens=max_tokens))
+    assert eng.step()
+    assert ("tick_dispatch" in {r["name"].split(".", 1)[1]
+                                for r in spans.rows}) == ticks
+    assert eng._pending_spills == []
+    evicted = spans.named("spill")[0]["evicted_blocks"]
+    st = eng.stats()
+    assert st["kv_tiers"]["host"]["blocks"] == evicted > 0
+    assert st["kv_tiers"]["spill_lands"] == 1
+    assert st["prefix_cache"]["spilled"] == evicted
+    eng.drain()
+    assert h.finish_reason == "length"
+    assert len(spans.named("spill_land")) == 1
+    assert eng.stats()["kv_tiers"]["spill_lands"] == 1
+
+
+@pytest.mark.parametrize("threshold, waited", [(-1.0, 1), (1e9, 0)])
+def test_spill_lands_counts_the_landings_that_waited(monkeypatch,
+                                                     threshold, waited):
+    """`spill_lands` counts landings, `spill_lands_waited` those whose
+    read of the row blocked longer than the engine's threshold (the
+    transfer was still under way); the span says the same as `ready`."""
+    from ray_tpu.serve.llm import engine as E
+    from ray_tpu.serve.llm.engine import Request
+
+    monkeypatch.setattr(E, "_SPILL_READY_S", threshold)
+    eng = _engine_of("dense", **_TIGHT_GEO)
+    _fill_tight(eng)
+    spans = _Spans(monkeypatch)
+    for i in (9, 10):
+        eng.submit(Request(prompt=_prompt16(i), max_tokens=2))
+        eng.drain()
+    st = eng.stats()["kv_tiers"]
+    assert st["spill_lands"] == len(spans.named("spill_land")) == 2
+    assert st["spill_lands_waited"] == 2 * waited
+    assert {r["ready"] for r in spans.named("spill_land")} == {1 - waited}
+
+
+def test_failed_landing_counts_and_never_blocks_the_eviction():
+    """A landing that fails is a spill that failed: `spill_errors`
+    counts it, `spilled` does not count its blocks, the blocks were
+    freed and the request is served."""
+    from ray_tpu.serve.llm.engine import Request
+
+    eng = _engine_of("dense", **_TIGHT_GEO)
+    _fill_tight(eng)
+
+    def broken(prefixes):
+        raise MemoryError("host tier out of memory")
+
+    eng._tiers.spill = broken
+    h = eng.submit(Request(prompt=_prompt16(9), max_tokens=4))
+    eng.drain()
+    assert h.finish_reason == "length" and len(h.tokens) == 4
+    st = eng.stats()
+    assert st["prefix_cache"]["spill_errors"] == 1
+    assert st["prefix_cache"]["spilled"] == 0
+    assert st["prefix_cache"]["evictions"] == 2
+    assert st["kv_tiers"]["host"]["blocks"] == 0
+    assert st["kv_tiers"]["spill_lands"] == 1
+    assert eng._pending_spills == []
+
+
+@pytest.mark.parametrize("kind", ["dense", "latent"])
+def test_warmup_compiles_every_export_row(kind):
+    """`warmup()` traces the export at every row length, so evicting
+    traffic, a checkpoint and a peer pull after it add no trace."""
+    from ray_tpu.serve.llm.engine import Request
+
+    eng = _engine_of(kind, **dict(_ROWS_GEO, num_kv_blocks=48))
+    eng.warmup()
+    st = eng.stats()
+    assert st["traces"] == {"tick": 1, "insert": 1, "export": 3, "adopt": 0}
+    assert st["trace_count"] == 2 + len(eng.config.export_rows)
+    # 20 cached links a prompt in a pool of 48: the third prompt evicts
+    # (rows of 16 and 32 by the count), as do the ones after it
+    hs = []
+    for i in range(5):
+        prompt = [1 + (i * 31 + j * 3) % 250 for j in range(160 + 8 * i)]
+        hs.append(eng.submit(Request(prompt=prompt, max_tokens=3,
+                                     chunked_prefill=True)))
+        eng.drain()
+    assert all(h.finish_reason == "length" for h in hs)
+    assert eng.stats()["prefix_cache"]["spilled"] > 40
+    eng.submit(Request(prompt=[7] * 12, max_tokens=4))
+    eng.step()
+    eng.preempt(int(eng._active.nonzero()[0][0]))          # checkpoint
+    eng.drain()
+    assert eng.export_prefix(prompt)                        # peer pull
+    st = eng.stats()
+    assert st["traces"] == {"tick": 1, "insert": 1, "export": 3, "adopt": 1}
 
 
 def test_cluster_prefix_index_gcs():

@@ -284,7 +284,11 @@ def test_engine_serves_through_eviction_spill_and_promotion(model, engine):
     assert st["prefix_cache"]["evictions"] > 0
     assert st["kv_tiers"]["host"]["spills"] > 0
     assert st["kv_tiers"]["promoted_blocks"] > 0
+    # 16 blocks a slot: `export_rows` is the one length, so the spill's
+    # gather is one trace whatever it evicted
+    assert engine.config.export_rows == (16,)
     assert st["traces"] == {"tick": 1, "insert": 1, "export": 1, "adopt": 1}
+    assert st["kv_tiers"]["spill_lands"] > 0 and not engine._pending_spills
     deficits = np.concatenate([
         R.served_token_deficits(weights, C, p, t) for p, t in served])
     assert deficits.size == 13 * 6

@@ -144,7 +144,9 @@ def test_slot_recycling_under_staggered_arrivals():
 
 def test_compile_count_guard():
     """A mixed workload traces at most n_prefill_buckets + 1 engine
-    programs — no per-request or per-shape recompiles."""
+    programs — no per-request or per-shape recompiles (the dense layout
+    has no export or adopt program; the paged one adds at most
+    len(export_rows) + 1 once something is evicted or migrated)."""
     from ray_tpu.serve.llm.engine import Request
 
     config, _ = _model()
@@ -248,8 +250,11 @@ def test_paged_greedy_parity_and_compile_count():
     for (p, n), h in zip(specs, handles):
         assert h.finish_reason == "length"
         assert h.tokens == _reference(p, n), (p, n)
+    # nothing evicted, checkpointed or pulled: none of the export's
+    # len(export_rows) traces, nor the adopt's one, is spent
     assert engine.trace_count <= len(engine.config.prefill_buckets) + 1, \
         engine.stats()
+    assert engine.stats()["traces"]["export"] == 0
 
 
 def test_paged_prefix_hit_skips_prefill_and_keeps_parity():

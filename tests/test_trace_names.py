@@ -12,7 +12,7 @@ import time
 import pytest
 
 STEP_CHILDREN = ["ctrl", "admit", "first_token_wait", "tick_dispatch",
-                 "tick_wait", "emit", "gauges"]
+                 "spill_land", "tick_wait", "emit", "gauges"]
 
 
 # ------------------------------------------------------- program names
@@ -124,11 +124,70 @@ def test_engine_step_spans_nest_and_cover(engine_traces, case):
     admits = [e for e in events if e[0] == "llm_engine.admit"]
     assert sum(int(e[3]["admitted"]) for e in admits) >= 2
     assert ("llm_engine.spill" in names) == (case == "evicting")
+    assert ("llm_engine.spill_land" in names) == (case == "evicting")
     assert ("llm_engine.evict" in names) == (evicted > 0) == (case == "evicting")
-    for sp in (e for e in events if e[0] == "llm_engine.spill"):
+    spills = [e for e in events if e[0] == "llm_engine.spill"]
+    for sp in spills:
         assert int(sp[3]["evicted_blocks"]) > 0 and int(sp[3]["bytes"]) > 0
         assert any(e[0] == "llm_engine.evict" and e[1] <= sp[1]
                    and sp[2] <= e[2] for e in events)
+    # every spilled block lands, in the step that spilled it: behind the
+    # dispatched tick (a child of the step) or before a later admission
+    # of the same step looks the tier up (under its admit_one)
+    lands = [e for e in events if e[0] == "llm_engine.spill_land"]
+    assert sum(int(e[3]["blocks"]) for e in lands) == sum(
+        int(e[3]["evicted_blocks"]) for e in spills)
+    assert sum(int(e[3]["bytes"]) for e in lands) == sum(
+        int(e[3]["bytes"]) for e in spills)
+    for ld in lands:
+        assert int(ld[3]["blocks"]) > 0 and int(ld[3]["ready"]) in (0, 1)
+        step = next(s for s in steps if s[1] <= ld[1] and ld[2] <= s[2])
+        assert any(step[1] <= e[1] < ld[1] for e in spills)
+
+
+@pytest.mark.parametrize("case, want", [
+    ("landed", 3.0),            # landings of 2, 3 and 40 ms: the median
+    ("no_admission", 0.0),      # a window of ticks alone still reports
+    ("spill_cut", 0.0),         # the trace ended between spill and landing
+    ("parent", None),           # a program that never writes the span
+    ("no_spans", None)])
+def test_spill_land_reader_reports_every_window(monkeypatch, case, want):
+    """`benchmarks/layer_metrics/spill_land_ms.py`: every traced window
+    of a program that lands its spills gives a number (the driver holds
+    the change to that), and the parent's gives none."""
+    import importlib.util
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    monkeypatch.syspath_prepend(bench)
+    import program_spans as PS
+
+    spec = importlib.util.spec_from_file_location(
+        "lm_spill_land_ms", os.path.join(bench, "layer_metrics",
+                                         "spill_land_ms.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    assert mod._program_lands()             # this tree writes mod.SPAN
+    ms = 1_000_000
+    spans = [("llm_engine.step", 0, 100 * ms, {}),
+             ("llm_engine.tick_dispatch", 10 * ms, ms, {"live": "2"}),
+             ("llm_engine.tick_wait", 60 * ms, 30 * ms, {})]
+    if case in ("landed", "parent"):
+        spans += [("llm_engine.admit_one", ms, 8 * ms, {}),
+                  ("llm_engine.spill", 2 * ms, ms, {"evicted_blocks": "3"})]
+    if case == "landed":
+        spans += [(mod.SPAN, t * ms, d * ms, {"blocks": "3"})
+                  for t, d in ((11, 2), (15, 3), (19, 40))]
+    if case == "spill_cut":
+        spans = [("llm_engine.admit_one", ms, 8 * ms, {}),
+                 ("llm_engine.spill", 2 * ms, ms, {"evicted_blocks": "3"})]
+    if case == "parent":
+        monkeypatch.setattr(mod, "_program_lands", lambda: False)
+    run = {"window": (0, 200 * ms), "trace": object(),
+           "program": None if case == "no_spans" else PS.Program(
+               sorted(spans, key=lambda s: (s[1], -s[2])), [])}
+    got = mod.read(run)
+    assert (got is None) if want is None else got == pytest.approx(want)
 
 
 def test_step_phases_write_train_spans(tmp_path):
