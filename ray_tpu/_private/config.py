@@ -219,7 +219,7 @@ _define("serve_accounting_instrumentation", bool, True,
         "computed vs avoided, decode tokens, KV block-seconds, "
         "chip-seconds per phase, folded into the tenant ledger and "
         "published to the GCS accounting ring. Off = the unmetered "
-        "engine; the serve_accounting_overhead bench prices the delta.")
+        "engine.")
 _define("serve_accounting_buffer_size", int, 4096,
         "Bound on the GCS serve-accounting ring "
         "(report_serve_accounting / list_serve_accounting rows across "
@@ -339,8 +339,7 @@ _define("train_goodput_instrumentation", bool, True,
         "(observability.goodput): rtpu_train_step_phase_seconds{phase} "
         "histograms, the rtpu_train_goodput_ratio gauge, train.step "
         "spans, and step-row heartbeats into the GCS step matrix "
-        "(report_train_steps). Off = the uninstrumented step loop; the "
-        "train_goodput_overhead bench prices the delta.")
+        "(report_train_steps). Off = the uninstrumented step loop.")
 _define("train_steps_buffer_size", int, 4096,
         "Bound on the GCS train-step matrix ring (report_train_steps/"
         "list_train_steps rows across all workers).")
@@ -368,8 +367,7 @@ _define("xla_attribution_instrumentation", bool, True,
         "memory_analysis capture on compile, MFU/MBU + roofline "
         "verdicts from sampled walls, rows into the GCS "
         "report_xla_programs ring, and the PERF_REGRESSION sentinel. "
-        "Off = plain trace/compile counters only; the "
-        "xla_attribution_overhead bench prices the delta.")
+        "Off = plain trace/compile counters only.")
 _define("xla_wall_sample_every", int, 64,
         "Sample every Nth steady-state call of a tracked jitted "
         "function with block_until_ready to measure an honest "

@@ -16,6 +16,18 @@ arrive via HF/DeepSpeed through the generic worker group, e.g.
 - Attention is pluggable: "xla" einsum (fused by XLA), "flash"
   (ray_tpu.ops pallas kernel on TPU), or "ring" (context parallel over a
   mesh axis) — selected by config or overridden per call.
+- ONE definition of a layer (`_layer`: norm, q/k/v projections, rotary,
+  the cache's attention, `wo`, SwiGLU or experts) and one trunk
+  (`_trunk`: embedding, the `layers` scan) under every entry point.
+  What differs between training, prefill, decode and verify is where a
+  layer's new K/V rows go and which rows its queries see, and that is a
+  cache object: `_NoCache` (`forward_hidden`, `prefill_kv`), `_Stripe`
+  (`decode_step`: `generate`, the speculative draft), `_History`
+  (`prefill_kv_paged`: the engine's insert), `_Paged`
+  (`decode_step_paged`, `verify_kv_paged`: the engine's tick and
+  verify). `generate` / `prefill` / `decode_step` are the reference the
+  engine's parity tests compare against: a path of their own over the
+  shared layer.
 """
 
 from __future__ import annotations
@@ -29,7 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.serving import DenseFns, ServingFns
+from ray_tpu.models.serving import DraftFns, ServingFns
 
 
 @dataclasses.dataclass(frozen=True)
@@ -163,8 +175,8 @@ def quantize_weights_int8(params: Dict[str, Any]) -> Dict[str, Any]:
     materializes). Norms and the embedding gather stay in bf16.
 
     Returns a params-shaped pytree where each quantized weight `w`
-    becomes the pair `w_q` (int8) + `w_s` (f32 scales); consumed by
-    decode_step/prefill via `_weight`.
+    becomes the pair `w_q` (int8) + `w_s` (f32 scales); every path
+    reads its weights through `_weight`, which takes either.
     """
     def quant(w):
         w32 = w.astype(jnp.float32)
@@ -218,10 +230,12 @@ def rope_freqs(head_dim: int, max_len: int, theta: float) -> Tuple[jax.Array, ja
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """x: [B, S, H, D]; cos/sin: [S, D/2]."""
+    """x: [B, Q, H, D] rotated by its positions' rows of the table:
+    cos/sin [B, Q, D/2], or [Q, D/2] where every sequence sits at the
+    same positions."""
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    cos = cos[None, :, None, :]
-    sin = sin[None, :, None, :]
+    cos = cos[..., None, :]
+    sin = sin[..., None, :]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
 
@@ -309,32 +323,8 @@ def embed_lookup(embed: jax.Array, tokens: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Forward
+# One layer, one trunk, and the caches a layer attends through
 # ---------------------------------------------------------------------------
-
-def _layer(config: LlamaConfig, cos, sin, attn_fn, x, layer_params):
-    c = config
-    p = layer_params
-    B, S, _ = x.shape
-    kd = c.head_dim
-
-    # Scope names (`attn`, `mlp`, ...) reach each operation's `op_name`
-    # and so a device trace; they change nothing in the compiled program.
-    with jax.named_scope("attn"):
-        h = rms_norm(x, p["attn_norm"], c.norm_eps)
-        q = (h @ p["wq"].astype(c.dtype)).reshape(B, S, c.n_heads, kd)
-        k = (h @ p["wk"].astype(c.dtype)).reshape(B, S, c.n_kv_heads, kd)
-        v = (h @ p["wv"].astype(c.dtype)).reshape(B, S, c.n_kv_heads, kd)
-        q = apply_rope(q, cos[:S], sin[:S])
-        k = apply_rope(k, cos[:S], sin[:S])
-        k = _repeat_kv(k, c.n_heads // c.n_kv_heads)
-        v = _repeat_kv(v, c.n_heads // c.n_kv_heads)
-        attn = attn_fn(q, k, v, causal=True)
-        x = x + attn.reshape(B, S, -1) @ p["wo"].astype(c.dtype)
-
-    with jax.named_scope("mlp"):
-        return _ffn(c, x, p)
-
 
 def _ffn(c: LlamaConfig, x, p):
     """A layer's feed-forward half, SwiGLU or experts: (x + delta, aux)."""
@@ -350,27 +340,54 @@ def _ffn(c: LlamaConfig, x, p):
             "router": p["router"], "w_gate": p["w_gate"],
             "w_up": p["w_up"], "w_down": p["w_down"]}, mcfg)
         return x + delta, aux
-    gate = jax.nn.silu(h @ p["w_gate"].astype(c.dtype))
-    up = h @ p["w_up"].astype(c.dtype)
-    x = x + (gate * up) @ p["w_down"].astype(c.dtype)
+    gate = jax.nn.silu(h @ _weight(p, "w_gate", c.dtype))
+    up = h @ _weight(p, "w_up", c.dtype)
+    x = x + (gate * up) @ _weight(p, "w_down", c.dtype)
     return x, jnp.zeros((), jnp.float32)
 
 
-def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
-                   config: LlamaConfig,
-                   attn_impl: Optional[str] = None):
-    """Trunk only: tokens [B, S] -> (hidden [B, S, D], aux). The fused
-    training loss consumes hidden states directly so the [B, S, V]
-    logits tensor never materializes (ops/fused_loss.py); `forward`
-    adds the lm_head matmul on top."""
-    c = config
-    impl = attn_impl or c.attn_impl
-    attn_fn = _get_attention_fn(impl)
-    cos, sin = rope_freqs(c.head_dim, c.max_seq_len, c.rope_theta)
+def _layer(c: LlamaConfig, p, x, rope, cache, leaves):
+    """The one definition of a layer: x [B, Q, D], rotated by `rope`
+    (cos, sin as `apply_rope` takes them), attends through `cache`,
+    whose slices for this layer are `leaves`.  Returns (x, what the
+    cache keeps of this layer, aux).
 
+    Scope names (`attn`, `mlp`, ...) reach each operation's `op_name`
+    and so a device trace; they change nothing in the compiled program."""
+    B, Q, _ = x.shape
+    kd = c.head_dim
+    with jax.named_scope("attn"):
+        h = rms_norm(x, p["attn_norm"], c.norm_eps)
+        q = (h @ _weight(p, "wq", c.dtype)).reshape(B, Q, c.n_heads, kd)
+        k = (h @ _weight(p, "wk", c.dtype)).reshape(B, Q, c.n_kv_heads, kd)
+        v = (h @ _weight(p, "wv", c.dtype)).reshape(B, Q, c.n_kv_heads, kd)
+        q, k = apply_rope(q, *rope), apply_rope(k, *rope)
+    attn, kept = cache.attend(c, q, k, v, leaves)
+    with jax.named_scope("attn"):
+        x = x + attn.reshape(B, Q, -1) @ _weight(p, "wo", c.dtype)
+    with jax.named_scope("mlp"):
+        x, aux = _ffn(c, x, p)
+    return x, kept, aux
+
+
+def _trunk(c: LlamaConfig, params, tokens, rope, cache, scoring=False):
+    """Embedding and the scan over the stacked layers, the cache's
+    per-layer leaves beside each layer's weights: tokens [B, Q] ->
+    (hidden before the final norm, the scan's stacked outputs).  Those
+    are what the cache keeps of each layer ([L, ...] leaves), or with
+    `scoring` (no cache is made: training, `forward`) the layers' aux
+    terms [L], the only case experts are implemented for."""
+    if c.n_experts and not scoring:
+        raise NotImplementedError(
+            "KV-cache prefill, decode and verify are not implemented for "
+            "MoE configs; use forward() for scoring")
     x = embed_lookup(params["embed"].astype(c.dtype), tokens)
 
-    layer_fn = partial(_layer, c, cos, sin, attn_fn)
+    def layer_fn(x, inputs):
+        p, leaves = inputs
+        x, kept, aux = _layer(c, p, x, rope, cache, leaves)
+        return x, (aux if scoring else kept)
+
     if isinstance(c.remat, str) and c.remat != "dots":
         raise ValueError(
             f"remat={c.remat!r}: expected False, True, or 'dots'")
@@ -384,13 +401,133 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
     elif c.remat:
         layer_fn = jax.checkpoint(layer_fn)
 
-    def scan_body(x, layer_params):
-        return layer_fn(x, layer_params)
-
     # `layers` names what the scan itself does around the body: slicing
-    # a layer's weights out of the stack, stacking what the backward needs.
+    # a layer's weights and cache out of the stacks, stacking what the
+    # backward needs, writing the cache back.
     with jax.named_scope("layers"):
-        x, aux = lax.scan(scan_body, x, params["layers"])
+        return lax.scan(layer_fn, x, (params["layers"], cache.leaves))
+
+
+class _NoCache:
+    """The sequence's own rows are its keys, through the pluggable
+    attention; keeps the new (pre-repeat) k, v rows."""
+    leaves = ()
+
+    def __init__(self, attn_fn):
+        self.attn_fn = attn_fn
+
+    def attend(self, c, q, k, v, leaves):
+        rep = c.n_heads // c.n_kv_heads
+        with jax.named_scope("attn"):
+            return self.attn_fn(q, _repeat_kv(k, rep), _repeat_kv(v, rep),
+                                causal=True), (k, v)
+
+
+class _Stripe:
+    """One [S] stripe a sequence, [L, B, S, n_kv, head_dim]: the new row
+    is written at the sequence's position (out of bounds, so dropped,
+    for an inactive one) and the whole stripe attended under the
+    position mask.  Keeps the updated stripes."""
+
+    def __init__(self, cache, positions, active):
+        self.leaves = (cache["k"], cache["v"])
+        S = cache["k"].shape[2]
+        self.positions = positions
+        self.write_pos = (positions if active is None
+                          else jnp.where(active, positions, S))
+
+    def attend(self, c, q, k, v, leaves):
+        k_cache, v_cache = leaves
+        bidx = jnp.arange(q.shape[0])
+        with jax.named_scope("kv_write"):
+            k_cache = k_cache.at[bidx, self.write_pos].set(k[:, 0])
+            v_cache = v_cache.at[bidx, self.write_pos].set(v[:, 0])
+        attn = _decode_attention(q, k_cache, v_cache,
+                                 self.positions[:, None])
+        return attn, (k_cache, v_cache)
+
+
+class _History:
+    """ONE sequence with its gathered history [L, S_pad, n_kv, head_dim]:
+    the new rows land at `start` of the layer's history and the queries
+    at `qpos` see keys at positions <= their own.  Keeps the new rows
+    for the engine to scatter into the pool."""
+
+    def __init__(self, hist_k, hist_v, start, qpos):
+        self.leaves = (hist_k, hist_v)
+        self.start, self.qpos = start, qpos
+
+    def attend(self, c, q, k, v, leaves):
+        hk, hv = leaves
+        keys = lax.dynamic_update_slice(hk, k[0].astype(hk.dtype),
+                                        (self.start, 0, 0))
+        vals = lax.dynamic_update_slice(hv, v[0].astype(hv.dtype),
+                                        (self.start, 0, 0))
+        rep = c.n_heads // c.n_kv_heads
+        with jax.named_scope("attn"):
+            attn = xla_attention(
+                q, _repeat_kv(keys[None].astype(c.dtype), rep),
+                _repeat_kv(vals[None].astype(c.dtype), rep),
+                causal=True, positions=self.qpos)
+        return attn, (k, v)
+
+
+class _Paged:
+    """New rows against the paged pool, at absolute positions `qpos`:
+    [B, Q] for Q queries a sequence, or [B] for one (the same thing at
+    the index shapes the decode tick was compiled with).  Each row is
+    written at (table[pos // bs], pos % bs) -- a physical block id out
+    of bounds, so dropped, for an inactive sequence -- and then every
+    sequence's dense [S_pad] view is gathered through its block table
+    (AFTER the writes, so a query sees its own row and those before it)
+    and read as it lies.  Keeps the updated pools."""
+
+    def __init__(self, pools, block_tables, qpos, active):
+        self.leaves = (pools["k"], pools["v"])
+        NB, bs = pools["k"].shape[1], pools["k"].shape[2]
+        seq = jnp.arange(qpos.shape[0]).reshape(
+            (-1,) + (1,) * (qpos.ndim - 1))
+        self.tables, self.qpos = block_tables, qpos
+        phys = block_tables[seq, qpos // bs]
+        if active is not None:
+            phys = jnp.where(active.reshape(seq.shape), phys, NB)
+        self.phys, self.off = phys, qpos % bs
+
+    def attend(self, c, q, k, v, leaves):
+        k_pool, v_pool = leaves
+        B, nb = self.tables.shape
+        dense = (B, nb * k_pool.shape[1], c.n_kv_heads, c.head_dim)
+        new = self.phys.shape + k.shape[2:]
+        with jax.named_scope("kv_write"):
+            k_pool = k_pool.at[self.phys, self.off].set(
+                k.reshape(new).astype(k_pool.dtype))
+            v_pool = v_pool.at[self.phys, self.off].set(
+                v.reshape(new).astype(v_pool.dtype))
+        with jax.named_scope("kv_gather"):
+            k_dense = k_pool[self.tables].reshape(dense)
+            v_dense = v_pool[self.tables].reshape(dense)
+        attn = _decode_attention(q, k_dense, v_dense,
+                                 self.qpos.reshape(B, -1))
+        return attn, (k_pool, v_pool)
+
+
+# ---------------------------------------------------------------------------
+# Forward (training, scoring)
+# ---------------------------------------------------------------------------
+
+def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
+                   config: LlamaConfig,
+                   attn_impl: Optional[str] = None):
+    """Trunk only: tokens [B, S] -> (hidden [B, S, D], aux). The fused
+    training loss consumes hidden states directly so the [B, S, V]
+    logits tensor never materializes (ops/fused_loss.py); `forward`
+    adds the lm_head matmul on top."""
+    c = config
+    S = tokens.shape[1]
+    cos, sin = rope_freqs(c.head_dim, c.max_seq_len, c.rope_theta)
+    cache = _NoCache(_get_attention_fn(attn_impl or c.attn_impl))
+    x, aux = _trunk(c, params, tokens, (cos[:S], sin[:S]),
+                    cache, scoring=True)
     x = rms_norm(x, params["norm_f"], c.norm_eps)
     return x, jnp.sum(aux)
 
@@ -401,15 +538,9 @@ def forward(params: Dict[str, Any], tokens: jax.Array,
             return_aux: bool = False):
     """tokens [B, S] int32 -> logits [B, S, V] (or (logits, aux_loss)
     with return_aux — the MoE router load-balance term)."""
-    c = config
     x, aux = forward_hidden(params, tokens, config, attn_impl)
-    head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-    # bf16 matmul on the MXU (fp32 here costs ~4x), fp32 accumulation for
-    # the softmax/loss that follows.
     with jax.named_scope("lm_head"):
-        logits = jax.lax.dot_general(
-            x, head.astype(c.dtype), (((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        logits = _head(config, params, x)
     if return_aux:
         return logits, aux
     return logits
@@ -438,8 +569,7 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
                                      attn_impl)
         c = config
         with jax.named_scope("loss_head"):
-            head = (params["embed"].T if c.tie_embeddings
-                    else params["lm_head"]).astype(c.dtype)
+            head = lm_head_weight(params, c)
             b, s, d = hidden.shape
             nll = blockwise_xent(hidden.reshape(b * s, d), head,
                                  targets.reshape(-1)).reshape(b, s)
@@ -460,16 +590,33 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
     return nll.mean() + aux
 
 
-def flops_per_token(config: LlamaConfig, seq_len: int) -> float:
-    """Approximate training FLOPs/token (fwd+bwd ~ 6*N + attention)."""
-    n = config.num_params()
-    attn = 12 * config.n_layers * config.dim * seq_len  # score+value matmuls
-    return 6.0 * n + attn
-
-
 # ---------------------------------------------------------------------------
 # Inference: KV-cache decode + generation (the Serve-on-TPU path)
 # ---------------------------------------------------------------------------
+
+def lm_head_weight(params: Dict[str, Any], config: LlamaConfig) -> jax.Array:
+    """Output-projection matrix [D, V] in compute dtype (tied or not)."""
+    if config.tie_embeddings:
+        return params["embed"].T.astype(config.dtype)
+    return _weight(params, "lm_head", config.dtype)
+
+
+def _head(c: LlamaConfig, params, x):
+    """Normed hidden [..., D] -> logits [..., V]: bf16 matmul on the MXU
+    (fp32 here costs ~4x), fp32 accumulation for the softmax/loss that
+    follows."""
+    return jax.lax.dot_general(
+        x, lm_head_weight(params, c), (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _logits(c: LlamaConfig, params, x, query=None):
+    """Final norm and output head of a cached step: hidden [B, Q, D] ->
+    logits [B, Q, V], or [B, V] of the one query `query`."""
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["norm_f"], c.norm_eps)
+        return _head(c, params, x if query is None else x[:, query])
+
 
 def init_kv_cache(config: LlamaConfig, batch_size: int,
                   max_len: Optional[int] = None) -> Dict[str, jax.Array]:
@@ -513,63 +660,18 @@ def decode_step(params: Dict[str, Any], cache: Dict[str, jax.Array],
 
     ``active`` [B] bool (optional) slot-masks the KV write: inactive
     rows keep their cache untouched (the write index is pushed out of
-    bounds, where scatter drops it) so a continuous-batching engine can
-    run dead slots through the same fixed-shape program without
+    bounds, where scatter drops it) so a fixed-shape program (the
+    engine's speculative draft) can run dead slots through without
     corrupting rows a later prefill has already claimed. Logits for
     inactive rows are garbage by construction — callers ignore them.
     """
-    if config.n_experts:
-        raise NotImplementedError(
-            "KV-cache decode for MoE configs is not implemented yet; "
-            "use forward() for scoring")
     c = config
     cos, sin = rope_freqs(c.head_dim, cache["k"].shape[2], c.rope_theta)
-    x = embed_lookup(params["embed"].astype(c.dtype), tokens[:, None])
-    B = tokens.shape[0]
-    kd = c.head_dim
-    pos_cos = cos[positions][:, None, :]       # [B, 1, D/2]
-    pos_sin = sin[positions][:, None, :]
-
-    def rope1(t):  # [B, 1, H, D]
-        t1, t2 = jnp.split(t.astype(jnp.float32), 2, axis=-1)
-        pc = pos_cos[:, :, None, :]
-        ps = pos_sin[:, :, None, :]
-        return jnp.concatenate(
-            [t1 * pc - t2 * ps, t2 * pc + t1 * ps], axis=-1).astype(t.dtype)
-
-    def layer(carry, inputs):
-        x = carry
-        p, k_cache, v_cache = inputs
-        h = rms_norm(x, p["attn_norm"], c.norm_eps)
-        q = (h @ _weight(p, "wq", c.dtype)).reshape(B, 1, c.n_heads, kd)
-        k = (h @ _weight(p, "wk", c.dtype)).reshape(B, 1, c.n_kv_heads, kd)
-        v = (h @ _weight(p, "wv", c.dtype)).reshape(B, 1, c.n_kv_heads, kd)
-        q, k = rope1(q), rope1(k)
-        # Write this token's k/v at its position. Inactive slots write at
-        # S (out of bounds -> dropped), leaving their rows untouched.
-        bidx = jnp.arange(B)
-        if active is None:
-            write_pos = positions
-        else:
-            write_pos = jnp.where(active, positions, k_cache.shape[1])
-        k_cache = k_cache.at[bidx, write_pos].set(k[:, 0])
-        v_cache = v_cache.at[bidx, write_pos].set(v[:, 0])
-        attn = _decode_attention(q, k_cache, v_cache, positions[:, None])
-        x = x + attn.reshape(B, 1, -1) @ _weight(p, "wo", c.dtype)
-        h = rms_norm(x, p["ffn_norm"], c.norm_eps)
-        gate = jax.nn.silu(h @ _weight(p, "w_gate", c.dtype))
-        up = h @ _weight(p, "w_up", c.dtype)
-        x = x + (gate * up) @ _weight(p, "w_down", c.dtype)
-        return x, (k_cache, v_cache)
-
-    x, (new_k, new_v) = lax.scan(
-        layer, x, (params["layers"], cache["k"], cache["v"]))
-    x = rms_norm(x, params["norm_f"], c.norm_eps)
-    head = lm_head_weight(params, c)
-    logits = jax.lax.dot_general(
-        x[:, 0], head, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return logits, {"k": new_k, "v": new_v}
+    x, (new_k, new_v) = _trunk(
+        c, params, tokens[:, None],
+        (cos[positions][:, None, :], sin[positions][:, None, :]),
+        _Stripe(cache, positions, active))
+    return _logits(c, params, x, query=0), {"k": new_k, "v": new_v}
 
 
 def init_paged_kv_cache(config: LlamaConfig, num_blocks: int,
@@ -585,185 +687,72 @@ def init_paged_kv_cache(config: LlamaConfig, num_blocks: int,
     return {"k": jnp.zeros(shape, c.dtype), "v": jnp.zeros(shape, c.dtype)}
 
 
+def verify_kv_paged(params: Dict[str, Any], pools: Dict[str, jax.Array],
+                    block_tables: jax.Array, tokens: jax.Array,
+                    positions: jax.Array, config: LlamaConfig,
+                    active: Optional[jax.Array] = None):
+    """K tokens a sequence against the paged pool, consumed in parallel:
+    tokens [B, K], token j of row b at absolute position
+    ``positions[b] + j``; block_tables [B, max_blocks] int32 maps each
+    sequence's logical block index -> physical pool block. Returns
+    (logits [B, K, V], updated pools). The speculative verify step, and
+    at K = 1 the decode step (`decode_step_paged`).
+
+    Row j's logits are the target model's distribution for the token
+    FOLLOWING input j — exactly what K single-token steps would produce
+    after consuming inputs 0..j one at a time, because every op here is
+    row-independent (per-position matmuls, and `_decode_attention` with
+    K queries a row instead of one): running K queries through one
+    program instead of K programs changes batching, not values. The
+    engine exploits this for draft verification: accept the longest
+    prefix where the target's argmax agrees with the draft, and greedy
+    parity holds by construction.
+
+    All K KV writes scatter before the dense gather (`_Paged`), so input
+    j attends to inputs i < j (their positions pass the ``key_pos <=
+    pos + j`` mask) and never to inputs i > j. Rejected inputs leave
+    stale rows past the accepted position — the same stale-rows-
+    overwritten-before-attended invariant every other path in this file
+    relies on. ``active`` masks writes by pushing the physical block id
+    out of bounds.
+    """
+    c = config
+    K = tokens.shape[1]
+    S_pad = block_tables.shape[1] * pools["k"].shape[2]
+    cos, sin = rope_freqs(c.head_dim, S_pad, c.rope_theta)
+    # Absolute position of every query; clamped so inactive rows with
+    # garbage positions still index rope/scatter safely (their writes
+    # are dropped and their logits ignored).
+    qpos = jnp.minimum(positions[:, None] + jnp.arange(K)[None, :],
+                       S_pad - 1)                            # [B, K]
+    x, (new_k, new_v) = _trunk(
+        c, params, tokens, (cos[qpos], sin[qpos]),
+        _Paged(pools, block_tables, qpos, active))
+    return _logits(c, params, x), {"k": new_k, "v": new_v}
+
+
 def decode_step_paged(params: Dict[str, Any], pools: Dict[str, jax.Array],
                       block_tables: jax.Array, tokens: jax.Array,
                       positions: jax.Array, config: LlamaConfig,
                       active: Optional[jax.Array] = None):
     """One incremental token against the paged pool: tokens [B] at
-    `positions` [B], block_tables [B, max_blocks] int32 mapping each
-    sequence's logical block index -> physical pool block. Returns
-    (logits [B, V], updated pools).
+    `positions` [B]. Returns (logits [B, V], updated pools).
 
     Token-exact with `decode_step` on a dense cache holding the same
     logical contents: the gather assembles each sequence's dense
     [S_pad] view (S_pad = max_blocks * block_size), the write lands at
     (table[pos // bs], pos % bs), and the same `_decode_attention` reads
     that view as it lies ([B, S_pad, kvH, D], no GQA repeat) and drops
-    padding/stale rows to exact zeros. ``active`` masks the pool write
-    by pushing the physical block index out of bounds (scatter drops
-    it), mirroring the dense path's out-of-bounds position trick.
+    padding/stale rows to exact zeros.
     """
-    if config.n_experts:
-        raise NotImplementedError(
-            "paged KV-cache decode for MoE configs is not implemented")
     c = config
-    NB, bs = pools["k"].shape[1], pools["k"].shape[2]
-    max_blocks = block_tables.shape[1]
-    S_pad = max_blocks * bs
+    S_pad = block_tables.shape[1] * pools["k"].shape[2]
     cos, sin = rope_freqs(c.head_dim, S_pad, c.rope_theta)
-    x = embed_lookup(params["embed"].astype(c.dtype), tokens[:, None])
-    B = tokens.shape[0]
-    kd = c.head_dim
-    pos_cos = cos[positions][:, None, :]
-    pos_sin = sin[positions][:, None, :]
-
-    def rope1(t):  # [B, 1, H, D]
-        t1, t2 = jnp.split(t.astype(jnp.float32), 2, axis=-1)
-        pc = pos_cos[:, :, None, :]
-        ps = pos_sin[:, :, None, :]
-        return jnp.concatenate(
-            [t1 * pc - t2 * ps, t2 * pc + t1 * ps], axis=-1).astype(t.dtype)
-
-    bidx = jnp.arange(B)
-    phys = block_tables[bidx, positions // bs]
-    if active is not None:
-        phys = jnp.where(active, phys, NB)     # OOB scatter -> dropped
-    off = positions % bs
-
-    def layer(carry, inputs):
-        x = carry
-        p, k_pool, v_pool = inputs
-        with jax.named_scope("attn"):
-            h = rms_norm(x, p["attn_norm"], c.norm_eps)
-            q = (h @ _weight(p, "wq", c.dtype)).reshape(
-                B, 1, c.n_heads, kd)
-            k = (h @ _weight(p, "wk", c.dtype)).reshape(
-                B, 1, c.n_kv_heads, kd)
-            v = (h @ _weight(p, "wv", c.dtype)).reshape(
-                B, 1, c.n_kv_heads, kd)
-            q, k = rope1(q), rope1(k)
-        with jax.named_scope("kv_write"):
-            k_pool = k_pool.at[phys, off].set(k[:, 0].astype(k_pool.dtype))
-            v_pool = v_pool.at[phys, off].set(v[:, 0].astype(v_pool.dtype))
-        # Per-sequence dense view via the block table (gather AFTER the
-        # write so this token's own row is attendable at `positions`).
-        with jax.named_scope("kv_gather"):
-            k_dense = k_pool[block_tables].reshape(
-                B, S_pad, c.n_kv_heads, kd)
-            v_dense = v_pool[block_tables].reshape(
-                B, S_pad, c.n_kv_heads, kd)
-        attn = _decode_attention(q, k_dense, v_dense, positions[:, None])
-        with jax.named_scope("attn"):
-            x = x + attn.reshape(B, 1, -1) @ _weight(p, "wo", c.dtype)
-        with jax.named_scope("mlp"):
-            h = rms_norm(x, p["ffn_norm"], c.norm_eps)
-            gate = jax.nn.silu(h @ _weight(p, "w_gate", c.dtype))
-            up = h @ _weight(p, "w_up", c.dtype)
-            x = x + (gate * up) @ _weight(p, "w_down", c.dtype)
-        return x, (k_pool, v_pool)
-
-    # `layers`: the scan's own slicing of a layer's weights and pool out
-    # of the stacks, and writing the pool back.
-    with jax.named_scope("layers"):
-        x, (new_k, new_v) = lax.scan(
-            layer, x, (params["layers"], pools["k"], pools["v"]))
-    with jax.named_scope("lm_head"):
-        x = rms_norm(x, params["norm_f"], c.norm_eps)
-        head = lm_head_weight(params, c)
-        logits = jax.lax.dot_general(
-            x[:, 0], head, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    return logits, {"k": new_k, "v": new_v}
-
-
-def verify_kv_paged(params: Dict[str, Any], pools: Dict[str, jax.Array],
-                    block_tables: jax.Array, tokens: jax.Array,
-                    positions: jax.Array, config: LlamaConfig,
-                    active: Optional[jax.Array] = None):
-    """K-token verify step for speculative decoding: tokens [B, K] are
-    consumed in parallel, token j of row b at absolute position
-    ``positions[b] + j``. Returns (logits [B, K, V], updated pools).
-
-    Row j's logits are the target model's distribution for the token
-    FOLLOWING input j — exactly what ``decode_step_paged`` would produce
-    after consuming inputs 0..j one at a time, because every op here is
-    row-independent (per-position matmuls, and `_decode_attention`, the
-    decode step's own helper, with K queries a row instead of one):
-    running K queries through one program instead of K programs changes
-    batching, not values. The engine exploits this for draft
-    verification: accept the longest prefix where the target's argmax
-    agrees with the draft, and greedy parity holds by construction.
-
-    All K KV writes scatter before the dense gather, so input j attends
-    to inputs i < j (their positions pass the ``key_pos <= pos + j``
-    mask) and never to inputs i > j. Rejected inputs leave stale rows
-    past the accepted position — the same stale-rows-overwritten-
-    before-attended invariant every other path in this file relies on.
-    ``active`` masks writes by pushing the physical block id out of
-    bounds, mirroring ``decode_step_paged``.
-    """
-    if config.n_experts:
-        raise NotImplementedError(
-            "paged KV-cache verify for MoE configs is not implemented")
-    c = config
-    NB, bs = pools["k"].shape[1], pools["k"].shape[2]
-    max_blocks = block_tables.shape[1]
-    S_pad = max_blocks * bs
-    cos, sin = rope_freqs(c.head_dim, S_pad, c.rope_theta)
-    B, K = tokens.shape
-    kd = c.head_dim
-    # Absolute position of every query; clamped so inactive rows with
-    # garbage positions still index rope/scatter safely (their writes
-    # are dropped and their logits ignored).
-    qpos = jnp.minimum(positions[:, None] + jnp.arange(K)[None, :],
-                       S_pad - 1)                            # [B, K]
-    pos_cos = cos[qpos]                                      # [B, K, D/2]
-    pos_sin = sin[qpos]
-
-    x = embed_lookup(params["embed"].astype(c.dtype), tokens)
-
-    def ropek(t):  # [B, K, H, D] rotated by per-(row, query) position
-        t1, t2 = jnp.split(t.astype(jnp.float32), 2, axis=-1)
-        pc = pos_cos[:, :, None, :]
-        ps = pos_sin[:, :, None, :]
-        return jnp.concatenate(
-            [t1 * pc - t2 * ps, t2 * pc + t1 * ps], axis=-1).astype(t.dtype)
-
-    phys = block_tables[jnp.arange(B)[:, None], qpos // bs]  # [B, K]
-    if active is not None:
-        phys = jnp.where(active[:, None], phys, NB)  # OOB scatter drop
-    off = qpos % bs
-
-    def layer(carry, inputs):
-        x = carry
-        p, k_pool, v_pool = inputs
-        h = rms_norm(x, p["attn_norm"], c.norm_eps)
-        q = (h @ _weight(p, "wq", c.dtype)).reshape(B, K, c.n_heads, kd)
-        k = (h @ _weight(p, "wk", c.dtype)).reshape(B, K, c.n_kv_heads, kd)
-        v = (h @ _weight(p, "wv", c.dtype)).reshape(B, K, c.n_kv_heads, kd)
-        q, k = ropek(q), ropek(k)
-        k_pool = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
-        v_pool = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
-        # Dense per-sequence view gathered AFTER all K writes: query j
-        # sees queries i < j through `_decode_attention`'s position mask.
-        k_dense = k_pool[block_tables].reshape(B, S_pad, c.n_kv_heads, kd)
-        v_dense = v_pool[block_tables].reshape(B, S_pad, c.n_kv_heads, kd)
-        attn = _decode_attention(q, k_dense, v_dense, qpos)
-        x = x + attn.reshape(B, K, -1) @ _weight(p, "wo", c.dtype)
-        h = rms_norm(x, p["ffn_norm"], c.norm_eps)
-        gate = jax.nn.silu(h @ _weight(p, "w_gate", c.dtype))
-        up = h @ _weight(p, "w_up", c.dtype)
-        x = x + (gate * up) @ _weight(p, "w_down", c.dtype)
-        return x, (k_pool, v_pool)
-
-    x, (new_k, new_v) = lax.scan(
-        layer, x, (params["layers"], pools["k"], pools["v"]))
-    x = rms_norm(x, params["norm_f"], c.norm_eps)
-    head = lm_head_weight(params, c)
-    logits = jax.lax.dot_general(
-        x, head, (((2,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                  # [B, K, V]
-    return logits, {"k": new_k, "v": new_v}
+    x, (new_k, new_v) = _trunk(
+        c, params, tokens[:, None],
+        (cos[positions][:, None, :], sin[positions][:, None, :]),
+        _Paged(pools, block_tables, positions, active))
+    return _logits(c, params, x, query=0), {"k": new_k, "v": new_v}
 
 
 def prefill_kv_paged(params: Dict[str, Any], tokens: jax.Array,
@@ -781,49 +770,11 @@ def prefill_kv_paged(params: Dict[str, Any], tokens: jax.Array,
     uses ONE program family for both fresh and prefix-hit admission.
     """
     c = config
-    B, Pb = tokens.shape
-    S_pad = hist_k.shape[1]
-    cos, sin = rope_freqs(c.head_dim, S_pad, c.rope_theta)
-    qpos = start + jnp.arange(Pb)
-    kd = c.head_dim
-
-    x = embed_lookup(params["embed"].astype(c.dtype), tokens)
-
-    def scan_body(x, inputs):
-        p, hk, hv = inputs
-        h = rms_norm(x, p["attn_norm"], c.norm_eps)
-        q = (h @ _weight(p, "wq", c.dtype)).reshape(B, Pb, c.n_heads, kd)
-        k = (h @ _weight(p, "wk", c.dtype)).reshape(B, Pb, c.n_kv_heads, kd)
-        v = (h @ _weight(p, "wv", c.dtype)).reshape(B, Pb, c.n_kv_heads, kd)
-        q = apply_rope(q, cos[qpos], sin[qpos])
-        k = apply_rope(k, cos[qpos], sin[qpos])
-        keys = lax.dynamic_update_slice(hk, k[0].astype(hk.dtype),
-                                        (start, 0, 0))
-        vals = lax.dynamic_update_slice(hv, v[0].astype(hv.dtype),
-                                        (start, 0, 0))
-        rep = c.n_heads // c.n_kv_heads
-        attn = xla_attention(
-            q, _repeat_kv(keys[None].astype(c.dtype), rep),
-            _repeat_kv(vals[None].astype(c.dtype), rep),
-            causal=True, positions=qpos)
-        x = x + attn.reshape(B, Pb, -1) @ _weight(p, "wo", c.dtype)
-        h = rms_norm(x, p["ffn_norm"], c.norm_eps)
-        gate = jax.nn.silu(h @ _weight(p, "w_gate", c.dtype))
-        up = h @ _weight(p, "w_up", c.dtype)
-        x = x + (gate * up) @ _weight(p, "w_down", c.dtype)
-        return x, (k, v)
-
-    x, (ks, vs) = lax.scan(scan_body, x, (params["layers"],
-                                          hist_k, hist_v))
-    x = rms_norm(x, params["norm_f"], c.norm_eps)
-    return x, ks, vs
-
-
-def lm_head_weight(params: Dict[str, Any], config: LlamaConfig) -> jax.Array:
-    """Output-projection matrix [D, V] in compute dtype (tied or not)."""
-    if config.tie_embeddings:
-        return params["embed"].T.astype(config.dtype)
-    return _weight(params, "lm_head", config.dtype)
+    cos, sin = rope_freqs(c.head_dim, hist_k.shape[1], c.rope_theta)
+    qpos = start + jnp.arange(tokens.shape[1])
+    x, (ks, vs) = _trunk(c, params, tokens, (cos[qpos], sin[qpos]),
+                         _History(hist_k, hist_v, start, qpos))
+    return rms_norm(x, params["norm_f"], c.norm_eps), ks, vs
 
 
 def prefill_kv(params: Dict[str, Any], tokens: jax.Array,
@@ -831,37 +782,14 @@ def prefill_kv(params: Dict[str, Any], tokens: jax.Array,
     """Prefill trunk: prompt [B, P] -> (normed hidden [B, P, D],
     per-layer pre-repeat ks/vs [L, B, P, n_kv, head_dim]).
 
-    Shared by `prefill` (whole-cache fill) and the continuous-batching
-    engine's insert-at-slot path (serve/llm/engine.py) so both produce
+    Shared by `prefill` (whole-cache fill) and the engine's seeding of
+    the speculative draft's cache (serve/llm/engine.py) so both produce
     bit-identical KV for the same prompt."""
     c = config
-    B, P = tokens.shape
-    cos, sin = rope_freqs(c.head_dim, P, c.rope_theta)
-    attn_fn = _get_attention_fn(c.attn_impl)
-    kd = c.head_dim
-
-    x = embed_lookup(params["embed"].astype(c.dtype), tokens)
-
-    def scan_body(x, p):
-        h = rms_norm(x, p["attn_norm"], c.norm_eps)
-        q = (h @ _weight(p, "wq", c.dtype)).reshape(B, P, c.n_heads, kd)
-        k = (h @ _weight(p, "wk", c.dtype)).reshape(B, P, c.n_kv_heads, kd)
-        v = (h @ _weight(p, "wv", c.dtype)).reshape(B, P, c.n_kv_heads, kd)
-        q = apply_rope(q, cos[:P], sin[:P])
-        k = apply_rope(k, cos[:P], sin[:P])
-        rep = c.n_heads // c.n_kv_heads
-        attn = attn_fn(q, _repeat_kv(k, rep), _repeat_kv(v, rep),
-                       causal=True)
-        x = x + attn.reshape(B, P, -1) @ _weight(p, "wo", c.dtype)
-        h = rms_norm(x, p["ffn_norm"], c.norm_eps)
-        gate = jax.nn.silu(h @ _weight(p, "w_gate", c.dtype))
-        up = h @ _weight(p, "w_up", c.dtype)
-        x = x + (gate * up) @ _weight(p, "w_down", c.dtype)
-        return x, (k, v)
-
-    x, (ks, vs) = lax.scan(scan_body, x, params["layers"])
-    x = rms_norm(x, params["norm_f"], c.norm_eps)
-    return x, ks, vs
+    cos, sin = rope_freqs(c.head_dim, tokens.shape[1], c.rope_theta)
+    x, (ks, vs) = _trunk(c, params, tokens, (cos, sin),
+                         _NoCache(_get_attention_fn(c.attn_impl)))
+    return rms_norm(x, params["norm_f"], c.norm_eps), ks, vs
 
 
 def prefill(params: Dict[str, Any], tokens: jax.Array,
@@ -875,10 +803,7 @@ def prefill(params: Dict[str, Any], tokens: jax.Array,
     S = max_len or c.max_seq_len
 
     x, ks, vs = prefill_kv(params, tokens, config)
-    head = lm_head_weight(params, c)
-    logits = jax.lax.dot_general(
-        x[:, -1], head, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    logits = _head(c, params, x[:, -1])
 
     cache = init_kv_cache(c, B, S)
     cache = {
@@ -943,5 +868,5 @@ _SERVING = ServingFns(
     init_params=init_params, init_pool=init_paged_kv_cache,
     prefill=_serve_prefill, decode=_serve_decode,
     head_weight=lm_head_weight, quantize_int8=quantize_weights_int8,
-    dense=DenseFns(init_kv_cache, prefill_kv, decode_step),
+    draft=DraftFns(init_kv_cache, prefill_kv, decode_step),
     verify=verify_kv_paged)

@@ -23,8 +23,10 @@ export, adopt, spill, promote) and never look inside a row.
     head_weight(params, config) -> [D, V]
     init_params(config, key), and what only some models have (None
     where a model has none, and the engine refuses by name):
-    quantize_int8(params), dense (init_cache, prefill, decode: the
-    dense layout and the speculative draft), verify (speculation).
+    quantize_int8(params); verify(params, pools, tables, tok [B, K],
+    pos [B], config, active) -> (logits [B, K, V], pools), the
+    speculative target's step; draft, what a model needs to BE a
+    speculative draft (`DraftFns`).
 """
 
 from __future__ import annotations
@@ -32,7 +34,13 @@ from __future__ import annotations
 from typing import Any, Callable, NamedTuple, Optional
 
 
-class DenseFns(NamedTuple):
+class DraftFns(NamedTuple):
+    """A speculative draft's own cache, one [S] stripe a slot and not
+    paged (the draft is small; paging it would buy nothing):
+    init_cache(config, B, S) -> {k, v: [L, B, S, ...]},
+    prefill(params, tokens [1, Pb], config) -> (hidden, ks, vs), the
+    rows of one slot's stripe, and decode(params, cache, tok [B],
+    pos [B], config, active) -> (logits [B, V], cache)."""
     init_cache: Callable[..., Any]
     prefill: Callable[..., Any]
     decode: Callable[..., Any]
@@ -47,5 +55,5 @@ class ServingFns(NamedTuple):
     head_weight: Callable[..., Any]
     init_counts: Optional[Callable[..., Any]] = None
     quantize_int8: Optional[Callable[..., Any]] = None
-    dense: Optional[DenseFns] = None
+    draft: Optional[DraftFns] = None
     verify: Optional[Callable[..., Any]] = None

@@ -36,7 +36,7 @@ Rows publish to the GCS over the bounded accounting ring
 ``serve_accounting_summary`` — the train-step-ring shape), surface as
 ``util.state.serve_accounting()`` and ``GET /api/accounting``, and the
 whole plane is gated on ``serve_accounting_instrumentation`` so the
-``serve_accounting_overhead`` bench can price the on/off delta.
+on/off delta can be priced.
 """
 
 from __future__ import annotations

@@ -131,8 +131,8 @@ def record_overlap(op: str, backend: str, issued_to_awaited_s: float,
     ``wait_*`` completing; ``compute_covered_s`` is how much of that span
     was busy with overlapped compute.  What compute did not cover, the
     step serialized on: ``exposed = max(0, span - covered)``.  Returns
-    ``{"exposed_s", "hidden_s", "exposed_fraction"}`` for callers (bench)
-    that also report the numbers directly.
+    ``{"exposed_s", "hidden_s", "exposed_fraction"}`` for callers that
+    also report the numbers directly.
     """
     global _exposed_total
     span = max(float(issued_to_awaited_s), 0.0)

@@ -34,7 +34,7 @@ stalled or just slow. Three cooperating pieces answer them:
   thread stacks of the stalled worker.
 
 Everything is gated on the ``train_goodput_instrumentation`` knob so
-the ``train_goodput_overhead`` bench can price the on/off delta.
+the on/off delta can be priced.
 """
 
 from __future__ import annotations

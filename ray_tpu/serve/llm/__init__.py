@@ -10,10 +10,9 @@ separate replica pools with KV-block migration over the object store
 and speculative decoding on the decode side. Evicted prefix blocks
 spill down a memory hierarchy (HBM -> host RAM -> object store,
 `KVTierManager`) and are promoted back through the adopt scatter when
-`PromoteCostModel` says re-adopt beats re-prefill. See PERF.md
-"Serving throughput" and README "Paged KV cache & routing" /
-"Disaggregated serving" / "KV memory hierarchy" for the design
-narrative and bench numbers.
+`PromoteCostModel` says re-adopt beats re-prefill. See README "Serving
+LLMs" / "Disaggregated serving" / "KV memory hierarchy" for the
+design narrative, and PERF.md for what the benchmark's cells measure.
 """
 
 from ray_tpu.serve.llm.deployment import LLMServer, build_llm_app
@@ -22,7 +21,7 @@ from ray_tpu.serve.llm.disagg import (
     build_disagg_llm_app,
 )
 from ray_tpu.serve.llm.engine import (
-    EngineConfig, LLMEngine, Request, RequestHandle, static_batch_generate,
+    EngineConfig, LLMEngine, Request, RequestHandle,
 )
 from ray_tpu.serve.llm.kv_cache import (
     BlockAllocator, KVPrefix, KVState, KVTierManager, PrefixCache,
@@ -36,5 +35,5 @@ __all__ = [
     "LLMRouter", "LLMServer", "PrefillServer", "PrefixCache",
     "PromoteCostModel", "Request", "RequestHandle", "TierHit",
     "build_disagg_llm_app", "build_llm_app", "build_routed_llm_app",
-    "stable_hash_prefix", "static_batch_generate",
+    "stable_hash_prefix",
 ]

@@ -5,9 +5,7 @@ Requests flow router -> replica -> engine: the replica actor hosts one
 invocations (which Serve runs concurrently up to
 ``max_ongoing_requests``) just submit into the engine's queue and block
 on their handle, so many in-flight HTTP/handle requests share the one
-compiled decode program. This is the piece that turns the single-chip
-decode number (bench `llama_decode_tokens_per_sec`) into a serving
-throughput number (`llama_serve_tokens_per_sec`).
+compiled decode program.
 """
 
 from __future__ import annotations
@@ -27,9 +25,8 @@ class LLMServer:
     the replica process, never on the serialization path).
 
     ``quantize`` defaults to ``"int8"`` — weight-only int8 decode
-    measured 1.28x decode throughput (2158 vs 1683 tok/s, taken before
-    this round on an installation that no longer exists) at matched
-    quality on the serving path, so it is the serve default;
+    reads half the weight bytes a token (not measured on this chip: the
+    benchmark's serving cells run bf16 and use int8 as their control);
     pass ``quantize="bf16"`` to opt out (e.g. for bit-parity against an
     offline bf16 reference). The legacy ``quantize_int8=True`` flag is
     honored as a synonym for ``quantize="int8"``.

@@ -10,33 +10,41 @@ the same answer: keep ONE fixed-shape compiled program fed continuously.
 
 Design — a bounded set of compiled programs, everything else is data:
 
-- A fixed pool of ``B = num_slots`` decode slots sharing one KV cache
-  ``[L, B, S, n_kv, head_dim]``. Per-slot position/last-token/active
-  state are device arrays with fixed shapes.
-- ONE jitted decode tick advances all live slots together
-  (models/llama.py::decode_step with the slot-active mask: dead slots
-  ride through the program but their KV writes are dropped). The tick
-  runs `decode_block` steps per dispatch through an internal lax.scan —
+- A fixed pool of ``B = num_slots`` decode slots over ONE paged KV
+  pool: ``pool_blocks`` blocks of ``kv_block_size`` rows, shared by all
+  slots through per-slot block tables (serve/llm/kv_cache.py), so a
+  short request does not reserve ``max_seq_len`` rows and a prompt
+  prefix that is already in the pool is not prefilled again (the prefix
+  cache). The pool's leaves are the model's (models/serving.py); the
+  engine moves whole blocks and never looks inside a row. Tables,
+  positions, last tokens and the active mask are arrays of fixed shape:
+  data, so who owns which block retraces nothing.
+- ONE jitted decode tick advances all live slots together (the model's
+  paged decode step with the slot-active mask: dead slots ride through
+  the program but their KV writes are dropped). The tick runs
+  `decode_block` steps per dispatch through an internal lax.scan —
   still one compiled program — so the host's per-tick work (dispatch,
   token readback, slot bookkeeping) is paid once per block, not once
-  per token. What that buys on a local chip is not measured yet.
-- Jitted prefill at a small set of padded prompt-length buckets; the
-  resulting per-layer KV lands in the shared cache at a slot index via
-  one `dynamic_update_slice` (insert-at-slot). One compiled program per
-  bucket, so a mixed workload traces exactly
-  ``len(prefill_buckets) + 1`` engine programs — `trace_count` exposes
-  the number for the compile-guard test. Workloads that adopt migrated
-  or tier-promoted KV add exactly ONE more (the fixed-shape adopt
-  scatter, shared by disagg migration and tier promotes).
+  per token.
+- Jitted prefill at a small set of padded prompt-length buckets: the
+  insert prefills the part of the prompt the prefix cache does not hold
+  over the slot's gathered history and scatters its rows into the
+  slot's new blocks. One compiled program per bucket, so a mixed
+  workload traces ``len(prefill_buckets) + 1`` programs; migration,
+  spill and promotion add the export gather (one per row length of
+  `export_rows`) and ONE adopt scatter, speculation one round program
+  and a draft insert per bucket. `trace_count` exposes the number for
+  the compile-guard test.
 - Slot eviction/recycling is host-side bookkeeping: EOS / stop-token /
-  max_tokens free the slot, the next queued request prefills into it.
-  Stale KV beyond a recycled slot's new position is harmless — decode
-  masks positions > pos and overwrites each position before ever
-  attending to it.
+  max_tokens free the slot and its blocks, the next queued request
+  prefills into it; when the pool is exhausted a request waits at the
+  head of its lane. Stale rows beyond a sequence's position are
+  harmless — decode masks positions > pos and overwrites each position
+  before ever attending to it.
 
 Greedy decoding is token-identical to per-request
 `models.llama.generate`: padding columns contribute exact zeros through
-the masked softmax, so bucket-padded prefill and the shared-cache
+the masked softmax, so bucket-padded prefill and the block-table
 decode reproduce the static path bit-for-bit (pinned by
 tests/test_serve_llm.py::test_greedy_parity_*).
 """
@@ -58,7 +66,7 @@ class EngineConfig:
     """Shapes of the engine's compiled programs (all static)."""
 
     num_slots: int = 8              # B: concurrent sequences in flight
-    max_seq_len: int = 512          # S: shared KV cache length per slot
+    max_seq_len: int = 512          # S: longest sequence a slot can hold
     # Padded prompt lengths; a prompt compiles into the smallest bucket
     # that holds it. Keep this SHORT — each bucket is one XLA program.
     prefill_buckets: Tuple[int, ...] = (32, 64, 128)
@@ -72,23 +80,20 @@ class EngineConfig:
     # happens at the same stop condition single-stepping would hit) and
     # admission latency of one block.
     decode_block: int = 1
-    # KV layout. "dense": one [S] stripe per slot (the PR-1 layout).
-    # "paged": a fixed pool of [kv_block_size]-row blocks shared by all
-    # slots through per-slot block tables (serve/llm/kv_cache.py) —
-    # short requests stop reserving max_seq rows, and the prefix cache
-    # can skip prefill for shared prompt prefixes. Both layouts are
-    # token-exact for greedy decoding and trace the same number of
-    # programs (block tables are data, not shape).
-    kv_layout: str = "dense"
+    # The one KV layout: a fixed pool of [kv_block_size]-row blocks
+    # shared by all slots through per-slot block tables. The field has
+    # one legal value and stays only because the benchmark's cell files
+    # pass it (ROADMAP D14).
+    kv_layout: str = "paged"
     # None -> GlobalConfig.serve_kv_block_size (RAY_TPU_-overridable).
     kv_block_size: Optional[int] = None
-    # Pool size; None -> num_slots * (max_seq_len / kv_block_size), the
-    # dense equivalent (no memory saving, full parity). Undersize it to
+    # Pool size; None -> num_slots * (max_seq_len / kv_block_size):
+    # every slot can reach max_seq_len at once. Undersize it to
     # oversubscribe HBM: admission queues on exhaustion, never crashes.
     num_kv_blocks: Optional[int] = None
-    prefix_cache: bool = True       # paged only: prompt-prefix reuse
-    # Speculative decoding (paged only; armed by constructing the
-    # engine with draft_params/draft_config): the draft proposes
+    prefix_cache: bool = True       # prompt-prefix reuse
+    # Speculative decoding (armed by constructing the engine with
+    # draft_params/draft_config): the draft proposes
     # spec_k - 1 tokens per round, one paged verify step accepts the
     # longest target-agreeing prefix — 1..spec_k tokens per round with
     # greedy parity by construction. None -> GlobalConfig.serve_spec_k.
@@ -103,10 +108,10 @@ class EngineConfig:
     # gather their HBM rows into a host-RAM tier (object-store overflow
     # when a cluster is attached) and re-admissions promote them back
     # through the adopt scatter when the PromoteCostModel favors the
-    # transfer over recompute. None -> on for paged + prefix_cache
-    # engines (both migration programs already exist; the spill runs
-    # the export gather at the row lengths of `export_rows`, one trace
-    # each, all compiled by `warmup()`). Forced off otherwise.
+    # transfer over recompute. None -> on with `prefix_cache` (both
+    # migration programs already exist; the spill runs the export
+    # gather at the row lengths of `export_rows`, one trace each, all
+    # compiled by `warmup()`).
     kv_spill: Optional[bool] = None
     kv_host_tier_bytes: Optional[int] = None    # None -> GlobalConfig
     # PromoteCostModel knobs, milliseconds; None -> GlobalConfig
@@ -142,20 +147,17 @@ class EngineConfig:
             raise ValueError(
                 f"largest prefill bucket {b[-1]} exceeds max_seq_len "
                 f"{self.max_seq_len}")
-        if self.kv_layout not in ("dense", "paged"):
+        if self.kv_layout != "paged":
             raise ValueError(
-                f"kv_layout must be 'dense' or 'paged', got "
-                f"{self.kv_layout!r}")
+                f"kv_layout={self.kv_layout!r}: the engine has one KV "
+                f"layout, 'paged' (the dense per-slot stripe was removed "
+                f"at PR 28)")
         if self.kv_spill is None:
-            object.__setattr__(
-                self, "kv_spill",
-                self.kv_layout == "paged" and self.prefix_cache)
-        elif self.kv_spill and (self.kv_layout != "paged"
-                                or not self.prefix_cache):
+            object.__setattr__(self, "kv_spill", self.prefix_cache)
+        elif self.kv_spill and not self.prefix_cache:
             raise ValueError(
-                "kv_spill requires kv_layout='paged' with "
-                "prefix_cache=True (the spill hook rides prefix-cache "
-                "eviction)")
+                "kv_spill requires prefix_cache=True (the spill hook "
+                "rides prefix-cache eviction)")
         if self.kv_host_tier_bytes is None:
             object.__setattr__(
                 self, "kv_host_tier_bytes",
@@ -172,23 +174,22 @@ class EngineConfig:
         if self.kv_block_size is None:
             object.__setattr__(self, "kv_block_size",
                                int(GlobalConfig.serve_kv_block_size))
-        if self.kv_layout == "paged":
-            bs = self.kv_block_size
-            if bs < 1:
-                raise ValueError("kv_block_size must be >= 1")
-            if self.max_seq_len % bs:
-                raise ValueError(
-                    f"max_seq_len {self.max_seq_len} must be a multiple "
-                    f"of kv_block_size {bs} (block tables tile the "
-                    f"sequence exactly)")
-            bad = [x for x in b if x % bs]
-            if bad:
-                raise ValueError(
-                    f"prefill buckets {bad} must be multiples of "
-                    f"kv_block_size {bs} (suffix KV scatters whole "
-                    f"blocks)")
-            if self.num_kv_blocks is not None and self.num_kv_blocks < 1:
-                raise ValueError("num_kv_blocks must be >= 1")
+        bs = self.kv_block_size
+        if bs < 1:
+            raise ValueError("kv_block_size must be >= 1")
+        if self.max_seq_len % bs:
+            raise ValueError(
+                f"max_seq_len {self.max_seq_len} must be a multiple "
+                f"of kv_block_size {bs} (block tables tile the "
+                f"sequence exactly)")
+        bad = [x for x in b if x % bs]
+        if bad:
+            raise ValueError(
+                f"prefill buckets {bad} must be multiples of "
+                f"kv_block_size {bs} (suffix KV scatters whole "
+                f"blocks)")
+        if self.num_kv_blocks is not None and self.num_kv_blocks < 1:
+            raise ValueError("num_kv_blocks must be >= 1")
 
     @property
     def max_blocks_per_slot(self) -> int:
@@ -232,9 +233,9 @@ class Request:
     slo: str = "interactive"
     # Stop after prefill + the first sampled token and export the KV
     # state (handle.kv_state) instead of decoding — the disaggregated
-    # prefill tier's mode (serve/llm/disagg). Paged layout only.
+    # prefill tier's mode (serve/llm/disagg).
     prefill_only: bool = False
-    # Paged + prefix-cache engines: admit prompts longer than the
+    # Prefix-cache engines: admit prompts longer than the
     # largest bucket by prefilling bucket-sized chunks through the
     # prefix cache (each chunk's blocks are cached, the next chunk
     # prefix-hits them), one chunk per scheduler step — so interactive
@@ -271,7 +272,7 @@ class RequestHandle:
         # Prompt positions THIS engine actually prefilled (suffix after
         # prefix-cache hits and tier promotes; summed across chunks).
         # len(prompt) - prefilled_tokens is the prefill work avoided —
-        # the bench's FLOPs-avoided numerator and its pacing input.
+        # the cost meter's `prefill_tokens_avoided`.
         self.prefilled_tokens = 0
         # Request-scoped tracing: the TraceContext active on the
         # submitting thread (the replica's llm.server_call span) plus a
@@ -316,7 +317,7 @@ class RequestHandle:
                 f"request {self.request_id} not finished in {timeout}s")
         return self.tokens
 
-    # Latency accounting for the bench (seconds).
+    # Latency accounting (seconds).
     @property
     def ttft_s(self) -> Optional[float]:
         if self.first_token_at is None:
@@ -374,62 +375,44 @@ class LLMEngine:
         # The model's own functions over its cache (models/serving.py):
         # the engine names no model module.
         self._model = model = model_config.serving()
-        if c.kv_layout == "dense" and model.dense is None:
-            raise ValueError(
-                f"{model.name} is served through kv_layout='paged' "
-                f"only: it has no dense-layout cache")
         if draft_params is not None and model.verify is None:
             raise ValueError(
                 f"{model.name} has no speculative verify step")
 
         # Device state (fixed shapes for the engine's whole lifetime).
-        self._paged = c.kv_layout == "paged"
-        if self._paged:
-            from ray_tpu.serve.llm.kv_cache import (BlockAllocator,
-                                                    KVTierManager,
-                                                    PrefixCache,
-                                                    PromoteCostModel)
+        from ray_tpu.serve.llm.kv_cache import (
+            BlockAllocator, KVTierManager, PrefixCache, PromoteCostModel)
 
-            # The pool: a flat dict of [L, NB, bs, ...] leaves that the
-            # model names; the engine moves whole blocks of every leaf
-            # and never looks inside a row.
-            self._cache = model.init_pool(
-                model_config, c.pool_blocks, c.kv_block_size)
-            # HBM bytes per block (every leaf's rows across all layers)
-            # — the byte-accounting basis for allocator/prefix/tier
-            # stats.
-            block_bytes = sum(int(x.nbytes) for x in
-                              self._cache.values()) // c.pool_blocks
-            self._allocator = BlockAllocator(c.pool_blocks,
-                                             c.kv_block_size,
-                                             block_bytes=block_bytes)
-            self._prefix = (PrefixCache(self._allocator)
-                            if c.prefix_cache else None)
-            # Per-slot block tables (host copy is the truth; the device
-            # sees it as a plain [B, max_blocks] int32 argument — data,
-            # not shape, so tables never retrace anything).
-            self._tables = np.zeros((B, c.max_blocks_per_slot), np.int32)
-            self._slot_blocks: List[List[int]] = [[] for _ in range(B)]
-            self._prefix_seen = {"hits": 0, "misses": 0,
-                                 "hit_tokens": 0, "evictions": 0,
-                                 "spilled": 0}
-            self._cost_model = PromoteCostModel(
-                adopt_fixed_s=c.kv_adopt_cost_fixed_ms * 1e-3,
-                adopt_per_block_s=c.kv_adopt_cost_per_block_ms * 1e-3,
-                prefill_per_token_s=c.kv_prefill_cost_per_token_ms
-                * 1e-3)
-            self._tiers = None
-            if c.kv_spill and self._prefix is not None:
-                self._tiers = KVTierManager(
-                    c.kv_host_tier_bytes, c.kv_block_size,
-                    put_fn=_tier_store_put, get_fn=_tier_store_get)
-                self._prefix.spill_fn = self._spill_evicted
-        else:
-            self._cache = model.dense.init_cache(model_config, B,
-                                                 c.max_seq_len)
-            self._allocator = None
-            self._prefix = None
-            self._tiers = None
+        # The pool: a flat dict of [L, NB, bs, ...] leaves that the
+        # model names; the engine moves whole blocks of every leaf
+        # and never looks inside a row.
+        self._cache = model.init_pool(
+            model_config, c.pool_blocks, c.kv_block_size)
+        # HBM bytes per block (every leaf's rows across all layers)
+        # — the byte-accounting basis for allocator/prefix/tier stats.
+        block_bytes = sum(int(x.nbytes) for x in
+                          self._cache.values()) // c.pool_blocks
+        self._allocator = BlockAllocator(c.pool_blocks, c.kv_block_size,
+                                         block_bytes=block_bytes)
+        self._prefix = (PrefixCache(self._allocator)
+                        if c.prefix_cache else None)
+        # Per-slot block tables (host copy is the truth; the device
+        # sees it as a plain [B, max_blocks] int32 argument — data,
+        # not shape, so tables never retrace anything).
+        self._tables = np.zeros((B, c.max_blocks_per_slot), np.int32)
+        self._slot_blocks: List[List[int]] = [[] for _ in range(B)]
+        self._prefix_seen = {"hits": 0, "misses": 0, "hit_tokens": 0,
+                             "evictions": 0, "spilled": 0}
+        self._cost_model = PromoteCostModel(
+            adopt_fixed_s=c.kv_adopt_cost_fixed_ms * 1e-3,
+            adopt_per_block_s=c.kv_adopt_cost_per_block_ms * 1e-3,
+            prefill_per_token_s=c.kv_prefill_cost_per_token_ms * 1e-3)
+        self._tiers = None
+        if c.kv_spill:                  # implies the prefix cache
+            self._tiers = KVTierManager(
+                c.kv_host_tier_bytes, c.kv_block_size,
+                put_fn=_tier_store_put, get_fn=_tier_store_get)
+            self._prefix.spill_fn = self._spill_evicted
         self._tok = jnp.zeros((B,), jnp.int32)
         self._pos = jnp.zeros((B,), jnp.int32)
         self._key = jax.random.key(rng_seed)
@@ -437,7 +420,7 @@ class LLMEngine:
         # summed on the device tick by tick; `stats()` reads it. Not
         # donated: `stats()` may read it from another thread.
         self._counters = (model.init_counts(model_config)
-                          if self._paged and model.init_counts else {})
+                          if model.init_counts else {})
         # Host-side mirrors fed into each program call (tiny transfers).
         self._active = np.zeros((B,), bool)
         self._temp = np.zeros((B,), np.float32)
@@ -487,9 +470,9 @@ class LLMEngine:
 
         # Speculative decoding: a small draft model proposing
         # spec_k - 1 greedy tokens per round, verified in one paged
-        # K-token target step (models/llama.py::verify_kv_paged). The
-        # draft keeps a dense per-slot cache — it is tiny, so paging it
-        # would buy nothing.
+        # K-token target step (the model's `verify`). The draft keeps
+        # a cache of its own, one [S] stripe a slot (models/serving.py
+        # `DraftFns`) — it is tiny, so paging it would buy nothing.
         self._draft = draft_params
         self.draft_config = draft_config
         self._spec_ok = np.zeros((B,), bool)
@@ -497,14 +480,10 @@ class LLMEngine:
         self._spec_proposed = 0
         self._spec_accepted = 0
         if draft_params is not None:
-            if not self._paged:
-                raise ValueError(
-                    "speculative decoding requires kv_layout='paged' "
-                    "(the verify step goes through block tables)")
             if draft_config is None:
                 raise ValueError("draft_params given without "
                                  "draft_config")
-            self._draft_model = draft_config.serving().dense
+            self._draft_model = draft_config.serving().draft
             self._draft_cache = self._draft_model.init_cache(
                 draft_config, B, c.max_seq_len)
 
@@ -517,40 +496,31 @@ class LLMEngine:
         from ray_tpu.observability import serve_metrics, tracked_jit
         from ray_tpu.observability.device import ensure_sampler_registered
 
-        if self._paged:
-            self._jit_tick = tracked_jit(
-                self._tick_fn_paged, name="llm_engine_tick",
-                trace_budget=1, donate_argnums=(1, 3, 4))
-            self._jit_insert = tracked_jit(
-                self._insert_fn_paged, name="llm_engine_insert",
+        self._jit_tick = tracked_jit(
+            self._tick_fn, name="llm_engine_tick",
+            trace_budget=1, donate_argnums=(1, 3, 4))
+        self._jit_insert = tracked_jit(
+            self._insert_fn, name="llm_engine_insert",
+            trace_budget=len(c.prefill_buckets),
+            donate_argnums=(1, 2, 3))
+        # KV migration programs: block counts are data (padded
+        # ids, out-of-bounds scatters dropped), so the adopt is ONE
+        # trace and the export one per row length of `export_rows`.
+        self._jit_export = tracked_jit(
+            self._export_fn, name="llm_engine_export",
+            trace_budget=len(c.export_rows))
+        self._jit_adopt = tracked_jit(
+            self._adopt_fn, name="llm_engine_adopt",
+            trace_budget=1, donate_argnums=(0, 1, 2))
+        if self._draft is not None:
+            self._jit_spec = tracked_jit(
+                self._spec_fn, name="llm_engine_spec",
+                trace_budget=1, donate_argnums=(2, 3, 5, 6))
+            self._jit_draft_insert = tracked_jit(
+                self._draft_insert_fn,
+                name="llm_engine_draft_insert",
                 trace_budget=len(c.prefill_buckets),
-                donate_argnums=(1, 2, 3))
-            # KV migration programs: block counts are data (padded
-            # ids, out-of-bounds scatters dropped), so the adopt is ONE
-            # trace and the export one per row length of `export_rows`.
-            self._jit_export = tracked_jit(
-                self._export_fn, name="llm_engine_export",
-                trace_budget=len(c.export_rows))
-            self._jit_adopt = tracked_jit(
-                self._adopt_fn, name="llm_engine_adopt",
-                trace_budget=1, donate_argnums=(0, 1, 2))
-            if self._draft is not None:
-                self._jit_spec = tracked_jit(
-                    self._spec_fn, name="llm_engine_spec",
-                    trace_budget=1, donate_argnums=(2, 3, 5, 6))
-                self._jit_draft_insert = tracked_jit(
-                    self._draft_insert_fn,
-                    name="llm_engine_draft_insert",
-                    trace_budget=len(c.prefill_buckets),
-                    donate_argnums=(1,))
-        else:
-            self._jit_tick = tracked_jit(
-                self._tick_fn, name="llm_engine_tick", trace_budget=1,
-                donate_argnums=(1, 2, 3))
-            self._jit_insert = tracked_jit(
-                self._insert_fn, name="llm_engine_insert",
-                trace_budget=len(c.prefill_buckets),
-                donate_argnums=(1, 2, 3))
+                donate_argnums=(1,))
         self._metrics = serve_metrics()
         ensure_sampler_registered()
 
@@ -567,73 +537,17 @@ class LLMEngine:
 
     # ------------------------------------------------------------ programs
 
-    def _tick_fn(self, params, cache, tok, pos, active, temp, key):
+    def _tick_fn(self, params, pools, tables, tok, pos, active, temp,
+                 key, counters=None):
         """`decode_block` decode steps for all B slots in one dispatch
-        (lax.scan — still ONE compiled program). Inactive slots are
+        (lax.scan — still ONE compiled program; the KV write/read goes
+        through the block tables, which are data). Inactive slots are
         computed but masked: no KV write, token/pos parked. Positions
         clamp at S-1 so a slot finishing mid-block can speculate ahead
         without ever attending past rows it wrote itself; the host
-        discards post-stop tokens."""
-        import jax
-        import jax.numpy as jnp
-
-        decode_step = self._model.dense.decode
-        S = self.config.max_seq_len
-
-        def body(carry, _):
-            cache, tok, pos, key = carry
-            logits, cache = decode_step(params, cache, tok, pos,
-                                        self.model_config, active=active)
-            key, sub = jax.random.split(key)
-            nxt = _sample(logits, temp, sub)
-            tok = jnp.where(active, nxt, tok)
-            pos = jnp.where(active, jnp.minimum(pos + 1, S - 1), pos)
-            return (cache, tok, pos, key), tok
-
-        (cache, tok, pos, key), toks = jax.lax.scan(
-            body, (cache, tok, pos, key), None,
-            length=self.config.decode_block)
-        return cache, tok, pos, key, toks          # toks: [K, B]
-
-    def _insert_fn(self, params, cache, tok, pos, padded_prompt,
-                   prompt_len, slot, temperature, key):
-        """Prefill one bucket-padded prompt and splice its KV into the
-        shared cache at `slot`; sample the first generated token from
-        the logits at the last REAL prompt position. One trace per
-        bucket length (the shape of `padded_prompt`)."""
-        import jax
-        import jax.numpy as jnp
-        from jax import lax
-
-        c = self.model_config
-        hidden, ks, vs = self._model.dense.prefill(
-            params, padded_prompt[None], c)
-        # ks/vs: [L, 1, Pb, n_kv, hd] -> rows [0, Pb) of this slot.
-        cache = {
-            "k": lax.dynamic_update_slice(
-                cache["k"], ks.astype(c.dtype), (0, slot, 0, 0, 0)),
-            "v": lax.dynamic_update_slice(
-                cache["v"], vs.astype(c.dtype), (0, slot, 0, 0, 0)),
-        }
-        x_last = lax.dynamic_index_in_dim(
-            hidden[0], prompt_len - 1, axis=0, keepdims=False)
-        logits = jax.lax.dot_general(
-            x_last[None], self._model.head_weight(params, c),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [1, V]
-        key, sub = jax.random.split(key)
-        first = _sample(logits, temperature[None], sub)[0]
-        tok = tok.at[slot].set(first)
-        pos = pos.at[slot].set(prompt_len)
-        return cache, tok, pos, key
-
-    def _tick_fn_paged(self, params, pools, tables, tok, pos, active,
-                       temp, key, counters=None):
-        """Paged twin of `_tick_fn`: same scan, same sampling, but the
-        KV write/read goes through the block tables (data, so still ONE
-        compiled program regardless of who owns which block). What the
-        model's step counts is added to `counters` (an empty tree for a
-        model that counts nothing: no operation, no argument)."""
+        discards post-stop tokens. What the model's step counts is
+        added to `counters` (an empty tree for a model that counts
+        nothing: no operation, no argument)."""
         import jax
         import jax.numpy as jnp
 
@@ -658,14 +572,16 @@ class LLMEngine:
             length=self.config.decode_block)
         return pools, tok, pos, key, toks, counters   # toks: [K, B]
 
-    def _insert_fn_paged(self, params, pools, tok, pos, table_row,
-                         hist_len, padded_suffix, suffix_len,
-                         new_block_ids, slot, temperature, key):
+    def _insert_fn(self, params, pools, tok, pos, table_row, hist_len,
+                   padded_suffix, suffix_len, new_block_ids, slot,
+                   temperature, key):
         """Prefill the (possibly prefix-truncated) suffix of one prompt
-        and scatter its KV into the slot's freshly-allocated blocks.
+        and scatter its KV into the slot's freshly-allocated blocks;
+        sample the first generated token from the logits at the last
+        REAL prompt position.
 
         The prefix-hit path IS the miss path: ``hist_len`` (dynamic
-        data) tells `prefill_kv_paged` where the suffix starts; a miss
+        data) tells the model's prefill where the suffix starts; a miss
         is just hist_len = 0 over an all-zero history. One trace per
         suffix bucket — the only static shapes are ``padded_suffix``
         [Pb] and ``new_block_ids`` [Pb / block_size], both functions of
@@ -688,8 +604,7 @@ class LLMEngine:
             params, padded_suffix[None], hist_len, hist, c, suffix_len)
         # rows: {leaf: [L, Pb, ...]} -> whole blocks into the pool at
         # the slot's new physical ids (padding rows ride along; decode
-        # overwrites each before attending, exactly like the dense path
-        # tolerates stale rows).
+        # overwrites each before attending).
         pools = {name: pool.at[:, new_block_ids].set(
             rows[name].astype(pool.dtype).reshape(
                 (pool.shape[0], Pb // bs, bs) + pool.shape[3:]))
@@ -729,10 +644,10 @@ class LLMEngine:
 
     def _draft_insert_fn(self, draft_params, dcache, padded_prompt,
                          slot):
-        """Prefill the draft model's dense cache for one admitted slot
+        """Prefill the draft model's cache stripe for one admitted slot
         (always the FULL padded prompt — the draft has no prefix cache;
-        padding rows are stale-but-masked exactly like the dense
-        insert). One trace per prompt bucket."""
+        padding rows are stale but masked, and overwritten before they
+        are attended). One trace per prompt bucket."""
         from jax import lax
 
         dc = self.draft_config
@@ -748,7 +663,7 @@ class LLMEngine:
     def _spec_fn(self, params, draft_params, pools, dcache, tables,
                  tok, pos, active):
         """One speculative round (greedy lanes only): the draft
-        proposes spec_k - 1 tokens from its dense cache, ONE paged
+        proposes spec_k - 1 tokens from its own cache, ONE paged
         verify step scores all spec_k inputs on the target, and the
         longest draft prefix agreeing with the target argmax is
         accepted. Every emitted token IS the target's argmax given
@@ -810,18 +725,13 @@ class LLMEngine:
             raise ValueError(
                 f"slo must be 'interactive' or 'batch', got "
                 f"{request.slo!r}")
-        if request.prefill_only and not self._paged:
-            raise ValueError(
-                "prefill_only requires kv_layout='paged' (the exported "
-                "checkpoint is a set of KV blocks)")
         chunked = request.chunked_prefill and P > top
         handle = RequestHandle(next(self._ids), request)
         if chunked:
-            if not (self._paged and self._prefix is not None):
+            if self._prefix is None:
                 raise ValueError(
-                    "chunked_prefill needs kv_layout='paged' with "
-                    "prefix_cache=True (chunks hand off through the "
-                    "prefix cache)")
+                    "chunked_prefill needs prefix_cache=True (chunks "
+                    "hand off through the prefix cache)")
             if P >= c.max_seq_len or -(-P // top) * top > c.max_seq_len:
                 raise ValueError(
                     f"prompt length {P} cannot be chunk-prefilled: "
@@ -831,20 +741,17 @@ class LLMEngine:
         elif P > top:
             raise ValueError(
                 f"prompt length {P} exceeds largest prefill bucket "
-                f"{top} (set chunked_prefill=True on a paged + "
-                f"prefix-cache engine)")
-        if self._paged:
-            # A request the pool can never hold must fail loudly at
-            # submit — queuing it would deadlock admission forever.
-            worst = self._blocks_needed(P, request.max_tokens)
-            worst = max(worst,
-                        self._bucket_for(min(P, top))
-                        // c.kv_block_size)
-            if worst > c.pool_blocks:
-                raise ValueError(
-                    f"request needs up to {worst} KV blocks but the "
-                    f"pool only has {c.pool_blocks}; raise "
-                    f"num_kv_blocks or lower max_tokens")
+                f"{top} (set chunked_prefill=True on a prefix-cache "
+                f"engine)")
+        # A request the pool can never hold must fail loudly at
+        # submit — queuing it would deadlock admission forever.
+        worst = max(self._blocks_needed(P, request.max_tokens),
+                    self._bucket_for(min(P, top)) // c.kv_block_size)
+        if worst > c.pool_blocks:
+            raise ValueError(
+                f"request needs up to {worst} KV blocks but the "
+                f"pool only has {c.pool_blocks}; raise "
+                f"num_kv_blocks or lower max_tokens")
         handle._engine = self
         self._capture_trace(handle)
         self._attach_meter(handle)
@@ -885,8 +792,6 @@ class LLMEngine:
         from ray_tpu.serve.llm.kv_cache import KVState
 
         c = self.config
-        if not self._paged:
-            raise ValueError("submit_adopted requires kv_layout='paged'")
         if not isinstance(state, KVState):
             raise TypeError(f"expected KVState, got {type(state)!r}")
         state.validate()
@@ -1006,8 +911,8 @@ class LLMEngine:
         """Move queued requests into free slots (one prefill each);
         returns (slot, fresh) pairs inserted this step — `fresh` is
         False for adopted checkpoints, whose last sampled token was
-        already emitted by the exporting engine. Paged layout:
-        admission additionally needs blocks — on pool exhaustion the
+        already emitted by the exporting engine. Admission needs
+        blocks as well as a slot — on pool exhaustion the
         request goes BACK to the lane head and admission stops
         (requests queue, never crash; blocks free as running sequences
         finish). Chunked-prefill intermediates are throwaway
@@ -1035,8 +940,8 @@ class LLMEngine:
                 slot = self._free[0]
                 t_chunk = time.monotonic()
                 with trace_span("llm_engine.admit_one", chunk=1):
-                    ok = self._admit_paged(handle, slot, upto=end,
-                                           throwaway=True)
+                    ok = self._admit_prefill(handle, slot, upto=end,
+                                             throwaway=True)
                 if not ok:
                     self._requeue(handle)
                     if req.slo == "interactive":
@@ -1053,7 +958,8 @@ class LLMEngine:
             fresh = handle.kv_state is None
             t_admit = time.monotonic()
             with trace_span("llm_engine.admit_one"):
-                ok = self._admit_one(handle, slot, fresh)
+                ok = (self._admit_prefill(handle, slot) if fresh
+                      else self._admit_adopted(handle, slot))
             if not ok:
                 self._free.appendleft(slot)
                 if req.slo == "interactive":
@@ -1087,34 +993,10 @@ class LLMEngine:
             inserted.append((slot, fresh))
         return inserted
 
-    def _admit_one(self, handle: RequestHandle, slot: int,
-                   fresh: bool) -> bool:
-        """One request into `slot`: adopt its checkpoint, or prefill
-        it (paged or dense). False when the pool cannot cover it."""
-        import numpy as np
-
-        if not fresh:
-            return self._admit_adopted(handle, slot)
-        if self._paged:
-            return self._admit_paged(handle, slot)
-        req = handle.request
-        P = len(req.prompt)
-        bucket = self._bucket_for(P)
-        padded = np.zeros((bucket,), np.int32)
-        padded[:P] = np.asarray(req.prompt, np.int32)
-        with trace_span("llm_engine.insert_dispatch", bucket=bucket):
-            self._cache, self._tok, self._pos, self._key = \
-                self._jit_insert(
-                    self.params, self._cache, self._tok, self._pos,
-                    padded, np.int32(P), np.int32(slot),
-                    np.float32(req.temperature), self._key)
-        handle.prefilled_tokens += P
-        return True
-
-    def _admit_paged(self, handle: RequestHandle, slot: int,
-                     upto: Optional[int] = None,
-                     throwaway: bool = False) -> bool:
-        """Block accounting + paged insert for one request. Returns
+    def _admit_prefill(self, handle: RequestHandle, slot: int,
+                       upto: Optional[int] = None,
+                       throwaway: bool = False) -> bool:
+        """Block accounting + insert for one request. Returns
         False (nothing allocated, nothing inserted) when the pool can't
         cover it even after evicting cold prefix entries.
 
@@ -1328,7 +1210,7 @@ class LLMEngine:
         return True
 
     def _draft_admit(self, consumed: List[int], slot: int) -> None:
-        """Prefill the draft model's dense cache with a slot's consumed
+        """Prefill the draft model's cache stripe with a slot's consumed
         tokens (prompt, plus prior output for adopted sequences). A
         sequence whose consumed length exceeds the largest bucket
         cannot seed the draft in one insert — it simply decodes without
@@ -1357,7 +1239,7 @@ class LLMEngine:
         self._active[slot] = False
         self._temp[slot] = 0.0
         self._spec_ok[slot] = False
-        if self._paged and self._slot_blocks[slot]:
+        if self._slot_blocks[slot]:
             # Drop this sequence's refs; blocks shared with the prefix
             # cache (or other sequences) stay resident.
             if handle is not None and handle.meter is not None:
@@ -1398,7 +1280,7 @@ class LLMEngine:
                 reason = "eos"                   # halt, eos IS emitted
             elif len(handle.tokens) >= req.max_tokens:
                 reason = "length"
-        # Hard cap: a slot may never write past the shared cache. The
+        # Hard cap: a slot may never write past its block table. The
         # NEXT token would land at pos = prompt + len(tokens); stop while
         # it still fits.
         if reason is None and (len(req.prompt) + len(handle.tokens)
@@ -1638,7 +1520,7 @@ class LLMEngine:
         :meth:`call_on_scheduler` from anywhere else."""
         import numpy as np
 
-        if not self._paged or self._prefix is None:
+        if self._prefix is None:
             return []
         c = self.config
         bs = c.kv_block_size
@@ -1707,8 +1589,6 @@ class LLMEngine:
         (handle.kv_state), the slot and blocks are released, and the
         next admission resumes decoding through the adopt path — the
         preempt → resume cycle is token-invisible to the client."""
-        if not self._paged:
-            raise ValueError("preempt requires kv_layout='paged'")
         st = self._slots[slot]
         handle = st.handle
         if handle is None:
@@ -1728,8 +1608,6 @@ class LLMEngine:
         has the least sunk prefill work per token emitted. The
         hold/cooldown gate means transient pressure (one tick of a
         full batch) never thrashes checkpoints."""
-        if not self._paged:
-            return
         with self._lock:
             waiting = len(self._queues["interactive"])
         if not waiting:
@@ -1954,17 +1832,12 @@ class LLMEngine:
             t_tick = time.monotonic()
             if spec:
                 out = self._spec_dispatch()
-            elif self._paged:
+            else:
                 (self._cache, self._tok, self._pos, self._key, out,
                  self._counters) = self._jit_tick(
                     self.params, self._cache, self._tables.copy(),
                     self._tok, self._pos, self._active.copy(),
                     self._temp.copy(), self._key, self._counters)
-            else:
-                self._cache, self._tok, self._pos, self._key, out = \
-                    self._jit_tick(
-                        self.params, self._cache, self._tok, self._pos,
-                        self._active.copy(), self._temp.copy(), self._key)
         # What this step's admissions evicted lands while the chip
         # runs their inserts and the tick.
         self._land_spills()
@@ -2063,37 +1936,36 @@ class LLMEngine:
                 self._spec_accepted / self._spec_proposed)
         m.active_slots.set(float(active))
         m.batch_utilization.set(active / self.config.num_slots)
-        if self._paged:
-            m.kv_blocks_used.set(float(self._allocator.used_blocks))
-            m.kv_blocks_free.set(float(self._allocator.free_blocks))
-            if self._prefix is not None:
-                cur = self._prefix.stats()
-                seen = self._prefix_seen
-                for field, ctr in (("hits", m.prefix_hits),
-                                   ("misses", m.prefix_misses),
-                                   ("hit_tokens", m.prefix_hit_tokens),
-                                   ("evictions", m.prefix_evictions)):
+        m.kv_blocks_used.set(float(self._allocator.used_blocks))
+        m.kv_blocks_free.set(float(self._allocator.free_blocks))
+        if self._prefix is not None:
+            cur = self._prefix.stats()
+            seen = self._prefix_seen
+            for field, ctr in (("hits", m.prefix_hits),
+                               ("misses", m.prefix_misses),
+                               ("hit_tokens", m.prefix_hit_tokens),
+                               ("evictions", m.prefix_evictions)):
+                d = cur[field] - seen[field]
+                if d > 0:
+                    ctr.inc(float(d))
+                    seen[field] = cur[field]
+        if self._tiers is not None:
+            ts = self._tiers.stats()
+            for tier in ("host", "store"):
+                cur, seen = ts[tier], self._tier_seen[tier]
+                for field, ctr in (
+                        ("hits", m.prefix_tier_hits),
+                        ("misses", m.prefix_tier_misses),
+                        ("spills", m.prefix_tier_spills),
+                        ("promotes", m.prefix_tier_promotes)):
                     d = cur[field] - seen[field]
                     if d > 0:
-                        ctr.inc(float(d))
+                        ctr.inc(float(d), tags={"tier": tier})
                         seen[field] = cur[field]
-            if self._tiers is not None:
-                ts = self._tiers.stats()
-                for tier in ("host", "store"):
-                    cur, seen = ts[tier], self._tier_seen[tier]
-                    for field, ctr in (
-                            ("hits", m.prefix_tier_hits),
-                            ("misses", m.prefix_tier_misses),
-                            ("spills", m.prefix_tier_spills),
-                            ("promotes", m.prefix_tier_promotes)):
-                        d = cur[field] - seen[field]
-                        if d > 0:
-                            ctr.inc(float(d), tags={"tier": tier})
-                            seen[field] = cur[field]
-                    m.kv_tier_bytes.set(float(cur["bytes"]),
-                                        tags={"tier": tier})
-                m.kv_tier_bytes.set(float(self._allocator.used_bytes),
-                                    tags={"tier": "hbm"})
+                m.kv_tier_bytes.set(float(cur["bytes"]),
+                                    tags={"tier": tier})
+            m.kv_tier_bytes.set(float(self._allocator.used_bytes),
+                                tags={"tier": "hbm"})
 
     def run(self, stop_event: threading.Event,
             idle_wait_s: float = 0.02) -> None:
@@ -2115,11 +1987,11 @@ class LLMEngine:
 
     def warmup(self) -> None:
         """Compile every program the engine can run — the decode tick
-        plus one insert per prefill bucket, and the paged layout's
-        export gather at every row length (`export_rows`; a spill, a
-        checkpoint or a peer pull picks one by its block count) —
-        before real traffic. The paged layout bypasses the prefix
-        cache while warming: a warm hit shrinks the padded suffix to a
+        plus one insert per prefill bucket, and the export gather at
+        every row length (`export_rows`; a spill, a checkpoint or a
+        peer pull picks one by its block count) — before real traffic.
+        The prefix cache is bypassed while warming: a warm hit shrinks
+        the padded suffix to a
         SMALLER bucket, leaving the larger bucket's insert uncompiled
         until a cache-miss request pays the compile inside its own
         latency. Synchronous; call before starting a run() thread."""
@@ -2146,11 +2018,10 @@ class LLMEngine:
         finally:
             self._prefix = prefix
             self._draft = draft
-        if self._paged:
-            import jax
+        import jax
 
-            for n in self.config.export_rows:   # one row alive at a time
-                jax.block_until_ready(self._export_blocks([0] * n))
+        for n in self.config.export_rows:       # one row alive at a time
+            jax.block_until_ready(self._export_blocks([0] * n))
 
     # ------------------------------------------------------------ inspection
 
@@ -2161,24 +2032,20 @@ class LLMEngine:
         len(buckets) inserts + 1 tick, plus at most len(export_rows)
         exports, 1 adopt, 1 spec round, and len(buckets) draft inserts
         when wired)."""
-        n = self._jit_tick.traces + self._jit_insert.traces
-        for name in ("_jit_export", "_jit_adopt", "_jit_spec",
-                     "_jit_draft_insert"):
-            fn = getattr(self, name, None)
-            if fn is not None:
-                n += fn.traces
-        return n
+        return sum(self._traces().values())
+
+    def _traces(self) -> Dict[str, int]:
+        """Traces by program family (the last two exist with a draft)."""
+        return {name: getattr(self, f"_jit_{name}").traces
+                for name in ("tick", "insert", "export", "adopt", "spec",
+                             "draft_insert")
+                if hasattr(self, f"_jit_{name}")}
 
     def stats(self) -> Dict[str, Any]:
         with self._lock:
             queued_by_lane = {lane: len(q)
                               for lane, q in self._queues.items()}
-        traces = {"tick": self._jit_tick.traces,
-                  "insert": self._jit_insert.traces}
-        for name in ("export", "adopt", "spec", "draft_insert"):
-            fn = getattr(self, f"_jit_{name}", None)
-            if fn is not None:
-                traces[name] = fn.traces
+        traces = self._traces()
         out = {
             "num_slots": self.config.num_slots,
             "active_slots": int(self._active.sum()),
@@ -2189,24 +2056,23 @@ class LLMEngine:
             "preempted": self._preempted,
             "kv_layout": self.config.kv_layout,
             "traces": traces,
-            "trace_count": self.trace_count,
-        }
-        if self._paged:
-            out["kv"] = dict(self._allocator.stats(),
-                             block_size=self.config.kv_block_size)
-            out["migration"] = {
+            "trace_count": sum(traces.values()),
+            "kv": dict(self._allocator.stats(),
+                       block_size=self.config.kv_block_size),
+            "migration": {
                 "blocks": self._migrated_blocks,
                 "bytes": self._migrated_bytes,
-            }
-            if self._prefix is not None:
-                out["prefix_cache"] = self._prefix.stats()
-            if self._tiers is not None:
-                out["kv_tiers"] = dict(
-                    self._tiers.stats(),
-                    promoted_blocks=self._promoted_blocks,
-                    promote_skips=self._promote_skips,
-                    spill_lands=self._spill_lands,
-                    spill_lands_waited=self._spill_lands_waited)
+            },
+        }
+        if self._prefix is not None:
+            out["prefix_cache"] = self._prefix.stats()
+        if self._tiers is not None:
+            out["kv_tiers"] = dict(
+                self._tiers.stats(),
+                promoted_blocks=self._promoted_blocks,
+                promote_skips=self._promote_skips,
+                spill_lands=self._spill_lands,
+                spill_lands_waited=self._spill_lands_waited)
         if self._counters:
             # the model's own counters, summed on the device since
             # start and read here (waits for the tick in flight)
@@ -2286,52 +2152,3 @@ def _sample(logits, temp, key):
     scaled = logits / jnp.maximum(temp, 1e-6)[:, None]
     sampled = jax.random.categorical(key, scaled).astype(jnp.int32)
     return jnp.where(temp > 0, sampled, greedy)
-
-
-def static_batch_generate(params, model_config, requests: List[Request],
-                          batch_size: int, pad_to: int,
-                          steps: Optional[int] = None,
-                          warmup: bool = True):
-    """The lockstep baseline the engine replaces: group requests in
-    arrival order, pad prompts to `pad_to`, decode `steps` (default:
-    max(max_tokens)) per group via models.llama.generate, truncate per
-    request. Used by bench.py for the continuous-vs-static comparison
-    on identical geometry (one compiled program: fixed B/P/N). Returns
-    (outputs, per-batch seconds) — the timings let the bench couple
-    batches to an arrival trace.
-
-    Throughput baseline ONLY: `generate` has no padding mask, so a
-    prompt shorter than `pad_to` sees trailing pad tokens in its context
-    and its output tokens differ from the unpadded result — which is one
-    of the deficiencies of the static path (the other, measured by the
-    bench, is that every request decodes for the group max). Compute
-    cost is identical to real content at the same shapes, so the timing
-    stands."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from ray_tpu.models.llama import generate
-
-    steps = steps or max(r.max_tokens for r in requests)
-    from ray_tpu.observability.jit import tracked_jit
-
-    gen = tracked_jit(lambda p, t: generate(p, t, model_config,
-                                            max_new_tokens=steps),
-                      name="llm_generate_batch")
-    if warmup:                              # compile outside the timings
-        np.asarray(gen(params, jnp.zeros((batch_size, pad_to),
-                                         jnp.int32)))
-    outs: List[List[int]] = []
-    batch_seconds: List[float] = []
-    for i in range(0, len(requests), batch_size):
-        group = requests[i:i + batch_size]
-        toks = np.zeros((batch_size, pad_to), np.int32)
-        for j, r in enumerate(group):
-            toks[j, :len(r.prompt)] = np.asarray(r.prompt, np.int32)
-        t0 = time.monotonic()
-        out = np.asarray(gen(params, jnp.asarray(toks)))
-        batch_seconds.append(time.monotonic() - t0)
-        for j, r in enumerate(group):
-            outs.append(out[j, :r.max_tokens].tolist())
-    return outs, batch_seconds

@@ -314,7 +314,7 @@ def _serving_cell(cell, one_chip):
 
 
 def _compiled_insert(eng, one_chip):
-    """`LLMEngine._insert_fn_paged` at the cell's largest bucket."""
+    """`LLMEngine._insert_fn` at the cell's largest bucket."""
     from ray_tpu.serve.llm.engine import LLMEngine
 
     def arg(dtype, *shape):
@@ -323,7 +323,7 @@ def _compiled_insert(eng, one_chip):
     ec = eng.config
     B, Pb = ec.num_slots, ec.prefill_buckets[-1]
     return jax.jit(
-        functools.partial(LLMEngine._insert_fn_paged, eng),
+        functools.partial(LLMEngine._insert_fn, eng),
         donate_argnums=(1, 2, 3)).lower(
         eng.params, eng.pools, arg(jnp.int32, B), arg(jnp.int32, B),
         arg(jnp.int32, ec.max_blocks_per_slot), arg(jnp.int32),
@@ -385,7 +385,7 @@ def test_latent_moe_cell_programs_fit_one_v5e(one_chip, program):
     B, nb = ec.num_slots, ec.max_blocks_per_slot
     if program == "tick":
         compiled = jax.jit(
-            functools.partial(LLMEngine._tick_fn_paged, eng),
+            functools.partial(LLMEngine._tick_fn, eng),
             donate_argnums=(1, 3, 4)).lower(
             params, pools, arg(jnp.int32, B, nb), arg(jnp.int32, B),
             arg(jnp.int32, B), arg(jnp.bool_, B), arg(jnp.float32, B), key,

@@ -323,25 +323,28 @@ def test_engine_exports_and_adopts_latent_blocks(model, engine):
     assert R.served_token_deficits(weights, C, p, h2.tokens).max() < 1e-4
 
 
-@pytest.mark.parametrize("what", ["dense_layout", "speculation", "int8"])
-def test_unsupported_paths_refuse_by_name(model, what):
+@pytest.mark.parametrize("what, refusal", [
+    ("dense_layout", "removed at PR 28"),       # for every model
+    ("speculation", "latent attention"),
+    ("int8", "latent attention")])
+def test_unsupported_paths_refuse_by_name(model, what, refusal):
     from ray_tpu.models.llama import LlamaConfig, init_params
     from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
 
     _, mc, _, params = model
     ec = dict(num_slots=2, max_seq_len=64, prefill_buckets=(BUCKET,),
               kv_block_size=BS)
-    with pytest.raises(ValueError, match="latent attention"):
+    with pytest.raises(ValueError, match=refusal):
         if what == "dense_layout":
             LLMEngine(params, mc, EngineConfig(kv_layout="dense", **ec))
         elif what == "speculation":
             dc = LlamaConfig.tiny(vocab_size=512)
-            LLMEngine(params, mc, EngineConfig(kv_layout="paged", **ec),
+            LLMEngine(params, mc, EngineConfig(**ec),
                       draft_params=init_params(dc, jax.random.key(0)),
                       draft_config=dc)
         else:
             from ray_tpu.serve.llm.deployment import LLMServer
 
             cls = getattr(LLMServer, "func_or_class", LLMServer)
-            cls(model_config=mc, engine_config=EngineConfig(
-                kv_layout="paged", **ec), quantize="int8")
+            cls(model_config=mc, engine_config=EngineConfig(**ec),
+                quantize="int8")

@@ -5,12 +5,18 @@ import numpy as np
 import pytest
 
 
-def test_decode_matches_full_forward():
+@pytest.mark.parametrize("weights", ["float32", "int8"])
+def test_decode_matches_full_forward(weights):
+    """Token by token through the cache is the full-sequence forward,
+    on the float tree and on the int8 one: every path reads its weights
+    through `_weight`, so `forward` over an int8 tree is bitwise
+    `forward` over the same tree dequantised."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models.llama import (
         LlamaConfig, decode_step, forward, init_kv_cache, init_params,
+        quantize_weights_int8,
     )
 
     config = LlamaConfig.tiny()
@@ -18,6 +24,24 @@ def test_decode_matches_full_forward():
     rng = np.random.RandomState(0)
     tokens = jnp.asarray(rng.randint(0, config.vocab_size, (2, 12)),
                          jnp.int32)
+    if weights == "int8":
+        params = quantize_weights_int8(params)
+
+        def dequantised(tree):
+            out = {k: v for k, v in tree.items()
+                   if not k.endswith(("_q", "_s")) and k != "layers"}
+            for k in tree:
+                if k.endswith("_q"):
+                    out[k[:-2]] = (tree[k].astype(config.dtype)
+                                   * tree[k[:-2] + "_s"].astype(config.dtype))
+            return out
+
+        plain = dequantised(params)
+        plain["layers"] = dequantised(params["layers"])
+        assert set(plain) == {"embed", "layers", "norm_f", "lm_head"}
+        np.testing.assert_array_equal(
+            np.asarray(forward(params, tokens, config)),
+            np.asarray(forward(plain, tokens, config)))
 
     full_logits = forward(params, tokens, config)  # [B, S, V]
 
@@ -204,10 +228,13 @@ def test_decode_attention_matches_explicit_repeat(heads, n_queries):
                              H // KVH, axis=0))
 
 
-def test_verify_kv_paged_matches_successive_decode_steps():
+@pytest.mark.parametrize("K", [4, 1])
+def test_verify_kv_paged_matches_successive_decode_steps(K):
     """`verify_kv_paged` over K tokens gives, row by row, the logits that
     K successive `decode_step_paged` calls give on a GQA config (rep 4):
-    the two run the same attention helper at other query counts."""
+    the two run the same layer over the same paged cache at other query
+    counts. At K = 1 they are one computation: logits and pools are
+    bitwise equal."""
     import jax
     import jax.numpy as jnp
 
@@ -218,7 +245,7 @@ def test_verify_kv_paged_matches_successive_decode_steps():
 
     config = LlamaConfig.tiny(n_heads=8, n_kv_heads=2)
     params = init_params(config, jax.random.key(4))
-    B, bs, K, start = 2, 4, 4, 5
+    B, bs, start = 2, 4, 5
     tables = jnp.asarray([[3, 6, 1, 8], [0, 5, 9, 2]], jnp.int32)
     step = jax.jit(lambda pl, t, p: decode_step_paged(
         params, pl, tables, t, p, config))
@@ -245,3 +272,76 @@ def test_verify_kv_paged_matches_successive_decode_steps():
         np.testing.assert_allclose(
             np.asarray(pools[name], np.float32),
             np.asarray(v_pools[name], np.float32), rtol=2e-2, atol=2e-2)
+    if K == 1:
+        np.testing.assert_array_equal(np.asarray(s_logits),
+                                      np.asarray(v_logits[:, 0]))
+        for name in ("k", "v"):
+            np.testing.assert_array_equal(
+                np.asarray(pools[name], np.float32),
+                np.asarray(v_pools[name], np.float32))
+
+
+def test_prefill_kv_paged_over_zero_history_is_prefill_kv():
+    """`prefill_kv_paged` at start = 0 over an all-zero history is
+    bitwise `prefill_kv`: hidden states and every layer's new K/V rows
+    (the engine's one insert program serves a miss as a hit of length
+    zero)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import (
+        LlamaConfig, init_params, prefill_kv, prefill_kv_paged,
+    )
+
+    config = LlamaConfig.tiny()
+    params = init_params(config, jax.random.key(6))
+    P = 16
+    toks = jnp.asarray(np.random.RandomState(6).randint(
+        0, config.vocab_size, (1, P)), jnp.int32)
+    hist = jnp.zeros((config.n_layers, P, config.n_kv_heads,
+                      config.head_dim), config.dtype)
+    want = jax.jit(lambda t: prefill_kv(params, t, config))(toks)
+    got = jax.jit(lambda t, h: prefill_kv_paged(
+        params, t, jnp.int32(0), h, h, config))(toks, hist)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32))
+
+
+@pytest.mark.parametrize("entry", [
+    "decode_step", "decode_step_paged", "verify_kv_paged", "prefill_kv",
+    "prefill_kv_paged"])
+def test_cached_entry_points_refuse_experts(entry):
+    """Experts are implemented for `forward` alone: every entry point
+    that makes or reads a KV cache refuses `n_experts`, with the one
+    message of the shared trunk."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+
+    config = llama.LlamaConfig.tiny(n_experts=2)
+    params = llama.init_params(config, jax.random.key(0))
+    tok, pos = jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32)
+    pools = llama.init_paged_kv_cache(config, num_blocks=2, block_size=4)
+    tables = jnp.zeros((1, 2), jnp.int32)
+    hist = pools["k"][:, 0]
+    calls = {
+        "decode_step": lambda: llama.decode_step(
+            params, llama.init_kv_cache(config, 1, max_len=8), tok, pos,
+            config),
+        "decode_step_paged": lambda: llama.decode_step_paged(
+            params, pools, tables, tok, pos, config),
+        "verify_kv_paged": lambda: llama.verify_kv_paged(
+            params, pools, tables, tok[:, None], pos, config),
+        "prefill_kv": lambda: llama.prefill_kv(params, tok[None], config),
+        "prefill_kv_paged": lambda: llama.prefill_kv_paged(
+            params, tok[None], jnp.int32(0), hist, hist, config),
+    }
+    with pytest.raises(NotImplementedError,
+                       match="not implemented for MoE configs; use "
+                             "forward"):
+        calls[entry]()
+    logits = llama.forward(params, tok[None], config)
+    assert logits.shape == (1, 1, config.vocab_size)

@@ -326,7 +326,8 @@ def _tiny_engine(buckets=(8,), slots=2, S=32):
     config = LlamaConfig.tiny()
     params = init_params(config, jax.random.key(0))
     return config, LLMEngine(params, config, EngineConfig(
-        num_slots=slots, max_seq_len=S, prefill_buckets=buckets))
+        num_slots=slots, max_seq_len=S, prefill_buckets=buckets,
+        kv_block_size=8))
 
 
 def test_tracked_jit_counts_and_warns():
@@ -374,10 +375,13 @@ def test_engine_recompile_detector_fires():
     assert h.finish_reason == "length"
     assert engine._jit_insert.traces == 1
     with pytest.warns(RecompileWarning, match="llm_engine_insert"):
+        # a 16-token suffix into two fresh blocks of 8: no such bucket
         engine._cache, engine._tok, engine._pos, engine._key = \
             engine._jit_insert(
                 engine.params, engine._cache, engine._tok, engine._pos,
-                np.zeros((12,), np.int32), np.int32(3), np.int32(0),
+                engine._tables[0].copy(), np.int32(0),
+                np.zeros((16,), np.int32), np.int32(3),
+                np.asarray([0, 1], np.int32), np.int32(0),
                 np.float32(0.0), engine._key)
     assert engine._jit_insert.traces == 2
 
@@ -401,8 +405,8 @@ def test_serve_telemetry_end_to_end(ray_start_regular):
     engine.drain()
     assert all(h.finish_reason == "length" for h in handles)
     st = engine.stats()
-    assert st["trace_count"] == (st["traces"]["tick"]
-                                 + st["traces"]["insert"])
+    assert st["trace_count"] == sum(st["traces"].values())
+    assert st["traces"]["tick"] == st["traces"]["insert"] == 1
 
     assert metrics.flush()
     w = global_worker()
@@ -422,14 +426,24 @@ def test_serve_telemetry_end_to_end(ray_start_regular):
 
     w.flush_task_events()
 
-    def _spans_arrived():
-        names = {e["name"] for e in ray_tpu.timeline()}
-        return {"llm.request", "jit_compile"} <= names
+    def _requests(trace):
+        # THIS run's requests: the module's cluster already holds the
+        # spans of the tests above (2-token requests), which satisfy a
+        # wait on names alone while this flush is still in flight.
+        return [e for e in trace if e["name"] == "llm.request"
+                and e["args"].get("tokens") == 4]
 
-    assert _wait_for(_spans_arrived, timeout=15), \
+    def _spans_arrived():
+        trace = ray_tpu.timeline()
+        names = {e["name"] for e in trace}
+        return len(_requests(trace)) >= 3 and {
+            "jit_compile", "llm.queued", "llm.prefill",
+            "llm.decode"} <= names
+
+    assert _wait_for(_spans_arrived, timeout=120), \
         {e["name"] for e in ray_tpu.timeline()}
     trace = ray_tpu.timeline()
-    req_spans = [e for e in trace if e["name"] == "llm.request"]
+    req_spans = _requests(trace)
     assert len(req_spans) >= 3
     assert all(e["cat"] == "span" for e in req_spans)
     assert all(e["args"].get("finish_reason") == "length"

@@ -284,15 +284,15 @@ def _model():
 
 
 def _paged_engine():
-    """One shared paged engine (block-seconds need the paged layout);
-    drained between tests to keep compile count flat."""
+    """One shared engine; drained between tests to keep compile count
+    flat."""
     if "engine" not in _CACHE:
         from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
 
         config, params = _model()
         _CACHE["engine"] = LLMEngine(params, config, EngineConfig(
             num_slots=2, max_seq_len=64, prefill_buckets=(8, 16),
-            kv_layout="paged", kv_block_size=8))
+            kv_block_size=8))
     return _CACHE["engine"]
 
 
@@ -372,7 +372,8 @@ class TestEngineAccounting:
         os.environ["RAY_TPU_serve_accounting_instrumentation"] = "0"
         try:
             engine = LLMEngine(params, config, EngineConfig(
-                num_slots=1, max_seq_len=32, prefill_buckets=(8,)))
+                num_slots=1, max_seq_len=32, prefill_buckets=(8,),
+                kv_block_size=8))
             h = engine.submit(Request(prompt=[1, 2, 3], max_tokens=2))
             engine.drain()
         finally:

@@ -363,7 +363,8 @@ def test_routed_llm_tracing_e2e(traced_cluster):
         handle = serve.run(build_routed_llm_app(
             model_config=config,
             engine_config={"num_slots": 2, "max_seq_len": 64,
-                           "prefill_buckets": (8, 16)},
+                           "prefill_buckets": (8, 16),
+                           "kv_block_size": 8},
             num_replicas=2, num_tpus=0, quantize="bf16",
             max_ongoing_requests=8,
             probe_interval_s=0.1), name="llm-traced")

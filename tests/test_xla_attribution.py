@@ -485,7 +485,8 @@ def test_engine_bucket_programs_attributed(xla_cluster):
     config = LlamaConfig.tiny()
     params = init_params(config, jax.random.key(0))
     engine = LLMEngine(params, config, EngineConfig(
-        num_slots=2, max_seq_len=32, prefill_buckets=(8,)))
+        num_slots=2, max_seq_len=32, prefill_buckets=(8,),
+        kv_block_size=8))
     rng = np.random.RandomState(3)
 
     def _wave():
