@@ -28,6 +28,13 @@ arrive via HF/DeepSpeed through the generic worker group, e.g.
   verify). `generate` / `prefill` / `decode_step` are the reference the
   engine's parity tests compare against: a path of their own over the
   shared layer.
+- What a cache KEEPS is CARRIED through the layer scan; what it EMITS
+  is a scan output.  `_Paged`'s stacked pools and `_Stripe`'s stacked
+  stripes [L, ...] ride in the scan's carry beside the activations and
+  each layer writes its rows in place at (its index, ...), so a donated
+  pool is one buffer from the program's argument to its result.
+  `_History` and `_NoCache` keep nothing: their new rows (and, when
+  scoring, the layers' aux terms) are the scan's stacked outputs.
 """
 
 from __future__ import annotations
@@ -346,11 +353,12 @@ def _ffn(c: LlamaConfig, x, p):
     return x, jnp.zeros((), jnp.float32)
 
 
-def _layer(c: LlamaConfig, p, x, rope, cache, leaves):
+def _layer(c: LlamaConfig, p, x, rope, cache, stacks, leaves):
     """The one definition of a layer: x [B, Q, D], rotated by `rope`
-    (cos, sin as `apply_rope` takes them), attends through `cache`,
-    whose slices for this layer are `leaves`.  Returns (x, what the
-    cache keeps of this layer, aux).
+    (cos, sin as `apply_rope` takes them), attends through `cache`:
+    `stacks` is what the cache carries through the layers (whole, every
+    layer's), `leaves` its inputs for this layer alone.  Returns (x, the
+    stacks as this layer leaves them, the rows it emits, aux).
 
     Scope names (`attn`, `mlp`, ...) reach each operation's `op_name`
     and so a device trace; they change nothing in the compiled program."""
@@ -362,31 +370,44 @@ def _layer(c: LlamaConfig, p, x, rope, cache, leaves):
         k = (h @ _weight(p, "wk", c.dtype)).reshape(B, Q, c.n_kv_heads, kd)
         v = (h @ _weight(p, "wv", c.dtype)).reshape(B, Q, c.n_kv_heads, kd)
         q, k = apply_rope(q, *rope), apply_rope(k, *rope)
-    attn, kept = cache.attend(c, q, k, v, leaves)
+    attn, stacks, rows = cache.attend(c, q, k, v, stacks, leaves)
     with jax.named_scope("attn"):
         x = x + attn.reshape(B, Q, -1) @ _weight(p, "wo", c.dtype)
     with jax.named_scope("mlp"):
         x, aux = _ffn(c, x, p)
-    return x, kept, aux
+    return x, stacks, rows, aux
 
 
 def _trunk(c: LlamaConfig, params, tokens, rope, cache, scoring=False):
-    """Embedding and the scan over the stacked layers, the cache's
-    per-layer leaves beside each layer's weights: tokens [B, Q] ->
-    (hidden before the final norm, the scan's stacked outputs).  Those
-    are what the cache keeps of each layer ([L, ...] leaves), or with
-    `scoring` (no cache is made: training, `forward`) the layers' aux
-    terms [L], the only case experts are implemented for."""
+    """Embedding and the scan over the stacked layers: tokens [B, Q] ->
+    (hidden before the final norm, what the cache keeps).
+
+    A cache is one of two kinds, by its class.  One that KEEPS an
+    updated stack (`_Paged`'s two pools, `_Stripe`'s two stripes) has it
+    CARRIED through the scan beside `x`, whole, and each layer writes its
+    rows in place at its own index, which the scan hands the layer beside
+    its weights (`cache.leaves` is `arange(L)`).  A scan's inputs and
+    outputs are separate buffers and cannot alias, so a pool scanned in a
+    layer at a time and stacked back out is two pools, with each layer's
+    slice cut out of one and copied into the other (PERF.md F3); the
+    carry is one buffer from the first layer to the last, so a donated
+    pool stays the only pool in the program.  One that only EMITS
+    (`_History`'s and `_NoCache`'s new k, v rows) carries nothing: its
+    per-layer inputs are scanned in and its rows come out as the scan's
+    stacked outputs [L, ...].  With `scoring` (no cache is made:
+    training, `forward`) the outputs are the layers' aux terms [L]
+    instead, the only case experts are implemented for."""
     if c.n_experts and not scoring:
         raise NotImplementedError(
             "KV-cache prefill, decode and verify are not implemented for "
             "MoE configs; use forward() for scoring")
     x = embed_lookup(params["embed"].astype(c.dtype), tokens)
 
-    def layer_fn(x, inputs):
+    def layer_fn(carry, inputs):
+        x, stacks = carry
         p, leaves = inputs
-        x, kept, aux = _layer(c, p, x, rope, cache, leaves)
-        return x, (aux if scoring else kept)
+        x, stacks, rows, aux = _layer(c, p, x, rope, cache, stacks, leaves)
+        return (x, stacks), (aux if scoring else rows)
 
     if isinstance(c.remat, str) and c.remat != "dots":
         raise ValueError(
@@ -402,62 +423,68 @@ def _trunk(c: LlamaConfig, params, tokens, rope, cache, scoring=False):
         layer_fn = jax.checkpoint(layer_fn)
 
     # `layers` names what the scan itself does around the body: slicing
-    # a layer's weights and cache out of the stacks, stacking what the
-    # backward needs, writing the cache back.
+    # a layer's weights (and an emitting cache's leaves) out of the
+    # stacks, stacking what the backward needs and what a cache emits.
     with jax.named_scope("layers"):
-        return lax.scan(layer_fn, x, (params["layers"], cache.leaves))
+        (x, stacks), outs = lax.scan(
+            layer_fn, (x, cache.stacks), (params["layers"], cache.leaves))
+    return x, (stacks if cache.stacks else outs)
 
 
 class _NoCache:
     """The sequence's own rows are its keys, through the pluggable
-    attention; keeps the new (pre-repeat) k, v rows."""
-    leaves = ()
+    attention; emits the new (pre-repeat) k, v rows."""
+    stacks = leaves = ()
 
     def __init__(self, attn_fn):
         self.attn_fn = attn_fn
 
-    def attend(self, c, q, k, v, leaves):
+    def attend(self, c, q, k, v, stacks, leaves):
         rep = c.n_heads // c.n_kv_heads
         with jax.named_scope("attn"):
             return self.attn_fn(q, _repeat_kv(k, rep), _repeat_kv(v, rep),
-                                causal=True), (k, v)
+                                causal=True), stacks, (k, v)
 
 
 class _Stripe:
-    """One [S] stripe a sequence, [L, B, S, n_kv, head_dim]: the new row
-    is written at the sequence's position (out of bounds, so dropped,
-    for an inactive one) and the whole stripe attended under the
-    position mask.  Keeps the updated stripes."""
+    """One [S] stripe a sequence, [L, B, S, n_kv, head_dim], carried
+    whole through the layers: layer `l` writes its new row at (l, b,
+    the sequence's position) -- a position out of bounds, so dropped,
+    for an inactive one -- and then attends its own [B, S] stripes under
+    the position mask.  Keeps the updated stripes."""
 
     def __init__(self, cache, positions, active):
-        self.leaves = (cache["k"], cache["v"])
-        S = cache["k"].shape[2]
+        self.stacks = (cache["k"], cache["v"])
+        L, _, S = cache["k"].shape[:3]
+        self.leaves = jnp.arange(L)
         self.positions = positions
         self.write_pos = (positions if active is None
                           else jnp.where(active, positions, S))
 
-    def attend(self, c, q, k, v, leaves):
-        k_cache, v_cache = leaves
+    def attend(self, c, q, k, v, stacks, l):
+        k_cache, v_cache = stacks
         bidx = jnp.arange(q.shape[0])
         with jax.named_scope("kv_write"):
-            k_cache = k_cache.at[bidx, self.write_pos].set(k[:, 0])
-            v_cache = v_cache.at[bidx, self.write_pos].set(v[:, 0])
-        attn = _decode_attention(q, k_cache, v_cache,
+            k_cache = k_cache.at[l, bidx, self.write_pos].set(k[:, 0])
+            v_cache = v_cache.at[l, bidx, self.write_pos].set(v[:, 0])
+        attn = _decode_attention(q, k_cache[l], v_cache[l],
                                  self.positions[:, None])
-        return attn, (k_cache, v_cache)
+        return attn, (k_cache, v_cache), ()
 
 
 class _History:
-    """ONE sequence with its gathered history [L, S_pad, n_kv, head_dim]:
-    the new rows land at `start` of the layer's history and the queries
-    at `qpos` see keys at positions <= their own.  Keeps the new rows
-    for the engine to scatter into the pool."""
+    """ONE sequence with its gathered history [L, S_pad, n_kv, head_dim],
+    scanned in a layer at a time: the new rows land at `start` of the
+    layer's history and the queries at `qpos` see keys at positions <=
+    their own.  Emits the new rows for the engine to scatter into the
+    pool; carries nothing."""
+    stacks = ()
 
     def __init__(self, hist_k, hist_v, start, qpos):
         self.leaves = (hist_k, hist_v)
         self.start, self.qpos = start, qpos
 
-    def attend(self, c, q, k, v, leaves):
+    def attend(self, c, q, k, v, stacks, leaves):
         hk, hv = leaves
         keys = lax.dynamic_update_slice(hk, k[0].astype(hk.dtype),
                                         (self.start, 0, 0))
@@ -469,22 +496,26 @@ class _History:
                 q, _repeat_kv(keys[None].astype(c.dtype), rep),
                 _repeat_kv(vals[None].astype(c.dtype), rep),
                 causal=True, positions=self.qpos)
-        return attn, (k, v)
+        return attn, stacks, (k, v)
 
 
 class _Paged:
-    """New rows against the paged pool, at absolute positions `qpos`:
-    [B, Q] for Q queries a sequence, or [B] for one (the same thing at
-    the index shapes the decode tick was compiled with).  Each row is
-    written at (table[pos // bs], pos % bs) -- a physical block id out
-    of bounds, so dropped, for an inactive sequence -- and then every
-    sequence's dense [S_pad] view is gathered through its block table
-    (AFTER the writes, so a query sees its own row and those before it)
-    and read as it lies.  Keeps the updated pools."""
+    """New rows against the paged pool [L, NB, bs, n_kv, head_dim], at
+    absolute positions `qpos`: [B, Q] for Q queries a sequence, or [B]
+    for one (the same thing at the index shapes the decode tick was
+    compiled with).  The two stacked pools are carried whole through the
+    layers, ONE buffer each from the tick's donated argument to its
+    result: layer `l` writes each row in place at (l, table[pos // bs],
+    pos % bs) -- a physical block id out of bounds, so dropped, for an
+    inactive sequence -- and then gathers every sequence's dense [S_pad]
+    view of its own layer, `pool[l, tables]` (AFTER the writes, so a
+    query sees its own row and those before it), and reads it as it
+    lies.  Keeps the updated pools."""
 
     def __init__(self, pools, block_tables, qpos, active):
-        self.leaves = (pools["k"], pools["v"])
-        NB, bs = pools["k"].shape[1], pools["k"].shape[2]
+        self.stacks = (pools["k"], pools["v"])
+        L, NB, bs = pools["k"].shape[:3]
+        self.leaves = jnp.arange(L)
         seq = jnp.arange(qpos.shape[0]).reshape(
             (-1,) + (1,) * (qpos.ndim - 1))
         self.tables, self.qpos = block_tables, qpos
@@ -493,22 +524,22 @@ class _Paged:
             phys = jnp.where(active.reshape(seq.shape), phys, NB)
         self.phys, self.off = phys, qpos % bs
 
-    def attend(self, c, q, k, v, leaves):
-        k_pool, v_pool = leaves
+    def attend(self, c, q, k, v, stacks, l):
+        k_pool, v_pool = stacks
         B, nb = self.tables.shape
-        dense = (B, nb * k_pool.shape[1], c.n_kv_heads, c.head_dim)
+        dense = (B, nb * k_pool.shape[2], c.n_kv_heads, c.head_dim)
         new = self.phys.shape + k.shape[2:]
         with jax.named_scope("kv_write"):
-            k_pool = k_pool.at[self.phys, self.off].set(
+            k_pool = k_pool.at[l, self.phys, self.off].set(
                 k.reshape(new).astype(k_pool.dtype))
-            v_pool = v_pool.at[self.phys, self.off].set(
+            v_pool = v_pool.at[l, self.phys, self.off].set(
                 v.reshape(new).astype(v_pool.dtype))
         with jax.named_scope("kv_gather"):
-            k_dense = k_pool[self.tables].reshape(dense)
-            v_dense = v_pool[self.tables].reshape(dense)
+            k_dense = k_pool[l, self.tables].reshape(dense)
+            v_dense = v_pool[l, self.tables].reshape(dense)
         attn = _decode_attention(q, k_dense, v_dense,
                                  self.qpos.reshape(B, -1))
-        return attn, (k_pool, v_pool)
+        return attn, (k_pool, v_pool), ()
 
 
 # ---------------------------------------------------------------------------

@@ -345,3 +345,296 @@ def test_cached_entry_points_refuse_experts(entry):
         calls[entry]()
     logits = llama.forward(params, tok[None], config)
     assert logits.shape == (1, 1, config.vocab_size)
+
+
+# ---------------------------------------------------------------------------
+# The caches that keep a stack (`_Paged`, `_Stripe`) ride in the layer
+# scan's carry and are written in place at the layer's index.  The
+# reference below is the same decoder written out layer by layer in a
+# Python loop over PER-LAYER buffers: no scan, no stacked cache.
+# ---------------------------------------------------------------------------
+
+def _written_out(config, params, tokens, rope, attend):
+    """tokens [B, Q] -> hidden before the final norm; `attend(l, q, k, v)`
+    is the layer's attention over whatever cache the caller keeps."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import apply_rope, rms_norm
+
+    c = config
+    B, Q = tokens.shape
+    x = params["embed"].astype(c.dtype)[tokens]
+    for l in range(c.n_layers):
+        p = {k: w[l].astype(c.dtype) for k, w in params["layers"].items()}
+        h = rms_norm(x, p["attn_norm"], c.norm_eps)
+        q = (h @ p["wq"]).reshape(B, Q, c.n_heads, c.head_dim)
+        k = (h @ p["wk"]).reshape(B, Q, c.n_kv_heads, c.head_dim)
+        v = (h @ p["wv"]).reshape(B, Q, c.n_kv_heads, c.head_dim)
+        q, k = apply_rope(q, *rope), apply_rope(k, *rope)
+        x = x + attend(l, q, k, v).reshape(B, Q, -1) @ p["wo"]
+        h = rms_norm(x, p["ffn_norm"], c.norm_eps)
+        x = x + (jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])) @ p["w_down"]
+    x = rms_norm(x, params["norm_f"], c.norm_eps)
+    return jax.lax.dot_general(
+        x, params["lm_head"].astype(c.dtype), (((2,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _written_out_paged(config, params, pools, tables, tokens, positions,
+                       active=None):
+    """K tokens a sequence against per-layer pools: (logits [B, K, V],
+    pools restacked)."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import _decode_attention, rope_freqs
+
+    B, K = tokens.shape
+    NB, bs = pools["k"].shape[1:3]
+    S_pad = tables.shape[1] * bs
+    cos, sin = rope_freqs(config.head_dim, S_pad, config.rope_theta)
+    qpos = jnp.minimum(positions[:, None] + jnp.arange(K)[None, :],
+                       S_pad - 1)
+    phys = tables[jnp.arange(B)[:, None], qpos // bs]
+    if active is not None:
+        phys = jnp.where(active[:, None], phys, NB)
+    per_layer = {n: [pools[n][l] for l in range(config.n_layers)]
+                 for n in ("k", "v")}
+
+    def attend(l, q, k, v):
+        for n, rows in (("k", k), ("v", v)):
+            per_layer[n][l] = per_layer[n][l].at[phys, qpos % bs].set(rows)
+        view = (B, S_pad, config.n_kv_heads, config.head_dim)
+        return _decode_attention(
+            q, per_layer["k"][l][tables].reshape(view),
+            per_layer["v"][l][tables].reshape(view), qpos)
+
+    logits = _written_out(config, params, tokens, (cos[qpos], sin[qpos]),
+                          attend)
+    return logits, {n: jnp.stack(per_layer[n]) for n in ("k", "v")}
+
+
+def _written_out_stripe(config, params, cache, tokens, positions,
+                        active=None):
+    """One token a sequence against per-layer stripes [B, S, kvH, D]."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import _decode_attention, rope_freqs
+
+    B = tokens.shape[0]
+    S = cache["k"].shape[2]
+    cos, sin = rope_freqs(config.head_dim, S, config.rope_theta)
+    write = positions if active is None else jnp.where(active, positions, S)
+    per_layer = {n: [cache[n][l] for l in range(config.n_layers)]
+                 for n in ("k", "v")}
+
+    def attend(l, q, k, v):
+        for n, rows in (("k", k), ("v", v)):
+            per_layer[n][l] = per_layer[n][l].at[
+                jnp.arange(B), write].set(rows[:, 0])
+        return _decode_attention(q, per_layer["k"][l], per_layer["v"][l],
+                                 positions[:, None])
+
+    logits = _written_out(
+        config, params, tokens[:, None],
+        (cos[positions][:, None, :], sin[positions][:, None, :]), attend)
+    return logits[:, 0], {n: jnp.stack(per_layer[n]) for n in ("k", "v")}
+
+
+def _filled_paged(config, params, tables, steps=5):
+    """Pools after `steps` decode steps, rows at different lengths, and
+    the positions the next token of each row sits at."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import decode_step_paged, init_paged_kv_cache
+
+    pools = init_paged_kv_cache(config, num_blocks=12, block_size=4)
+    step = jax.jit(lambda pl, t, p: decode_step_paged(
+        params, pl, tables, t, p, config))
+    toks = jnp.asarray(np.random.RandomState(7).randint(
+        0, config.vocab_size, (tables.shape[0], steps)), jnp.int32)
+    for i in range(steps):
+        _, pools = step(pools, toks[:, i], jnp.asarray([i, i + 2], jnp.int32))
+    return pools, jnp.asarray([steps, steps + 2], jnp.int32)
+
+
+def _bits(x):
+    return np.asarray(x, np.float32)
+
+
+def _exactly(fn, *args):
+    """`fn(*args)` compiled to round after every bf16 operation.  Left to
+    itself XLA:CPU keeps a fused chain of bf16 elementwise operations in
+    float32 (`xla_allow_excess_precision`), so two programs of the same
+    arithmetic that FUSE differently, a scan body and the same layers
+    unrolled, round differently; with it off they agree to the bit."""
+    import jax
+
+    return jax.jit(fn).lower(*args).compile(
+        compiler_options={"xla_allow_excess_precision": False})(*args)
+
+
+_TABLES = [[3, 6, 1, 8], [0, 5, 9, 2]]
+
+
+@pytest.mark.parametrize("entry, K", [
+    ("decode_step_paged", 1), ("verify_kv_paged", 1), ("verify_kv_paged", 3)])
+def test_paged_step_is_the_layers_written_out_without_a_scan(entry, K):
+    """The tick's and verify's logits and pools, bitwise, against the
+    decoder written out over per-layer pools: carrying the stacked pools
+    through the scan and writing at `(l, phys, off)` changes where the
+    rows live, not one value."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+
+    config = llama.LlamaConfig.tiny(n_layers=3, n_heads=8, n_kv_heads=2)
+    params = llama.init_params(config, jax.random.key(8))
+    tables = jnp.asarray(_TABLES, jnp.int32)
+    pools, base = _filled_paged(config, params, tables)
+    toks = jnp.asarray(np.random.RandomState(9).randint(
+        0, config.vocab_size, (2, K)), jnp.int32)
+    want_logits, want_pools = _exactly(
+        lambda pl, t, p: _written_out_paged(config, params, pl, tables, t, p),
+        pools, toks, base)
+    if entry == "decode_step_paged":
+        got_logits, got_pools = _exactly(
+            lambda pl, t, p: llama.decode_step_paged(
+                params, pl, tables, t, p, config), pools, toks[:, 0], base)
+        want_logits = want_logits[:, 0]
+    else:
+        got_logits, got_pools = _exactly(
+            lambda pl, t, p: llama.verify_kv_paged(
+                params, pl, tables, t, p, config), pools, toks, base)
+    np.testing.assert_array_equal(_bits(got_logits), _bits(want_logits))
+    for name in ("k", "v"):
+        assert got_pools[name].shape == pools[name].shape
+        np.testing.assert_array_equal(_bits(got_pools[name]),
+                                      _bits(want_pools[name]))
+        assert (_bits(got_pools[name]) != _bits(pools[name])).any()
+
+
+def test_stripe_step_is_the_layers_written_out_without_a_scan():
+    """`decode_step` over the carried stripes, the same way."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+
+    config = llama.LlamaConfig.tiny(n_layers=3)
+    params = llama.init_params(config, jax.random.key(10))
+    toks = jnp.asarray(np.random.RandomState(11).randint(
+        0, config.vocab_size, (2, 6)), jnp.int32)
+    _, cache = jax.jit(lambda t: llama.prefill(params, t, config,
+                                               max_len=16))(toks[:, :5])
+    pos = jnp.asarray([5, 3], jnp.int32)
+    got_logits, got = _exactly(lambda c, t, p: llama.decode_step(
+        params, c, t, p, config), cache, toks[:, 5], pos)
+    want_logits, want = _exactly(lambda c, t, p: _written_out_stripe(
+        config, params, c, t, p), cache, toks[:, 5], pos)
+    np.testing.assert_array_equal(_bits(got_logits), _bits(want_logits))
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(_bits(got[name]), _bits(want[name]))
+        assert (_bits(got[name]) != _bits(cache[name])).any()
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("kind", ["paged", "stripe"])
+def test_a_layers_write_leaves_every_other_layer_as_it_was(kind, layer):
+    """One layer's `attend` on the carried stacks: the new rows land at
+    the layer's own index, at each sequence's row, and every other value
+    of both stacks (other layers, other blocks, other rows) is bitwise
+    what it was; the view attended is that layer's, after the write."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+
+    config = llama.LlamaConfig.tiny(n_layers=3)
+    L, B, kvh, hd = config.n_layers, 2, config.n_kv_heads, config.head_dim
+    keys = jax.random.split(jax.random.key(12), 5)
+    q = jax.random.normal(keys[0], (B, 1, config.n_heads, hd), config.dtype)
+    k = jax.random.normal(keys[1], (B, 1, kvh, hd), config.dtype)
+    v = jax.random.normal(keys[2], (B, 1, kvh, hd), config.dtype)
+    pos = jnp.asarray([5, 10], jnp.int32)
+    if kind == "paged":
+        shape = (L, 12, 4, kvh, hd)
+        tables = jnp.asarray(_TABLES, jnp.int32)
+        where = [(layer, _TABLES[b][int(pos[b]) // 4], int(pos[b]) % 4)
+                 for b in range(B)]
+    else:
+        shape = (L, B, 16, kvh, hd)
+        where = [(layer, b, int(pos[b])) for b in range(B)]
+    before = {"k": jax.random.normal(keys[3], shape, config.dtype),
+              "v": jax.random.normal(keys[4], shape, config.dtype)}
+    cache = (llama._Paged(before, tables, pos, None) if kind == "paged"
+             else llama._Stripe(before, pos, None))
+    attn, after, rows = jax.jit(
+        lambda l: cache.attend(config, q, k, v, cache.stacks, l))(
+        jnp.int32(layer))
+    assert rows == () and attn.shape == q.shape
+    for name, new, stack in (("k", k, after[0]), ("v", v, after[1])):
+        want = np.array(_bits(before[name]))
+        for b, at in enumerate(where):
+            want[at] = _bits(new[b, 0])
+        np.testing.assert_array_equal(_bits(stack), want)
+    # the attended view is the written layer's: the same rows as a
+    # per-layer cache gives
+    own = {n: after[i][layer] for i, n in enumerate(("k", "v"))}
+    if kind == "paged":
+        own = {n: x[tables].reshape(B, 16, kvh, hd) for n, x in own.items()}
+    np.testing.assert_array_equal(
+        _bits(attn), _bits(llama._decode_attention(
+            q, own["k"], own["v"], pos[:, None])))
+
+
+@pytest.mark.parametrize("entry", [
+    "decode_step_paged", "verify_kv_paged", "decode_step"])
+def test_an_inactive_slot_leaves_the_whole_stack_untouched(entry):
+    """`active` all False: every layer's write is dropped and the pools
+    (stripes) come back bitwise as they went in.  One slot inactive: the
+    stacks equal those of the written-out reference under the same mask,
+    and the inactive slot's rows are as they were in every layer."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+
+    config = llama.LlamaConfig.tiny(n_layers=3)
+    params = llama.init_params(config, jax.random.key(13))
+    tables = jnp.asarray(_TABLES, jnp.int32)
+    toks = jnp.asarray(np.random.RandomState(14).randint(
+        0, config.vocab_size, (2, 3)), jnp.int32)
+    if entry == "decode_step":
+        stacks = jax.tree.map(
+            lambda x: jax.random.normal(jax.random.key(15), x.shape, x.dtype),
+            llama.init_kv_cache(config, 2, max_len=16))
+        base = jnp.asarray([5, 7], jnp.int32)
+        step = lambda c, a: llama.decode_step(
+            params, c, toks[:, 0], base, config, active=a)
+        ref = lambda c, a: _written_out_stripe(
+            config, params, c, toks[:, 0], base, a)
+    else:
+        stacks, base = _filled_paged(config, params, tables)
+        K = 3 if entry == "verify_kv_paged" else 1
+        fn = getattr(llama, entry)
+        t = toks[:, :K] if entry == "verify_kv_paged" else toks[:, 0]
+        step = lambda pl, a: fn(params, pl, tables, t, base, config,
+                                active=a)
+        ref = lambda pl, a: _written_out_paged(
+            config, params, pl, tables, toks[:, :K], base, a)
+    _, none = _exactly(step, stacks, jnp.asarray([False, False]))
+    mask = jnp.asarray([True, False])
+    _, one = _exactly(step, stacks, mask)
+    _, want = _exactly(ref, stacks, mask)
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(_bits(none[name]), _bits(stacks[name]))
+        np.testing.assert_array_equal(_bits(one[name]), _bits(want[name]))
+        changed = _bits(one[name]) != _bits(stacks[name])
+        assert changed.any()
+        if entry == "decode_step":
+            assert not changed[:, 1].any()          # slot 1's stripe
+        else:
+            assert not changed[:, _TABLES[1]].any()  # slot 1's blocks
