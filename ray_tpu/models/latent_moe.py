@@ -279,28 +279,43 @@ def _swiglu(h, w_gate, w_up, w_down, dt):
         @ w_down.astype(dt)
 
 
-def _layer(c: LatentMoEConfig, l: int, p, x, qpos, cos, sin, cache,
-           live=None):
-    """x [B, S, D] at absolute positions qpos [B, S]; cos/sin
-    [B, S, r/2].  Returns (x, tokens routed to each expert or None)."""
+def latent_attention(c: LatentMoEConfig, l: int, p, x, qpos, cos, sin,
+                     cache):
+    """The attention half of a layer: x [B, S, D] at absolute positions
+    qpos [B, S] -> x + attention, the layer's rows going through `cache`
+    at index `l`.  cos/sin [B, S, r/2], or None for a model that applies
+    no rotary (the r "rope" channels of query and key are then plain
+    channels, as they lie)."""
     B, S, D = x.shape
     dt, H = c.dtype, c.n_heads
     n, r, rank = c.qk_nope_head_dim, c.qk_rope_head_dim, c.kv_lora_rank
     with jax.named_scope("attn"):
         h = rms_norm(x, p["attn_norm"], c.norm_eps)
         q = (h @ p["wq"].astype(dt)).reshape(B, S, H, n + r)
-        q_rope = _rope_interleaved(q[..., n:], cos[:, :, None],
-                                   sin[:, :, None]).astype(dt)
         ckr = h @ p["wkv_a"].astype(dt)
+        if cos is None:
+            q_rope, k_rope = q[..., n:], ckr[..., rank:]
+        else:
+            q_rope = _rope_interleaved(q[..., n:], cos[:, :, None],
+                                       sin[:, :, None]).astype(dt)
+            k_rope = _rope_interleaved(ckr[..., rank:], cos, sin).astype(dt)
         new = jnp.concatenate(
-            [rms_norm(ckr[..., :rank], p["kv_norm"], c.norm_eps),
-             _rope_interleaved(ckr[..., rank:], cos, sin).astype(dt),
+            [rms_norm(ckr[..., :rank], p["kv_norm"], c.norm_eps), k_rope,
              jnp.zeros((B, S, c.cache_row - rank - r), dt)], -1)
     rows = cache.update(l, new)
     with jax.named_scope("attn"):
         attn = cache.attend(c, p["wkv_b"].astype(dt), q[..., :n], q_rope,
                             rows, qpos)
-        x = x + attn @ p["wo"].astype(dt)
+        return x + attn @ p["wo"].astype(dt)
+
+
+def feed_forward(c: LatentMoEConfig, p, x, live=None, share=None):
+    """The feed-forward half of a layer: a dense SwiGLU, or the routed
+    experts (of which this chip holds `share`, models/moe.py) plus the
+    shared ones.  Returns (x, tokens routed to each held expert or
+    None)."""
+    B, S, D = x.shape
+    dt = c.dtype
     if "router" not in p:
         with jax.named_scope("mlp"):
             h = rms_norm(x, p["ffn_norm"], c.norm_eps)
@@ -311,11 +326,20 @@ def _layer(c: LatentMoEConfig, l: int, p, x, qpos, cos, sin, cache,
         y, sizes = dropless_moe(
             h.reshape(B * S, D), p,
             sigmoid_bias_top_k(c.top_k, c.routed_scaling_factor),
-            live=None if live is None else live.reshape(B * S))
+            live=None if live is None else live.reshape(B * S),
+            share=share)
         with jax.named_scope("shared"):
             y = y.reshape(B, S, D) + _swiglu(
                 h, p["ws_gate"], p["ws_up"], p["ws_down"], dt)
         return x + y, sizes
+
+
+def _layer(c: LatentMoEConfig, l: int, p, x, qpos, cos, sin, cache,
+           live=None):
+    """x [B, S, D] at absolute positions qpos [B, S]; cos/sin
+    [B, S, r/2].  Returns (x, tokens routed to each expert or None)."""
+    x = latent_attention(c, l, p, x, qpos, cos, sin, cache)
+    return feed_forward(c, p, x, live)
 
 
 def _stack(c: LatentMoEConfig, params, tokens, qpos, cache, live=None):

@@ -182,7 +182,8 @@ def sigmoid_bias_top_k(k: int, scale: float = 1.0) -> Routing:
 
 
 def dropless_moe(x: jax.Array, params: Dict[str, jax.Array],
-                 routing: Routing, live: Optional[jax.Array] = None
+                 routing: Routing, live: Optional[jax.Array] = None,
+                 share: Optional[Tuple[int, int]] = None
                  ) -> Tuple[jax.Array, jax.Array]:
     """x [T, D] -> (y [T, D], tokens routed to each expert [E] int32).
 
@@ -195,7 +196,16 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array],
     products, and the results go back to their tokens by the inverse
     permutation.  `live` [T] bool takes rows out altogether (padding, a
     dead decode slot): they are in no group, add nothing to the counts
-    and get y = 0, so an expert only they picked is not read."""
+    and get y = 0, so an expert only they picked is not read.
+
+    `share` (rank r, of n) says WHICH experts this layer holds: the
+    contiguous range [r E/n, (r+1) E/n) of the E the router is wide,
+    `w_gate` / `w_up` / `w_down` being those E/n.  Routing, the k chosen
+    and their weights are over all E as published; an assignment to an
+    expert held elsewhere is in no group here and reads nothing, so y is
+    the part of the layer's result that the held experts give (the
+    shares' parts add up to the whole layer's) and the counts [E/n] are
+    the held experts' own."""
     T, D = x.shape
     E = params["router"].shape[-1]
     with jax.named_scope("router"):
@@ -203,6 +213,12 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array],
                          params["router"].astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
         idx, w = routing(logits, params)                    # [T, k]
+        if share is not None:
+            E //= share[1]
+            idx = idx - share[0] * E
+            held = (idx >= 0) & (idx < E)
+            idx = jnp.where(held, idx, E)                   # sorts last
+            w = jnp.where(held, w, 0.0)
         if live is not None:
             idx = jnp.where(live[:, None], idx, E)          # sorts last
             w = jnp.where(live[:, None], w, 0.0)

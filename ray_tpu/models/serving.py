@@ -23,6 +23,20 @@ export, adopt, spill, promote) and never look inside a row.
     head_weight(params, config) -> [D, V]
     init_params(config, key), and what only some models have (None
     where a model has none, and the engine refuses by name):
+    init_slot_state(config, num_slots) -> {leaf: [L', B, ...]}, a
+    SECOND kind of state, of a fixed size a slot whatever the sequence's
+    length (a recurrent layer's): a row a slot a layer that keeps one.
+    A model that has it takes and returns it beside the pool:
+        prefill(..., n_real, state) -> (hidden, rows, state), `state`
+        {leaf: [L', ...]} ONE slot's rows, as they stood after the
+        sequence's first `start` tokens (zeros at start 0) in, after
+        the last REAL token of this call out;
+        decode(..., active, state) -> (logits, pools, counts, state),
+        the whole tree, a dead slot's rows left as they were.
+    The engine keeps such a model's state by slot and a sequence in the
+    slot it was admitted to: nothing that moves rows without the state
+    (prefix reuse, spill, export, adopt, preemption, speculation) is
+    offered for it.
     quantize_int8(params); verify(params, pools, tables, tok [B, K],
     pos [B], config, active) -> (logits [B, K, V], pools), the
     speculative target's step; draft, what a model needs to BE a
@@ -54,6 +68,7 @@ class ServingFns(NamedTuple):
     decode: Callable[..., Any]
     head_weight: Callable[..., Any]
     init_counts: Optional[Callable[..., Any]] = None
+    init_slot_state: Optional[Callable[..., Any]] = None
     quantize_int8: Optional[Callable[..., Any]] = None
     draft: Optional[DraftFns] = None
     verify: Optional[Callable[..., Any]] = None

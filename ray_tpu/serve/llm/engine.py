@@ -378,6 +378,11 @@ class LLMEngine:
         if draft_params is not None and model.verify is None:
             raise ValueError(
                 f"{model.name} has no speculative verify step")
+        self._stateful = model.init_slot_state is not None
+        if c.prefix_cache:
+            self._refuse_if_stateful(
+                "prefix reuse (and the spill that rides it; set "
+                "prefix_cache=False)", "a cached block")
 
         # Device state (fixed shapes for the engine's whole lifetime).
         from ray_tpu.serve.llm.kv_cache import (
@@ -413,6 +418,16 @@ class LLMEngine:
                 c.kv_host_tier_bytes, c.kv_block_size,
                 put_fn=_tier_store_put, get_fn=_tier_store_get)
             self._prefix.spill_fn = self._spill_evicted
+        # The second kind of state (models/serving.py): a row a slot a
+        # layer that keeps one, {leaf: [L', B, ...]}; None for a model
+        # whose whole state is rows in the pool. A sequence of such a
+        # model stays in the slot it was admitted to, and a prompt
+        # longer than a bucket is inserted into that slot chunk by
+        # chunk (`_chunking`: the slots whose prompts are under way,
+        # inactive until their last chunk).
+        self._slot_state = (model.init_slot_state(model_config, B)
+                            if self._stateful else None)
+        self._chunking: deque = deque()
         self._tok = jnp.zeros((B,), jnp.int32)
         self._pos = jnp.zeros((B,), jnp.int32)
         self._key = jax.random.key(rng_seed)
@@ -498,11 +513,11 @@ class LLMEngine:
 
         self._jit_tick = tracked_jit(
             self._tick_fn, name="llm_engine_tick",
-            trace_budget=1, donate_argnums=(1, 3, 4))
+            trace_budget=1, donate_argnums=(1, 3, 4, 9))
         self._jit_insert = tracked_jit(
             self._insert_fn, name="llm_engine_insert",
             trace_budget=len(c.prefill_buckets),
-            donate_argnums=(1, 2, 3))
+            donate_argnums=(1, 2, 3, 12))
         # KV migration programs: block counts are data (padded
         # ids, out-of-bounds scatters dropped), so the adopt is ONE
         # trace and the export one per row length of `export_rows`.
@@ -538,7 +553,7 @@ class LLMEngine:
     # ------------------------------------------------------------ programs
 
     def _tick_fn(self, params, pools, tables, tok, pos, active, temp,
-                 key, counters=None):
+                 key, counters=None, state=None):
         """`decode_block` decode steps for all B slots in one dispatch
         (lax.scan — still ONE compiled program; the KV write/read goes
         through the block tables, which are data). Inactive slots are
@@ -547,7 +562,9 @@ class LLMEngine:
         without ever attending past rows it wrote itself; the host
         discards post-stop tokens. What the model's step counts is
         added to `counters` (an empty tree for a model that counts
-        nothing: no operation, no argument)."""
+        nothing: no operation, no argument). `state` is the per-slot
+        state of a model that keeps one (None otherwise: no argument),
+        advanced for the live slots."""
         import jax
         import jax.numpy as jnp
 
@@ -555,26 +572,32 @@ class LLMEngine:
         S = self.config.max_seq_len
 
         def body(carry, _):
-            pools, tok, pos, key, counters = carry
-            logits, pools, counts = decode(
-                params, pools, tables, tok, pos, self.model_config,
-                active)
+            pools, tok, pos, key, counters, state = carry
+            if state is None:
+                logits, pools, counts = decode(
+                    params, pools, tables, tok, pos, self.model_config,
+                    active)
+            else:
+                logits, pools, counts, state = decode(
+                    params, pools, tables, tok, pos, self.model_config,
+                    active, state)
             counters = jax.tree.map(jnp.add, counters, counts)
             with jax.named_scope("sample"):
                 key, sub = jax.random.split(key)
                 nxt = _sample(logits, temp, sub)
                 tok = jnp.where(active, nxt, tok)
                 pos = jnp.where(active, jnp.minimum(pos + 1, S - 1), pos)
-            return (pools, tok, pos, key, counters), tok
+            return (pools, tok, pos, key, counters, state), tok
 
-        (pools, tok, pos, key, counters), toks = jax.lax.scan(
-            body, (pools, tok, pos, key, counters or {}), None,
+        (pools, tok, pos, key, counters, state), toks = jax.lax.scan(
+            body, (pools, tok, pos, key, counters or {}, state), None,
             length=self.config.decode_block)
-        return pools, tok, pos, key, toks, counters   # toks: [K, B]
+        out = (pools, tok, pos, key, toks, counters)         # toks [K, B]
+        return out if state is None else out + (state,)
 
     def _insert_fn(self, params, pools, tok, pos, table_row, hist_len,
                    padded_suffix, suffix_len, new_block_ids, slot,
-                   temperature, key):
+                   temperature, key, state=None):
         """Prefill the (possibly prefix-truncated) suffix of one prompt
         and scatter its KV into the slot's freshly-allocated blocks;
         sample the first generated token from the logits at the last
@@ -586,6 +609,12 @@ class LLMEngine:
         suffix bucket — the only static shapes are ``padded_suffix``
         [Pb] and ``new_block_ids`` [Pb / block_size], both functions of
         the bucket — so compile count stays <= len(prefill_buckets).
+
+        A model with per-slot `state` gets this slot's rows as they
+        stand after the `hist_len` tokens already inserted — zeros when
+        there are none, which is how a slot is cleared at admission —
+        and its rows after the last real token of this call are put
+        back: the hand-off between the chunks of one prompt.
         """
         import jax
         import jax.numpy as jnp
@@ -600,8 +629,16 @@ class LLMEngine:
         hist = {name: pool[:, table_row].reshape(
             (pool.shape[0], S_pad) + pool.shape[3:])
             for name, pool in pools.items()}
-        hidden, rows = self._model.prefill(
-            params, padded_suffix[None], hist_len, hist, c, suffix_len)
+        if state is None:
+            hidden, rows = self._model.prefill(
+                params, padded_suffix[None], hist_len, hist, c, suffix_len)
+        else:
+            hidden, rows, mine = self._model.prefill(
+                params, padded_suffix[None], hist_len, hist, c, suffix_len,
+                {name: jnp.where(hist_len > 0, x[:, slot], 0)
+                 for name, x in state.items()})
+            state = {name: x.at[:, slot].set(mine[name].astype(x.dtype))
+                     for name, x in state.items()}
         # rows: {leaf: [L, Pb, ...]} -> whole blocks into the pool at
         # the slot's new physical ids (padding rows ride along; decode
         # overwrites each before attending).
@@ -619,7 +656,8 @@ class LLMEngine:
         first = _sample(logits, temperature[None], sub)[0]
         tok = tok.at[slot].set(first)
         pos = pos.at[slot].set(hist_len + suffix_len)
-        return pools, tok, pos, key
+        out = (pools, tok, pos, key)
+        return out if state is None else out + (state,)
 
     def _export_fn(self, pools, table_row):
         """Gather the blocks `table_row` names into dense {leaf: [L,
@@ -726,9 +764,11 @@ class LLMEngine:
                 f"slo must be 'interactive' or 'batch', got "
                 f"{request.slo!r}")
         chunked = request.chunked_prefill and P > top
+        if request.prefill_only:
+            self._refuse_if_stateful("prefill_only", "an exported KVState")
         handle = RequestHandle(next(self._ids), request)
         if chunked:
-            if self._prefix is None:
+            if self._prefix is None and not self._stateful:
                 raise ValueError(
                     "chunked_prefill needs prefix_cache=True (chunks "
                     "hand off through the prefix cache)")
@@ -747,6 +787,8 @@ class LLMEngine:
         # submit — queuing it would deadlock admission forever.
         worst = max(self._blocks_needed(P, request.max_tokens),
                     self._bucket_for(min(P, top)) // c.kv_block_size)
+        if chunked and self._stateful:
+            worst = self._chunk_blocks(handle)
         if worst > c.pool_blocks:
             raise ValueError(
                 f"request needs up to {worst} KV blocks but the "
@@ -759,6 +801,14 @@ class LLMEngine:
             self._queues[request.slo].append(handle)
         self._work.set()
         return handle
+
+    def _refuse_if_stateful(self, what: str, carrier: str) -> None:
+        """Whatever moves a sequence's rows without its per-slot state
+        would lose it: refused, by the model's name."""
+        if self._stateful:
+            raise ValueError(
+                f"{self._model.name} keeps a state by slot that {carrier} "
+                f"does not carry: {what} is not offered")
 
     def _attach_meter(self, handle: RequestHandle) -> None:
         """Attach a cost meter (after _capture_trace: the meter is
@@ -792,6 +842,7 @@ class LLMEngine:
         from ray_tpu.serve.llm.kv_cache import KVState
 
         c = self.config
+        self._refuse_if_stateful("adopting one", "a KVState")
         if not isinstance(state, KVState):
             raise TypeError(f"expected KVState, got {type(state)!r}")
         state.validate()
@@ -867,7 +918,8 @@ class LLMEngine:
 
     def has_work(self) -> bool:
         return (any(self._queues.values()) or bool(self._active.any())
-                or bool(self._cancelled) or bool(self._ctrl_q))
+                or bool(self._cancelled) or bool(self._ctrl_q)
+                or bool(self._chunking))
 
     # ------------------------------------------------------------ scheduling
 
@@ -921,6 +973,19 @@ class LLMEngine:
         admissions interleave with a long prefill."""
         inserted: List[Tuple[int, bool]] = []
         chunk_budget = 1
+        if self._chunking:
+            # A model with per-slot state: the prompt under way goes on
+            # in the slot it keeps, ahead of anything queued, and joins
+            # the tick with its last chunk.
+            slot = self._chunking[0]
+            handle = self._slots[slot].handle
+            self._admit_chunk(handle, slot)
+            chunk_budget = 0
+            if handle._chunk_idx == len(handle._chunk_ends):
+                self._chunking.popleft()
+                self._active[slot] = True
+                self._temp[slot] = handle.request.temperature
+                inserted.append((slot, True))
         while self._free:
             handle = self._pop_next()
             if handle is None:
@@ -936,6 +1001,20 @@ class LLMEngine:
                 if chunk_budget == 0:
                     self._requeue(handle)
                     break
+                if self._stateful:
+                    # first chunk: the request takes its slot and every
+                    # block it will need now, and keeps them
+                    slot = self._free.popleft()
+                    if not self._admit_chunk(handle, slot):
+                        self._free.appendleft(slot)
+                        self._requeue(handle)
+                        if req.slo == "interactive":
+                            self._admit_blocked = True
+                        break
+                    chunk_budget -= 1
+                    self._occupy(handle, slot)
+                    self._chunking.append(slot)
+                    continue
                 end = handle._chunk_ends[handle._chunk_idx]
                 slot = self._free[0]
                 t_chunk = time.monotonic()
@@ -975,23 +1054,93 @@ class LLMEngine:
                 # real chip work this request caused.
                 handle.meter.note_chip(
                     "prefill", time.monotonic() - t_admit)
-            if handle.admitted_at is None:
-                handle.admitted_at = time.monotonic()
-                self._metrics.queue_wait.observe(
-                    handle.admitted_at - handle.submitted_at)
-                if handle.meter is not None:
-                    handle.meter.note_queue_wait(
-                        handle.admitted_at - handle.submitted_at)
-            st = self._slots[slot]
-            if st.uses:
-                self._slot_reuses += 1
-                self._metrics.slot_reuses.inc()
-            st.uses += 1
-            st.handle = handle
+            self._occupy(handle, slot)
             self._active[slot] = True
             self._temp[slot] = req.temperature
             inserted.append((slot, fresh))
         return inserted
+
+    def _occupy(self, handle: RequestHandle, slot: int) -> None:
+        """The request has its slot: the queue wait ends here."""
+        if handle.admitted_at is None:
+            handle.admitted_at = time.monotonic()
+            self._metrics.queue_wait.observe(
+                handle.admitted_at - handle.submitted_at)
+            if handle.meter is not None:
+                handle.meter.note_queue_wait(
+                    handle.admitted_at - handle.submitted_at)
+        st = self._slots[slot]
+        if st.uses:
+            self._slot_reuses += 1
+            self._metrics.slot_reuses.inc()
+        st.uses += 1
+        st.handle = handle
+
+    def _chunk_blocks(self, handle: RequestHandle) -> int:
+        """Blocks a chunked prompt of a model with per-slot state takes
+        with its first chunk: every position it can write, and the
+        whole bucket of its last chunk."""
+        req, bs = handle.request, self.config.kv_block_size
+        P = len(req.prompt)
+        start = handle._chunk_ends[-2] if len(handle._chunk_ends) > 1 else 0
+        return max(self._blocks_needed(P, req.max_tokens),
+                   (start + self._bucket_for(P - start)) // bs)
+
+    def _admit_chunk(self, handle: RequestHandle, slot: int) -> bool:
+        """The next chunk of a chunked prompt of a model with per-slot
+        state, into the slot the request keeps. The first chunk takes
+        the blocks (False, and nothing taken, when the pool cannot
+        cover them); a later one finds the rows and the state of the
+        chunks before it in the slot, so nothing is handed over and
+        nothing can be lost in between."""
+        import numpy as np
+
+        req, c = handle.request, self.config
+        bs = c.kv_block_size
+        i = handle._chunk_idx
+        start = handle._chunk_ends[i - 1] if i else 0
+        n = handle._chunk_ends[i] - start
+        t_chunk = time.monotonic()
+        with trace_span("llm_engine.admit_one", chunk=1):
+            if i == 0:
+                blocks = self._allocator.alloc(self._chunk_blocks(handle))
+                if blocks is None:
+                    return False
+                self._tables[slot] = 0
+                self._tables[slot, :len(blocks)] = blocks
+                self._slot_blocks[slot] = blocks
+                if handle.meter is not None:
+                    handle.meter.blocks_acquired(len(blocks))
+            bucket = self._bucket_for(n)
+            padded = np.zeros((bucket,), np.int32)
+            padded[:n] = np.asarray(req.prompt[start:start + n], np.int32)
+            row = self._tables[slot].copy()
+            self._insert(slot, row, start, padded, n,
+                         row[start // bs:(start + bucket) // bs],
+                         req.temperature)
+        if handle.meter is not None:
+            handle.meter.note_chip("prefill", time.monotonic() - t_chunk)
+        handle.prefilled_tokens += n
+        handle._chunk_idx += 1
+        return True
+
+    def _insert(self, slot: int, row, hist_len: int, padded, suffix_len: int,
+                scatter_ids, temperature: float) -> None:
+        """Dispatch the insert program of `padded`'s bucket."""
+        import numpy as np
+
+        said = {"bucket": len(padded)}
+        if self._stateful:      # what the chunk is, for the trace's readers
+            said.update(tokens=int(suffix_len), state_in=int(hist_len > 0))
+        with trace_span("llm_engine.insert_dispatch", **said):
+            (self._cache, self._tok, self._pos, self._key,
+             *state) = self._jit_insert(
+                self.params, self._cache, self._tok, self._pos, row,
+                np.int32(hist_len), padded, np.int32(suffix_len),
+                scatter_ids, np.int32(slot), np.float32(temperature),
+                self._key, self._slot_state)
+        if state:
+            self._slot_state, = state
 
     def _admit_prefill(self, handle: RequestHandle, slot: int,
                        upto: Optional[int] = None,
@@ -1121,13 +1270,8 @@ class LLMEngine:
         padded[:suffix_len] = np.asarray(prompt[hist_len:], np.int32)
         scatter_ids = np.asarray(new_blocks[n_pro:n_pro + bucket // bs],
                                  np.int32)
-        with trace_span("llm_engine.insert_dispatch", bucket=bucket):
-            self._cache, self._tok, self._pos, self._key = \
-                self._jit_insert(
-                    self.params, self._cache, self._tok, self._pos,
-                    row, np.int32(hist_len), padded, np.int32(suffix_len),
-                    scatter_ids, np.int32(slot),
-                    np.float32(req.temperature), self._key)
+        self._insert(slot, row, hist_len, padded, suffix_len, scatter_ids,
+                     req.temperature)
         handle.prefilled_tokens += suffix_len
         if self._prefix is not None:
             # Register the prompt's FULL blocks (all rows real) so the
@@ -1239,6 +1383,8 @@ class LLMEngine:
         self._active[slot] = False
         self._temp[slot] = 0.0
         self._spec_ok[slot] = False
+        if slot in self._chunking:      # cancelled between two chunks
+            self._chunking.remove(slot)
         if self._slot_blocks[slot]:
             # Drop this sequence's refs; blocks shared with the prefix
             # cache (or other sequences) stay resident.
@@ -1520,6 +1666,7 @@ class LLMEngine:
         :meth:`call_on_scheduler` from anywhere else."""
         import numpy as np
 
+        self._refuse_if_stateful("export_prefix", "an exported block")
         if self._prefix is None:
             return []
         c = self.config
@@ -1591,6 +1738,7 @@ class LLMEngine:
         preempt → resume cycle is token-invisible to the client."""
         st = self._slots[slot]
         handle = st.handle
+        self._refuse_if_stateful("preemption", "a checkpoint")
         if handle is None:
             raise ValueError(f"slot {slot} is not live")
         handle.kv_state = self._export_state(slot)
@@ -1610,7 +1758,7 @@ class LLMEngine:
         full batch) never thrashes checkpoints."""
         with self._lock:
             waiting = len(self._queues["interactive"])
-        if not waiting:
+        if not waiting or self._stateful:
             self._preempt_gate.propose(0, 0)
             return
         batch_slots = [
@@ -1804,6 +1952,7 @@ class LLMEngine:
             self._process_cancels()
             self._maybe_preempt()
         self._admit_blocked = False
+        did_ctrl = did_ctrl or bool(self._chunking)   # a chunk will go out
         with trace_span("llm_engine.admit") as sp:
             inserted = self._admit()
             sp.set_metadata(admitted=len(inserted))
@@ -1834,10 +1983,13 @@ class LLMEngine:
                 out = self._spec_dispatch()
             else:
                 (self._cache, self._tok, self._pos, self._key, out,
-                 self._counters) = self._jit_tick(
+                 self._counters, *state) = self._jit_tick(
                     self.params, self._cache, self._tables.copy(),
                     self._tok, self._pos, self._active.copy(),
-                    self._temp.copy(), self._key, self._counters)
+                    self._temp.copy(), self._key, self._counters,
+                    self._slot_state)
+                if state:
+                    self._slot_state, = state
         # What this step's admissions evicted lands while the chip
         # runs their inserts and the tick.
         self._land_spills()
@@ -2020,6 +2172,8 @@ class LLMEngine:
             self._draft = draft
         import jax
 
+        if self._stateful:
+            return                      # exports nothing (models/serving.py)
         for n in self.config.export_rows:       # one row alive at a time
             jax.block_until_ready(self._export_blocks([0] * n))
 
@@ -2040,6 +2194,20 @@ class LLMEngine:
                 for name in ("tick", "insert", "export", "adopt", "spec",
                              "draft_insert")
                 if hasattr(self, f"_jit_{name}")}
+
+    def slot_state(self, slot: int) -> Optional[Dict[str, Any]]:
+        """One slot's rows of the model's per-slot state, on the host
+        (None for a model that keeps none): what the slot holds after
+        its last insert or tick.  A finished request's rows stay as it
+        left them until the next admission into the slot zeroes them.
+        For tests and for the benchmark's audit of the state's
+        precision; call it between steps."""
+        if not self._stateful:
+            return None
+        import numpy as np
+
+        return {name: np.asarray(x[:, slot])
+                for name, x in self._slot_state.items()}
 
     def stats(self) -> Dict[str, Any]:
         with self._lock:
@@ -2073,6 +2241,11 @@ class LLMEngine:
                 promote_skips=self._promote_skips,
                 spill_lands=self._spill_lands,
                 spill_lands_waited=self._spill_lands_waited)
+        if self._stateful:
+            out["slot_state"] = {
+                "bytes": sum(int(x.nbytes)
+                             for x in self._slot_state.values()),
+                "prompts_under_way": len(self._chunking)}
         if self._counters:
             # the model's own counters, summed on the device since
             # start and read here (waits for the tick in flight)

@@ -478,6 +478,63 @@ def test_latent_moe_cell_programs_fit_one_v5e(one_chip, program):
     assert _hbm_gib(compiled) < V5E_HBM_GIB - 2.0
 
 
+@pytest.mark.parametrize("program", ["tick", "insert"])
+def test_hybrid_cell_programs_fit_one_v5e(one_chip, program):
+    """The engine's decode tick and its largest insert at the geometry of
+    the benchmark's `agent-decode-hybrid` cell (KDA state by slot beside
+    the paged latent pool, 64 of 256 experts held, at
+    Kimi-Linear-48B-A3B's published widths; the depth, slots, row
+    length, buckets and pool its files state): they compile for v5e,
+    the grouped products are the chip's own grouped-matmul calls, the
+    latent pool AND the slots' recurrent state are updated in place
+    (donated, static layer index), and arguments + temporaries fit HBM.
+    These readings sized the configuration's depth and the cell's
+    slots and pool."""
+    from ray_tpu.serve.llm.engine import LLMEngine
+
+    eng = _serving_cell("agent-decode-hybrid", one_chip)
+    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+                                eng.published)
+    params, pools, key = eng.params, eng.pools, eng.key
+    assert (published["num_hidden_layers"], published["hidden_size"],
+            published["num_experts"], published["vocab_size"],
+            mc.n_experts, mc.n_kda_layers, mc.n_mla_layers) \
+        == (8, 2304, 64, 40960, 256, 6, 2)
+    state = _placed(jax.eval_shape(
+        lambda: model.init_slot_state(mc, ec.num_slots)), one_chip)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    B, nb = ec.num_slots, ec.max_blocks_per_slot
+    if program == "tick":
+        compiled = jax.jit(
+            functools.partial(LLMEngine._tick_fn, eng),
+            donate_argnums=(1, 3, 4, 9)).lower(
+            params, pools, arg(jnp.int32, B, nb), arg(jnp.int32, B),
+            arg(jnp.int32, B), arg(jnp.bool_, B), arg(jnp.float32, B), key,
+            _placed(jax.eval_shape(lambda: model.init_counts(mc)),
+                    one_chip), state).compile()
+        assert compiled.as_text().count(
+            'custom_call_target="tpu_custom_call"') >= 3 * mc.n_moe_layers
+    else:
+        Pb = ec.prefill_buckets[-1]
+        compiled = jax.jit(
+            functools.partial(LLMEngine._insert_fn, eng),
+            donate_argnums=(1, 2, 3, 12)).lower(
+            params, pools, arg(jnp.int32, B), arg(jnp.int32, B),
+            arg(jnp.int32, nb), arg(jnp.int32), arg(jnp.int32, Pb),
+            arg(jnp.int32), arg(jnp.int32, Pb // ec.kv_block_size),
+            arg(jnp.int32), arg(jnp.float32), key, state).compile()
+    m = compiled.memory_analysis()
+    kept = sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in list(pools.values()) + list(state.values()))
+    print(program, "GiB", _hbm_gib(compiled), "temp",
+          m.temp_size_in_bytes / GIB, "args", m.argument_size_in_bytes / GIB)
+    assert m.alias_size_in_bytes >= kept                # both in place
+    assert _hbm_gib(compiled) < V5E_HBM_GIB - 0.5
+
+
 def test_train_step_holds_flash_kernel_and_fits_one_v5e(topo, on_tpu):
     """One whole `build_train_step` program at chip_smoke.py's train
     widths, depth and batch, on a one-device mesh."""

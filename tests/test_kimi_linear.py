@@ -1,0 +1,489 @@
+"""Recurrent delta-rule layers (KDA) with their state by slot beside
+latent attention over the paged latent cache, and an expert layer that
+holds a share of the experts (`models/kimi_linear.py`, `ops/kda.py`,
+`models/moe.py::dropless_moe(share=)`, `serve/llm/engine.py`), against
+the plain float32 reference of `benchmarks/reference/kda_hybrid_decoder.py`
+on seeded random weights at a tiny size.  Logits are compared, never
+sampled tokens (but for the engine tests, which judge served tokens by
+their reference logits, as the benchmark does).
+
+Tolerances and their reasons
+----------------------------
+* 5e-6 on logits of magnitude 0.6, float32 against float32 on the CPU:
+  the program and the reference differ in the ORDER of float32 sums
+  (the chunkwise form against the token-by-token recurrence, sorted
+  expert groups against blocks, absorbed against expanded attention);
+  that reads 2e-7 to 6e-7 here.  A recurrent state kept in bf16 between
+  tokens reads 1e-3 and int8-rounded matrices 2e-2:
+  `test_lower_precision_is_caught` holds the tolerance to half of both.
+* `kda_chunked` against `kda_step` applied token by token: 2e-6 on
+  outputs and states of magnitude 1 to 3, for decays within 1e-4 of 1
+  (where nothing is forgotten and sums grow) and for decays of e^-12 a
+  token (where a factored exp(-G) would overflow after 8 tokens).
+* The engine tests serve greedy tokens in float32; each served token's
+  reference logit lies within 1e-4 of the reference maximum (0 unless
+  two logits tie to within the sums' reordering).
+"""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+TOL = 5e-6
+# two periods less one layer: KDA KDA KDA MLA KDA, layer 1 dense
+C = dict(hidden_size=64, num_attention_heads=4, kv_lora_rank=32,
+         qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+         intermediate_size=128, moe_intermediate_size=32,
+         num_experts=2, num_experts_per_token=2, num_shared_experts=1,
+         routed_scaling_factor=2.446, moe_renormalize=True,
+         mla_use_nope=True, vocab_size=512, num_hidden_layers=5,
+         first_k_dense_replace=1, rms_norm_eps=1e-5,
+         router_bias_scale=0.1, initializer_range=0.02,
+         linear_attn_config=dict(
+             full_attn_layers=[4, 8], kda_layers=[1, 2, 3, 5, 6, 7],
+             num_heads=4, head_dim=16, short_conv_kernel_size=4),
+         deployment=dict(num_experts=8, rank=1),
+         precision=dict(recurrent_state="float32"))
+BS = 4            # rows a block
+BUCKET = 16       # one prefill bucket
+
+
+def _build(c, **overrides):
+    from families import kda_hybrid_decoder as F
+    from reference import kda_hybrid_decoder as R
+
+    mc = F.model_config(c, max_seq_len=64, compute_dtype="float32",
+                        param_dtype="float32", **overrides)
+    weights = R.init_weights(c, 11, jnp.float32)
+    return R, mc, weights, F.program_params(weights)
+
+
+@pytest.fixture(scope="module")
+def model():
+    return _build(C)
+
+
+def _tokens(n, seed=0):
+    return [int(t) for t in np.random.RandomState(seed).randint(0, 512, n)]
+
+
+def _reference_logits(R, weights, toks, start, n, c=C):
+    return np.asarray(R.logits_for_positions(weights, c, toks, start, n,
+                                             pad_to=64))
+
+
+# ------------------------------------------------ (a) no cache, whole model
+
+def test_forward_matches_reference(model):
+    from ray_tpu.models.kimi_linear import forward
+
+    R, mc, weights, params = model
+    assert (mc.n_kda_layers, mc.n_mla_layers, mc.n_held_experts,
+            mc.expert_rank, mc.expert_shards) == (4, 1, 2, 1, 4)
+    toks = _tokens(50)
+    got = np.asarray(forward(params, jnp.asarray(toks)[None], mc)[0])
+    want = _reference_logits(R, weights, toks, 0, 50)
+    assert np.abs(want).max() > 0.3
+    assert np.abs(got - want).max() < TOL
+
+
+# ---------------------- (b) prefill + decode: paged rows and slot state
+
+def _prefill(mc, params, pools, state, slot, table, toks, start,
+             bucket=BUCKET):
+    """One bucket-padded chunk of `toks` at `start` into the blocks of
+    `table` and the state row of `slot`, as the engine's insert program
+    does it."""
+    from ray_tpu.models.kimi_linear import prefill_paged
+
+    S_pad = table.shape[0] * BS
+    hist = {k: v[:, table].reshape((v.shape[0], S_pad) + v.shape[3:])
+            for k, v in pools.items()}
+    padded = np.zeros((bucket,), np.int32)
+    padded[:len(toks)] = toks
+    mine = {k: jnp.where(start > 0, v[:, slot], 0) for k, v in state.items()}
+    x, rows, mine = prefill_paged(params, jnp.asarray(padded)[None],
+                                  jnp.int32(start), hist, mc,
+                                  jnp.int32(len(toks)), mine)
+    ids = table[start // BS: start // BS + bucket // BS]
+    pools = {k: v.at[:, ids].set(rows[k].reshape(
+        (v.shape[0], bucket // BS, BS) + v.shape[3:]))
+        for k, v in pools.items()}
+    state = {k: v.at[:, slot].set(mine[k]) for k, v in state.items()}
+    return x[0, :len(toks)], pools, state
+
+
+@pytest.mark.parametrize("case", ["one_bucket", "chunked"])
+def test_paged_prefill_and_decode_match_reference(model, case):
+    """Prefill (one bucket; two chunks, the second over the first's rows
+    and state) and then 10 decode steps through the paged latent pool
+    and the slot's recurrent state: logits at every position against
+    the reference's full forward."""
+    from ray_tpu.models.kimi_linear import (LM, decode_step_paged,
+                                            init_paged_pool,
+                                            init_slot_state)
+
+    R, mc, weights, params = model
+    n_prompt = {"one_bucket": 13, "chunked": 27}[case]
+    toks = _tokens(n_prompt + 10, seed=3)
+    pools = init_paged_pool(mc, 40, BS)
+    assert pools["latent"].shape[0] == 1            # MLA layers only
+    state = init_slot_state(mc, 3)
+    # slot 2 holds another sequence's garbage: admission must clear it
+    state = jax.tree.map(lambda x: x.at[:, 2].set(1.0), state)
+    table = np.arange(16, dtype=np.int32) + 5
+    hidden = []
+    for start in range(0, n_prompt, BUCKET):
+        x, pools, state = _prefill(mc, params, pools, state, 2, table,
+                                   toks[start:min(start + BUCKET, n_prompt)],
+                                   start)
+        hidden.append(x)
+    got = [np.asarray(LM._head(mc, params, jnp.concatenate(hidden)))]
+    tables = np.zeros((3, 16), np.int32)
+    tables[2] = table
+    active = jnp.asarray([False, False, True])
+    before = jax.tree.map(lambda x: np.asarray(x[:, :2]), state)
+    for t in range(n_prompt, n_prompt + 10):
+        logits, pools, counts, state = decode_step_paged(
+            params, pools, jnp.asarray(tables),
+            jnp.asarray([0, 0, toks[t]]), jnp.asarray([0, 0, t]), mc,
+            active, state)
+        got.append(np.asarray(logits[2:3]))
+    want = _reference_logits(R, weights, toks, 0, len(toks))
+    assert np.abs(np.concatenate(got) - want).max() < TOL
+    # dead slots: their state stands as it was
+    for k, v in before.items():
+        assert np.array_equal(np.asarray(state[k][:, :2]), v)
+    assert int(counts["live_slots"]) == 1 and int(counts["ticks"]) == 1
+    assert int(counts["pairs_total"]) == mc.top_k * mc.n_moe_layers
+    assert int(counts["pairs_local"]) == int(counts["expert_tokens"].sum())
+    assert counts["expert_tokens"].shape == (4, 2)   # held experts only
+
+
+# --------------------- (c) what the chunks of one prompt hand each other
+
+@pytest.mark.parametrize("case", ["chunked_equals_whole",
+                                  "padded_equals_unpadded"])
+def test_prefill_hand_off(model, case):
+    """A prompt prefilled in chunks leaves the rows, the recurrent state
+    and the convolution's tail that the same prompt prefilled whole
+    leaves; a prompt in a larger (padded) bucket leaves what it leaves
+    in one it fills exactly: padding advances nothing."""
+    from ray_tpu.models.kimi_linear import init_paged_pool, init_slot_state
+
+    _, mc, _, params = model
+    toks = _tokens(32, seed=5)
+    table = np.arange(16, dtype=np.int32) + 2
+    plans = {"chunked_equals_whole": ((((0, 29),), 32),
+                                      (((0, 16), (16, 29)), 16)),
+             "padded_equals_unpadded": ((((0, 16),), 32),
+                                        (((0, 16),), 16))}[case]
+    out = []
+    for chunks, bucket in plans:
+        pools, state = init_paged_pool(mc, 30, BS), init_slot_state(mc, 2)
+        xs = []
+        for a, b in chunks:
+            x, pools, state = _prefill(mc, params, pools, state, 1, table,
+                                       toks[a:b], a, bucket)
+            xs.append(np.asarray(x))
+        n = sum(len(x) for x in xs)
+        rows = np.asarray(pools["latent"][:, table]).reshape(
+            1, -1, pools["latent"].shape[-1])[:, :n]
+        out.append((np.concatenate(xs), rows,
+                    {k: np.asarray(v[:, 1]) for k, v in state.items()}))
+    (xa, ra, sa), (xb, rb, sb) = out
+    assert xa.shape == xb.shape and np.abs(xa).max() > 0.5
+    assert np.abs(xa - xb).max() < TOL
+    assert np.abs(ra - rb).max() < TOL
+    assert np.abs(sa["S"]).max() > 1e-3 and np.abs(sa["conv"]).max() > 1e-3
+    for k in sa:
+        assert np.abs(sa[k] - sb[k]).max() < TOL, k
+
+
+# ---------------- (d) the chunkwise form against the one-token recurrence
+
+@pytest.mark.parametrize("lo,hi", [(-1e-4, -1e-6), (-12.0, -3.0),
+                                   (-2.0, -0.01)],
+                         ids=["decay_near_1", "decay_near_0", "mixed"])
+def test_chunkwise_kda_equals_recurrence(lo, hi):
+    from ray_tpu.ops.kda import kda_chunked, kda_step
+
+    B, T, H, dk, dv = 2, 50, 3, 16, 8
+    ks = jax.random.split(jax.random.key(0), 7)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (B, T, H, dk)))
+    k = unit(jax.random.normal(ks[1], (B, T, H, dk)))
+    v = jax.random.normal(ks[2], (B, T, H, dv))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[3], (B, T, H)))
+    g = jax.random.uniform(ks[4], (B, T, H, dk), minval=lo, maxval=hi)
+    S = S0 = jax.random.normal(ks[5], (B, H, dk, dv))
+    n_real = jnp.asarray([50, 37])
+    outs, states = [], []
+    for t in range(T):
+        o, S = kda_step(S, q[:, t], k[:, t], v[:, t], g[:, t], beta[:, t])
+        outs.append(o)
+        states.append(S)
+    want = jnp.stack(outs, 1)
+    got, S_got = kda_chunked(q, k, v, g, beta, S0, n_real, chunk=16)
+    assert jnp.abs(want).max() > 0.5
+    assert jnp.abs(got[0] - want[0]).max() < 2e-6
+    assert jnp.abs(got[1, :37] - want[1, :37]).max() < 2e-6
+    # the state after the last REAL token: padding did not advance it
+    S_want = jnp.stack([states[49][0], states[36][1]])
+    assert jnp.abs(S_got - S_want).max() < 2e-6
+
+
+# ----------------------------------- (e) the shares add up to the layer
+
+def test_expert_shares_sum_to_the_uncut_layer():
+    """The parts of an expert layer's result that the four shares give
+    (each routes over all 8 experts, holds 2 and sums those), with the
+    shared expert counted once, add up to what the reference gives for
+    the whole layer with all 8 experts held."""
+    from reference import kda_hybrid_decoder as R
+
+    from ray_tpu.models import latent_moe as LM
+    from ray_tpu.models.moe import dropless_moe, sigmoid_bias_top_k
+
+    uncut = dict(C, num_experts=8, deployment=dict(num_experts=8, rank=0))
+    w_all = R.init_weights(uncut, 11, jnp.float32)["layers"][1]
+    # wide inputs: the scores then spread past the selection bias, so
+    # the tokens' choices differ and fall on every share
+    h = 8.0 * jax.random.normal(jax.random.key(4), (24, 64), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        routed = R.held_experts(uncut, h, R.route(
+            uncut, h, w_all["router"], w_all["router_bias"]),
+            w_all["experts"])
+        want = routed + R._swiglu(h, w_all["ws_gate"], w_all["ws_up"],
+                                  w_all["ws_down"])
+    assert jnp.abs(routed).max() > 1e-3
+    total, seen, busy = 0.0, 0, 0
+    for rank in range(4):
+        share = dict(C, deployment=dict(num_experts=8, rank=rank))
+        w = R.init_weights(share, 11, jnp.float32)["layers"][1]
+        assert jnp.array_equal(w["router"], w_all["router"])
+        bank = R.expert_bank(w["experts"])
+        assert bank["w_gate"].shape[0] == 2
+        y, sizes = dropless_moe(
+            h, dict(w, **bank), sigmoid_bias_top_k(2, 2.446),
+            share=(rank, 4))
+        total, seen = total + y, seen + int(sizes.sum())
+        busy += int(sizes.sum() > 0)
+    assert seen == 24 * 2                   # every assignment, once
+    assert busy >= 3                        # and not all on one share
+    shared = LM._swiglu(h, w_all["ws_gate"], w_all["ws_up"],
+                        w_all["ws_down"], jnp.float32)
+    assert jnp.abs(total + shared - want).max() < TOL
+
+
+# --------------------------------------------------- (f) lower precision
+
+@pytest.mark.parametrize("what", ["state_bf16", "int8"])
+def test_lower_precision_is_caught(model, what):
+    """The tolerance is tight enough: a recurrent state kept in bf16
+    between tokens (the cell's second control), or matrices rounded to
+    int8 (its first), fails it."""
+    from families import kda_hybrid_decoder as F
+
+    from ray_tpu.models.kimi_linear import forward
+
+    R, mc, weights, params = model
+    toks = _tokens(50)
+    want = _reference_logits(R, weights, toks, 0, 50)
+    if what == "int8":
+        # the control deletes the bank of experts `program_params` made
+        # last: make that one this test's own, not the fixture's
+        _, _, weights, _ = _build(C)
+        params = jax.jit(F.lower_precision_params)(weights)
+        got = forward(params, jnp.asarray(toks)[None], mc)[0]
+    else:
+        c = dict(C, precision=dict(recurrent_state="bfloat16"))
+        _, mc16, _, _ = _build(c)
+        assert mc16.state_dtype == jnp.bfloat16
+        got = _served_logits(mc16, params, toks)
+    assert np.abs(np.asarray(got) - want).max() > 2 * TOL
+
+
+def _served_logits(mc, params, toks):
+    """Logits of every position through the serving path: the first
+    bucket prefilled, the rest decoded a token at a time."""
+    from ray_tpu.models.kimi_linear import (LM, decode_step_paged,
+                                            init_paged_pool,
+                                            init_slot_state)
+
+    pools, state = init_paged_pool(mc, 20, BS), init_slot_state(mc, 1)
+    table = np.arange(16, dtype=np.int32) + 1
+    x, pools, state = _prefill(mc, params, pools, state, 0, table,
+                               toks[:BUCKET], 0)
+    got = [LM._head(mc, params, x)]
+    for t in range(BUCKET, len(toks)):
+        logits, pools, _, state = decode_step_paged(
+            params, pools, jnp.asarray(table[None]), jnp.asarray([toks[t]]),
+            jnp.asarray([t]), mc, jnp.asarray([True]), state)
+        got.append(logits)
+    return jnp.concatenate(got)
+
+
+def test_float32_state_through_the_serving_path(model):
+    """`_served_logits` with the state as the file states it is inside
+    the tolerance (so what `state_bf16` shows is the state's precision)."""
+    R, mc, weights, params = model
+    toks = _tokens(50)
+    got = np.asarray(_served_logits(mc, params, toks))
+    assert np.abs(got - _reference_logits(R, weights, toks, 0, 50)).max() \
+        < TOL
+
+
+# ------------------------------------------------------- (g) the engine
+
+def _engine(mc, params, **over):
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+    cfg = dict(num_slots=2, max_seq_len=64, prefill_buckets=(8, 16),
+               kv_block_size=BS, num_kv_blocks=40, prefix_cache=False)
+    cfg.update(over)
+    return LLMEngine(params, mc, EngineConfig(**cfg), rng_seed=3)
+
+
+def test_engine_serves_chunked_prompts_and_recycles_slots(model):
+    """Seven requests through two slots, prompts from one token to three
+    chunks: every slot is freed and re-admitted, every served token is
+    the reference's choice given the served prefix (so a re-admitted
+    slot started from a zero state: a leak would change its logits),
+    and a prompt under way keeps its slot inactive until its last
+    chunk."""
+    from ray_tpu.serve.llm.engine import Request
+
+    R, mc, weights, params = model
+    engine = _engine(mc, params)
+    engine.warmup()
+    assert engine.stats()["traces"] == {"tick": 1, "insert": 2,
+                                        "export": 0, "adopt": 0}
+    prompts = [_tokens(n, seed=20 + n) for n in (1, 16, 37, 9, 45, 17, 3)]
+    handles = [engine.submit(Request(
+        prompt=p, max_tokens=6, chunked_prefill=len(p) > 16))
+        for p in prompts]
+    seen_under_way = 0
+    while engine.has_work():
+        engine.step()
+        for slot in engine._chunking:
+            seen_under_way += 1
+            assert not engine._active[slot]
+            assert engine._slots[slot].handle is not None
+    assert seen_under_way > 0
+    st = engine.stats()
+    assert st["slot_reuses"] >= 5 and st["trace_count"] == 3
+    assert st["slot_state"]["prompts_under_way"] == 0
+    assert st["kv"]["used_blocks"] == 0
+    for p, h in zip(prompts, handles):
+        assert h.finish_reason == "length" and len(h.tokens) == 6
+        assert h.prefilled_tokens == len(p)
+        d = R.served_token_deficits(weights, C, p, h.tokens)
+        assert d.max() < 1e-4, (len(p), d)
+    ctr = st["counters"]
+    assert int(ctr["ticks"]) > 0
+    assert int(ctr["live_slots"]) <= 2 * int(ctr["ticks"])
+    assert int(ctr["pairs_local"]) == int(ctr["expert_tokens"].sum())
+    assert int(ctr["pairs_total"]) == int(ctr["live_slots"]) * 2 * 4
+
+
+def test_slot_state_is_what_the_reference_carries(model):
+    """`LLMEngine.slot_state`: after a chunked prompt and six tokens the
+    slot's recurrent state is the reference recurrence's over the prompt
+    and the first five, and a model without per-slot state has none."""
+    from ray_tpu.serve.llm.engine import Request
+
+    R, mc, weights, params = model
+    engine = _engine(mc, params, num_slots=1)
+    p = _tokens(37, seed=9)
+    h = engine.submit(Request(prompt=p, max_tokens=6, chunked_prefill=True))
+    while engine.has_work():
+        engine.step()
+    got = engine.slot_state(0)
+    assert got["S"].shape == (4, 4, 16, 16) and got["S"].dtype == np.float32
+    assert got["conv"].shape == (4, 3, 3 * 64)
+    want = R.kda_states(weights, C, p + h.tokens[:-1])
+    # states of 8e-3 at these sizes; float32 sums in another order read
+    # 1e-6 of that, a state kept in bf16 between tokens 4e-3 of it
+    assert np.abs(want).max() > 1e-3
+    assert np.abs(got["S"] - want).max() < 1e-4 * np.abs(want).max()
+
+    from ray_tpu.models.latent_moe import LatentMoEConfig, init_params
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+    lc = LatentMoEConfig.tiny()
+    plain = LLMEngine(init_params(lc, jax.random.key(0)), lc, EngineConfig(
+        num_slots=1, max_seq_len=64, prefill_buckets=(8,), kv_block_size=BS))
+    assert plain.slot_state(0) is None
+
+
+def test_cancel_between_chunks_frees_the_slot(model):
+    from ray_tpu.serve.llm.engine import Request
+
+    _, mc, _, params = model
+    engine = _engine(mc, params)
+    h = engine.submit(Request(prompt=_tokens(45), max_tokens=4,
+                              chunked_prefill=True))
+    engine.step()                       # first chunk: slot and blocks taken
+    assert len(engine._chunking) == 1 and not engine._active.any()
+    assert engine.stats()["kv"]["used_blocks"] > 0
+    assert h.cancel()
+    engine.drain()
+    assert h.finish_reason == "cancelled"
+    assert not engine._chunking and len(engine._free) == 2
+    assert engine.stats()["kv"]["used_blocks"] == 0
+    # and the slot serves the next request from a zero state
+    p = _tokens(20, seed=9)
+    h2 = engine.submit(Request(prompt=p, max_tokens=3, chunked_prefill=True))
+    engine.drain()
+    want = engine_free = _engine(mc, params)
+    h3 = want.submit(Request(prompt=p, max_tokens=3, chunked_prefill=True))
+    engine_free.drain()
+    assert h2.tokens == h3.tokens and len(h2.tokens) == 3
+
+
+@pytest.mark.parametrize("what", ["prefix_cache", "export_prefix",
+                                  "adopt", "prefill_only", "preempt",
+                                  "speculative_verify"])
+def test_engine_refuses_by_name_what_would_lose_the_state(model, what):
+    """Whatever moves rows without the recurrent state is refused, and
+    the refusal names the model."""
+    from ray_tpu.serve.llm.engine import (EngineConfig, LLMEngine, Request)
+    from ray_tpu.serve.llm.kv_cache import KVState
+
+    _, mc, _, params = model
+    name = "models/kimi_linear.py"
+    with pytest.raises(ValueError, match=name):
+        if what == "prefix_cache":
+            _engine(mc, params, prefix_cache=True)
+        elif what == "speculative_verify":
+            LLMEngine(params, mc, EngineConfig(
+                num_slots=2, max_seq_len=64, prefill_buckets=(8,),
+                kv_block_size=BS, prefix_cache=False),
+                draft_params=params, draft_config=mc)
+        else:
+            engine = _engine(mc, params)
+            if what == "export_prefix":
+                engine.export_prefix(_tokens(8))
+            elif what == "prefill_only":
+                engine.submit(Request(prompt=_tokens(5), max_tokens=2,
+                                      prefill_only=True))
+            elif what == "preempt":
+                engine.submit(Request(prompt=_tokens(5), max_tokens=4))
+                engine.step()
+                engine.preempt(0)
+            else:
+                engine.submit_adopted(
+                    Request(prompt=[1, 2], max_tokens=4),
+                    KVState(prompt=[1, 2], tokens=[3], next_tok=3, pos=2,
+                            temperature=0.0, block_size=BS, blocks={}))

@@ -209,8 +209,8 @@ def test_lower_precision_is_caught(model, what, monkeypatch):
         real = latent_moe.dropless_moe
         monkeypatch.setattr(
             latent_moe, "dropless_moe",
-            lambda x, p, routing, live=None: real(
-                x, p, lambda lg, pp: routing(bf16(lg), pp), live))
+            lambda x, p, routing, **kw: real(
+                x, p, lambda lg, pp: routing(bf16(lg), pp), **kw))
     else:
         real = latent_moe._masked_softmax
         monkeypatch.setattr(
