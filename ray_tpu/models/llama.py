@@ -507,12 +507,25 @@ class _Paged:
     layers, ONE buffer each from the tick's donated argument to its
     result: layer `l` writes each row in place at (l, table[pos // bs],
     pos % bs) -- a physical block id out of bounds, so dropped, for an
-    inactive sequence -- and then gathers every sequence's dense [S_pad]
-    view of its own layer, `pool[l, tables]` (AFTER the writes, so a
-    query sees its own row and those before it), and reads it as it
-    lies.  Keeps the updated pools."""
+    inactive sequence -- and then attends (AFTER the writes, so a query
+    sees its own row and those before it) by one of two paths, chosen
+    by backend and shape alone (`ops.paged_attention.engages`):
+
+    - the kernel (a TPU, shapes that tile): `ops.paged_attention` reads
+      the live blocks of the live sequences out of the whole pool
+      through the block table, at (l, table[b, j]); nothing is
+      gathered, a dead slot and a row past a sequence's length cost
+      nothing.  Its scalars (`plan`) are made here, once a program.
+    - the gather (everywhere else, and the reference the kernel is
+      tested against): every sequence's dense [S_pad] view of its own
+      layer, `pool[l, tables]`, read as it lies by `_decode_attention`
+      under the position mask.
+
+    Keeps the updated pools."""
 
     def __init__(self, pools, block_tables, qpos, active):
+        from ray_tpu.ops import paged_attention
+
         self.stacks = (pools["k"], pools["v"])
         L, NB, bs = pools["k"].shape[:3]
         self.leaves = jnp.arange(L)
@@ -523,17 +536,28 @@ class _Paged:
         if active is not None:
             phys = jnp.where(active.reshape(seq.shape), phys, NB)
         self.phys, self.off = phys, qpos % bs
+        self.plan = None
+        if paged_attention.engages(pools["k"]):
+            with jax.named_scope("attn"), jax.named_scope("paged"):
+                self.plan = paged_attention.plan(
+                    block_tables, qpos, active, bs)
 
     def attend(self, c, q, k, v, stacks, l):
         k_pool, v_pool = stacks
         B, nb = self.tables.shape
-        dense = (B, nb * k_pool.shape[2], c.n_kv_heads, c.head_dim)
         new = self.phys.shape + k.shape[2:]
         with jax.named_scope("kv_write"):
             k_pool = k_pool.at[l, self.phys, self.off].set(
                 k.reshape(new).astype(k_pool.dtype))
             v_pool = v_pool.at[l, self.phys, self.off].set(
                 v.reshape(new).astype(v_pool.dtype))
+        if self.plan is not None:
+            from ray_tpu.ops.paged_attention import paged_attention
+
+            with jax.named_scope("attn"), jax.named_scope("paged"):
+                attn = paged_attention(q, k_pool, v_pool, l, self.plan)
+            return attn, (k_pool, v_pool), ()
+        dense = (B, nb * k_pool.shape[2], c.n_kv_heads, c.head_dim)
         with jax.named_scope("kv_gather"):
             k_dense = k_pool[l, self.tables].reshape(dense)
             v_dense = v_pool[l, self.tables].reshape(dense)
@@ -732,16 +756,18 @@ def verify_kv_paged(params: Dict[str, Any], pools: Dict[str, jax.Array],
     Row j's logits are the target model's distribution for the token
     FOLLOWING input j — exactly what K single-token steps would produce
     after consuming inputs 0..j one at a time, because every op here is
-    row-independent (per-position matmuls, and `_decode_attention` with
-    K queries a row instead of one): running K queries through one
+    row-independent (per-position matmuls, and decode attention with K
+    queries a row instead of one): running K queries through one
     program instead of K programs changes batching, not values. The
     engine exploits this for draft verification: accept the longest
     prefix where the target's argmax agrees with the draft, and greedy
     parity holds by construction.
 
-    All K KV writes scatter before the dense gather (`_Paged`), so input
-    j attends to inputs i < j (their positions pass the ``key_pos <=
-    pos + j`` mask) and never to inputs i > j. Rejected inputs leave
+    All K KV writes scatter before the layer attends (`_Paged`: the
+    paged-attention kernel over the live blocks on a TPU, the dense
+    gather + `_decode_attention` elsewhere; the same mask in both), so
+    input j attends to inputs i < j (their positions pass the ``key_pos
+    <= pos + j`` mask) and never to inputs i > j. Rejected inputs leave
     stale rows past the accepted position — the same stale-rows-
     overwritten-before-attended invariant every other path in this file
     relies on. ``active`` masks writes by pushing the physical block id
@@ -769,12 +795,18 @@ def decode_step_paged(params: Dict[str, Any], pools: Dict[str, jax.Array],
     """One incremental token against the paged pool: tokens [B] at
     `positions` [B]. Returns (logits [B, V], updated pools).
 
-    Token-exact with `decode_step` on a dense cache holding the same
-    logical contents: the gather assembles each sequence's dense
-    [S_pad] view (S_pad = max_blocks * block_size), the write lands at
-    (table[pos // bs], pos % bs), and the same `_decode_attention` reads
-    that view as it lies ([B, S_pad, kvH, D], no GQA repeat) and drops
-    padding/stale rows to exact zeros.
+    The write lands at (table[pos // bs], pos % bs); then `_Paged`
+    attends by one of two paths.  On a TPU, at shapes that tile, the
+    `ops.paged_attention` kernel reads each live sequence's live blocks
+    out of the stacked pool through its table row (float32 scores and
+    softmax; a dead slot and rows past a sequence's length are never
+    read).  Everywhere else the gather assembles each sequence's dense
+    [S_pad] view (S_pad = max_blocks * block_size) and the same
+    `_decode_attention` as `decode_step`'s reads it as it lies ([B,
+    S_pad, kvH, D], no GQA repeat), dropping padding/stale rows to
+    exact zeros: token-exact with `decode_step` on a dense cache
+    holding the same logical contents, and the reference the kernel is
+    tested against.
     """
     c = config
     S_pad = block_tables.shape[1] * pools["k"].shape[2]
@@ -894,10 +926,16 @@ def _serve_decode(params, pools, tables, tok, pos, config, active):
     return logits, pools, {}
 
 
+def _serve_paged_attention(pools):
+    from ray_tpu.ops import paged_attention
+
+    return "kernel" if paged_attention.engages(pools["k"]) else "gather"
+
+
 _SERVING = ServingFns(
     name="dense decoder (models/llama.py)",
     init_params=init_params, init_pool=init_paged_kv_cache,
     prefill=_serve_prefill, decode=_serve_decode,
     head_weight=lm_head_weight, quantize_int8=quantize_weights_int8,
     draft=DraftFns(init_kv_cache, prefill_kv, decode_step),
-    verify=verify_kv_paged)
+    verify=verify_kv_paged, paged_attention=_serve_paged_attention)

@@ -40,7 +40,9 @@ export, adopt, spill, promote) and never look inside a row.
     quantize_int8(params); verify(params, pools, tables, tok [B, K],
     pos [B], config, active) -> (logits [B, K, V], pools), the
     speculative target's step; draft, what a model needs to BE a
-    speculative draft (`DraftFns`).
+    speculative draft (`DraftFns`); paged_attention(pools) -> "kernel"
+    | "gather", which path `decode` compiles its attention to over
+    these pools on this backend (`engine.stats()` shows it).
 """
 
 from __future__ import annotations
@@ -72,3 +74,4 @@ class ServingFns(NamedTuple):
     quantize_int8: Optional[Callable[..., Any]] = None
     draft: Optional[DraftFns] = None
     verify: Optional[Callable[..., Any]] = None
+    paged_attention: Optional[Callable[..., str]] = None

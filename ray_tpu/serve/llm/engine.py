@@ -451,6 +451,8 @@ class LLMEngine:
         self._work = threading.Event()
         self._ids = itertools.count()
         self._completed = 0
+        self._live_rows_sum = 0         # over all ticks: `_live_rows`
+        self._padded_rows_sum = 0
         self._slot_reuses = 0
         self._cancelled: set = set()    # request ids, guarded by _lock
         self._admit_blocked = False     # interactive admission starved
@@ -1976,7 +1978,9 @@ class LLMEngine:
                 self._update_gauges()
             return bool(inserted) or did_cancel or did_ctrl
         live = np.nonzero(self._active)[0]
-        with trace_span("llm_engine.tick_dispatch", live=len(live)):
+        rows = self._live_rows(live)
+        with trace_span("llm_engine.tick_dispatch", live=len(live),
+                        rows=rows):
             spec = self._spec_ready(live)
             t_tick = time.monotonic()
             if spec:
@@ -2017,6 +2021,21 @@ class LLMEngine:
         with trace_span("llm_engine.gauges"):
             self._update_gauges()
         return True
+
+    def _live_rows(self, live) -> int:
+        """KV rows the tick about to go out has to read: the live slots'
+        prompt and emitted tokens, summed (the pending token's own row
+        among them), and counted beside the rows of the padded
+        [num_slots, max_seq_len] view over all ticks (`stats()`)."""
+        S = self.config.max_seq_len
+        rows = 0
+        for slot in live:
+            h = self._slots[int(slot)].handle
+            if h is not None:
+                rows += min(len(h.request.prompt) + len(h.tokens), S)
+        self._live_rows_sum += rows
+        self._padded_rows_sum += self.config.num_slots * S
+        return rows
 
     def _credit_decode(self, live, dt: float) -> None:
         """Split one decode/verify tick's wall time evenly across the
@@ -2223,6 +2242,16 @@ class LLMEngine:
             "slot_reuses": self._slot_reuses,
             "preempted": self._preempted,
             "kv_layout": self.config.kv_layout,
+            # which path the tick's attention compiled to: the model
+            # says (by backend and shape alone); "gather" for a model
+            # that has only that one
+            "paged_attention": (
+                self._model.paged_attention(self._cache)
+                if self._model.paged_attention else "gather"),
+            # what the ticks had to read against what the padded
+            # [num_slots, max_seq_len] view holds
+            "live_rows": self._live_rows_sum,
+            "padded_rows": self._padded_rows_sum,
             "traces": traces,
             "trace_count": sum(traces.values()),
             "kv": dict(self._allocator.stats(),

@@ -69,7 +69,9 @@ def ring_mesh(topo):
 @pytest.fixture
 def on_tpu(monkeypatch):
     """`ops.attention` picks kernel-vs-XLA and compiled-vs-interpret from
-    the default backend, which is the CPU here: answer for the chip."""
+    the default backend, which is the CPU here: answer for the chip.
+    `ops.paged_attention` (the decode tick's kernel) asks the same
+    function, so the tick tests below compile what the CHIP runs."""
     from ray_tpu.ops import attention
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
@@ -239,27 +241,30 @@ def _compiled_paged_tick(config, engine, one_chip):
     return compiled, params, pools
 
 
-def test_paged_decode_program_fits_one_v5e(one_chip):
+def test_paged_decode_program_fits_one_v5e(one_chip, on_tpu):
     """The engine's decode program at chip_smoke.py's serve widths, depth
-    and pool: compiles, and weights + pool + the program's own temporaries
-    (one layer's gathered K/V view: the donated pool is carried through
-    the layer scan and written in place) fit HBM."""
+    and pool: compiles with the paged-attention kernel in it, and
+    weights + pool + the program's own temporaries (next to nothing:
+    the donated pool is carried through the layer scan, written in
+    place and read by the kernel where it lies) fit HBM."""
     import chip_smoke
 
     compiled, _, _ = _compiled_paged_tick(
         _smoke_config("serve"), chip_smoke.chip_spec(0)["serve"]["engine"],
         one_chip)
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
     assert _hbm_gib(compiled) < V5E_HBM_GIB - 0.5
 
 
-def test_benchmark_decode_tick_builds_no_repeated_kv(one_chip):
+def test_benchmark_decode_tick_builds_no_repeated_kv(one_chip, on_tpu):
     """The tick of the benchmark's `chat-decode` cell (Mistral-7B-v0.3
     widths, 20 layers of bf16 weights, 32 slots x 2048, 1800 blocks of
-    16): grouped-query attention reads the gathered K/V rows as they are,
-    so nothing the size of their `n_heads / n_kv_heads`-fold repeat
-    exists in the compiled program, and its temporaries show it (4.5-4.7
-    GiB with the repeat, 2.67 without it beside a second pool, 0.13 since
-    the pool rides in the layer scan's carry: the test below)."""
+    16): the paged-attention kernel reads the live K/V blocks out of
+    the pool through the block table, so the compiled program holds the
+    kernel and NO dense view of the padded rows -- neither the gathered
+    `[32,2048,8,128]` / `[4096,16,8,128]` (0.13 GiB of temporaries until
+    PR 31) nor anything the size of their `n_heads / n_kv_heads`-fold
+    repeat (4.5-4.7 GiB until PR 25) -- and its temporaries show it."""
     import math
     import re
 
@@ -274,15 +279,18 @@ def test_benchmark_decode_tick_builds_no_repeated_kv(one_chip):
         config, dict(num_slots=B, max_seq_len=S_pad, kv_block_size=16,
                      num_kv_blocks=1800), one_chip)
     text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
     held = {x.shape for x in jax.tree.leaves((params, pools))}
-    repeated = B * S_pad * config.n_heads * config.head_dim
+    L, NB, bs, kvh, D = pools["k"].shape
+    held.add((L, NB, bs * kvh, D))      # the kernel's view: a bitcast
+    gathered = B * S_pad * config.n_kv_heads * config.head_dim
     shapes = {tuple(int(d) for d in dims.split(","))
               for dims in re.findall(r"\b[a-z]+\d+\[([\d,]+)\]", text)}
-    assert (B, S_pad, config.n_kv_heads, config.head_dim) in shapes  # parsed
-    # in particular no [32,2048,8,4,128] and no [32,2048,32,128]
+    assert pools["k"].shape in shapes                       # parsed
+    # in particular no [32,2048,8,128] and no [4096,16,8,128]
     assert not {s for s in shapes
-                if math.prod(s) >= repeated and s not in held}
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * GIB
+                if math.prod(s) >= gathered and s not in held}
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.01 * GIB
 
 
 def _serving_cell(cell, one_chip):
@@ -363,7 +371,32 @@ def _results(text):
     return out
 
 
-def test_benchmark_decode_tick_keeps_one_kv_pool(one_chip):
+def _compiled_cell_tick(eng, one_chip):
+    """`LLMEngine._tick_fn` of a serving cell (`_serving_cell`), with
+    the model's counters and per-slot state where it has them, donated
+    as `_jit_tick` donates."""
+    from ray_tpu.serve.llm.engine import LLMEngine
+
+    ec, mc, model = eng.config, eng.model_config, eng._model
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    B = ec.num_slots
+    extra = [_placed(jax.eval_shape(init), one_chip) for init in (
+        model.init_counts and (lambda: model.init_counts(mc)),
+        model.init_slot_state and (lambda: model.init_slot_state(mc, B)))
+        if init]
+    return jax.jit(
+        functools.partial(LLMEngine._tick_fn, eng),
+        donate_argnums=(1, 3, 4) + ((9,) if model.init_slot_state else ())
+    ).lower(
+        eng.params, eng.pools, arg(jnp.int32, B, ec.max_blocks_per_slot),
+        arg(jnp.int32, B), arg(jnp.int32, B), arg(jnp.bool_, B),
+        arg(jnp.float32, B), eng.key, *extra).compile()
+
+
+def test_benchmark_decode_tick_keeps_one_kv_pool(one_chip, on_tpu):
     """`LLMEngine._tick_fn` at the `chat-decode` cell's geometry, pools,
     tokens and positions donated as `_jit_tick` donates them: the stacked
     pools ride in the layer scan's carry and each layer writes its rows
@@ -373,37 +406,58 @@ def test_benchmark_decode_tick_keeps_one_kv_pool(one_chip):
     `xs` and stacked out as `ys` the program had 2 `copy` and 2
     `dynamic-update-slice` of the first shape, a `copy-done` and the
     scan's slices of the second, and 2.56 GiB of temporaries: PERF.md
-    F3); what is left is one layer's gathered view, 0.13 GiB."""
-    from ray_tpu.serve.llm.engine import LLMEngine
-
+    F3).  The paged-attention kernel takes the whole stacked pools as
+    they lie (its flat `[20,1800,128,128]` view is a bitcast) and the
+    layer index as a scalar: its operand costs no copy either, and with
+    the gathered view gone (0.126 GiB until PR 31) the temporaries are
+    under a megabyte."""
     eng = _serving_cell("chat-decode", one_chip)
-    ec = eng.config
-
-    def arg(dtype, *shape):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    B = ec.num_slots
-    compiled = jax.jit(
-        functools.partial(LLMEngine._tick_fn, eng),
-        donate_argnums=(1, 3, 4)).lower(
-        eng.params, eng.pools, arg(jnp.int32, B, ec.max_blocks_per_slot),
-        arg(jnp.int32, B), arg(jnp.int32, B), arg(jnp.bool_, B),
-        arg(jnp.float32, B), eng.key).compile()
+    compiled = _compiled_cell_tick(eng, one_chip)
     pool = eng.pools["k"].shape
     assert pool == (20, 1800, 16, 8, 128) == eng.pools["v"].shape
-    results = _results(compiled.as_text())
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text   # the kernel
+    results = _results(text)
     made = {op for op, shapes in results if pool in shapes}
     assert "scatter" in made and "parameter" in made      # parsed
+    flat = pool[:2] + (pool[2] * pool[3], pool[4])  # the kernel's view
+    assert "bitcast" in {op for op, shapes in results if flat in shapes}
     moved = [(op, shapes) for op, shapes in results
              if op in ("copy", "copy-start", "copy-done", "dynamic-slice",
                        "dynamic-update-slice")
-             and shapes & {pool, pool[1:]}]
+             and shapes & {pool, pool[1:], flat, flat[1:]}]
     assert not moved, moved
     m = compiled.memory_analysis()
     pool_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
                      for x in eng.pools.values())
     assert m.alias_size_in_bytes >= pool_bytes            # in place
-    assert m.temp_size_in_bytes < 0.5 * GIB
+    assert m.temp_size_in_bytes < 0.13 * GIB
+
+
+@pytest.mark.parametrize("program", [
+    "assistant-decode-moe tick", "agent-decode-hybrid tick",
+    "chat-decode insert"])
+def test_paged_attention_leaves_the_other_programs_as_they_were(
+        one_chip, on_tpu, monkeypatch, program):
+    """The kernel is `_Paged.attend`'s alone.  The latent models' ticks
+    (their own `_PagedDecode`) and the dense model's insert (`_History`)
+    compile for v5e to the same text, metadata apart, whether the
+    selector answers as on the chip or is taken away: the text the
+    parent compiled (PERF.md section 6, PR 31, has that comparison)."""
+    from ray_tpu.ops import paged_attention
+
+    cell, kind = program.split()
+
+    def compiled():
+        eng = _serving_cell(cell, one_chip)
+        return _without_metadata((
+            _compiled_cell_tick if kind == "tick" else _compiled_insert)(
+                eng, one_chip).as_text())
+
+    with_kernel = compiled()
+    monkeypatch.setattr(paged_attention, "engages", lambda pool: False)
+    assert compiled() == with_kernel
+    assert "paged_attention" not in with_kernel
 
 
 @pytest.mark.parametrize("cell, rows", [
@@ -443,28 +497,14 @@ def test_latent_moe_cell_programs_fit_one_v5e(one_chip, program):
     calls, the pool is updated in place (unrolled layers: no second
     pool), and arguments + temporaries fit HBM.  These readings sized
     the configuration's depth and the cell's pool."""
-    from ray_tpu.serve.llm.engine import LLMEngine
-
     eng = _serving_cell("assistant-decode-moe", one_chip)
-    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
-                                eng.published)
-    params, pools, key = eng.params, eng.pools, eng.key
+    mc, published, pools = eng.model_config, eng.published, eng.pools
     assert (published["num_hidden_layers"], published["hidden_size"],
             published["n_routed_experts"], published["vocab_size"]) \
         == (8, 2048, 128, 128256)
 
-    def arg(dtype, *shape):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    B, nb = ec.num_slots, ec.max_blocks_per_slot
     if program == "tick":
-        compiled = jax.jit(
-            functools.partial(LLMEngine._tick_fn, eng),
-            donate_argnums=(1, 3, 4)).lower(
-            params, pools, arg(jnp.int32, B, nb), arg(jnp.int32, B),
-            arg(jnp.int32, B), arg(jnp.bool_, B), arg(jnp.float32, B), key,
-            _placed(jax.eval_shape(lambda: model.init_counts(mc)),
-                    one_chip)).compile()
+        compiled = _compiled_cell_tick(eng, one_chip)
         n_moe = mc.n_layers - mc.n_dense_layers
         assert compiled.as_text().count(
             'custom_call_target="tpu_custom_call"') >= 3 * n_moe
@@ -508,13 +548,7 @@ def test_hybrid_cell_programs_fit_one_v5e(one_chip, program):
 
     B, nb = ec.num_slots, ec.max_blocks_per_slot
     if program == "tick":
-        compiled = jax.jit(
-            functools.partial(LLMEngine._tick_fn, eng),
-            donate_argnums=(1, 3, 4, 9)).lower(
-            params, pools, arg(jnp.int32, B, nb), arg(jnp.int32, B),
-            arg(jnp.int32, B), arg(jnp.bool_, B), arg(jnp.float32, B), key,
-            _placed(jax.eval_shape(lambda: model.init_counts(mc)),
-                    one_chip), state).compile()
+        compiled = _compiled_cell_tick(eng, one_chip)
         assert compiled.as_text().count(
             'custom_call_target="tpu_custom_call"') >= 3 * mc.n_moe_layers
     else:

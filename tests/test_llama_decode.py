@@ -180,6 +180,49 @@ def test_paged_decode_matches_dense():
         toks = jnp.argmax(dl, -1).astype(jnp.int32)
 
 
+def test_paged_decode_through_the_kernel_matches_dense(monkeypatch):
+    """The same stream with heads of 128 (shapes that tile) and the
+    Pallas interpreter forced: `decode_step_paged` attends through
+    `ops.paged_attention` -- live blocks only, one slot further along
+    than the other, the last steps crossing into a second block -- and
+    still reproduces `decode_step` on a dense cache."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import (
+        LlamaConfig, decode_step, decode_step_paged, init_kv_cache,
+        init_paged_kv_cache, init_params,
+    )
+    from ray_tpu.ops import attention, paged_attention
+
+    monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", True)
+    config = LlamaConfig.tiny(dim=256, n_heads=2, n_kv_heads=1)
+    params = init_params(config, jax.random.key(0))
+    B, bs = 2, 16                            # S_pad = 32
+    cache = init_kv_cache(config, B, max_len=32)
+    pools = init_paged_kv_cache(config, num_blocks=6, block_size=bs)
+    assert paged_attention.engages(pools["k"])
+    tables = jnp.asarray([[3, 1], [0, 5]], jnp.int32)
+
+    dense_step = jax.jit(
+        lambda c, t, p: decode_step(params, c, t, p, config))
+    paged_step = jax.jit(
+        lambda pl, t, p: decode_step_paged(params, pl, tables, t, p,
+                                           config))
+    rng = np.random.RandomState(3)
+    toks = jnp.asarray(rng.randint(0, config.vocab_size, (B,)), jnp.int32)
+    for i in range(10):
+        pos = jnp.asarray([i, i + 9], jnp.int32)
+        dl, cache = dense_step(cache, toks, pos)
+        pl_, pools = paged_step(pools, toks, pos)
+        np.testing.assert_array_equal(
+            np.argmax(np.asarray(dl), -1),
+            np.argmax(np.asarray(pl_), -1))
+        np.testing.assert_allclose(np.asarray(dl), np.asarray(pl_),
+                                   rtol=2e-2, atol=2e-2)
+        toks = jnp.argmax(dl, -1).astype(jnp.int32)
+
+
 # (n_heads, n_kv_heads): MHA, the tiny config's rep 2, the benchmark's
 # rep 4, and MQA
 GQA_SHAPES = {"mha": (4, 4), "rep2": (4, 2), "rep4": (8, 2), "mqa": (4, 1)}

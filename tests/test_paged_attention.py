@@ -1,0 +1,264 @@
+"""`ops.paged_attention` (the decode tick's kernel) against the path it
+replaces: the block-table gather + `models.llama._decode_attention`.
+
+CPU, the kernel through the Pallas interpreter.  Tolerance: operands
+are bf16, the kernel keeps scores, softmax and accumulation in float32
+and rounds once, to bf16, at the end; the gather path rounds the scores
+to bf16 BEFORE the softmax and the probabilities after it.  On
+unit-normal queries, keys and values the two differ by at most 2 ulp of
+a bf16 output of size 1 (2 ** -6 = 0.0156); each is within 0.012 of the
+same attention computed in float32 throughout, the kernel the closer.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.llama import (
+    LlamaConfig, _decode_attention, decode_step_paged, init_paged_kv_cache,
+    init_params, verify_kv_paged,
+)
+from ray_tpu.ops import attention, paged_attention as pa
+
+ATOL = 2 ** -6          # 2 ulp of a bf16 value in [1, 2)
+D, BS, NB_ROW, L, LAYER, CHUNK = 128, 16, 8, 3, 2, 3
+# dead, one row, a whole block, a block and a row, a full table row
+LENGTHS = (0, 1, 16, 17, NB_ROW * BS)
+GROUPS = {"rep4": (8, 2), "rep1": (4, 4), "mqa": (4, 1)}
+
+
+def _case(heads, kv_heads, n_q, seed):
+    """A pool, queries, a table of distinct blocks a sequence, and the
+    queries' positions: the last query of a live sequence sits on its
+    last row."""
+    rng = np.random.default_rng(seed)
+    B, NB = len(LENGTHS), len(LENGTHS) * NB_ROW + 4
+    pool = lambda: jnp.asarray(                               # noqa: E731
+        rng.standard_normal((L, NB, BS, kv_heads, D)), jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((B, n_q, heads, D)), jnp.bfloat16)
+    tables = rng.permutation(NB - 4)[:B * NB_ROW].reshape(
+        B, NB_ROW).astype(np.int32)
+    lengths = np.maximum(np.asarray(LENGTHS), (np.asarray(LENGTHS) > 0)
+                         * n_q)         # n_q queries need n_q rows
+    last = np.maximum(lengths - 1, 0)
+    qpos = np.maximum(last[:, None] - (n_q - 1) + np.arange(n_q)[None],
+                      0).astype(np.int32)
+    return q, pool(), pool(), tables, qpos, lengths
+
+
+def _reference(q, k_pool, v_pool, tables, qpos, dtype=None):
+    B, nb = tables.shape
+    kvh = k_pool.shape[3]
+    k = k_pool[LAYER][tables].reshape(B, nb * BS, kvh, D)
+    v = v_pool[LAYER][tables].reshape(B, nb * BS, kvh, D)
+    if dtype is not None:
+        q, k, v = q.astype(dtype), k.astype(dtype), v.astype(dtype)
+    return np.asarray(_decode_attention(q, k, v, jnp.asarray(qpos)),
+                      np.float32)
+
+
+def _kernel(q, k_pool, v_pool, tables, qpos, active, chunk=CHUNK):
+    scalars = pa.plan(jnp.asarray(tables), jnp.asarray(qpos),
+                      None if active is None else jnp.asarray(active),
+                      BS, chunk)
+    return np.asarray(pa.paged_attention(
+        q, k_pool, v_pool, jnp.int32(LAYER), scalars, chunk=chunk),
+        np.float32)
+
+
+def _spoil(tables, lengths, how, nb_total):
+    """Every table entry the kernel has no business with: entries past
+    a sequence's last live block, and every entry of a dead row."""
+    dirty = tables.copy()
+    for b, n in enumerate(lengths):
+        live = -(-int(n) // BS)
+        if how == "out_of_range":
+            dirty[b, live:] = [nb_total + 7, 2 ** 30, -1][b % 3]
+        elif how == "other_sequences":
+            dirty[b, live:] = tables[(b + 1) % len(lengths), 0]
+        elif how == "poisoned":
+            dirty[b, live:] = nb_total - 1      # a block of NaN
+    return dirty
+
+
+@pytest.mark.parametrize("n_q", [1, 3])
+@pytest.mark.parametrize("group", sorted(GROUPS))
+def test_kernel_matches_the_gather_path(group, n_q):
+    """Every length class at once (dead, 1, 16, 17, a full row), a layer
+    index that is not 0, chunks of 3 blocks so that a sequence ends
+    inside a chunk, at a chunk's end and after several."""
+    q, kp, vp, tables, qpos, lengths = _case(*GROUPS[group], n_q, seed=n_q)
+    active = lengths > 0
+    got = _kernel(q, kp, vp, tables, qpos, active)
+    want = _reference(q, kp, vp, tables, qpos)
+    exact = _reference(q, kp, vp, tables, qpos, jnp.float32)
+    assert not got[~active].any()               # a dead slot: zeros
+    assert np.abs(got[active] - want[active]).max() <= ATOL
+    # no further from float32 throughout than the path it replaces
+    assert (np.abs(got[active] - exact[active]).max()
+            <= np.abs(want[active] - exact[active]).max() + 2 ** -8)
+
+
+@pytest.mark.parametrize("how", ["out_of_range", "other_sequences",
+                                 "poisoned"])
+@pytest.mark.parametrize("n_q", [1, 3])
+def test_entries_past_a_length_change_nothing(n_q, how):
+    """`engine.py` never clears a freed slot's table row, so entries
+    past a length and in dead rows hold stale and out-of-range ids: the
+    output is the clean table's to the bit, also when they name a block
+    of NaN (no such row reaches a sum, masked or not)."""
+    q, kp, vp, tables, qpos, lengths = _case(8, 2, n_q, seed=7)
+    nb_total = kp.shape[1]
+    kp, vp = (x.at[:, nb_total - 1].set(jnp.nan) for x in (kp, vp))
+    active = lengths > 0
+    clean = _kernel(q, kp, vp, tables, qpos, active)
+    dirty = _kernel(q, kp, vp, _spoil(tables, lengths, how, nb_total),
+                    qpos, active)
+    assert np.isfinite(dirty).all()
+    np.testing.assert_array_equal(dirty, clean)
+
+
+@pytest.mark.parametrize("n_q", [1, 3])
+def test_only_live_blocks_are_copied(monkeypatch, n_q):
+    """Every copy the kernel starts, recorded: exactly the live blocks
+    of the live sequences at the layer asked for, K and V once each,
+    and no table entry beyond them is ever dereferenced."""
+    q, kp, vp, tables, qpos, lengths = _case(8, 2, n_q, seed=11)
+    dirty = _spoil(tables, lengths, "out_of_range", kp.shape[1])
+    seen = []
+    block_copy = pa._block_copy
+
+    def recording(pool, layer, phys, *rest):
+        if not isinstance(phys, int):           # a start, not a wait
+            jax.debug.callback(
+                lambda l, p: seen.append((int(l), int(p))), layer, phys)
+        return block_copy(pool, layer, phys, *rest)
+
+    monkeypatch.setattr(pa, "_block_copy", recording)
+    _kernel(q, kp, vp, dirty, qpos, lengths > 0)
+    jax.effects_barrier()
+    want = sorted((LAYER, int(tables[b, j]))
+                  for b, n in enumerate(lengths)
+                  for j in range(-(-int(n) // BS))) * 2
+    assert sorted(seen) == sorted(want)
+
+
+def test_all_live_without_a_mask_and_one_chunk_a_row():
+    """`active=None` is every slot live; a chunk as long as the table
+    row is one item a sequence."""
+    q, kp, vp, tables, qpos, lengths = _case(8, 2, 1, seed=3)
+    lengths = np.where(lengths > 0, lengths, 5)
+    qpos = (lengths - 1)[:, None].astype(np.int32)
+    got = _kernel(q, kp, vp, tables, qpos, None, chunk=64)
+    assert np.abs(got - _reference(q, kp, vp, tables, qpos)).max() <= ATOL
+
+
+# ------------------------------------------------- through the model's steps
+
+def _small(**kw):
+    return LlamaConfig.tiny(
+        vocab_size=128, dim=1024, n_layers=2, n_heads=8, n_kv_heads=8,
+        hidden_dim=256, max_seq_len=128, param_dtype=jnp.bfloat16, **kw)
+
+
+@pytest.fixture
+def forced(monkeypatch):
+    """The test hook `ops.attention` has: run the kernels through the
+    interpreter off TPU."""
+    monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", True)
+
+
+def test_the_selector_is_backend_and_shape_alone(monkeypatch):
+    pool = lambda kvh, d, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        (2, 8, 16, kvh, d), dt)
+    assert not pa.engages(pool(8, 128))             # the CPU, not forced
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    assert pa.engages(pool(8, 128)) and pa.engages(pool(32, 256))
+    assert pa.engages(pool(1, 128))                 # MQA: 16 rows a block
+    assert not pa.engages(pool(8, 16))              # the rehearsal's heads
+    assert not pa.engages(pool(8, 128, jnp.float32))
+    assert not pa.engages(jax.ShapeDtypeStruct(     # 8 rows: half a tile
+        (2, 8, 4, 2, 128), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("step", ["decode", "verify"])
+def test_model_steps_agree_on_both_paths(forced, monkeypatch, step):
+    """`decode_step_paged` and `verify_kv_paged` (K = 3) over a pool
+    with history: the kernel's logits against the gather path's, the
+    same pools written, the same greedy tokens."""
+    c = _small()
+    params = init_params(c, jax.random.key(0))
+    rng = np.random.default_rng(5)
+    B, nb, NB = 3, c.max_seq_len // BS, 30
+    pools = jax.tree.map(
+        lambda x: jnp.asarray(rng.standard_normal(x.shape) * 0.5, x.dtype),
+        init_paged_kv_cache(c, NB, BS))
+    tables = jnp.asarray(rng.permutation(NB)[:B * nb].reshape(B, nb),
+                         jnp.int32)
+    pos = jnp.asarray([37, 0, 90], jnp.int32)
+    active = jnp.asarray([True, False, True])
+    if step == "decode":
+        tok = jnp.asarray(rng.integers(0, c.vocab_size, B), jnp.int32)
+        fn = lambda: decode_step_paged(                       # noqa: E731
+            params, pools, tables, tok, pos, c, active)
+    else:
+        tok = jnp.asarray(rng.integers(0, c.vocab_size, (B, 3)), jnp.int32)
+        fn = lambda: verify_kv_paged(                         # noqa: E731
+            params, pools, tables, tok, pos, c, active)
+    assert pa.engages(pools["k"])
+    kernel_logits, kernel_pools = jax.jit(fn)()
+    monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", False)
+    assert not pa.engages(pools["k"])
+    gather_logits, gather_pools = jax.jit(fn)()
+    live = np.asarray(active)
+    a, b = (np.asarray(x, np.float32)[live]
+            for x in (kernel_logits, gather_logits))
+    assert np.abs(a - b).max() <= 0.05 * np.abs(b).max()
+    np.testing.assert_array_equal(a.argmax(-1), b.argmax(-1))
+    # layer 0 writes the same rows on both paths; deeper layers' rows
+    # carry the attention's rounding
+    for leaf in ("k", "v"):
+        np.testing.assert_array_equal(
+            np.asarray(kernel_pools[leaf][0], np.float32),
+            np.asarray(gather_pools[leaf][0], np.float32))
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_engine_serves_the_same_greedy_tokens_on_both_paths(
+        forced, monkeypatch, seed):
+    """A small `LLMEngine` (heads of 128, 8 KV heads: shapes that tile)
+    serves three prompts of different lengths beside each other; the
+    tokens through the kernel equal the gather path's, and `stats()`
+    names the path and counts the live rows."""
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine, Request
+
+    c = _small()
+    params = init_params(c, jax.random.key(seed))
+    rng = np.random.default_rng(seed)
+    prompts = [rng.integers(1, c.vocab_size, n).tolist() for n in (5, 19, 40)]
+
+    def serve():
+        eng = LLMEngine(params, c, EngineConfig(
+            num_slots=4, max_seq_len=128, prefill_buckets=(16, 32, 64),
+            kv_block_size=BS, prefix_cache=False))
+        handles = [eng.submit(Request(prompt=p, max_tokens=6))
+                   for p in prompts]
+        for _ in range(200):
+            if all(h.finished_at is not None for h in handles):
+                break
+            eng.step()
+        return [h.tokens for h in handles], eng.stats()
+
+    kernel_tokens, kernel_stats = serve()
+    monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", False)
+    gather_tokens, gather_stats = serve()
+    assert kernel_tokens == gather_tokens
+    assert all(len(t) == 6 for t in kernel_tokens)
+    assert kernel_stats["paged_attention"] == "kernel"
+    assert gather_stats["paged_attention"] == "gather"
+    for stats in (kernel_stats, gather_stats):
+        assert 0 < stats["live_rows"] < stats["padded_rows"]
+        assert stats["padded_rows"] % (4 * 128) == 0
+    assert kernel_stats["live_rows"] == gather_stats["live_rows"]

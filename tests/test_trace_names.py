@@ -145,6 +145,19 @@ def test_engine_step_spans_nest_and_cover(engine_traces, case):
         assert any(step[1] <= e[1] < ld[1] for e in spills)
 
 
+@pytest.mark.parametrize("case", ["roomy", "evicting"])
+def test_tick_dispatch_carries_its_live_rows(engine_traces, case):
+    """`rows=` beside `live=`: the KV rows the tick about to go out has
+    to read, here 16 prompt tokens + the 1..6 emitted of every live
+    slot (the yardstick of a reader for the paged-attention kernel)."""
+    ticks = [e[3] for e in engine_traces[case][0]
+             if e[0] == "llm_engine.tick_dispatch"]
+    assert ticks
+    for args in ticks:
+        live, rows = int(args["live"]), int(args["rows"])
+        assert 1 <= live <= 2 and 17 * live <= rows <= 22 * live
+
+
 @pytest.mark.parametrize("case, want", [
     ("landed", 3.0),            # landings of 2, 3 and 40 ms: the median
     ("no_admission", 0.0),      # a window of ticks alone still reports
