@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from ray_tpu.models import latent_moe as LM
 from ray_tpu.models.llama import embed_lookup, rms_norm
 from ray_tpu.models.serving import ServingFns
-from ray_tpu.ops import kda
+from ray_tpu.ops import kda, short_conv
 
 L2_EPS = 1e-6
 
@@ -202,7 +202,8 @@ class _Sequences:
         self.tails: List[jax.Array] = []
 
     def conv(self, j, x, w):
-        y, tail = kda.short_conv(x, w, self.inp["conv"][j], self.n_real)
+        y, tail = short_conv.short_conv(x, w, self.inp["conv"][j],
+                                        self.n_real)
         self.tails.append(tail.astype(self.inp["conv"].dtype))
         return y
 
@@ -231,7 +232,7 @@ class _Step:
 
     def conv(self, j, x, w):
         old = self.tree["conv"][j]
-        y, tail = kda.short_conv_step(x[:, 0], w, old)
+        y, tail = short_conv.short_conv_step(x[:, 0], w, old)
         self.tree["conv"] = self.tree["conv"].at[j].set(
             self._keep(tail, old))
         return y[:, None]

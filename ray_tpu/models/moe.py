@@ -165,18 +165,20 @@ def softmax_top_k(k: int) -> Routing:
     return route
 
 
-def sigmoid_bias_top_k(k: int, scale: float = 1.0) -> Routing:
+def sigmoid_bias_top_k(k: int, scale: float = 1.0,
+                       eps: float = 1e-20) -> Routing:
     """Sigmoid scores; the k experts with the largest score PLUS the
     selection bias (`router_bias`, a buffer, not a weight) are chosen,
     and weighted by their scores WITHOUT it, renormalised to sum to
-    `scale` (the `noaux_tc` rule with one group)."""
+    `scale` (the `noaux_tc` rule with one group).  `eps` is what the
+    published rule adds to the sum it divides by."""
 
     def route(logits, params):
         s = jax.nn.sigmoid(logits)
         _, idx = jax.lax.top_k(
             s + params["router_bias"].astype(jnp.float32), k)
         w = jnp.take_along_axis(s, idx, axis=-1)
-        return idx, w / (w.sum(-1, keepdims=True) + 1e-20) * scale
+        return idx, w / (w.sum(-1, keepdims=True) + eps) * scale
 
     return route
 

@@ -37,9 +37,8 @@ All state arithmetic is float32 with float32 matrix products
 (`Precision.HIGHEST`): the products are small beside the model's and the
 state is the one thing here whose error compounds over a sequence.
 
-`short_conv` / `short_conv_step` are the causal depthwise convolution
-over time in front of q, k and v, with the rows before the first token
-handed in (`tail`) and the last `K - 1` real rows handed back.
+The causal depthwise convolution in front of q, k and v is
+`ops/short_conv.py`'s.
 """
 
 from __future__ import annotations
@@ -52,29 +51,6 @@ from jax import lax
 
 _HI = lax.Precision.HIGHEST
 CHUNK = 64      # tokens a chunk of the prefill form
-
-
-def short_conv(x: jax.Array, w: jax.Array, tail: jax.Array,
-               n_real: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """x [B, T, C] rows at t = 0.., w [K, C] (w[K-1] multiplies the
-    current row), tail [B, K-1, C] the rows before t = 0.  Returns
-    (y [B, T, C], the K-1 rows before t = n_real [B])."""
-    K = w.shape[0]
-    T = x.shape[1]
-    xx = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
-    y = sum(xx[:, j:j + T] * w[j].astype(x.dtype) for j in range(K))
-    new_tail = jax.vmap(lambda rows, n: lax.dynamic_slice_in_dim(
-        rows, n, K - 1, axis=0))(xx, jnp.broadcast_to(n_real, x.shape[:1]))
-    return y, new_tail
-
-
-def short_conv_step(x: jax.Array, w: jax.Array, tail: jax.Array
-                    ) -> Tuple[jax.Array, jax.Array]:
-    """One row a sequence: x [B, C], tail [B, K-1, C] -> (y [B, C],
-    the tail with x behind it and its oldest row gone)."""
-    xx = jnp.concatenate([tail.astype(x.dtype), x[:, None]], axis=1)
-    y = jnp.einsum("bkc,kc->bc", xx, w.astype(x.dtype))
-    return y, xx[:, 1:]
 
 
 def kda_step(S: jax.Array, q: jax.Array, k: jax.Array, v: jax.Array,

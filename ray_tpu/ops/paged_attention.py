@@ -31,6 +31,16 @@ matrix unit once either way, and the copies, not the products, are the
 kernel's time (on a v5e, 32 sequences of 1000 and of 2048 rows: 88% and
 91% of the HBM bound for the bytes it reads; PERF.md section 6, PR 31).
 
+Heads narrower than a lane row.  A pool `[L, NB, bs, kvH, 64]` would be
+stored with half of every 128-lane row as padding.  A model with heads
+of 64 keeps ONE pool instead, a row a token a KV head holding that
+head's K ‖ V (`[L, NB, bs, kvH, 2 D]`, no padded lanes), and calls the
+kernel with `v_pool=None`: one copy a block brings both, the query is
+laid into the K lanes of a zero row (so `q . row` is `q . k`), the
+value product runs over the whole row, and the V lanes of the result
+are the output.  The same loop, the same masks, one buffer instead of
+two.
+
 Precision: bf16 operands, float32 scores, softmax and accumulation (the
 gather path it replaces rounds the scores to bf16 first).
 
@@ -43,6 +53,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -106,8 +117,14 @@ def _block_copy(pool, layer, phys, buf, slot, t, rows, sem):
 
 
 def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
-            tab_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, *,
-            nb, bs, kvh, n_heads, n_q, chunk, scale):
+            tab_ref, q_ref, *refs, nb, bs, kvh, n_heads, n_q, chunk,
+            scale):
+    # refs: the pools in HBM, the output, a chunk buffer a pool, the
+    # semaphores.  Two pools (K, V) or one whose rows are K ‖ V.
+    n_pools = (len(refs) - 2) // 2
+    o_ref, sems = refs[n_pools], refs[-1]
+    pools = tuple(zip(refs[:n_pools], refs[n_pools + 1:-1]))
+    kbuf, vbuf = pools[0][1], pools[-1][1]
     layer, n_items = layer_ref[0], n_ref[0]
     rows = bs * kvh                         # of a block in the flat view
     qh, d = q_ref.shape[1:]
@@ -131,8 +148,7 @@ def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
             # only `start` reads the table: entries past `live` are
             # never looked at
             phys = tab_ref[b * nb + j * chunk + t] if start else 0
-            for which, (pool, buf) in enumerate(((k_hbm, kbuf),
-                                                 (v_hbm, vbuf))):
+            for which, (pool, buf) in enumerate(pools):
                 copy = _block_copy(pool, layer, phys, buf, slot, t, rows,
                                    sems.at[slot, which])
                 copy.start() if start else copy.wait()
@@ -192,17 +208,23 @@ def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
         jnp.zeros((qh, 1), jnp.float32), jnp.zeros((qh, d), jnp.float32)))
 
 
-def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                    layer: jax.Array, scalars, *,
-                    chunk: int = CHUNK_BLOCKS) -> jax.Array:
+def paged_attention(q: jax.Array, k_pool: jax.Array,
+                    v_pool: Optional[jax.Array], layer: jax.Array,
+                    scalars, *, chunk: int = CHUNK_BLOCKS) -> jax.Array:
     """q [B, Q, H, D] (rotated) against layer `layer` of the stacked
     pools [L, NB, bs, kvH, D], through `scalars` = `plan(tables, qpos,
     active, bs, chunk)`: [B, Q, H, D], zeros for a dead sequence.  Query
     j of sequence b sees keys at positions <= qpos[b, j].  The pools
     stay whole in HBM; the layer index is a scalar the kernel adds to
-    its block addresses, never a slice of the pool."""
+    its block addresses, never a slice of the pool.
+
+    `v_pool=None`: `k_pool` [L, NB, bs, kvH, 2 D] holds K ‖ V a row (the
+    module's docstring); q and the result are still D wide."""
     B, Q, H, D = q.shape
-    L, NB, bs, kvh, _ = k_pool.shape
+    L, NB, bs, kvh, W = k_pool.shape
+    pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
+    if v_pool is None:
+        q = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
     nb = scalars[-1].shape[0] // B
     chunk = min(chunk, nb)
     if scalars[1].shape[0] != B * -(-nb // chunk):
@@ -210,7 +232,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             f"the scalars were planned for another chunk than {chunk}")
     rows = bs * kvh
     interpret = not _attention._on_tpu()
-    buf = pltpu.VMEM((2, chunk * rows, D), k_pool.dtype)
+    buf = pltpu.VMEM((2, chunk * rows, W), k_pool.dtype)
     kernel = functools.partial(
         _kernel, nb=nb, bs=bs, kvh=kvh, n_heads=H, n_q=Q, chunk=chunk,
         scale=1.0 / math.sqrt(D))
@@ -219,18 +241,18 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1 + len(scalars),
             grid=(1,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))]),
-        out_shape=jax.ShapeDtypeStruct((B, Q * H, D), q.dtype),
+            scratch_shapes=[buf] * len(pools)
+            + [pltpu.SemaphoreType.DMA((2, len(pools)))]),
+        out_shape=jax.ShapeDtypeStruct((B, Q * H, W), q.dtype),
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=64 * 2 ** 20),
         name="paged_attention",
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), *scalars,
-      q.reshape(B, Q * H, D),
-      k_pool.reshape(L, NB, rows, D), v_pool.reshape(L, NB, rows, D))
-    return out.reshape(B, Q, H, D)
+      q.reshape(B, Q * H, W),
+      *(pool.reshape(L, NB, rows, W) for pool in pools))
+    return out.reshape(B, Q, H, W)[..., W - D:]

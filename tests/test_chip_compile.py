@@ -569,6 +569,64 @@ def test_hybrid_cell_programs_fit_one_v5e(one_chip, program):
     assert _hbm_gib(compiled) < V5E_HBM_GIB - 0.5
 
 
+@pytest.mark.parametrize("program", ["tick", "insert"])
+def test_conv_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
+    """The engine's decode tick and its largest insert at the geometry of
+    the benchmark's `compose-decode-conv-moe` cell (gated convolutions
+    with a two-row tail by slot beside GQA heads of 64 in a K ‖ V paged
+    pool, a whole bank of 32 experts, at LFM2-8B-A1B's published widths;
+    the depth, slots, row length, buckets and pool its files state):
+    they compile for v5e, `paged_attention` answers "kernel" and the
+    tick holds one kernel call an attention layer beside the grouped
+    products, the pool (2048 B a token a layer) AND the slots' tails are
+    updated in place, and arguments + temporaries fit HBM."""
+    from ray_tpu.serve.llm.engine import LLMEngine
+
+    eng = _serving_cell("compose-decode-conv-moe", one_chip)
+    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+                                eng.published)
+    params, pools, key = eng.params, eng.pools, eng.key
+    assert (published["num_hidden_layers"], published["hidden_size"],
+            published["num_experts"], published["vocab_size"],
+            mc.n_conv_layers, mc.n_attn_layers, mc.n_moe_layers, mc.head_dim,
+            ec.num_slots) == (14, 2048, 32, 65536, 11, 3, 12, 64, 256)
+    assert model.paged_attention(pools) == "kernel"
+    kv = pools["kv"]
+    assert math.prod(kv.shape[3:]) * kv.dtype.itemsize == 2048
+    state = _placed(jax.eval_shape(
+        lambda: model.init_slot_state(mc, ec.num_slots)), one_chip)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    B, nb = ec.num_slots, ec.max_blocks_per_slot
+    if program == "tick":
+        compiled = _compiled_cell_tick(eng, one_chip)
+        text = compiled.as_text()
+        assert text.count("paged_attention") >= mc.n_attn_layers
+        assert text.count('custom_call_target="tpu_custom_call"') \
+            >= 3 * mc.n_moe_layers + mc.n_attn_layers
+        # no padded [B, S_pad] view of the pool is built
+        padded = (B, nb * ec.kv_block_size) + kv.shape[3:]
+        assert not any(padded in shapes for _, shapes in _results(text))
+    else:
+        Pb = ec.prefill_buckets[-1]
+        compiled = jax.jit(
+            functools.partial(LLMEngine._insert_fn, eng),
+            donate_argnums=(1, 2, 3, 12)).lower(
+            params, pools, arg(jnp.int32, B), arg(jnp.int32, B),
+            arg(jnp.int32, nb), arg(jnp.int32), arg(jnp.int32, Pb),
+            arg(jnp.int32), arg(jnp.int32, Pb // ec.kv_block_size),
+            arg(jnp.int32), arg(jnp.float32), key, state).compile()
+    m = compiled.memory_analysis()
+    kept = sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in list(pools.values()) + list(state.values()))
+    print(program, "GiB", _hbm_gib(compiled), "temp",
+          m.temp_size_in_bytes / GIB, "args", m.argument_size_in_bytes / GIB)
+    assert m.alias_size_in_bytes >= kept                # both in place
+    assert _hbm_gib(compiled) < V5E_HBM_GIB - 0.5
+
+
 def test_train_step_holds_flash_kernel_and_fits_one_v5e(topo, on_tpu):
     """One whole `build_train_step` program at chip_smoke.py's train
     widths, depth and batch, on a one-device mesh."""

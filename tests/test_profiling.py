@@ -124,6 +124,11 @@ def test_sampler_idle_overhead_bounded():
     """Sampling an idle process at the default-ish rate costs a small
     fraction of a CPU (the sampler must be safe to leave running
     against a live worker)."""
+    # Idle means idle: a file that ran earlier in this process may have
+    # left compile captures queued on the `xla-capture` worker.
+    from ray_tpu.observability import flush_captures
+
+    flush_captures(timeout=120.0)
     window = 1.0
     cpu0 = time.process_time()
     s = StackSampler(hz=100).start()
