@@ -46,7 +46,9 @@ from jax import lax
 from ray_tpu.models.latent_moe import _swiglu
 from ray_tpu.models.llama import (_decode_attention, _repeat_kv, apply_rope,
                                   embed_lookup, rms_norm, xla_attention)
-from ray_tpu.models.moe import dropless_moe, sigmoid_bias_top_k
+from ray_tpu.models.moe import (
+    dropless_moe, serving_grouped_path, sigmoid_bias_top_k,
+)
 from ray_tpu.models.serving import ServingFns
 from ray_tpu.ops import paged_attention as paged
 from ray_tpu.ops import short_conv
@@ -454,4 +456,5 @@ _SERVING = ServingFns(
     init_params=init_params, init_pool=init_paged_pool,
     prefill=prefill_paged, decode=decode_step_paged,
     head_weight=lm_head_weight, init_counts=init_counts,
-    init_slot_state=init_slot_state, paged_attention=_paged_attention)
+    init_slot_state=init_slot_state, paged_attention=_paged_attention,
+    grouped_matmul=serving_grouped_path)

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import latent_moe as LM
 from ray_tpu.models.llama import embed_lookup, rms_norm
+from ray_tpu.models.moe import serving_grouped_path
 from ray_tpu.models.serving import ServingFns
 from ray_tpu.ops import kda, short_conv
 
@@ -401,4 +402,5 @@ _SERVING = ServingFns(
     init_params=init_params, init_pool=init_paged_pool,
     prefill=prefill_paged, decode=decode_step_paged,
     head_weight=LM.lm_head_weight, init_counts=init_counts,
-    init_slot_state=init_slot_state)
+    init_slot_state=init_slot_state,
+    grouped_matmul=serving_grouped_path)

@@ -43,7 +43,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.llama import embed_lookup, rms_norm
-from ray_tpu.models.moe import dropless_moe, sigmoid_bias_top_k
+from ray_tpu.models.moe import (
+    dropless_moe, serving_grouped_path, sigmoid_bias_top_k,
+)
 from ray_tpu.models.serving import ServingFns
 
 
@@ -436,4 +438,5 @@ _SERVING = ServingFns(
     name="latent attention + dropless experts (models/latent_moe.py)",
     init_params=init_params, init_pool=init_paged_pool,
     prefill=prefill_paged, decode=decode_step_paged,
-    head_weight=lm_head_weight, init_counts=init_counts)
+    head_weight=lm_head_weight, init_counts=init_counts,
+    grouped_matmul=serving_grouped_path)

@@ -18,8 +18,9 @@ shardings and GSPMD places dispatch/combine all-to-alls on the ICI ring.
 
 Two expert layers live here.  `dropless_moe` is THE dropless layer: the
 assignments are sorted by expert and three grouped matrix products
-(`jax.lax.ragged_dot`) run over the experts, so every token gets all of
-its experts whatever the load and an expert nobody picked is not read.
+(`ops/grouped_matmul.py` on a TPU in bf16, `jax.lax.ragged_dot`
+elsewhere) run over the experts, so every token gets all of its experts
+whatever the load and an expert nobody picked is not read.
 Its routing rule is an argument (`softmax_top_k`, `sigmoid_bias_top_k`).
 `moe_layer` below is the older capacity-dispatch layer that
 `LlamaConfig.n_experts` trains with; it DROPS tokens past an expert's
@@ -34,6 +35,8 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+from ray_tpu.ops import grouped_matmul
 
 
 @dataclasses.dataclass(frozen=True)
@@ -183,6 +186,39 @@ def sigmoid_bias_top_k(k: int, scale: float = 1.0,
     return route
 
 
+def grouped_path(rows: int, w_shape: Tuple[int, int, int], dtype) -> str:
+    """"kernel" | "xla": which grouped product `dropless_moe` compiles
+    to for `rows` assignments over experts `w_gate` [E, D, F] on this
+    backend (`engine.stats()` shows it): `ops.grouped_matmul` where it
+    engages for BOTH shapes, D -> F and F -> D, else `lax.ragged_dot`."""
+    e, d, f = w_shape
+    both = (grouped_matmul.engages(rows, e, d, f, dtype)
+            and grouped_matmul.engages(rows, e, f, d, dtype))
+    return "kernel" if both else "xla"
+
+
+def serving_grouped_path(config, slots: int) -> str:
+    """`ServingFns.grouped_matmul` of a model whose expert layers are
+    `dropless_moe`: `grouped_path` at the decode tick's shape, `slots`
+    tokens of `top_k` assignments over the experts this chip holds."""
+    held = getattr(config, "n_held_experts", config.n_experts)
+    return grouped_path(
+        slots * config.top_k,
+        (held, config.dim, config.expert_hidden_dim), config.dtype)
+
+
+def _grouped_product(sizes, rows, w_shape, dtype):
+    """The ONE call site of a grouped product: (xs [rows, K], w [E, K,
+    N]) -> [rows, N] over `sizes`, by the Pallas kernel (its walk
+    planned once here, shared by the three products) or by
+    `lax.ragged_dot`."""
+    if grouped_path(rows, w_shape, dtype) == "kernel":
+        scalars = grouped_matmul.plan(sizes, rows)
+        return lambda xs, w: grouped_matmul.grouped_matmul(
+            xs, w, sizes, scalars)
+    return lambda xs, w: jax.lax.ragged_dot(xs, w, sizes)
+
+
 def dropless_moe(x: jax.Array, params: Dict[str, jax.Array],
                  routing: Routing, live: Optional[jax.Array] = None,
                  share: Optional[Tuple[int, int]] = None
@@ -207,7 +243,17 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array],
     expert held elsewhere is in no group here and reads nothing, so y is
     the part of the layer's result that the held experts give (the
     shares' parts add up to the whole layer's) and the counts [E/n] are
-    the held experts' own."""
+    the held experts' own.
+
+    The three grouped products run by ONE of two paths, chosen by
+    backend, dtype and shape alone (`grouped_path`): on a TPU, in bf16,
+    with D and F in whole lane rows, the Pallas kernel of
+    `ops/grouped_matmul.py`, which reads each TOUCHED expert's matrix
+    once at a row tile that fits the group; everywhere else (every CPU
+    run unless a test forces the interpreter, float32 as the model
+    tests run it) `lax.ragged_dot`, the reference.  The same rows and
+    the same arithmetic either way: bf16 operands, float32
+    accumulation, one rounding."""
     T, D = x.shape
     E = params["router"].shape[-1]
     with jax.named_scope("router"):
@@ -231,10 +277,10 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array],
         sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
         xs = x[order // k]                                  # [T * k, D]
         dt = x.dtype
-        gate = jax.lax.ragged_dot(xs, params["w_gate"].astype(dt), sizes)
-        up = jax.lax.ragged_dot(xs, params["w_up"].astype(dt), sizes)
-        ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up,
-                                params["w_down"].astype(dt), sizes)
+        product = _grouped_product(sizes, T * k, params["w_gate"].shape, dt)
+        gate = product(xs, params["w_gate"].astype(dt))
+        up = product(xs, params["w_up"].astype(dt))
+        ys = product(jax.nn.silu(gate) * up, params["w_down"].astype(dt))
         # rows past the last group belong to no expert: whatever the
         # grouped product left there must not reach a token
         ys = jnp.where((jnp.arange(T * k) < sizes.sum())[:, None], ys, 0)

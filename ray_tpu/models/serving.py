@@ -42,7 +42,10 @@ export, adopt, spill, promote) and never look inside a row.
     speculative target's step; draft, what a model needs to BE a
     speculative draft (`DraftFns`); paged_attention(pools) -> "kernel"
     | "gather", which path `decode` compiles its attention to over
-    these pools on this backend (`engine.stats()` shows it).
+    these pools on this backend (`engine.stats()` shows it);
+    grouped_matmul(config, num_slots) -> "kernel" | "xla", the same for
+    the experts' grouped products at the tick's shape
+    (`models/moe.py::serving_grouped_path`).
 """
 
 from __future__ import annotations
@@ -75,3 +78,4 @@ class ServingFns(NamedTuple):
     draft: Optional[DraftFns] = None
     verify: Optional[Callable[..., Any]] = None
     paged_attention: Optional[Callable[..., str]] = None
+    grouped_matmul: Optional[Callable[..., str]] = None

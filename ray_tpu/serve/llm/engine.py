@@ -2248,6 +2248,12 @@ class LLMEngine:
             "paged_attention": (
                 self._model.paged_attention(self._cache)
                 if self._model.paged_attention else "gather"),
+            # and the experts' grouped products, at the tick's shape
+            # ("xla" also for a model that has none)
+            "grouped_matmul": (
+                self._model.grouped_matmul(self.model_config,
+                                           self.config.num_slots)
+                if self._model.grouped_matmul else "xla"),
             # what the ticks had to read against what the padded
             # [num_slots, max_seq_len] view holds
             "live_rows": self._live_rows_sum,

@@ -371,6 +371,14 @@ def _results(text):
     return out
 
 
+def _grouped_products_are_the_kernel(text, n_moe_layers):
+    """A compiled expert program with `ops.grouped_matmul` engaged: three
+    kernel calls an expert layer and none of XLA's grouped matmul left
+    (`ragged-dot` custom calls, `ragged_dot_tiling` in their config)."""
+    assert text.count("grouped_matmul") >= 3 * n_moe_layers
+    assert "ragged" not in _without_metadata(text)
+
+
 def _compiled_cell_tick(eng, one_chip):
     """`LLMEngine._tick_fn` of a serving cell (`_serving_cell`), with
     the model's counters and per-slot state where it has them, donated
@@ -460,6 +468,52 @@ def test_paged_attention_leaves_the_other_programs_as_they_were(
     assert "paged_attention" not in with_kernel
 
 
+@pytest.mark.parametrize("program", ["chat-decode tick", "train step"])
+def test_grouped_matmul_leaves_the_other_programs_as_they_were(
+        topo, one_chip, on_tpu, monkeypatch, program):
+    """The kernel is `dropless_moe`'s alone.  `chat-decode`'s tick and a
+    train step (the dense decoder; flash, `remat="dots"`) compile for
+    v5e to the same text, metadata apart, whether the selector answers
+    as on the chip or is taken away, and hold no such call: the text
+    the parent compiled (PERF.md section 6, PR 33, has that
+    comparison)."""
+    import optax
+
+    from ray_tpu.models.llama import LlamaConfig, init_params, loss_fn
+    from ray_tpu.ops import grouped_matmul
+    from ray_tpu.parallel import build_train_step, create_train_state
+
+    def tick():
+        eng = _serving_cell("chat-decode", one_chip)
+        return _compiled_cell_tick(eng, one_chip).as_text()
+
+    def train_step():
+        config = LlamaConfig(vocab_size=2048, dim=512, n_layers=2,
+                             n_heads=4, n_kv_heads=2, hidden_dim=1024,
+                             max_seq_len=1024, attn_impl="flash",
+                             remat="dots")
+        mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+        everywhere = NamedSharding(mesh, P())
+        optimizer = optax.adamw(1e-3)
+        state = _placed(jax.eval_shape(
+            lambda p: create_train_state(p, optimizer), jax.eval_shape(
+                lambda: init_params(config, jax.random.key(0)))),
+            everywhere)
+        batch = {"tokens": jax.ShapeDtypeStruct(
+            (2, 1025), jnp.int32, sharding=everywhere)}
+        return build_train_step(
+            lambda p, b: loss_fn(p, b, config), optimizer, mesh, None,
+            everywhere).lower(state, batch).compile().as_text()
+
+    compiled = tick if program == "chat-decode tick" else train_step
+    with_kernel = _without_metadata(compiled())
+    monkeypatch.setattr(grouped_matmul, "engages",
+                        lambda m, g, k, n, dtype: False)
+    assert _without_metadata(compiled()) == with_kernel
+    assert "grouped_matmul" not in with_kernel
+    assert "ragged" not in with_kernel
+
+
 @pytest.mark.parametrize("cell, rows", [
     ("chat-decode", (16, 32, 64, 128)),
     ("assistant-decode-moe", (16, 32, 64, 128, 256))])
@@ -488,28 +542,28 @@ def test_export_rows_compile_and_fit_beside_the_insert(one_chip, cell, rows):
 
 
 @pytest.mark.parametrize("program", ["tick", "insert"])
-def test_latent_moe_cell_programs_fit_one_v5e(one_chip, program):
+def test_latent_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     """The engine's decode tick and its largest insert at the geometry of
     the benchmark's `assistant-decode-moe` cell (latent attention and
     dropless experts at kanana-2-30b-a3b's published widths, the depth,
     slots, row length, buckets and pool its files state): they compile
-    for v5e, the grouped products are the chip's own grouped-matmul
-    calls, the pool is updated in place (unrolled layers: no second
-    pool), and arguments + temporaries fit HBM.  These readings sized
-    the configuration's depth and the cell's pool."""
+    for v5e, the grouped products are `ops.grouped_matmul`'s kernel
+    calls (it engages at 384 rows over 128 experts and at the insert's
+    12288) with no `ragged-dot` left, the pool is updated in place
+    (unrolled layers: no second pool), and arguments + temporaries fit
+    HBM.  These readings sized the configuration's depth and the
+    cell's pool."""
     eng = _serving_cell("assistant-decode-moe", one_chip)
     mc, published, pools = eng.model_config, eng.published, eng.pools
     assert (published["num_hidden_layers"], published["hidden_size"],
             published["n_routed_experts"], published["vocab_size"]) \
         == (8, 2048, 128, 128256)
+    assert eng._model.grouped_matmul(mc, eng.config.num_slots) == "kernel"
 
-    if program == "tick":
-        compiled = _compiled_cell_tick(eng, one_chip)
-        n_moe = mc.n_layers - mc.n_dense_layers
-        assert compiled.as_text().count(
-            'custom_call_target="tpu_custom_call"') >= 3 * n_moe
-    else:
-        compiled = _compiled_insert(eng, one_chip)
+    compiled = (_compiled_cell_tick if program == "tick"
+                else _compiled_insert)(eng, one_chip)
+    _grouped_products_are_the_kernel(compiled.as_text(),
+                                     mc.n_layers - mc.n_dense_layers)
     m = compiled.memory_analysis()
     pool_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
                      for x in pools.values())
@@ -519,13 +573,15 @@ def test_latent_moe_cell_programs_fit_one_v5e(one_chip, program):
 
 
 @pytest.mark.parametrize("program", ["tick", "insert"])
-def test_hybrid_cell_programs_fit_one_v5e(one_chip, program):
+def test_hybrid_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     """The engine's decode tick and its largest insert at the geometry of
     the benchmark's `agent-decode-hybrid` cell (KDA state by slot beside
     the paged latent pool, 64 of 256 experts held, at
     Kimi-Linear-48B-A3B's published widths; the depth, slots, row
     length, buckets and pool its files state): they compile for v5e,
-    the grouped products are the chip's own grouped-matmul calls, the
+    the grouped products are `ops.grouped_matmul`'s kernel calls (it
+    engages at 1024 rows of which a quarter are held and at the
+    insert's 16384) with no `ragged-dot` left, the
     latent pool AND the slots' recurrent state are updated in place
     (donated, static layer index), and arguments + temporaries fit HBM.
     These readings sized the configuration's depth and the cell's
@@ -547,10 +603,9 @@ def test_hybrid_cell_programs_fit_one_v5e(one_chip, program):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     B, nb = ec.num_slots, ec.max_blocks_per_slot
+    assert model.grouped_matmul(mc, B) == "kernel"
     if program == "tick":
         compiled = _compiled_cell_tick(eng, one_chip)
-        assert compiled.as_text().count(
-            'custom_call_target="tpu_custom_call"') >= 3 * mc.n_moe_layers
     else:
         Pb = ec.prefill_buckets[-1]
         compiled = jax.jit(
@@ -560,6 +615,7 @@ def test_hybrid_cell_programs_fit_one_v5e(one_chip, program):
             arg(jnp.int32, nb), arg(jnp.int32), arg(jnp.int32, Pb),
             arg(jnp.int32), arg(jnp.int32, Pb // ec.kv_block_size),
             arg(jnp.int32), arg(jnp.float32), key, state).compile()
+    _grouped_products_are_the_kernel(compiled.as_text(), mc.n_moe_layers)
     m = compiled.memory_analysis()
     kept = sum(math.prod(x.shape) * x.dtype.itemsize
                for x in list(pools.values()) + list(state.values()))
@@ -576,10 +632,12 @@ def test_conv_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     with a two-row tail by slot beside GQA heads of 64 in a K ‖ V paged
     pool, a whole bank of 32 experts, at LFM2-8B-A1B's published widths;
     the depth, slots, row length, buckets and pool its files state):
-    they compile for v5e, `paged_attention` answers "kernel" and the
-    tick holds one kernel call an attention layer beside the grouped
-    products, the pool (2048 B a token a layer) AND the slots' tails are
-    updated in place, and arguments + temporaries fit HBM."""
+    they compile for v5e, `paged_attention` and `grouped_matmul` answer
+    "kernel", the tick holds one paged-attention call an attention
+    layer, tick and insert three `ops.grouped_matmul` calls an expert
+    layer and no `ragged-dot`, the pool (2048 B a token a layer) AND
+    the slots' tails are updated in place, and arguments + temporaries
+    fit HBM."""
     from ray_tpu.serve.llm.engine import LLMEngine
 
     eng = _serving_cell("compose-decode-conv-moe", one_chip)
@@ -591,6 +649,7 @@ def test_conv_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
             mc.n_conv_layers, mc.n_attn_layers, mc.n_moe_layers, mc.head_dim,
             ec.num_slots) == (14, 2048, 32, 65536, 11, 3, 12, 64, 256)
     assert model.paged_attention(pools) == "kernel"
+    assert model.grouped_matmul(mc, ec.num_slots) == "kernel"
     kv = pools["kv"]
     assert math.prod(kv.shape[3:]) * kv.dtype.itemsize == 2048
     state = _placed(jax.eval_shape(
@@ -618,6 +677,7 @@ def test_conv_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
             arg(jnp.int32, nb), arg(jnp.int32), arg(jnp.int32, Pb),
             arg(jnp.int32), arg(jnp.int32, Pb // ec.kv_block_size),
             arg(jnp.int32), arg(jnp.float32), key, state).compile()
+    _grouped_products_are_the_kernel(compiled.as_text(), mc.n_moe_layers)
     m = compiled.memory_analysis()
     kept = sum(math.prod(x.shape) * x.dtype.itemsize
                for x in list(pools.values()) + list(state.values()))

@@ -385,6 +385,70 @@ def test_decode_step_agrees_on_both_paths(monkeypatch):
     assert jnp.abs(sg["tail"][2] - sk["tail"][2]).max() < 2e-3
 
 
+def _tiling(**over):
+    """The tiny model at expert widths that tile (bf16, 128 -> 128):
+    `ops.grouped_matmul` engages wherever the interpreter is forced;
+    heads of 16, so the paged-attention kernel does not."""
+    from ray_tpu.models import conv_moe as M
+
+    c = M.ConvMoEConfig.tiny(dim=128, expert_hidden_dim=128, **over)
+    return M, c, M.init_params(c, jax.random.key(0))
+
+
+@pytest.mark.parametrize("rows", ["all", "live"])
+def test_dropless_moe_agrees_on_both_grouped_paths(rows, monkeypatch):
+    """An expert layer of this model: `ops.grouped_matmul` through the
+    Pallas interpreter against `lax.ragged_dot`, the same counts to the
+    row and outputs to 2 ulp of bf16 at their size."""
+    from ray_tpu.models import moe
+    from ray_tpu.ops import attention
+
+    M, c, params = _tiling()
+    p = params["layers"][c.n_dense_layers]
+    x = jax.random.normal(jax.random.key(6), (48, 128), c.dtype)
+    live = None if rows == "all" else jnp.arange(48) % 3 != 1
+    routing = moe.sigmoid_bias_top_k(c.top_k, c.routed_scaling_factor,
+                                     M.ROUTE_EPS)
+    out = {}
+    for path, force in (("xla", False), ("kernel", True)):
+        monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", force)
+        assert M._SERVING.grouped_matmul(c, 24) == path
+        out[path] = moe.dropless_moe(x, p, routing, live=live)
+    (yx, sx), (yk, sk) = out["xla"], out["kernel"]
+    assert sx.tolist() == sk.tolist()
+    assert int(sx.sum()) == (96 if live is None else 2 * int(live.sum()))
+    yx, yk = np.asarray(yx, np.float32), np.asarray(yk, np.float32)
+    scale = np.abs(yx).max()
+    assert scale > 1e-3 and np.abs(yx - yk).max() <= 2 ** -7 * scale
+
+
+def test_engine_serves_the_same_tokens_on_both_grouped_paths(monkeypatch):
+    """One engine run a path over the same prompts (a chunked one, so
+    inserts of two buckets and the tick all run their grouped products
+    by the kernel): `engine.stats()` names the path, and the greedy
+    tokens are the same."""
+    from ray_tpu.ops import attention
+    from ray_tpu.serve.llm.engine import Request
+
+    M, c, params = _tiling(max_seq_len=64)
+    prompts = [_tokens(n, seed=40 + n) for n in (5, 16, 30)]
+    served = {}
+    for path, force in (("xla", False), ("kernel", True)):
+        monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", force)
+        engine = _engine(c, params)
+        handles = [engine.submit(Request(
+            prompt=p, max_tokens=5, chunked_prefill=len(p) > 16))
+            for p in prompts]
+        while engine.has_work():
+            engine.step()
+        st = engine.stats()
+        assert st["grouped_matmul"] == path
+        assert st["paged_attention"] == "gather"
+        served[path] = [h.tokens for h in handles]
+        assert all(len(t) == 5 for t in served[path])
+    assert served["kernel"] == served["xla"]
+
+
 # --------------------------------------------------- (e) lower precision
 
 def test_lower_precision_is_caught(model):

@@ -286,6 +286,38 @@ def test_expert_shares_sum_to_the_uncut_layer():
     assert jnp.abs(total + shared - want).max() < TOL
 
 
+@pytest.mark.parametrize("rank", [0, 3])
+def test_a_share_agrees_on_both_grouped_paths(rank, monkeypatch):
+    """`dropless_moe(share=)` at widths that tile (bf16, 128 -> 128, 2
+    of 8 experts held, dead rows besides): three quarters of the
+    assignments sort last, in no group.  `ops.grouped_matmul` through
+    the Pallas interpreter against `lax.ragged_dot`: the held experts'
+    counts to the row, outputs to 2 ulp of bf16 at their size."""
+    from ray_tpu.models import kimi_linear as KL, moe
+    from ray_tpu.ops import attention
+
+    c = KL.KimiLinearConfig.tiny(dim=128, expert_hidden_dim=128,
+                                 expert_shards=4, expert_rank=rank)
+    p = KL.init_params(c, jax.random.key(5))["layers"][1]
+    assert p["w_gate"].shape == (2, 128, 128)
+    x = 4.0 * jax.random.normal(jax.random.key(6), (64, 128), c.dtype)
+    live = jnp.arange(64) % 4 != 2
+    routing = moe.sigmoid_bias_top_k(c.top_k, c.routed_scaling_factor)
+    out = {}
+    for path, force in (("xla", False), ("kernel", True)):
+        monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", force)
+        assert KL._SERVING.grouped_matmul(c, 32) == path
+        out[path] = moe.dropless_moe(x, p, routing, live=live,
+                                     share=(rank, 4))
+    (yx, sx), (yk, sk) = out["xla"], out["kernel"]
+    assert sx.shape == (2,) and sx.tolist() == sk.tolist()
+    assert 0 < int(sx.sum()) < 48 * 2
+    yx, yk = np.asarray(yx, np.float32), np.asarray(yk, np.float32)
+    scale = np.abs(yx).max()
+    assert scale > 1e-3 and np.abs(yx - yk).max() <= 2 ** -7 * scale
+    assert not yk[~np.asarray(live)].any()
+
+
 # --------------------------------------------------- (f) lower precision
 
 @pytest.mark.parametrize("what", ["state_bf16", "int8"])

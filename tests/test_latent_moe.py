@@ -247,6 +247,36 @@ def test_softmax_top_k_routing_is_dropless_too():
     assert float(jnp.abs(y - want).max()) < TOL
 
 
+@pytest.mark.parametrize("rows", ["all", "live"])
+def test_dropless_moe_agrees_on_both_grouped_paths(rows, monkeypatch):
+    """An expert layer of this model at widths that tile (bf16, 128 ->
+    128, 8 experts, top 2): `ops.grouped_matmul` through the Pallas
+    interpreter against `lax.ragged_dot`.  The same counts to the row;
+    outputs to 2 ulp of bf16 at their size (both accumulate in float32
+    and round once; XLA:CPU sums K in another order)."""
+    from ray_tpu.models import latent_moe as LM, moe
+    from ray_tpu.ops import attention
+
+    c = LM.LatentMoEConfig.tiny(dim=128, expert_hidden_dim=128)
+    p = LM.init_params(c, jax.random.key(5))["layers"][1]
+    x = jax.random.normal(jax.random.key(6), (40, 128), c.dtype)
+    live = None if rows == "all" else jnp.arange(40) % 3 != 1
+    routing = moe.sigmoid_bias_top_k(c.top_k, c.routed_scaling_factor)
+    out = {}
+    for path, force in (("xla", False), ("kernel", True)):
+        monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", force)
+        assert LM._SERVING.grouped_matmul(c, 20) == path
+        out[path] = moe.dropless_moe(x, p, routing, live=live)
+    (yx, sx), (yk, sk) = out["xla"], out["kernel"]
+    assert sx.tolist() == sk.tolist()
+    assert int(sx.sum()) == (80 if live is None else 2 * int(live.sum()))
+    yx, yk = np.asarray(yx, np.float32), np.asarray(yk, np.float32)
+    scale = np.abs(yx).max()
+    assert scale > 1e-3 and np.abs(yx - yk).max() <= 2 ** -7 * scale
+    if live is not None:
+        assert not yk[~np.asarray(live)].any()
+
+
 # ------------------------------------------------------- (e) the engine
 
 @pytest.fixture(scope="module")
