@@ -224,7 +224,12 @@ class TrackedJit:
         try:
             import jax
 
-            jax.block_until_ready(out)
+            from ray_tpu.observability.profiling import trace_span
+
+            # The fence stands in a profiler trace under whatever span
+            # holds the call (an engine's `llm_engine.tick_dispatch`).
+            with trace_span("jit.wall_sample", fn=self.name):
+                jax.block_until_ready(out)
             wall = time.perf_counter() - t0
             exposed = max(_cumulative_exposed() - exposed0, 0.0)
             from ray_tpu.observability import xla as _xla

@@ -343,6 +343,65 @@ class _Slot:
         self.uses = 0
 
 
+class _LoopClock:
+    """Where the scheduler thread's time goes, always on: seconds on
+    `time.monotonic()` and calls of each top-level phase of `_step` and
+    `run`, read through `stats()["loop"]`. `phase("llm_engine.<name>")`
+    is the ONE way a phase is opened: it opens that span of a profiler
+    trace and adds the elapsed time to `<name>`'s counter, so the two
+    have the same boundaries. A phase opened inside another (a
+    landing before a tier lookup, inside `admit`) is a bare span: the
+    outer one has its time, and the phases never sum past the wall.
+    `now` is the clock's last reading. `warmup` starts it anew, so that
+    it holds no compile. Scheduler thread only."""
+
+    PHASES = ("ctrl", "admit", "first_token_wait", "tick_dispatch",
+              "spill_land", "tick_ready", "tick_readback", "emit",
+              "gauges", "idle")
+
+    def __init__(self):
+        self.steps = self.ticks = 0
+        self.seconds = dict.fromkeys(self.PHASES, 0.0)
+        self.calls = dict.fromkeys(self.PHASES, 0)
+        self.now = time.monotonic()
+        self._open = False
+
+    def phase(self, span: str, **args) -> "_Phase":
+        return _Phase(self, span.rpartition(".")[2],
+                      trace_span(span, **args))
+
+    def stats(self) -> Dict[str, Any]:
+        return {"steps": self.steps, "ticks": self.ticks,
+                "seconds": dict(self.seconds), "calls": dict(self.calls)}
+
+
+class _Phase:
+    """One `with` of `_LoopClock.phase`; yields the span."""
+
+    __slots__ = ("_clock", "_name", "_span", "_t0")
+
+    def __init__(self, clock, name, span):
+        self._clock, self._name, self._span = clock, name, span
+        self._t0 = None                 # stays None inside another phase
+
+    def __enter__(self):
+        span = self._span.__enter__()
+        c = self._clock
+        if not c._open:
+            c._open = True
+            self._t0 = c.now = time.monotonic()
+        return span
+
+    def __exit__(self, *exc):
+        if self._t0 is not None:
+            c = self._clock
+            c.now = time.monotonic()
+            c.seconds[self._name] += c.now - self._t0
+            c.calls[self._name] += 1
+            c._open = False
+        return self._span.__exit__(*exc)
+
+
 class LLMEngine:
     """Slot-based continuous-batching engine over one model's
     parameters; the model's functions come from its config object
@@ -468,6 +527,7 @@ class LLMEngine:
         self._pending_spills: List[Tuple[Dict[str, Any],
                                          List[Tuple[int, ...]]]] = []
         self._in_step = False
+        self._loop = _LoopClock()
         self._spill_lands = 0           # landings, and those that had
         self._spill_lands_waited = 0    # to wait for the transfer
         self._tier_seen = {t: {"hits": 0, "misses": 0, "spills": 0,
@@ -1571,7 +1631,8 @@ class LLMEngine:
         pending, self._pending_spills = self._pending_spills, []
         bs = self.config.kv_block_size
         n_blocks = sum(len(toks) for _, toks in pending)
-        with trace_span("llm_engine.spill_land", blocks=n_blocks) as sp:
+        with self._loop.phase("llm_engine.spill_land",
+                              blocks=n_blocks) as sp:
             t0 = time.monotonic()
             landed, waited = 0, True
             try:
@@ -1937,6 +1998,7 @@ class LLMEngine:
         slot. Returns True if any work was done."""
         with trace_span("llm_engine.step"):
             self._in_step = True
+            self._loop.steps += 1
             try:
                 return self._step()
             finally:
@@ -1948,14 +2010,15 @@ class LLMEngine:
         `llm_engine.<phase>` spans that together cover the step."""
         import numpy as np
 
-        with trace_span("llm_engine.ctrl"):
+        phase = self._loop.phase
+        with phase("llm_engine.ctrl"):
             did_cancel = bool(self._cancelled)
             did_ctrl = self._process_ctrl()
             self._process_cancels()
             self._maybe_preempt()
         self._admit_blocked = False
         did_ctrl = did_ctrl or bool(self._chunking)   # a chunk will go out
-        with trace_span("llm_engine.admit") as sp:
+        with phase("llm_engine.admit") as sp:
             inserted = self._admit()
             sp.set_metadata(admitted=len(inserted))
         if inserted:
@@ -1963,7 +2026,7 @@ class LLMEngine:
             # the tick below overwrites it with the second). Adopted
             # slots skip this: their pending token was emitted by the
             # exporting engine already.
-            with trace_span("llm_engine.first_token_wait"):
+            with phase("llm_engine.first_token_wait"):
                 tok_host = np.asarray(self._tok)
                 for slot, fresh in inserted:
                     if not fresh:
@@ -1974,15 +2037,15 @@ class LLMEngine:
                         self._emit(slot, int(tok_host[slot]))
         if not self._active.any():
             self._land_spills()         # no tick to land behind
-            with trace_span("llm_engine.gauges"):
+            with phase("llm_engine.gauges"):
                 self._update_gauges()
             return bool(inserted) or did_cancel or did_ctrl
         live = np.nonzero(self._active)[0]
         rows = self._live_rows(live)
-        with trace_span("llm_engine.tick_dispatch", live=len(live),
-                        rows=rows):
+        with phase("llm_engine.tick_dispatch", live=len(live), rows=rows):
+            t_tick = self._loop.now
+            self._loop.ticks += 1
             spec = self._spec_ready(live)
-            t_tick = time.monotonic()
             if spec:
                 out = self._spec_dispatch()
             else:
@@ -2001,9 +2064,10 @@ class LLMEngine:
             if spec:
                 toks_host, n_emit = self._spec_wait(*out)
             else:
-                toks_host = np.asarray(out)         # [K, B]
-        with trace_span("llm_engine.emit"):
-            self._credit_decode(live, time.monotonic() - t_tick)
+                toks_host, = self._read_back(out)       # [K, B]
+        with phase("llm_engine.emit"):
+            # the tick's wall time, dispatch to readback, on the one clock
+            self._credit_decode(live, self._loop.now - t_tick)
             for slot in live:
                 s = int(slot)
                 n = int(n_emit[s]) if spec else toks_host.shape[0]
@@ -2018,9 +2082,30 @@ class LLMEngine:
                         break      # finished earlier in the block —
                         #            remaining tokens were speculative
                     self._emit(s, int(toks_host[k, s]))
-        with trace_span("llm_engine.gauges"):
+        with phase("llm_engine.gauges"):
             self._update_gauges()
         return True
+
+    def _read_back(self, *outs):
+        """`llm_engine.tick_wait`'s two halves: wait until the tick's
+        outputs are defined (`tick_ready`: the device is done and the
+        host has been told), then read them on the host
+        (`tick_readback`). The copies are asked for BEFORE the wait, so
+        they queue behind the tick as `np.asarray` of a pending array
+        queues its own: asked for after it, each costs one more wake-up
+        of this thread (0.1 ms a tick on a v5e host, PERF.md PR 34), and
+        `tick_readback` is what is left of them once the tick is known
+        done."""
+        import numpy as np
+
+        nbytes = sum(x.nbytes for x in outs)
+        with self._loop.phase("llm_engine.tick_ready"):
+            for x in outs:
+                x.copy_to_host_async()
+            for x in outs:
+                x.block_until_ready()
+        with self._loop.phase("llm_engine.tick_readback", bytes=nbytes):
+            return [np.asarray(x) for x in outs]
 
     def _live_rows(self, live) -> int:
         """KV rows the tick about to go out has to read: the live slots'
@@ -2082,9 +2167,7 @@ class LLMEngine:
     def _spec_wait(self, t, n_emit):
         """Read a speculative round back: (tokens [K, B] host, n_emit
         [B] host); the caller emits tokens[0:n_emit[s], s] per slot."""
-        import numpy as np
-
-        n_host = np.asarray(n_emit)
+        t_host, n_host = self._read_back(t, n_emit)
         live = int((n_host > 0).sum())
         self._spec_rounds += 1
         self._spec_proposed += (self.config.spec_k - 1) * live
@@ -2092,7 +2175,7 @@ class LLMEngine:
         self._metrics.spec_proposed.inc(
             float((self.config.spec_k - 1) * live))
         self._metrics.spec_accepted.inc(float(int(n_host.sum()) - live))
-        return np.asarray(t).T, n_host
+        return t_host.T, n_host
 
     def _update_gauges(self) -> None:
         m = self._metrics
@@ -2145,7 +2228,14 @@ class LLMEngine:
             if not self.step():
                 self._work.clear()
                 if not self.has_work():
-                    self._work.wait(idle_wait_s)
+                    # empty for want of traffic: both counts read 0
+                    # unless a submit raced the test above
+                    with self._lock:
+                        queued = sum(map(len, self._queues.values()))
+                    with self._loop.phase(
+                            "llm_engine.idle", queued=queued,
+                            live=int(self._active.sum())):
+                        self._work.wait(idle_wait_s)
 
     def drain(self, timeout: float = 300.0) -> None:
         """Synchronously step until queue and slots are empty (tests and
@@ -2189,6 +2279,9 @@ class LLMEngine:
         finally:
             self._prefix = prefix
             self._draft = draft
+        # the phase clock counts a warm engine's time: the compiles
+        # above stood inside `admit` and `tick_dispatch`
+        self._loop = _LoopClock()
         import jax
 
         if self._stateful:
@@ -2258,6 +2351,8 @@ class LLMEngine:
             # [num_slots, max_seq_len] view holds
             "live_rows": self._live_rows_sum,
             "padded_rows": self._padded_rows_sum,
+            # the scheduler thread's seconds and calls by phase
+            "loop": self._loop.stats(),
             "traces": traces,
             "trace_count": sum(traces.values()),
             "kv": dict(self._allocator.stats(),

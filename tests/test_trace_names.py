@@ -7,12 +7,16 @@ import contextlib
 import glob
 import os
 import re
+import threading
 import time
 
 import pytest
 
 STEP_CHILDREN = ["ctrl", "admit", "first_token_wait", "tick_dispatch",
                  "spill_land", "tick_wait", "emit", "gauges"]
+LOOP_PHASES = ["ctrl", "admit", "first_token_wait", "tick_dispatch",
+               "spill_land", "tick_ready", "tick_readback", "emit", "gauges",
+               "idle"]
 
 
 # ------------------------------------------------------- program names
@@ -57,7 +61,11 @@ def _host_events(trace_dir, prefixes):
 def _profiled(trace_dir):
     import jax
 
-    jax.profiler.start_trace(str(trace_dir))
+    # as the benchmark traces (benchmarks/run.py): host spans, no Python
+    # tracer, whose cost a call would stand between two spans
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
     try:
         yield
     finally:
@@ -68,7 +76,10 @@ def _profiled(trace_dir):
 def engine_traces(tmp_path_factory):
     """One tiny paged engine, traced twice: two requests in a roomy pool
     (nothing evicted), then distinct prompts until the prefix cache has
-    to give blocks back (evict + spill)."""
+    to give blocks back (evict + spill); then `run` on the empty engine,
+    traced ("idle").  Before them one untraced drain with the engine's
+    phase clock read on either side ("loop": before, after, the
+    drain's wall seconds)."""
     import jax
 
     from ray_tpu.models.llama import LlamaConfig, init_params
@@ -85,11 +96,16 @@ def engine_traces(tmp_path_factory):
         hs = [engine.submit(Request(
             prompt=[(base + 7 * i + j) % 200 + 1 for j in range(16)],
             max_tokens=6)) for i in range(n)]
+        t0 = time.monotonic()
         engine.drain()
+        wall = time.monotonic() - t0
         assert all(h.finish_reason == "length" for h in hs)
+        return wall
 
     serve(0, 1)                                    # compiles, untraced
-    out = {}
+    before = engine.stats()["loop"]
+    wall = serve(25, 2)
+    out = {"loop": (before, engine.stats()["loop"], wall)}
     for case, base, n in (("roomy", 50, 2), ("evicting", 100, 8)):
         d = tmp_path_factory.mktemp(case)
         ev0 = engine.stats()["prefix_cache"]["evictions"]
@@ -97,6 +113,14 @@ def engine_traces(tmp_path_factory):
             serve(base, n)
         out[case] = (_host_events(str(d), ("llm_engine.",)),
                      engine.stats()["prefix_cache"]["evictions"] - ev0)
+    d, stop = tmp_path_factory.mktemp("idle"), threading.Event()
+    with _profiled(d):
+        th = threading.Thread(target=engine.run, args=(stop,))
+        th.start()
+        time.sleep(0.1)
+        stop.set()
+        th.join()
+    out["idle"] = _host_events(str(d), ("llm_engine.",))
     return out
 
 
@@ -158,6 +182,177 @@ def test_tick_dispatch_carries_its_live_rows(engine_traces, case):
         assert 1 <= live <= 2 and 17 * live <= rows <= 22 * live
 
 
+def _bench_reader(monkeypatch, name):
+    import importlib.util
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    monkeypatch.syspath_prepend(bench)
+    spec = importlib.util.spec_from_file_location(
+        "lm_" + name, os.path.join(bench, "layer_metrics", name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("case", ["roomy", "evicting"])
+def test_tick_wait_is_ready_then_readback(engine_traces, case):
+    """`llm_engine.tick_wait` keeps its extent (the accepted readers
+    read it) and holds two children that cover it: `tick_ready`, until
+    the tick's output is defined, and `tick_readback` (`bytes=`), its
+    copy to the host."""
+    events = engine_traces[case][0]
+    waits = [e for e in events if e[0] == "llm_engine.tick_wait"]
+    assert len(waits) >= 4
+    covered = total = 0
+    for _, w0, w1, _ in waits:
+        kids = [e for e in events if w0 <= e[1] and e[2] <= w1
+                and e[0] != "llm_engine.tick_wait"]
+        assert [e[0] for e in kids] == ["llm_engine.tick_ready",
+                                        "llm_engine.tick_readback"]
+        ready, back = kids
+        assert ready[2] <= back[1]
+        assert int(back[3]["bytes"]) == 2 * 4       # [K=1, B=2] int32
+        covered += ready[2] - ready[1] + back[2] - back[1]
+        total += w1 - w0
+    assert covered / total > 0.8
+    # one of each a tick, and nowhere else
+    for name in ("tick_ready", "tick_readback"):
+        assert sum(e[0] == "llm_engine." + name for e in events) == len(waits)
+
+
+def test_empty_engine_waits_under_idle(engine_traces):
+    """`LLMEngine.run` with nothing queued and nothing live: the wait
+    for work is `llm_engine.idle` (`queued=0`, `live=0`), beside the
+    steps that found nothing to do and inside none."""
+    events = engine_traces["idle"]
+    idles = [e for e in events if e[0] == "llm_engine.idle"]
+    steps = [e for e in events if e[0] == "llm_engine.step"]
+    assert len(idles) >= 3 and len(steps) >= 3
+    for _, i0, i1, args in idles:
+        assert int(args["queued"]) == 0 and int(args["live"]) == 0
+        assert not any(s[1] <= i0 and i1 <= s[2] for s in steps)
+    # the 100 ms the engine stood empty lie under the span (waits of
+    # 20 ms each, the last cut short by the stop)
+    assert 0.05e9 < sum(e[2] - e[1] for e in idles) < 0.2e9
+    assert not any(e[0] == "llm_engine.tick_dispatch" for e in events)
+
+
+@pytest.mark.parametrize("key", ["phases", "calls", "seconds"])
+def test_engine_keeps_a_phase_clock(engine_traces, key):
+    """`engine.stats()["loop"]`: seconds and calls of every top-level
+    phase of the scheduler loop, always on (here with no profiler)."""
+    before, after, wall = engine_traces["loop"]
+    d = {k: {p: after[k][p] - before[k][p] for p in LOOP_PHASES}
+         for k in ("seconds", "calls")}
+    steps, ticks = (after[k] - before[k] for k in ("steps", "ticks"))
+    if key == "phases":
+        assert set(after) == {"steps", "ticks", "seconds", "calls"}
+        assert list(after["seconds"]) == list(after["calls"]) == LOOP_PHASES
+    elif key == "calls":
+        # two requests of 6 tokens admitted in one step: 5 ticks
+        assert steps == ticks == 5
+        c = d["calls"]
+        assert c["tick_ready"] == c["tick_readback"] == ticks
+        assert c["tick_dispatch"] == c["emit"] == ticks
+        assert c["ctrl"] == c["admit"] == c["gauges"] == steps
+        assert c["first_token_wait"] == 1 and c["idle"] == 0
+    else:
+        # the phases cover the drain and never count an instant twice
+        # (a loose floor: a CPU step is short beside its loop's turn)
+        total = sum(d["seconds"].values())
+        assert all(v >= 0 for v in d["seconds"].values())
+        assert 0.8 * wall <= total <= wall, (total, wall)
+
+
+def test_sampled_fence_stands_in_the_trace(tmp_path):
+    """`TrackedJit` fences every `xla_wall_sample_every`-th call with
+    `block_until_ready` inside the call: the fence is `jit.wall_sample`
+    (`fn=`), under whatever span holds the call."""
+    import jax.numpy as jnp
+
+    from ray_tpu.observability import tracked_jit
+    from ray_tpu.observability.profiling import trace_span
+
+    f = tracked_jit(lambda x: x * 3, name="trace_names_sampled")
+    f(jnp.ones((3,)))                   # compiles: never a sample
+    f._sample_every, f.calls = 2, 0
+    with _profiled(tmp_path):
+        for _ in range(4):
+            with trace_span("llm_engine.tick_dispatch", live=1):
+                f(jnp.ones((3,)))
+    ev = _host_events(str(tmp_path), ("jit.wall_sample",
+                                      "llm_engine.tick_dispatch"))
+    samples = [e for e in ev if e[0] == "jit.wall_sample"]
+    holders = [e for e in ev if e[0] == "llm_engine.tick_dispatch"]
+    assert len(samples) == 2 and len(holders) == 4
+    for s in samples:
+        assert s[3]["fn"] == "trace_names_sampled"
+        assert any(h[1] <= s[1] and s[2] <= h[2] for h in holders)
+
+
+@pytest.mark.parametrize("name, want", [
+    ("tick_readback_ms", 0.25), ("tick_launch_notify_ms", 1.5),
+    ("tick_host_ms", 2.0), ("engine_idle_share", None)])
+def test_tick_gap_readers_load_and_read(monkeypatch, name, want):
+    """The four readers of PR 34 (`benchmarks/layer_metrics/`, on
+    `benchmarks/tick_gap.py`), loaded by path as the harness loads them,
+    on a trace built here: two quiet ticks of 10 ms whose executions
+    start 0.5 ms after their dispatch and are known to the host 1 ms
+    after their end, the device's clock 2 ms behind the host's, then an
+    empty engine for 2.5 ms.  A program without the spans reads None."""
+    import types
+
+    mod = _bench_reader(monkeypatch, name)
+    import program_spans as PS
+    import tick_gap as TG
+    import trace_reduce as TR
+
+    assert TG.program_writes("llm_engine.idle")      # this tree's engine
+    us, skew = 1_000, 2_000_000
+    spans, mods = [], []
+    for t in (0, 12_000 * us):
+        spans += [("llm_engine.step", t, 11_950 * us, {}),
+                  ("llm_engine.ctrl", t, 10 * us, {}),
+                  ("llm_engine.admit", t + 10 * us, 10 * us,
+                   {"admitted": "0"}),
+                  ("llm_engine.tick_dispatch", t + 20 * us, 300 * us,
+                   {"live": "1"}),
+                  ("llm_engine.tick_wait", t + 320 * us, 11_450 * us, {}),
+                  ("llm_engine.tick_ready", t + 320 * us, 11_200 * us, {}),
+                  ("llm_engine.tick_readback", t + 11_520 * us, 250 * us,
+                   {"bytes": "4"}),
+                  ("llm_engine.emit", t + 11_770 * us, 100 * us, {}),
+                  ("llm_engine.gauges", t + 11_870 * us, 80 * us, {})]
+        mods.append(("jit_llm_engine_tick(1)", t + 520 * us - skew,
+                     10_000 * us))
+    spans.append(("llm_engine.idle", 24_000 * us, 2_500 * us,
+                  {"queued": "0", "live": "0"}))
+    loop = {"steps": 4, "ticks": 2, "calls": {}, "seconds": {
+        "ctrl": 0.0002, "tick_dispatch": 0.002, "tick_readback": 0.001,
+        "emit": 0.0005, "gauges": 0.0003, "tick_ready": 0.02, "idle": 1.0}}
+    engine = types.SimpleNamespace(stats=lambda: {"loop": loop})
+    run = {"window": (-skew, 26_500 * us), "records": {"recs": [
+        types.SimpleNamespace(handle=types.SimpleNamespace(engine=engine))]},
+        "trace": TR.Trace({"/device:TPU:0": {
+            TR.MODULE_LINE: mods,
+            TR.OPS_LINE: [("op", s, d) for _, s, d in mods]}}, []),
+        "program": PS.Program(sorted(spans, key=lambda s: (s[1], -s[2])),
+                              [])}
+    if name == "engine_idle_share":
+        # the reported window (first span's start to the last one's end,
+        # 0 to 26.5 ms) holds 2 ms of device idle between the two ticks
+        # and 5.98 after the second; 2.5 of them under the idle span
+        want = 100.0 * 2.5 / (2.0 + 5.98)
+    assert mod.read(run) == pytest.approx(want)
+    bare = {"window": run["window"], "trace": run["trace"], "records": {},
+            "program": PS.Program([s for s in spans if s[0] not in (
+                "llm_engine.tick_ready", "llm_engine.tick_readback",
+                "llm_engine.idle")], [])}
+    monkeypatch.setattr(TG, "program_writes", lambda span: False)
+    assert mod.read(bare) is None
+
+
 @pytest.mark.parametrize("case, want", [
     ("landed", 3.0),            # landings of 2, 3 and 40 ms: the median
     ("no_admission", 0.0),      # a window of ticks alone still reports
@@ -168,18 +363,9 @@ def test_spill_land_reader_reports_every_window(monkeypatch, case, want):
     """`benchmarks/layer_metrics/spill_land_ms.py`: every traced window
     of a program that lands its spills gives a number (the driver holds
     the change to that), and the parent's gives none."""
-    import importlib.util
-
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks")
-    monkeypatch.syspath_prepend(bench)
+    mod = _bench_reader(monkeypatch, "spill_land_ms")
     import program_spans as PS
 
-    spec = importlib.util.spec_from_file_location(
-        "lm_spill_land_ms", os.path.join(bench, "layer_metrics",
-                                         "spill_land_ms.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
     assert mod._program_lands()             # this tree writes mod.SPAN
     ms = 1_000_000
     spans = [("llm_engine.step", 0, 100 * ms, {}),
