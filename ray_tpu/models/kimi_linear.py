@@ -361,13 +361,8 @@ def decode_step_paged(params, pools, tables, tokens, positions,
     no row, goes through no expert and keeps its state.  Returns
     (logits [B, V], pools, counts, state)."""
     c = config
-    pool = pools["latent"]
-    bs = pool.shape[2]
     B = tokens.shape[0]
-    phys = tables[jnp.arange(B), positions // bs]
-    if active is not None:
-        phys = jnp.where(active, phys, pool.shape[1])
-    cache = LM._PagedDecode(pool, tables, phys, positions % bs)
+    cache = LM._PagedDecode(pools["latent"], tables, positions, active)
     rec = _Step(state, active)
     x, routed = _stack(c, params, tokens[:, None], positions[:, None],
                        cache, rec, live=None if active is None
@@ -403,4 +398,5 @@ _SERVING = ServingFns(
     prefill=prefill_paged, decode=decode_step_paged,
     head_weight=LM.lm_head_weight, init_counts=init_counts,
     init_slot_state=init_slot_state,
+    paged_attention=LM.serving_paged_attention,
     grouped_matmul=serving_grouped_path)

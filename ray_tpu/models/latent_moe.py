@@ -23,7 +23,8 @@ interface:
   unrolled: a layer's expert weights are then buffers of their own that
   the grouped products read in place (a `lax.scan` over stacked weights
   copies each layer's slice out first), and the paged pool is written
-  and gathered at a static layer index, in place.
+  and read (by `ops/paged_attention.py`'s kernel through the block
+  table, or gathered) at a static layer index, in place.
 - **One definition of a layer** (`_layer`) over three caches: none
   (`forward`: scoring and tests), history + write-back (`prefill_paged`:
   bucketed and chunked prefill with a prefix history), paged decode
@@ -47,6 +48,7 @@ from ray_tpu.models.moe import (
     dropless_moe, serving_grouped_path, sigmoid_bias_top_k,
 )
 from ray_tpu.models.serving import ServingFns
+from ray_tpu.ops import paged_attention as paged
 
 
 @dataclasses.dataclass(frozen=True)
@@ -198,6 +200,25 @@ def attend_expanded(c: LatentMoEConfig, wkv_b, q_nope, q_rope, rows, qpos):
     return out.reshape(B, -1, c.n_heads * v)
 
 
+def _absorbed_query(c: LatentMoEConfig, w, q_nope, q_rope, row: int):
+    """Each head's query as a cache row is laid: `wkv_b`'s key part
+    folded into q_nope (w [rank, H, n + v]), then the shared-key
+    channels, then zeros up to `row`.  [B, Q, H, row]."""
+    q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope,
+                       w[..., :c.qk_nope_head_dim])
+    return jnp.concatenate(
+        [q_lat, q_rope, jnp.zeros(q_lat.shape[:-1] + (
+            row - c.kv_lora_rank - c.qk_rope_head_dim,), q_lat.dtype)],
+        axis=-1)
+
+
+def _absorbed_output(c: LatentMoEConfig, w, o_lat):
+    """o_lat [B, Q, H, rank], the weighted sum of the latents, through
+    `wkv_b`'s value part: [B, Q, H * v]."""
+    out = jnp.einsum("bqhr,rhv->bqhv", o_lat, w[..., c.qk_nope_head_dim:])
+    return out.reshape(out.shape[0], -1, c.n_heads * c.v_head_dim)
+
+
 def attend_absorbed(c: LatentMoEConfig, wkv_b, q_nope, q_rope, rows, qpos):
     """Decode form: `wkv_b`'s key part goes into the query and its value
     part onto the output, so the rows are read as they lie: multi-query
@@ -205,24 +226,21 @@ def attend_absorbed(c: LatentMoEConfig, wkv_b, q_nope, q_rope, rows, qpos):
     on it.  The weighted sum runs over whole rows and the columns past
     the latent are dropped from its result: a quarter more
     multiply-adds, and no copy of the rows without them."""
-    B, K, row = rows.shape
-    n, v, rank = c.qk_nope_head_dim, c.v_head_dim, c.kv_lora_rank
-    w = wkv_b.reshape(rank, c.n_heads, n + v)
-    q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, w[..., :n])
-    q_row = jnp.concatenate(
-        [q_lat, q_rope, jnp.zeros(q_lat.shape[:-1] + (
-            row - rank - c.qk_rope_head_dim,), q_lat.dtype)], axis=-1)
+    _, K, row = rows.shape
+    rank = c.kv_lora_rank
+    w = wkv_b.reshape(rank, c.n_heads, -1)
+    q_row = _absorbed_query(c, w, q_nope, q_rope, row)
     scores = jnp.einsum("bqhr,bkr->bhqk", q_row, rows).astype(
         jnp.float32) * (1.0 / math.sqrt(c.qk_head_dim))
     probs = _masked_softmax(scores, qpos, K, q_nope.dtype)
     o_lat = jnp.einsum("bhqk,bkr->bqhr", probs, rows)[..., :rank]
-    out = jnp.einsum("bqhr,rhv->bqhv", o_lat, w[..., n:])
-    return out.reshape(B, -1, c.n_heads * v)
+    return _absorbed_output(c, w, o_lat)
 
 
 # ---------------------------------------------------------------------------
 # The cache interface: where a layer's new rows go and which rows its
-# queries see.  `update(l, new [B, S, cache_row])` -> rows [B, K, cache_row].
+# queries see.  `update(l, new [B, S, cache_row])` -> rows [B, K, cache_row]
+# (what the cache's own `attend` reads).
 # ---------------------------------------------------------------------------
 
 class _NoCache:
@@ -251,25 +269,59 @@ class _History:
 
 
 class _PagedDecode:
-    """One new row a sequence: written into the pool at its block-table
-    position, then every sequence's rows gathered through the tables."""
-    attend = staticmethod(attend_absorbed)
+    """One new row a sequence at `positions` [B]: written into the pool
+    [L, NB, bs, cache_row] at its block-table position (a physical
+    block out of bounds, so dropped, for a dead slot), then attended in
+    the absorbed form by one of two paths, chosen by backend and shape
+    alone (`ops.paged_attention.engages`, as `models/llama.py::_Paged`):
 
-    def __init__(self, pool, tables, phys, off):
-        self.pool = pool
-        self.tables, self.phys, self.off = tables, phys, off
+    - the kernel: `paged_latent_attention` reads the live blocks of the
+      live sequences out of the whole pool through the block table, at
+      (l, table[b, j]); nothing is gathered.  Its scalars (`plan`) are
+      made here, once a program;
+    - the gather (everywhere else, and the reference the kernel is
+      tested against): every sequence's padded view `pool[l, tables]`,
+      read by `attend_absorbed` under the position mask.
+
+    Keeps the updated pool."""
+
+    def __init__(self, pool, tables, positions, active):
+        NB, bs = pool.shape[1:3]
+        phys = tables[jnp.arange(positions.shape[0]), positions // bs]
+        if active is not None:
+            phys = jnp.where(active, phys, NB)
+        self.pool, self.tables = pool, tables
+        self.phys, self.off = phys, positions % bs
+        self.plan = None
+        if paged.engages(pool):
+            with jax.named_scope("attn"), jax.named_scope("paged"):
+                self.plan = paged.plan(tables, positions, active, bs)
 
     def update(self, l, new):
+        """Writes the rows; returns what `attend` reads: the gathered
+        rows, or the layer whose blocks the kernel walks."""
         B, nb = self.tables.shape
         pool = self.pool
         with jax.named_scope("kv_write"):
             pool = pool.at[l, self.phys, self.off].set(
                 new[:, 0].astype(pool.dtype))
-        with jax.named_scope("kv_gather"):
-            rows = pool[l, self.tables].reshape(
-                B, nb * pool.shape[2], pool.shape[3]).astype(new.dtype)
         self.pool = pool
-        return rows
+        if self.plan is not None:
+            return l
+        with jax.named_scope("kv_gather"):
+            return pool[l, self.tables].reshape(
+                B, nb * pool.shape[2], pool.shape[3]).astype(new.dtype)
+
+    def attend(self, c, wkv_b, q_nope, q_rope, rows, qpos):
+        if self.plan is None:
+            return attend_absorbed(c, wkv_b, q_nope, q_rope, rows, qpos)
+        w = wkv_b.reshape(c.kv_lora_rank, c.n_heads, -1)
+        q_row = _absorbed_query(c, w, q_nope, q_rope, self.pool.shape[-1])
+        with jax.named_scope("paged"):
+            o_lat = paged.paged_latent_attention(
+                q_row[:, 0], self.pool, rows, self.plan,
+                scale=1.0 / math.sqrt(c.qk_head_dim), rank=c.kv_lora_rank)
+        return _absorbed_output(c, w, o_lat[:, None])
 
 
 # ---------------------------------------------------------------------------
@@ -410,13 +462,7 @@ def decode_step_paged(params, pools, tables, tokens, positions,
     (logits [B, V], pools, counts): tokens routed to each expert of each
     expert layer, the distinct experts touched summed over those layers,
     and 1 for the tick."""
-    pool = pools["latent"]
-    bs = pool.shape[2]
-    B = tokens.shape[0]
-    phys = tables[jnp.arange(B), positions // bs]
-    if active is not None:
-        phys = jnp.where(active, phys, pool.shape[1])
-    cache = _PagedDecode(pool, tables, phys, positions % bs)
+    cache = _PagedDecode(pools["latent"], tables, positions, active)
     x, routed = _stack(config, params, tokens[:, None], positions[:, None],
                        cache, live=None if active is None
                        else active[:, None])
@@ -434,9 +480,14 @@ def init_counts(config: LatentMoEConfig) -> Dict[str, jax.Array]:
             "ticks": jnp.zeros((), jnp.int32)}
 
 
+def serving_paged_attention(pools) -> str:
+    return "kernel" if paged.engages(pools["latent"]) else "gather"
+
+
 _SERVING = ServingFns(
     name="latent attention + dropless experts (models/latent_moe.py)",
     init_params=init_params, init_pool=init_paged_pool,
     prefill=prefill_paged, decode=decode_step_paged,
     head_weight=lm_head_weight, init_counts=init_counts,
+    paged_attention=serving_paged_attention,
     grouped_matmul=serving_grouped_path)

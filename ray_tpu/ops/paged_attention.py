@@ -1,12 +1,18 @@
 """Decode attention over the paged KV pool, read through the block table.
 
-The dense decoder's decode tick and speculative verify hold their keys
-and values in ONE stacked pool a kind, `[L, NB, bs, kvH, D]`, and a block
-table a sequence.  `paged_attention` is a Pallas TPU kernel that reads
-the live blocks of every live sequence straight out of that pool, where
-it lies in HBM, and nothing else: no dense `[B, S_pad, kvH, D]` view is
-built, a dead slot costs nothing, and a table entry past a sequence's
-length is never dereferenced (stale and out-of-range ids live there).
+The decode tick (and the dense decoder's speculative verify) holds its
+keys and values in ONE stacked pool a kind, `[L, NB, bs, kvH, D]`, and a
+block table a sequence.  `paged_attention` is a Pallas TPU kernel that
+reads the live blocks of every live sequence straight out of that pool,
+where it lies in HBM, and nothing else: no dense `[B, S_pad, kvH, D]`
+view is built, a dead slot costs nothing, and a table entry past a
+sequence's length is never dereferenced (stale and out-of-range ids
+live there).  Three forms of one walk: two pools (K, V), one pool of
+K ‖ V rows, and the latent pool's one row a token
+(`paged_latent_attention`); `plan`, the work list, the copy loop and the
+online softmax are the same code, and what differs is static: how many
+buffers, how the query is laid into a row, the scale, whether heads are
+grouped, and which lanes of the value product are kept.
 
 How it walks.  `plan` (plain XLA, once a program, outside the layer
 scan) cuts every live sequence into chunks of `chunk` blocks and lays
@@ -41,12 +47,27 @@ value product runs over the whole row, and the V lanes of the result
 are the output.  The same loop, the same masks, one buffer instead of
 two.
 
-Precision: bf16 operands, float32 scores, softmax and accumulation (the
-gather path it replaces rounds the scores to bf16 first).
+The latent pool.  A latent-attention model (`models/latent_moe.py`,
+and `models/kimi_linear.py`'s MLA layers) keeps ONE row a token a
+layer, latent ‖ shared key ‖ zeros up to whole lanes (`[L, NB, bs, W]`,
+W = 640 for a latent of 512 and a key of 64), which every query head
+reads: one KV head, so a block is `[bs, W]` as it lies and no score is
+masked for its group.  The caller absorbs `wkv_b`'s key part into the
+query and lays it as a row is laid; `paged_latent_attention` scores it
+against whole rows (x the caller's scale, 1/sqrt(qk_head_dim): the
+row's width says nothing about it), and multiplies the probabilities
+with the rows' latent lanes alone where those are whole lane tiles (4
+of 5), which is the output: `attend_absorbed`'s arithmetic
+(`models/latent_moe.py`) without its padded `[B, S_pad, W]` view.  With
+32 query rows against a chunk of 512 x 640 the products, not the
+copies, are about half of its time (PERF.md section 6, PR 36).
 
-The gather + `models.llama._decode_attention` stays as the reference and
-as the path wherever the kernel does not engage (`engages`): off TPU,
-and at shapes that do not tile.
+Precision: bf16 operands, float32 scores, softmax and accumulation (the
+gather paths it replaces round the scores to bf16 first).
+
+The gather + `models.llama._decode_attention` (or + `attend_absorbed`)
+stays as the reference and as the path wherever the kernel does not
+engage (`engages`): off TPU, and at shapes that do not tile.
 """
 
 from __future__ import annotations
@@ -69,15 +90,17 @@ CHUNK_BLOCKS = 32
 
 
 def engages(pool: jax.Array) -> bool:
-    """Whether `_Paged.attend` runs the kernel over `pool` [L, NB, bs,
-    kvH, D]: `ops.attention`'s rule for the backend (a TPU always, off
-    TPU only when a test forces the interpreter), and shapes that tile:
-    a bf16 pool whose rows are whole lanes (D % 128) and whose blocks
-    are whole packed tiles (bs * kvH % 16), so that a block is a
+    """Whether a decode tick runs the kernel over `pool` [L, NB, bs,
+    kvH, D] (or [L, NB, bs, D]: the latent pool, one KV head):
+    `ops.attention`'s rule for the backend (a TPU always, off TPU only
+    when a test forces the interpreter), and shapes that tile: a bf16
+    pool whose rows are whole lanes (D % 128) and whose blocks are
+    whole packed tiles (bs * kvH % 16), so that a block is a
     `[bs * kvH, D]` matrix as it lies in HBM and the flat view of the
     pool is no copy (PERF.md section 6, PR 31: compiled and run at 1,
     4, 8 and 32 KV heads)."""
-    _, _, bs, kvh, d = pool.shape
+    bs, d = pool.shape[2], pool.shape[-1]
+    kvh = pool.shape[3] if pool.ndim == 5 else 1
     tiles = (pool.dtype == jnp.bfloat16 and d % 128 == 0
              and (bs * kvh) % 16 == 0)
     return tiles and (_attention._on_tpu()
@@ -120,14 +143,16 @@ def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
             tab_ref, q_ref, *refs, nb, bs, kvh, n_heads, n_q, chunk,
             scale):
     # refs: the pools in HBM, the output, a chunk buffer a pool, the
-    # semaphores.  Two pools (K, V) or one whose rows are K ‖ V.
+    # semaphores.  Two pools (K, V) or one whose rows hold both (K ‖ V,
+    # or the latent row).  The output keeps the value product's first
+    # `d` lanes: all of a row, or the latent's whole lane tiles.
     n_pools = (len(refs) - 2) // 2
     o_ref, sems = refs[n_pools], refs[-1]
     pools = tuple(zip(refs[:n_pools], refs[n_pools + 1:-1]))
     kbuf, vbuf = pools[0][1], pools[-1][1]
     layer, n_items = layer_ref[0], n_ref[0]
     rows = bs * kvh                         # of a block in the flat view
-    qh, d = q_ref.shape[1:]
+    qh, d = o_ref.shape[1:]
     width = chunk * rows
 
     # A dead slot's row, and a chunk's rows past its last live block:
@@ -160,7 +185,9 @@ def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
     # row = query index * H + head; column = token in chunk * kvH + group
     row = lax.broadcasted_iota(jnp.int32, (qh, width), 0)
     col = lax.broadcasted_iota(jnp.int32, (qh, width), 1)
-    own_group = (row % n_heads) // (n_heads // kvh) == col % kvh
+    # one KV head (the latent pool): every query head reads every row
+    own_group = None if kvh == 1 else \
+        (row % n_heads) // (n_heads // kvh) == col % kvh
     token = col // kvh
     row1 = lax.broadcasted_iota(jnp.int32, (qh, 1), 0)
 
@@ -183,7 +210,9 @@ def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
         for t in range(1, n_q):
             qpos = jnp.where(row1 >= t * n_heads, qpos_ref[b * n_q + t],
                              qpos)
-        seen = own_group & (token <= qpos - j * (chunk * bs))
+        seen = token <= qpos - j * (chunk * bs)
+        if own_group is not None:
+            seen = own_group & seen
 
         s = lax.dot_general(
             q_ref[b], kbuf[slot], (((1,), (1,)), ((), ())),
@@ -193,8 +222,9 @@ def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
         l = alpha * l + p.sum(axis=-1, keepdims=True)
+        values = vbuf[slot] if d == vbuf.shape[-1] else vbuf[slot, :, :d]
         acc = alpha * acc + jnp.dot(
-            p.astype(vbuf.dtype), vbuf[slot],
+            p.astype(vbuf.dtype), values,
             preferred_element_type=jnp.float32)
 
         @pl.when((j + 1) * (chunk * bs) >= len_ref[b])
@@ -206,6 +236,43 @@ def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
     lax.fori_loop(0, n_items, step, (
         jnp.full((qh, 1), _MASK, jnp.float32),
         jnp.zeros((qh, 1), jnp.float32), jnp.zeros((qh, d), jnp.float32)))
+
+
+def _call(q, pools, layer, scalars, *, kvh, n_heads, n_q, scale,
+          out_width, chunk):
+    """The kernel over `pools` (flat: [L, NB, bs * kvH, W] each) for q
+    [B, Q * H, W], laid as the first pool's rows are: [B, Q * H,
+    out_width], the first lanes of the value product over the last
+    pool's rows."""
+    B, qh, W = q.shape
+    rows = pools[0].shape[2]
+    nb = scalars[-1].shape[0] // B
+    chunk = min(chunk, nb)
+    if scalars[1].shape[0] != B * -(-nb // chunk):
+        raise ValueError(
+            f"the scalars were planned for another chunk than {chunk}")
+    interpret = not _attention._on_tpu()
+    buf = pltpu.VMEM((2, chunk * rows, W), pools[0].dtype)
+    kernel = functools.partial(
+        _kernel, nb=nb, bs=rows // kvh, kvh=kvh, n_heads=n_heads, n_q=n_q,
+        chunk=chunk, scale=scale)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1 + len(scalars),
+            grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[buf] * len(pools)
+            + [pltpu.SemaphoreType.DMA((2, len(pools)))]),
+        out_shape=jax.ShapeDtypeStruct((B, qh, out_width), q.dtype),
+        interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 2 ** 20),
+        name="paged_attention",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), *scalars, q, *pools)
 
 
 def paged_attention(q: jax.Array, k_pool: jax.Array,
@@ -225,34 +292,32 @@ def paged_attention(q: jax.Array, k_pool: jax.Array,
     pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
     if v_pool is None:
         q = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
-    nb = scalars[-1].shape[0] // B
-    chunk = min(chunk, nb)
-    if scalars[1].shape[0] != B * -(-nb // chunk):
-        raise ValueError(
-            f"the scalars were planned for another chunk than {chunk}")
-    rows = bs * kvh
-    interpret = not _attention._on_tpu()
-    buf = pltpu.VMEM((2, chunk * rows, W), k_pool.dtype)
-    kernel = functools.partial(
-        _kernel, nb=nb, bs=bs, kvh=kvh, n_heads=H, n_q=Q, chunk=chunk,
-        scale=1.0 / math.sqrt(D))
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1 + len(scalars),
-            grid=(1,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)]
-            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-            scratch_shapes=[buf] * len(pools)
-            + [pltpu.SemaphoreType.DMA((2, len(pools)))]),
-        out_shape=jax.ShapeDtypeStruct((B, Q * H, W), q.dtype),
-        interpret=interpret,
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=64 * 2 ** 20),
-        name="paged_attention",
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), *scalars,
-      q.reshape(B, Q * H, W),
-      *(pool.reshape(L, NB, rows, W) for pool in pools))
+    out = _call(
+        q.reshape(B, Q * H, W),
+        [pool.reshape(L, NB, bs * kvh, W) for pool in pools], layer,
+        scalars, kvh=kvh, n_heads=H, n_q=Q, scale=1.0 / math.sqrt(D),
+        out_width=W, chunk=chunk)
     return out.reshape(B, Q, H, W)[..., W - D:]
+
+
+# Jitted so that the call sites of an unrolled layer loop (one a latent
+# layer, the layer index an argument) trace and lower the kernel ONCE:
+# eight of them cost every process start half a second of lowering; the
+# compiled tick is the same, instruction names apart.
+@functools.partial(jax.jit, static_argnames=("scale", "rank", "chunk"))
+def paged_latent_attention(q_row: jax.Array, pool: jax.Array,
+                           layer: jax.Array, scalars, *, scale: float,
+                           rank: int,
+                           chunk: int = CHUNK_BLOCKS) -> jax.Array:
+    """Absorbed latent attention: q_row [B, H, W], each head's absorbed
+    query laid as a cache row is (latent ‖ shared key ‖ zeros), against
+    layer `layer` of the latent pool [L, NB, bs, W], one row a token
+    that every head reads: [B, H, rank], the probability-weighted sum
+    of the rows' first `rank` lanes, zeros for a dead sequence.  Scores
+    are q_row . row x `scale`; sequence b sees rows at positions <=
+    qpos[b].  The value product runs over the latent's lanes alone
+    where they are whole lane tiles (512 of 640), else over the row."""
+    return _call(q_row, [pool], layer, scalars, kvh=1,
+                 n_heads=q_row.shape[1], n_q=1, scale=scale,
+                 out_width=rank if rank % 128 == 0 else pool.shape[-1],
+                 chunk=chunk)[..., :rank]

@@ -335,8 +335,18 @@ def _serving_cell(cell, one_chip):
         key=_placed(jax.eval_shape(lambda: jax.random.key(0)), one_chip))
 
 
+def _slot_state(eng, one_chip):
+    """The model's per-slot state as shapes on the chip, in a list (none
+    for a model that keeps none)."""
+    model = eng._model
+    return [_placed(jax.eval_shape(lambda: model.init_slot_state(
+        eng.model_config, eng.config.num_slots)), one_chip)] \
+        if model.init_slot_state else []
+
+
 def _compiled_insert(eng, one_chip):
-    """`LLMEngine._insert_fn` at the cell's largest bucket."""
+    """`LLMEngine._insert_fn` at the cell's largest bucket, the slots'
+    state donated beside the pools where the model keeps one."""
     from ray_tpu.serve.llm.engine import LLMEngine
 
     def arg(dtype, *shape):
@@ -344,14 +354,15 @@ def _compiled_insert(eng, one_chip):
 
     ec = eng.config
     B, Pb = ec.num_slots, ec.prefill_buckets[-1]
+    state = _slot_state(eng, one_chip)
     return jax.jit(
         functools.partial(LLMEngine._insert_fn, eng),
-        donate_argnums=(1, 2, 3)).lower(
+        donate_argnums=(1, 2, 3) + ((12,) if state else ())).lower(
         eng.params, eng.pools, arg(jnp.int32, B), arg(jnp.int32, B),
         arg(jnp.int32, ec.max_blocks_per_slot), arg(jnp.int32),
         arg(jnp.int32, Pb), arg(jnp.int32),
         arg(jnp.int32, Pb // ec.kv_block_size), arg(jnp.int32),
-        arg(jnp.float32), eng.key).compile()
+        arg(jnp.float32), eng.key, *state).compile()
 
 
 def _results(text):
@@ -391,10 +402,9 @@ def _compiled_cell_tick(eng, one_chip):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     B = ec.num_slots
-    extra = [_placed(jax.eval_shape(init), one_chip) for init in (
-        model.init_counts and (lambda: model.init_counts(mc)),
-        model.init_slot_state and (lambda: model.init_slot_state(mc, B)))
-        if init]
+    extra = ([_placed(jax.eval_shape(lambda: model.init_counts(mc)),
+                      one_chip)] if model.init_counts else []) \
+        + _slot_state(eng, one_chip)
     return jax.jit(
         functools.partial(LLMEngine._tick_fn, eng),
         donate_argnums=(1, 3, 4) + ((9,) if model.init_slot_state else ())
@@ -442,30 +452,76 @@ def test_benchmark_decode_tick_keeps_one_kv_pool(one_chip, on_tpu):
     assert m.temp_size_in_bytes < 0.13 * GIB
 
 
-@pytest.mark.parametrize("program", [
-    "assistant-decode-moe tick", "agent-decode-hybrid tick",
-    "chat-decode insert"])
-def test_paged_attention_leaves_the_other_programs_as_they_were(
-        one_chip, on_tpu, monkeypatch, program):
-    """The kernel is `_Paged.attend`'s alone.  The latent models' ticks
-    (their own `_PagedDecode`) and the dense model's insert (`_History`)
-    compile for v5e to the same text, metadata apart, whether the
-    selector answers as on the chip or is taken away: the text the
-    parent compiled (PERF.md section 6, PR 31, has that comparison)."""
+@pytest.mark.parametrize("cell", [
+    "assistant-decode-moe", "agent-decode-hybrid", "chat-decode"])
+def test_paged_attention_leaves_the_inserts_as_they_were(
+        one_chip, on_tpu, monkeypatch, cell):
+    """The kernel is the decode tick's (`_Paged.attend`, and since PR 36
+    the latent models' `_PagedDecode`).  The inserts attend through
+    `_History` and compile for v5e to the same text, metadata apart,
+    whether the selector answers as on the chip or is taken away: the
+    text the parent compiled (PERF.md section 6, PRs 31 and 36, have
+    that comparison)."""
     from ray_tpu.ops import paged_attention
 
-    cell, kind = program.split()
-
     def compiled():
-        eng = _serving_cell(cell, one_chip)
-        return _without_metadata((
-            _compiled_cell_tick if kind == "tick" else _compiled_insert)(
-                eng, one_chip).as_text())
+        return _without_metadata(_compiled_insert(
+            _serving_cell(cell, one_chip), one_chip).as_text())
 
     with_kernel = compiled()
     monkeypatch.setattr(paged_attention, "engages", lambda pool: False)
     assert compiled() == with_kernel
     assert "paged_attention" not in with_kernel
+
+
+@pytest.mark.parametrize("cell, pool, gathered", [
+    ("assistant-decode-moe", (8, 8192, 16, 640), (16384, 16, 640)),
+    ("agent-decode-hybrid", (2, 32768, 16, 640), (65536, 16, 640))])
+def test_latent_ticks_read_the_pool_through_the_block_table(
+        one_chip, on_tpu, monkeypatch, cell, pool, gathered):
+    """The two latent families' ticks at their cells' geometry (64 x
+    4096 over 8192 blocks; 128 x 8192 over 32768): `paged_attention`
+    answers "kernel", the tick holds one kernel call a latent layer, no
+    instruction has the gathered view's shape (`pool[l, tables]`: 0.21
+    and 0.84 GB a layer on the gather path) or the padded rows', and
+    none copies, slices or re-stacks the whole pool (1.34 GB): the
+    Python layer loop writes a row in place and hands the kernel the
+    pool as it lies.  Against the same tick with the selector taken
+    away (the parent's program) the temporaries fall from 0.33 to 0.02
+    GiB and from 1.31 to 0.30; `test_*_cell_programs_fit_one_v5e` holds
+    tick and insert to the chip's memory."""
+    from ray_tpu.ops import paged_attention
+
+    eng = _serving_cell(cell, one_chip)
+    ec, latent = eng.config, eng.pools["latent"]
+    assert latent.shape == pool
+    assert eng._model.paged_attention(eng.pools) == "kernel"
+    compiled = _compiled_cell_tick(eng, one_chip)
+    text = compiled.as_text()
+    assert text.count("paged_attention") >= pool[0]
+    results = _results(text)
+    padded = (ec.num_slots, ec.max_seq_len, pool[3])
+    assert gathered == (ec.num_slots * ec.max_blocks_per_slot,) + pool[2:]
+    assert not [op for op, shapes in results
+                if shapes & {gathered, padded}]
+    made = {op for op, shapes in results if pool in shapes}
+    assert "scatter" in made and "parameter" in made        # parsed
+    moved = [(op, shapes) for op, shapes in results
+             if op in ("copy", "copy-start", "copy-done", "dynamic-slice",
+                       "dynamic-update-slice", "gather")
+             and shapes & {pool, pool[1:]}]
+    assert not moved, moved
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= math.prod(pool) * 2     # in place
+
+    monkeypatch.setattr(paged_attention, "engages", lambda pool: False)
+    assert eng._model.paged_attention(eng.pools) == "gather"
+    parent = _compiled_cell_tick(eng, one_chip)
+    assert any(gathered in shapes for _, shapes in _results(parent.as_text()))
+    # the temporaries fall by most of one layer's gathered view (0.99
+    # and 0.81 of it)
+    assert m.temp_size_in_bytes + 0.75 * math.prod(gathered) * 2 \
+        < parent.memory_analysis().temp_size_in_bytes
 
 
 @pytest.mark.parametrize("program", ["chat-decode tick", "train step"])
@@ -586,35 +642,18 @@ def test_hybrid_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     (donated, static layer index), and arguments + temporaries fit HBM.
     These readings sized the configuration's depth and the cell's
     slots and pool."""
-    from ray_tpu.serve.llm.engine import LLMEngine
-
     eng = _serving_cell("agent-decode-hybrid", one_chip)
     ec, mc, model, published = (eng.config, eng.model_config, eng._model,
                                 eng.published)
-    params, pools, key = eng.params, eng.pools, eng.key
+    pools = eng.pools
     assert (published["num_hidden_layers"], published["hidden_size"],
             published["num_experts"], published["vocab_size"],
             mc.n_experts, mc.n_kda_layers, mc.n_mla_layers) \
         == (8, 2304, 64, 40960, 256, 6, 2)
-    state = _placed(jax.eval_shape(
-        lambda: model.init_slot_state(mc, ec.num_slots)), one_chip)
-
-    def arg(dtype, *shape):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    B, nb = ec.num_slots, ec.max_blocks_per_slot
-    assert model.grouped_matmul(mc, B) == "kernel"
-    if program == "tick":
-        compiled = _compiled_cell_tick(eng, one_chip)
-    else:
-        Pb = ec.prefill_buckets[-1]
-        compiled = jax.jit(
-            functools.partial(LLMEngine._insert_fn, eng),
-            donate_argnums=(1, 2, 3, 12)).lower(
-            params, pools, arg(jnp.int32, B), arg(jnp.int32, B),
-            arg(jnp.int32, nb), arg(jnp.int32), arg(jnp.int32, Pb),
-            arg(jnp.int32), arg(jnp.int32, Pb // ec.kv_block_size),
-            arg(jnp.int32), arg(jnp.float32), key, state).compile()
+    state, = _slot_state(eng, one_chip)
+    assert model.grouped_matmul(mc, ec.num_slots) == "kernel"
+    compiled = (_compiled_cell_tick if program == "tick"
+                else _compiled_insert)(eng, one_chip)
     _grouped_products_are_the_kernel(compiled.as_text(), mc.n_moe_layers)
     m = compiled.memory_analysis()
     kept = sum(math.prod(x.shape) * x.dtype.itemsize
@@ -638,12 +677,10 @@ def test_conv_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     layer and no `ragged-dot`, the pool (2048 B a token a layer) AND
     the slots' tails are updated in place, and arguments + temporaries
     fit HBM."""
-    from ray_tpu.serve.llm.engine import LLMEngine
-
     eng = _serving_cell("compose-decode-conv-moe", one_chip)
     ec, mc, model, published = (eng.config, eng.model_config, eng._model,
                                 eng.published)
-    params, pools, key = eng.params, eng.pools, eng.key
+    pools = eng.pools
     assert (published["num_hidden_layers"], published["hidden_size"],
             published["num_experts"], published["vocab_size"],
             mc.n_conv_layers, mc.n_attn_layers, mc.n_moe_layers, mc.head_dim,
@@ -652,12 +689,7 @@ def test_conv_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     assert model.grouped_matmul(mc, ec.num_slots) == "kernel"
     kv = pools["kv"]
     assert math.prod(kv.shape[3:]) * kv.dtype.itemsize == 2048
-    state = _placed(jax.eval_shape(
-        lambda: model.init_slot_state(mc, ec.num_slots)), one_chip)
-
-    def arg(dtype, *shape):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
+    state, = _slot_state(eng, one_chip)
     B, nb = ec.num_slots, ec.max_blocks_per_slot
     if program == "tick":
         compiled = _compiled_cell_tick(eng, one_chip)
@@ -669,14 +701,7 @@ def test_conv_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
         padded = (B, nb * ec.kv_block_size) + kv.shape[3:]
         assert not any(padded in shapes for _, shapes in _results(text))
     else:
-        Pb = ec.prefill_buckets[-1]
-        compiled = jax.jit(
-            functools.partial(LLMEngine._insert_fn, eng),
-            donate_argnums=(1, 2, 3, 12)).lower(
-            params, pools, arg(jnp.int32, B), arg(jnp.int32, B),
-            arg(jnp.int32, nb), arg(jnp.int32), arg(jnp.int32, Pb),
-            arg(jnp.int32), arg(jnp.int32, Pb // ec.kv_block_size),
-            arg(jnp.int32), arg(jnp.float32), key, state).compile()
+        compiled = _compiled_insert(eng, one_chip)
     _grouped_products_are_the_kernel(compiled.as_text(), mc.n_moe_layers)
     m = compiled.memory_analysis()
     kept = sum(math.prod(x.shape) * x.dtype.itemsize

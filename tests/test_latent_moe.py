@@ -378,3 +378,114 @@ def test_unsupported_paths_refuse_by_name(model, what, refusal):
             cls = getattr(LLMServer, "func_or_class", LLMServer)
             cls(model_config=mc, engine_config=EngineConfig(**ec),
                 quantize="int8")
+
+
+# ------------------------- (f) the decode tick's two attention paths
+
+KERNEL_BS = 16    # rows a block: a whole packed tile, so the kernel engages
+
+
+def _tiling():
+    """bf16, a latent of one lane tile in a row of two (128 ‖ 8 ‖ zeros
+    to 256): the shapes `ops.paged_attention.engages` asks for."""
+    from ray_tpu.models import latent_moe as LM
+
+    c = LM.LatentMoEConfig.tiny(kv_lora_rank=128)
+    assert c.cache_row == 256 and c.dtype == jnp.bfloat16
+    return LM, c
+
+
+def _drawn_at_a_tenth(LM, c, seed):
+    """Matrices at 0.1, not `init_params`' 0.02: at 0.02 the tiny
+    model's best two logits lie closer than bf16 rounding moves them
+    and greedy tokens say nothing about the path."""
+    return jax.tree.map(lambda x: 5 * x if x.ndim >= 2 else x,
+                        LM.init_params(c, jax.random.key(seed)))
+
+
+def test_decode_step_agrees_on_both_attention_paths(monkeypatch):
+    """`decode_step_paged` over a pool with history, one slot dead: the
+    kernel's logits against the gather path's, the same greedy tokens,
+    the same rows written at the same places."""
+    from ray_tpu.ops import attention, paged_attention
+
+    LM, c = _tiling()
+    params = _drawn_at_a_tenth(LM, c, 0)
+    rng = np.random.default_rng(5)
+    B, nb, NB = 3, c.max_seq_len // KERNEL_BS, 30
+    pools = jax.tree.map(
+        lambda x: jnp.asarray(rng.standard_normal(x.shape) * 0.5, x.dtype),
+        LM.init_paged_pool(c, NB, KERNEL_BS))
+    tables = jnp.asarray(rng.permutation(NB)[:B * nb].reshape(B, nb),
+                         jnp.int32)
+    pos = jnp.asarray([37, 0, 90], jnp.int32)
+    active = jnp.asarray([True, False, True])
+    tok = jnp.asarray(rng.integers(0, c.vocab_size, B), jnp.int32)
+    step = lambda: jax.jit(lambda: LM.decode_step_paged(      # noqa: E731
+        params, pools, tables, tok, pos, c, active))()
+    out = {}
+    for path, force in (("gather", False), ("kernel", True)):
+        monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", force)
+        assert paged_attention.engages(pools["latent"]) == force
+        assert LM._SERVING.paged_attention(pools) == path
+        out[path] = step()
+    live = np.asarray(active)
+    a, b = (np.asarray(out[path][0], np.float32)[live]
+            for path in ("kernel", "gather"))
+    assert np.abs(a - b).max() <= 0.05 * np.abs(b).max()
+    np.testing.assert_array_equal(a.argmax(-1), b.argmax(-1))
+    wrote = {path: np.asarray(out[path][1]["latent"], np.float32)
+             for path in out}
+    before = np.asarray(pools["latent"], np.float32)
+    for path in wrote:              # one row a live slot a layer, no more
+        changed = (wrote[path] != before).any(-1)
+        assert changed.sum() == c.n_layers * live.sum()
+    # layer 0 writes the same rows on both paths; deeper layers' rows
+    # carry the attention's rounding
+    np.testing.assert_array_equal(wrote["kernel"][0], wrote["gather"][0])
+    np.testing.assert_allclose(wrote["kernel"], wrote["gather"], atol=0.05)
+    assert out["kernel"][2]["expert_tokens"].sum() \
+        == out["gather"][2]["expert_tokens"].sum()
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_engine_serves_the_same_greedy_tokens_on_both_attention_paths(
+        monkeypatch, seed):
+    """Three prompts of different lengths beside each other, a free
+    slot: the tokens through the kernel equal the gather path's, and
+    `stats()` names the path.  The two paths' logits differ by a
+    hundredth of their size, each as far from float32 throughout as the
+    other (the kernel keeps scores in float32, the gather path rounds
+    them), so the seeds are ones at which no served token's best two
+    logits lie closer than that: a flip at another seed is that
+    rounding, which `test_decode_step_agrees_on_both_attention_paths`
+    bounds, and not a wrong row."""
+    from ray_tpu.ops import attention
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine, Request
+
+    LM, c = _tiling()
+    params = _drawn_at_a_tenth(LM, c, seed)
+    rng = np.random.default_rng(seed)
+    prompts = [rng.integers(1, c.vocab_size, n).tolist() for n in (5, 19, 40)]
+
+    def serve():
+        eng = LLMEngine(params, c, EngineConfig(
+            num_slots=4, max_seq_len=128, prefill_buckets=(16, 32, 64),
+            kv_block_size=KERNEL_BS, prefix_cache=False))
+        handles = [eng.submit(Request(prompt=p, max_tokens=6))
+                   for p in prompts]
+        for _ in range(200):
+            if all(h.finished_at is not None for h in handles):
+                break
+            eng.step()
+        return [h.tokens for h in handles], eng.stats()
+
+    gather_tokens, gather_stats = serve()
+    monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", True)
+    kernel_tokens, kernel_stats = serve()
+    assert kernel_tokens == gather_tokens
+    assert all(len(t) == 6 for t in kernel_tokens)
+    assert kernel_stats["paged_attention"] == "kernel"
+    assert gather_stats["paged_attention"] == "gather"
+    assert 0 < kernel_stats["live_rows"] == gather_stats["live_rows"] \
+        < kernel_stats["padded_rows"]

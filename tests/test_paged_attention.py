@@ -10,12 +10,15 @@ a bf16 output of size 1 (2 ** -6 = 0.0156); each is within 0.012 of the
 same attention computed in float32 throughout, the kernel the closer.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import kimi_linear, latent_moe as LM
 from ray_tpu.models.llama import (
     LlamaConfig, _decode_attention, decode_step_paged, init_paged_kv_cache,
     init_params, verify_kv_paged,
@@ -262,3 +265,139 @@ def test_engine_serves_the_same_greedy_tokens_on_both_paths(
         assert 0 < stats["live_rows"] < stats["padded_rows"]
         assert stats["padded_rows"] % (4 * 128) == 0
     assert kernel_stats["live_rows"] == gather_stats["live_rows"]
+
+
+# ----------------------------------------------------- the latent pool's form
+
+# 32 heads on one row a token: latent 512 ‖ shared key 64 ‖ zeros to 640
+LAT = LM.LatentMoEConfig.tiny(
+    n_heads=32, kv_lora_rank=512, qk_nope_head_dim=16, qk_rope_head_dim=64,
+    v_head_dim=16, dtype=jnp.bfloat16)
+# dead; ends inside a block; inside a chunk; past one chunk; a full row
+LAT_LENGTHS = (0, 5, 2 * BS + 3, CHUNK * BS + 9, NB_ROW * BS)
+
+
+def _latent_case(seed, c=LAT):
+    """A latent pool whose pad lanes are zero as the model writes them,
+    one query a sequence on its last row, `wkv_b`, distinct blocks."""
+    rng = np.random.default_rng(seed)
+    B, NB = len(LAT_LENGTHS), len(LAT_LENGTHS) * NB_ROW + 4
+    real = c.kv_lora_rank + c.qk_rope_head_dim
+    pool = rng.standard_normal((L, NB, BS, c.cache_row))
+    pool[..., real:] = 0
+    n = c.qk_nope_head_dim
+    q_nope = jnp.asarray(rng.standard_normal((B, 1, c.n_heads, n)),
+                         jnp.bfloat16)
+    q_rope = jnp.asarray(
+        rng.standard_normal((B, 1, c.n_heads, c.qk_rope_head_dim)),
+        jnp.bfloat16)
+    wkv_b = jnp.asarray(rng.standard_normal(
+        (c.kv_lora_rank, c.n_heads * (n + c.v_head_dim)))
+        * c.kv_lora_rank ** -0.5, jnp.bfloat16)
+    tables = rng.permutation(NB - 4)[:B * NB_ROW].reshape(
+        B, NB_ROW).astype(np.int32)
+    lengths = np.asarray(LAT_LENGTHS)
+    qpos = np.maximum(lengths - 1, 0).astype(np.int32)
+    return (jnp.asarray(pool, jnp.bfloat16), q_nope, q_rope, wkv_b, tables,
+            qpos, lengths)
+
+
+def _latent_reference(pool, q_nope, q_rope, wkv_b, tables, qpos, c=LAT,
+                      dtype=jnp.bfloat16):
+    B, nb = tables.shape
+    rows = pool[LAYER][tables].reshape(B, nb * BS, c.cache_row)
+    return np.asarray(LM.attend_absorbed(
+        c, wkv_b.astype(dtype), q_nope.astype(dtype), q_rope.astype(dtype),
+        rows.astype(dtype), jnp.asarray(qpos)[:, None]), np.float32)
+
+
+def _latent_kernel(pool, q_nope, q_rope, wkv_b, tables, qpos, active,
+                   c=LAT, chunk=CHUNK):
+    scalars = pa.plan(jnp.asarray(tables), jnp.asarray(qpos),
+                      None if active is None else jnp.asarray(active),
+                      BS, chunk)
+    w = wkv_b.reshape(c.kv_lora_rank, c.n_heads, -1)
+    q_row = LM._absorbed_query(c, w, q_nope, q_rope, c.cache_row)
+    o_lat = pa.paged_latent_attention(
+        q_row[:, 0], pool, jnp.int32(LAYER), scalars,
+        scale=c.qk_head_dim ** -0.5, rank=c.kv_lora_rank, chunk=chunk)
+    return np.asarray(LM._absorbed_output(c, w, o_lat[:, None]),
+                      np.float32)
+
+
+@pytest.mark.parametrize("rank", [512, 96])
+def test_latent_kernel_matches_gather_and_attend_absorbed(rank):
+    """32 heads on rows of 640 (rank 512, shared key 64) and, with a
+    latent that is no whole number of lane tiles (96 + 64 in a row of
+    256), the value product over the whole row: every length class at
+    once, the score scaled by 1/sqrt(qk_head_dim), not by the row."""
+    c = LAT if rank == 512 else dataclasses.replace(LAT, kv_lora_rank=rank)
+    assert c.cache_row == (640 if rank == 512 else 256)
+    case = _latent_case(rank, c)
+    lengths = case[-1]
+    active = lengths > 0
+    got = _latent_kernel(*case[:-1], active, c=c)
+    want = _latent_reference(*case[:-1], c=c)
+    exact = _latent_reference(*case[:-1], c=c, dtype=jnp.float32)
+    assert got.shape == want.shape == (len(lengths), 1,
+                                       c.n_heads * c.v_head_dim)
+    assert not got[~active].any()               # a dead slot: zeros
+    assert np.abs(got[active] - want[active]).max() <= ATOL
+    assert (np.abs(got[active] - exact[active]).max()
+            <= np.abs(want[active] - exact[active]).max() + 2 ** -8)
+
+
+@pytest.mark.parametrize("how", ["out_of_range", "other_sequences",
+                                 "poisoned"])
+def test_latent_entries_past_a_length_change_nothing(how):
+    pool, *rest, tables, qpos, lengths = _latent_case(7)
+    nb_total = pool.shape[1]
+    pool = pool.at[:, nb_total - 1].set(jnp.nan)
+    active = lengths > 0
+    clean = _latent_kernel(pool, *rest, tables, qpos, active)
+    dirty = _latent_kernel(
+        pool, *rest, _spoil(tables, lengths, how, nb_total), qpos, active)
+    assert np.isfinite(dirty).all()
+    np.testing.assert_array_equal(dirty, clean)
+
+
+def test_latent_kernel_copies_only_live_blocks_once(monkeypatch):
+    """One copy a live block of a live sequence, at the layer asked
+    for; a dead slot copies nothing."""
+    pool, *rest, tables, qpos, lengths = _latent_case(11)
+    dirty = _spoil(tables, lengths, "out_of_range", pool.shape[1])
+    seen = []
+    block_copy = pa._block_copy
+
+    def recording(src, layer, phys, *more):
+        if not isinstance(phys, int):           # a start, not a wait
+            jax.debug.callback(
+                lambda l, p: seen.append((int(l), int(p))), layer, phys)
+        return block_copy(src, layer, phys, *more)
+
+    monkeypatch.setattr(pa, "_block_copy", recording)
+    # the entry is jitted: trace it anew with the recorder in, and
+    # leave no such trace behind for the next test of these shapes
+    pa.paged_latent_attention.clear_cache()
+    try:
+        _latent_kernel(pool, *rest, dirty, qpos, lengths > 0)
+        jax.effects_barrier()
+    finally:
+        pa.paged_latent_attention.clear_cache()
+    assert sorted(seen) == sorted(
+        (LAYER, int(tables[b, j])) for b, n in enumerate(lengths)
+        for j in range(-(-int(n) // BS)))
+
+
+def test_the_selector_reads_a_latent_pool_as_one_kv_head(monkeypatch):
+    pool = lambda bs, row, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        (2, 8, bs, row), dt)
+    assert not pa.engages(pool(16, 640))            # the CPU, not forced
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    assert pa.engages(pool(16, 640)) and pa.engages(pool(32, 128))
+    assert not pa.engages(pool(16, 576))            # no whole lane rows
+    assert not pa.engages(pool(8, 640))             # half a packed tile
+    assert not pa.engages(pool(16, 640, jnp.float32))
+    for fns in (LM._SERVING, kimi_linear._SERVING):
+        assert fns.paged_attention({"latent": pool(16, 640)}) == "kernel"
+        assert fns.paged_attention({"latent": pool(4, 128)}) == "gather"
