@@ -8,6 +8,31 @@ one `latent` row (latent ‖ rotary key) for latent attention.  The engine
 and the cache manager move whole blocks of every leaf (insert scatter,
 export, adopt, spill, promote) and never look inside a row.
 
+Leaves are of one of two KINDS.  `full` (every leaf of every model but
+one): a sequence holds a block for each `bs` of its rows, in a table as
+wide as `max_seq_len`, block `t // bs` for position t.  `window`
+(`window_kind(config) -> (W, leaf names)`, None for a model that has
+none): leaves of layers whose query at p reads the keys p - W + 1 .. p
+alone.  They have `[L', NBw, bs, ...]` blocks of their OWN, an allocator
+and a table of their own, and that table is a RING, `ring = (W + largest
+prefill bucket) / bs` blocks wide (never wider than `max_seq_len`):
+position t lives in `table_w[(t // bs) % ring]`, and a sequence holds
+`min(its blocks, ring)` of them whatever its length while its prompt
+goes in, and once it decodes the `W / bs + 1` that the window before a
+position straddles (`kv_cache.WindowRing.cover` gives the others back
+and hands the block that fell out of the window to the position that
+comes into it: the table stays `ring` wide).  A model with
+window leaves gets both tables wherever it got one:
+`init_pool(config, num_blocks, block_size, window_blocks=NBw)`,
+`decode(..., tables={"full": [B, nb], "window": [B, ring]}, ...)`, and
+in `prefill` the window leaves' history is the ring as it lies,
+`{leaf: [L', ring * bs, ...]}`, row r holding the position that is r
+modulo `ring * bs` (those of the last `ring * bs` positions before
+`start` that were written; a chunk needs the W before it).  Nothing
+that moves rows without knowing kinds (prefix reuse, spill, export,
+adopt, preemption, speculation) is offered for such a model; a prompt
+longer than a bucket goes into its slot chunk by chunk.
+
     init_pool(config, num_blocks, block_size) -> {leaf: [L, NB, bs, ...]}
     prefill(params, tokens [1, Pb], start, hist, config, n_real)
         -> (normed hidden [1, Pb, D], rows {leaf: [L, Pb, ...]})
@@ -74,6 +99,7 @@ class ServingFns(NamedTuple):
     head_weight: Callable[..., Any]
     init_counts: Optional[Callable[..., Any]] = None
     init_slot_state: Optional[Callable[..., Any]] = None
+    window_kind: Optional[Callable[..., Any]] = None
     quantize_int8: Optional[Callable[..., Any]] = None
     draft: Optional[DraftFns] = None
     verify: Optional[Callable[..., Any]] = None

@@ -62,6 +62,32 @@ of 5), which is the output: `attend_absorbed`'s arithmetic
 32 query rows against a chunk of 512 x 640 the products, not the
 copies, are about half of its time (PERF.md section 6, PR 36).
 
+Few KV heads.  A pool `[L, NB, bs, 4, 128]` is tiled (4, 128) by the
+TPU compiler, and an insert's scatter of whole blocks into it is then
+compiled as a re-tiling copy of the WHOLE pool in and another out
+(deviceless v5e compile, PERF.md section 6, PR 38).  A model with fewer
+than 8 KV heads keeps a token's KV heads SIDE BY SIDE in one row instead
+(`[L, NB, bs, kvH * D]`, 512 lanes at 4 heads of 128), and
+`paged_attention` reads such a pool as the latent pool is read: one
+"KV head" whose row every query head scores whole, a query laid into
+the lanes of its own group of a zero row (so `q_row . row` is `q . k` of
+that group), the value product over the whole row, and each head's
+output taken from its group's lanes.  As many multiplications as the
+group mask costs the `[bs, kvH, D]` form, no mask, and a block is a
+`[bs, kvH * D]` matrix as it lies.
+
+The window form.  A sliding-window layer's query at position p sees the
+keys p - W + 1 .. p alone, and its pool holds no more: the table a
+sequence is `ring` blocks wide and position t lives in
+`table[(t // bs) % ring]` (`serve/llm/engine.py` keeps it so).  `plan`
+and the kernel take `window=W`: a sequence's first chunk is the one that
+holds p - W + 1 (chunks stay aligned to absolute positions, so at most
+`(W - 2) // (chunk * bs) + 2` of them whatever the length), its copies
+start at the block that holds that key, the table is indexed modulo its
+width, and a key at or before p - W is masked beside one past p.  The
+same kernel, the same loop: what differs is in the scalars and in three
+static branches.  One query a sequence (no verify step over a window).
+
 Precision: bf16 operands, float32 scores, softmax and accumulation (the
 gather paths it replaces round the scores to bf16 first).
 
@@ -107,26 +133,47 @@ def engages(pool: jax.Array) -> bool:
                       or _attention.FORCE_PALLAS_INTERPRET)
 
 
+def _window_chunks(window: int, chunk: int, block_size: int) -> int:
+    """The most chunks of `chunk` blocks, aligned to absolute positions,
+    that `window` consecutive keys straddle."""
+    return (window - 2) // (chunk * block_size) + 2
+
+
 def plan(tables: jax.Array, qpos: jax.Array, active, block_size: int,
-         chunk: int = CHUNK_BLOCKS):
+         chunk: int = CHUNK_BLOCKS, window: Optional[int] = None):
     """The kernel's scalars, computed once a program: tables [B, nb];
     qpos [B] or [B, Q], the queries' absolute positions (the last is
     the largest); active [B] bool or None (all live).  A sequence's
     length is its last query's position + 1, or 0 when it is dead; its
     chunks are `chunk` blocks each; the work list is the chunks of all
     sequences end to end, (sequence, chunk index) an item, padded to
-    its static bound."""
+    its static bound.
+
+    `window`: the module docstring's window form.  tables [B, ring] is
+    a ring; a sequence's chunks run from the one that holds its first
+    visible key, length - window, under their ABSOLUTE indices."""
     B, nb = tables.shape
     chunk = min(chunk, nb)
+    span = chunk * block_size
     qpos = qpos.reshape(B, -1).astype(jnp.int32)
     lengths = qpos[:, -1] + 1
     if active is not None:
         lengths = jnp.where(active, lengths, 0)
-    n_chunks = -(-lengths // (chunk * block_size))
+    n_chunks = -(-lengths // span)
+    if window is None:
+        skipped, bound = 0, -(-nb // chunk)
+    else:
+        if qpos.shape[1] != 1:
+            raise ValueError("the window form takes one query a sequence")
+        skipped = jnp.maximum(lengths - window, 0) // span
+        n_chunks, bound = n_chunks - skipped, _window_chunks(
+            window, chunk, block_size)
     ends = jnp.cumsum(n_chunks)
-    item = jnp.arange(B * (-(-nb // chunk)), dtype=jnp.int32)
+    item = jnp.arange(B * bound, dtype=jnp.int32)
     seq = jnp.minimum((item[:, None] >= ends[None, :]).sum(-1), B - 1)
     first = (ends - n_chunks)[seq]
+    if window is not None:
+        first = first - skipped[seq]
     return (ends[-1:].astype(jnp.int32), seq.astype(jnp.int32),
             (item - first).astype(jnp.int32), lengths.astype(jnp.int32),
             qpos.reshape(-1), tables.reshape(-1).astype(jnp.int32))
@@ -141,7 +188,7 @@ def _block_copy(pool, layer, phys, buf, slot, t, rows, sem):
 
 def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
             tab_ref, q_ref, *refs, nb, bs, kvh, n_heads, n_q, chunk,
-            scale):
+            scale, window):
     # refs: the pools in HBM, the output, a chunk buffer a pool, the
     # semaphores.  Two pools (K, V) or one whose rows hold both (K ‖ V,
     # or the latent row).  The output keeps the value product's first
@@ -165,14 +212,23 @@ def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
         live = (len_ref[b] + bs - 1) // bs - j * chunk
         return b, j, jnp.minimum(live, chunk)
 
+    def first_key(b):               # of a window: the first one seen
+        return jnp.maximum(len_ref[b] - window, 0)
+
     def copies(i, slot, start):
         b, j, live = item_of(i)
+        # a window's first chunk begins at the block of its first key
+        lo = 0 if window is None else jnp.maximum(
+            first_key(b) // bs - j * chunk, 0)
 
-        @pl.loop(0, live)
+        @pl.loop(lo, live)
         def _(t):
             # only `start` reads the table: entries past `live` are
-            # never looked at
-            phys = tab_ref[b * nb + j * chunk + t] if start else 0
+            # never looked at (a window's table is a ring)
+            at = j * chunk + t
+            if window is not None:
+                at = at % nb
+            phys = tab_ref[b * nb + at] if start else 0
             for which, (pool, buf) in enumerate(pools):
                 copy = _block_copy(pool, layer, phys, buf, slot, t, rows,
                                    sems.at[slot, which])
@@ -201,7 +257,8 @@ def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
 
         copies(i, slot, False)
         b, j, _ = item_of(i)
-        fresh = j == 0
+        fresh = j == (0 if window is None
+                      else first_key(b) // (chunk * bs))
         m = jnp.where(fresh, _MASK, m)
         l = jnp.where(fresh, 0.0, l)
         acc = jnp.where(fresh, 0.0, acc)
@@ -211,6 +268,8 @@ def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
             qpos = jnp.where(row1 >= t * n_heads, qpos_ref[b * n_q + t],
                              qpos)
         seen = token <= qpos - j * (chunk * bs)
+        if window is not None:
+            seen = seen & (token > qpos - window - j * (chunk * bs))
         if own_group is not None:
             seen = own_group & seen
 
@@ -239,7 +298,7 @@ def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
 
 
 def _call(q, pools, layer, scalars, *, kvh, n_heads, n_q, scale,
-          out_width, chunk):
+          out_width, chunk, window=None):
     """The kernel over `pools` (flat: [L, NB, bs * kvH, W] each) for q
     [B, Q * H, W], laid as the first pool's rows are: [B, Q * H,
     out_width], the first lanes of the value product over the last
@@ -248,14 +307,16 @@ def _call(q, pools, layer, scalars, *, kvh, n_heads, n_q, scale,
     rows = pools[0].shape[2]
     nb = scalars[-1].shape[0] // B
     chunk = min(chunk, nb)
-    if scalars[1].shape[0] != B * -(-nb // chunk):
+    bound = -(-nb // chunk) if window is None else _window_chunks(
+        window, chunk, rows // kvh)
+    if scalars[1].shape[0] != B * bound:
         raise ValueError(
             f"the scalars were planned for another chunk than {chunk}")
     interpret = not _attention._on_tpu()
     buf = pltpu.VMEM((2, chunk * rows, W), pools[0].dtype)
     kernel = functools.partial(
         _kernel, nb=nb, bs=rows // kvh, kvh=kvh, n_heads=n_heads, n_q=n_q,
-        chunk=chunk, scale=scale)
+        chunk=chunk, scale=scale, window=window)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -277,7 +338,8 @@ def _call(q, pools, layer, scalars, *, kvh, n_heads, n_q, scale,
 
 def paged_attention(q: jax.Array, k_pool: jax.Array,
                     v_pool: Optional[jax.Array], layer: jax.Array,
-                    scalars, *, chunk: int = CHUNK_BLOCKS) -> jax.Array:
+                    scalars, *, chunk: int = CHUNK_BLOCKS,
+                    window: Optional[int] = None) -> jax.Array:
     """q [B, Q, H, D] (rotated) against layer `layer` of the stacked
     pools [L, NB, bs, kvH, D], through `scalars` = `plan(tables, qpos,
     active, bs, chunk)`: [B, Q, H, D], zeros for a dead sequence.  Query
@@ -286,8 +348,29 @@ def paged_attention(q: jax.Array, k_pool: jax.Array,
     its block addresses, never a slice of the pool.
 
     `v_pool=None`: `k_pool` [L, NB, bs, kvH, 2 D] holds K ‖ V a row (the
-    module's docstring); q and the result are still D wide."""
+    module's docstring); q and the result are still D wide.
+
+    Pools of FOUR axes, [L, NB, bs, kvH * D], hold a token's KV heads
+    side by side in one row (the module's docstring, "Few KV heads").
+
+    `window`: query b sees keys in (qpos[b] - window, qpos[b]] alone and
+    the table is a ring (`plan(..., window=window)` made the scalars)."""
     B, Q, H, D = q.shape
+    if k_pool.ndim == 4:
+        W = k_pool.shape[-1]
+        kvh = W // D
+        # head h of group g into lanes [g D, (g + 1) D) of a zero row
+        place = jnp.eye(kvh, dtype=q.dtype)
+        q_row = jnp.einsum("bqgrd,gk->bqgrkd",
+                           q.reshape(B, Q, kvh, H // kvh, D), place)
+        out = _call(
+            q_row.reshape(B, Q * H, W), [k_pool, v_pool], layer, scalars,
+            kvh=1, n_heads=H, n_q=Q, scale=1.0 / math.sqrt(D), out_width=W,
+            chunk=chunk, window=window)
+        return jnp.einsum(
+            "bqgrkd,gk->bqgrd",
+            out.reshape(B, Q, kvh, H // kvh, kvh, D), place).reshape(
+                B, Q, H, D)
     L, NB, bs, kvh, W = k_pool.shape
     pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
     if v_pool is None:
@@ -296,7 +379,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array,
         q.reshape(B, Q * H, W),
         [pool.reshape(L, NB, bs * kvh, W) for pool in pools], layer,
         scalars, kvh=kvh, n_heads=H, n_q=Q, scale=1.0 / math.sqrt(D),
-        out_width=W, chunk=chunk)
+        out_width=W, chunk=chunk, window=window)
     return out.reshape(B, Q, H, W)[..., W - D:]
 
 

@@ -13,6 +13,19 @@ spill down a memory hierarchy (HBM -> host RAM -> object store,
 `PromoteCostModel` says re-adopt beats re-prefill. See README "Serving
 LLMs" / "Disaggregated serving" / "KV memory hierarchy" for the
 design narrative, and PERF.md for what the benchmark's cells measure.
+
+Models the engine serves, each through `config.serving()`
+(models/serving.py) and each with a cell in the benchmark: the dense
+GQA decoder (`models/llama.py`), latent attention with routed and
+shared experts (`models/latent_moe.py`), recurrent delta-rule layers
+with a state by slot beside latent attention (`models/kimi_linear.py`),
+gated short convolutions beside GQA (`models/conv_moe.py`), and
+sliding-window beside full attention over two kinds of paged pool
+(`models/window_moe.py`: a table by position for the full layers, a
+ring of blocks for the window layers, `kv_cache.WindowRing`).  The last
+three stay in the slot they were admitted to: prefix reuse, spill,
+export / adopt, preemption and speculation are refused for them by
+name.
 """
 
 from ray_tpu.serve.llm.deployment import LLMServer, build_llm_app
@@ -25,7 +38,7 @@ from ray_tpu.serve.llm.engine import (
 )
 from ray_tpu.serve.llm.kv_cache import (
     BlockAllocator, KVPrefix, KVState, KVTierManager, PrefixCache,
-    PromoteCostModel, TierHit, stable_hash_prefix,
+    PromoteCostModel, TierHit, WindowRing, stable_hash_prefix,
 )
 from ray_tpu.serve.llm.router import LLMRouter, build_routed_llm_app
 
@@ -34,6 +47,6 @@ __all__ = [
     "KVImporter", "KVPrefix", "KVState", "KVTierManager", "LLMEngine",
     "LLMRouter", "LLMServer", "PrefillServer", "PrefixCache",
     "PromoteCostModel", "Request", "RequestHandle", "TierHit",
-    "build_disagg_llm_app", "build_llm_app", "build_routed_llm_app",
+    "WindowRing", "build_disagg_llm_app", "build_llm_app", "build_routed_llm_app",
     "stable_hash_prefix",
 ]

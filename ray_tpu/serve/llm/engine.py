@@ -91,6 +91,9 @@ class EngineConfig:
     # every slot can reach max_seq_len at once. Undersize it to
     # oversubscribe HBM: admission queues on exhaustion, never crashes.
     num_kv_blocks: Optional[int] = None
+    # Blocks of a model's WINDOW kind of pool leaves (models/serving.py;
+    # unused by a model that has none); None -> num_slots rings.
+    num_window_blocks: Optional[int] = None
     prefix_cache: bool = True       # prompt-prefix reuse
     # Speculative decoding (armed by constructing the engine with
     # draft_params/draft_config): the draft proposes
@@ -190,6 +193,8 @@ class EngineConfig:
                 f"blocks)")
         if self.num_kv_blocks is not None and self.num_kv_blocks < 1:
             raise ValueError("num_kv_blocks must be >= 1")
+        if self.num_window_blocks is not None and self.num_window_blocks < 1:
+            raise ValueError("num_window_blocks must be >= 1")
 
     @property
     def max_blocks_per_slot(self) -> int:
@@ -438,26 +443,53 @@ class LLMEngine:
             raise ValueError(
                 f"{model.name} has no speculative verify step")
         self._stateful = model.init_slot_state is not None
+        # (W, leaf names) of a model with a window kind of pool leaves
+        # (models/serving.py), else None
+        window = (model.window_kind(model_config)
+                  if model.window_kind else None)
+        self._window_leaves = window[1] if window else ()
+        # Such a sequence, like one with a state by slot, stays in the
+        # slot it was admitted to and is moved by nothing.
+        self._pinned = self._stateful or window is not None
         if c.prefix_cache:
-            self._refuse_if_stateful(
+            self._refuse_if_pinned(
                 "prefix reuse (and the spill that rides it; set "
                 "prefix_cache=False)", "a cached block")
 
         # Device state (fixed shapes for the engine's whole lifetime).
         from ray_tpu.serve.llm.kv_cache import (
-            BlockAllocator, KVTierManager, PrefixCache, PromoteCostModel)
+            BlockAllocator, KVTierManager, PrefixCache, PromoteCostModel,
+            WindowRing)
 
         # The pool: a flat dict of [L, NB, bs, ...] leaves that the
         # model names; the engine moves whole blocks of every leaf
         # and never looks inside a row.
-        self._cache = model.init_pool(
-            model_config, c.pool_blocks, c.kv_block_size)
+        bs = c.kv_block_size
+        if window is None:
+            self._cache = model.init_pool(model_config, c.pool_blocks, bs)
+        else:
+            # a ring holds the window before a chunk and the chunk
+            ring = min(-(-(window[0] + c.prefill_buckets[-1]) // bs),
+                       c.max_blocks_per_slot)
+            n_w = c.num_window_blocks or c.num_slots * ring
+            self._cache = model.init_pool(model_config, c.pool_blocks, bs,
+                                          window_blocks=n_w)
         # HBM bytes per block (every leaf's rows across all layers)
         # — the byte-accounting basis for allocator/prefix/tier stats.
-        block_bytes = sum(int(x.nbytes) for x in
-                          self._cache.values()) // c.pool_blocks
-        self._allocator = BlockAllocator(c.pool_blocks, c.kv_block_size,
+        block_bytes = sum(
+            int(x.nbytes) for name, x in self._cache.items()
+            if name not in self._window_leaves) // c.pool_blocks
+        self._allocator = BlockAllocator(c.pool_blocks, bs,
                                          block_bytes=block_bytes)
+        # The window kind's blocks and its ring of a table a slot; None
+        # for a model all of whose leaves are of the full kind.
+        self._ring = None
+        if window is not None:
+            self._ring = WindowRing(window[0], ring, BlockAllocator(
+                n_w, bs, block_bytes=sum(
+                    int(self._cache[name].nbytes)
+                    for name in self._window_leaves) // n_w), B,
+                lookahead=c.decode_block)
         self._prefix = (PrefixCache(self._allocator)
                         if c.prefix_cache else None)
         # Per-slot block tables (host copy is the truth; the device
@@ -512,6 +544,7 @@ class LLMEngine:
         self._completed = 0
         self._live_rows_sum = 0         # over all ticks: `_live_rows`
         self._padded_rows_sum = 0
+        self._live_kv_bytes_sum = 0     # a model with a window kind
         self._slot_reuses = 0
         self._cancelled: set = set()    # request ids, guarded by _lock
         self._admit_blocked = False     # interactive admission starved
@@ -683,13 +716,20 @@ class LLMEngine:
 
         c = self.model_config
         bs = self.config.kv_block_size
-        S_pad = self.config.max_blocks_per_slot * bs
         Pb = padded_suffix.shape[0]
-        # History view: this slot's dense [S_pad] gather of every leaf.
-        # Rows at and past hist_len are stale — masked inside the
-        # model's prefill.
-        hist = {name: pool[:, table_row].reshape(
-            (pool.shape[0], S_pad) + pool.shape[3:])
+
+        def of_kind(x, name):
+            # a model with a window kind hands a table row and block
+            # ids a kind (models/serving.py)
+            if self._ring is None:
+                return x
+            return x["window" if name in self._window_leaves else "full"]
+
+        # History view: this slot's dense [S_pad] gather of every leaf
+        # (a window leaf's: its ring as it lies). Rows at and past
+        # hist_len are stale — masked inside the model's prefill.
+        hist = {name: pool[:, of_kind(table_row, name)].reshape(
+            (pool.shape[0], -1) + pool.shape[3:])
             for name, pool in pools.items()}
         if state is None:
             hidden, rows = self._model.prefill(
@@ -704,7 +744,7 @@ class LLMEngine:
         # rows: {leaf: [L, Pb, ...]} -> whole blocks into the pool at
         # the slot's new physical ids (padding rows ride along; decode
         # overwrites each before attending).
-        pools = {name: pool.at[:, new_block_ids].set(
+        pools = {name: pool.at[:, of_kind(new_block_ids, name)].set(
             rows[name].astype(pool.dtype).reshape(
                 (pool.shape[0], Pb // bs, bs) + pool.shape[3:]))
             for name, pool in pools.items()}
@@ -827,10 +867,10 @@ class LLMEngine:
                 f"{request.slo!r}")
         chunked = request.chunked_prefill and P > top
         if request.prefill_only:
-            self._refuse_if_stateful("prefill_only", "an exported KVState")
+            self._refuse_if_pinned("prefill_only", "an exported KVState")
         handle = RequestHandle(next(self._ids), request)
         if chunked:
-            if self._prefix is None and not self._stateful:
+            if self._prefix is None and not self._pinned:
                 raise ValueError(
                     "chunked_prefill needs prefix_cache=True (chunks "
                     "hand off through the prefix cache)")
@@ -849,13 +889,20 @@ class LLMEngine:
         # submit — queuing it would deadlock admission forever.
         worst = max(self._blocks_needed(P, request.max_tokens),
                     self._bucket_for(min(P, top)) // c.kv_block_size)
-        if chunked and self._stateful:
+        if chunked and self._pinned:
             worst = self._chunk_blocks(handle)
         if worst > c.pool_blocks:
             raise ValueError(
                 f"request needs up to {worst} KV blocks but the "
                 f"pool only has {c.pool_blocks}; raise "
                 f"num_kv_blocks or lower max_tokens")
+        if self._ring is not None and self._ring.blocks_for(worst) \
+                > self._ring.allocator.num_blocks:
+            raise ValueError(
+                f"request needs {self._ring.blocks_for(worst)} blocks of "
+                f"the window kind but that pool only has "
+                f"{self._ring.allocator.num_blocks}; raise "
+                f"num_window_blocks")
         handle._engine = self
         self._capture_trace(handle)
         self._attach_meter(handle)
@@ -864,13 +911,19 @@ class LLMEngine:
         self._work.set()
         return handle
 
-    def _refuse_if_stateful(self, what: str, carrier: str) -> None:
-        """Whatever moves a sequence's rows without its per-slot state
-        would lose it: refused, by the model's name."""
+    def _refuse_if_pinned(self, what: str, carrier: str) -> None:
+        """Whatever moves a sequence's rows without its per-slot state,
+        or without telling a window kind's ring from a full kind's
+        table, would lose rows: refused, by the model's name."""
         if self._stateful:
             raise ValueError(
                 f"{self._model.name} keeps a state by slot that {carrier} "
                 f"does not carry: {what} is not offered")
+        if self._window_leaves:
+            raise ValueError(
+                f"{self._model.name} keeps its window layers' rows in a "
+                f"ring of blocks of their own kind that {carrier} does "
+                f"not carry: {what} is not offered")
 
     def _attach_meter(self, handle: RequestHandle) -> None:
         """Attach a cost meter (after _capture_trace: the meter is
@@ -904,7 +957,7 @@ class LLMEngine:
         from ray_tpu.serve.llm.kv_cache import KVState
 
         c = self.config
-        self._refuse_if_stateful("adopting one", "a KVState")
+        self._refuse_if_pinned("adopting one", "a KVState")
         if not isinstance(state, KVState):
             raise TypeError(f"expected KVState, got {type(state)!r}")
         state.validate()
@@ -1063,7 +1116,7 @@ class LLMEngine:
                 if chunk_budget == 0:
                     self._requeue(handle)
                     break
-                if self._stateful:
+                if self._pinned:
                     # first chunk: the request takes its slot and every
                     # block it will need now, and keeps them
                     slot = self._free.popleft()
@@ -1098,8 +1151,8 @@ class LLMEngine:
             slot = self._free.popleft()
             fresh = handle.kv_state is None
             t_admit = time.monotonic()
-            with trace_span("llm_engine.admit_one"):
-                ok = (self._admit_prefill(handle, slot) if fresh
+            with trace_span("llm_engine.admit_one") as sp:
+                ok = (self._admit_prefill(handle, slot, span=sp) if fresh
                       else self._admit_adopted(handle, slot))
             if not ok:
                 self._free.appendleft(slot)
@@ -1139,9 +1192,9 @@ class LLMEngine:
         st.handle = handle
 
     def _chunk_blocks(self, handle: RequestHandle) -> int:
-        """Blocks a chunked prompt of a model with per-slot state takes
-        with its first chunk: every position it can write, and the
-        whole bucket of its last chunk."""
+        """Blocks a chunked prompt of a model whose sequences stay in
+        their slot takes with its first chunk: every position it can
+        write, and the whole bucket of its last chunk."""
         req, bs = handle.request, self.config.kv_block_size
         P = len(req.prompt)
         start = handle._chunk_ends[-2] if len(handle._chunk_ends) > 1 else 0
@@ -1149,12 +1202,13 @@ class LLMEngine:
                    (start + self._bucket_for(P - start)) // bs)
 
     def _admit_chunk(self, handle: RequestHandle, slot: int) -> bool:
-        """The next chunk of a chunked prompt of a model with per-slot
-        state, into the slot the request keeps. The first chunk takes
-        the blocks (False, and nothing taken, when the pool cannot
-        cover them); a later one finds the rows and the state of the
-        chunks before it in the slot, so nothing is handed over and
-        nothing can be lost in between."""
+        """The next chunk of a chunked prompt of a model whose sequences
+        stay in their slot (a state by slot, a window kind of pool),
+        into the slot the request keeps. The first chunk takes the
+        blocks (False, and nothing taken, when a pool cannot cover
+        them); a later one finds the rows and the state of the chunks
+        before it in the slot, so nothing is handed over and nothing
+        can be lost in between."""
         import numpy as np
 
         req, c = handle.request, self.config
@@ -1163,9 +1217,10 @@ class LLMEngine:
         start = handle._chunk_ends[i - 1] if i else 0
         n = handle._chunk_ends[i] - start
         t_chunk = time.monotonic()
-        with trace_span("llm_engine.admit_one", chunk=1):
+        with trace_span("llm_engine.admit_one", chunk=1) as sp:
             if i == 0:
-                blocks = self._allocator.alloc(self._chunk_blocks(handle))
+                blocks = self._take_blocks(
+                    slot, self._chunk_blocks(handle), sp)
                 if blocks is None:
                     return False
                 self._tables[slot] = 0
@@ -1186,11 +1241,30 @@ class LLMEngine:
         handle._chunk_idx += 1
         return True
 
+    def _take_blocks(self, slot: int, n: int, span) -> Optional[List[int]]:
+        """`n` blocks of the full kind and, for a model with a window
+        kind, the ring's share of them into `slot`'s ring: all or
+        nothing (None). Says on `span` what a window model took."""
+        blocks = self._allocator.alloc(n)
+        if blocks is None or self._ring is None:
+            return blocks
+        if not self._ring.take(slot, n):
+            self._allocator.free(blocks)
+            return None
+        span.set_metadata(blocks_full=n,
+                          blocks_window=self._ring.blocks_for(n))
+        return blocks
+
     def _insert(self, slot: int, row, hist_len: int, padded, suffix_len: int,
                 scatter_ids, temperature: float) -> None:
         """Dispatch the insert program of `padded`'s bucket."""
         import numpy as np
 
+        if self._ring is not None:      # a table row and ids a kind
+            row = {"full": row, "window": self._ring.tables[slot].copy()}
+            scatter_ids = {"full": scatter_ids,
+                           "window": self._ring.block_ids(
+                               slot, hist_len, len(padded))}
         said = {"bucket": len(padded)}
         if self._stateful:      # what the chunk is, for the trace's readers
             said.update(tokens=int(suffix_len), state_in=int(hist_len > 0))
@@ -1206,7 +1280,7 @@ class LLMEngine:
 
     def _admit_prefill(self, handle: RequestHandle, slot: int,
                        upto: Optional[int] = None,
-                       throwaway: bool = False) -> bool:
+                       throwaway: bool = False, span=None) -> bool:
         """Block accounting + insert for one request. Returns
         False (nothing allocated, nothing inserted) when the pool can't
         cover it even after evicting cold prefix entries.
@@ -1292,7 +1366,7 @@ class LLMEngine:
             # and insert scatters write full blocks, and every written
             # block must be owned by this slot.
             n_new = max(need_total - n_hit, n_pro + bucket // bs)
-            new_blocks = self._allocator.alloc(n_new)
+            new_blocks = self._take_blocks(slot, n_new, span)
             if new_blocks is None and self._prefix is not None:
                 want = n_new - self._allocator.free_blocks
                 with trace_span("llm_engine.evict", blocks=want):
@@ -1462,6 +1536,8 @@ class LLMEngine:
             else:
                 self._allocator.free(self._slot_blocks[slot])
             self._slot_blocks[slot] = []
+            if self._ring is not None:
+                self._ring.release(slot)
         self._free.append(slot)
 
     def _emit(self, slot: int, token: int) -> None:
@@ -1729,7 +1805,7 @@ class LLMEngine:
         :meth:`call_on_scheduler` from anywhere else."""
         import numpy as np
 
-        self._refuse_if_stateful("export_prefix", "an exported block")
+        self._refuse_if_pinned("export_prefix", "an exported block")
         if self._prefix is None:
             return []
         c = self.config
@@ -1801,7 +1877,7 @@ class LLMEngine:
         preempt → resume cycle is token-invisible to the client."""
         st = self._slots[slot]
         handle = st.handle
-        self._refuse_if_stateful("preemption", "a checkpoint")
+        self._refuse_if_pinned("preemption", "a checkpoint")
         if handle is None:
             raise ValueError(f"slot {slot} is not live")
         handle.kv_state = self._export_state(slot)
@@ -1821,7 +1897,7 @@ class LLMEngine:
         full batch) never thrashes checkpoints."""
         with self._lock:
             waiting = len(self._queues["interactive"])
-        if not waiting or self._stateful:
+        if not waiting or self._pinned:
             self._preempt_gate.propose(0, 0)
             return
         batch_slots = [
@@ -2041,8 +2117,10 @@ class LLMEngine:
                 self._update_gauges()
             return bool(inserted) or did_cancel or did_ctrl
         live = np.nonzero(self._active)[0]
-        rows = self._live_rows(live)
-        with phase("llm_engine.tick_dispatch", live=len(live), rows=rows):
+        if self._ring is not None:
+            self._cover_rings(live)
+        with phase("llm_engine.tick_dispatch", live=len(live),
+                   **self._live_rows(live)):
             t_tick = self._loop.now
             self._loop.ticks += 1
             spec = self._spec_ready(live)
@@ -2051,7 +2129,7 @@ class LLMEngine:
             else:
                 (self._cache, self._tok, self._pos, self._key, out,
                  self._counters, *state) = self._jit_tick(
-                    self.params, self._cache, self._tables.copy(),
+                    self.params, self._cache, self._tick_tables(),
                     self._tok, self._pos, self._active.copy(),
                     self._temp.copy(), self._key, self._counters,
                     self._slot_state)
@@ -2107,20 +2185,56 @@ class LLMEngine:
         with self._loop.phase("llm_engine.tick_readback", bytes=nbytes):
             return [np.asarray(x) for x in outs]
 
-    def _live_rows(self, live) -> int:
-        """KV rows the tick about to go out has to read: the live slots'
-        prompt and emitted tokens, summed (the pending token's own row
-        among them), and counted beside the rows of the padded
-        [num_slots, max_seq_len] view over all ticks (`stats()`)."""
+    def _cover_rings(self, live) -> None:
+        """A model's window kind: each live slot's ring is brought to
+        cover the positions the tick about to go out writes, and no
+        more than the window before them (kv_cache.WindowRing.cover)."""
+        c = self.config
+        for slot in map(int, live):
+            h = self._slots[slot].handle
+            if h is not None:
+                # its rows, the pending token's own among them
+                n = min(len(h.request.prompt) + len(h.tokens),
+                        c.max_seq_len)
+                self._ring.cover(slot, n - 1, min(
+                    n - 2 + c.decode_block, c.max_seq_len - 1))
+
+    def _tick_tables(self):
+        """The block tables as the tick takes them: one, or one a kind
+        for a model with a window kind (models/serving.py)."""
+        if self._ring is None:
+            return self._tables.copy()
+        return {"full": self._tables.copy(),
+                "window": self._ring.tables.copy()}
+
+    def _live_rows(self, live) -> Dict[str, int]:
+        """`rows`: KV rows the tick about to go out has to read in a
+        layer that reads them all: the live slots' prompt and emitted
+        tokens, summed (the pending token's own row among them), and
+        counted beside the rows of the padded [num_slots, max_seq_len]
+        view over all ticks (`stats()`). For a model with a window kind
+        also `window_rows`, what a window layer has to read (a slot's
+        rows or the window, whichever is less); the bytes of both
+        kinds' blocks that the live slots hold are summed over all
+        ticks beside their rows (`stats()["kv"]["live_bytes"]`)."""
         S = self.config.max_seq_len
-        rows = 0
+        W = self._ring.window if self._ring is not None else S
+        rows = window_rows = 0
         for slot in live:
             h = self._slots[int(slot)].handle
             if h is not None:
-                rows += min(len(h.request.prompt) + len(h.tokens), S)
+                n = min(len(h.request.prompt) + len(h.tokens), S)
+                rows += n
+                window_rows += min(n, W)
         self._live_rows_sum += rows
         self._padded_rows_sum += self.config.num_slots * S
-        return rows
+        if self._ring is None:
+            return {"rows": rows}
+        self._live_kv_bytes_sum += sum(
+            len(self._slot_blocks[s]) * self._allocator.block_bytes
+            + len(self._ring.slot_blocks[s])
+            * self._ring.allocator.block_bytes for s in map(int, live))
+        return {"rows": rows, "window_rows": window_rows}
 
     def _credit_decode(self, live, dt: float) -> None:
         """Split one decode/verify tick's wall time evenly across the
@@ -2284,7 +2398,7 @@ class LLMEngine:
         self._loop = _LoopClock()
         import jax
 
-        if self._stateful:
+        if self._pinned:
             return                      # exports nothing (models/serving.py)
         for n in self.config.export_rows:       # one row alive at a time
             jax.block_until_ready(self._export_blocks([0] * n))
@@ -2355,8 +2469,14 @@ class LLMEngine:
             "loop": self._loop.stats(),
             "traces": traces,
             "trace_count": sum(traces.values()),
+            # the full kind's blocks; a model's window kind under
+            # "window" (models/serving.py), and the bytes of both kinds
+            # the live slots held, summed over all ticks as `live_rows`
             "kv": dict(self._allocator.stats(),
-                       block_size=self.config.kv_block_size),
+                       block_size=self.config.kv_block_size,
+                       **({} if self._ring is None
+                          else {"window": self._ring.stats(),
+                                "live_bytes": self._live_kv_bytes_sum})),
             "migration": {
                 "blocks": self._migrated_blocks,
                 "bytes": self._migrated_bytes,

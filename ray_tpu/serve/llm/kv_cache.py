@@ -55,8 +55,8 @@ from typing import (Any, Callable, Dict, List, Optional, Sequence, Tuple)
 
 __all__ = [
     "BlockAllocator", "KVPrefix", "KVState", "KVTierManager",
-    "PrefixCache", "PromoteCostModel", "TierHit", "hash_prefix",
-    "stable_hash_prefix",
+    "PrefixCache", "PromoteCostModel", "TierHit", "WindowRing",
+    "hash_prefix", "stable_hash_prefix",
 ]
 
 
@@ -291,6 +291,98 @@ class BlockAllocator:
             "used_bytes": self.used_bytes,
             "free_bytes": self.free_bytes,
         }
+
+
+class WindowRing:
+    """Host side of a model's WINDOW kind of pool leaves
+    (models/serving.py): blocks of their own (`allocator`), and a table
+    a slot that is a ring, `ring` blocks wide, in which the block of
+    position t is entry `(t // block_size) % ring`.  A sequence takes
+    `min(blocks, ring)` entries up front, as it takes the full kind's:
+    a chunk's insert finds the window before it and room for its own
+    rows.  Once its prompt is in, a sequence writes `lookahead`
+    positions a dispatch and reads `window` keys back from the first of
+    them: `keep` blocks at a time, whatever its length.  `cover` gives
+    the others back before the sequence's first decode dispatch and
+    from then on hands the block that fell out of the window to the
+    position that comes into it.  Scheduler thread only (the allocator
+    has its own lock)."""
+
+    def __init__(self, window: int, ring: int, allocator: BlockAllocator,
+                 num_slots: int, lookahead: int = 1):
+        import numpy as np
+
+        self.window, self.ring, self.allocator = window, ring, allocator
+        # blocks that positions first - window + 1 .. first + lookahead
+        # - 1 can straddle
+        self.keep = (window + lookahead - 2) // allocator.block_size + 2
+        self.tables = np.zeros((num_slots, ring), np.int32)
+        self.slot_blocks: List[List[int]] = [[] for _ in range(num_slots)]
+        # [lowest, highest] block, by position, of a slot `cover` trimmed
+        self._held: List[Optional[List[int]]] = [None] * num_slots
+
+    def blocks_for(self, n_blocks: int) -> int:
+        """Of a sequence that holds `n_blocks` of the full kind, at
+        admission."""
+        return min(n_blocks, self.ring)
+
+    def take(self, slot: int, n_blocks: int) -> bool:
+        """`blocks_for(n_blocks)` blocks into `slot`'s ring; False, and
+        nothing taken, when the allocator cannot cover them."""
+        blocks = self.allocator.alloc(self.blocks_for(n_blocks))
+        if blocks is None:
+            return False
+        self.tables[slot] = 0
+        self.tables[slot, :len(blocks)] = blocks
+        self.slot_blocks[slot] = blocks
+        self._held[slot] = None
+        return True
+
+    def cover(self, slot: int, first: int, last: int) -> None:
+        """Before a decode dispatch that writes `slot`'s positions
+        first .. last (at most `lookahead` of them; the prompt is in)
+        and reads the keys first - window + 1 .. last.  A slot that
+        holds more than `keep` blocks gives the others back, once; then
+        each block a dispatch newly writes is the one that fell out of
+        the window `keep` blocks before it."""
+        import numpy as np
+
+        bs, ring = self.allocator.block_size, self.ring
+        held = self._held[slot]
+        if held is None:
+            blocks = self.slot_blocks[slot]
+            if len(blocks) <= self.keep:
+                return          # a block for every position it will write
+            lo = max(first - self.window + 1, 0) // bs
+            if len(blocks) < ring:      # entries past them hold no block
+                lo = min(lo, len(blocks) - self.keep)
+            held = self._held[slot] = [lo, lo + self.keep - 1]
+            kept = self.tables[
+                slot, np.arange(lo, lo + self.keep) % ring].tolist()
+            self.slot_blocks[slot] = kept
+            self.allocator.free(sorted(set(blocks) - set(kept)))
+        while held[1] < last // bs:
+            block = self.tables[slot, held[0] % ring]
+            held[0] += 1
+            held[1] += 1
+            self.tables[slot, held[1] % ring] = block
+
+    def release(self, slot: int) -> None:
+        self.allocator.free(self.slot_blocks[slot])
+        self.slot_blocks[slot] = []
+
+    def block_ids(self, slot: int, start: int, n_rows: int):
+        """Physical blocks of positions start .. start + n_rows - 1
+        (whole blocks), through the ring (before `cover` trimmed it)."""
+        import numpy as np
+
+        bs = self.allocator.block_size
+        at = (start // bs + np.arange(n_rows // bs)) % self.ring
+        return self.tables[slot, at]
+
+    def stats(self) -> Dict[str, int]:
+        return dict(self.allocator.stats(), window=self.window,
+                    ring_blocks=self.ring, keep_blocks=self.keep)
 
 
 @dataclass

@@ -326,13 +326,30 @@ def _serving_cell(cell, one_chip):
                              compute_dtype="bfloat16",
                              param_dtype="bfloat16")
     model = mc.serving()
+    # a model with a window kind of pool leaves (models/serving.py): the
+    # ring's width and that pool's blocks, as `LLMEngine.__init__` has them
+    ring, leaves, extra = None, (), {}
+    if model.window_kind:
+        window, leaves = model.window_kind(mc)
+        ring = types.SimpleNamespace(ring=min(
+            -(-(window + ec.prefill_buckets[-1]) // ec.kv_block_size),
+            ec.max_blocks_per_slot))
+        extra = {"window_blocks": ec.num_window_blocks}
     return types.SimpleNamespace(
         _model=model, model_config=mc, config=ec, published=published,
+        _ring=ring, _window_leaves=leaves,
         params=_placed(jax.eval_shape(
             lambda: model.init_params(mc, jax.random.key(0))), one_chip),
         pools=_placed(jax.eval_shape(lambda: model.init_pool(
-            mc, ec.pool_blocks, ec.kv_block_size)), one_chip),
+            mc, ec.pool_blocks, ec.kv_block_size, **extra)), one_chip),
         key=_placed(jax.eval_shape(lambda: jax.random.key(0)), one_chip))
+
+
+def _by_kind(eng, full, window):
+    """An argument the engine hands a kind for a model with a window
+    kind of pool (`{"full": .., "window": ..}`), else the full kind's."""
+    return full if eng._ring is None else {"full": full,
+                                           "window": window(eng._ring.ring)}
 
 
 def _slot_state(eng, one_chip):
@@ -355,13 +372,15 @@ def _compiled_insert(eng, one_chip):
     ec = eng.config
     B, Pb = ec.num_slots, ec.prefill_buckets[-1]
     state = _slot_state(eng, one_chip)
+    ids = arg(jnp.int32, Pb // ec.kv_block_size)
     return jax.jit(
         functools.partial(LLMEngine._insert_fn, eng),
         donate_argnums=(1, 2, 3) + ((12,) if state else ())).lower(
         eng.params, eng.pools, arg(jnp.int32, B), arg(jnp.int32, B),
-        arg(jnp.int32, ec.max_blocks_per_slot), arg(jnp.int32),
+        _by_kind(eng, arg(jnp.int32, ec.max_blocks_per_slot),
+                 lambda ring: arg(jnp.int32, ring)), arg(jnp.int32),
         arg(jnp.int32, Pb), arg(jnp.int32),
-        arg(jnp.int32, Pb // ec.kv_block_size), arg(jnp.int32),
+        _by_kind(eng, ids, lambda ring: ids), arg(jnp.int32),
         arg(jnp.float32), eng.key, *state).compile()
 
 
@@ -409,7 +428,9 @@ def _compiled_cell_tick(eng, one_chip):
         functools.partial(LLMEngine._tick_fn, eng),
         donate_argnums=(1, 3, 4) + ((9,) if model.init_slot_state else ())
     ).lower(
-        eng.params, eng.pools, arg(jnp.int32, B, ec.max_blocks_per_slot),
+        eng.params, eng.pools,
+        _by_kind(eng, arg(jnp.int32, B, ec.max_blocks_per_slot),
+                 lambda ring: arg(jnp.int32, B, ring)),
         arg(jnp.int32, B), arg(jnp.int32, B), arg(jnp.bool_, B),
         arg(jnp.float32, B), eng.key, *extra).compile()
 
@@ -709,6 +730,62 @@ def test_conv_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     print(program, "GiB", _hbm_gib(compiled), "temp",
           m.temp_size_in_bytes / GIB, "args", m.argument_size_in_bytes / GIB)
     assert m.alias_size_in_bytes >= kept                # both in place
+    assert _hbm_gib(compiled) < V5E_HBM_GIB - 0.5
+
+
+@pytest.mark.parametrize("program", ["tick", "insert"])
+def test_window_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
+    """The engine's decode tick and its largest insert at the geometry of
+    the benchmark's `mixed-decode-window-moe` cell (window and full GQA
+    layers over two kinds of paged pool, a table of 1152 blocks and a
+    ring of 256 a slot, 128 experts beside a shared one and a head
+    200,192 wide, at Trinity-Mini's published widths; the depth, slots,
+    row length, buckets and both pools its files state): they compile
+    for v5e, `paged_attention` and `grouped_matmul` answer "kernel", the
+    tick holds one paged-attention call a layer (one of them the full
+    form) and builds no padded view of either pool, tick and insert
+    three `ops.grouped_matmul` calls an expert layer and no `ragged-dot`,
+    both kinds of pool (2048 B a token a layer, a token's four KV heads
+    side by side in one row) are updated in place and NOT copied to be
+    re-tiled (as `[bs, 4, 128]` blocks each insert copied every pool in
+    and out: 2.39 GiB of temporaries),
+    the insert at 2048 over an 18,432-row history keeps its temporaries
+    under 1.5 GiB (float32 scores over the whole history would be 4.5),
+    and arguments + temporaries fit HBM."""
+    eng = _serving_cell("mixed-decode-window-moe", one_chip)
+    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+                                eng.published)
+    pools = eng.pools
+    assert (published["num_hidden_layers"], published["hidden_size"],
+            published["num_experts"], published["vocab_size"],
+            mc.n_window_layers, mc.n_full_layers, mc.n_moe_layers,
+            mc.window, mc.n_kv_heads, ec.num_slots, ec.max_seq_len,
+            eng._ring.ring) == (5, 2048, 128, 200192, 4, 1, 4, 2048, 4, 64,
+                                18432, 256)
+    assert model.paged_attention(pools) == "kernel"
+    assert model.grouped_matmul(mc, ec.num_slots) == "kernel"
+    assert pools["k"].shape == (1, ec.pool_blocks, 16, 4 * 128)
+    assert pools["v_w"].shape == (4, ec.num_window_blocks, 16, 4 * 128)
+    B = ec.num_slots
+    if program == "tick":
+        compiled = _compiled_cell_tick(eng, one_chip)
+        text = compiled.as_text()
+        assert text.count("paged_attention") >= mc.n_layers
+        assert text.count('custom_call_target="tpu_custom_call"') \
+            >= 3 * mc.n_moe_layers + mc.n_layers
+        row = pools["k"].shape[3:]
+        padded = {(B, n * ec.kv_block_size) + row
+                  for n in (ec.max_blocks_per_slot, eng._ring.ring)}
+        assert not any(padded & shapes for _, shapes in _results(text))
+    else:
+        compiled = _compiled_insert(eng, one_chip)
+    _grouped_products_are_the_kernel(compiled.as_text(), mc.n_moe_layers)
+    m = compiled.memory_analysis()
+    kept = sum(math.prod(x.shape) * x.dtype.itemsize for x in pools.values())
+    print(program, "GiB", _hbm_gib(compiled), "temp",
+          m.temp_size_in_bytes / GIB, "args", m.argument_size_in_bytes / GIB)
+    assert m.alias_size_in_bytes >= kept                # both in place
+    assert m.temp_size_in_bytes < 1.5 * GIB
     assert _hbm_gib(compiled) < V5E_HBM_GIB - 0.5
 
 

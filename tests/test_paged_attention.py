@@ -401,3 +401,134 @@ def test_the_selector_reads_a_latent_pool_as_one_kv_head(monkeypatch):
     for fns in (LM._SERVING, kimi_linear._SERVING):
         assert fns.paged_attention({"latent": pool(16, 640)}) == "kernel"
         assert fns.paged_attention({"latent": pool(4, 128)}) == "gather"
+
+
+# ---------------------------------------------------------------------------
+# The window form: a ring of a table, the keys p - W + 1 .. p
+# ---------------------------------------------------------------------------
+
+W_KEYS, RING = 48, 5        # 3 blocks of window in a ring of 5 (80 rows)
+# dead; under, at and just over the window; past the ring, where the
+# table wraps; past it twice over, at a block's first and last row
+W_LENGTHS = (0, 1, 47, 48, 49, 64, 81, 96, 177, 192)
+
+
+def _window_case(heads, kv_heads, seed):
+    """Pools whose ring of a sequence holds what a stream of that length
+    leaves there: position t in block `table[(t // BS) % RING]`, older
+    rows overwritten, rows the stream never reached random."""
+    rng = np.random.default_rng(seed)
+    B, NB = len(W_LENGTHS), len(W_LENGTHS) * RING + 3
+    pool = lambda: jnp.asarray(                               # noqa: E731
+        rng.standard_normal((L, NB, BS, kv_heads, D)), jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((B, 1, heads, D)), jnp.bfloat16)
+    tables = rng.permutation(NB - 3)[:B * RING].reshape(
+        B, RING).astype(np.int32)
+    lengths = np.asarray(W_LENGTHS)
+    qpos = np.maximum(lengths - 1, 0).astype(np.int32)[:, None]
+    return q, pool(), pool(), tables, qpos, lengths
+
+
+def _window_reference(q, k_pool, v_pool, tables, qpos, window):
+    """The masked gather (`models/window_moe.py::_Paged`'s other path):
+    row r of the gathered ring is the last position <= p that is r
+    modulo the ring's rows."""
+    from ray_tpu.models.window_moe import _masked_attention, _seen
+
+    B, ring = tables.shape
+    kvh = k_pool.shape[3]
+    rows = ring * BS
+    k = k_pool[LAYER][tables].reshape(B, rows, kvh, D)
+    v = v_pool[LAYER][tables].reshape(B, rows, kvh, D)
+    pos = jnp.asarray(qpos)
+    kpos = pos - (pos - jnp.arange(rows)[None]) % rows
+    return np.asarray(_masked_attention(q, k, v, _seen(pos, kpos, window)),
+                      np.float32)
+
+
+def _window_kernel(q, k_pool, v_pool, tables, qpos, active, window, chunk):
+    scalars = pa.plan(jnp.asarray(tables), jnp.asarray(qpos),
+                      jnp.asarray(active), BS, chunk, window=window)
+    return np.asarray(pa.paged_attention(
+        q, k_pool, v_pool, jnp.int32(LAYER), scalars, chunk=chunk,
+        window=window), np.float32)
+
+
+@pytest.mark.parametrize("chunk", [2, 5])
+@pytest.mark.parametrize("heads,kv_heads", [(8, 4), (16, 8)])
+def test_window_form_matches_the_masked_gather(heads, kv_heads, chunk):
+    """Lengths under, at and over the window and past the ring, at 4 and
+    8 KV heads, the chunk smaller than the window and as wide as the
+    ring."""
+    q, kp, vp, tables, qpos, lengths = _window_case(heads, kv_heads, 11)
+    active = lengths > 0
+    got = _window_kernel(q, kp, vp, tables, qpos, active, W_KEYS, chunk)
+    want = _window_reference(q, kp, vp, tables, qpos, W_KEYS)
+    assert np.all(got[~active] == 0.0)
+    np.testing.assert_allclose(got[active], want[active], atol=ATOL, rtol=0)
+    # and the window matters: without it the longer streams read more
+    whole = _window_reference(q, kp, vp, tables, qpos, None)
+    assert np.abs(whole - want)[lengths > W_KEYS].max() > 10 * ATOL
+
+
+def test_window_form_copies_the_windows_blocks_alone(monkeypatch):
+    """Every copy the window form starts, recorded: exactly the blocks
+    from the one of a stream's first visible key to its last, through
+    the ring, K and V once each: at most window / BS + 1 blocks a
+    sequence a pool whatever its length."""
+    q, kp, vp, tables, qpos, lengths = _window_case(8, 4, 12)
+    seen = []
+    block_copy = pa._block_copy
+
+    def recording(pool, layer, phys, *rest):
+        if not isinstance(phys, int):           # a start, not a wait
+            jax.debug.callback(
+                lambda l, p: seen.append((int(l), int(p))), layer, phys)
+        return block_copy(pool, layer, phys, *rest)
+
+    monkeypatch.setattr(pa, "_block_copy", recording)
+    _window_kernel(q, kp, vp, tables, qpos, lengths > 0, W_KEYS, 2)
+    jax.effects_barrier()
+    want = sorted((LAYER, int(tables[b, j % RING]))
+                  for b, n in enumerate(lengths)
+                  for j in range(max(int(n) - W_KEYS, 0) // BS,
+                                 -(-int(n) // BS))) * 2
+    assert sorted(seen) == sorted(want)
+    assert len(want) <= 2 * (lengths > 0).sum() * (W_KEYS // BS + 1)
+
+
+def test_window_plan_is_bounded_by_the_window_not_the_length():
+    tables = jnp.zeros((3, 256), jnp.int32)
+    qpos = jnp.asarray([5, 2047, 18000], jnp.int32)
+    n, seq, chunk, lengths, _, _ = pa.plan(tables, qpos, None, 16,
+                                           window=2048)
+    assert seq.shape[0] == 3 * 5        # (2048 - 2) // 512 + 2 a sequence
+    # 1 chunk, 4 chunks, and 18001 - 2048 = 15953 -> chunks 31 .. 35
+    assert int(n[0]) == 1 + 4 + 5
+    assert list(np.asarray(chunk[:10])) == [0, 0, 1, 2, 3, 31, 32, 33, 34,
+                                            35]
+    with pytest.raises(ValueError, match="one query"):
+        pa.plan(tables, jnp.zeros((3, 2), jnp.int32), None, 16, window=2048)
+
+
+@pytest.mark.parametrize("window", [None, W_KEYS])
+@pytest.mark.parametrize("heads,kv_heads", [(8, 4), (8, 2)])
+def test_kv_heads_side_by_side_in_a_row(heads, kv_heads, window):
+    """Pools of four axes, a token's KV heads side by side in one row
+    [L, NB, bs, kvH * D] (few KV heads: `ops/paged_attention.py`), give
+    what the same rows give as [L, NB, bs, kvH, D]: both forms of the
+    walk, against the masked gather."""
+    q, kp, vp, tables, qpos, lengths = _window_case(heads, kv_heads, 13)
+    if window is None:                  # a table by position: no wrap
+        lengths = np.minimum(lengths, RING * BS)
+        qpos = np.maximum(lengths - 1, 0).astype(np.int32)[:, None]
+    active = lengths > 0
+    flat = [x.reshape(x.shape[:3] + (-1,)) for x in (kp, vp)]
+    scalars = pa.plan(jnp.asarray(tables), jnp.asarray(qpos),
+                      jnp.asarray(active), BS, 2, window=window)
+    got = np.asarray(pa.paged_attention(
+        q, *flat, jnp.int32(LAYER), scalars, chunk=2, window=window),
+        np.float32)
+    want = _window_reference(q, kp, vp, tables, qpos, window)
+    assert np.all(got[~active] == 0.0)
+    np.testing.assert_allclose(got[active], want[active], atol=ATOL, rtol=0)

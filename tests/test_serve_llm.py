@@ -461,3 +461,248 @@ def test_serve_llm_deployment_smoke(ray_start_regular):
             assert out["finish_reason"] == "length"
     finally:
         serve.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# Two kinds of pool in one engine: a table by position for a model's
+# full-attention leaves, a ring of blocks for its window leaves
+# (models/serving.py, kv_cache.WindowRing)
+# ---------------------------------------------------------------------------
+
+def _window_engine(**kw):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.window_moe import WindowMoEConfig, init_params
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+    if "window_model" not in _CACHE:
+        config = WindowMoEConfig.tiny(dtype=jnp.float32,
+                                      param_dtype=jnp.float32)
+        _CACHE["window_model"] = (config,
+                                  init_params(config, jax.random.key(0)))
+    config, params = _CACHE["window_model"]
+    cfg = dict(num_slots=3, max_seq_len=96, prefill_buckets=(8, 16),
+               kv_block_size=4, num_kv_blocks=60, num_window_blocks=14,
+               prefix_cache=False)
+    extra = {k: kw.pop(k) for k in ("draft_params", "draft_config")
+             if k in kw}
+    cfg.update(kw)
+    return LLMEngine(params, config, EngineConfig(**cfg), **extra)
+
+
+def _held(engine):
+    """(blocks of the full kind, of the window kind) each slot holds."""
+    return [(len(a), len(b)) for a, b in zip(
+        engine._slot_blocks, engine._ring.slot_blocks)]
+
+
+def test_window_ring_bounds_a_long_stream_beside_a_short_one():
+    """Window 8, buckets to 16, blocks of 4: a ring of 6 blocks.  A
+    3-token request and one whose 55-token prompt and 30-token answer
+    wrap the ring three times, side by side: the long one holds a block
+    for each 4 of its rows in the full kind and SIX in the window kind,
+    the short one the same few of both; everything is given back."""
+    from ray_tpu.serve.llm.engine import Request
+
+    engine = _window_engine()
+    ring = engine._ring
+    assert (ring.window, ring.ring, ring.tables.shape) == (8, 6, (3, 6))
+    rng = np.random.RandomState(0)
+    short = engine.submit(Request(prompt=rng.randint(1, 500, 3).tolist(),
+                                  max_tokens=4))
+    long = engine.submit(Request(prompt=rng.randint(1, 500, 55).tolist(),
+                                 max_tokens=30, chunked_prefill=True))
+    engine.step()
+    assert sorted(_held(engine)) == [(0, 0), (2, 2), (22, 6)]
+    kv = engine.stats()["kv"]
+    assert (kv["used_blocks"], kv["window"]["used_blocks"]) == (24, 8)
+    assert kv["window"]["ring_blocks"] == 6
+    # the ring's table: position t in entry (t // 4) % 6
+    slot = _held(engine).index((22, 6))
+    assert list(ring.block_ids(slot, 16, 16)) == [
+        ring.tables[slot, i % 6] for i in range(4, 8)]
+    # its prompt in, the long one keeps the window's blocks and the one
+    # it writes: (8 - 1) // 4 + 2 = 3, handed on as it decodes
+    while len(long.tokens) < 2:
+        engine.step()
+    assert ring.keep == 3 and _held(engine)[slot] == (22, 3)
+    assert engine.stats()["kv"]["window"]["used_blocks"] <= 3 + 2
+    engine.drain()
+    assert short.finish_reason == long.finish_reason == "length"
+    assert len(long.tokens) == 30
+    kv = engine.stats()["kv"]
+    assert kv["used_blocks"] == kv["window"]["used_blocks"] == 0
+    assert _held(engine) == [(0, 0)] * 3
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_either_kind_running_dry_queues_and_never_crashes(kind):
+    """Three 40-row requests into a pool one kind of which holds two:
+    the third waits at the head of its lane with nothing taken, and is
+    served when a slot's blocks come back."""
+    from ray_tpu.serve.llm.engine import Request
+
+    engine = _window_engine(**(
+        {"num_kv_blocks": 25} if kind == "full"
+        else {"num_window_blocks": 13}))
+    rng = np.random.RandomState(1)
+    handles = [engine.submit(Request(
+        prompt=rng.randint(1, 500, 30).tolist(), max_tokens=10,
+        chunked_prefill=True)) for _ in range(3)]
+    for _ in range(3):                  # a first chunk a step
+        engine.step()
+    # the first is decoding and keeps 3 of its 6; what it gave back is
+    # not enough for the third
+    assert sorted(_held(engine)) == [(0, 0), (10, 3), (10, 6)]
+    assert engine.stats()["queued"] == 1
+    engine.drain()
+    assert all(h.finish_reason == "length" and len(h.tokens) == 10
+               for h in handles)
+    # a request no pool of that kind could ever hold is refused at submit
+    with pytest.raises(ValueError, match="KV blocks|window kind"):
+        _window_engine(num_kv_blocks=5, num_window_blocks=5).submit(
+            Request(prompt=[1] * 16, max_tokens=20))
+
+
+def test_a_16k_stream_holds_a_ring_in_the_window_kind():
+    """At the benchmark cell's geometry (64 slots x 18,432, blocks of 16,
+    buckets to 2048, window 2048; tiny widths): a 16,384-token prompt
+    with a 1,024-token answer takes 1,088 blocks of the full kind, its
+    full length, and 256 of the window kind: 4,096 rows in each window
+    layer, whatever its length; once its prompt is in, 129 of them."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.window_moe import WindowMoEConfig, init_params
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine, Request
+
+    config = WindowMoEConfig.tiny(window=2048, max_seq_len=18432,
+                                  prefill_key_block=1024,
+                                  dtype=jnp.float32, param_dtype=jnp.float32)
+    engine = LLMEngine(
+        init_params(config, jax.random.key(0)), config, EngineConfig(
+            num_slots=64, max_seq_len=18432,
+            prefill_buckets=(256, 512, 1024, 2048), kv_block_size=16,
+            num_kv_blocks=2048, num_window_blocks=512, prefix_cache=False))
+    ring = engine._ring
+    assert ring.ring == 256 and ring.ring * 16 <= 4096 + 16
+    handle = engine.submit(Request(prompt=[7] * 16384, max_tokens=1024,
+                                   chunked_prefill=True))
+    engine.step()                       # the first chunk takes every block
+    assert (16384 + 1024) // 16 == 1088
+    assert _held(engine)[0] == (1088, 256)
+    assert engine.stats()["kv"]["window"]["used_blocks"] == 256
+    while len(handle.tokens) < 2:       # the other seven chunks, a tick
+        engine.step()
+    # decoding, it keeps the window's blocks and the one it writes
+    assert ring.keep == 129 and _held(engine)[0] == (1088, 129)
+    assert engine.stats()["kv"]["window"]["used_blocks"] == 129
+    handle.cancel()
+    engine.step()
+    assert _held(engine)[0] == (0, 0)
+
+
+@pytest.mark.parametrize("n_blocks", [5, 9, 12, 13, 40])
+def test_window_ring_cover_keeps_the_window_and_no_more(n_blocks):
+    """`WindowRing.cover` on its own, window 16, blocks of 4, a ring of
+    12, two positions a dispatch (`keep` 6): a sequence of `n_blocks`
+    blocks, its prompt written through the ring as chunks write it,
+    then decoded to its end.  Before every dispatch each block of the
+    window and of the positions written is in the table, owned, and
+    holds the rows last written at those positions; a sequence that
+    took more than `keep` blocks holds `keep` from its first dispatch
+    on, and everything comes back."""
+    from ray_tpu.serve.llm.kv_cache import BlockAllocator, WindowRing
+
+    bs, W, n_ring, ahead = 4, 16, 12, 2
+    allocator = BlockAllocator(64, bs, block_bytes=1)
+    ring = WindowRing(W, n_ring, allocator, 2, lookahead=ahead)
+    assert ring.keep == 6
+    assert ring.take(1, n_blocks)
+    took = min(n_blocks, n_ring)
+    assert allocator.stats()["used_blocks"] == took
+    total = n_blocks * bs
+    prompt = total - 11
+    wrote = {}                          # physical block -> block by position
+    for b in range(-(-prompt // bs)):
+        wrote[int(ring.tables[1, b % n_ring])] = b
+    for first in range(prompt, total, ahead):
+        last = min(first + ahead - 1, total - 1)
+        ring.cover(1, first, last)
+        held = ring.slot_blocks[1]
+        assert len(held) == (took if took <= ring.keep else ring.keep)
+        assert len(set(held)) == len(held)
+        assert allocator.stats()["used_blocks"] == len(held)
+        for pos in range(first, last + 1):
+            wrote[int(ring.tables[1, (pos // bs) % n_ring])] = pos // bs
+        for b in range(max(first - W + 1, 0) // bs, last // bs + 1):
+            block = int(ring.tables[1, b % n_ring])
+            assert block in held and wrote[block] == b, (first, b)
+    ring.release(1)
+    assert allocator.stats()["used_blocks"] == 0
+
+
+@pytest.mark.parametrize("what", [
+    "prefix_cache", "kv_spill", "export_prefix", "submit_adopted",
+    "prefill_only", "preempt", "draft_model"])
+def test_window_kind_refuses_by_name_what_moves_rows(what):
+    """Whatever moves rows without telling a ring from a table is
+    refused, and the refusal names the model."""
+    from ray_tpu.serve.llm.engine import Request
+    from ray_tpu.serve.llm.kv_cache import KVState
+
+    with pytest.raises(ValueError, match="models/window_moe.py"):
+        if what == "prefix_cache":
+            _window_engine(prefix_cache=True)
+        elif what == "kv_spill":
+            _window_engine(prefix_cache=True, kv_spill=True)
+        elif what == "draft_model":
+            config, params = _CACHE.get("window_model") or (
+                _window_engine() and _CACHE["window_model"])
+            _window_engine(draft_params=params, draft_config=config)
+        else:
+            engine = _window_engine()
+            if what == "export_prefix":
+                engine.export_prefix([1] * 8)
+            elif what == "prefill_only":
+                engine.submit(Request(prompt=[1] * 5, max_tokens=2,
+                                      prefill_only=True))
+            elif what == "preempt":
+                engine.submit(Request(prompt=[1] * 5, max_tokens=4))
+                engine.step()
+                engine.preempt(0)
+            else:
+                engine.submit_adopted(
+                    Request(prompt=[1, 2], max_tokens=4),
+                    KVState(prompt=[1, 2], tokens=[3], next_tok=3, pos=2,
+                            temperature=0.0, block_size=4, blocks={}))
+
+
+def test_dispatch_and_admission_spans_say_both_kinds():
+    """`llm_engine.tick_dispatch` carries `rows=` and `window_rows=`,
+    and the bytes the live slots hold are summed beside their rows; the
+    span that holds the allocation says `blocks_full=` and
+    `blocks_window=`; a model with one kind says `rows=` alone."""
+    from ray_tpu.serve.llm.engine import Request
+
+    engine = _window_engine()
+    engine.submit(Request(prompt=[3] * 30, max_tokens=4,
+                          chunked_prefill=True))
+    engine.step(), engine.step()
+    before = engine.stats()["kv"]["live_bytes"]
+    said = engine._live_rows([0])
+    assert said == {"rows": 32, "window_rows": 8}
+    kv = engine.stats()["kv"]
+    assert kv["live_bytes"] - before == 9 * kv["block_bytes"] \
+        + 3 * kv["window"]["block_bytes"]       # decoding: `keep` of 6
+
+    class Span:
+        def set_metadata(self, **kw):
+            self.said = kw
+
+    span = Span()
+    assert engine._take_blocks(1, 9, span) is not None
+    assert span.said == {"blocks_full": 9, "blocks_window": 6}
+    assert set(_shared_engine()._live_rows([])) == {"rows"}
+    assert "live_bytes" not in _shared_engine().stats()["kv"]
