@@ -219,11 +219,21 @@ class _Sequences:
 
 
 class _Step:
-    """One token a slot: each layer's rows of the whole tree read and
-    written at a static layer index, in place; a dead slot keeps its."""
+    """One token a slot, the whole tree updated in place at a static
+    layer index; a dead slot keeps its rows.  The delta-rule state goes
+    by ONE of two paths, chosen by backend and shape alone
+    (`ops.kda.engages`): the Pallas step over the whole stack, which
+    reads and writes each LIVE slot's `[H, dk, dv]` rows once where
+    they lie and a dead slot's never (`plan`: the live slots' indices,
+    made here once a tick for all its layers), or `kda_step` on the
+    layer's rows of all slots and `_keep`.  The convolution's tail
+    (`[conv_size - 1, 3 W]` a slot) always goes the second way."""
 
     def __init__(self, state, active):
         self.tree, self.active = dict(state), active
+        S = state["S"]
+        self.plan = kda.live_plan(active, S.shape[1]) if kda.engages(
+            S.shape[-2], S.shape[-1], S.dtype) else None
 
     def _keep(self, new, old):
         if self.active is None:
@@ -239,9 +249,13 @@ class _Step:
         return y[:, None]
 
     def recur(self, j, q, k, v, g, beta):
+        now = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+        if self.plan is not None:
+            o, self.tree["S"] = kda.kda_step_live(
+                self.tree["S"], j, *now, self.plan)
+            return o[:, None]
         old = self.tree["S"][j]
-        o, S = kda.kda_step(old.astype(jnp.float32), q[:, 0], k[:, 0],
-                            v[:, 0], g[:, 0], beta[:, 0])
+        o, S = kda.kda_step(old.astype(jnp.float32), *now)
         self.tree["S"] = self.tree["S"].at[j].set(self._keep(S, old))
         return o[:, None]
 
@@ -374,7 +388,10 @@ def decode_step_paged(params, pools, tables, tokens, positions,
               "ticks": jnp.ones((), jnp.int32),
               "live_slots": n_live,
               "pairs_local": jnp.sum(routed, dtype=jnp.int32),
-              "pairs_total": n_live * (c.top_k * c.n_moe_layers)}
+              "pairs_total": n_live * (c.top_k * c.n_moe_layers),
+              # slot-layers the Pallas step advanced (0: `kda_step` ran)
+              "kda_rows_stepped": n_live * (
+                  c.n_kda_layers if rec.plan is not None else 0)}
     return LM._head(c, params, x[:, 0]), {"latent": cache.pool}, counts, \
         rec.state()
 
@@ -382,13 +399,15 @@ def decode_step_paged(params, pools, tables, tokens, positions,
 def init_counts(config: KimiLinearConfig) -> Dict[str, jax.Array]:
     """Zeros of what `decode_step_paged` counts: tokens routed to each
     HELD expert of each expert layer, the distinct ones touched, ticks,
-    live slots summed over ticks, and the token-expert assignments that
-    fell on held experts beside all that were made."""
+    live slots summed over ticks, the token-expert assignments that
+    fell on held experts beside all that were made, and the slot-layers
+    whose state the Pallas step advanced (`live_slots` x KDA layers
+    where it engages, 0 where `kda_step` ran)."""
     z = jnp.zeros((), jnp.int32)
     return {"expert_tokens": jnp.zeros(
                 (config.n_moe_layers, config.n_held_experts), jnp.int32),
             "experts_touched": z, "ticks": z, "live_slots": z,
-            "pairs_local": z, "pairs_total": z}
+            "pairs_local": z, "pairs_total": z, "kda_rows_stepped": z}
 
 
 _SERVING = ServingFns(

@@ -11,13 +11,38 @@ query `k`, `q` [dk] (L2-normalised by the caller, `q` scaled), value
        # = (I - beta k k^T) Diag(alpha) S + beta k v^T
     o  = S^T q
 
-Two forms of that one function, plain `jax.numpy`:
+Three forms of that one function:
 
-- `kda_step`: one token a sequence (decode).  Both reductions over the
-  state (`S'^T k` and `S'^T q`) are taken in one pass and the output is
-  put together from them (`o = S'^T q + beta (k . q) u`), so the state is
-  read for the reductions, read and written for the update, and never
-  for the output.
+- `kda_step` (plain `jax.numpy`): one token a sequence (decode).  Both
+  reductions over the state (`S'^T k` and `S'^T q`) are taken in one
+  pass and the output is put together from them (`o = S'^T q + beta
+  (k . q) u`), so the state is read for the reductions, read and
+  written for the update, and never for the output.  It is the
+  reference of the next form and the path wherever that does not
+  engage (`engages`: off TPU, a state kept in bf16, heads narrower than
+  a lane row).
+- `kda_step_live` (a Pallas TPU kernel): the same step on one layer of
+  the decode tick's WHOLE stacked state `[Lk, B, H, dk, dv]`, left
+  where it lies in HBM and aliased input to output, for the LIVE slots
+  alone.  `live_plan` (plain XLA, once a tick, shared by its layers)
+  lists the live slots first; the kernel is one grid step whose loop
+  runs that many trips, as `ops/paged_attention.py`'s walk does: a
+  trip starts the copy of the NEXT live slot's `[H, dk, dv]` rows (2 MB
+  at heads of 128 x 128 x 32) into the other half of a double-buffered
+  VMEM scratch, waits for its own, takes both reductions, `u`, `o` and
+  the update head by head on the vector unit in float32, in the
+  operations `kda_step` writes (on the chip the two agree to the bit),
+  and starts ONE copy of the new rows back to where the old ones lay.
+  So a live slot's state is read once and written once a layer, and a
+  dead slot's is never copied, computed or written: no `where(live,
+  new, old)`, no `.at[layer].set`, no layer cut out of the stack.  A
+  head's vectors over dk (`alpha k`, `alpha q`, `alpha`, `k`) multiply
+  the state's ROWS, so XLA lays them as columns (`[B, dk, 4 H]`, dk
+  down the sublanes) and the kernel broadcasts each along the lanes;
+  `beta` and `k . q` are scalars in scalar memory.  On a v5e, 128 slots
+  x 6 layers: 0.31 ms + 37 us a live slot a tick (83% of the HBM bound
+  for the bytes it moves), against 12.0 ms whatever is live for the
+  plain form's passes over all 128 (PERF.md section 6, PR 39).
 - `kda_chunked`: a whole (padded) sequence from an initial state
   (prefill), `chunk` tokens at a time.  Inside a chunk the products of
   the `(I - beta k k^T) Diag(alpha)` factors are written in the WY / UT
@@ -43,14 +68,20 @@ The causal depthwise convolution in front of q, k and v is
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import attention as _attention
 
 _HI = lax.Precision.HIGHEST
 CHUNK = 64      # tokens a chunk of the prefill form
+_LANE = 128
 
 
 def kda_step(S: jax.Array, q: jax.Array, k: jax.Array, v: jax.Array,
@@ -70,6 +101,151 @@ def kda_step(S: jax.Array, q: jax.Array, k: jax.Array, v: jax.Array,
     u = (v - r[..., 0]) * beta[..., None]
     o = r[..., 1] + jnp.sum(k * q, -1, keepdims=True) * u
     return o, S * a[..., None] + k[..., None] * u[..., None, :]
+
+
+def engages(dk: int, dv: int, dtype) -> bool:
+    """Whether a decode tick steps its states through `kda_step_live`:
+    `ops.attention`'s rule for the backend (a TPU always, off TPU only
+    when a test forces the interpreter), a float32 state, and `dk` and
+    `dv` in whole lane rows, so that a head's state is `[dk, dv]` whole
+    tiles as it lies in HBM."""
+    tiles = (dtype == jnp.float32 and dk % _LANE == 0 and dv % _LANE == 0)
+    return tiles and (_attention._on_tpu()
+                      or _attention.FORCE_PALLAS_INTERPRET)
+
+
+def live_plan(active: Optional[jax.Array], n: int):
+    """The kernel's scalars, once a tick for all its layers: (the slots
+    [n] int32 as a permutation, live first, each part in slot order; the
+    live count [1]).  `active` [n] bool, or None: every slot live.  Sums
+    over comparisons, no sort and no host."""
+    slot = jnp.arange(n, dtype=jnp.int32)
+    if active is None:
+        return slot, jnp.full((1,), n, jnp.int32)
+    live = active.astype(jnp.int32)
+    before = jnp.where(slot[:, None] > slot[None, :], live[None, :],
+                       0).sum(-1)                   # live slots before b
+    count = live.sum()
+    place = jnp.where(active, before, count + slot - before)
+    order = jnp.where(place[None, :] == slot[:, None], slot[None, :],
+                      0).sum(-1)
+    return order.astype(jnp.int32), count.reshape(1).astype(jnp.int32)
+
+
+def _live_kernel(layer_ref, slots_ref, n_ref, scal_ref, cols_ref, v_ref,
+                 s_in, o_ref, s_out, cbuf, vbuf, sbuf, nbuf, sems, *, heads):
+    # s_in and s_out are ONE stack in HBM (aliased); a live slot's rows
+    # of layer `layer` are read once from the one and written once
+    # through the other, a dead slot's by neither.
+    layer, n = layer_ref[0], n_ref[0]
+    nslots = o_ref.shape[0]
+
+    def loads(i, half):
+        b = slots_ref[i]
+        return (pltpu.make_async_copy(s_in.at[layer, b], sbuf.at[half],
+                                      sems.at[0, half]),
+                pltpu.make_async_copy(cols_ref.at[b], cbuf.at[half],
+                                      sems.at[1, half]),
+                pltpu.make_async_copy(v_ref.at[b], vbuf.at[half],
+                                      sems.at[2, half]))
+
+    def store(i, half):
+        return pltpu.make_async_copy(
+            nbuf.at[half], s_out.at[layer, slots_ref[i]], sems.at[3, half])
+
+    o_ref[...] = jnp.zeros_like(o_ref)      # a dead slot's row
+
+    @pl.when(n > 0)
+    def _():
+        for copy in loads(0, 0):
+            copy.start()
+
+    @pl.loop(0, n)
+    def _(i):
+        half = i % 2
+
+        @pl.when(i + 1 < n)
+        def _():
+            for copy in loads(i + 1, 1 - half):
+                copy.start()
+
+        for copy in loads(i, half):
+            copy.wait()
+
+        @pl.when(i >= 2)                    # this half's last write-back
+        def _():
+            store(i - 2, half).wait()
+
+        b = slots_ref[i]
+        cols = cbuf[half]                   # [dk, 4 H]
+        for h in range(heads):
+            S = sbuf[half, h]               # [dk, dv]
+            ak, aq, a, k = (cols[:, c * heads + h:c * heads + h + 1]
+                            for c in range(4))
+            r_k = jnp.sum(S * ak, axis=0, keepdims=True)     # [1, dv]
+            r_q = jnp.sum(S * aq, axis=0, keepdims=True)
+            beta = scal_ref[b * heads + h]
+            kq = scal_ref[(nslots + b) * heads + h]
+            u = (vbuf[half, pl.ds(h, 1), :] - r_k) * beta
+            o_ref[b, pl.ds(h, 1), :] = r_q + kq * u
+            nbuf[half, h] = S * a + k * u
+        store(i, half).start()
+
+    for last in (n - 2, n - 1):             # the write-backs in flight
+        @pl.when(last >= 0)
+        def _():
+            store(last, last % 2).wait()
+
+
+# Jitted so that a tick's call sites (one a KDA layer, the layer index
+# an argument) trace and lower the kernel once.
+@jax.jit
+def _step_live(S, layer, cols, v, scal, slots, count):
+    Lk, B, H, dk, dv = S.shape
+    interpret = not _attention._on_tpu()
+    block = pltpu.VMEM((2, H, dk, dv), jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_live_kernel, heads=H),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * 3,
+            out_specs=(pl.BlockSpec(memory_space=pltpu.VMEM),
+                       pl.BlockSpec(memory_space=pl.ANY)),
+            scratch_shapes=[pltpu.VMEM((2, dk, 4 * H), jnp.float32),
+                            pltpu.VMEM((2, H, dv), jnp.float32),
+                            block, block, pltpu.SemaphoreType.DMA((4, 2))]),
+        out_shape=(jax.ShapeDtypeStruct((B, H, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(S.shape, S.dtype)),
+        input_output_aliases={6: 1},
+        interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 2 ** 20),
+        name="kda_step",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), slots, count, scal, cols,
+      v, S)
+
+
+def kda_step_live(S: jax.Array, layer, q: jax.Array, k: jax.Array,
+                  v: jax.Array, g: jax.Array, beta: jax.Array, plan
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """`kda_step` on layer `layer` of the WHOLE stack S [Lk, B, H, dk,
+    dv] float32 for the live slots of `plan` = `live_plan(active, B)`,
+    in place: (o [B, H, dv] float32, zeros for a dead slot; the stack,
+    the same buffer where the caller donates it).  q, k, g [B, H, dk];
+    v [B, H, dv]; beta [B, H].  The stack stays in HBM; the layer index
+    is a scalar the kernel adds to its addresses, never a slice."""
+    f = lambda x: x.astype(jnp.float32)
+    q, k, v, g, beta = f(q), f(k), f(v), f(g), f(beta)
+    a = jnp.exp(g)
+    # a head's four vectors over dk as COLUMNS (dk down the sublanes,
+    # as the state's rows lie): alpha k ‖ alpha q ‖ alpha ‖ k, heads
+    # side by side in the lanes
+    cols = jnp.swapaxes(jnp.concatenate([k * a, q * a, a, k], axis=1), 1, 2)
+    scal = jnp.stack([beta, jnp.sum(k * q, -1)]).reshape(-1)
+    return _step_live(S, layer, cols, v, scal, *plan)
 
 
 _SOLVE_BLOCK = 16

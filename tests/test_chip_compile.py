@@ -685,6 +685,51 @@ def test_hybrid_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     assert _hbm_gib(compiled) < V5E_HBM_GIB - 0.5
 
 
+def test_hybrid_tick_steps_live_states_where_they_lie(
+        one_chip, on_tpu, monkeypatch):
+    """The `agent-decode-hybrid` tick with `ops.kda.engages` answering
+    as on the chip: one `kda_step` kernel call a KDA layer over the
+    WHOLE donated stack `[6,128,32,128,128]` (1.61 GB), which no
+    instruction copies, slices or re-stacks, and no instruction makes a
+    layer's `[128,32,128,128]` (268 MB: the plain form, the same tick
+    with the selector taken away, cuts one out of the stack, makes a new
+    one, selects and writes it back, each a pass over all 128 slots).
+    The insert is the same text either way (`kda_chunked` alone)."""
+    from ray_tpu.ops import kda
+
+    eng = _serving_cell("agent-decode-hybrid", one_chip)
+    state, = _slot_state(eng, one_chip)
+    stack = state["S"].shape
+    assert stack == (6, 128, 32, 128, 128)
+    assert kda.engages(*stack[-2:], state["S"].dtype)
+    compiled = _compiled_cell_tick(eng, one_chip)
+    text = compiled.as_text()
+    assert text.count("kda_step") >= stack[0]
+    results = _results(text)
+    assert "parameter" in {op for op, shapes in results
+                           if stack in shapes}                # parsed
+    assert not [op for op, shapes in results if stack[1:] in shapes]
+    moved = [(op, shapes) for op, shapes in results
+             if op in ("copy", "copy-start", "copy-done", "dynamic-slice",
+                       "dynamic-update-slice", "select", "fusion")
+             and stack in shapes]
+    assert not moved, moved
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= math.prod(stack) * 4      # in place
+    insert = _without_metadata(_compiled_insert(eng, one_chip).as_text())
+    assert "kda_step" not in insert
+
+    monkeypatch.setattr(kda, "engages", lambda dk, dv, dtype: False)
+    parent = _compiled_cell_tick(eng, one_chip)
+    assert [op for op, shapes in _results(parent.as_text())
+            if stack[1:] in shapes]
+    # the temporaries fall by a layer's new state and more
+    assert m.temp_size_in_bytes + math.prod(stack[1:]) * 4 \
+        < parent.memory_analysis().temp_size_in_bytes
+    assert _without_metadata(
+        _compiled_insert(eng, one_chip).as_text()) == insert
+
+
 @pytest.mark.parametrize("program", ["tick", "insert"])
 def test_conv_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     """The engine's decode tick and its largest insert at the geometry of

@@ -632,3 +632,69 @@ def test_engine_serves_the_same_greedy_tokens_on_both_attention_paths(
     assert gather_stats["paged_attention"] == "gather"
     assert 0 < kernel_stats["live_rows"] == gather_stats["live_rows"] \
         < kernel_stats["padded_rows"]
+
+
+# -------------------- (i) the recurrence's live step through the engine
+
+def test_engine_steps_live_states_in_place_on_both_kda_paths(monkeypatch):
+    """A float32 model with KDA heads of 128 through `LLMEngine`, two
+    slots: a prompt in one bucket, one in three chunks, ticks, the first
+    slot freed, left free while the other ticks on, then taken by a
+    third request.  With `ops.kda.kda_step_live` forced through the
+    interpreter against the plain `kda_step` path: the same tokens, the
+    slots' states within 2e-6 of their size, a freed slot's rows the
+    same BITS after the ticks that follow (the kernel never writes a
+    dead slot; the plain form writes back what it read), and
+    `kda_rows_stepped / (live_slots x KDA layers)` 1.0 against 0.0."""
+    from ray_tpu.models import kimi_linear as KL
+    from ray_tpu.ops import attention
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine, Request
+
+    c = KL.KimiLinearConfig.tiny(kda_head_dim=128, kda_heads=2,
+                                 dtype=jnp.float32,
+                                 param_dtype=jnp.float32)
+    params = _drawn_at_a_tenth(KL, c, 2)
+    first, long, third = (_tokens(n, seed=70 + n) for n in (9, 41, 20))
+
+    def serve():
+        eng = LLMEngine(params, c, EngineConfig(
+            num_slots=2, max_seq_len=64, prefill_buckets=(8, 16),
+            kv_block_size=BS, num_kv_blocks=40, prefix_cache=False),
+            rng_seed=3)
+        a = eng.submit(Request(prompt=first, max_tokens=3,
+                               chunked_prefill=True))
+        b = eng.submit(Request(prompt=long, max_tokens=16,
+                               chunked_prefill=True))
+        eng.step()
+        slot, = (i for i, s in enumerate(eng._slots) if s.handle is a)
+        while a.finished_at is None:
+            eng.step()
+        freed = eng.slot_state(slot)
+        assert np.abs(freed["S"]).max() > 1e-3
+        for _ in range(4):                      # the other slot ticks on
+            eng.step()
+        assert b.finished_at is None and len(b.tokens) >= 4
+        kept = eng.slot_state(slot)
+        for leaf in freed:
+            np.testing.assert_array_equal(kept[leaf], freed[leaf])
+        d = eng.submit(Request(prompt=third, max_tokens=5,
+                               chunked_prefill=True))
+        while eng.has_work():
+            eng.step()
+        assert eng.stats()["slot_reuses"] >= 1
+        ctr = eng.stats()["counters"]
+        return ([h.tokens for h in (a, b, d)],
+                [eng.slot_state(s)["S"] for s in range(2)],
+                int(ctr["kda_rows_stepped"])
+                / (int(ctr["live_slots"]) * c.n_kda_layers))
+
+    plain_tokens, plain_states, plain_share = serve()
+    monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", True)
+    assert KL.kda.engages(c.kda_head_dim, c.kda_head_dim, c.state_dtype)
+    kernel_tokens, kernel_states, kernel_share = serve()
+    assert kernel_tokens == plain_tokens
+    assert [len(t) for t in kernel_tokens] == [3, 16, 5]
+    for got, want in zip(kernel_states, plain_states):
+        assert np.abs(want).max() > 1e-3
+        assert np.abs(got - want).max() <= 2e-6 * np.abs(want).max()
+    assert (plain_share, kernel_share) == (0.0, 1.0)
