@@ -195,7 +195,9 @@ def init_slot_state(config: KimiLinearConfig, num_slots: int
 class _Sequences:
     """Whole (padded) sequences, each from the state handed in
     {leaf: [Lk, B, ...]}: the chunkwise form over the first `n_real`
-    tokens; the states after them are kept for the caller."""
+    tokens; the states after them are kept for the caller.  (This and
+    `_Step` carry `models/gdn_hybrid.py`'s states too, whose `S` lies
+    several heads a row, `ops.kda.pack`: one a row here.)"""
 
     def __init__(self, state, n_real):
         self.inp, self.n_real = state, n_real
@@ -209,9 +211,11 @@ class _Sequences:
         return y
 
     def recur(self, j, q, k, v, g, beta):
-        o, S = kda.kda_chunked(q, k, v, g, beta, self.inp["S"][j],
+        S0 = self.inp["S"][j]
+        p = q.shape[-2] // S0.shape[-3]     # heads a row (`ops.kda.pack`)
+        o, S = kda.kda_chunked(q, k, v, g, beta, kda.unpack(S0, p),
                                self.n_real)
-        self.S.append(S.astype(self.inp["S"].dtype))
+        self.S.append(kda.pack(S, p).astype(S0.dtype))
         return o
 
     def state(self):
@@ -255,8 +259,10 @@ class _Step:
                 self.tree["S"], j, *now, self.plan)
             return o[:, None]
         old = self.tree["S"][j]
-        o, S = kda.kda_step(old.astype(jnp.float32), *now)
-        self.tree["S"] = self.tree["S"].at[j].set(self._keep(S, old))
+        p = q.shape[-2] // old.shape[-3]    # heads a row (`ops.kda.pack`)
+        o, S = kda.kda_step(kda.unpack(old, p).astype(jnp.float32), *now)
+        self.tree["S"] = self.tree["S"].at[j].set(
+            self._keep(kda.pack(S, p), old))
         return o[:, None]
 
     def state(self):

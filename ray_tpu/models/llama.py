@@ -506,8 +506,9 @@ class _Paged:
     compiled with).  The two stacked pools are carried whole through the
     layers, ONE buffer each from the tick's donated argument to its
     result: layer `l` writes each row in place at (l, table[pos // bs],
-    pos % bs) -- a physical block id out of bounds, so dropped, for an
-    inactive sequence -- and then attends (AFTER the writes, so a query
+    pos % bs), as the pool lies (`ops.paged_attention.write_rows`) -- a
+    physical block id out of bounds, so dropped, for an inactive
+    sequence -- and then attends (AFTER the writes, so a query
     sees its own row and those before it) by one of two paths, chosen
     by backend and shape alone (`ops.paged_attention.engages`):
 
@@ -546,14 +547,14 @@ class _Paged:
         k_pool, v_pool = stacks
         B, nb = self.tables.shape
         new = self.phys.shape + k.shape[2:]
-        with jax.named_scope("kv_write"):
-            k_pool = k_pool.at[l, self.phys, self.off].set(
-                k.reshape(new).astype(k_pool.dtype))
-            v_pool = v_pool.at[l, self.phys, self.off].set(
-                v.reshape(new).astype(v_pool.dtype))
-        if self.plan is not None:
-            from ray_tpu.ops.paged_attention import paged_attention
+        from ray_tpu.ops.paged_attention import paged_attention, write_rows
 
+        with jax.named_scope("kv_write"):
+            k_pool = write_rows(k_pool, l, self.phys, self.off,
+                                k.reshape(new))
+            v_pool = write_rows(v_pool, l, self.phys, self.off,
+                                v.reshape(new))
+        if self.plan is not None:
             with jax.named_scope("attn"), jax.named_scope("paged"):
                 attn = paged_attention(q, k_pool, v_pool, l, self.plan)
             return attn, (k_pool, v_pool), ()

@@ -50,7 +50,10 @@ longer than a bucket goes into its slot chunk by chunk.
     where a model has none, and the engine refuses by name):
     init_slot_state(config, num_slots) -> {leaf: [L', B, ...]}, a
     SECOND kind of state, of a fixed size a slot whatever the sequence's
-    length (a recurrent layer's): a row a slot a layer that keeps one.
+    length: a row a slot a layer that keeps one (the delta-rule state
+    and convolution tail of `models/kimi_linear.py` and
+    `models/gdn_hybrid.py`, the convolution tail of
+    `models/conv_moe.py`).
     A model that has it takes and returns it beside the pool:
         prefill(..., n_real, state) -> (hidden, rows, state), `state`
         {leaf: [L', ...]} ONE slot's rows, as they stood after the

@@ -1,10 +1,14 @@
-"""Kimi Delta Attention (KDA): a gated delta rule with one decay a
-key CHANNEL, as a recurrence over a fixed-size state.
+"""Gated delta rules as a recurrence over a fixed-size state: Kimi Delta
+Attention (one decay a key CHANNEL, keys and values of one size) and the
+gated delta rule of `models/gdn_hybrid.py` (one decay a HEAD, keys of
+another size than values, write strengths up to 2) are one function.
 
 For each head, with a state `S` [dk, dv] (float32), a token's key and
 query `k`, `q` [dk] (L2-normalised by the caller, `q` scaled), value
-`v` [dv], log-decay `g` [dk] (<= 0, `alpha = exp(g)`) and write strength
-`beta` (a scalar in (0, 1)):
+`v` [dv], log-decay `g` (<= 0, `alpha = exp(g)`: [dk], one a channel, or
+[1], one a head, which broadcasts through every form below) and write
+strength `beta` (a scalar: in (0, 1), or in (0, 2) where the model
+doubles it):
 
     S' = Diag(alpha) S
     S  = S' + beta k (v - S'^T k)^T
@@ -19,30 +23,27 @@ Three forms of that one function:
   (k . q) u`), so the state is read for the reductions, read and
   written for the update, and never for the output.  It is the
   reference of the next form and the path wherever that does not
-  engage (`engages`: off TPU, a state kept in bf16, heads narrower than
-  a lane row).
+  engage (`engages`: off TPU, a state kept in bf16, rows of the stack
+  that are no whole tiles).
 - `kda_step_live` (a Pallas TPU kernel): the same step on one layer of
-  the decode tick's WHOLE stacked state `[Lk, B, H, dk, dv]`, left
-  where it lies in HBM and aliased input to output, for the LIVE slots
-  alone.  `live_plan` (plain XLA, once a tick, shared by its layers)
-  lists the live slots first; the kernel is one grid step whose loop
-  runs that many trips, as `ops/paged_attention.py`'s walk does: a
-  trip starts the copy of the NEXT live slot's `[H, dk, dv]` rows (2 MB
-  at heads of 128 x 128 x 32) into the other half of a double-buffered
-  VMEM scratch, waits for its own, takes both reductions, `u`, `o` and
-  the update head by head on the vector unit in float32, in the
-  operations `kda_step` writes (on the chip the two agree to the bit),
-  and starts ONE copy of the new rows back to where the old ones lay.
-  So a live slot's state is read once and written once a layer, and a
-  dead slot's is never copied, computed or written: no `where(live,
-  new, old)`, no `.at[layer].set`, no layer cut out of the stack.  A
-  head's vectors over dk (`alpha k`, `alpha q`, `alpha`, `k`) multiply
-  the state's ROWS, so XLA lays them as columns (`[B, dk, 4 H]`, dk
-  down the sublanes) and the kernel broadcasts each along the lanes;
-  `beta` and `k . q` are scalars in scalar memory.  On a v5e, 128 slots
-  x 6 layers: 0.31 ms + 37 us a live slot a tick (83% of the HBM bound
-  for the bytes it moves), against 12.0 ms whatever is live for the
-  plain form's passes over all 128 (PERF.md section 6, PR 39).
+  the decode tick's WHOLE stacked state, left where it lies in HBM and
+  aliased input to output, for the LIVE slots alone.  `live_plan`
+  (plain XLA, once a tick, shared by its layers) lists the live slots
+  first; the kernel is one grid step whose loop runs that many trips,
+  as `ops/paged_attention.py`'s walk does: a trip starts the copy of
+  the NEXT live slot's rows (2 MB at 32 heads of 128 x 128, 2.2 MB at
+  30 of 96 x 192) into the other half of a double-buffered VMEM
+  scratch, waits for its own, takes both reductions, `u`, `o` and the
+  update row by row on the vector unit in float32, in the operations
+  `kda_step` writes (on the chip the two agree to the bit), and starts
+  ONE copy of the new rows back to where the old ones lay.  So a live
+  slot's state is read once and written once a layer, and a dead
+  slot's is never copied, computed or written: no `where(live, new,
+  old)`, no `.at[layer].set`, no layer cut out of the stack.  A head's
+  vectors over dk (`alpha k`, `alpha q`, `alpha`, `k`) multiply the
+  state's ROWS, so XLA lays them as columns (`[B, dk, 4 H]`, dk down
+  the sublanes) and the kernel broadcasts each along the lanes; `beta`
+  and `k . q` are scalars in scalar memory.
 - `kda_chunked`: a whole (padded) sequence from an initial state
   (prefill), `chunk` tokens at a time.  Inside a chunk the products of
   the `(I - beta k k^T) Diag(alpha)` factors are written in the WY / UT
@@ -51,12 +52,33 @@ Three forms of that one function:
   `u = (I + A Diag(beta))^-1 (v - (k exp(G)) S_0)` are the
   pseudo-values every row writes, found for all chunks at once by
   forward substitution (`_unit_lower_solve`); a scan over chunks then
-  carries `S`.
+  carries `S`.  With one decay a head the exponential comes out of the
+  sum over channels, and `A` and its twin for the queries are two
+  `[C, dk] x [dk, C]` matrix products under the `[C, C]` decays; with
+  one a channel they are a `[C, C, dk]` reduction on the vector unit.
   The decay differences are masked to `i <= r` BEFORE the exponential,
   where they are <= 0: `exp(-G_i)` alone overflows float32 after a few
   strongly decayed tokens.  Tokens at and past `n_real` (padding) get
   `g = 0`, `beta = 0`: they leave the state as it is, so the state
   handed back is the one after the last REAL token.
+
+The stack's layout.  The kernel wants a slot's state of a layer in whole
+(8, 128) float32 tiles, or HBM stores and every copy moves padding: a
+head's `[96, 192]` would be tiled as `[96, 256]`, a third more bytes.
+`pack` therefore lays `heads_a_row(H, dv)` consecutive heads SIDE BY
+SIDE in the lanes, `[.., H / p, dk, p dv]`: one head a row at values of
+128 (Kimi: `[32, 128, 128]`, the layout it always had), two at 192
+(`[15, 96, 384]`: three whole lane rows, 2,211,840 B a slot a layer,
+exactly heads x dk x dv x 4).  In a packed row the kernel picks a head's
+columns and scalars by lane (`lane < dv`: one select a column), which
+the one-head rows do not pay.  The models keep `S` packed wherever it
+lives (`init_slot_state`); `kda_step` and `kda_chunked` take and give
+`[.., H, dk, dv]`, and `unpack` / `pack` stand between (the identity at
+one head a row).  On a v5e, 128 slots x 6 layers: at 128 x 128 x 32
+heads 0.31 ms + 37 us a live slot a tick, 83% of the HBM bound for the
+bytes it moves, against 12.0 ms whatever is live for the plain form's
+passes over all 128 (PERF.md section 6, PR 39); at 96 x 192 x 30 heads
+with about 70 live 74% of the bound (PR 40).
 
 All state arithmetic is float32 with float32 matrix products
 (`Precision.HIGHEST`): the products are small beside the model's and the
@@ -69,6 +91,7 @@ The causal depthwise convolution in front of q, k and v is
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -82,13 +105,15 @@ from ray_tpu.ops import attention as _attention
 _HI = lax.Precision.HIGHEST
 CHUNK = 64      # tokens a chunk of the prefill form
 _LANE = 128
+_SUBLANE = 8
 
 
 def kda_step(S: jax.Array, q: jax.Array, k: jax.Array, v: jax.Array,
              g: jax.Array, beta: jax.Array
              ) -> Tuple[jax.Array, jax.Array]:
-    """S [B, H, dk, dv] float32; q, k, g [B, H, dk]; v [B, H, dv];
-    beta [B, H].  Returns (o [B, H, dv] float32, the new S)."""
+    """S [B, H, dk, dv] float32; q, k [B, H, dk]; g [B, H, dk] or
+    [B, H, 1] (one decay a head); v [B, H, dv]; beta [B, H].  Returns
+    (o [B, H, dv] float32, the new S)."""
     f = lambda a: a.astype(jnp.float32)
     q, k, v, g, beta = f(q), f(k), f(v), f(g), f(beta)
     a = jnp.exp(g)
@@ -103,13 +128,44 @@ def kda_step(S: jax.Array, q: jax.Array, k: jax.Array, v: jax.Array,
     return o, S * a[..., None] + k[..., None] * u[..., None, :]
 
 
+def heads_a_row(heads: int, dv: int) -> int:
+    """How many heads' values lie side by side in one row of the stack
+    (`pack`): the fewest that fill whole lane rows (1 at dv 128, 2 at
+    192: 384 lanes), or 1 where the heads do not divide by that."""
+    p = _LANE // math.gcd(dv, _LANE)
+    return p if heads % p == 0 else 1
+
+
+def pack(S: jax.Array, p: int) -> jax.Array:
+    """[..., H, dk, dv] -> [..., H / p, dk, p dv]: the states of `p`
+    consecutive heads side by side in the lanes (the layout the stack is
+    kept in; the identity at p = 1)."""
+    if p == 1:
+        return S
+    *lead, H, dk, dv = S.shape
+    S = jnp.moveaxis(S.reshape(*lead, H // p, p, dk, dv), -3, -2)
+    return S.reshape(*lead, H // p, dk, p * dv)
+
+
+def unpack(S: jax.Array, p: int) -> jax.Array:
+    """`pack`'s inverse: [..., H / p, dk, p dv] -> [..., H, dk, dv]."""
+    if p == 1:
+        return S
+    *lead, G, dk, W = S.shape
+    S = jnp.moveaxis(S.reshape(*lead, G, dk, p, W // p), -2, -3)
+    return S.reshape(*lead, G * p, dk, W // p)
+
+
 def engages(dk: int, dv: int, dtype) -> bool:
     """Whether a decode tick steps its states through `kda_step_live`:
     `ops.attention`'s rule for the backend (a TPU always, off TPU only
-    when a test forces the interpreter), a float32 state, and `dk` and
-    `dv` in whole lane rows, so that a head's state is `[dk, dv]` whole
-    tiles as it lies in HBM."""
-    tiles = (dtype == jnp.float32 and dk % _LANE == 0 and dv % _LANE == 0)
+    when a test forces the interpreter), a float32 state, and rows of
+    the stack AS IT LIES (`[.., dk, dv]` its last two axes, `dv` the
+    packed width where heads share a row) that are whole tiles: `dk` in
+    whole sublanes (% 8) and `dv` in whole lane rows (% 128), so that
+    nothing of a slot's `[H / p, dk, p dv]` is padding in HBM."""
+    tiles = (dtype == jnp.float32 and dk % _SUBLANE == 0
+             and dv % _LANE == 0)
     return tiles and (_attention._on_tpu()
                       or _attention.FORCE_PALLAS_INTERPRET)
 
@@ -134,11 +190,23 @@ def live_plan(active: Optional[jax.Array], n: int):
 
 def _live_kernel(layer_ref, slots_ref, n_ref, scal_ref, cols_ref, v_ref,
                  s_in, o_ref, s_out, cbuf, vbuf, sbuf, nbuf, sems, *, heads):
+    # `heads` H; a row of the stack holds p = H / (its groups) of them
+    # side by side, each over its own dv lanes.
     # s_in and s_out are ONE stack in HBM (aliased); a live slot's rows
     # of layer `layer` are read once from the one and written once
     # through the other, a dead slot's by neither.
     layer, n = layer_ref[0], n_ref[0]
-    nslots = o_ref.shape[0]
+    nslots, _, width = o_ref.shape      # rows padded to whole sublanes
+    groups = sbuf.shape[1]
+    p = heads // groups
+    lane = lax.broadcasted_iota(jnp.int32, (1, width), 1) if p > 1 else None
+
+    def wide(parts):
+        # a head's column [dk, 1] or scalar over that head's lanes
+        out = parts[-1]
+        for j in range(p - 2, -1, -1):
+            out = jnp.where(lane < (j + 1) * (width // p), parts[j], out)
+        return out
 
     def loads(i, half):
         b = slots_ref[i]
@@ -178,17 +246,18 @@ def _live_kernel(layer_ref, slots_ref, n_ref, scal_ref, cols_ref, v_ref,
 
         b = slots_ref[i]
         cols = cbuf[half]                   # [dk, 4 H]
-        for h in range(heads):
-            S = sbuf[half, h]               # [dk, dv]
-            ak, aq, a, k = (cols[:, c * heads + h:c * heads + h + 1]
-                            for c in range(4))
-            r_k = jnp.sum(S * ak, axis=0, keepdims=True)     # [1, dv]
+        for j in range(groups):
+            S = sbuf[half, j]               # [dk, p dv]
+            hs = range(j * p, (j + 1) * p)
+            ak, aq, a, k = (wide([cols[:, c * heads + h:c * heads + h + 1]
+                                  for h in hs]) for c in range(4))
+            r_k = jnp.sum(S * ak, axis=0, keepdims=True)     # [1, p dv]
             r_q = jnp.sum(S * aq, axis=0, keepdims=True)
-            beta = scal_ref[b * heads + h]
-            kq = scal_ref[(nslots + b) * heads + h]
-            u = (vbuf[half, pl.ds(h, 1), :] - r_k) * beta
-            o_ref[b, pl.ds(h, 1), :] = r_q + kq * u
-            nbuf[half, h] = S * a + k * u
+            beta = wide([scal_ref[b * heads + h] for h in hs])
+            kq = wide([scal_ref[(nslots + b) * heads + h] for h in hs])
+            u = (vbuf[half, pl.ds(j, 1), :] - r_k) * beta
+            o_ref[b, pl.ds(j, 1), :] = r_q + kq * u
+            nbuf[half, j] = S * a + k * u
         store(i, half).start()
 
     for last in (n - 2, n - 1):             # the write-backs in flight
@@ -201,9 +270,10 @@ def _live_kernel(layer_ref, slots_ref, n_ref, scal_ref, cols_ref, v_ref,
 # an argument) trace and lower the kernel once.
 @jax.jit
 def _step_live(S, layer, cols, v, scal, slots, count):
-    Lk, B, H, dk, dv = S.shape
+    Lk, B, G, dk, W = S.shape           # G rows of p heads, W = p dv
+    H = scal.shape[0] // (2 * B)
     interpret = not _attention._on_tpu()
-    block = pltpu.VMEM((2, H, dk, dv), jnp.float32)
+    block = pltpu.VMEM((2, G, dk, W), jnp.float32)
     return pl.pallas_call(
         functools.partial(_live_kernel, heads=H),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -213,10 +283,10 @@ def _step_live(S, layer, cols, v, scal, slots, count):
             + [pl.BlockSpec(memory_space=pl.ANY)] * 3,
             out_specs=(pl.BlockSpec(memory_space=pltpu.VMEM),
                        pl.BlockSpec(memory_space=pl.ANY)),
-            scratch_shapes=[pltpu.VMEM((2, dk, 4 * H), jnp.float32),
-                            pltpu.VMEM((2, H, dv), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((2,) + cols.shape[1:], jnp.float32),
+                            pltpu.VMEM((2,) + v.shape[1:], jnp.float32),
                             block, block, pltpu.SemaphoreType.DMA((4, 2))]),
-        out_shape=(jax.ShapeDtypeStruct((B, H, dv), jnp.float32),
+        out_shape=(jax.ShapeDtypeStruct(v.shape, jnp.float32),
                    jax.ShapeDtypeStruct(S.shape, S.dtype)),
         input_output_aliases={6: 1},
         interpret=interpret,
@@ -231,21 +301,31 @@ def _step_live(S, layer, cols, v, scal, slots, count):
 def kda_step_live(S: jax.Array, layer, q: jax.Array, k: jax.Array,
                   v: jax.Array, g: jax.Array, beta: jax.Array, plan
                   ) -> Tuple[jax.Array, jax.Array]:
-    """`kda_step` on layer `layer` of the WHOLE stack S [Lk, B, H, dk,
-    dv] float32 for the live slots of `plan` = `live_plan(active, B)`,
-    in place: (o [B, H, dv] float32, zeros for a dead slot; the stack,
-    the same buffer where the caller donates it).  q, k, g [B, H, dk];
-    v [B, H, dv]; beta [B, H].  The stack stays in HBM; the layer index
-    is a scalar the kernel adds to its addresses, never a slice."""
+    """`kda_step` on layer `layer` of the WHOLE stack S [Lk, B, H / p,
+    dk, p dv] float32 (`pack`: p = `heads_a_row(H, dv)` heads a row)
+    for the live slots of `plan` = `live_plan(active, B)`, in place:
+    (o [B, H, dv] float32, zeros for a dead slot; the stack, the same
+    buffer where the caller donates it).  q, k [B, H, dk]; g [B, H, dk]
+    or [B, H, 1] (one decay a head); v [B, H, dv]; beta [B, H].  The
+    stack stays in HBM; the layer index is a scalar the kernel adds to
+    its addresses, never a slice."""
     f = lambda x: x.astype(jnp.float32)
     q, k, v, g, beta = f(q), f(k), f(v), f(g), f(beta)
-    a = jnp.exp(g)
+    B, H, dv = v.shape
+    a = jnp.broadcast_to(jnp.exp(g), k.shape)
     # a head's four vectors over dk as COLUMNS (dk down the sublanes,
     # as the state's rows lie): alpha k ‖ alpha q ‖ alpha ‖ k, heads
     # side by side in the lanes
     cols = jnp.swapaxes(jnp.concatenate([k * a, q * a, a, k], axis=1), 1, 2)
+    if cols.shape[-1] % _LANE:              # whole lane rows for the copy
+        cols = jnp.pad(cols, [(0, 0), (0, 0), (0, -cols.shape[-1] % _LANE)])
     scal = jnp.stack([beta, jnp.sum(k * q, -1)]).reshape(-1)
-    return _step_live(S, layer, cols, v, scal, *plan)
+    G = S.shape[2]
+    v = v.reshape(B, G, -1)
+    if G % _SUBLANE:                        # whole sublane tiles likewise
+        v = jnp.pad(v, [(0, 0), (0, -G % _SUBLANE), (0, 0)])
+    o, S = _step_live(S, layer, cols, v, scal, *plan)
+    return o[:, :G].reshape(B, H, dv), S
 
 
 _SOLVE_BLOCK = 16
@@ -282,8 +362,9 @@ def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                 beta: jax.Array, S0: jax.Array,
                 n_real: Optional[jax.Array] = None, chunk: int = CHUNK
                 ) -> Tuple[jax.Array, jax.Array]:
-    """q, k, g [B, T, H, dk]; v [B, T, H, dv]; beta [B, T, H]; S0
-    [B, H, dk, dv] float32; n_real [B] or a scalar (None: all T).
+    """q, k [B, T, H, dk]; g [B, T, H, dk] or [B, T, H, 1] (one decay
+    a head); v [B, T, H, dv]; beta [B, T, H]; S0 [B, H, dk, dv]
+    float32; n_real [B] or a scalar (None: all T).
     Returns (o [B, T, H, dv] float32, S after token n_real - 1)."""
     f = lambda a: a.astype(jnp.float32)
     q, k, v, g, beta, S0 = f(q), f(k), f(v), f(g), f(beta), f(S0)
@@ -305,16 +386,22 @@ def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
 
     q, k, v, g, beta = chunks(q), chunks(k), chunks(v), chunks(g), \
         chunks(beta)
-    G = jnp.cumsum(g, axis=-2)                               # [B,N,H,C,dk]
+    G = jnp.cumsum(g, axis=-2)                  # [B,N,H,C,dk] (or 1)
     # decay from row i to row r, per channel; masked before the exp
     diff = G[..., :, None, :] - G[..., None, :, :]           # [..,r,i,dk]
     lower = jnp.tril(jnp.ones((C, C), bool))
     decay = jnp.exp(jnp.where(lower[..., None], diff, -jnp.inf))
-    # rows k (for A) and q (for the outputs) against the decayed keys,
-    # in one reduction: the [C, C, dk] products are never kept
-    AA = jnp.sum(jnp.stack([k, q])[..., :, None, :]
-                 * (k[..., None, :, :] * decay)[None], -1)   # [2,B,N,H,C,C]
-    A, Aq = AA[0], AA[1]                                     # Aq: i <= r
+    if g.shape[-1] == 1:
+        # one decay a head comes out of the sum over channels: two
+        # [C, dk] x [dk, C] products under the [C, C] decays
+        AA = jnp.einsum("x...rc,...ic->x...ri", jnp.stack([k, q]), k,
+                        precision=_HI) * decay[..., 0]
+    else:
+        # rows k (for A) and q (for the outputs) against the decayed
+        # keys, in one reduction: the [C, C, dk] products are never kept
+        AA = jnp.sum(jnp.stack([k, q])[..., :, None, :]
+                     * (k[..., None, :, :] * decay)[None], -1)
+    A, Aq = AA[0], AA[1]                        # [B,N,H,C,C]; Aq: i <= r
     A = jnp.where(jnp.tril(lower, -1), A, 0.0)               # i <  r
     # (I + A Diag(beta)) u = v - (k exp(G)) S_0: solve for both terms
     k_in = k * jnp.exp(G)

@@ -76,6 +76,21 @@ output taken from its group's lanes.  As many multiplications as the
 group mask costs the `[bs, kvH, D]` form, no mask, and a block is a
 `[bs, kvH * D]` matrix as it lies.
 
+Many KV heads that are no whole tile.  A pool `[L, NB, bs, 30, 128]`
+(as many K/V heads as query heads, 30 of them) is laid by the TPU
+compiler with a block's heads OUTSIDE its tokens, `[30, bs, 128]` in
+HBM: 30 rows would pad to 32 under the (16, 128) bf16 tile, 16 do not.
+The flat `[bs * kvH, D]` view of such a pool is a copy of all of it
+(deviceless v5e compile: two 3 GB copies a call), so the kernel takes
+the view that IS the bitcast, `swapaxes(pool, 2, 3)`: a block is
+`[kvH * bs, D]` with row = group * bs + token, and the column masks are
+computed for that order (`heads_major`, static).  `write_rows` puts a
+tick's new rows into such a pool through the same view.  The same walk,
+the same copies; what the group mask costs does grow with the heads:
+at 30 groups of one query head the kernel forms 30 times the scores it
+keeps, on a matrix unit fed 30 rows (PERF.md section 6, PR 40 has the
+reading).
+
 The window form.  A sliding-window layer's query at position p sees the
 keys p - W + 1 .. p alone, and its pool holds no more: the table a
 sequence is `ring` blocks wide and position t lives in
@@ -133,6 +148,34 @@ def engages(pool: jax.Array) -> bool:
                       or _attention.FORCE_PALLAS_INTERPRET)
 
 
+def _heads_major(kvh: int) -> bool:
+    """Whether the TPU compiler lays a block `[bs, kvH, D]` head by head
+    (`[kvH, bs, D]` in HBM, no padded rows): more than 4 KV heads that
+    are not whole sublane tiles (deviceless v5e compiles: 6, 12, 20 and
+    30 heads so; 2 and 4 in tiles of their own, 8, 24 and 32 token by
+    token)."""
+    return kvh > 4 and kvh % 8 != 0
+
+
+def write_rows(pool: jax.Array, layer, phys: jax.Array, off: jax.Array,
+               rows: jax.Array) -> jax.Array:
+    """New rows into `pool` [L, NB, bs, kvH, D] at (layer, phys, off):
+    phys and off [...] (a sequence, or a sequence and a query), rows
+    [..., kvH, D]; a block id out of bounds is dropped.  Where a block
+    lies head by head (`_heads_major`) the write goes through that view,
+    a `[D]` row at (layer, block, head, offset) each: as
+    `.at[layer, phys, off].set` the compiler re-tiles the WHOLE pool
+    token by token for the scatter and back (deviceless v5e compile at
+    30 heads: four copies of a 3 GB pool a layer)."""
+    rows = rows.astype(pool.dtype)
+    kvh = pool.shape[3]
+    if not _heads_major(kvh):
+        return pool.at[layer, phys, off].set(rows)
+    view = jnp.swapaxes(pool, 2, 3).at[
+        layer, phys[..., None], jnp.arange(kvh), off[..., None]].set(rows)
+    return jnp.swapaxes(view, 2, 3)
+
+
 def _window_chunks(window: int, chunk: int, block_size: int) -> int:
     """The most chunks of `chunk` blocks, aligned to absolute positions,
     that `window` consecutive keys straddle."""
@@ -188,7 +231,7 @@ def _block_copy(pool, layer, phys, buf, slot, t, rows, sem):
 
 def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
             tab_ref, q_ref, *refs, nb, bs, kvh, n_heads, n_q, chunk,
-            scale, window):
+            scale, window, heads_major):
     # refs: the pools in HBM, the output, a chunk buffer a pool, the
     # semaphores.  Two pools (K, V) or one whose rows hold both (K ‖ V,
     # or the latent row).  The output keeps the value product's first
@@ -238,13 +281,19 @@ def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
     def _():
         copies(0, 0, True)
 
-    # row = query index * H + head; column = token in chunk * kvH + group
+    # row = query index * H + head; column = token in chunk * kvH + group,
+    # or, where a block lies head by head, block * rows + group * bs +
+    # token in block
     row = lax.broadcasted_iota(jnp.int32, (qh, width), 0)
     col = lax.broadcasted_iota(jnp.int32, (qh, width), 1)
+    if heads_major:
+        group = col % rows // bs
+        token = col // rows * bs + col % bs
+    else:
+        group, token = col % kvh, col // kvh
     # one KV head (the latent pool): every query head reads every row
     own_group = None if kvh == 1 else \
-        (row % n_heads) // (n_heads // kvh) == col % kvh
-    token = col // kvh
+        (row % n_heads) // (n_heads // kvh) == group
     row1 = lax.broadcasted_iota(jnp.int32, (qh, 1), 0)
 
     def step(i, carry):
@@ -298,8 +347,9 @@ def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
 
 
 def _call(q, pools, layer, scalars, *, kvh, n_heads, n_q, scale,
-          out_width, chunk, window=None):
-    """The kernel over `pools` (flat: [L, NB, bs * kvH, W] each) for q
+          out_width, chunk, window=None, heads_major=False):
+    """The kernel over `pools` (flat: [L, NB, bs * kvH, W] each, a
+    block's rows token by token or, `heads_major`, head by head) for q
     [B, Q * H, W], laid as the first pool's rows are: [B, Q * H,
     out_width], the first lanes of the value product over the last
     pool's rows."""
@@ -316,7 +366,7 @@ def _call(q, pools, layer, scalars, *, kvh, n_heads, n_q, scale,
     buf = pltpu.VMEM((2, chunk * rows, W), pools[0].dtype)
     kernel = functools.partial(
         _kernel, nb=nb, bs=rows // kvh, kvh=kvh, n_heads=n_heads, n_q=n_q,
-        chunk=chunk, scale=scale, window=window)
+        chunk=chunk, scale=scale, window=window, heads_major=heads_major)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -375,11 +425,16 @@ def paged_attention(q: jax.Array, k_pool: jax.Array,
     pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
     if v_pool is None:
         q = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
+    # a block that lies head by head: the kernel takes that view, which
+    # is then the bitcast
+    heads_major = _heads_major(kvh)
+    if heads_major:
+        pools = [jnp.swapaxes(pool, 2, 3) for pool in pools]
     out = _call(
         q.reshape(B, Q * H, W),
         [pool.reshape(L, NB, bs * kvh, W) for pool in pools], layer,
         scalars, kvh=kvh, n_heads=H, n_q=Q, scale=1.0 / math.sqrt(D),
-        out_width=W, chunk=chunk, window=window)
+        out_width=W, chunk=chunk, window=window, heads_major=heads_major)
     return out.reshape(B, Q, H, W)[..., W - D:]
 
 

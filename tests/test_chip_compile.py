@@ -22,6 +22,7 @@ through an option of the program; all of it lives in this one file.
 import functools
 import math
 import os
+import re
 
 import numpy as np
 import pytest
@@ -832,6 +833,85 @@ def test_window_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     assert m.alias_size_in_bytes >= kept                # both in place
     assert m.temp_size_in_bytes < 1.5 * GIB
     assert _hbm_gib(compiled) < V5E_HBM_GIB - 0.5
+
+
+@pytest.mark.parametrize("program", ["tick", "insert"])
+def test_gdn_hybrid_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
+    """The engine's decode tick (128 slots x 4096) and its largest insert
+    (512) at the geometry of the benchmark's `reason-decode-gdn-hybrid`
+    cell (gated delta-rule state by slot beside full attention of 30 K/V
+    heads in two paged pools, at Olmo-Hybrid-7B's published widths; the
+    depth, slots, row length, buckets and pool its files state): they
+    compile for v5e; `paged_attention` answers "kernel" and the tick
+    holds one call an attention layer over pools `[2, NB, 16, 30, 128]`
+    that no instruction copies (the compiler lays a block of 30 heads
+    head by head and the kernel takes that view: a bitcast); the
+    delta-rule state steps through one `kda_step` kernel call a layer
+    over the WHOLE donated stack `[6, 128, 15, 96, 384]`, two heads a
+    row, which no instruction copies, slices or re-stacks and whose
+    bytes in HBM are the mathematics' 2,211,840 a slot a layer (no
+    padded lane: the stack's argument is exactly that many); pools and
+    state are updated in place, and arguments + temporaries fit HBM."""
+    eng = _serving_cell("reason-decode-gdn-hybrid", one_chip)
+    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+                                eng.published)
+    pools = eng.pools
+    assert (published["num_hidden_layers"], published["hidden_size"],
+            published["vocab_size"], mc.n_gdn_layers, mc.n_attn_layers,
+            mc.n_kv_heads, mc.gdn_key_dim, mc.gdn_value_dim, mc.rope_theta,
+            ec.num_slots, ec.max_seq_len, ec.prefill_buckets[-1]) \
+        == (8, 3840, 100352, 6, 2, 30, 96, 192, None, 128, 4096, 512)
+    assert model.paged_attention(pools) == "kernel"
+    pool = pools["k"].shape
+    assert pool == (2, ec.pool_blocks, 16, 30, 128) == pools["v"].shape
+    state, = _slot_state(eng, one_chip)
+    stack = state["S"].shape
+    assert stack == (6, 128, 15, 96, 384)
+    from ray_tpu.ops import kda
+
+    assert kda.engages(*stack[-2:], state["S"].dtype)
+    compiled = (_compiled_cell_tick if program == "tick"
+                else _compiled_insert)(eng, one_chip)
+    text = compiled.as_text()
+    results = _results(text)
+    m = compiled.memory_analysis()
+    kept = sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in list(pools.values()) + list(state.values()))
+    print(program, "GiB", _hbm_gib(compiled), "temp",
+          m.temp_size_in_bytes / GIB, "args", m.argument_size_in_bytes / GIB)
+    assert m.alias_size_in_bytes >= kept                # both in place
+    assert _hbm_gib(compiled) < V5E_HBM_GIB - 0.5
+    # the state's bytes are the mathematics': what the program's
+    # arguments weigh is the shapes' own product, no padded tile
+    args = math.prod(stack) * 4 + sum(
+        math.prod(x.shape) * x.dtype.itemsize
+        for x in jax.tree.leaves((eng.params, pools, state["conv"])))
+    assert math.prod(stack[2:]) * 4 == 2211840
+    assert abs(m.argument_size_in_bytes - args) < 0.01 * GIB
+    layouts = set(re.findall(
+        r"f32\[6,128,15,96,384\]\{([^}]*)\}", text))
+    assert layouts <= {"4,3,2,1,0:T(8,128)", "4,3,2,1,0"} \
+        and "4,3,2,1,0:T(8,128)" in layouts, layouts        # whole tiles
+    if program == "insert":
+        plain = _without_metadata(text)
+        assert "kda_step" not in plain and "paged_attention" not in plain
+        assert m.temp_size_in_bytes < 1.5 * GIB
+        return
+    assert text.count("kda_step") >= stack[0]
+    assert text.count("paged_attention") >= mc.n_attn_layers
+    assert "parameter" in {op for op, shapes in results if stack in shapes}
+    assert not [op for op, shapes in results if stack[1:] in shapes]
+    flat = pool[:2] + (pool[2] * pool[3], pool[4])  # the kernel's view
+    assert "bitcast" in {op for op, shapes in results if flat in shapes}
+    moved = [(op, shapes) for op, shapes in results
+             if op in ("copy", "copy-start", "copy-done", "dynamic-slice",
+                       "dynamic-update-slice", "select", "fusion")
+             and shapes & {stack, pool, pool[1:], flat, flat[1:]}]
+    assert not moved, moved
+    # no padded [B, S_pad] view of a pool is built
+    padded = (ec.num_slots, ec.max_seq_len) + pool[3:]
+    assert not any(padded in shapes for _, shapes in results)
+    assert m.temp_size_in_bytes < 0.5 * GIB
 
 
 def test_train_step_holds_flash_kernel_and_fits_one_v5e(topo, on_tpu):

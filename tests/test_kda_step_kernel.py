@@ -126,6 +126,9 @@ def test_live_plan_lists_the_live_slots_first(case):
     (256, 128, jnp.float32, True, True),
     (16, 16, jnp.float32, True, False),         # the audit's heads
     (128, 64, jnp.float32, True, False),
+    (96, 384, jnp.float32, True, True),         # two heads of 192 a row
+    (96, 192, jnp.float32, True, False),        # unpacked: half a lane row
+    (100, 128, jnp.float32, True, False),       # not whole sublanes
     (128, 128, jnp.bfloat16, True, False),      # a state kept in bf16
     (128, 128, jnp.float32, False, False),      # the CPU as it is
 ])
@@ -133,3 +136,73 @@ def test_engages_by_backend_shape_and_dtype_alone(monkeypatch, dk, dv,
                                                   dtype, forced, want):
     monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", forced)
     assert kda.engages(dk, dv, dtype) is want
+
+
+# (heads, dk, dv): keys of 96 against values of 192 (two heads a row of
+# the stack), the square heads of 128, a tiny odd pair (one head a row)
+# and a tiny pair that packs.
+SHAPES = {"96x192": (2, 96, 192), "128x128": (2, 128, 128),
+          "5x3": (3, 5, 3), "24x64": (4, 24, 64)}
+LIVE = {"none": [], "one": [2], "two": [0, 3], "three": [0, 1, 3],
+        "all": [0, 1, 2, 3]}
+
+
+@pytest.mark.parametrize("decay", ["a_head", "a_channel"])
+@pytest.mark.parametrize("live", sorted(LIVE))
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_packed_stack_steps_as_kda_step_does(interpreter, shape, live,
+                                             decay):
+    """The kernel over the stack as `pack` lays it, at key and value
+    sizes that differ, with `g` one number a head or one a channel,
+    against `kda_step` on the unpacked rows; write strengths up to 2.
+    Dead slots and the other layer keep their bits."""
+    h, dk, dv = SHAPES[shape]
+    n, steps = 4, 6
+    p = kda.heads_a_row(h, dv)
+    assert p == {"96x192": 2, "24x64": 2}.get(shape, 1)
+    ks = jax.random.split(jax.random.key(40), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (steps, n, h, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (steps, n, h, dk)))
+    v = jax.random.normal(ks[2], (steps, n, h, dv))
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[3], (steps, n, h)))
+    g = -jnp.exp(jax.random.uniform(
+        ks[4], (steps, n, h, 1 if decay == "a_head" else dk),
+        minval=np.log(1e-3), maxval=np.log(4.0)))
+    S0 = jax.random.normal(ks[5], (LK, n, h, dk, dv))
+    active = jnp.zeros(n, bool).at[jnp.asarray(LIVE[live], int)].set(True)
+    plan = kda.live_plan(active, n)
+
+    def plain(S, x):
+        o, new = kda.kda_step(S, *x)
+        keep = active.reshape(-1, 1, 1, 1)
+        return jnp.where(keep, new, S), jnp.where(keep[..., 0], o, 0)
+
+    def kernel(S, x):
+        o, S = kda.kda_step_live(S, LAYER, *x, plan)
+        return S, o
+
+    xs = (q, k, v, g, beta)
+    S_want, o_want = jax.jit(lambda S: lax.scan(plain, S, xs))(S0[LAYER])
+    stack, o_got = jax.jit(lambda S: lax.scan(kernel, S, xs))(
+        kda.pack(S0, p))
+    assert stack.shape == (LK, n, h // p, dk, p * dv)
+    S_got = kda.unpack(stack, p)
+    mask = np.asarray(active)
+    np.testing.assert_array_equal(S_got[LAYER][~mask], S0[LAYER][~mask])
+    np.testing.assert_array_equal(S_got[1 - LAYER], S0[1 - LAYER])
+    assert not np.asarray(o_got)[:, ~mask].any()
+    if mask.any():
+        for want, got in ((S_want[mask], S_got[LAYER][mask]),
+                          (o_want[:, mask], o_got[:, mask])):
+            assert np.abs(got - want).max() <= REL * np.abs(want).max()
+        assert np.abs(S_want[mask] - S0[LAYER][mask]).max() > 0.1
+
+
+def test_pack_lays_heads_side_by_side_and_unpack_undoes_it():
+    S = jnp.arange(2 * 4 * 3 * 5, dtype=jnp.float32).reshape(2, 4, 3, 5)
+    packed = kda.pack(S, 2)
+    assert packed.shape == (2, 2, 3, 10)
+    np.testing.assert_array_equal(packed[1, 0, :, 5:], S[1, 1])
+    np.testing.assert_array_equal(kda.unpack(packed, 2), S)
+    assert kda.pack(S, 1) is S and kda.unpack(S, 1) is S

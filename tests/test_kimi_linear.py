@@ -215,10 +215,16 @@ def test_prefill_hand_off(model, case):
 @pytest.mark.parametrize("lo,hi", [(-1e-4, -1e-6), (-12.0, -3.0),
                                    (-2.0, -0.01)],
                          ids=["decay_near_1", "decay_near_0", "mixed"])
-def test_chunkwise_kda_equals_recurrence(lo, hi):
+@pytest.mark.parametrize("width", ["a_channel", "a_head"])
+def test_chunkwise_kda_equals_recurrence(lo, hi, width):
+    """`width` a_head: `g` [.., H, 1], one decay a head, keys of another
+    size than values and write strengths up to 2 (the gated delta rule
+    of `models/gdn_hybrid.py`)."""
     from ray_tpu.ops.kda import kda_chunked, kda_step
 
     B, T, H, dk, dv = 2, 50, 3, 16, 8
+    if width == "a_head":
+        dk, dv = 12, 24
     ks = jax.random.split(jax.random.key(0), 7)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
     q = unit(jax.random.normal(ks[0], (B, T, H, dk)))
@@ -226,6 +232,8 @@ def test_chunkwise_kda_equals_recurrence(lo, hi):
     v = jax.random.normal(ks[2], (B, T, H, dv))
     beta = jax.nn.sigmoid(jax.random.normal(ks[3], (B, T, H)))
     g = jax.random.uniform(ks[4], (B, T, H, dk), minval=lo, maxval=hi)
+    if width == "a_head":
+        beta, g = 2 * beta, g[..., :1]
     S = S0 = jax.random.normal(ks[5], (B, H, dk, dv))
     n_real = jnp.asarray([50, 37])
     outs, states = [], []

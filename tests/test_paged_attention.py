@@ -29,7 +29,11 @@ ATOL = 2 ** -6          # 2 ulp of a bf16 value in [1, 2)
 D, BS, NB_ROW, L, LAYER, CHUNK = 128, 16, 8, 3, 2, 3
 # dead, one row, a whole block, a block and a row, a full table row
 LENGTHS = (0, 1, 16, 17, NB_ROW * BS)
-GROUPS = {"rep4": (8, 2), "rep1": (4, 4), "mqa": (4, 1)}
+# the last three: more than 4 KV heads that are not whole sublane tiles,
+# whose blocks the kernel views head by head (as many K/V heads as query
+# heads at 30 and at a tiny 6; groups of two at 12)
+GROUPS = {"rep4": (8, 2), "rep1": (4, 4), "mqa": (4, 1),
+          "mha30": (30, 30), "mha6": (6, 6), "rep2_kv12": (24, 12)}
 
 
 def _case(heads, kv_heads, n_q, seed):
