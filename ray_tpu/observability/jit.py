@@ -30,7 +30,11 @@ On top of the trace guard rides the XLA attribution plane
 capture worker so the extra compile never lands on the caller), and
 every ``xla_wall_sample_every``-th steady-state call
 is fenced with ``block_until_ready`` to sample an honest execution wall
-(0 disables sampling: the fence never runs on the hot path).
+(0 disables sampling: the fence never runs on the hot path). An owner
+that keeps its calls in flight and waits for them elsewhere (the serving
+engine's decode tick) passes ``fence_samples=False``: the sampled call
+is then only marked, and the owner hands in the wall it measured where
+it waits (:meth:`TrackedJit.take_sample` / :meth:`TrackedJit.record_wall`).
 """
 
 from __future__ import annotations
@@ -104,12 +108,16 @@ class TrackedJit:
     """
 
     def __init__(self, fn: Callable, *, name: Optional[str] = None,
-                 trace_budget: Optional[int] = None, **jit_kwargs):
+                 trace_budget: Optional[int] = None,
+                 fence_samples: bool = True, **jit_kwargs):
         import jax
 
         self.name = name or getattr(fn, "__name__", "jitted")
         self.traces = 0
         self.calls = 0
+        # False: a sampled call is marked, not fenced (`take_sample`)
+        self._fence_samples = fence_samples
+        self._sample_due = None
         if trace_budget is None:
             from ray_tpu._private.config import GlobalConfig
 
@@ -161,7 +169,11 @@ class TrackedJit:
             dt = time.perf_counter() - t0
             self._on_compile(dt, args, kwargs)
         elif sample:
-            self._sample_wall(out, t0, exposed0, args, kwargs)
+            due = (_arg_signature(args, kwargs), exposed0)
+            if self._fence_samples:
+                self._sample_wall(out, t0, due)
+            else:
+                self._sample_due = due
         return out
 
     def _on_compile(self, seconds: float, args, kwargs) -> None:
@@ -217,27 +229,50 @@ class TrackedJit:
                 f"check for varying shapes/dtypes/static args on the "
                 f"hot path", RecompileWarning, stacklevel=4)
 
-    def _sample_wall(self, out, t0: float, exposed0: float,
-                     args, kwargs) -> None:
-        """Fence the sampled call and hand its wall (plus the exposed
-        collective seconds it straddled) to the attribution plane."""
+    def _sample_wall(self, out, t0: float, due) -> None:
+        """Fence the sampled call and hand in its wall."""
         try:
             import jax
 
             from ray_tpu.observability.profiling import trace_span
 
             # The fence stands in a profiler trace under whatever span
-            # holds the call (an engine's `llm_engine.tick_dispatch`).
+            # holds the call.
             with trace_span("jit.wall_sample", fn=self.name):
                 jax.block_until_ready(out)
-            wall = time.perf_counter() - t0
-            exposed = max(_cumulative_exposed() - exposed0, 0.0)
-            from ray_tpu.observability import xla as _xla
-
-            _xla.on_tracked_sample(self, _arg_signature(args, kwargs),
-                                   wall, exposed)
+            self._report_wall(due, time.perf_counter() - t0)
         except Exception:
             pass  # sampling must never break the hot path
+
+    def take_sample(self):
+        """With `fence_samples=False`: the mark of the call just made if
+        it was a sampled one (for `record_wall`, once the owner has
+        waited for that call's outputs), else None."""
+        due, self._sample_due = self._sample_due, None
+        return due
+
+    def record_wall(self, due, wall_s: float) -> None:
+        """Hand in the wall the owner measured for the call
+        `take_sample` marked. `jit.wall_sample` is then an instant
+        where the owner stood when it knew the wall."""
+        try:
+            from ray_tpu.observability.profiling import trace_span
+
+            with trace_span("jit.wall_sample", fn=self.name):
+                pass
+            self._report_wall(due, wall_s)
+        except Exception:
+            pass  # sampling must never break the hot path
+
+    def _report_wall(self, due, wall_s: float) -> None:
+        """A sampled call's wall, plus the exposed collective seconds
+        it straddled, to the attribution plane (`due`: the call's
+        signature and the exposed seconds before it)."""
+        from ray_tpu.observability import xla as _xla
+
+        sig, exposed0 = due
+        _xla.on_tracked_sample(
+            self, sig, wall_s, max(_cumulative_exposed() - exposed0, 0.0))
 
     # -- AOT surface -------------------------------------------------
 
@@ -334,6 +369,7 @@ def _cumulative_exposed() -> float:
 def tracked_jit(fn: Optional[Callable] = None, *,
                 name: Optional[str] = None,
                 trace_budget: Optional[int] = None,
+                fence_samples: bool = True,
                 **jit_kwargs):
     """Drop-in ``jax.jit`` replacement with compile telemetry.
 
@@ -343,10 +379,10 @@ def tracked_jit(fn: Optional[Callable] = None, *,
     if fn is None:
         def deco(f):
             return TrackedJit(f, name=name, trace_budget=trace_budget,
-                              **jit_kwargs)
+                              fence_samples=fence_samples, **jit_kwargs)
         return deco
     return TrackedJit(fn, name=name, trace_budget=trace_budget,
-                      **jit_kwargs)
+                      fence_samples=fence_samples, **jit_kwargs)
 
 
 def jit_stats() -> Dict[str, Dict[str, float]]:

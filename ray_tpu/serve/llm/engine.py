@@ -26,6 +26,21 @@ Design — a bounded set of compiled programs, everything else is data:
   still one compiled program — so the host's per-tick work (dispatch,
   token readback, slot bookkeeping) is paid once per block, not once
   per token.
+- The plain tick is software-pipelined ONE deep: a step dispatches tick
+  k while tick k-1 still runs, THEN waits for tick k-1, reads it back
+  and emits its tokens, so the chip has its next tick queued while the
+  host does its own work of a tick. Everything tick k takes from tick
+  k-1 is a device array chained from output to input; what the host
+  has to know one tick early it counts (`_rows`: the rows dispatched a
+  slot, against `_row_limit`, say which slots tick k-1 finishes by
+  `max_tokens` or the sequence limit; a slot that ends by `eos_id` or a
+  stop token is found one tick late, computed once more, and its row
+  of the tick in flight dropped: a row is emitted only to the handle
+  that held the slot when the tick was dispatched). Whatever needs the
+  emitted tokens and the device state to agree (a checkpoint, an
+  adoption, a control call, a speculative round, an engine with no slot
+  left to tick, `drain`, `run`'s exit) first reads the tick in flight
+  back: `_settle`, the one such point.
 - Jitted prefill at a small set of padded prompt-length buckets: the
   insert prefills the part of the prompt the prefix cache does not hold
   over the slot's gathered history and scatters its rows into the
@@ -73,12 +88,14 @@ class EngineConfig:
     eos_id: Optional[int] = None    # config-level end-of-sequence token
     # Decode steps per tick dispatch (lax.scan inside the ONE tick
     # program). >1 pays the host's per-tick work (dispatch, readback,
-    # slot bookkeeping, during which the device idles) once per block
-    # instead of once per token — unmeasured on a local chip — at the cost
-    # of up to K-1 speculative tokens per finished slot (computed, then
-    # discarded host-side; parity is unaffected because truncation
+    # slot bookkeeping) once per block instead of once per token, at the
+    # cost of up to K-1 speculative tokens per finished slot (computed,
+    # then discarded host-side; parity is unaffected because truncation
     # happens at the same stop condition single-stepping would hit) and
-    # admission latency of one block.
+    # admission latency of one block. Since the tick is pipelined (PR
+    # 41) the device no longer idles during that work, so a block only
+    # pays where the host's work of a tick outlasts the tick; the
+    # measured pair is in ROADMAP.md, Design D4.
     decode_block: int = 1
     # The one KV layout: a fixed pool of [kv_block_size]-row blocks
     # shared by all slots through per-slot block tables. The field has
@@ -350,61 +367,91 @@ class _Slot:
 
 class _LoopClock:
     """Where the scheduler thread's time goes, always on: seconds on
-    `time.monotonic()` and calls of each top-level phase of `_step` and
-    `run`, read through `stats()["loop"]`. `phase("llm_engine.<name>")`
-    is the ONE way a phase is opened: it opens that span of a profiler
-    trace and adds the elapsed time to `<name>`'s counter, so the two
-    have the same boundaries. A phase opened inside another (a
-    landing before a tier lookup, inside `admit`) is a bare span: the
-    outer one has its time, and the phases never sum past the wall.
-    `now` is the clock's last reading. `warmup` starts it anew, so that
-    it holds no compile. Scheduler thread only."""
+    `time.monotonic()` and calls of each phase of `_step` and `run`,
+    read through `stats()["loop"]`. `phase("llm_engine.<name>")` is the
+    ONE way a phase is opened: it opens that span of a profiler trace
+    and adds the elapsed time to `<name>`'s counter, so the two have
+    the same boundaries. A phase opened inside another (a landing
+    before a tier lookup inside `admit`, a `settle` inside `ctrl`, the
+    `tick_ready`, `tick_readback` and `emit` of a `settle`) stops the
+    outer one's clock until it closes: every instant is counted once,
+    under the innermost phase open, and every phase counts its calls.
+    `now` is the clock's last reading. Beside them `ticks` (dispatched),
+    `overlapped` (those dispatched while another was in flight) and
+    `settles` (times the tick in flight was read back out of turn, by
+    cause). `warmup` starts it anew, so that it holds no compile.
+    Scheduler thread only."""
 
-    PHASES = ("ctrl", "admit", "first_token_wait", "tick_dispatch",
-              "spill_land", "tick_ready", "tick_readback", "emit",
-              "gauges", "idle")
+    PHASES = ("ctrl", "admit", "first_token_wait", "settle",
+              "tick_dispatch", "spill_land", "tick_ready", "tick_readback",
+              "emit", "gauges", "idle")
+    # who reads the tick in flight back out of turn (`LLMEngine._settle`)
+    SETTLES = ("preempt", "ctrl", "adopt", "prefill_only", "spec", "empty",
+               "drain", "stop")
 
     def __init__(self):
-        self.steps = self.ticks = 0
+        self.steps = self.ticks = self.overlapped = 0
+        self.settles = dict.fromkeys(self.SETTLES, 0)
         self.seconds = dict.fromkeys(self.PHASES, 0.0)
         self.calls = dict.fromkeys(self.PHASES, 0)
         self.now = time.monotonic()
-        self._open = False
+        self._open: List[str] = []      # innermost last
 
     def phase(self, span: str, **args) -> "_Phase":
         return _Phase(self, span.rpartition(".")[2],
                       trace_span(span, **args))
 
+    def _read(self) -> None:
+        """Lay the time since the last reading to the innermost phase."""
+        t = time.monotonic()
+        if self._open:
+            self.seconds[self._open[-1]] += t - self.now
+        self.now = t
+
     def stats(self) -> Dict[str, Any]:
         return {"steps": self.steps, "ticks": self.ticks,
+                "overlapped": self.overlapped,
+                "settles": dict(self.settles),
                 "seconds": dict(self.seconds), "calls": dict(self.calls)}
 
 
 class _Phase:
     """One `with` of `_LoopClock.phase`; yields the span."""
 
-    __slots__ = ("_clock", "_name", "_span", "_t0")
+    __slots__ = ("_clock", "_name", "_span")
 
     def __init__(self, clock, name, span):
         self._clock, self._name, self._span = clock, name, span
-        self._t0 = None                 # stays None inside another phase
 
     def __enter__(self):
         span = self._span.__enter__()
-        c = self._clock
-        if not c._open:
-            c._open = True
-            self._t0 = c.now = time.monotonic()
+        self._clock._read()
+        self._clock._open.append(self._name)
         return span
 
     def __exit__(self, *exc):
-        if self._t0 is not None:
-            c = self._clock
-            c.now = time.monotonic()
-            c.seconds[self._name] += c.now - self._t0
-            c.calls[self._name] += 1
-            c._open = False
+        c = self._clock
+        c._read()
+        c._open.pop()
+        c.calls[self._name] += 1
         return self._span.__exit__(*exc)
+
+
+class _Tick:
+    """A dispatched tick (or speculative round) that has not been read
+    back: its output arrays, the slots that were live in it with the
+    handle each held THEN (a slot released and filled again meanwhile
+    must not get the old request's row), the clock's reading when its
+    dispatch began, `TrackedJit`'s mark if it is a sampled call, and
+    the model's counters as the tick leaves them."""
+
+    __slots__ = ("outs", "live", "handles", "at", "spec", "sample",
+                 "counters")
+
+    def __init__(self, outs, live, handles, at, spec, sample, counters):
+        self.outs, self.live, self.handles = outs, live, handles
+        self.at, self.spec, self.sample = at, spec, sample
+        self.counters = counters
 
 
 class LLMEngine:
@@ -523,13 +570,30 @@ class LLMEngine:
         self._pos = jnp.zeros((B,), jnp.int32)
         self._key = jax.random.key(rng_seed)
         # What the model's decode step counts (models/serving.py),
-        # summed on the device tick by tick; `stats()` reads it. Not
-        # donated: `stats()` may read it from another thread.
+        # summed on the device tick by tick. Not donated: `stats()`
+        # reads, maybe from another thread, those of the last tick read
+        # back (`_counters_read`), while the next tick takes them on.
         self._counters = (model.init_counts(model_config)
                           if model.init_counts else {})
+        self._counters_read = self._counters
         # Host-side mirrors fed into each program call (tiny transfers).
+        # `_active`: the slot holds a decoding sequence (from its last
+        # insert until it is released).
         self._active = np.zeros((B,), bool)
         self._temp = np.zeros((B,), np.float32)
+        # Rows DISPATCHED a slot: prompt + generated tokens, those of a
+        # tick in flight among them (the pending token's own row too),
+        # and the count at which the sequence ends by `max_tokens` or
+        # the sequence limit. The host knows both before the tick in
+        # flight ends: the next tick's mask is `_active & (_rows <
+        # _row_limit)`, and the rows it reads and the ring it needs are
+        # counted from `_rows`.
+        self._rows = np.zeros((B,), np.int64)
+        self._row_limit = np.zeros((B,), np.int64)
+        # Dispatched, not read back: at most one between two steps
+        # (oldest first). Scheduler thread only.
+        self._flying: deque = deque()
+        self._ready_at = 0.0            # the clock when the last tick was ready
 
         # Host-side scheduler state. One queue per SLO lane; admission
         # drains "interactive" before "batch" (all queue accesses under
@@ -606,8 +670,11 @@ class LLMEngine:
         from ray_tpu.observability import serve_metrics, tracked_jit
         from ray_tpu.observability.device import ensure_sampler_registered
 
+        # No fence inside a dispatch (it would drain the pipeline and
+        # time two ticks as one): a sampled tick's wall is taken where
+        # `_read_back` waits for it anyway (`_land_tick`).
         self._jit_tick = tracked_jit(
-            self._tick_fn, name="llm_engine_tick",
+            self._tick_fn, name="llm_engine_tick", fence_samples=False,
             trace_budget=1, donate_argnums=(1, 3, 4, 9))
         self._jit_insert = tracked_jit(
             self._insert_fn, name="llm_engine_insert",
@@ -973,6 +1040,10 @@ class LLMEngine:
             raise ValueError(
                 f"max_tokens {request.max_tokens} already reached by "
                 f"the checkpoint ({len(state.tokens)} tokens)")
+        if len(state.prompt) + len(state.tokens) >= c.max_seq_len:
+            raise ValueError(
+                f"the checkpoint already holds max_seq_len "
+                f"{c.max_seq_len} rows: nothing is left to decode")
         if request.slo not in ("interactive", "batch"):
             raise ValueError(
                 f"slo must be 'interactive' or 'batch', got "
@@ -1034,7 +1105,7 @@ class LLMEngine:
     def has_work(self) -> bool:
         return (any(self._queues.values()) or bool(self._active.any())
                 or bool(self._cancelled) or bool(self._ctrl_q)
-                or bool(self._chunking))
+                or bool(self._chunking) or bool(self._flying))
 
     # ------------------------------------------------------------ scheduling
 
@@ -1098,8 +1169,7 @@ class LLMEngine:
             chunk_budget = 0
             if handle._chunk_idx == len(handle._chunk_ends):
                 self._chunking.popleft()
-                self._active[slot] = True
-                self._temp[slot] = handle.request.temperature
+                self._activate(handle, slot, fresh=True)
                 inserted.append((slot, True))
         while self._free:
             handle = self._pop_next()
@@ -1170,10 +1240,23 @@ class LLMEngine:
                 handle.meter.note_chip(
                     "prefill", time.monotonic() - t_admit)
             self._occupy(handle, slot)
-            self._active[slot] = True
-            self._temp[slot] = req.temperature
+            self._activate(handle, slot, fresh)
             inserted.append((slot, fresh))
         return inserted
+
+    def _activate(self, handle: RequestHandle, slot: int,
+                  fresh: bool) -> None:
+        """The sequence's prompt is in: the slot joins the next tick.
+        Its rows so far are the prompt's and its tokens', the pending
+        one's own among them (a fresh insert's first token is sampled
+        and not yet on the handle)."""
+        req = handle.request
+        P = len(req.prompt)
+        self._active[slot] = True
+        self._temp[slot] = req.temperature
+        self._rows[slot] = P + len(handle.tokens) + int(fresh)
+        self._row_limit[slot] = min(P + req.max_tokens,
+                                    self.config.max_seq_len)
 
     def _occupy(self, handle: RequestHandle, slot: int) -> None:
         """The request has its slot: the queue wait ends here."""
@@ -1434,6 +1517,7 @@ class LLMEngine:
         one past the pool (out-of-bounds writes drop under jit)."""
         import numpy as np
 
+        self._settle("adopt")
         t_mig = time.time()
         req = handle.request
         st = handle.kv_state
@@ -1603,6 +1687,7 @@ class LLMEngine:
                 reason = "length"
         donate = False
         if reason is None:
+            self._settle("prefill_only")
             handle.kv_state = self._export_state(slot)
             reason = "prefill"
             donate = True
@@ -1787,6 +1872,8 @@ class LLMEngine:
         with self._lock:
             batch = list(self._ctrl_q)
             self._ctrl_q.clear()
+        if batch:
+            self._settle("ctrl")
         for fn, box, ev in batch:
             try:
                 box.append(fn())
@@ -1875,9 +1962,10 @@ class LLMEngine:
         (handle.kv_state), the slot and blocks are released, and the
         next admission resumes decoding through the adopt path — the
         preempt → resume cycle is token-invisible to the client."""
-        st = self._slots[slot]
-        handle = st.handle
         self._refuse_if_pinned("preemption", "a checkpoint")
+        # the exported pending token must be one the client has
+        self._settle("preempt")
+        handle = self._slots[slot].handle
         if handle is None:
             raise ValueError(f"slot {slot} is not live")
         handle.kv_state = self._export_state(slot)
@@ -1910,6 +1998,11 @@ class LLMEngine:
             not self._free or self._admit_blocked)
         if self._preempt_gate.propose(0, 1 if pressure else 0) != 1:
             return
+        if self._settle("preempt"):     # a candidate may just have ended
+            batch_slots = [s for s in batch_slots
+                           if self._slots[s].handle is not None]
+            if not batch_slots:
+                return
         victim = max(batch_slots,
                      key=lambda s: self._slots[s].handle.admitted_at)
         try:
@@ -2069,9 +2162,12 @@ class LLMEngine:
         """One scheduler iteration: process cancellations, apply the
         preemption policy, admit queued requests into free slots
         (prefill + first token each; prefill_only requests finish here
-        with their checkpoint), then one decode tick — speculative when
-        every live slot qualifies, plain otherwise — for every live
-        slot. Returns True if any work was done."""
+        with their checkpoint), then dispatch one decode tick for every
+        slot that has a token left to decode and, behind it, read the
+        tick of the step before back and emit its tokens (a
+        speculative round, when every live slot qualifies, is read back
+        in its own step; a step that finds no slot to tick reads the
+        tick in flight back). Returns True if any work was done."""
         with trace_span("llm_engine.step"):
             self._in_step = True
             self._loop.steps += 1
@@ -2083,7 +2179,19 @@ class LLMEngine:
 
     def _step(self) -> bool:
         """`step`'s body. Its phases stand in a profiler trace as
-        `llm_engine.<phase>` spans that together cover the step."""
+        `llm_engine.<phase>` spans that together cover the step:
+        `ctrl`, `admit`, `first_token_wait` (an admitting step),
+        `tick_dispatch` (tick k; `in_flight=1` while tick k-1 runs),
+        `spill_land`, `tick_wait` (tick k-1: `tick_ready` +
+        `tick_readback`), `emit` (tick k-1's tokens), `gauges`. The
+        first tick after a settle has none to wait for, and the step
+        that finds no slot to tick writes `settle` (the last tick's
+        `tick_wait` and `emit` inside it) in `tick_dispatch`'s place.
+        Who the tick advances is counted under `admit` (once more under
+        `first_token_wait`, where a first token can end its sequence,
+        and after a round's settle, which can end any), and a round's
+        last condition is read under `tick_dispatch`: no wait of the
+        host's lies between two phases."""
         import numpy as np
 
         phase = self._loop.phase
@@ -2097,11 +2205,13 @@ class LLMEngine:
         with phase("llm_engine.admit") as sp:
             inserted = self._admit()
             sp.set_metadata(admitted=len(inserted))
+            mask, live = self._tick_slots()
         if inserted:
             # First generated token per freshly-prefilled slot (before
             # the tick below overwrites it with the second). Adopted
             # slots skip this: their pending token was emitted by the
-            # exporting engine already.
+            # exporting engine already. The insert ran behind the tick
+            # in flight, whose tokens go out after this step's dispatch.
             with phase("llm_engine.first_token_wait"):
                 tok_host = np.asarray(self._tok)
                 for slot, fresh in inserted:
@@ -2111,77 +2221,148 @@ class LLMEngine:
                         self._finish_prefill(slot, int(tok_host[slot]))
                     else:
                         self._emit(slot, int(tok_host[slot]))
-        if not self._active.any():
+                # a first token can end its sequence
+                mask, live = self._tick_slots()
+        want_spec = live.size > 0 and self._spec_wanted(live)
+        settled = want_spec and self._settle("spec")
+        if settled:
+            # a round reads `_pos` on the host and its length is known
+            # only when it is read back; the tick that landed may have
+            # ended slots (eos, a stop token): who is left
+            mask, live = self._tick_slots()
+        if not live.size:
+            settled = settled or self._settle("empty")
             self._land_spills()         # no tick to land behind
             with phase("llm_engine.gauges"):
                 self._update_gauges()
-            return bool(inserted) or did_cancel or did_ctrl
-        live = np.nonzero(self._active)[0]
-        if self._ring is not None:
-            self._cover_rings(live)
-        with phase("llm_engine.tick_dispatch", live=len(live),
-                   **self._live_rows(live)):
-            t_tick = self._loop.now
+            return bool(inserted) or did_cancel or did_ctrl or settled
+        with phase("llm_engine.tick_dispatch") as sp:
+            at, sample = self._loop.now, None
+            spec = want_spec and self._spec_fits(live)
+            if self._ring is not None:
+                self._cover_rings(live)
+            sp.set_metadata(live=len(live), in_flight=len(self._flying),
+                            **self._live_rows(live))
             self._loop.ticks += 1
-            spec = self._spec_ready(live)
+            self._loop.overlapped += bool(self._flying)
             if spec:
-                out = self._spec_dispatch()
+                outs = self._spec_dispatch(mask)
             else:
                 (self._cache, self._tok, self._pos, self._key, out,
                  self._counters, *state) = self._jit_tick(
                     self.params, self._cache, self._tick_tables(),
-                    self._tok, self._pos, self._active.copy(),
+                    self._tok, self._pos, mask,
                     self._temp.copy(), self._key, self._counters,
                     self._slot_state)
                 if state:
                     self._slot_state, = state
+                outs = (out,)                           # [K, B]
+                sample = self._jit_tick.take_sample()
+                self._rows[live] += self.config.decode_block
+            self._flying.append(_Tick(
+                outs, live, [self._slots[s].handle for s in live], at, spec,
+                sample, self._counters))
         # What this step's admissions evicted lands while the chip
         # runs their inserts and the tick.
         self._land_spills()
-        with trace_span("llm_engine.tick_wait"):
-            if spec:
-                toks_host, n_emit = self._spec_wait(*out)
-            else:
-                toks_host, = self._read_back(out)       # [K, B]
-        with phase("llm_engine.emit"):
-            # the tick's wall time, dispatch to readback, on the one clock
-            self._credit_decode(live, self._loop.now - t_tick)
-            for slot in live:
-                s = int(slot)
-                n = int(n_emit[s]) if spec else toks_host.shape[0]
-                if spec and n > 0:
-                    # Per-slot speculative accounting: a live slot's
-                    # round proposed spec_k - 1 drafts, accepted n - 1.
-                    h = self._slots[s].handle
-                    if h is not None and h.meter is not None:
-                        h.meter.note_spec(self.config.spec_k - 1, n - 1)
-                for k in range(n):
-                    if self._slots[s].handle is None:
-                        break      # finished earlier in the block —
-                        #            remaining tokens were speculative
-                    self._emit(s, int(toks_host[k, s]))
+        if spec or len(self._flying) > 1:
+            self._land_tick()           # a round: its own; else tick k-1
         with phase("llm_engine.gauges"):
             self._update_gauges()
         return True
+
+    def _tick_slots(self):
+        """The slots the next tick advances, as its mask and as
+        indices: those that hold a decoding sequence which the ticks
+        dispatched so far do not finish by `max_tokens` or the sequence
+        limit (a slot that ends by `eos_id` or a stop token is still
+        here until that token is read)."""
+        import numpy as np
+
+        mask = self._active & (self._rows < self._row_limit)
+        return mask, np.nonzero(mask)[0]
+
+    def _settle(self, cause: str) -> bool:
+        """Read the tick in flight back and emit its tokens, out of
+        turn: after it the handles' tokens, the host's counts and the
+        device's state agree. Everything that reads a slot's state, or
+        hands it on, calls this first (`cause` says who; counted in
+        `stats()["loop"]["settles"]`). False where nothing was in
+        flight."""
+        if not self._flying:
+            return False
+        with self._loop.phase("llm_engine.settle") as sp:
+            sp.set_metadata(cause=cause)
+            self._loop.settles[cause] += 1
+            while self._flying:
+                self._land_tick()
+        return True
+
+    def _land_tick(self) -> None:
+        """Wait for the oldest dispatched tick (or round), read it back
+        (`llm_engine.tick_wait`) and emit its tokens
+        (`llm_engine.emit`), each row to the handle that held the slot
+        when the tick was dispatched and to no other. The tick's wall
+        (a sampled call's `jit.wall_sample`, and what the handles are
+        billed) is known when the wait ends and handed on under
+        `emit`."""
+        tick = self._flying.popleft()
+        ready_before = self._ready_at
+        with trace_span("llm_engine.tick_wait"):
+            if tick.spec:
+                toks_host, n_emit = self._spec_wait(*tick.outs)
+            else:
+                toks_host, = self._read_back(*tick.outs)    # [K, B]
+        with self._loop.phase("llm_engine.emit"):
+            # The tick's wall on the one clock: it could not start
+            # before its dispatch nor before the tick ahead of it was
+            # done, and `tick_ready` ended when the host knew it done
+            # (between two ticks of a full pipeline: the interval
+            # between their `tick_ready` ends).
+            wall = self._ready_at - max(tick.at, ready_before)
+            if tick.sample is not None:
+                self._jit_tick.record_wall(tick.sample, wall)
+            self._credit_decode(tick.handles, wall)
+            self._counters_read = tick.counters
+            for slot, handle in zip(map(int, tick.live), tick.handles):
+                n = int(n_emit[slot]) if tick.spec else toks_host.shape[0]
+                if tick.spec:
+                    self._rows[slot] += n
+                    if n > 0 and handle.meter is not None:
+                        # Per-slot speculative accounting: a live slot's
+                        # round proposed spec_k - 1 drafts, accepted n - 1.
+                        handle.meter.note_spec(self.config.spec_k - 1, n - 1)
+                for k in range(n):
+                    if self._slots[slot].handle is not handle:
+                        break      # finished earlier in the block (the
+                        #            rest was speculative), or released
+                        #            while the tick was in flight
+                    self._emit(slot, int(toks_host[k, slot]))
+            # the tick's outputs and their host view are let go under
+            # this phase, not between two
+            del tick, toks_host
 
     def _read_back(self, *outs):
         """`llm_engine.tick_wait`'s two halves: wait until the tick's
         outputs are defined (`tick_ready`: the device is done and the
         host has been told), then read them on the host
-        (`tick_readback`). The copies are asked for BEFORE the wait, so
-        they queue behind the tick as `np.asarray` of a pending array
-        queues its own: asked for after it, each costs one more wake-up
-        of this thread (0.1 ms a tick on a v5e host, PERF.md PR 34), and
-        `tick_readback` is what is left of them once the tick is known
-        done."""
+        (`tick_readback`). The tick waited for is the one dispatched a
+        step ago: the next one is already queued behind it, so the
+        device has work while the host reads and emits. The copies are
+        asked for BEFORE the wait, so they queue behind the tick as
+        `np.asarray` of a pending array queues its own: asked for after
+        it, each costs one more wake-up of this thread (0.1 ms a tick
+        on a v5e host, PERF.md PR 34), and `tick_readback` is what is
+        left of them once the tick is known done."""
         import numpy as np
 
-        nbytes = sum(x.nbytes for x in outs)
         with self._loop.phase("llm_engine.tick_ready"):
             for x in outs:
                 x.copy_to_host_async()
+            nbytes = sum(x.nbytes for x in outs)    # behind the wait
             for x in outs:
                 x.block_until_ready()
+        self._ready_at = self._loop.now
         with self._loop.phase("llm_engine.tick_readback", bytes=nbytes):
             return [np.asarray(x) for x in outs]
 
@@ -2191,13 +2372,11 @@ class LLMEngine:
         more than the window before them (kv_cache.WindowRing.cover)."""
         c = self.config
         for slot in map(int, live):
-            h = self._slots[slot].handle
-            if h is not None:
-                # its rows, the pending token's own among them
-                n = min(len(h.request.prompt) + len(h.tokens),
-                        c.max_seq_len)
-                self._ring.cover(slot, n - 1, min(
-                    n - 2 + c.decode_block, c.max_seq_len - 1))
+            # its rows, the pending token's own among them, and those
+            # the tick in flight writes
+            n = min(int(self._rows[slot]), c.max_seq_len)
+            self._ring.cover(slot, n - 1, min(
+                n - 2 + c.decode_block, c.max_seq_len - 1))
 
     def _tick_tables(self):
         """The block tables as the tick takes them: one, or one a kind
@@ -2209,23 +2388,21 @@ class LLMEngine:
 
     def _live_rows(self, live) -> Dict[str, int]:
         """`rows`: KV rows the tick about to go out has to read in a
-        layer that reads them all: the live slots' prompt and emitted
-        tokens, summed (the pending token's own row among them), and
+        layer that reads them all: the live slots' prompt and generated
+        tokens, summed (the pending token's own row among them, and
+        what a tick in flight writes: `_rows`), and
         counted beside the rows of the padded [num_slots, max_seq_len]
         view over all ticks (`stats()`). For a model with a window kind
         also `window_rows`, what a window layer has to read (a slot's
         rows or the window, whichever is less); the bytes of both
         kinds' blocks that the live slots hold are summed over all
         ticks beside their rows (`stats()["kv"]["live_bytes"]`)."""
+        import numpy as np
+
         S = self.config.max_seq_len
         W = self._ring.window if self._ring is not None else S
-        rows = window_rows = 0
-        for slot in live:
-            h = self._slots[int(slot)].handle
-            if h is not None:
-                n = min(len(h.request.prompt) + len(h.tokens), S)
-                rows += n
-                window_rows += min(n, W)
+        n = np.minimum(self._rows[live], S)
+        rows, window_rows = int(n.sum()), int(np.minimum(n, W).sum())
         self._live_rows_sum += rows
         self._padded_rows_sum += self.config.num_slots * S
         if self._ring is None:
@@ -2236,46 +2413,46 @@ class LLMEngine:
             * self._ring.allocator.block_bytes for s in map(int, live))
         return {"rows": rows, "window_rows": window_rows}
 
-    def _credit_decode(self, live, dt: float) -> None:
+    def _credit_decode(self, handles, dt: float) -> None:
         """Split one decode/verify tick's wall time evenly across the
-        slots that were live in it (an attribution, not a hardware
+        requests that were live in it (an attribution, not a hardware
         counter — documented as approximate in accounting.py). Runs
         BEFORE the emit loop so a request finishing this tick still
         gets billed for it."""
-        if not self._acct or dt <= 0 or len(live) == 0:
+        if not self._acct or dt <= 0 or not handles:
             return
-        share = dt / len(live)
-        for slot in live:
-            h = self._slots[int(slot)].handle
-            if h is not None and h.meter is not None:
+        share = dt / len(handles)
+        for h in handles:
+            if h.meter is not None:
                 h.meter.note_chip("decode", share)
 
-    def _spec_ready(self, live) -> bool:
+    def _spec_wanted(self, live) -> bool:
         """A speculative round runs only when EVERY live slot
         qualifies: greedy sampling (acceptance compares argmaxes),
-        draft cache seeded (spec_ok), and spec_k - 1 positions of
-        headroom before the sequence limit. Mixed batches fall back to
-        the plain tick — correctness never depends on this gate, only
-        decode speed."""
+        draft cache seeded (spec_ok), and (`_spec_fits`) spec_k - 1
+        positions of headroom before the sequence limit. Mixed batches
+        fall back to the plain tick — correctness never depends on
+        this gate, only decode speed. What the host knows without the
+        device: a step that wants a round settles first."""
+        return (self._draft is not None
+                and bool(self._spec_ok[live].all())
+                and not bool((self._temp[live] > 0).any()))
+
+    def _spec_fits(self, live) -> bool:
+        """`_spec_wanted`'s last condition, from `_pos` read on the
+        host (settled: nothing is in flight that would move it)."""
         import numpy as np
 
-        if self._draft is None:
-            return False
-        if not bool(self._spec_ok[live].all()):
-            return False
-        if bool((self._temp[live] > 0).any()):
-            return False
         pos_host = np.asarray(self._pos)
         return bool((pos_host[live] <= self.config.max_seq_len
                      - self.config.spec_k).all())
 
-    def _spec_dispatch(self):
+    def _spec_dispatch(self, mask):
         """Dispatch one speculative round; `_spec_wait` reads it."""
         (self._cache, self._draft_cache, self._tok, self._pos,
          t, n_emit) = self._jit_spec(
             self.params, self._draft, self._cache, self._draft_cache,
-            self._tables.copy(), self._tok, self._pos,
-            self._active.copy())
+            self._tables.copy(), self._tok, self._pos, mask)
         return t, n_emit
 
     def _spec_wait(self, t, n_emit):
@@ -2350,6 +2527,7 @@ class LLMEngine:
                             "llm_engine.idle", queued=queued,
                             live=int(self._active.sum())):
                         self._work.wait(idle_wait_s)
+        self._settle("stop")
 
     def drain(self, timeout: float = 300.0) -> None:
         """Synchronously step until queue and slots are empty (tests and
@@ -2359,6 +2537,7 @@ class LLMEngine:
             if time.monotonic() > deadline:
                 raise TimeoutError("engine did not drain")
             self.step()
+        self._settle("drain")
 
     def warmup(self) -> None:
         """Compile every program the engine can run — the decode tick
@@ -2496,13 +2675,15 @@ class LLMEngine:
                 "bytes": sum(int(x.nbytes)
                              for x in self._slot_state.values()),
                 "prompts_under_way": len(self._chunking)}
-        if self._counters:
+        if self._counters_read:
             # the model's own counters, summed on the device since
-            # start and read here (waits for the tick in flight)
+            # start, as the last tick READ BACK left them: they agree
+            # with the tokens emitted, and no caller (a metrics thread,
+            # a load generator) waits for the tick in flight
             import numpy as np
 
             out["counters"] = {name: np.asarray(x) for name, x in
-                               self._counters.items()}
+                               self._counters_read.items()}
         if self._draft is not None or self._spec_rounds:
             denom = max(self._spec_proposed, 1)
             out["spec"] = {

@@ -437,6 +437,32 @@ def test_engine_refuses_by_name_what_would_lose_the_state(model, what):
             engine.preempt(0)
 
 
+@pytest.mark.parametrize("when", ["in_flight", "drained"])
+def test_engine_stats_count_the_ticks_read_back(model, when):
+    """`stats()["counters"]` are the model's counters as the last tick
+    READ BACK left them: with a tick in flight they agree with the
+    tokens emitted, one tick behind the device (and the caller, which
+    may be another thread, waits for no tick); after a drain they hold
+    every tick."""
+    from ray_tpu.serve.llm.engine import Request
+
+    R, mc, weights, params = model
+    engine = _engine(mc, params)
+    h = engine.submit(Request(prompt=_tokens(7, seed=3), max_tokens=6,
+                              temperature=0.0))
+    if when == "in_flight":
+        while len(h.tokens) < 3:
+            engine.step()
+        assert len(engine._flying) == 1
+    else:
+        engine.drain()
+    loop, counters = engine.stats()["loop"], engine.stats()["counters"]
+    # a token at the insert, then one a tick read back
+    assert int(counters["ticks"]) == len(h.tokens) - 1 \
+        == loop["ticks"] - len(engine._flying)
+    engine.drain()
+
+
 def test_engine_steps_live_states_through_the_kernel(monkeypatch):
     """With the interpreter forced, at heads that tile (keys of 8
     sublanes' worth, two heads of 64 a 128-lane row), the tick steps the

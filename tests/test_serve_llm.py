@@ -194,6 +194,243 @@ def test_eos_and_stop_tokens():
     assert h2.finish_reason == "stop" and h2.tokens == ref[:2]
 
 
+# ------------------------------------------------ one tick in flight
+
+_LEN8 = list(range(1, 9))              # its reference's 3rd token is the eos
+
+
+def _pipe_engine():
+    """Two slots, `eos_id` = the third token the reference gives
+    `_LEN8`; shared by the tests of the pipelined loop (drained between)."""
+    if "engine_pipe" not in _CACHE:
+        _CACHE["engine_pipe"] = _engine(slots=2,
+                                        eos_id=_reference(_LEN8, 8)[2])
+    return _CACHE["engine_pipe"]
+
+
+def _len8(seed):
+    return _specs(seed, [(8, 8)])[0][0]
+
+
+def _loop(engine):
+    return engine.stats()["loop"]
+
+
+def _step_until(engine, cond, limit=200):
+    for _ in range(limit):
+        if cond():
+            return
+        engine.step()
+    raise AssertionError("condition never held")
+
+
+def _mixed_batch():
+    """One batch on the pipelined loop whose requests end three ways,
+    served once: by eos (found one tick late: the slot is computed once
+    more and that row dropped), by a stop token, by length."""
+    if "mixed_batch" not in _CACHE:
+        from ray_tpu.serve.llm.engine import Request
+
+        engine = _pipe_engine()
+        eos = engine.config.eos_id
+        refs = {"eos": _reference(_LEN8, 8), "stop": _reference(_len8(3), 8),
+                "length": _reference(_len8(2), 8)}
+        assert eos not in refs["stop"] + refs["length"] + refs["eos"][:2]
+        stop = refs["stop"][4]
+        assert stop not in refs["stop"][:4]
+        before = _loop(engine)
+        handles = {
+            "eos": engine.submit(Request(prompt=_LEN8, max_tokens=8)),
+            "stop": engine.submit(Request(prompt=_len8(3), max_tokens=8,
+                                          stop=(stop,))),
+            "length": engine.submit(Request(prompt=_len8(2), max_tokens=8))}
+        engine.drain()
+        want = {"eos": refs["eos"][:3], "stop": refs["stop"][:4],
+                "length": refs["length"]}
+        _CACHE["mixed_batch"] = (handles, want, before, _loop(engine))
+    return _CACHE["mixed_batch"]
+
+
+@pytest.mark.parametrize("ends_by", ["length", "eos", "stop"])
+def test_pipelined_loop_serves_the_synchronous_tokens(ends_by):
+    """The tokens of the static per-request path, token for token,
+    however a request ends; the ticks of the batch overlapped."""
+    handles, want, before, after = _mixed_batch()
+    h = handles[ends_by]
+    assert h.finish_reason == ends_by and h.tokens == want[ends_by]
+    ticks = after["ticks"] - before["ticks"]
+    overlapped = after["overlapped"] - before["overlapped"]
+    assert 0 < overlapped < ticks
+    assert after["calls"]["emit"] == after["calls"]["tick_dispatch"] \
+        == after["ticks"]
+
+
+@pytest.mark.parametrize("freed_by", ["eos", "cancel"])
+def test_a_refilled_slot_never_gets_the_stale_row(freed_by):
+    """A slot released while a tick that holds its old request is in
+    flight (by an eos read one tick late, or by `cancel`) and filled
+    again: the tick's row goes to no handle, the new request's tokens
+    are its own."""
+    from ray_tpu.serve.llm.engine import Request
+
+    engine = _pipe_engine()
+    # the other slot stays busy, so the freed one is the one refilled
+    keep = engine.submit(Request(prompt=_len8(4), max_tokens=24))
+    old = engine.submit(Request(prompt=_LEN8, max_tokens=8))
+    new = engine.submit(Request(prompt=_len8(2), max_tokens=8))
+    _step_until(engine, lambda: len(old.tokens) >= 2)
+    slot = next(i for i, st in enumerate(engine._slots) if st.handle is old)
+    if freed_by == "cancel":
+        assert old.cancel()
+    else:
+        _step_until(engine, old.done)
+        assert old.finish_reason == "eos"
+    # the tick in flight was dispatched with the old request in the slot
+    tick, = engine._flying
+    assert old in tick.handles and slot in tick.live
+    seen = len(old.tokens)
+    engine.step()                       # frees (cancel), refills, lands it
+    assert engine._slots[slot].handle is new
+    assert len(old.tokens) == seen and len(new.tokens) == 1
+    engine.drain()
+    assert new.tokens == _reference(_len8(2), 8)
+    assert keep.tokens == _reference(_len8(4), 24)
+    assert old.tokens == _reference(_LEN8, 8)[:seen]
+
+
+@pytest.mark.parametrize("what", ["preempt", "ctrl", "prefill_only",
+                                  "adopt", "spec"])
+def test_whatever_reads_a_slots_state_settles_first(what):
+    """`preempt`, a `call_on_scheduler` body (`export_prefix`), a
+    `prefill_only` request's export, a `submit_adopted` request's
+    admission and a speculative round each read the tick in flight back
+    first (`stats()["loop"]["settles"]`, by cause), and every request's
+    tokens are the synchronous run's."""
+    import threading
+
+    from ray_tpu.serve.llm.engine import Request
+
+    if what == "spec":
+        if "engine_spec" not in _CACHE:
+            from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+            config, params = _model()
+            _CACHE["engine_spec"] = LLMEngine(
+                params, config, EngineConfig(
+                    num_slots=2, max_seq_len=64, prefill_buckets=(8, 16),
+                    kv_block_size=4, spec_k=3),
+                draft_params=params, draft_config=config)
+        engine = _CACHE["engine_spec"]
+    else:
+        engine = _shared_engine()
+    settles = lambda: _loop(engine)["settles"].get(what, 0)   # noqa: E731
+    before = settles()
+    busy = engine.submit(Request(prompt=_len8(4), max_tokens=24,
+                                 temperature=0.7 if what == "spec" else 0.0))
+    _step_until(engine, lambda: len(busy.tokens) >= 2)
+    assert len(engine._flying) == 1     # a plain tick is in flight
+    others = []
+    if what == "preempt":
+        slot = next(i for i, st in enumerate(engine._slots)
+                    if st.handle is busy)
+        engine.preempt(slot)
+        # the checkpoint's pending token is one the client has
+        assert busy.kv_state.tokens == busy.tokens
+        assert busy.kv_state.next_tok == busy.tokens[-1]
+    elif what == "ctrl":
+        seen = []
+
+        def body():
+            seen.append(len(engine._flying))
+            return engine.export_prefix(_len8(4))
+
+        th = threading.Thread(
+            target=lambda: seen.append(engine.call_on_scheduler(body)))
+        th.start()
+        _step_until(engine, lambda: len(seen) == 2)
+        th.join()
+        assert seen[0] == 0 and len(seen[1]) == 2   # settled; 8 tokens
+    elif what in ("prefill_only", "adopt"):
+        pre = engine.submit(Request(prompt=_len8(2), max_tokens=8,
+                                    prefill_only=True))
+        _step_until(engine, pre.done)
+        assert pre.finish_reason == "prefill"
+        assert pre.tokens == _reference(_len8(2), 8)[:1]
+        if what == "adopt":
+            # (a checkpoint with no row left to decode would never tick)
+            with pytest.raises(ValueError, match="already holds max_seq_len"):
+                _engine(slots=1, buckets=(8,), S=8).submit_adopted(
+                    Request(prompt=_len8(2), max_tokens=8), pre.kv_state)
+            assert len(engine._flying) == 1
+            before = settles()
+            others.append((engine.submit_adopted(
+                Request(prompt=_len8(2), max_tokens=8), pre.kv_state),
+                _reference(_len8(2), 8)))
+            engine.step()
+    else:
+        # a greedy request beside the sampled one: plain ticks; alone,
+        # once the sampled one is cancelled: rounds, the first of which
+        # finds the last plain tick in flight
+        others.append((engine.submit(Request(prompt=_len8(2), max_tokens=8)),
+                       _reference(_len8(2), 8)))
+        _step_until(engine, lambda: len(others[0][0].tokens) >= 2)
+        rounds = engine.stats()["spec"]["rounds"]
+        busy.cancel()
+        engine.drain()
+        assert engine.stats()["spec"]["rounds"] > rounds
+    assert settles() == before + 1
+    engine.drain()
+    if what != "spec":
+        assert busy.tokens == _reference(_len8(4), 24)
+    for h, ref in others:
+        assert h.finish_reason == "length" and h.tokens == ref
+    assert not engine._flying
+
+
+@pytest.mark.parametrize("ended_by", ["stop", "eos"])
+def test_a_round_refused_after_its_settle_ticks_who_is_left(ended_by):
+    """With a draft model, a step whose live slots all qualify for a
+    round settles first; the tick it lands can end a slot by a stop
+    token or by eos, and the round can then still be refused because
+    another slot stands within `spec_k` of the sequence limit.  The
+    plain tick that goes out instead holds the slots that are left, not
+    the released one (whose handle is gone)."""
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine, Request
+
+    (short, _), = _specs(0, _PARITY_PAIRS[:1])          # 3 tokens
+    ref_short, ref_long = _reference(short, 6), _reference(_len8(3), 8)
+    assert ref_short[1] not in ref_short[:1] + ref_long[:4]
+    if "engine_spec_limit" not in _CACHE:
+        config, params = _model()
+        # 12 rows a sequence, rounds of 5: a prompt of 8 is refused a
+        # round from its first tick to its last
+        _CACHE["engine_spec_limit"] = LLMEngine(
+            params, config, EngineConfig(
+                num_slots=2, max_seq_len=12, prefill_buckets=(8,),
+                kv_block_size=4, spec_k=5, eos_id=ref_short[1],
+                prefix_cache=False),        # its six blocks are theirs
+            draft_params=params, draft_config=config)
+    engine = _CACHE["engine_spec_limit"]
+    before, rounds = _loop(engine)["settles"]["spec"], \
+        engine.stats()["spec"]["rounds"]
+    long = engine.submit(Request(prompt=_len8(3), max_tokens=4))
+    ends = engine.submit(Request(
+        prompt=short, max_tokens=6,
+        stop=(ref_short[1],) if ended_by == "stop" else ()))
+    engine.step()                       # both admitted, a plain tick out
+    tick, = engine._flying
+    assert not tick.spec and {*tick.handles} == {long, ends}
+    engine.step()       # settles for a round: `ends` ends; refused: `long`
+    assert ends.finish_reason == ended_by and not long.done()
+    tick, = engine._flying
+    assert not tick.spec and tick.handles == [long]
+    engine.drain()
+    assert long.finish_reason == "length" and long.tokens == ref_long[:4]
+    assert ends.tokens == ref_short[:1 + (ended_by == "eos")]
+    assert _loop(engine)["settles"]["spec"] - before == 2
+    assert engine.stats()["spec"]["rounds"] == rounds
+
+
 def test_streaming_callback_and_latency_fields():
     from ray_tpu.serve.llm.engine import Request
 
@@ -641,6 +878,37 @@ def test_window_ring_cover_keeps_the_window_and_no_more(n_blocks):
             assert block in held and wrote[block] == b, (first, b)
     ring.release(1)
     assert allocator.stats()["used_blocks"] == 0
+
+
+@pytest.mark.parametrize("decode_block", [1, 2])
+def test_window_ring_is_covered_for_the_rows_dispatched(decode_block):
+    """`WindowRing.cover` under the engine's one-deep pipeline: the
+    first position a dispatch writes is counted from the rows DISPATCHED
+    (`_rows`), which with a tick in flight is `decode_block` past what
+    the handle's emitted tokens say; every covering call says exactly
+    the positions its tick writes."""
+    from ray_tpu.serve.llm.engine import Request
+
+    engine = _window_engine(decode_block=decode_block)
+    ring, calls = engine._ring, []
+    cover = ring.cover
+
+    def recording(slot, first, last):
+        h = engine._slots[slot].handle
+        calls.append((first, last, len(h.request.prompt) + len(h.tokens),
+                      sum(slot in t.live for t in engine._flying)))
+        return cover(slot, first, last)
+
+    ring.cover = recording
+    h = engine.submit(Request(prompt=[5] * 21, max_tokens=33,
+                              chunked_prefill=True))
+    engine.drain()
+    assert len(h.tokens) == 33 and len(calls) == -(-32 // decode_block)
+    for i, (first, last, emitted, in_flight) in enumerate(calls):
+        assert first == 21 + i * decode_block   # the pending token's row
+        assert last == first + decode_block - 1
+        assert first == emitted - 1 + in_flight * decode_block
+    assert [c[3] for c in calls] == [0] + [1] * (len(calls) - 1)
 
 
 @pytest.mark.parametrize("what", [

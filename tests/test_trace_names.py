@@ -12,11 +12,11 @@ import time
 
 import pytest
 
-STEP_CHILDREN = ["ctrl", "admit", "first_token_wait", "tick_dispatch",
-                 "spill_land", "tick_wait", "emit", "gauges"]
-LOOP_PHASES = ["ctrl", "admit", "first_token_wait", "tick_dispatch",
-               "spill_land", "tick_ready", "tick_readback", "emit", "gauges",
-               "idle"]
+STEP_CHILDREN = ["ctrl", "admit", "first_token_wait", "settle",
+                 "tick_dispatch", "spill_land", "tick_wait", "emit", "gauges"]
+LOOP_PHASES = ["ctrl", "admit", "first_token_wait", "settle",
+               "tick_dispatch", "spill_land", "tick_ready", "tick_readback",
+               "emit", "gauges", "idle"]
 
 
 # ------------------------------------------------------- program names
@@ -145,6 +145,27 @@ def test_engine_step_spans_nest_and_cover(engine_traces, case):
     assert total and 1.0 - covered / total < 0.05
     names = {e[0] for e in events}
     assert {"llm_engine.admit_one", "llm_engine.insert_dispatch"} <= names
+    # one tick in flight (PR 41): a step's `tick_wait` and `emit` are
+    # those of the tick dispatched a step before; the first tick of a
+    # wave of requests finds none in flight (`in_flight=0`) and waits
+    # for none, and the wave's last is read back by the step that finds
+    # no slot to tick, under `settle` (`cause=empty`), in
+    # `tick_dispatch`'s place (the requests of a wave end together)
+    ticks = [e for e in events if e[0] == "llm_engine.tick_dispatch"]
+    waves = 1 if case == "roomy" else 4
+    assert [int(e[3]["in_flight"]) for e in ticks].count(0) == waves
+    assert int(ticks[0][3]["in_flight"]) == 0
+    settles = [e for e in events if e[0] == "llm_engine.settle"]
+    assert [e[3]["cause"] for e in settles] == ["empty"] * waves
+    for st in settles:
+        # (by name: another engine's idle loop, left running by a test
+        # of this process, may write its own steps meanwhile)
+        want = ["llm_engine.tick_wait", "llm_engine.tick_ready",
+                "llm_engine.tick_readback", "llm_engine.emit"]
+        assert [e[0] for e in events if st[1] <= e[1] and e[2] <= st[2]
+                and e[0] in want] == want
+    for name in ("tick_wait", "emit"):
+        assert sum(e[0] == "llm_engine." + name for e in events) == len(ticks)
     admits = [e for e in events if e[0] == "llm_engine.admit"]
     assert sum(int(e[3]["admitted"]) for e in admits) >= 2
     assert ("llm_engine.spill" in names) == (case == "evicting")
@@ -247,16 +268,23 @@ def test_engine_keeps_a_phase_clock(engine_traces, key):
          for k in ("seconds", "calls")}
     steps, ticks = (after[k] - before[k] for k in ("steps", "ticks"))
     if key == "phases":
-        assert set(after) == {"steps", "ticks", "seconds", "calls"}
+        assert set(after) == {"steps", "ticks", "overlapped", "settles",
+                              "seconds", "calls"}
         assert list(after["seconds"]) == list(after["calls"]) == LOOP_PHASES
     elif key == "calls":
-        # two requests of 6 tokens admitted in one step: 5 ticks
-        assert steps == ticks == 5
+        # two requests of 6 tokens admitted in one step: 5 ticks, and
+        # the step that finds no slot left to tick reads the last back
+        assert (steps, ticks) == (6, 5)
         c = d["calls"]
         assert c["tick_ready"] == c["tick_readback"] == ticks
         assert c["tick_dispatch"] == c["emit"] == ticks
         assert c["ctrl"] == c["admit"] == c["gauges"] == steps
         assert c["first_token_wait"] == 1 and c["idle"] == 0
+        # every tick but the first went out while one was in flight
+        overlapped = after["overlapped"] - before["overlapped"]
+        assert 0 < overlapped <= ticks and overlapped == ticks - 1
+        assert c["settle"] == 1
+        assert after["settles"]["empty"] - before["settles"]["empty"] == 1
     else:
         # the phases cover the drain and never count an instant twice
         # (a loose floor: a CPU step is short beside its loop's turn)
@@ -265,42 +293,170 @@ def test_engine_keeps_a_phase_clock(engine_traces, key):
         assert 0.8 * wall <= total <= wall, (total, wall)
 
 
-def test_sampled_fence_stands_in_the_trace(tmp_path):
+@pytest.mark.parametrize("who", ["fence", "owner", "engine"])
+def test_sampled_fence_stands_in_the_trace(tmp_path, who):
     """`TrackedJit` fences every `xla_wall_sample_every`-th call with
     `block_until_ready` inside the call: the fence is `jit.wall_sample`
-    (`fn=`), under whatever span holds the call."""
+    (`fn=`), under whatever span holds the call ("fence").  An owner
+    that keeps its calls in flight (`fence_samples=False`) is handed the
+    sampled call's mark and gives the wall it measured where it waits:
+    `jit.wall_sample` is then an instant there, and no call is fenced
+    ("owner").  The engine's tick is such an owner: its samples stand
+    where the tick that was waited for is accounted, under
+    `llm_engine.emit`, none inside a `tick_dispatch`, while the ticks
+    keep overlapping ("engine")."""
     import jax.numpy as jnp
 
     from ray_tpu.observability import tracked_jit
     from ray_tpu.observability.profiling import trace_span
 
-    f = tracked_jit(lambda x: x * 3, name="trace_names_sampled")
-    f(jnp.ones((3,)))                   # compiles: never a sample
-    f._sample_every, f.calls = 2, 0
-    with _profiled(tmp_path):
-        for _ in range(4):
-            with trace_span("llm_engine.tick_dispatch", live=1):
-                f(jnp.ones((3,)))
-    ev = _host_events(str(tmp_path), ("jit.wall_sample",
-                                      "llm_engine.tick_dispatch"))
+    if who == "engine":
+        import jax
+
+        from ray_tpu.models.llama import LlamaConfig, init_params
+        from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine, Request
+
+        config = LlamaConfig.tiny()
+        engine = LLMEngine(init_params(config, jax.random.key(0)), config,
+                           EngineConfig(num_slots=2, max_seq_len=32,
+                                        prefill_buckets=(8,), kv_block_size=8))
+        engine.submit(Request(prompt=[1, 2, 3], max_tokens=2))
+        engine.drain()                  # compiles: never a sample
+        f = engine._jit_tick
+        f._sample_every, f.calls = 2, 0
+        walls = []
+        record = f.record_wall
+        f.record_wall = lambda due, wall: (walls.append(wall),
+                                           record(due, wall))[1]
+        with _profiled(tmp_path):
+            engine.submit(Request(prompt=[4, 5, 6], max_tokens=9))
+            engine.drain()              # 8 ticks
+        loop = engine.stats()["loop"]
+        assert loop["overlapped"] == loop["ticks"] - 2
+        assert len(walls) == 4 and all(0 < w < 1.0 for w in walls)
+        name, dispatch, wait = ("llm_engine_tick", "llm_engine.tick_dispatch",
+                                "llm_engine.emit")
+    else:
+        name, dispatch, wait = ("trace_names_sampled_" + who,
+                                "llm_engine.tick_dispatch",
+                                "llm_engine.tick_wait")
+        f = tracked_jit(lambda x: x * 3, name=name,
+                        fence_samples=who == "fence")
+        f(jnp.ones((3,)))                   # compiles: never a sample
+        f._sample_every, f.calls = 2, 0
+        with _profiled(tmp_path):
+            for _ in range(4):
+                with trace_span(dispatch, live=1):
+                    out = f(jnp.ones((3,)))
+                due = f.take_sample()
+                with trace_span(wait):
+                    out.block_until_ready()
+                    if due is not None:
+                        f.record_wall(due, 0.001)
+    ev = _host_events(str(tmp_path), ("jit.wall_sample", dispatch, wait))
     samples = [e for e in ev if e[0] == "jit.wall_sample"]
-    holders = [e for e in ev if e[0] == "llm_engine.tick_dispatch"]
-    assert len(samples) == 2 and len(holders) == 4
+    holders = [e for e in ev if e[0] == (
+        dispatch if who == "fence" else wait)]
+    others = [e for e in ev if e[0] == (
+        wait if who == "fence" else dispatch)]
+    assert len(samples) == (4 if who == "engine" else 2)
+    assert len(others) >= 4
     for s in samples:
-        assert s[3]["fn"] == "trace_names_sampled"
+        assert s[3]["fn"] == name
         assert any(h[1] <= s[1] and s[2] <= h[2] for h in holders)
+        assert not any(o[1] <= s[1] and s[2] <= o[2] for o in others)
 
 
+def _tick_trace(order):
+    """A trace built here, times in us, the device's clock 2 ms behind
+    the host's; ticks of 10 ms whose executions are known to the host
+    1 ms after their end; a readback of 0.25 ms, 0.5 ms of host work
+    between knowing a tick done and the next dispatch's start; then an
+    empty engine for 2.5 ms.
+
+    "own_step" (before PR 41): two quiet steps that each dispatch a
+    tick, whose execution starts 0.5 ms later, and wait for it.
+    "in_flight": step k dispatches tick k while tick k-1 runs, then
+    waits for tick k-1; the executions stand back to back; step 0 waits
+    for none and step 4, which finds no slot to tick, reads tick 3 back
+    under `settle`."""
+    us, skew = 1_000, 2_000_000
+    spans, mods = [], []
+
+    def step(t, wait_end, dispatch=True, first=False):
+        """One step from `t`; its wait ends (readback included) at
+        `wait_end`; returns where the next begins."""
+        spans.extend([("llm_engine.ctrl", t, 10 * us, {}),
+                      ("llm_engine.admit", t + 10 * us, 10 * us,
+                       {"admitted": "0"})])
+        at = t + 20 * us
+        if dispatch:
+            spans.append(("llm_engine.tick_dispatch", at, 300 * us,
+                          {"live": "1", "in_flight": str(int(not first))}))
+            at += 300 * us
+        if not first:
+            inner = [("llm_engine.tick_wait", at, wait_end - at, {}),
+                     ("llm_engine.tick_ready", at, wait_end - 250 * us - at,
+                      {}),
+                     ("llm_engine.tick_readback", wait_end - 250 * us,
+                      250 * us, {"bytes": "4"}),
+                     ("llm_engine.emit", wait_end, 100 * us, {})]
+            if not dispatch:
+                spans.append(("llm_engine.settle", at,
+                              wait_end + 100 * us - at, {"cause": "empty"}))
+            spans.extend(inner)
+            at = wait_end + 100 * us
+        spans.extend([("llm_engine.gauges", at, 80 * us, {}),
+                      ("llm_engine.step", t, at + 80 * us - t, {})])
+        return at + 130 * us
+
+    if order == "own_step":
+        for t in (0, 12_000 * us):
+            step(t, t + 11_770 * us)
+            mods.append(("jit_llm_engine_tick(1)", t + 520 * us - skew,
+                         10_000 * us))
+        end = 24_000 * us
+        loop = {"ticks": 2}
+    else:
+        t = step(0, None, first=True)
+        for k in range(4):              # execution k: 10 ms from 0.52 ms
+            mods.append(("jit_llm_engine_tick(1)",
+                         (520 + 10_000 * k) * us - skew, 10_000 * us))
+            t = step(t, (520 + 10_000 * (k + 1) + 1_250) * us,
+                     dispatch=k < 3)
+        end = t
+        loop = {"ticks": 4, "overlapped": 3, "settles": {"empty": 1}}
+    spans.append(("llm_engine.idle", end, 2_500 * us,
+                  {"queued": "0", "live": "0"}))
+    loop.update(steps=5, calls={}, seconds={
+        "ctrl": 0.0002, "tick_dispatch": 0.002, "tick_readback": 0.001,
+        "emit": 0.0005, "gauges": 0.0003, "tick_ready": 0.02, "idle": 1.0})
+    return spans, mods, loop, (-skew, end + 2_500 * us)
+
+
+@pytest.mark.parametrize("order", ["own_step", "in_flight"])
 @pytest.mark.parametrize("name, want", [
-    ("tick_readback_ms", 0.25), ("tick_launch_notify_ms", 1.5),
-    ("tick_host_ms", 2.0), ("engine_idle_share", None)])
-def test_tick_gap_readers_load_and_read(monkeypatch, name, want):
-    """The four readers of PR 34 (`benchmarks/layer_metrics/`, on
-    `benchmarks/tick_gap.py`), loaded by path as the harness loads them,
-    on a trace built here: two quiet ticks of 10 ms whose executions
-    start 0.5 ms after their dispatch and are known to the host 1 ms
-    after their end, the device's clock 2 ms behind the host's, then an
-    empty engine for 2.5 ms.  A program without the spans reads None."""
+    ("tick_readback_ms", (0.25, 0.25)),
+    ("tick_launch_notify_ms", (1.5, -0.5)),
+    ("tick_host_ms", (2.0, 1.0)),
+    ("host_loop_ms", (0.5, 0.5)),
+    ("engine_idle_share", (100 * 2.5 / (2.0 + 5.98), 100 * 2.5 / 5.98)),
+    ("tick_overlap_share", (None, 75.0))])
+def test_tick_gap_readers_load_and_read(monkeypatch, name, want, order):
+    """The readers of the time between two ticks
+    (`benchmarks/layer_metrics/`, on `benchmarks/tick_gap.py` and
+    `program_spans.py`), loaded by path as the harness loads them, on
+    `_tick_trace`'s two step orders: each reads a number, or None, and
+    none raises.  With a tick in flight the executions stand back to
+    back (`G` = 0), so `tick_launch_notify_ms` = `G - H` reads MINUS the
+    host's 0.5 ms between `tick_ready`'s end and the next dispatch;
+    `tick_readback_ms`, `host_loop_ms` and `tick_host_ms` still read the
+    host's own work, which the device no longer waits for.  The reported
+    window of "own_step" holds 2 ms of device idle between its two ticks
+    and 5.98 after the second, of "in_flight" 5.98 after the fourth; 2.5
+    of them under the idle span.  `tick_overlap_share` reads a recorded
+    `loop`: 3 of 4 ticks dispatched behind one in flight.  A program
+    without the spans or the counts reads None."""
     import types
 
     mod = _bench_reader(monkeypatch, name)
@@ -309,48 +465,27 @@ def test_tick_gap_readers_load_and_read(monkeypatch, name, want):
     import trace_reduce as TR
 
     assert TG.program_writes("llm_engine.idle")      # this tree's engine
-    us, skew = 1_000, 2_000_000
-    spans, mods = [], []
-    for t in (0, 12_000 * us):
-        spans += [("llm_engine.step", t, 11_950 * us, {}),
-                  ("llm_engine.ctrl", t, 10 * us, {}),
-                  ("llm_engine.admit", t + 10 * us, 10 * us,
-                   {"admitted": "0"}),
-                  ("llm_engine.tick_dispatch", t + 20 * us, 300 * us,
-                   {"live": "1"}),
-                  ("llm_engine.tick_wait", t + 320 * us, 11_450 * us, {}),
-                  ("llm_engine.tick_ready", t + 320 * us, 11_200 * us, {}),
-                  ("llm_engine.tick_readback", t + 11_520 * us, 250 * us,
-                   {"bytes": "4"}),
-                  ("llm_engine.emit", t + 11_770 * us, 100 * us, {}),
-                  ("llm_engine.gauges", t + 11_870 * us, 80 * us, {})]
-        mods.append(("jit_llm_engine_tick(1)", t + 520 * us - skew,
-                     10_000 * us))
-    spans.append(("llm_engine.idle", 24_000 * us, 2_500 * us,
-                  {"queued": "0", "live": "0"}))
-    loop = {"steps": 4, "ticks": 2, "calls": {}, "seconds": {
-        "ctrl": 0.0002, "tick_dispatch": 0.002, "tick_readback": 0.001,
-        "emit": 0.0005, "gauges": 0.0003, "tick_ready": 0.02, "idle": 1.0}}
+    spans, mods, loop, window = _tick_trace(order)
     engine = types.SimpleNamespace(stats=lambda: {"loop": loop})
-    run = {"window": (-skew, 26_500 * us), "records": {"recs": [
+    run = {"window": window, "records": {"recs": [
         types.SimpleNamespace(handle=types.SimpleNamespace(engine=engine))]},
         "trace": TR.Trace({"/device:TPU:0": {
             TR.MODULE_LINE: mods,
             TR.OPS_LINE: [("op", s, d) for _, s, d in mods]}}, []),
         "program": PS.Program(sorted(spans, key=lambda s: (s[1], -s[2])),
                               [])}
-    if name == "engine_idle_share":
-        # the reported window (first span's start to the last one's end,
-        # 0 to 26.5 ms) holds 2 ms of device idle between the two ticks
-        # and 5.98 after the second; 2.5 of them under the idle span
-        want = 100.0 * 2.5 / (2.0 + 5.98)
-    assert mod.read(run) == pytest.approx(want)
+    want = want[order == "in_flight"]
+    got = mod.read(run)
+    assert (got is None) if want is None else got == pytest.approx(want)
     bare = {"window": run["window"], "trace": run["trace"], "records": {},
             "program": PS.Program([s for s in spans if s[0] not in (
                 "llm_engine.tick_ready", "llm_engine.tick_readback",
                 "llm_engine.idle")], [])}
     monkeypatch.setattr(TG, "program_writes", lambda span: False)
-    assert mod.read(bare) is None
+    if name == "host_loop_ms":          # PR 24's: steps and waits suffice
+        assert mod.read(bare) == pytest.approx(0.5)
+    else:
+        assert mod.read(bare) is None
 
 
 @pytest.mark.parametrize("case, want", [

@@ -363,3 +363,55 @@ def test_engine_serves_through_both_kinds_past_a_ring_wrap(model):
         assert np.all(lg.max(-1) - chosen <= RTOL * np.abs(lg).max())
     kv = engine.stats()["kv"]
     assert kv["used_blocks"] == 0 and kv["window"]["used_blocks"] == 0
+
+
+class _RecordingTick:
+    """Stands in for `engine._jit_tick`: lays each dispatch's live slots
+    and window table aside, then calls through."""
+
+    def __init__(self, engine):
+        self.engine, self.tick, self.seen = engine, engine._jit_tick, []
+
+    def __call__(self, params, pools, tables, tok, pos, active, *rest):
+        e = self.engine
+        self.seen.append((
+            [(s, e._slots[s].handle) for s in np.nonzero(active)[0]],
+            tables["window"].copy(), [list(b) for b in e._ring.slot_blocks],
+            len(e._flying)))
+        return self.tick(params, pools, tables, tok, pos, active, *rest)
+
+    def take_sample(self):
+        return self.tick.take_sample()
+
+
+def test_ring_covers_the_row_a_tick_behind_one_in_flight_writes(model):
+    """One tick in flight (PR 41): when tick k goes out the handles are
+    one token short of the rows dispatched, and the ring still has to
+    hold, owned and distinct, the block of the row tick k WRITES (a
+    handle's j-th tick writes position prompt + j - 1, counted here by
+    the dispatches themselves) and of the window before it."""
+    from ray_tpu.serve.llm.engine import Request
+
+    R, mc, weights, params = model
+    engine = _engine(mc, params)
+    rec = engine._jit_tick = _RecordingTick(engine)
+    ring, W = engine._ring, C["sliding_window"]
+    prompts = [_tokens(n, seed=n) for n in (5, 40, 21)]
+    handles = [engine.submit(Request(prompt=p, max_tokens=40,
+                                     chunked_prefill=len(p) > BUCKET))
+               for p in prompts]
+    engine.drain()
+    assert all(len(h.tokens) == 40 for h in handles)
+    ticks_of = {}
+    overlapped = 0
+    for live, table, owned, in_flight in rec.seen:
+        overlapped += in_flight
+        for slot, h in live:
+            j = ticks_of[h] = ticks_of.get(h, 0) + 1
+            row = len(h.request.prompt) + j - 1
+            blocks = [int(table[slot, b % ring.ring])
+                      for b in range(max(row - W + 1, 0) // BS, row // BS + 1)]
+            assert len(set(blocks)) == len(blocks), (row, blocks)
+            assert set(blocks) <= set(owned[slot]), (row, blocks)
+    assert set(ticks_of.values()) == {39}       # the insert gave the first
+    assert overlapped > len(rec.seen) * 0.8
