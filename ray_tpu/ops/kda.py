@@ -54,10 +54,25 @@ Three forms of that one function:
   forward substitution (`_unit_lower_solve`); a scan over chunks then
   carries `S`.  With one decay a head the exponential comes out of the
   sum over channels, and `A` and its twin for the queries are two
-  `[C, dk] x [dk, C]` matrix products under the `[C, C]` decays; with
-  one a channel they are a `[C, C, dk]` reduction on the vector unit.
-  The decay differences are masked to `i <= r` BEFORE the exponential,
-  where they are <= 0: `exp(-G_i)` alone overflows float32 after a few
+  `[C, dk] x [dk, C]` matrix products under the `[C, C]` decays, the
+  differences masked to `i <= r` BEFORE the exponential, where they are
+  <= 0.  With one a channel it cannot come out, and the terms are taken
+  by sub-blocks of `_SOLVE_BLOCK` rows (`_decayed_products`).  For a
+  sub-block of keys whose last row is R and a row r below the
+  sub-block, `exp(G_r - G_i) = exp(G_r - G_R) exp(G_R - G_i)`: the
+  reference row lies BETWEEN the two sides (`i <= R < r`) and G does
+  not increase down the rows, so both exponents are <= 0, nothing
+  overflows, and a factor underflows only where the whole term does.
+  The rows below, decayed back to R, times the sub-block's keys,
+  decayed forward to R, are then ONE `[rows, dk] x [dk, 16]` matrix
+  product, three a chunk of 64 (for the k rows and the q rows
+  together).  Only the diagonal sub-blocks (`i` and `r` in one
+  sub-block) keep the masked difference itself, `[16, 16, dk]` for the
+  k rows and again for the q rows, each exponential inside the sum it
+  feeds: no tensor has both chunk axes and the channel axis (`[C, C,
+  dk]` is 2.1 GB a layer at a 2048-token insert of 32 heads of 128, and
+  was written out and read back).  This is not the factoring from the
+  chunk's START: `exp(-G_i)` alone overflows float32 after a few
   strongly decayed tokens.  Tokens at and past `n_real` (padding) get
   `g = 0`, `beta = 0`: they leave the state as it is, so the state
   handed back is the one after the last REAL token.
@@ -358,6 +373,53 @@ def _unit_lower_solve(N: jax.Array, rhs: jax.Array) -> jax.Array:
     return jnp.concatenate(out, -2)
 
 
+def _decayed_products(x: jax.Array, k: jax.Array, G: jax.Array
+                      ) -> jax.Array:
+    """`out[.., r, i] = sum_c x[.., r, c] k[.., i, c] exp(G[.., r, c] -
+    G[.., i, c])` for i <= r and 0 above the diagonal, with one decay a
+    channel: x [X, ..., C, dk] (X stacked sets of rows against the same
+    keys), k, G [..., C, dk], G the cumulative log-decay inside the
+    chunk (non-increasing down the rows).  By sub-blocks of
+    `_SOLVE_BLOCK` rows, so that nothing `[C, C, dk]` is built: a
+    sub-block of keys, decayed FORWARD to its own last row R, against
+    all the rows below it, decayed BACK to R, is one `[rows, dk] x [dk,
+    block]` matrix product (both exponents <= 0: i <= R < r); the
+    diagonal sub-blocks take the difference itself, masked to i <= r
+    before the exponential, at `[block, block, dk]`."""
+    X, (C, dk), b = x.shape[0], k.shape[-2:], _SOLVE_BLOCK
+    assert C % b == 0, (C, b)
+    nb = C // b
+    blocks = lambda a: a.reshape(a.shape[:-2] + (nb, b, dk))
+    xb, kb, Gb = blocks(x), blocks(k), blocks(G)
+    # the diagonal sub-blocks, the X sets' rows one under the other: each
+    # exponential then feeds ONE product and is taken inside the
+    # reduction (shared between the sets it is written out and read
+    # back, 512 MB a layer at a 2048-token insert)
+    diff = jnp.concatenate([Gb] * X, -2)[..., :, None, :] \
+        - Gb[..., None, :, :]                            # [..,nb,X b,b,dk]
+    lower = jnp.tile(jnp.tril(jnp.ones((b, b), bool)), (X, 1))
+    diag = jnp.sum(jnp.concatenate(list(xb), -2)[..., :, None, :]
+                   * kb[..., None, :, :]
+                   * jnp.exp(jnp.where(lower[..., None], diff, -jnp.inf)),
+                   -1)
+    diag = jnp.stack(jnp.split(diag, X, -2))             # [X,..,nb,b,b]
+    G_ref = Gb[..., -1:, :]             # a sub-block's last row R
+    k_fwd = kb * jnp.exp(G_ref - Gb)
+    strips = []
+    for m in range(nb):
+        parts = [jnp.zeros(diag.shape[:-3] + (m * b, b), diag.dtype)] \
+            if m else []                                 # above the diagonal
+        parts.append(diag[..., m, :, :])
+        if m + 1 < nb:
+            below = slice((m + 1) * b, C)
+            back = x[..., below, :] * jnp.exp(G[..., below, :]
+                                              - G_ref[..., m, :, :])
+            parts.append(jnp.einsum("x...rc,...ic->x...ri", back,
+                                    k_fwd[..., m, :, :], precision=_HI))
+        strips.append(jnp.concatenate(parts, -2))        # [X,..,C,b]
+    return jnp.concatenate(strips, -1)
+
+
 def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                 beta: jax.Array, S0: jax.Array,
                 n_real: Optional[jax.Array] = None, chunk: int = CHUNK
@@ -387,20 +449,18 @@ def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     q, k, v, g, beta = chunks(q), chunks(k), chunks(v), chunks(g), \
         chunks(beta)
     G = jnp.cumsum(g, axis=-2)                  # [B,N,H,C,dk] (or 1)
-    # decay from row i to row r, per channel; masked before the exp
-    diff = G[..., :, None, :] - G[..., None, :, :]           # [..,r,i,dk]
     lower = jnp.tril(jnp.ones((C, C), bool))
-    decay = jnp.exp(jnp.where(lower[..., None], diff, -jnp.inf))
     if g.shape[-1] == 1:
         # one decay a head comes out of the sum over channels: two
-        # [C, dk] x [dk, C] products under the [C, C] decays
+        # [C, dk] x [dk, C] products under the [C, C] decays from row i
+        # to row r, masked before the exp
+        diff = G[..., :, None, :] - G[..., None, :, :]       # [..,r,i,1]
+        decay = jnp.exp(jnp.where(lower[..., None], diff, -jnp.inf))
         AA = jnp.einsum("x...rc,...ic->x...ri", jnp.stack([k, q]), k,
                         precision=_HI) * decay[..., 0]
     else:
-        # rows k (for A) and q (for the outputs) against the decayed
-        # keys, in one reduction: the [C, C, dk] products are never kept
-        AA = jnp.sum(jnp.stack([k, q])[..., :, None, :]
-                     * (k[..., None, :, :] * decay)[None], -1)
+        # rows k (for A) and q (for the outputs) against the decayed keys
+        AA = _decayed_products(jnp.stack([k, q]), k, G)
     A, Aq = AA[0], AA[1]                        # [B,N,H,C,C]; Aq: i <= r
     A = jnp.where(jnp.tril(lower, -1), A, 0.0)               # i <  r
     # (I + A Diag(beta)) u = v - (k exp(G)) S_0: solve for both terms

@@ -731,6 +731,37 @@ def test_hybrid_tick_steps_live_states_where_they_lie(
         _compiled_insert(eng, one_chip).as_text()) == insert
 
 
+@pytest.mark.parametrize("cell, dk, temp_gib", [
+    ("agent-decode-hybrid", 128, 1.5), ("reason-decode-gdn-hybrid", 96, 0.5)])
+def test_delta_rule_inserts_hold_no_chunk_by_chunk_by_channel_tensor(
+        one_chip, on_tpu, cell, dk, temp_gib):
+    """The largest insert of both delta-rule cells (Kimi at its 2048
+    bucket, one decay a key channel; Olmo-Hybrid at 512, one a head):
+    no float32 result, fused computations' own included, has both of
+    `ops.kda.kda_chunked`'s chunk axes AND the channel axis `[.., 64,
+    64, dk]` (2.1 GB a KDA layer at Kimi's bucket, which
+    `_decayed_products` replaces by matrix products over 16-row
+    sub-blocks: the diagonal blocks' `[.., 32, 16, 128]`, the k rows
+    over the q rows, inside a reduction's fusion is what is left of it;
+    the a-head arm never had one, its decays are `[.., 64, 64]`).
+    Temporaries: 1.30 GiB against the 2.41 the `[C, C, dk]` form took
+    for Kimi (the history's softmax holds them now), 0.45 for
+    Olmo-Hybrid."""
+    from ray_tpu.ops import kda
+
+    eng = _serving_cell(cell, one_chip)
+    C, b = kda.CHUNK, kda._SOLVE_BLOCK
+    assert eng.config.prefill_buckets[-1] % C == 0
+    compiled = _compiled_insert(eng, one_chip)
+    shapes = set().union(*(
+        shapes for _, shapes in _results(compiled.as_text())))
+    assert any(s[-2:] == (C, C) for s in shapes)            # parsed
+    if dk == 128:
+        assert any(s[-3:] == (2 * b, b, dk) for s in shapes)
+    assert not sorted(s for s in shapes if s[-3:] == (C, C, dk))
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_gib * GIB
+
+
 @pytest.mark.parametrize("program", ["tick", "insert"])
 def test_conv_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     """The engine's decode tick and its largest insert at the geometry of

@@ -19,7 +19,11 @@ Tolerances and their reasons
 * `kda_chunked` against `kda_step` applied token by token: 2e-6 on
   outputs and states of magnitude 1 to 3, for decays within 1e-4 of 1
   (where nothing is forgotten and sums grow) and for decays of e^-12 a
-  token (where a factored exp(-G) would overflow after 8 tokens).
+  token (where a factored exp(-G) would overflow after 8 tokens), at
+  chunks of 16 (one sub-block) and of 64 (four, the terms between them
+  matrix products around a reference row); 2e-5 where strong and weak
+  tokens alternate inside a chunk of 64 (reads 7.9e-6, with the
+  `[C, C, dk]` reduction as with the products: the 64-row solve).
 * The engine tests serve greedy tokens in float32; each served token's
   reference logit lies within 1e-4 of the reference maximum (0 unless
   two logits tie to within the sums' reordering).
@@ -212,6 +216,20 @@ def test_prefill_hand_off(model, case):
 
 # ---------------- (d) the chunkwise form against the one-token recurrence
 
+def _token_by_token(q, k, v, g, beta, S):
+    """`kda_step` over the sequence: (outputs [B, T, H, dv], the state
+    after every token)."""
+    from ray_tpu.ops.kda import kda_step
+
+    step = jax.jit(kda_step)
+    outs, states = [], []
+    for t in range(q.shape[1]):
+        o, S = step(S, q[:, t], k[:, t], v[:, t], g[:, t], beta[:, t])
+        outs.append(o)
+        states.append(S)
+    return jnp.stack(outs, 1), states
+
+
 @pytest.mark.parametrize("lo,hi", [(-1e-4, -1e-6), (-12.0, -3.0),
                                    (-2.0, -0.01)],
                          ids=["decay_near_1", "decay_near_0", "mixed"])
@@ -220,7 +238,7 @@ def test_chunkwise_kda_equals_recurrence(lo, hi, width):
     """`width` a_head: `g` [.., H, 1], one decay a head, keys of another
     size than values and write strengths up to 2 (the gated delta rule
     of `models/gdn_hybrid.py`)."""
-    from ray_tpu.ops.kda import kda_chunked, kda_step
+    from ray_tpu.ops.kda import kda_chunked
 
     B, T, H, dk, dv = 2, 50, 3, 16, 8
     if width == "a_head":
@@ -234,14 +252,9 @@ def test_chunkwise_kda_equals_recurrence(lo, hi, width):
     g = jax.random.uniform(ks[4], (B, T, H, dk), minval=lo, maxval=hi)
     if width == "a_head":
         beta, g = 2 * beta, g[..., :1]
-    S = S0 = jax.random.normal(ks[5], (B, H, dk, dv))
+    S0 = jax.random.normal(ks[5], (B, H, dk, dv))
     n_real = jnp.asarray([50, 37])
-    outs, states = [], []
-    for t in range(T):
-        o, S = kda_step(S, q[:, t], k[:, t], v[:, t], g[:, t], beta[:, t])
-        outs.append(o)
-        states.append(S)
-    want = jnp.stack(outs, 1)
+    want, states = _token_by_token(q, k, v, g, beta, S0)
     got, S_got = kda_chunked(q, k, v, g, beta, S0, n_real, chunk=16)
     assert jnp.abs(want).max() > 0.5
     assert jnp.abs(got[0] - want[0]).max() < 2e-6
@@ -249,6 +262,89 @@ def test_chunkwise_kda_equals_recurrence(lo, hi, width):
     # the state after the last REAL token: padding did not advance it
     S_want = jnp.stack([states[49][0], states[36][1]])
     assert jnp.abs(S_got - S_want).max() < 2e-6
+
+
+def _decays(case, key, shape):
+    """Log-decays a token a channel for the sub-block cases: `near_1`
+    within 1e-4 of no decay, `near_0` e^-12 to e^-3 a token, `mixed`
+    strong and weak tokens alternating inside every sub-block of 16 (in
+    row 0; row 1 the other way round, by threes)."""
+    weak = jax.random.uniform(key, shape, minval=-1e-4, maxval=-1e-6)
+    strong = jax.random.uniform(jax.random.fold_in(key, 1), shape,
+                                minval=-12.0, maxval=-3.0)
+    if case != "mixed":
+        return {"near_1": weak, "near_0": strong}[case]
+    t = jnp.arange(shape[1])
+    pick = jnp.stack([t % 2 == 0, t % 3 != 0])[:, :, None, None]
+    return jnp.where(pick, strong, weak)
+
+
+@pytest.mark.parametrize("T, n_real", [(150, (150, 101)), (192, (192, 64))],
+                         ids=["ragged_last_chunk", "whole_chunks"])
+@pytest.mark.parametrize("case, tol", [("near_1", 2e-6), ("near_0", 2e-6),
+                                       ("mixed", 2e-5)],
+                         ids=["near_1", "near_0", "mixed"])
+def test_chunkwise_kda_by_sub_blocks_equals_recurrence(case, tol, T, n_real):
+    """One decay a channel at `chunk=64`: four sub-blocks of 16 a chunk,
+    so the in-chunk terms between sub-blocks are the matrix products
+    around a reference row (`ops.kda._decayed_products`), several chunks
+    from a non-zero state, the last one ragged, one row short of T.
+    (`mixed` reads 7.9e-6 with the products as with the `[C, C, dk]`
+    reduction they replace, 1.1e-6 at chunks of 16: the 64-row solve's
+    rounding, where weak rows keep `A` near 1 between strong ones; the
+    products themselves are held to float64 in the next test.)"""
+    from ray_tpu.ops.kda import kda_chunked
+
+    B, H, dk, dv = 2, 3, 16, 8
+    ks = jax.random.split(jax.random.key(1), 7)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (B, T, H, dk)))
+    k = unit(jax.random.normal(ks[1], (B, T, H, dk)))
+    v = jax.random.normal(ks[2], (B, T, H, dv))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[3], (B, T, H)))
+    g = _decays(case, ks[4], (B, T, H, dk))
+    S0 = jax.random.normal(ks[5], (B, H, dk, dv))
+    want, states = _token_by_token(q, k, v, g, beta, S0)
+    got, S_got = kda_chunked(q, k, v, g, beta, S0, jnp.asarray(n_real),
+                             chunk=64)
+    assert jnp.abs(want).max() > 0.5
+    for row, n in enumerate(n_real):
+        assert jnp.abs(got[row, :n] - want[row, :n]).max() < tol
+        # the state after the last REAL token: padding did not advance it
+        assert jnp.abs(S_got[row] - states[n - 1][row]).max() < tol
+
+
+@pytest.mark.parametrize("per_token", [-20.0, -1e-5, (-20.0, -1e-5)],
+                         ids=["e-20_a_token", "no_decay", "alternating"])
+def test_decayed_products_neither_overflow_nor_lose_a_term(per_token):
+    """`_decayed_products` against the sum it stands for, taken in
+    float64 from the decay differences themselves: at e^-20 a token
+    (`exp(-G_i)` from the chunk's start would pass float32 after five
+    rows) every entry is finite, the entries beside the diagonal are not
+    lost, and nothing lies above the diagonal."""
+    from ray_tpu.ops.kda import _decayed_products
+
+    C, dk = 64, 16
+    ks = jax.random.split(jax.random.key(2), 4)
+    x = jax.random.normal(ks[0], (2, 3, C, dk))
+    k = jax.random.normal(ks[1], (3, C, dk))
+    per_token = jnp.resize(jnp.asarray(per_token), C)[:, None]
+    g = per_token * jax.random.uniform(ks[2], (3, C, dk), minval=0.5,
+                                       maxval=1.0)
+    # a few channels that hardly decay, so that far entries survive
+    g = jnp.where(jnp.arange(dk) < 3, 1e-3 * g, g)
+    G = jnp.cumsum(g, -2)
+    got = np.asarray(_decayed_products(x, k, G))
+    assert np.isfinite(got).all()
+    G64 = np.asarray(G, np.float64)
+    diff = G64[:, :, None, :] - G64[:, None, :, :]           # [.., r, i, c]
+    lower = np.tril(np.ones((C, C), bool))
+    want = np.einsum("xhrc,hic,hric->xhri", np.asarray(x, np.float64),
+                     np.asarray(k, np.float64),
+                     np.exp(np.where(lower[..., None], diff, -np.inf)))
+    assert np.abs(want[..., 40:, :16]).max() > 1e-2     # across sub-blocks
+    assert np.abs(got - want).max() < 1e-5 * np.abs(want).max()
+    assert not got[..., ~lower].any()
 
 
 # ----------------------------------- (e) the shares add up to the layer
