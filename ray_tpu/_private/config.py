@@ -155,41 +155,9 @@ _define("serve_autoscale_interval_s", float, 2.0,
 _define("serve_autoscale_cooldown_s", float, 5.0,
         "Minimum spacing between scale actions on one deployment, on "
         "top of the up/downscale hold delays.")
-_define("serve_kv_block_size", int, 16,
-        "Default paged-KV block size (rows per HBM block) for serve "
-        "LLM engines built without an explicit kv_block_size.")
 _define("serve_router_probe_interval_s", float, 1.0,
         "Period of the LLM router's per-replica queue-depth probe; a "
         "stalled replica sheds traffic within about one period.")
-_define("serve_preempt_hold_s", float, 0.25,
-        "How long the interactive lane must stay starved (queued "
-        "request + no admissible slot) before the engine's Hysteresis "
-        "gate lets it checkpoint a batch decode — transient pressure "
-        "from one full tick never thrashes checkpoints.")
-_define("serve_preempt_cooldown_s", float, 1.0,
-        "Minimum spacing between batch-decode preemptions on one "
-        "engine (each checkpoint costs an export + a later re-adopt).")
-_define("serve_spec_k", int, 4,
-        "Speculative decoding depth for serve LLM engines built with a "
-        "draft model: spec_k - 1 draft proposals verified per round, "
-        "so each verify step emits 1..spec_k tokens.")
-_define("serve_kv_host_tier_bytes", int, 256 * 1024 * 1024,
-        "Host-RAM budget of the KV memory hierarchy's middle tier "
-        "(serve/llm/kv_cache.KVTierManager): evicted prefix blocks "
-        "spill here instead of vanishing; overflow demotes to the "
-        "object store (or is dropped, counted, when no cluster is "
-        "attached).")
-_define("serve_kv_adopt_cost_fixed_ms", float, 2.0,
-        "PromoteCostModel: fixed cost of one tier->HBM promote "
-        "dispatch (host staging + the adopt scatter launch), "
-        "independent of block count.")
-_define("serve_kv_adopt_cost_per_block_ms", float, 0.1,
-        "PromoteCostModel: marginal cost per promoted KV block "
-        "(host->device transfer of one block's rows).")
-_define("serve_kv_prefill_cost_per_token_ms", float, 0.05,
-        "PromoteCostModel: prefill cost per prompt token — the "
-        "recompute side of the promote-vs-recompute crossover. Short "
-        "suffixes recompute; long ones re-adopt.")
 _define("serve_prefix_index_publish_interval_s", float, 2.0,
         "Period of each LLM replica's prefix-index publish (hash-chain "
         "heads + tier residency -> GCS report_prefix_index).")

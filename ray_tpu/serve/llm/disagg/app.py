@@ -32,9 +32,7 @@ def build_disagg_llm_app(model_config: Any = None,
     """Bind the disaggregated tier as one Serve application.
 
     ``engine_config`` shapes the decode pool; ``prefill_engine_config``
-    (default: same config) shapes the prefill pool, which needs
-    ``prefix_cache=True`` (chunked long-prompt admission hands off
-    through it). ``speculative`` is
+    (default: same config) shapes the prefill pool. ``speculative`` is
     forwarded to the decode pool only: the draft model speeds decoding
     and has nothing to do during prefill.
     """

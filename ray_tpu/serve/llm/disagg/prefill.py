@@ -31,8 +31,7 @@ class PrefillServer(LLMServer):
     The engine config should lean prefill-shaped: few slots (each
     admission occupies a slot only for its prefill), a deep block pool,
     and ``prefix_cache=True`` so shared prompt prefixes amortize across
-    requests — and so chunked long-prompt prefill works at all (chunks
-    hand off through the prefix cache).
+    requests.
     """
 
     def prefill(self, request: Dict[str, Any]) -> Dict[str, Any]:

@@ -102,8 +102,7 @@ class EngineConfig:
     # one legal value and stays only because the benchmark's cell files
     # pass it (ROADMAP D14).
     kv_layout: str = "paged"
-    # None -> GlobalConfig.serve_kv_block_size (RAY_TPU_-overridable).
-    kv_block_size: Optional[int] = None
+    kv_block_size: int = 16
     # Pool size; None -> num_slots * (max_seq_len / kv_block_size):
     # every slot can reach max_seq_len at once. Undersize it to
     # oversubscribe HBM: admission queues on exhaustion, never crashes.
@@ -116,14 +115,13 @@ class EngineConfig:
     # draft_params/draft_config): the draft proposes
     # spec_k - 1 tokens per round, one paged verify step accepts the
     # longest target-agreeing prefix — 1..spec_k tokens per round with
-    # greedy parity by construction. None -> GlobalConfig.serve_spec_k.
-    spec_k: Optional[int] = None
+    # greedy parity by construction.
+    spec_k: int = 4
     # Batch-lane preemption hysteresis: interactive pressure must hold
     # preempt_hold_s before a batch decode is checkpointed, and grants
     # are spaced by preempt_cooldown_s (observability/control.py gate).
-    # None -> GlobalConfig.serve_preempt_{hold,cooldown}_s.
-    preempt_hold_s: Optional[float] = None
-    preempt_cooldown_s: Optional[float] = None
+    preempt_hold_s: float = 0.25
+    preempt_cooldown_s: float = 1.0
     # Tiered KV spill (kv_cache.KVTierManager): prefix-cache evictions
     # gather their HBM rows into a host-RAM tier (object-store overflow
     # when a cluster is attached) and re-admissions promote them back
@@ -133,34 +131,21 @@ class EngineConfig:
     # gather at the row lengths of `export_rows`, one trace each, all
     # compiled by `warmup()`).
     kv_spill: Optional[bool] = None
-    kv_host_tier_bytes: Optional[int] = None    # None -> GlobalConfig
-    # PromoteCostModel knobs, milliseconds; None -> GlobalConfig
-    # serve_kv_adopt_cost_*/serve_kv_prefill_cost_per_token_ms.
-    kv_adopt_cost_fixed_ms: Optional[float] = None
-    kv_adopt_cost_per_block_ms: Optional[float] = None
-    kv_prefill_cost_per_token_ms: Optional[float] = None
+    kv_host_tier_bytes: int = 256 * 1024 * 1024     # the host tier's budget
+    # PromoteCostModel, milliseconds: a promote's dispatch, a promoted
+    # block's transfer, and a prompt token's recompute on the other side.
+    kv_adopt_cost_fixed_ms: float = 2.0
+    kv_adopt_cost_per_block_ms: float = 0.1
+    kv_prefill_cost_per_token_ms: float = 0.05
 
     def __post_init__(self):
-        from ray_tpu._private.config import GlobalConfig
-
         if self.decode_block < 1:
             raise ValueError("decode_block must be >= 1")
         if not self.prefill_buckets:
             raise ValueError("need at least one prefill bucket")
-        if self.spec_k is None:
-            object.__setattr__(self, "spec_k",
-                               int(GlobalConfig.serve_spec_k))
         if self.spec_k < 2:
             raise ValueError("spec_k must be >= 2 (one draft proposal "
                              "plus the bonus target token)")
-        if self.preempt_hold_s is None:
-            object.__setattr__(
-                self, "preempt_hold_s",
-                float(GlobalConfig.serve_preempt_hold_s))
-        if self.preempt_cooldown_s is None:
-            object.__setattr__(
-                self, "preempt_cooldown_s",
-                float(GlobalConfig.serve_preempt_cooldown_s))
         b = tuple(sorted(set(int(x) for x in self.prefill_buckets)))
         object.__setattr__(self, "prefill_buckets", b)
         if b[-1] > self.max_seq_len:
@@ -178,22 +163,6 @@ class EngineConfig:
             raise ValueError(
                 "kv_spill requires prefix_cache=True (the spill hook "
                 "rides prefix-cache eviction)")
-        if self.kv_host_tier_bytes is None:
-            object.__setattr__(
-                self, "kv_host_tier_bytes",
-                int(GlobalConfig.serve_kv_host_tier_bytes))
-        for name, knob in (
-                ("kv_adopt_cost_fixed_ms",
-                 GlobalConfig.serve_kv_adopt_cost_fixed_ms),
-                ("kv_adopt_cost_per_block_ms",
-                 GlobalConfig.serve_kv_adopt_cost_per_block_ms),
-                ("kv_prefill_cost_per_token_ms",
-                 GlobalConfig.serve_kv_prefill_cost_per_token_ms)):
-            if getattr(self, name) is None:
-                object.__setattr__(self, name, float(knob))
-        if self.kv_block_size is None:
-            object.__setattr__(self, "kv_block_size",
-                               int(GlobalConfig.serve_kv_block_size))
         bs = self.kv_block_size
         if bs < 1:
             raise ValueError("kv_block_size must be >= 1")
@@ -257,11 +226,10 @@ class Request:
     # state (handle.kv_state) instead of decoding — the disaggregated
     # prefill tier's mode (serve/llm/disagg).
     prefill_only: bool = False
-    # Prefix-cache engines: admit prompts longer than the
-    # largest bucket by prefilling bucket-sized chunks through the
-    # prefix cache (each chunk's blocks are cached, the next chunk
-    # prefix-hits them), one chunk per scheduler step — so interactive
-    # admissions interleave instead of stalling behind one long prefill.
+    # Admit a prompt longer than the largest bucket piece by piece: the
+    # request takes its slot and its blocks with the first piece and
+    # one piece goes in a scheduler step, so other admissions
+    # interleave instead of stalling behind one long prefill.
     chunked_prefill: bool = False
     # Cost-accounting identity: whose ledger row this request bills to
     # (observability/accounting.py). The schema is ready for the
@@ -310,8 +278,11 @@ class RequestHandle:
         self.meter: Optional[Any] = None
         self._done = threading.Event()
         self._engine: Optional["LLMEngine"] = None
-        self._chunk_ends: List[int] = []   # chunked-prefill boundaries
-        self._chunk_idx = 0
+        # Where the prompt's pieces end (one piece for a prompt that
+        # fits a bucket), and how many of its rows lie in its slot:
+        # None until it has one (`LLMEngine._admit_piece`).
+        self._piece_ends: List[int] = []
+        self._prompt_rows: Optional[int] = None
         self._adopted_submit = False   # arrived via submit_adopted
 
     def done(self) -> bool:
@@ -558,13 +529,11 @@ class LLMEngine:
             self._prefix.spill_fn = self._spill_evicted
         # The second kind of state (models/serving.py): a row a slot a
         # layer that keeps one, {leaf: [L', B, ...]}; None for a model
-        # whose whole state is rows in the pool. A sequence of such a
-        # model stays in the slot it was admitted to, and a prompt
-        # longer than a bucket is inserted into that slot chunk by
-        # chunk (`_chunking`: the slots whose prompts are under way,
-        # inactive until their last chunk).
+        # whose whole state is rows in the pool.
         self._slot_state = (model.init_slot_state(model_config, B)
                             if self._stateful else None)
+        # The slots whose prompts are under way, a piece a step,
+        # inactive until their last piece (`_admit`).
         self._chunking: deque = deque()
         self._tok = jnp.zeros((B,), jnp.int32)
         self._pos = jnp.zeros((B,), jnp.int32)
@@ -932,32 +901,23 @@ class LLMEngine:
             raise ValueError(
                 f"slo must be 'interactive' or 'batch', got "
                 f"{request.slo!r}")
-        chunked = request.chunked_prefill and P > top
         if request.prefill_only:
             self._refuse_if_pinned("prefill_only", "an exported KVState")
         handle = RequestHandle(next(self._ids), request)
-        if chunked:
-            if self._prefix is None and not self._pinned:
+        if P > top:
+            if not request.chunked_prefill:
                 raise ValueError(
-                    "chunked_prefill needs prefix_cache=True (chunks "
-                    "hand off through the prefix cache)")
+                    f"prompt length {P} exceeds largest prefill bucket "
+                    f"{top} (set chunked_prefill=True)")
             if P >= c.max_seq_len or -(-P // top) * top > c.max_seq_len:
                 raise ValueError(
                     f"prompt length {P} cannot be chunk-prefilled: "
                     f"ceil({P}/{top}) bucket-sized chunks exceed "
                     f"max_seq_len {c.max_seq_len}")
-            handle._chunk_ends = list(range(top, P, top)) + [P]
-        elif P > top:
-            raise ValueError(
-                f"prompt length {P} exceeds largest prefill bucket "
-                f"{top} (set chunked_prefill=True on a prefix-cache "
-                f"engine)")
+        handle._piece_ends = list(range(top, P, top)) + [P]
         # A request the pool can never hold must fail loudly at
         # submit — queuing it would deadlock admission forever.
-        worst = max(self._blocks_needed(P, request.max_tokens),
-                    self._bucket_for(min(P, top)) // c.kv_block_size)
-        if chunked and self._pinned:
-            worst = self._chunk_blocks(handle)
+        worst = self._blocks_to_take(handle, 0)
         if worst > c.pool_blocks:
             raise ValueError(
                 f"request needs up to {worst} KV blocks but the "
@@ -1147,102 +1107,74 @@ class LLMEngine:
 
     def _admit(self) -> List[Tuple[int, bool]]:
         """Move queued requests into free slots (one prefill each);
-        returns (slot, fresh) pairs inserted this step — `fresh` is
-        False for adopted checkpoints, whose last sampled token was
-        already emitted by the exporting engine. Admission needs
-        blocks as well as a slot — on pool exhaustion the
+        returns (slot, fresh) pairs whose prompts are in this step —
+        `fresh` is False for adopted checkpoints, whose last sampled
+        token was already emitted by the exporting engine. Admission
+        needs blocks as well as a slot — on pool exhaustion the
         request goes BACK to the lane head and admission stops
         (requests queue, never crash; blocks free as running sequences
-        finish). Chunked-prefill intermediates are throwaway
-        admissions (KV lands in the prefix cache, the slot is reused
-        immediately) rate-limited to one chunk per step so interactive
-        admissions interleave with a long prefill."""
+        finish). A prompt of several pieces takes its slot and blocks
+        with the first and keeps them: a later piece goes into that
+        slot ahead of anything queued, and one such piece goes out a
+        step, so other admissions interleave with a long prefill."""
         inserted: List[Tuple[int, bool]] = []
-        chunk_budget = 1
+        piece_budget = 1
         if self._chunking:
-            # A model with per-slot state: the prompt under way goes on
-            # in the slot it keeps, ahead of anything queued, and joins
-            # the tick with its last chunk.
-            slot = self._chunking[0]
-            handle = self._slots[slot].handle
-            self._admit_chunk(handle, slot)
-            chunk_budget = 0
-            if handle._chunk_idx == len(handle._chunk_ends):
-                self._chunking.popleft()
-                self._activate(handle, slot, fresh=True)
-                inserted.append((slot, True))
+            slot = self._chunking.popleft()
+            self._admit_one(self._slots[slot].handle, slot, inserted)
+            piece_budget = 0
         while self._free:
             handle = self._pop_next()
             if handle is None:
                 break
             if handle._done.is_set():
                 continue   # cancelled while queued by a racing cancel()
-            req = handle.request
-            if handle._chunk_ends and \
-                    handle._chunk_idx < len(handle._chunk_ends) - 1:
-                # Intermediate chunk: prefill prompt[:end] through the
-                # prefix cache and free the slot again. Budget of one
-                # chunk per step keeps the lane responsive.
-                if chunk_budget == 0:
+            if handle.kv_state is None and len(handle._piece_ends) > 1:
+                if piece_budget == 0:
                     self._requeue(handle)
                     break
-                if self._pinned:
-                    # first chunk: the request takes its slot and every
-                    # block it will need now, and keeps them
-                    slot = self._free.popleft()
-                    if not self._admit_chunk(handle, slot):
-                        self._free.appendleft(slot)
-                        self._requeue(handle)
-                        if req.slo == "interactive":
-                            self._admit_blocked = True
-                        break
-                    chunk_budget -= 1
-                    self._occupy(handle, slot)
-                    self._chunking.append(slot)
-                    continue
-                end = handle._chunk_ends[handle._chunk_idx]
-                slot = self._free[0]
-                t_chunk = time.monotonic()
-                with trace_span("llm_engine.admit_one", chunk=1):
-                    ok = self._admit_prefill(handle, slot, upto=end,
-                                             throwaway=True)
-                if not ok:
-                    self._requeue(handle)
-                    if req.slo == "interactive":
-                        self._admit_blocked = True
-                    break
-                if handle.meter is not None:
-                    handle.meter.note_chip(
-                        "prefill", time.monotonic() - t_chunk)
-                chunk_budget -= 1
-                handle._chunk_idx += 1
-                self._requeue(handle)
-                continue
+                piece_budget -= 1
             slot = self._free.popleft()
-            fresh = handle.kv_state is None
-            t_admit = time.monotonic()
-            with trace_span("llm_engine.admit_one") as sp:
-                ok = (self._admit_prefill(handle, slot, span=sp) if fresh
-                      else self._admit_adopted(handle, slot))
-            if not ok:
+            if not self._admit_one(handle, slot, inserted):
                 self._free.appendleft(slot)
-                if req.slo == "interactive":
+                if handle.request.slo == "interactive":
                     self._admit_blocked = True
                 self._requeue(handle)
                 break
-            if self._draft is not None and fresh:
-                self._draft_admit(list(req.prompt), slot)
-            if handle.meter is not None:
-                # Admission dispatch (insert/adopt + draft seed) billed
-                # as this request's prefill chip-time; fresh admissions
-                # resume-from-preempt included — the adopt scatter is
-                # real chip work this request caused.
-                handle.meter.note_chip(
-                    "prefill", time.monotonic() - t_admit)
             self._occupy(handle, slot)
+        return inserted
+
+    def _admit_one(self, handle: RequestHandle, slot: int,
+                   inserted: List[Tuple[int, bool]]) -> bool:
+        """One admission dispatch for `handle` into `slot`: its
+        checkpoint, or the next piece of its prompt. With the prompt in,
+        the slot joins the tick (and `inserted`); with pieces left it
+        waits in `_chunking`. False, and nothing changed, where the
+        pool cannot cover the sequence."""
+        req = handle.request
+        fresh = handle.kv_state is None
+        said = {"chunk": 1} if fresh and len(handle._piece_ends) > 1 else {}
+        t_admit = time.monotonic()
+        with trace_span("llm_engine.admit_one", **said) as sp:
+            ok = (self._admit_piece(handle, slot, sp) if fresh
+                  else self._admit_adopted(handle, slot))
+        if not ok:
+            return False
+        prompt_in = not fresh or handle._prompt_rows == len(req.prompt)
+        if prompt_in and fresh and self._draft is not None:
+            self._draft_admit(list(req.prompt), slot)
+        if handle.meter is not None:
+            # Admission dispatch (insert/adopt + draft seed) billed
+            # as this request's prefill chip-time; fresh admissions
+            # resume-from-preempt included — the adopt scatter is
+            # real chip work this request caused.
+            handle.meter.note_chip("prefill", time.monotonic() - t_admit)
+        if prompt_in:
             self._activate(handle, slot, fresh)
             inserted.append((slot, fresh))
-        return inserted
+        else:
+            self._chunking.appendleft(slot)
+        return True
 
     def _activate(self, handle: RequestHandle, slot: int,
                   fresh: bool) -> None:
@@ -1274,55 +1206,20 @@ class LLMEngine:
         st.uses += 1
         st.handle = handle
 
-    def _chunk_blocks(self, handle: RequestHandle) -> int:
-        """Blocks a chunked prompt of a model whose sequences stay in
-        their slot takes with its first chunk: every position it can
-        write, and the whole bucket of its last chunk."""
+    def _blocks_to_take(self, handle: RequestHandle, start: int) -> int:
+        """Blocks the sequence takes when its prompt goes in from row
+        `start` on (the rows before it are a cached prefix's): every
+        position it can write, and the whole bucket of every piece
+        (the insert scatters whole blocks, and the slot must own each
+        block written)."""
         req, bs = handle.request, self.config.kv_block_size
-        P = len(req.prompt)
-        start = handle._chunk_ends[-2] if len(handle._chunk_ends) > 1 else 0
-        return max(self._blocks_needed(P, req.max_tokens),
-                   (start + self._bucket_for(P - start)) // bs)
-
-    def _admit_chunk(self, handle: RequestHandle, slot: int) -> bool:
-        """The next chunk of a chunked prompt of a model whose sequences
-        stay in their slot (a state by slot, a window kind of pool),
-        into the slot the request keeps. The first chunk takes the
-        blocks (False, and nothing taken, when a pool cannot cover
-        them); a later one finds the rows and the state of the chunks
-        before it in the slot, so nothing is handed over and nothing
-        can be lost in between."""
-        import numpy as np
-
-        req, c = handle.request, self.config
-        bs = c.kv_block_size
-        i = handle._chunk_idx
-        start = handle._chunk_ends[i - 1] if i else 0
-        n = handle._chunk_ends[i] - start
-        t_chunk = time.monotonic()
-        with trace_span("llm_engine.admit_one", chunk=1) as sp:
-            if i == 0:
-                blocks = self._take_blocks(
-                    slot, self._chunk_blocks(handle), sp)
-                if blocks is None:
-                    return False
-                self._tables[slot] = 0
-                self._tables[slot, :len(blocks)] = blocks
-                self._slot_blocks[slot] = blocks
-                if handle.meter is not None:
-                    handle.meter.blocks_acquired(len(blocks))
-            bucket = self._bucket_for(n)
-            padded = np.zeros((bucket,), np.int32)
-            padded[:n] = np.asarray(req.prompt[start:start + n], np.int32)
-            row = self._tables[slot].copy()
-            self._insert(slot, row, start, padded, n,
-                         row[start // bs:(start + bucket) // bs],
-                         req.temperature)
-        if handle.meter is not None:
-            handle.meter.note_chip("prefill", time.monotonic() - t_chunk)
-        handle.prefilled_tokens += n
-        handle._chunk_idx += 1
-        return True
+        need = self._blocks_needed(len(req.prompt), req.max_tokens)
+        for end in handle._piece_ends:
+            if end > start:
+                need = max(need,
+                           (start + self._bucket_for(end - start)) // bs)
+                start = end
+        return need
 
     def _take_blocks(self, slot: int, n: int, span) -> Optional[List[int]]:
         """`n` blocks of the full kind and, for a model with a window
@@ -1361,34 +1258,68 @@ class LLMEngine:
         if state:
             self._slot_state, = state
 
-    def _admit_prefill(self, handle: RequestHandle, slot: int,
-                       upto: Optional[int] = None,
-                       throwaway: bool = False, span=None) -> bool:
-        """Block accounting + insert for one request. Returns
-        False (nothing allocated, nothing inserted) when the pool can't
-        cover it even after evicting cold prefix entries.
-
-        `upto` prefills only prompt[:upto] (a chunked-prefill chunk);
-        `throwaway` additionally keeps the slot free — the KV outlives
-        the admission only through the prefix-cache refs taken at
-        insert, so the next chunk (or the final admission) prefix-hits
-        it. The sampled token of a throwaway insert is garbage by
-        construction and never read: the slot stays inactive, so the
-        tick masks it and the final admission overwrites tok/pos."""
+    def _admit_piece(self, handle: RequestHandle, slot: int, span) -> bool:
+        """The next piece of this request's prompt into this slot: the
+        ONE way a prompt goes in, whatever the model (a prompt that
+        fits a bucket is the case of one piece). The first piece takes
+        the slot's blocks (`_take_prompt_blocks`: False, nothing taken
+        and nothing inserted, when the pool cannot cover them) and
+        starts behind the cached prefix; a later one finds the rows,
+        and the state by slot of a model that keeps one, where the
+        pieces before it left them. The slot holds its own references
+        from the first piece to its release, so nothing is handed over
+        between two pieces and no eviction in between can take a row
+        away. Pieces end at multiples of the largest bucket
+        (`submit`). After EACH piece the prompt's full blocks so far
+        are registered in the prefix cache (their rows are real once
+        its insert is dispatched: programs run in order), so a request
+        sharing the prefix hits them while the rest still goes in."""
         import numpy as np
 
         req = handle.request
+        bs = self.config.kv_block_size
+        if handle._prompt_rows is None and \
+                not self._take_prompt_blocks(handle, slot, span):
+            return False
+        start = handle._prompt_rows
+        end = next(e for e in handle._piece_ends if e > start)
+        n = end - start
+        bucket = self._bucket_for(n)
+        padded = np.zeros((bucket,), np.int32)
+        padded[:n] = np.asarray(req.prompt[start:end], np.int32)
+        row = self._tables[slot].copy()
+        self._insert(slot, row, start, padded, n,
+                     row[start // bs:(start + bucket) // bs],
+                     req.temperature)
+        handle.prefilled_tokens += n
+        handle._prompt_rows = end
+        if self._prefix is not None and end >= bs:
+            # the next request sharing this prefix skips its prefill
+            self._prefix.insert(req.prompt[:end],
+                                self._slot_blocks[slot][:end // bs])
+        return True
+
+    def _take_prompt_blocks(self, handle: RequestHandle, slot: int,
+                            span) -> bool:
+        """Before a prompt's first piece: every block the sequence can
+        need (`_blocks_to_take`) into `slot`'s table, all or nothing,
+        evicting cold prefix entries if that closes the gap. With a
+        prefix cache the table starts with the longest cached prefix,
+        extended by spilled chain links where the cost model says the
+        transfer beats the recompute; `handle._prompt_rows` says how
+        many of the prompt's rows the slot holds so."""
+        req = handle.request
         c = self.config
         bs = c.kv_block_size
-        prompt = req.prompt if upto is None else req.prompt[:upto]
+        prompt = req.prompt
         P = len(prompt)
-        if throwaway:
-            # Only the chunk itself; headroom is the FINAL admission's
-            # problem (these blocks are cache-owned the moment the
-            # insert returns).
-            need_total = -(-P // bs)
-        else:
-            need_total = self._blocks_needed(P, req.max_tokens)
+
+        def fits(n_blocks: int) -> bool:
+            # history + every padded piece within the slot's table (a
+            # shallow hit on a near-max prompt can otherwise push a
+            # bucket's whole-block scatter past S)
+            return self._blocks_to_take(handle, n_blocks * bs) \
+                <= c.max_blocks_per_slot
 
         # Longest cached prefix, capped so the LAST prompt token is
         # always prefilled (its logits seed the first sampled token).
@@ -1396,22 +1327,7 @@ class LLMEngine:
         if self._prefix is not None:
             hit_blocks = self._prefix.match(prompt,
                                             max_blocks=(P - 1) // bs)
-        if P - len(hit_blocks) * bs > c.prefill_buckets[-1]:
-            # Chunked-prefill continuation whose earlier chunks were
-            # evicted from the prefix cache before this admission: the
-            # remaining suffix no longer fits any bucket. Rewind the
-            # chunk plan to what the cache still covers and re-chunk.
-            self._allocator.free(hit_blocks)
-            handle._chunk_idx = (len(hit_blocks) * bs) \
-                // c.prefill_buckets[-1]
-            return False
-        # Trim the hit so history + the padded suffix bucket still fit
-        # in the slot's table (a shallow hit on a near-max prompt can
-        # otherwise push the bucket's whole-block scatter past S).
-        while hit_blocks:
-            hl = len(hit_blocks) * bs
-            if hl + self._bucket_for(P - hl) <= c.max_seq_len:
-                break
+        while hit_blocks and not fits(len(hit_blocks)):
             self._allocator.free([hit_blocks.pop()])
         n_hit = len(hit_blocks)
         # Tier continuation: extend the HBM hit with spilled chain
@@ -1429,11 +1345,7 @@ class LLMEngine:
                 promote = self._tiers.lookup(prompt, bs,
                                              start_depth=n_hit,
                                              max_blocks=cap)
-            # Same table-fit trim as the HBM hit above.
-            while promote:
-                hl = (n_hit + len(promote)) * bs
-                if hl + self._bucket_for(P - hl) <= c.max_seq_len:
-                    break
+            while promote and not fits(n_hit + len(promote)):
                 promote.pop()
             if promote and not self._cost_model.should_promote(
                     len(promote), bs):
@@ -1441,14 +1353,8 @@ class LLMEngine:
                 promote = []
         while True:
             n_pro = len(promote)
-            hist_len = (n_hit + n_pro) * bs
-            suffix_len = P - hist_len
-            bucket = self._bucket_for(suffix_len)
-            # Fresh blocks: the rest of the sequence, but at least the
-            # promoted links plus the whole suffix bucket — the adopt
-            # and insert scatters write full blocks, and every written
-            # block must be owned by this slot.
-            n_new = max(need_total - n_hit, n_pro + bucket // bs)
+            n_new = self._blocks_to_take(
+                handle, (n_hit + n_pro) * bs) - n_hit
             new_blocks = self._take_blocks(slot, n_new, span)
             if new_blocks is None and self._prefix is not None:
                 want = n_new - self._allocator.free_blocks
@@ -1467,43 +1373,20 @@ class LLMEngine:
             return False
 
         blocks = hit_blocks + new_blocks
-        row = np.zeros((c.max_blocks_per_slot,), np.int32)
-        row[:len(blocks)] = blocks
-        if not throwaway:
-            self._tables[slot] = row
-            self._slot_blocks[slot] = blocks
-            if handle.meter is not None:
-                # Block-seconds meter opens here; _release_slot closes
-                # it with the same count (all blocks alloc up front).
-                # Throwaway chunk admissions skip it — their KV is
-                # cache-owned the moment the insert returns.
-                handle.meter.blocks_acquired(len(blocks))
-
+        self._tables[slot] = 0
+        self._tables[slot, :len(blocks)] = blocks
+        self._slot_blocks[slot] = blocks
+        if handle.meter is not None:
+            # Block-seconds meter opens here; _release_slot closes
+            # it with the same count (all blocks alloc up front).
+            handle.meter.blocks_acquired(len(blocks))
         if promote:
             # Land the tier links in new_blocks[:n_pro] BEFORE the
-            # insert below reads them as history.
+            # first piece's insert reads them as history.
             with trace_span("llm_engine.promote", blocks=n_pro):
                 self._promote_tier_hits(promote, new_blocks[:n_pro],
                                         slot, handle=handle)
-        padded = np.zeros((bucket,), np.int32)
-        padded[:suffix_len] = np.asarray(prompt[hist_len:], np.int32)
-        scatter_ids = np.asarray(new_blocks[n_pro:n_pro + bucket // bs],
-                                 np.int32)
-        self._insert(slot, row, hist_len, padded, suffix_len, scatter_ids,
-                     req.temperature)
-        handle.prefilled_tokens += suffix_len
-        if self._prefix is not None:
-            # Register the prompt's FULL blocks (all rows real) so the
-            # next request sharing this prefix skips their prefill.
-            full = P // bs
-            if full:
-                self._prefix.insert(prompt, blocks[:full])
-        if throwaway:
-            # The prefix cache now owns the chunk's full blocks (insert
-            # increfed them); drop this admission's transient refs. The
-            # slot was never activated, so its garbage tok/pos rows are
-            # masked by the tick and overwritten at final admission.
-            self._allocator.free(blocks)
+        handle._prompt_rows = (n_hit + n_pro) * bs
         return True
 
     def _admit_adopted(self, handle: RequestHandle, slot: int) -> bool:
@@ -1818,7 +1701,7 @@ class LLMEngine:
         pool blocks through the ONE adopt program (padding ids point
         one past the pool — dropped under jit). The tok/pos writes are
         placeholders: the insert that follows for the same slot owns
-        them (and a throwaway slot is never activated). Tier entries
+        them. Tier entries
         are popped only after the scatter dispatched — the
         all-or-nothing contract."""
         import numpy as np
@@ -1965,9 +1848,9 @@ class LLMEngine:
         self._refuse_if_pinned("preemption", "a checkpoint")
         # the exported pending token must be one the client has
         self._settle("preempt")
-        handle = self._slots[slot].handle
-        if handle is None:
+        if not self._active[slot]:      # free, or its prompt still going in
             raise ValueError(f"slot {slot} is not live")
+        handle = self._slots[slot].handle
         handle.kv_state = self._export_state(slot)
         self._release_slot(slot, donate=True)
         self._preempted += 1
@@ -1990,7 +1873,7 @@ class LLMEngine:
             return
         batch_slots = [
             s for s in range(self.config.num_slots)
-            if self._slots[s].handle is not None
+            if self._active[s]          # not a prompt still going in
             and self._slots[s].handle.request.slo == "batch"
             and not self._slots[s].handle.request.prefill_only
         ]
@@ -2201,11 +2084,11 @@ class LLMEngine:
             self._process_cancels()
             self._maybe_preempt()
         self._admit_blocked = False
-        did_ctrl = did_ctrl or bool(self._chunking)   # a chunk will go out
         with phase("llm_engine.admit") as sp:
             inserted = self._admit()
             sp.set_metadata(admitted=len(inserted))
             mask, live = self._tick_slots()
+        did_ctrl = did_ctrl or bool(self._chunking)   # a piece went out
         if inserted:
             # First generated token per freshly-prefilled slot (before
             # the tick below overwrites it with the second). Adopted

@@ -36,7 +36,7 @@ without a device):
   prefix block no longer vanishes: the engine's spill hook gathers its
   rows off the pool (one `_export_fn` dispatch per eviction batch) and
   parks them here, first in host RAM (bounded by
-  ``serve_kv_host_tier_bytes``), demoting LRU entries to the object
+  ``EngineConfig.kv_host_tier_bytes``), demoting LRU entries to the object
   store when the host tier overflows (``put_fn``/``get_fn`` — wired to
   ``ray_tpu.put``/``get`` by the deployment; absent a cluster, cold
   overflow is dropped and counted). A re-admitted prompt that misses
@@ -632,9 +632,9 @@ class PromoteCostModel:
     recomputing costs prefill over the covered tokens. Short suffixes
     lose to recompute — prefill is one fused program and the fixed
     adopt cost dominates — so admission only promotes when the model
-    says the crossover is passed. Defaults come from the
-    ``serve_kv_adopt_cost_*`` / ``serve_kv_prefill_cost_per_token_ms``
-    config knobs; benches overwrite them with measured numbers.
+    says the crossover is passed. The engine builds it from
+    ``EngineConfig.kv_adopt_cost_*`` / ``kv_prefill_cost_per_token_ms``;
+    benches overwrite them with measured numbers.
     """
 
     adopt_fixed_s: float = 2e-3

@@ -96,6 +96,37 @@ def _without_metadata(text: str) -> str:
     return re.sub(r'"body":"[^"]+"', '"body":""', text)
 
 
+# sha256 of `_without_metadata(compiled.as_text())` at PR 42's tree
+# (efba6a6), which PR 43 left as it was: it changed how a long prompt's
+# pieces are ADMITTED and no device program. A PR that means to change
+# one of these programs pins its own text here and says so; one that
+# does not (a scheduler change, a clean-up) has this to show it.
+PROGRAM_TEXT_SHA256 = {
+    ("chat-decode", "tick"):
+        "48a91f54a548addd9d951f33258125cd66601f6eb5de512b9f23800388b2ae93",
+    ("chat-decode", "insert"):
+        "33e1fd0f5f8e39ac4168e9c0371c61ce1c57240d9308ea125ea1b63b1d527fc6",
+    ("assistant-decode-moe", "tick"):
+        "8f11c202dee0816c9bda3bb0a54e3745760458d31f1d8595b01ede0a6ca1dedb",
+    ("assistant-decode-moe", "insert"):
+        "65ba858b4c7162f9310e1639960524afc4cf39e6972c8d6ab79d965c4716e7c6",
+}
+
+
+def _is_the_pinned_text(cell, program, text):
+    """Where a cell's tick or largest insert has a pinned hash, the
+    compiled v5e text (as the chip runs it: `on_tpu`, no selector
+    patched) is that text. The tests that compile these programs
+    anyway call this, so the pin costs no compile of its own."""
+    import hashlib
+
+    want = PROGRAM_TEXT_SHA256.get((cell, program))
+    if want is not None:
+        assert hashlib.sha256(
+            _without_metadata(text).encode()).hexdigest() == want, (
+            cell, program)
+
+
 def _hbm_gib(compiled) -> float:
     m = compiled.memory_analysis()
     return (m.argument_size_in_bytes + m.output_size_in_bytes
@@ -491,6 +522,7 @@ def test_paged_attention_leaves_the_inserts_as_they_were(
             _serving_cell(cell, one_chip), one_chip).as_text())
 
     with_kernel = compiled()
+    _is_the_pinned_text(cell, "insert", with_kernel)
     monkeypatch.setattr(paged_attention, "engages", lambda pool: False)
     assert compiled() == with_kernel
     assert "paged_attention" not in with_kernel
@@ -520,6 +552,7 @@ def test_latent_ticks_read_the_pool_through_the_block_table(
     assert eng._model.paged_attention(eng.pools) == "kernel"
     compiled = _compiled_cell_tick(eng, one_chip)
     text = compiled.as_text()
+    _is_the_pinned_text(cell, "tick", text)
     assert text.count("paged_attention") >= pool[0]
     results = _results(text)
     padded = (ec.num_slots, ec.max_seq_len, pool[3])
@@ -585,6 +618,8 @@ def test_grouped_matmul_leaves_the_other_programs_as_they_were(
 
     compiled = tick if program == "chat-decode tick" else train_step
     with_kernel = _without_metadata(compiled())
+    if program == "chat-decode tick":
+        _is_the_pinned_text("chat-decode", "tick", with_kernel)
     monkeypatch.setattr(grouped_matmul, "engages",
                         lambda m, g, k, n, dtype: False)
     assert _without_metadata(compiled()) == with_kernel
@@ -1126,3 +1161,4 @@ def test_carried_stacks_leave_the_benchmark_train_step_as_it_was(
     plain = compiled_text()
     assert carried.count("tpu_custom_call") >= 3
     assert carried == plain
+

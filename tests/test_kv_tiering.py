@@ -499,6 +499,102 @@ def test_warmup_compiles_every_export_row(kind):
     assert st["traces"] == {"tick": 1, "insert": 1, "export": 3, "adopt": 1}
 
 
+_LONG = [5 + (i * 11) % 190 for i in range(40)]     # pieces of 32 and 8
+
+
+def _chunked(**fields):
+    from ray_tpu.serve.llm.engine import Request
+
+    return Request(**{"prompt": _LONG, "max_tokens": 8,
+                      "chunked_prefill": True, **fields})
+
+
+def _unchunked(kind, **fields):
+    """What an engine whose largest bucket holds the whole prompt makes
+    of the request: the handle, memoized a kind."""
+    from ray_tpu.serve.llm.engine import Request
+
+    key = (kind, tuple(fields.get("prompt", _LONG)),
+           fields.get("prefill_only", False))
+    if key not in _CACHE:
+        if ("big", kind) not in _CACHE:
+            _CACHE["big", kind] = _engine_of(
+                kind, **{**_GEO, "prefill_buckets": (16, 48)})
+        big = _CACHE["big", kind]
+        _CACHE[key] = big.submit(Request(**{
+            "prompt": _LONG, "max_tokens": 8, **fields}))
+        big.drain()
+    return _CACHE[key]
+
+
+@pytest.mark.parametrize("case", [
+    "no_prefix_cache", "starts_at_the_hit", "a_sharer_hits_its_blocks",
+    "cancel_between_pieces", "prefill_only_exports"])
+@pytest.mark.parametrize("kind", ["dense", "latent"])
+def test_chunked_prompt_goes_into_the_slot_it_keeps(kind, case):
+    """A prompt longer than the largest bucket goes in piece by piece
+    into ONE slot, whatever the model: without a prefix cache; from a
+    cached prefix on; leaving its blocks for a sharer to hit; giving
+    every block back when cancelled between two pieces; and exporting
+    the KVState an unchunked prefill exports."""
+    import numpy as np
+
+    from ray_tpu.serve.llm.engine import Request
+
+    bs = _GEO["kv_block_size"]
+    want = list(_unchunked(kind).tokens)
+    if case == "no_prefix_cache":
+        eng = _engine_of(kind, **_GEO, prefix_cache=False)
+        h = eng.submit(_chunked())
+        assert eng.step() and list(eng._chunking) == [0]
+        assert not eng._active.any()        # inactive until the last piece
+        eng.drain()
+        assert h.tokens == want and h.prefilled_tokens == len(_LONG)
+    elif case == "starts_at_the_hit":
+        eng = _engine_of(kind, **_GEO)
+        eng.submit(Request(prompt=_LONG[:2 * bs + 4], max_tokens=2))
+        eng.drain()
+        h = eng.submit(_chunked())
+        eng.drain()
+        assert h.tokens == want
+        assert h.prefilled_tokens == len(_LONG) - 2 * bs
+        assert eng.stats()["prefix_cache"]["hit_tokens"] == 2 * bs
+    elif case == "a_sharer_hits_its_blocks":
+        eng = _engine_of(kind, **_GEO)
+        eng.submit(_chunked())
+        eng.drain()
+        sharer = _LONG[:36] + [3, 1, 4, 1, 5, 9, 2, 6]
+        h = eng.submit(_chunked(prompt=sharer))
+        eng.drain()
+        assert h.prefilled_tokens == len(sharer) - 4 * bs
+        assert h.tokens == _unchunked(kind, prompt=sharer).tokens
+    elif case == "cancel_between_pieces":
+        eng = _engine_of(kind, **_GEO, kv_spill=False)
+        free = eng._allocator.free_blocks
+        h = eng.submit(_chunked())
+        assert eng.step() and list(eng._chunking) == [0]
+        assert h.cancel() and eng.step()
+        assert h.finish_reason == "cancelled" and not eng._chunking
+        assert len(eng._free) == _GEO["num_slots"]
+        # the first piece's full blocks are the cache's alone now
+        assert eng._allocator.free_blocks == free - 32 // bs
+        eng._prefix.clear()
+        assert eng._allocator.free_blocks == free
+    else:
+        eng = _engine_of(kind, **_GEO)
+        h = eng.submit(_chunked(prefill_only=True))
+        eng.drain()
+        got, ref = h.kv_state, _unchunked(kind, prefill_only=True).kv_state
+        assert h.finish_reason == "prefill" and h.tokens == want[:1]
+        assert (got.prompt, got.tokens, got.next_tok, got.pos) == (
+            ref.prompt, ref.tokens, ref.next_tok, ref.pos)
+        assert got.blocks.keys() == ref.blocks.keys()
+        for name, x in got.blocks.items():
+            np.testing.assert_allclose(
+                np.asarray(x, np.float32),
+                np.asarray(ref.blocks[name], np.float32), atol=2e-2)
+
+
 def test_cluster_prefix_index_gcs():
     """report_prefix_index / lookup_prefix_index: roundtrip,
     last-write-wins per replica, the serve_prefix_index_max_heads cap,
