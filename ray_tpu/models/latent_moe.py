@@ -1,8 +1,10 @@
 """Latent-attention decoder with dropless sigmoid-routed experts.
 
 The block of the DeepSeek-V3 family as published (`model_type`
-`deepseek_v3`, no query compression), written once over a cache
-interface:
+`deepseek_v3`), written once over a cache interface; the query is
+projected whole, or through a low rank under a norm of its own
+(`q_lora_rank`, with both low-rank paths scaled where `scale_lora` says
+so: `models/shortcut_moe.py` runs this attention twice a layer):
 
 - **Latent attention (MLA).** A token's key/value state is ONE row per
   layer, `c ‖ k_rope`: the normed latent (`kv_lora_rank` wide) and one
@@ -68,6 +70,11 @@ class LatentMoEConfig:
     top_k: int = 6
     n_shared_experts: int = 2
     routed_scaling_factor: float = 2.448
+    # the query through a low rank with a norm of its own (None: `wq`
+    # whole), and the two low-rank paths x sqrt(dim / rank) after
+    # their norms (`mla_scale_q_lora` / `mla_scale_kv_lora`)
+    q_lora_rank: Optional[int] = None
+    scale_lora: bool = False
     max_seq_len: int = 32768
     rope_theta: float = 1e6
     norm_eps: float = 1e-6
@@ -333,6 +340,16 @@ def _swiglu(h, w_gate, w_up, w_down, dt):
         @ w_down.astype(dt)
 
 
+def _low_rank(c: LatentMoEConfig, z, norm):
+    """A low-rank path after its norm: z [..., rank], x sqrt(dim / rank)
+    where the config scales them.  The constant is applied where the
+    published block applies it and folded into nothing: the cached
+    latent is the scaled one (the shared rotary key beside it is not
+    scaled), so `wkv_b` and the absorbed forms read it as it lies."""
+    z = rms_norm(z, norm, c.norm_eps)
+    return z * math.sqrt(c.dim / z.shape[-1]) if c.scale_lora else z
+
+
 def latent_attention(c: LatentMoEConfig, l: int, p, x, qpos, cos, sin,
                      cache):
     """The attention half of a layer: x [B, S, D] at absolute positions
@@ -345,7 +362,12 @@ def latent_attention(c: LatentMoEConfig, l: int, p, x, qpos, cos, sin,
     n, r, rank = c.qk_nope_head_dim, c.qk_rope_head_dim, c.kv_lora_rank
     with jax.named_scope("attn"):
         h = rms_norm(x, p["attn_norm"], c.norm_eps)
-        q = (h @ p["wq"].astype(dt)).reshape(B, S, H, n + r)
+        if c.q_lora_rank is None:
+            q = h @ p["wq"].astype(dt)
+        else:
+            q = _low_rank(c, h @ p["wq_a"].astype(dt), p["q_norm"]) \
+                @ p["wq_b"].astype(dt)
+        q = q.reshape(B, S, H, n + r)
         ckr = h @ p["wkv_a"].astype(dt)
         if cos is None:
             q_rope, k_rope = q[..., n:], ckr[..., rank:]
@@ -354,7 +376,7 @@ def latent_attention(c: LatentMoEConfig, l: int, p, x, qpos, cos, sin,
                                        sin[:, :, None]).astype(dt)
             k_rope = _rope_interleaved(ckr[..., rank:], cos, sin).astype(dt)
         new = jnp.concatenate(
-            [rms_norm(ckr[..., :rank], p["kv_norm"], c.norm_eps), k_rope,
+            [_low_rank(c, ckr[..., :rank], p["kv_norm"]), k_rope,
              jnp.zeros((B, S, c.cache_row - rank - r), dt)], -1)
     rows = cache.update(l, new)
     with jax.named_scope("attn"):
@@ -396,13 +418,18 @@ def _layer(c: LatentMoEConfig, l: int, p, x, qpos, cos, sin, cache,
     return feed_forward(c, p, x, live)
 
 
+def rotary(c: LatentMoEConfig, qpos):
+    """cos, sin [B, S, r/2] of the rope channels at positions qpos."""
+    r = c.qk_rope_head_dim
+    inv = 1.0 / (c.rope_theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r))
+    freqs = qpos.astype(jnp.float32)[..., None] * inv
+    return jnp.cos(freqs), jnp.sin(freqs)
+
+
 def _stack(c: LatentMoEConfig, params, tokens, qpos, cache, live=None):
     """Embedding, every layer, final norm: tokens [B, S] at qpos [B, S]
     -> (normed hidden [B, S, D], routed tokens [n_moe_layers, E])."""
-    r = c.qk_rope_head_dim
-    inv = 1.0 / (c.rope_theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r))
-    freqs = qpos.astype(jnp.float32)[..., None] * inv       # [B, S, r/2]
-    cos, sin = jnp.cos(freqs), jnp.sin(freqs)
+    cos, sin = rotary(c, qpos)
     x = embed_lookup(params["embed"].astype(c.dtype), tokens)
     routed = []
     for l, p in enumerate(params["layers"]):

@@ -16,12 +16,16 @@ Sharding recipe (see `moe_param_specs`): experts [E, ...] sharded
 P("expert", ...); token tensors data-sharded; jit with those out/in
 shardings and GSPMD places dispatch/combine all-to-alls on the ICI ring.
 
-Two expert layers live here.  `dropless_moe` is THE dropless layer: the
-assignments are sorted by expert and three grouped matrix products
-(`ops/grouped_matmul.py` on a TPU in bf16, `jax.lax.ragged_dot`
-elsewhere) run over the experts, so every token gets all of its experts
-whatever the load and an expert nobody picked is not read.
-Its routing rule is an argument (`softmax_top_k`, `sigmoid_bias_top_k`).
+Two expert layers live here.  `dropless_moe` is THE dropless layer, the
+one every served model runs: the assignments are sorted by expert and
+three grouped matrix products (`ops/grouped_matmul.py` on a TPU in
+bf16, `jax.lax.ragged_dot` elsewhere) run over the experts this chip
+holds (`share`), so every token gets all of its held experts whatever
+the load and an expert nobody picked is not read; an assignment to a
+ZERO-COMPUTE expert (`n_zero`: the router's last columns) is in no
+group, reads no weight and adds its weight times the token itself.
+Its routing rule is an argument (`softmax_top_k`, `sigmoid_bias_top_k`,
+`softmax_bias_top_k`).
 `moe_layer` below is the older capacity-dispatch layer that
 `LlamaConfig.n_experts` trains with; it DROPS tokens past an expert's
 capacity and is due for folding into the dropless one (ROADMAP Design).
@@ -186,6 +190,22 @@ def sigmoid_bias_top_k(k: int, scale: float = 1.0,
     return route
 
 
+def softmax_bias_top_k(k: int, scale: float = 1.0) -> Routing:
+    """Softmax scores over the router's WHOLE width (zero-compute
+    columns among them); the k with the largest score PLUS the selection
+    bias (`router_bias`, a buffer, not a weight) are chosen, and
+    weighted by their scores WITHOUT it x `scale`, not renormalised: a
+    token's weights sum to what its chosen k hold of the softmax."""
+
+    def route(logits, params):
+        s = jax.nn.softmax(logits, axis=-1)
+        _, idx = jax.lax.top_k(
+            s + params["router_bias"].astype(jnp.float32), k)
+        return idx, jnp.take_along_axis(s, idx, axis=-1) * scale
+
+    return route
+
+
 def grouped_path(rows: int, w_shape: Tuple[int, int, int], dtype) -> str:
     """"kernel" | "xla": which grouped product `dropless_moe` compiles
     to for `rows` assignments over experts `w_gate` [E, D, F] on this
@@ -200,7 +220,10 @@ def grouped_path(rows: int, w_shape: Tuple[int, int, int], dtype) -> str:
 def serving_grouped_path(config, slots: int) -> str:
     """`ServingFns.grouped_matmul` of a model whose expert layers are
     `dropless_moe`: `grouped_path` at the decode tick's shape, `slots`
-    tokens of `top_k` assignments over the experts this chip holds."""
+    tokens of `top_k` assignments over the experts this chip holds.
+    `slots * top_k` is the rows the products are COMPILED for, an upper
+    bound on the rows a tick fills: dead slots, assignments to experts
+    held elsewhere and zero-compute picks are in no group."""
     held = getattr(config, "n_held_experts", config.n_experts)
     return grouped_path(
         slots * config.top_k,
@@ -221,12 +244,13 @@ def _grouped_product(sizes, rows, w_shape, dtype):
 
 def dropless_moe(x: jax.Array, params: Dict[str, jax.Array],
                  routing: Routing, live: Optional[jax.Array] = None,
-                 share: Optional[Tuple[int, int]] = None
+                 share: Optional[Tuple[int, int]] = None, n_zero: int = 0
                  ) -> Tuple[jax.Array, jax.Array]:
-    """x [T, D] -> (y [T, D], tokens routed to each expert [E] int32).
+    """x [T, D] -> (y [T, D], tokens routed to each HELD expert [E]
+    int32; with `n_zero`, one more entry: the zero picks).
 
-    params: `router` [D, E], what `routing` reads beside it, and the
-    experts' SwiGLU weights `w_gate` / `w_up` [E, D, F], `w_down`
+    params: `router` [D, R], what `routing` reads beside it, and the
+    held experts' SwiGLU weights `w_gate` / `w_up` [E, D, F], `w_down`
     [E, F, D].  Router logits, scores and selection are float32 (a
     float32 matrix product, not the chip's one-pass default); the expert
     products run in x's dtype.  No capacity: the T * k assignments are
@@ -236,14 +260,25 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array],
     dead decode slot): they are in no group, add nothing to the counts
     and get y = 0, so an expert only they picked is not read.
 
-    `share` (rank r, of n) says WHICH experts this layer holds: the
-    contiguous range [r E/n, (r+1) E/n) of the E the router is wide,
-    `w_gate` / `w_up` / `w_down` being those E/n.  Routing, the k chosen
-    and their weights are over all E as published; an assignment to an
-    expert held elsewhere is in no group here and reads nothing, so y is
-    the part of the layer's result that the held experts give (the
-    shares' parts add up to the whole layer's) and the counts [E/n] are
-    the held experts' own.
+    The router's R columns are the routed experts, then `n_zero`
+    ZERO-COMPUTE experts (identities): an assignment to one of those is
+    in no group and reads no weight; it adds its weight times the token
+    itself, `sum_k w_k x` over a token's zero picks, computed here for
+    every live token whatever `share` says (a token's zero picks are
+    where the token is, and no share's).  So the rows the products fill
+    vary from token to token: k less its zero picks.  The live tokens'
+    zero picks are counted in the counts' last entry, [E + 1].
+
+    `share` (rank r, of n) says WHICH routed experts this layer holds:
+    the contiguous range [r E, (r+1) E) of the R - n_zero routed
+    columns, `w_gate` / `w_up` / `w_down` being those E = (R - n_zero)
+    / n.  Routing, the k chosen and their weights are over all R columns
+    as published; an assignment to an expert held elsewhere is in no
+    group here and reads nothing, so y is the part of the layer's result
+    that the held experts give plus the zero picks' (the shares' parts,
+    with the zero picks' counted once, add up to the whole layer's) and
+    the counts are the held experts' own.  A router that is not
+    `n * E + n_zero` wide is refused.
 
     The three grouped products run by ONE of two paths, chosen by
     backend, dtype and shape alone (`grouped_path`): on a TPU, in bf16,
@@ -255,15 +290,26 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array],
     the same arithmetic either way: bf16 operands, float32
     accumulation, one rounding."""
     T, D = x.shape
-    E = params["router"].shape[-1]
+    R = params["router"].shape[-1]
+    E = params["w_gate"].shape[0]
+    shards = 1 if share is None else share[1]
+    if R != shards * E + n_zero:
+        raise ValueError(
+            f"dropless_moe: a router {R} wide does not route over "
+            f"{shards} x {E} held experts + {n_zero} zero-compute ones "
+            f"(= {shards * E + n_zero})")
     with jax.named_scope("router"):
         logits = jnp.dot(x.astype(jnp.float32),
                          params["router"].astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
         idx, w = routing(logits, params)                    # [T, k]
-        if share is not None:
-            E //= share[1]
-            idx = idx - share[0] * E
+        if n_zero:
+            zero = idx >= R - n_zero
+            if live is not None:
+                zero = zero & live[:, None]
+            w_zero = jnp.where(zero, w, 0.0).sum(-1)        # [T]
+        if share is not None or n_zero:
+            idx = idx - (0 if share is None else share[0] * E)
             held = (idx >= 0) & (idx < E)
             idx = jnp.where(held, idx, E)                   # sorts last
             w = jnp.where(held, w, 0.0)
@@ -288,4 +334,8 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array],
             jnp.arange(T * k, dtype=jnp.int32))
         y = jnp.einsum("tkd,tk->td",
                        ys[back].reshape(T, k, D).astype(jnp.float32), w)
+    if n_zero:
+        with jax.named_scope("zero"):
+            y = y + w_zero[:, None] * x.astype(jnp.float32)
+            sizes = jnp.append(sizes, jnp.sum(zero, dtype=jnp.int32))
     return y.astype(dt), sizes

@@ -332,6 +332,15 @@ def trace_span(name: str, **args):
     return jax.profiler.TraceAnnotation(name, **args)
 
 
+def trace_live() -> bool:
+    """Whether a profiler session is recording now (the flag
+    :func:`trace_span` tests): what is worth doing only for the trace,
+    such as a device read whose value goes into a span, asks first."""
+    import jax
+
+    return jax.profiler.TraceAnnotation.is_enabled()
+
+
 def capture_tpu_trace(duration_s: float,
                       trace_dir: Optional[str] = None) -> Dict[str, Any]:
     """Run ``jax.profiler.start_trace``/``stop_trace`` for ``duration_s``

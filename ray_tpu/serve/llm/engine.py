@@ -73,7 +73,7 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-from ray_tpu.observability.profiling import trace_span
+from ray_tpu.observability.profiling import trace_live, trace_span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -2196,7 +2196,19 @@ class LLMEngine:
                 toks_host, n_emit = self._spec_wait(*tick.outs)
             else:
                 toks_host, = self._read_back(*tick.outs)    # [K, B]
-        with self._loop.phase("llm_engine.emit"):
+        with self._loop.phase("llm_engine.emit") as sp:
+            if tick.counters and trace_live():
+                # Under a profiler session alone (a device read a tick):
+                # the model's scalar counters as THIS tick left them,
+                # summed since the engine started, so that a reader has
+                # them at both ends of any interval of the trace and
+                # lays counts and device time of the same ticks side by
+                # side.
+                import jax
+
+                scalars = jax.device_get({k: v for k, v in
+                                          tick.counters.items() if not v.ndim})
+                sp.set_metadata(**{k: int(v) for k, v in scalars.items()})
             # The tick's wall on the one clock: it could not start
             # before its dispatch nor before the tick ahead of it was
             # done, and `tick_ready` ended when the host knew it done

@@ -980,6 +980,47 @@ def test_gdn_hybrid_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     assert m.temp_size_in_bytes < 0.5 * GIB
 
 
+@pytest.mark.parametrize("program", ["tick", "insert"])
+def test_shortcut_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
+    """The engine's decode tick and its largest insert at the geometry of
+    the benchmark's `longform-decode-zero-moe` cell (two latent
+    sublayers and two dense feed-forwards a layer, 16 of 512 routed
+    experts held beside 256 zero-compute ones, at LongCat-Flash-Chat's
+    published widths; the depth, slots, row length, buckets and pool its
+    files state): they compile for v5e, both kernel paths answer
+    "kernel" (the paged latent kernel at 64 query heads on a 640-wide
+    row, one call a SUBLAYER; the grouped products at 6144 x 2048 over
+    1536 rows of which the router's real, held picks are filled), the
+    pool of 8 latent layers is updated in place, and arguments +
+    temporaries fit HBM beside the 10.35 GB of weights.  These readings
+    chose the top bucket, 1024 (the insert's temporaries at 512 / 1024 /
+    2048: 0.92 / 1.25 / 1.84 GiB over 11.51 of arguments; the tick's
+    0.02)."""
+    eng = _serving_cell("longform-decode-zero-moe", one_chip)
+    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+                                eng.published)
+    assert (published["num_layers"], published["hidden_size"],
+            published["n_routed_experts"], published["zero_expert_num"],
+            published["vocab_size"], mc.n_experts, mc.n_held_experts,
+            mc.router_width) == (4, 6144, 16, 256, 16384, 512, 16, 768)
+    assert eng.pools["latent"].shape == (8, 12288, 16, 640)
+    assert model.paged_attention(eng.pools) == "kernel"
+    assert model.grouped_matmul(mc, ec.num_slots) == "kernel"
+    compiled = (_compiled_cell_tick if program == "tick"
+                else _compiled_insert)(eng, one_chip)
+    text = compiled.as_text()
+    _grouped_products_are_the_kernel(text, mc.n_layers)
+    assert (text.count("paged_attention") >= 2 * mc.n_layers) \
+        == (program == "tick")
+    m = compiled.memory_analysis()
+    pool_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                     for x in eng.pools.values())
+    print(program, "GiB", _hbm_gib(compiled), "temp",
+          m.temp_size_in_bytes / GIB, "args", m.argument_size_in_bytes / GIB)
+    assert m.alias_size_in_bytes >= pool_bytes          # in place
+    assert _hbm_gib(compiled) < V5E_HBM_GIB
+
+
 def test_train_step_holds_flash_kernel_and_fits_one_v5e(topo, on_tpu):
     """One whole `build_train_step` program at chip_smoke.py's train
     widths, depth and batch, on a one-device mesh."""
