@@ -29,8 +29,9 @@ so: `models/shortcut_moe.py` runs this attention twice a layer):
   table, or gathered) at a static layer index, in place.
 - **One definition of a layer** (`_layer`) over three caches: none
   (`forward`: scoring and tests), history + write-back (`prefill_paged`:
-  bucketed and chunked prefill with a prefix history), paged decode
-  (`decode_step_paged`).
+  bucketed and chunked prefill with a prefix history, the expanded
+  form tile by tile over the keys the request has and not the slot's
+  padded rows: `_History.attend`), paged decode (`decode_step_paged`).
 
 Every size comes from `LatentMoEConfig`; there is no knob beside it.
 """
@@ -49,7 +50,7 @@ from ray_tpu.models.llama import embed_lookup, rms_norm
 from ray_tpu.models.moe import (
     dropless_moe, serving_grouped_path, sigmoid_bias_top_k,
 )
-from ray_tpu.models.serving import ServingFns
+from ray_tpu.models.serving import HISTORY_TILE, ServingFns
 from ray_tpu.ops import paged_attention as paged
 
 
@@ -262,7 +263,6 @@ class _History:
     """One sequence with a gathered history `hist` [L, S_pad, cache_row]:
     the new rows land at `start` of the layer's history, and are kept
     for the engine to scatter into the pool."""
-    attend = staticmethod(attend_expanded)
 
     def __init__(self, hist, start):
         self.hist, self.start = hist, start
@@ -273,6 +273,67 @@ class _History:
         return lax.dynamic_update_slice(
             self.hist[l], self.rows[-1], (self.start, 0))[None].astype(
                 new.dtype)
+
+    def attend(self, c, wkv_b, q_nope, q_rope, rows, qpos):
+        """`attend_expanded` over the keys this call can see and no
+        more: the updated history `rows` [B, S_pad, cache_row] is walked
+        in tiles of `HISTORY_TILE` rows up to `start + Q`, one past the
+        last query's own key, and everything behind it (stale rows of
+        the slot's padded table, which the position mask gives weight
+        zero) is never read.  The trip count is data, so a bucket is
+        still one program.
+
+        A tile is the expanded form on its rows (keys and values
+        rebuilt from the latent, both score products in the compute
+        dtype, float32 scores) under an online softmax: the running
+        maximum and sum [B, H, Q] and the values' accumulator
+        [B, Q, H, v] are float32, and a tile's probabilities go into the
+        value product in the compute dtype as the plain form's do.
+        Tile 0 holds key 0, which every query sees, so from the first
+        tile on the running maximum is a real score: a later tile that
+        a query sees nothing of adds exp(-1e30 - m) = 0 to it, and no
+        query divides by zero.
+
+        The last tile of a history that is no multiple of the tile (or
+        shorter than one) is slid back to end at S_pad, as
+        `dynamic_slice` would clamp it: its keys' positions are those
+        of the rows actually read, and the rows the tile before it
+        scored already are masked."""
+        B, S_pad, _ = rows.shape
+        Q, H, dt = q_nope.shape[1], c.n_heads, q_nope.dtype
+        n, v, rank = c.qk_nope_head_dim, c.v_head_dim, c.kv_lora_rank
+        r = c.qk_rope_head_dim
+        T = min(HISTORY_TILE, S_pad)
+        scale = 1.0 / math.sqrt(c.qk_head_dim)
+
+        def tile(i, carry):
+            m, s, acc = carry
+            first = jnp.minimum(i * T, S_pad - T)
+            t = lax.dynamic_slice_in_dim(rows, first, T, axis=1)
+            kv = (t[..., :rank] @ wkv_b).reshape(B, T, H, n + v)
+            scores = (jnp.einsum("bqhn,bkhn->bhqk", q_nope, kv[..., :n])
+                      + jnp.einsum("bqhr,bkr->bhqk", q_rope,
+                                   t[..., rank:rank + r])
+                      ).astype(jnp.float32) * scale
+            kpos = first + jnp.arange(T)
+            seen = (kpos[None, None, None, :] <= qpos[:, None, :, None]) \
+                & (kpos >= i * T)
+            scores = jnp.where(seen, scores, -1e30)
+            m_new = jnp.maximum(m, scores.max(-1))
+            p = jnp.exp(scores - m_new[..., None])
+            keep = jnp.exp(m - m_new)
+            out = jnp.einsum("bhqk,bkhv->bqhv", p.astype(dt), kv[..., n:],
+                             preferred_element_type=jnp.float32)
+            return (m_new, s * keep + p.sum(-1),
+                    acc * keep.swapaxes(1, 2)[..., None] + out)
+
+        m, s, acc = lax.fori_loop(
+            0, (self.start + Q + T - 1) // T, tile,
+            (jnp.full((B, H, Q), -1e30, jnp.float32),
+             jnp.zeros((B, H, Q), jnp.float32),
+             jnp.zeros((B, Q, H, v), jnp.float32)))
+        out = acc / s.swapaxes(1, 2)[..., None]
+        return out.astype(dt).reshape(B, Q, H * v)
 
 
 class _PagedDecode:

@@ -80,6 +80,19 @@ from __future__ import annotations
 
 from typing import Any, Callable, NamedTuple, Optional
 
+# Rows of `hist` a prefill reads at a time where it walks its history
+# (`models/latent_moe.py::_History.attend`): the insert's attention
+# costs `start + Pb` rounded up to this, not S_pad.  A multiple of the
+# chip's 128 lanes.  On the chip, one sublayer alone at LongCat's
+# widths: 256 reads as 512 does at the 128-512 buckets and 0.71-0.76 of
+# it at the 1024 and 2048 buckets (at 512 and 1024 a tile's float32
+# scores no longer stay on the chip between the products); 128 reads
+# as 256 and 1024 worse than 512 at every shape (PERF.md section 6,
+# PR 45).  The
+# engine counts what such a walk reads for every model
+# (`stats()["insert_keys_walked"]`).
+HISTORY_TILE = 256
+
 
 class DraftFns(NamedTuple):
     """A speculative draft's own cache, one [S] stripe a slot and not

@@ -578,6 +578,11 @@ class LLMEngine:
         self._live_rows_sum = 0         # over all ticks: `_live_rows`
         self._padded_rows_sum = 0
         self._live_kv_bytes_sum = 0     # a model with a window kind
+        # over all inserts: the keys a walk of the history up to the
+        # piece's last row reads (models/serving.py::HISTORY_TILE),
+        # and the rows of the padded history the insert is handed
+        self._insert_keys_walked = 0
+        self._insert_keys_padded = 0
         self._slot_reuses = 0
         self._cancelled: set = set()    # request ids, guarded by _lock
         self._admit_blocked = False     # interactive admission starved
@@ -1276,6 +1281,8 @@ class LLMEngine:
         sharing the prefix hits them while the rest still goes in."""
         import numpy as np
 
+        from ray_tpu.models.serving import HISTORY_TILE
+
         req = handle.request
         bs = self.config.kv_block_size
         if handle._prompt_rows is None and \
@@ -1291,6 +1298,10 @@ class LLMEngine:
         self._insert(slot, row, start, padded, n,
                      row[start // bs:(start + bucket) // bs],
                      req.temperature)
+        S = self.config.max_seq_len
+        self._insert_keys_walked += min(
+            -(-(start + bucket) // HISTORY_TILE) * HISTORY_TILE, S)
+        self._insert_keys_padded += S
         handle.prefilled_tokens += n
         handle._prompt_rows = end
         if self._prefix is not None and end >= bs:
@@ -2539,6 +2550,12 @@ class LLMEngine:
             # [num_slots, max_seq_len] view holds
             "live_rows": self._live_rows_sum,
             "padded_rows": self._padded_rows_sum,
+            # the same of the inserts: history + bucket rounded up to
+            # the walk's tile (what `latent_moe._History` reads; what a
+            # walk WOULD read, for a family that scores the whole padded
+            # history) against the padded history's rows
+            "insert_keys_walked": self._insert_keys_walked,
+            "insert_keys_padded": self._insert_keys_padded,
             # the scheduler thread's seconds and calls by phase
             "loop": self._loop.stats(),
             "traces": traces,

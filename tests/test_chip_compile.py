@@ -101,6 +101,8 @@ def _without_metadata(text: str) -> str:
 # pieces are ADMITTED and no device program. A PR that means to change
 # one of these programs pins its own text here and says so; one that
 # does not (a scheduler change, a clean-up) has this to show it.
+# PR 45 meant to change kanana's insert (`_History.attend` walks the
+# history in tiles) and re-pinned it; the three others are PR 42's.
 PROGRAM_TEXT_SHA256 = {
     ("chat-decode", "tick"):
         "48a91f54a548addd9d951f33258125cd66601f6eb5de512b9f23800388b2ae93",
@@ -109,7 +111,7 @@ PROGRAM_TEXT_SHA256 = {
     ("assistant-decode-moe", "tick"):
         "8f11c202dee0816c9bda3bb0a54e3745760458d31f1d8595b01ede0a6ca1dedb",
     ("assistant-decode-moe", "insert"):
-        "65ba858b4c7162f9310e1639960524afc4cf39e6972c8d6ab79d965c4716e7c6",
+        "7ea6d60d46bd647067feef60f4dff765f30aa43261cc52d3564b29c2b43221de",
 }
 
 
@@ -794,6 +796,37 @@ def test_delta_rule_inserts_hold_no_chunk_by_chunk_by_channel_tensor(
     if dk == 128:
         assert any(s[-3:] == (2 * b, b, dk) for s in shapes)
     assert not sorted(s for s in shapes if s[-3:] == (C, C, dk))
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_gib * GIB
+
+
+@pytest.mark.parametrize("cell, temp_gib", [
+    ("assistant-decode-moe", 0.7), ("agent-decode-hybrid", 0.9),
+    ("longform-decode-zero-moe", 1.1)])
+def test_latent_inserts_hold_no_padded_score_tensor(
+        one_chip, on_tpu, cell, temp_gib):
+    """The largest insert of the three latent-attention cells (kanana
+    and Kimi at their 2048 bucket over 4096 and 8192 padded rows,
+    LongCat at 1024 over 5120): `latent_moe._History.attend` walks the
+    history in tiles under a `while` a latent layer, so no result, fused
+    computations' own included, has the heads beside `(Pb, S_pad)` (the
+    plain form's `[1, H, Pb, S_pad]` float32 scores, 1.07 / 2.15 / 1.34
+    GB a layer; `(Pb, S_pad)` alone is also kanana's `[2048, 4096]`
+    attention output), and a tile's `[1, H, Pb, HISTORY_TILE]` are
+    there.  Temporaries, deviceless, parent → PR 45: 0.917 → 0.430 GiB,
+    1.297 → 0.594, 1.247 → 0.952 (LongCat's rest is the grouped
+    products' 12,288 rows and the dense feed-forwards); the bounds lie
+    between."""
+    from ray_tpu.models.serving import HISTORY_TILE
+
+    eng = _serving_cell(cell, one_chip)
+    ec, H = eng.config, eng.model_config.n_heads
+    Pb, S_pad = ec.prefill_buckets[-1], ec.max_seq_len
+    compiled = _compiled_insert(eng, one_chip)
+    text = compiled.as_text()
+    shapes = set().union(*(shapes for _, shapes in _results(text)))
+    assert any(s[-3:] == (H, Pb, HISTORY_TILE) for s in shapes)   # parsed
+    assert not sorted(s for s in shapes if s[-3:] == (H, Pb, S_pad))
+    assert text.count(" while(") >= eng.pools["latent"].shape[0]
     assert compiled.memory_analysis().temp_size_in_bytes < temp_gib * GIB
 
 

@@ -38,6 +38,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import latent_walk
+
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks")
 if BENCH not in sys.path:
@@ -175,6 +177,26 @@ def test_paged_prefill_and_decode_match_reference(model, case):
 
 
 # --------------------- (c) what the chunks of one prompt hand each other
+
+@pytest.mark.parametrize("case", sorted(latent_walk.CASES))
+def test_insert_walks_the_history_it_has(model, case, monkeypatch):
+    """A whole insert (the latent layers with no rotary beside the
+    recurrent ones, which read no history) by `_History`'s walk of the
+    history up to `start + Pb` and by `attend_expanded` over all of the
+    padded history: the normed hidden states of every query, the padded
+    ones included, agree to 1e-5 and are finite.  In float32: between
+    two bf16 forms a routing flip moves a hidden state by half its
+    size; the walk's bf16 rounding is held to the plain form's where no
+    router follows it, `tests/test_latent_moe.py::
+    test_history_walk_equals_the_plain_form`."""
+    from ray_tpu.models.kimi_linear import init_slot_state, prefill_paged
+
+    _, mc, _, params = model
+    state = {k: v[:, 0] for k, v in init_slot_state(mc, 1).items()}
+    assert latent_walk.insert_walk_error(
+        monkeypatch, prefill_paged, mc, params, mc.n_mla_layers, case,
+        state) < 1e-5
+
 
 @pytest.mark.parametrize("case", ["chunked_equals_whole",
                                   "padded_equals_unpadded"])

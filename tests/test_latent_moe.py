@@ -28,6 +28,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import latent_walk
+
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks")
 if BENCH not in sys.path:
@@ -162,6 +164,65 @@ def test_absorbed_attention_equals_expanded(model):
     b = attend_expanded(mc, wkv_b, q_nope, q_rope, rows, qpos)
     assert float(jnp.abs(b).max()) > 0.5
     assert float(jnp.abs(a - b).max()) < TOL
+
+
+# ----------------------------- (c2) the insert walks the history it has
+
+def _walk_and_plain(mc, S_pad, start, Q, dtype, n_real):
+    """`_History.attend` and `attend_expanded` over the same updated
+    history, whose rows past `start + Q` are loud and stale; inputs are
+    bf16 numbers in either dtype, so float32 is bf16's truth."""
+    from ray_tpu.models import latent_moe as LM
+
+    def drawn(key, *shape, scale=1.0):
+        x = jax.random.normal(key, shape) * scale
+        return x.astype(jnp.bfloat16).astype(dtype)
+
+    ks = jax.random.split(jax.random.key(S_pad + start), 5)
+    H, n, v = mc.n_heads, mc.qk_nope_head_dim, mc.v_head_dim
+    q_nope = drawn(ks[0], 1, Q, H, n)
+    q_rope = drawn(ks[1], 1, Q, H, mc.qk_rope_head_dim)
+    hist = drawn(ks[2], 1, S_pad, mc.cache_row, scale=3.0)
+    new = drawn(ks[3], 1, Q, mc.cache_row)
+    new = new.at[:, n_real:].set(0)
+    wkv_b = drawn(ks[4], mc.kv_lora_rank, H * (n + v), scale=0.1)
+
+    @jax.jit
+    def both(start):        # traced, as the insert program has it
+        qpos = (start + jnp.arange(Q))[None]
+        cache = LM._History(hist, start)
+        rows = cache.update(0, new)
+        return (cache.attend(mc, wkv_b, q_nope, q_rope, rows, qpos),
+                LM.attend_expanded(mc, wkv_b, q_nope, q_rope, rows, qpos))
+
+    return [np.asarray(x, np.float32) for x in both(jnp.int32(start))]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(latent_walk.CASES))
+def test_history_walk_equals_the_plain_form(model, case, dtype):
+    """The insert's attention over tiles of the history up to `start +
+    Q` against `attend_expanded` over all of it.  float32: 1e-5 of the
+    output's size (the order of float32 sums differs, as between any
+    two blockings).  bf16: both forms round their probabilities to bf16
+    at different scales (the walk's relative to the running maximum,
+    the plain form's normalised), so they are held to the float32 plain
+    form: the walk is no further from it than the plain bf16 form is,
+    with a quarter of room.  Every output is finite, the padded
+    queries' too."""
+    _, mc, _, _ = model
+    S_pad, start, Q, n_real = latent_walk.geometry(case)
+    walk, truth = _walk_and_plain(mc, S_pad, start, Q, jnp.float32, n_real)
+    size = np.abs(truth).max()
+    assert size > 0.1
+    if dtype == "float32":
+        assert np.abs(walk - truth).max() < 1e-5 * size
+    else:
+        walk, plain = _walk_and_plain(mc, S_pad, start, Q, jnp.bfloat16,
+                                      n_real)
+        assert np.abs(walk - truth).max() \
+            < 1.25 * np.abs(plain - truth).max() + 1e-3 * size
+    assert np.isfinite(walk).all()
 
 
 # --------------------------------------------------- (d) nothing is dropped
@@ -331,6 +392,42 @@ def test_engine_serves_through_eviction_spill_and_promotion(model, engine):
     assert int(ctr["expert_tokens"].sum()) == decoded * 2 * 2
     assert 0 < int(ctr["experts_touched"]) <= int(ctr["ticks"]) * 2 * 4
     assert int(ctr["ticks"]) >= 5 * 7
+
+
+def test_engine_counts_the_keys_its_inserts_walk(model):
+    """One short prompt and one prompt in three pieces through an
+    engine whose rows are four tiles long: `stats()` sums, over the
+    inserts, history + bucket rounded up to the tile beside the padded
+    history's rows.  The third piece's keys cross a tile edge in the
+    engine's own program, and every served token's reference logit
+    still lies at the reference's maximum."""
+    from ray_tpu.models.serving import HISTORY_TILE as T
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine, Request
+
+    R, mc, weights, params = model
+    S, top = 4 * T, T // 2
+    engine = LLMEngine(params, mc, EngineConfig(
+        num_slots=2, max_seq_len=S, prefill_buckets=(BUCKET, top // 2, top),
+        kv_layout="paged", kv_block_size=BS, num_kv_blocks=2 * S // BS,
+        prefix_cache=False))
+    assert engine.stats()["insert_keys_walked"] == 0
+    prompts = [_tokens(13, seed=50), _tokens(2 * top + 44, seed=51)]
+    hs = [engine.submit(Request(prompt=p, max_tokens=4,
+                                chunked_prefill=len(p) > top))
+          for p in prompts]
+    engine.drain()
+    st = engine.stats()
+    # 13 in the 16 bucket; [0, top), [top, 2 top), then 44 in top / 2
+    ends = [BUCKET, top, 2 * top, 2 * top + top // 2]
+    assert st["insert_keys_walked"] \
+        == sum(-(-e // T) * T for e in ends) == 5 * T
+    assert st["insert_keys_padded"] == len(ends) * S \
+        >= st["insert_keys_walked"]
+    assert st["traces"]["insert"] == 3         # a program a bucket
+    deficits = np.concatenate([
+        R.served_token_deficits(weights, C, p, list(h.tokens))
+        for p, h in zip(prompts, hs)])
+    assert deficits.size == 8 and deficits.mean() < 1e-4, deficits.max()
 
 
 def test_engine_exports_and_adopts_latent_blocks(model, engine):

@@ -36,6 +36,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import latent_walk
+
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks")
 if BENCH not in sys.path:
@@ -208,6 +210,24 @@ def test_paged_prefill_and_decode_match_reference(model, case):
             counts["expert_tokens"].sum()) <= int(counts["real_picks"])
         assert int(counts["experts_touched"]) == int(
             (np.asarray(counts["expert_tokens"]) > 0).sum())
+
+
+@pytest.mark.parametrize("case", sorted(latent_walk.CASES))
+def test_insert_walks_the_history_it_has(model, case, monkeypatch):
+    """A whole insert (both sublayers of both layers, the query through
+    its low rank, both low-rank paths scaled) by `_History`'s walk of
+    the history up to `start + Pb` and by `attend_expanded` over all of
+    the padded history: the normed hidden states of every query, the
+    padded ones included, agree to 1e-5 and are finite.  In float32:
+    between two bf16 forms a routing flip moves a hidden state by half
+    its size; the walk's bf16 rounding is held to the plain form's
+    where no router follows it, `tests/test_latent_moe.py::
+    test_history_walk_equals_the_plain_form`."""
+    from ray_tpu.models.shortcut_moe import prefill_paged
+
+    _, mc, _, params = model
+    assert latent_walk.insert_walk_error(
+        monkeypatch, prefill_paged, mc, params, 2 * mc.n_layers, case) < TOL
 
 
 # ----------------------------------- (c) the expert layer, piece by piece
