@@ -1,0 +1,387 @@
+"""What the deviceless v5e compile tests (`test_chip_compile*.py`) share.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (on-chip-measurement guide §2, step 3). The tests
+hand the jitted functions `ShapeDtypeStruct`s placed on described v5e
+devices, so the compiler refuses here — at no chip time — what it would
+refuse on the machine: an API the installed JAX dropped, a kernel that
+cannot be lowered or partitioned, more VMEM than a kernel may use, a
+program that does not fit HBM. Nothing runs: a compile that passes says
+nothing about results or speed.
+
+Rules these files keep (the guide explains each): the topology is
+described only inside the module-scoped, non-autouse fixture below —
+never at import, in a `skipif` or in `parametrize` — so a process that
+collects these files and runs none of their cases loads no TPU library;
+several processes may each describe it (one xdist worker a file does);
+every compile happens in the test's own process; the persistent
+compilation cache is off around them (a deviceless executable can be
+written to it but not read back); code that asks `jax.default_backend()`
+is answered for the chip around the compile itself (`program`), not
+through an option of the program.
+
+A whole program compiles ONCE a process: `program` remembers, by a key
+that names everything deciding the program, what the cases read of it
+(its text, its memory analysis) and lets the executable go. The files
+are cut so that every case reading a cell's tick or insert lives in one
+file, and the session prints how often each key compiled over all
+workers (`conftest.py`): a 2 there is a minute of the run spent twice.
+"""
+
+import collections
+import functools
+import hashlib
+import importlib
+import json
+import os
+import re
+import sys
+import types
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+GIB = 2 ** 30
+V5E_HBM_GIB = 15.75     # what the v5e compiler itself reports as capacity
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmarks")
+
+
+@functools.cache
+def _described():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    return topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = _described()
+    except Exception as e:  # no TPU compiler here: nothing to test with
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """`ops.attention` picks kernel-vs-XLA and compiled-vs-interpret from
+    the default backend, which is the CPU here: answer for the chip, for
+    a case that compiles a kernel itself or asks a selector
+    (`model.paged_attention(pools)`, `kda.engages`).  `program` answers
+    so around its own compiles and needs no fixture."""
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+
+
+def placed(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def without_metadata(text: str) -> str:
+    """A compiled program's text less what only names things: the
+    per-instruction metadata, the source tables and the kernels'
+    serialized modules (their debug locations)."""
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r"\n(FileNames|FunctionNames|FileLocations|"
+                  r"StackFrames)\n(.+\n)*", "\n", text)
+    return re.sub(r'"body":"[^"]+"', '"body":""', text)
+
+
+def renumbered(text: str) -> str:
+    """`text` with its instructions named by first appearance."""
+    names = {}
+    return re.sub(
+        r"%[A-Za-z_][\w.\-]*",
+        lambda m: names.setdefault(m.group(0), "%%i%d" % len(names)), text)
+
+
+def hbm_gib(memory) -> float:
+    return (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            - memory.alias_size_in_bytes + memory.temp_size_in_bytes) / GIB
+
+
+def results_of(text):
+    """(opcode, shapes of its result) of every instruction in a compiled
+    program's text, fused computations' own instructions included."""
+    out = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([a-z][a-z\-]*)\(",
+                     line)
+        if m:
+            out.append((m.group(2), {
+                tuple(int(d) for d in dims.split(",") if d)
+                for dims in re.findall(r"\b[a-z]+\d+\[([\d,]*)\]",
+                                       m.group(1))}))
+    return out
+
+
+# ---------------------------------------------------- one compile a program
+
+COMPILES = collections.Counter()    # key -> compiles in this process
+_PROGRAMS = {}
+
+
+def program(key, build):
+    """What the cases read of the v5e program `key`: `.text`, `.plain`
+    (the text less its metadata), `.memory` (its `memory_analysis()`)
+    and `.hbm_gib`.  `build()` lowers and compiles it the first time a
+    case asks, with `ops.attention._on_tpu` answered for the chip around
+    that compile and nowhere else: every program here is the one the
+    chip runs (no case takes a selector away any more: the pins hold the
+    texts that was done for), and `key` names what decides it."""
+    from ray_tpu.ops import attention
+
+    if key not in _PROGRAMS:
+        asked, attention._on_tpu = attention._on_tpu, lambda: True
+        try:
+            compiled = build()
+        finally:
+            attention._on_tpu = asked
+        COMPILES[key] += 1
+        text, memory = compiled.as_text(), compiled.memory_analysis()
+        _PROGRAMS[key] = types.SimpleNamespace(
+            text=text, plain=without_metadata(text), memory=memory,
+            hbm_gib=hbm_gib(memory))
+    return _PROGRAMS[key]
+
+
+# sha256 of a program's text less its metadata (`without_metadata`): every
+# serving cell's tick and largest insert as the chip runs them, and the
+# two train steps whose texts accepted PRs were shown to leave alone.
+# A PR that means to change one of these programs pins its own text here
+# and says so; one that does not (a scheduler change, a clean-up, a kernel
+# for another model) has this to show it.  `chat-decode`'s two and
+# `assistant-decode-moe`'s tick are PR 42's tree's (efba6a6), kanana's
+# insert PR 45's (`_History.attend` walks the history in tiles); the rest
+# were pinned by PR 46 at PR 45's tree, where each equal-text guard that a
+# pin replaced was run one last time and held (CHANGES.md, PR 46).
+PROGRAM_TEXT_SHA256 = {
+    ("chat-decode", "tick"):
+        "48a91f54a548addd9d951f33258125cd66601f6eb5de512b9f23800388b2ae93",
+    ("chat-decode", "insert"):
+        "33e1fd0f5f8e39ac4168e9c0371c61ce1c57240d9308ea125ea1b63b1d527fc6",
+    ("assistant-decode-moe", "tick"):
+        "8f11c202dee0816c9bda3bb0a54e3745760458d31f1d8595b01ede0a6ca1dedb",
+    ("assistant-decode-moe", "insert"):
+        "7ea6d60d46bd647067feef60f4dff765f30aa43261cc52d3564b29c2b43221de",
+    ("agent-decode-hybrid", "tick"):
+        "2211d4c0ca524e4343cc7997c80ca3f11d2216fe85bb322cc9d86730d0d1dee7",
+    ("agent-decode-hybrid", "insert"):
+        "16968ac98f4fa7a9e2410480aaf3a25b69ce8608a92787dbe40a36aabb73d9ca",
+    ("compose-decode-conv-moe", "tick"):
+        "eb5930149d004d30510feb1230c29d9b3055bcea6b41be400a885904f1a6af07",
+    ("compose-decode-conv-moe", "insert"):
+        "a8c25196b640bfcb5d9253af8ac9decf3223ed4d38b7152b7d16005de95957f0",
+    ("mixed-decode-window-moe", "tick"):
+        "ab3d09efdab6c14edbb8e0cc678b75f5d1d1df6032fe7931641e9b39880fe1dd",
+    ("mixed-decode-window-moe", "insert"):
+        "5a10c0de6c4e48c235608d2a198cbf8c7f32efdc6d7861d4be3fb80b7fc7bc79",
+    ("reason-decode-gdn-hybrid", "tick"):
+        "7db8f02c0133bc1b6757a1eda4a18cfbf6f2ae756478d598ac520e07ef1199b1",
+    ("reason-decode-gdn-hybrid", "insert"):
+        "e019023848c69523dced14ed9ddba01bd1006fe4338d20b640381aa55409729d",
+    ("longform-decode-zero-moe", "tick"):
+        "cde3ce8aace9a4c1aced034762e8612cbc39f0080091b150a14a97330fa938b7",
+    ("longform-decode-zero-moe", "insert"):
+        "a11a11354453fee562dd3d3b4e8f8f721faf28d29ff147724b0828cd39aaf7b5",
+    ("two small layers", "train step, scope names apart"):
+        "1d700902d1c682aaec9e4b41afc84286eb99ba0c4758f7046d1be38b1848714c",
+    ("two small layers", "train step, its kernels"):
+        "cf8043a1be3c7d2314b71cb866520de6f8dbb0ddd6678f78b9a87daf49c49e31",
+    ("pretrain-1chip", "train step, renumbered"):
+        "51b06f2e0f3285376dbc998817fecf690d572e01726357a4a93a5765710e2b0d",
+}
+
+
+def text_sha256(text):
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def is_the_pinned_text(name, which, plain):
+    """`plain` (a program's text less its metadata) is the text pinned
+    under `(name, which)`."""
+    assert text_sha256(plain) == PROGRAM_TEXT_SHA256[name, which], (
+        name, which)
+
+
+# ------------------------------------------------ the benchmark's serving cells
+
+@functools.cache
+def serving_cell(cell):
+    """The engine of one of the benchmark's serving cells as shapes on
+    the described chip: what `LLMEngine`'s program functions read of
+    `self` (`_model`, `model_config`, `config`), and beside it the
+    configuration's file (`published`) and the model's parameters,
+    pool and key."""
+    from ray_tpu.serve.llm.engine import EngineConfig
+
+    one_chip = SingleDeviceSharding(_described().devices[0])
+    if BENCH not in sys.path:
+        sys.path.insert(0, BENCH)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        declared = json.load(f)
+    name = next(w["config"] for w in declared["workloads"]
+                if w["name"] == cell)
+    with open(os.path.join(ROOT, next(
+            c["file"] for c in declared["configs"]
+            if c["name"] == name))) as f:
+        published = json.load(f)
+    with open(os.path.join(BENCH, "workloads", cell + ".json")) as f:
+        ec = EngineConfig(**json.load(f)["engine"])
+    family = importlib.import_module("families." + published["family"])
+    mc = family.model_config(published, max_seq_len=ec.max_seq_len,
+                             compute_dtype="bfloat16",
+                             param_dtype="bfloat16")
+    model = mc.serving()
+    # a model with a window kind of pool leaves (models/serving.py): the
+    # ring's width and that pool's blocks, as `LLMEngine.__init__` has them
+    ring, leaves, extra = None, (), {}
+    if model.window_kind:
+        window, leaves = model.window_kind(mc)
+        ring = types.SimpleNamespace(ring=min(
+            -(-(window + ec.prefill_buckets[-1]) // ec.kv_block_size),
+            ec.max_blocks_per_slot))
+        extra = {"window_blocks": ec.num_window_blocks}
+    return types.SimpleNamespace(
+        name=cell, _model=model, model_config=mc, config=ec,
+        published=published, _ring=ring, _window_leaves=leaves,
+        one_chip=one_chip,
+        params=placed(jax.eval_shape(
+            lambda: model.init_params(mc, jax.random.key(0))), one_chip),
+        pools=placed(jax.eval_shape(lambda: model.init_pool(
+            mc, ec.pool_blocks, ec.kv_block_size, **extra)), one_chip),
+        key=placed(jax.eval_shape(lambda: jax.random.key(0)), one_chip))
+
+
+def _by_kind(eng, full, window):
+    """An argument the engine hands a kind for a model with a window
+    kind of pool (`{"full": .., "window": ..}`), else the full kind's."""
+    return full if eng._ring is None else {"full": full,
+                                           "window": window(eng._ring.ring)}
+
+
+def slot_state(eng):
+    """The model's per-slot state as shapes on the chip, in a list (none
+    for a model that keeps none)."""
+    model = eng._model
+    return [placed(jax.eval_shape(lambda: model.init_slot_state(
+        eng.model_config, eng.config.num_slots)), eng.one_chip)] \
+        if model.init_slot_state else []
+
+
+def _compiled_insert(eng):
+    """`LLMEngine._insert_fn` at the cell's largest bucket, the slots'
+    state donated beside the pools where the model keeps one."""
+    from ray_tpu.serve.llm.engine import LLMEngine
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=eng.one_chip)
+
+    ec = eng.config
+    B, Pb = ec.num_slots, ec.prefill_buckets[-1]
+    state = slot_state(eng)
+    ids = arg(jnp.int32, Pb // ec.kv_block_size)
+    return jax.jit(
+        functools.partial(LLMEngine._insert_fn, eng),
+        donate_argnums=(1, 2, 3) + ((12,) if state else ())).lower(
+        eng.params, eng.pools, arg(jnp.int32, B), arg(jnp.int32, B),
+        _by_kind(eng, arg(jnp.int32, ec.max_blocks_per_slot),
+                 lambda ring: arg(jnp.int32, ring)), arg(jnp.int32),
+        arg(jnp.int32, Pb), arg(jnp.int32),
+        _by_kind(eng, ids, lambda ring: ids), arg(jnp.int32),
+        arg(jnp.float32), eng.key, *state).compile()
+
+
+def _compiled_tick(eng):
+    """`LLMEngine._tick_fn` of a serving cell, with the model's counters
+    and per-slot state where it has them, donated as `_jit_tick`
+    donates."""
+    from ray_tpu.serve.llm.engine import LLMEngine
+
+    ec, mc, model = eng.config, eng.model_config, eng._model
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=eng.one_chip)
+
+    B = ec.num_slots
+    extra = ([placed(jax.eval_shape(lambda: model.init_counts(mc)),
+                     eng.one_chip)] if model.init_counts else []) \
+        + slot_state(eng)
+    return jax.jit(
+        functools.partial(LLMEngine._tick_fn, eng),
+        donate_argnums=(1, 3, 4) + ((9,) if model.init_slot_state else ())
+    ).lower(
+        eng.params, eng.pools,
+        _by_kind(eng, arg(jnp.int32, B, ec.max_blocks_per_slot),
+                 lambda ring: arg(jnp.int32, B, ring)),
+        arg(jnp.int32, B), arg(jnp.int32, B), arg(jnp.bool_, B),
+        arg(jnp.float32, B), eng.key, *extra).compile()
+
+
+def cell_program(cell, which):
+    """A serving cell's decode tick (`"tick"`) or its largest insert
+    (`"insert"`) as the chip runs it, compiled once a process, and held
+    to its pin."""
+    build = {"tick": _compiled_tick, "insert": _compiled_insert}[which]
+    compiled = program((cell, which), lambda: build(serving_cell(cell)))
+    is_the_pinned_text(cell, which, compiled.plain)
+    return compiled
+
+
+def grouped_products_are_the_kernel(text, n_moe_layers):
+    """A compiled expert program with `ops.grouped_matmul` engaged: three
+    kernel calls an expert layer and none of XLA's grouped matmul left
+    (`ragged-dot` custom calls, `ragged_dot_tiling` in their config)."""
+    assert text.count("grouped_matmul") >= 3 * n_moe_layers
+    assert "ragged" not in without_metadata(text)
+
+
+def delta_rule_insert_holds_no_channel_tensor(cell, dk, temp_gib):
+    """The largest insert of a delta-rule cell (Kimi at its 2048 bucket,
+    one decay a key channel; Olmo-Hybrid at 512, one a head): no float32
+    result, fused computations' own included, has both of
+    `ops.kda.kda_chunked`'s chunk axes AND the channel axis `[.., 64,
+    64, dk]` (2.1 GB a KDA layer at Kimi's bucket, which
+    `_decayed_products` replaces by matrix products over 16-row
+    sub-blocks: the diagonal blocks' `[.., 32, 16, 128]`, the k rows
+    over the q rows, inside a reduction's fusion is what is left of it;
+    the a-head arm never had one, its decays are `[.., 64, 64]`).
+    Temporaries: 1.30 GiB against the 2.41 the `[C, C, dk]` form took
+    for Kimi (the history's softmax holds them now), 0.45 for
+    Olmo-Hybrid.  The two cells' cases live in two files, each beside
+    the other readers of its cell's insert."""
+    from ray_tpu.ops import kda
+
+    eng = serving_cell(cell)
+    C, b = kda.CHUNK, kda._SOLVE_BLOCK
+    assert eng.config.prefill_buckets[-1] % C == 0
+    compiled = cell_program(eng.name, "insert")
+    shapes = set().union(*(
+        shapes for _, shapes in results_of(compiled.text)))
+    assert any(s[-2:] == (C, C) for s in shapes)            # parsed
+    if dk == 128:
+        assert any(s[-3:] == (2 * b, b, dk) for s in shapes)
+    assert not sorted(s for s in shapes if s[-3:] == (C, C, dk))
+    assert compiled.memory.temp_size_in_bytes < temp_gib * GIB

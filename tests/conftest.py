@@ -162,6 +162,67 @@ def pytest_configure(config):
     # long-haul tests opt out of the bounded tier with this marker.
     config.addinivalue_line(
         "markers", "slow: excluded from the bounded tier-1 run")
+    # `--dist loadfile` starts the files in the order below, not by their
+    # number of cases (xdist's `--loadscope-reorder`, on by default).
+    config.option.loadscopereorder = False
+
+
+# ---------------------------------------------------------------------------
+# The order the files start in.  Under `--dist loadfile` xdist hands a worker
+# the next FILE when it runs dry, by default the one with the most CASES
+# left: a file of seven cases that takes 400 s (three learners on Pendulum;
+# seven whole-program compiles for v5e) starts in the run's last third,
+# beside every other file of few and long cases, and the run ends when it
+# does (PR 46: 1,208 s and three learners over their 180 s, against 837 s
+# with the same cases in files of 18 and 25).  Longest first is the schedule
+# for a makespan: the files below start in the order of their seconds (junit,
+# whole runs under six workers on eight cores), the rest after them as
+# collected.  A file missing here costs the run's tail at most its own
+# length; refresh the table from a run's junit file when the tail grows.
+# ---------------------------------------------------------------------------
+_FILE_SECONDS = {
+    "test_kimi_linear.py": 249,
+    "test_serve_llm.py": 235,
+    "test_kimi_linear_engine.py": 229,
+    "test_chip_compile_latent_cells.py": 214,
+    "test_rllib_algos_continuous.py": 196,
+    "test_rllib_offline.py": 180,
+    "test_latent_moe.py": 159,
+    "test_rllib_algos.py": 156,
+    "test_chip_compile_kv_cells.py": 154,
+    "test_podracer.py": 147,
+    "test_paged_attention.py": 142,
+    "test_conv_moe.py": 140,
+    "test_gdn_hybrid.py": 132,
+    "test_models.py": 126,
+    "test_window_moe.py": 124,
+    "test_kda_step_kernel.py": 123,
+    "test_chip_compile.py": 117,
+    "test_serve_features.py": 116,
+    "test_shortcut_moe.py": 112,
+    "test_train.py": 109,
+    "test_kv_tiering.py": 108,
+    "test_llama_decode.py": 106,
+    "test_graftlint.py": 106,
+    "test_rllib.py": 93,
+    "test_chip_smoke.py": 93,
+    "test_rllib_rainbow.py": 85,
+    "test_moe_pipeline.py": 76,
+    "test_ring_attention.py": 68,
+    "test_serve_llm_disagg.py": 65,
+    "test_cluster.py": 64,
+    "test_ulysses.py": 64,
+    "test_rllib_multiagent.py": 61,
+    "test_tune.py": 60,
+    "test_overlap.py": 57,
+}
+_START_ORDER = {name: at for at, name in enumerate(
+    sorted(_FILE_SECONDS, key=_FILE_SECONDS.get, reverse=True))}
+
+
+def pytest_collection_modifyitems(items):
+    items.sort(key=lambda item: _START_ORDER.get(      # stable
+        os.path.basename(item.nodeid.split("::", 1)[0]), len(_START_ORDER)))
 
 
 def _clear_alarm(old):
@@ -196,6 +257,68 @@ def pytest_runtest_teardown(item, nextitem):
         yield
     finally:
         _clear_alarm(old)
+
+
+# ---------------------------------------------------------------------------
+# The deviceless v5e compiles count themselves (tests/chip_programs.py): a
+# program that compiles twice in a run is a minute spent twice. Each xdist
+# worker hands its counts to the controller, and the run prints their sum.
+# ---------------------------------------------------------------------------
+def _v5e_compiles():
+    counted = sys.modules.get("chip_programs")
+    return {" ".join(key): n for key, n in counted.COMPILES.items()} \
+        if counted else {}
+
+
+def pytest_sessionfinish(session):
+    handed = getattr(session.config, "workeroutput", None)
+    if handed is not None:                  # an xdist worker
+        handed["v5e_compiles"] = _v5e_compiles()
+
+
+_WORKERS_V5E_COMPILES = []
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_testnodedown(node, error):
+    _WORKERS_V5E_COMPILES.append(
+        getattr(node, "workeroutput", {}).get("v5e_compiles", {}))
+
+
+def pytest_terminal_summary(terminalreporter):
+    import collections
+
+    total = collections.Counter()
+    for counts in _WORKERS_V5E_COMPILES + [_v5e_compiles()]:
+        total.update(counts)
+    if total:
+        terminalreporter.write_line(
+            "v5e compiles: %d programs, %d compiles%s" % (
+                len(total), sum(total.values()), "".join(
+                    "\n  %d x %s" % (n, key)
+                    for key, n in sorted(total.items()))))
+
+
+@pytest.fixture(scope="module")
+def shared_engine():
+    """`shared_engine(key, build)`: the module's one `LLMEngine` under
+    `key`, which names what decides its programs (the model's and the
+    engine's configuration, the selectors' answers it was built under).
+    `build()` makes it the first time a case asks: a tiny model's tick
+    and inserts take the CPU compiler 10-30 s an engine, and a case that
+    only serves through one and reads it needs no engine of its own.  It
+    is handed out drained and a case leaves it drained; a case that must
+    end with a broken or refused engine builds its own."""
+    built = {}
+
+    def get(key, build):
+        if key not in built:
+            built[key] = build()
+        assert not built[key].has_work(), key
+        return built[key]
+
+    yield get
+    built.clear()
 
 
 @pytest.fixture(scope="module", autouse=True)

@@ -1,0 +1,211 @@
+"""Deviceless v5e compiles of the benchmark's serving cells that keep K
+and V heads in paged pools (`compose-decode-conv-moe`,
+`mixed-decode-window-moe`, `reason-decode-gdn-hybrid`): the decode tick
+and the largest insert of each, as the chip runs them, at the geometry
+its files state.  `chip_programs.py` has the rules these files keep, the
+fixtures, the one compile a program (`cell_program`, which also holds
+each text to its pin) and the cells as shapes (`serving_cell`); every
+case that reads one of these cells' programs is in this file, the other
+cells are in `test_chip_compile_latent_cells.py`.
+"""
+
+import math
+import re
+
+import pytest
+
+import jax
+
+from chip_programs import (     # noqa: F401  (fixtures)
+    GIB, V5E_HBM_GIB, cell_program, delta_rule_insert_holds_no_channel_tensor,
+    grouped_products_are_the_kernel, on_tpu, one_chip, results_of,
+    serving_cell, slot_state, topo,
+)
+
+
+@pytest.mark.parametrize("program", ["tick", "insert"])
+def test_conv_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
+    """The engine's decode tick and its largest insert at the geometry of
+    the benchmark's `compose-decode-conv-moe` cell (gated convolutions
+    with a two-row tail by slot beside GQA heads of 64 in a K ‖ V paged
+    pool, a whole bank of 32 experts, at LFM2-8B-A1B's published widths;
+    the depth, slots, row length, buckets and pool its files state):
+    they compile for v5e, `paged_attention` and `grouped_matmul` answer
+    "kernel", the tick holds one paged-attention call an attention
+    layer, tick and insert three `ops.grouped_matmul` calls an expert
+    layer and no `ragged-dot`, the pool (2048 B a token a layer) AND
+    the slots' tails are updated in place, and arguments + temporaries
+    fit HBM."""
+    eng = serving_cell("compose-decode-conv-moe")
+    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+                                eng.published)
+    pools = eng.pools
+    assert (published["num_hidden_layers"], published["hidden_size"],
+            published["num_experts"], published["vocab_size"],
+            mc.n_conv_layers, mc.n_attn_layers, mc.n_moe_layers, mc.head_dim,
+            ec.num_slots) == (14, 2048, 32, 65536, 11, 3, 12, 64, 256)
+    assert model.paged_attention(pools) == "kernel"
+    assert model.grouped_matmul(mc, ec.num_slots) == "kernel"
+    kv = pools["kv"]
+    assert math.prod(kv.shape[3:]) * kv.dtype.itemsize == 2048
+    state, = slot_state(eng)
+    B, nb = ec.num_slots, ec.max_blocks_per_slot
+    if program == "tick":
+        compiled = cell_program(eng.name, "tick")
+        text = compiled.text
+        assert text.count("paged_attention") >= mc.n_attn_layers
+        assert text.count('custom_call_target="tpu_custom_call"') \
+            >= 3 * mc.n_moe_layers + mc.n_attn_layers
+        # no padded [B, S_pad] view of the pool is built
+        padded = (B, nb * ec.kv_block_size) + kv.shape[3:]
+        assert not any(padded in shapes for _, shapes in results_of(text))
+    else:
+        compiled = cell_program(eng.name, "insert")
+    grouped_products_are_the_kernel(compiled.text, mc.n_moe_layers)
+    m = compiled.memory
+    kept = sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in list(pools.values()) + list(state.values()))
+    print(program, "GiB", compiled.hbm_gib, "temp",
+          m.temp_size_in_bytes / GIB, "args", m.argument_size_in_bytes / GIB)
+    assert m.alias_size_in_bytes >= kept                # both in place
+    assert compiled.hbm_gib < V5E_HBM_GIB - 0.5
+
+
+@pytest.mark.parametrize("program", ["tick", "insert"])
+def test_window_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
+    """The engine's decode tick and its largest insert at the geometry of
+    the benchmark's `mixed-decode-window-moe` cell (window and full GQA
+    layers over two kinds of paged pool, a table of 1152 blocks and a
+    ring of 256 a slot, 128 experts beside a shared one and a head
+    200,192 wide, at Trinity-Mini's published widths; the depth, slots,
+    row length, buckets and both pools its files state): they compile
+    for v5e, `paged_attention` and `grouped_matmul` answer "kernel", the
+    tick holds one paged-attention call a layer (one of them the full
+    form) and builds no padded view of either pool, tick and insert
+    three `ops.grouped_matmul` calls an expert layer and no `ragged-dot`,
+    both kinds of pool (2048 B a token a layer, a token's four KV heads
+    side by side in one row) are updated in place and NOT copied to be
+    re-tiled (as `[bs, 4, 128]` blocks each insert copied every pool in
+    and out: 2.39 GiB of temporaries),
+    the insert at 2048 over an 18,432-row history keeps its temporaries
+    under 1.5 GiB (float32 scores over the whole history would be 4.5),
+    and arguments + temporaries fit HBM."""
+    eng = serving_cell("mixed-decode-window-moe")
+    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+                                eng.published)
+    pools = eng.pools
+    assert (published["num_hidden_layers"], published["hidden_size"],
+            published["num_experts"], published["vocab_size"],
+            mc.n_window_layers, mc.n_full_layers, mc.n_moe_layers,
+            mc.window, mc.n_kv_heads, ec.num_slots, ec.max_seq_len,
+            eng._ring.ring) == (5, 2048, 128, 200192, 4, 1, 4, 2048, 4, 64,
+                                18432, 256)
+    assert model.paged_attention(pools) == "kernel"
+    assert model.grouped_matmul(mc, ec.num_slots) == "kernel"
+    assert pools["k"].shape == (1, ec.pool_blocks, 16, 4 * 128)
+    assert pools["v_w"].shape == (4, ec.num_window_blocks, 16, 4 * 128)
+    B = ec.num_slots
+    if program == "tick":
+        compiled = cell_program(eng.name, "tick")
+        text = compiled.text
+        assert text.count("paged_attention") >= mc.n_layers
+        assert text.count('custom_call_target="tpu_custom_call"') \
+            >= 3 * mc.n_moe_layers + mc.n_layers
+        row = pools["k"].shape[3:]
+        padded = {(B, n * ec.kv_block_size) + row
+                  for n in (ec.max_blocks_per_slot, eng._ring.ring)}
+        assert not any(padded & shapes for _, shapes in results_of(text))
+    else:
+        compiled = cell_program(eng.name, "insert")
+    grouped_products_are_the_kernel(compiled.text, mc.n_moe_layers)
+    m = compiled.memory
+    kept = sum(math.prod(x.shape) * x.dtype.itemsize for x in pools.values())
+    print(program, "GiB", compiled.hbm_gib, "temp",
+          m.temp_size_in_bytes / GIB, "args", m.argument_size_in_bytes / GIB)
+    assert m.alias_size_in_bytes >= kept                # both in place
+    assert m.temp_size_in_bytes < 1.5 * GIB
+    assert compiled.hbm_gib < V5E_HBM_GIB - 0.5
+
+
+@pytest.mark.parametrize("program", ["tick", "insert"])
+def test_gdn_hybrid_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
+    """The engine's decode tick (128 slots x 4096) and its largest insert
+    (512) at the geometry of the benchmark's `reason-decode-gdn-hybrid`
+    cell (gated delta-rule state by slot beside full attention of 30 K/V
+    heads in two paged pools, at Olmo-Hybrid-7B's published widths; the
+    depth, slots, row length, buckets and pool its files state): they
+    compile for v5e; `paged_attention` answers "kernel" and the tick
+    holds one call an attention layer over pools `[2, NB, 16, 30, 128]`
+    that no instruction copies (the compiler lays a block of 30 heads
+    head by head and the kernel takes that view: a bitcast); the
+    delta-rule state steps through one `kda_step` kernel call a layer
+    over the WHOLE donated stack `[6, 128, 15, 96, 384]`, two heads a
+    row, which no instruction copies, slices or re-stacks and whose
+    bytes in HBM are the mathematics' 2,211,840 a slot a layer (no
+    padded lane: the stack's argument is exactly that many); pools and
+    state are updated in place, and arguments + temporaries fit HBM."""
+    eng = serving_cell("reason-decode-gdn-hybrid")
+    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+                                eng.published)
+    pools = eng.pools
+    assert (published["num_hidden_layers"], published["hidden_size"],
+            published["vocab_size"], mc.n_gdn_layers, mc.n_attn_layers,
+            mc.n_kv_heads, mc.gdn_key_dim, mc.gdn_value_dim, mc.rope_theta,
+            ec.num_slots, ec.max_seq_len, ec.prefill_buckets[-1]) \
+        == (8, 3840, 100352, 6, 2, 30, 96, 192, None, 128, 4096, 512)
+    assert model.paged_attention(pools) == "kernel"
+    pool = pools["k"].shape
+    assert pool == (2, ec.pool_blocks, 16, 30, 128) == pools["v"].shape
+    state, = slot_state(eng)
+    stack = state["S"].shape
+    assert stack == (6, 128, 15, 96, 384)
+    from ray_tpu.ops import kda
+
+    assert kda.engages(*stack[-2:], state["S"].dtype)
+    compiled = cell_program(eng.name, program)
+    text = compiled.text
+    results = results_of(text)
+    m = compiled.memory
+    kept = sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in list(pools.values()) + list(state.values()))
+    print(program, "GiB", compiled.hbm_gib, "temp",
+          m.temp_size_in_bytes / GIB, "args", m.argument_size_in_bytes / GIB)
+    assert m.alias_size_in_bytes >= kept                # both in place
+    assert compiled.hbm_gib < V5E_HBM_GIB - 0.5
+    # the state's bytes are the mathematics': what the program's
+    # arguments weigh is the shapes' own product, no padded tile
+    args = math.prod(stack) * 4 + sum(
+        math.prod(x.shape) * x.dtype.itemsize
+        for x in jax.tree.leaves((eng.params, pools, state["conv"])))
+    assert math.prod(stack[2:]) * 4 == 2211840
+    assert abs(m.argument_size_in_bytes - args) < 0.01 * GIB
+    layouts = set(re.findall(
+        r"f32\[6,128,15,96,384\]\{([^}]*)\}", text))
+    assert layouts <= {"4,3,2,1,0:T(8,128)", "4,3,2,1,0"} \
+        and "4,3,2,1,0:T(8,128)" in layouts, layouts        # whole tiles
+    if program == "insert":
+        plain = compiled.plain
+        assert "kda_step" not in plain and "paged_attention" not in plain
+        assert m.temp_size_in_bytes < 1.5 * GIB
+        return
+    assert text.count("kda_step") >= stack[0]
+    assert text.count("paged_attention") >= mc.n_attn_layers
+    assert "parameter" in {op for op, shapes in results if stack in shapes}
+    assert not [op for op, shapes in results if stack[1:] in shapes]
+    flat = pool[:2] + (pool[2] * pool[3], pool[4])  # the kernel's view
+    assert "bitcast" in {op for op, shapes in results if flat in shapes}
+    moved = [(op, shapes) for op, shapes in results
+             if op in ("copy", "copy-start", "copy-done", "dynamic-slice",
+                       "dynamic-update-slice", "select", "fusion")
+             and shapes & {stack, pool, pool[1:], flat, flat[1:]}]
+    assert not moved, moved
+    # no padded [B, S_pad] view of a pool is built
+    padded = (ec.num_slots, ec.max_seq_len) + pool[3:]
+    assert not any(padded in shapes for _, shapes in results)
+    assert m.temp_size_in_bytes < 0.5 * GIB
+
+
+@pytest.mark.parametrize("cell, dk, temp_gib", [("reason-decode-gdn-hybrid", 96, 0.5)])
+def test_delta_rule_inserts_hold_no_chunk_by_chunk_by_channel_tensor(
+        one_chip, on_tpu, cell, dk, temp_gib):
+    delta_rule_insert_holds_no_channel_tensor(cell, dk, temp_gib)

@@ -37,6 +37,7 @@ Tolerances and their reasons
   reference logit lies within 1e-4 of the reference maximum.
 """
 
+import functools
 import os
 import sys
 
@@ -81,6 +82,16 @@ def model():
     return _build(C)
 
 
+@functools.cache
+def _jitted(name):
+    """A program function of `models/conv_moe.py` under `jax.jit`, its
+    configuration static: one compile a shape for the whole module where
+    op-by-op dispatch compiled every primitive of every layer."""
+    from ray_tpu.models import conv_moe
+
+    return jax.jit(getattr(conv_moe, name), static_argnames=("config",))
+
+
 def _tokens(n, seed=0):
     return [int(t) for t in np.random.RandomState(seed).randint(0, 512, n)]
 
@@ -99,14 +110,12 @@ def _close(got, want):
 # ------------------------------------------------ (a) no cache, whole model
 
 def test_forward_matches_reference(model):
-    from ray_tpu.models.conv_moe import forward
-
     R, mc, weights, params = model
     assert (mc.n_conv_layers, mc.n_attn_layers, mc.n_moe_layers,
             mc.attn_layers, mc.conv_size) == (6, 2, 6, (2, 6), 3)
     assert "lm_head" not in params                  # tied
     toks = _tokens(50)
-    got = forward(params, jnp.asarray(toks)[None], mc)[0]
+    got = _jitted("forward")(params, jnp.asarray(toks)[None], mc)[0]
     _close(got, _reference_logits(R, weights, toks, 0, 50))
 
 
@@ -117,14 +126,12 @@ def test_bf16_forward_is_near_the_reference(seed):
     from families import conv_moe_decoder as F
     from reference import conv_moe_decoder as R
 
-    from ray_tpu.models.conv_moe import forward
-
     mc = F.model_config(C, max_seq_len=64, compute_dtype="bfloat16",
                         param_dtype="bfloat16")
     weights = R.init_weights(C, seed, jnp.bfloat16)
     toks = _tokens(50, seed)
-    got = np.asarray(forward(F.program_params(weights),
-                             jnp.asarray(toks)[None], mc)[0])
+    got = np.asarray(_jitted("forward")(F.program_params(weights),
+                                        jnp.asarray(toks)[None], mc)[0])
     want = _reference_logits(R, weights, toks, 0, 50)
     assert np.abs(want).max() > 2.0
     assert np.abs(got - want).mean() < 0.08
@@ -139,17 +146,15 @@ def _prefill(mc, params, pools, state, slot, table, toks, start,
     """One bucket-padded chunk of `toks` at `start` into the blocks of
     `table` and the tail row of `slot`, as the engine's insert program
     does it."""
-    from ray_tpu.models.conv_moe import prefill_paged
-
     S_pad = table.shape[0] * BS
     hist = {k: v[:, table].reshape((v.shape[0], S_pad) + v.shape[3:])
             for k, v in pools.items()}
     padded = np.zeros((bucket,), np.int32)
     padded[:len(toks)] = toks
     mine = {k: jnp.where(start > 0, v[:, slot], 0) for k, v in state.items()}
-    x, rows, mine = prefill_paged(params, jnp.asarray(padded)[None],
-                                  jnp.int32(start), hist, mc,
-                                  jnp.int32(len(toks)), mine)
+    x, rows, mine = _jitted("prefill_paged")(
+        params, jnp.asarray(padded)[None], jnp.int32(start), hist, mc,
+        jnp.int32(len(toks)), mine)
     ids = table[start // BS: start // BS + bucket // BS]
     pools = {k: v.at[:, ids].set(rows[k].reshape(
         (v.shape[0], bucket // BS, BS) + v.shape[3:]))
@@ -166,8 +171,8 @@ def test_paged_prefill_and_decode_match_reference(model, case):
     paged K ‖ V pool and the slot's tail: logits at every position
     against the reference's full forward; the dead slots' tails and the
     blocks no table names stand as they were."""
-    from ray_tpu.models.conv_moe import (_head, decode_step_paged,
-                                         init_paged_pool, init_slot_state)
+    from ray_tpu.models.conv_moe import (_head, init_paged_pool,
+                                         init_slot_state)
 
     R, mc, weights, params = model
     n_prompt = {"one_bucket": 13, "two_chunks": 27, "three_chunks": 41}[case]
@@ -192,7 +197,7 @@ def test_paged_prefill_and_decode_match_reference(model, case):
     tables[2] = table
     active = jnp.asarray([False, False, True])
     for t in range(n_prompt, n_prompt + 10):
-        logits, pools, counts, state = decode_step_paged(
+        logits, pools, counts, state = _jitted("decode_step_paged")(
             params, pools, jnp.asarray(tables),
             jnp.asarray([0, 0, toks[t]]), jnp.asarray([0, 0, t]), mc,
             active, state)
@@ -372,8 +377,9 @@ def test_decode_step_agrees_on_both_paths(monkeypatch):
     for path, force in (("gather", False), ("kernel", True)):
         monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", force)
         assert M._paged_attention(pools) == path
-        out[path] = M.decode_step_paged(params, pools, tables, tok, pos, c,
-                                        active, state)
+        # jitted anew: the path is chosen as it traces
+        out[path] = jax.jit(lambda: M.decode_step_paged(
+            params, pools, tables, tok, pos, c, active, state))()
     (lg, pg, _, sg), (lk, pk, _, sk) = out["gather"], out["kernel"]
     live = np.asarray(active)
     assert np.abs(np.asarray(lg)).max() > 0.1
@@ -456,8 +462,6 @@ def test_lower_precision_is_caught(model):
     cell's control) fail it by a factor of two at least."""
     from families import conv_moe_decoder as F
 
-    from ray_tpu.models.conv_moe import forward
-
     R, mc, weights, _ = model
     toks = _tokens(50)
     want = _reference_logits(R, weights, toks, 0, 50)
@@ -465,7 +469,8 @@ def test_lower_precision_is_caught(model):
     # last: make that one this test's own, not the fixture's
     _, _, mine, _ = _build(C)
     params = jax.jit(F.lower_precision_params)(mine)
-    got = np.asarray(forward(params, jnp.asarray(toks)[None], mc)[0])
+    got = np.asarray(_jitted("forward")(params, jnp.asarray(toks)[None],
+                                        mc)[0])
     assert np.abs(got - want).max() > 2 * RTOL * np.abs(want).max()
 
 
@@ -512,7 +517,21 @@ def _engine(mc, params, **over):
     return LLMEngine(params, mc, EngineConfig(**cfg), rng_seed=3)
 
 
-def test_engine_serves_chunked_prompts_and_recycles_slots(model):
+@pytest.fixture
+def engine(model, shared_engine):
+    """The module's one engine at `_engine`'s own configuration, warmed
+    up: drained when a case takes it and when it leaves it."""
+    _, mc, _, params = model
+
+    def build():
+        engine = _engine(mc, params)
+        engine.warmup()
+        return engine
+
+    return shared_engine("two slots", build)
+
+
+def test_engine_serves_chunked_prompts_and_recycles_slots(model, engine):
     """Seven requests through two slots, prompts from one token to three
     chunks: every slot is freed and re-admitted, every served token is
     the reference's choice given the served prefix (so a re-admitted
@@ -521,9 +540,8 @@ def test_engine_serves_chunked_prompts_and_recycles_slots(model):
     from ray_tpu.serve.llm.engine import Request
 
     R, mc, weights, params = model
-    engine = _engine(mc, params)
-    engine.warmup()
     st = engine.stats()
+    reuses_before = st["slot_reuses"]
     assert st["traces"] == {"tick": 1, "insert": 2, "export": 0, "adopt": 0}
     assert st["paged_attention"] == "gather"         # the CPU
     prompts = [_tokens(n, seed=20 + n) for n in (1, 16, 37, 9, 45, 17, 3)]
@@ -538,7 +556,8 @@ def test_engine_serves_chunked_prompts_and_recycles_slots(model):
             assert not engine._active[slot]
     assert seen_under_way > 0
     st = engine.stats()
-    assert st["slot_reuses"] >= 5 and st["trace_count"] == 3
+    assert st["slot_reuses"] - reuses_before >= 5
+    assert st["trace_count"] == 3
     assert st["kv"]["used_blocks"] == 0
     for p, h in zip(prompts, handles):
         assert h.finish_reason == "length" and len(h.tokens) == 6
@@ -551,7 +570,7 @@ def test_engine_serves_chunked_prompts_and_recycles_slots(model):
     assert int(ctr["expert_tokens"].sum()) % (2 * 6) == 0
 
 
-def test_slot_tail_is_the_last_two_rows(model):
+def test_slot_tail_is_the_last_two_rows(model, engine):
     """`LLMEngine.slot_state`: after a chunked prompt and six tokens the
     slot's tail in the first convolution layer is the reference's
     `B * X` of the last two tokens it has seen."""
@@ -559,12 +578,13 @@ def test_slot_tail_is_the_last_two_rows(model):
     from ray_tpu.serve.llm.engine import Request
 
     _, mc, weights, params = model
-    engine = _engine(mc, params, num_slots=1)
     p = _tokens(37, seed=9)
     h = engine.submit(Request(prompt=p, max_tokens=6, chunked_prefill=True))
+    engine.step()
+    slot, = (i for i, s in enumerate(engine._slots) if s.handle is h)
     while engine.has_work():
         engine.step()
-    got = engine.slot_state(0)["tail"]
+    got = engine.slot_state(slot)["tail"]
     assert got.shape == (6, 2, 64)
     seen = p + h.tokens[:-1]
     w = weights["layers"][0]
@@ -617,7 +637,7 @@ def test_engine_refuses_by_name_what_would_lose_the_tail(model, what):
 # ------------------------------------------------------- (g) the names
 
 @pytest.mark.parametrize("program", ["tick", "insert"])
-def test_programs_carry_the_scopes_the_readers_read(model, program,
+def test_programs_carry_the_scopes_the_readers_read(model, engine, program,
                                                    monkeypatch):
     """`conv` > `in_proj`, `mix`, `out_proj`; `attn` > `qk_norm`,
     `kv_write` and (tick) `paged` on the kernel path, `kv_gather` on the
@@ -629,7 +649,6 @@ def test_programs_carry_the_scopes_the_readers_read(model, program,
     from ray_tpu.ops import attention
 
     _, mc, _, params = model
-    engine = _engine(mc, params)
     e = engine
     if program == "tick":
         lowered = e._jit_tick.lower(

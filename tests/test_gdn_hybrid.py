@@ -23,6 +23,7 @@ Tolerances and their reasons
 """
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -70,6 +71,16 @@ def model():
     return _build(C)
 
 
+@functools.cache
+def _jitted(name):
+    """A program function of `models/gdn_hybrid.py` under `jax.jit`, its
+    configuration static: one compile a shape for the whole module where
+    op-by-op dispatch compiled every primitive of every layer."""
+    from ray_tpu.models import gdn_hybrid as M
+
+    return jax.jit(getattr(M, name), static_argnames=("config",))
+
+
 def _tokens(n, seed=0):
     return [int(t) for t in np.random.RandomState(seed).randint(0, 512, n)]
 
@@ -88,26 +99,22 @@ def _off(got, want):
 # ------------------------------------------------ (a) no cache, whole model
 
 def test_forward_matches_reference(model):
-    from ray_tpu.models.gdn_hybrid import forward
-
     R, mc, weights, params = model
     assert (mc.n_gdn_layers, mc.n_attn_layers, mc.attn_layers,
             mc.heads_a_row, mc.rope_theta) == (5, 1, (3,), 2, None)
     toks = _tokens(50)
-    got = forward(params, jnp.asarray(toks)[None], mc)[0]
+    got = _jitted("forward")(params, jnp.asarray(toks)[None], mc)[0]
     assert _off(got, _reference_logits(R, weights, toks, 0, 50)) < RTOL
 
 
 def test_a_rope_theta_in_the_file_rotates_half(model):
     """A number under `rope_parameters.rope_theta` is a data change: the
     program and the reference both rotate, and differ from no rotation."""
-    from ray_tpu.models.gdn_hybrid import forward
-
     c = dict(C, rope_parameters={"rope_theta": 10000.0})
     R, mc, weights, params = _build(c)
     assert mc.rope_theta == 10000.0
     toks = _tokens(50)
-    got = forward(params, jnp.asarray(toks)[None], mc)[0]
+    got = _jitted("forward")(params, jnp.asarray(toks)[None], mc)[0]
     assert _off(got, _reference_logits(R, weights, toks, 0, 50, c=c)) < RTOL
     assert _off(got, _reference_logits(R, weights, toks, 0, 50)) > 100 * RTOL
 
@@ -153,7 +160,9 @@ def test_a_mutilated_program_fails(model, what, monkeypatch):
     name, fn, piece = MUTILATIONS[what]
     monkeypatch.setattr(M, name, fn)
     toks = _tokens(48)
-    got = M.forward(params, jnp.asarray(toks)[None], mc)[0]
+    # jitted anew: the mutilation is there as it traces
+    got = jax.jit(lambda p, t: M.forward(p, t, mc))(
+        params, jnp.asarray(toks)[None])[0]
     assert _off(got, _reference_logits(R, weights, toks, 0, 48)) \
         > 100 * RTOL
     assert _off(got, _reference_logits(R, weights, toks, 0, 48,
@@ -211,17 +220,15 @@ def _prefill(mc, params, pools, state, slot, table, toks, start,
     """One bucket-padded chunk of `toks` at `start` into the blocks of
     `table` and the state row of `slot`, as the engine's insert program
     does it."""
-    from ray_tpu.models.gdn_hybrid import prefill_paged
-
     S_pad = table.shape[0] * BS
     hist = {k: v[:, table].reshape((v.shape[0], S_pad) + v.shape[3:])
             for k, v in pools.items()}
     padded = np.zeros((bucket,), np.int32)
     padded[:len(toks)] = toks
     mine = {k: jnp.where(start > 0, v[:, slot], 0) for k, v in state.items()}
-    x, rows, mine = prefill_paged(params, jnp.asarray(padded)[None],
-                                  jnp.int32(start), hist, mc,
-                                  jnp.int32(len(toks)), mine)
+    x, rows, mine = _jitted("prefill_paged")(
+        params, jnp.asarray(padded)[None], jnp.int32(start), hist, mc,
+        jnp.int32(len(toks)), mine)
     ids = table[start // BS: start // BS + bucket // BS]
     pools = {k: v.at[:, ids].set(rows[k].reshape(
         (v.shape[0], bucket // BS, BS) + v.shape[3:]))
@@ -235,8 +242,8 @@ def _served_logits(mc, params, toks, n_prompt=None, slots=3, slot=2):
     prompt in chunks of BUCKET (state and tail handed on in the slot),
     the rest a decode step a token with dead slots beside the live one.
     Returns [len(toks), V]; checks that the dead slots' state stands."""
-    from ray_tpu.models.gdn_hybrid import (LM, decode_step_paged,
-                                           init_paged_pool, init_slot_state)
+    from ray_tpu.models.gdn_hybrid import (LM, init_paged_pool,
+                                           init_slot_state)
 
     n_prompt = n_prompt or len(toks) - 10
     n_blocks = -(-len(toks) // BUCKET) * BUCKET // BS
@@ -257,7 +264,7 @@ def _served_logits(mc, params, toks, n_prompt=None, slots=3, slot=2):
     active = jnp.arange(slots) == slot
     dead = np.arange(slots) != slot
     before = jax.tree.map(lambda x: np.asarray(x[:, dead]), state)
-    step = jax.jit(decode_step_paged, static_argnums=5)
+    step = _jitted("decode_step_paged")
     for t in range(n_prompt, len(toks)):
         tok = np.zeros(slots, np.int32)
         pos = np.zeros(slots, np.int32)
@@ -390,7 +397,16 @@ def _engine(mc, params, **over):
     return LLMEngine(params, mc, EngineConfig(**{**cfg, **over}), rng_seed=0)
 
 
-def test_engine_serves_chunked_prompts_and_recycles_slots(model):
+@pytest.fixture
+def engine(model, shared_engine):
+    """The module's one engine at `_engine`'s own configuration, every
+    selector answering as on the CPU: drained when a case takes it and
+    when it leaves it."""
+    _, mc, _, params = model
+    return shared_engine("three slots", lambda: _engine(mc, params))
+
+
+def test_engine_serves_chunked_prompts_and_recycles_slots(model, engine):
     """Five requests through three slots (a slot is reused with its
     state cleared), prompts shorter and longer than the top bucket:
     every served token's reference logit lies within the tolerance of
@@ -398,7 +414,7 @@ def test_engine_serves_chunked_prompts_and_recycles_slots(model):
     from ray_tpu.serve.llm.engine import Request
 
     R, mc, weights, params = model
-    engine = _engine(mc, params)
+    live_before = int(engine.stats()["counters"]["live_slots"])
     lengths = (5, 16, 23, 37, 9)
     handles = [engine.submit(Request(
         prompt=_tokens(n, seed=20 + i), max_tokens=6, temperature=0.0,
@@ -408,7 +424,7 @@ def test_engine_serves_chunked_prompts_and_recycles_slots(model):
     stats = engine.stats()
     assert stats["paged_attention"] == "gather"
     assert stats["counters"]["gdn_rows_stepped"] == 0
-    assert stats["counters"]["live_slots"] >= 5 * 5
+    assert stats["counters"]["live_slots"] - live_before >= 5 * 5
     for i, (n, h) in enumerate(zip(lengths, handles)):
         assert h.finish_reason == "length" and len(h.tokens) == 6
         d = R.served_token_deficits(weights, C, _tokens(n, seed=20 + i),
@@ -438,7 +454,7 @@ def test_engine_refuses_by_name_what_would_lose_the_state(model, what):
 
 
 @pytest.mark.parametrize("when", ["in_flight", "drained"])
-def test_engine_stats_count_the_ticks_read_back(model, when):
+def test_engine_stats_count_the_ticks_read_back(engine, when):
     """`stats()["counters"]` are the model's counters as the last tick
     READ BACK left them: with a tick in flight they agree with the
     tokens emitted, one tick behind the device (and the caller, which
@@ -446,8 +462,7 @@ def test_engine_stats_count_the_ticks_read_back(model, when):
     every tick."""
     from ray_tpu.serve.llm.engine import Request
 
-    R, mc, weights, params = model
-    engine = _engine(mc, params)
+    before = engine.stats()         # drained: every tick read back
     h = engine.submit(Request(prompt=_tokens(7, seed=3), max_tokens=6,
                               temperature=0.0))
     if when == "in_flight":
@@ -458,12 +473,14 @@ def test_engine_stats_count_the_ticks_read_back(model, when):
         engine.drain()
     loop, counters = engine.stats()["loop"], engine.stats()["counters"]
     # a token at the insert, then one a tick read back
-    assert int(counters["ticks"]) == len(h.tokens) - 1 \
-        == loop["ticks"] - len(engine._flying)
+    assert int(counters["ticks"]) - int(before["counters"]["ticks"]) \
+        == len(h.tokens) - 1 \
+        == loop["ticks"] - before["loop"]["ticks"] - len(engine._flying)
     engine.drain()
 
 
-def test_engine_steps_live_states_through_the_kernel(monkeypatch):
+def test_engine_steps_live_states_through_the_kernel(model, engine,
+                                                     monkeypatch):
     """With the interpreter forced, at heads that tile (keys of 8
     sublanes' worth, two heads of 64 a 128-lane row), the tick steps the
     packed stack through `kda_step_live` and counts it; the tokens are
@@ -471,18 +488,19 @@ def test_engine_steps_live_states_through_the_kernel(monkeypatch):
     from ray_tpu.ops import attention
     from ray_tpu.serve.llm.engine import Request
 
-    R, mc, weights, params = _build(C)
+    R, mc, weights, params = model
     served = {}
     for force in (False, True):
         monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", force)
-        engine = _engine(mc, params)
-        hs = [engine.submit(Request(prompt=_tokens(n, seed=40 + n),
-                                    max_tokens=5, temperature=0.0,
-                                    chunked_prefill=n > 16))
+        # the plain path's is the module's engine; the kernel's is built
+        # and traced under the forced interpreter
+        eng = _engine(mc, params) if force else engine
+        hs = [eng.submit(Request(prompt=_tokens(n, seed=40 + n),
+                                 max_tokens=5, temperature=0.0,
+                                 chunked_prefill=n > 16))
               for n in (7, 21)]
-        while engine.has_work():
-            engine.step()
-        counters = engine.stats()["counters"]
+        eng.drain()
+        counters = eng.stats()["counters"]
         assert counters["gdn_rows_stepped"] == (
             counters["live_slots"] * mc.n_gdn_layers if force else 0)
         served[force] = [list(h.tokens) for h in hs]

@@ -27,6 +27,50 @@ FIXTURES = os.path.join(os.path.dirname(__file__), "lint_fixtures")
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(FIXTURES)))
 
 
+@pytest.fixture(scope="module")
+def package_run():
+    """One graftlint run over the whole package, through the CLI and
+    under the repo's own baseline (20 s of CPU alone, 40 under six
+    workers), for the cases that only read it: its exit code and output,
+    how often each pass visited each module, and its CPU seconds beside
+    those of parsing the same sources once and walking them once a pass,
+    taken in this process right after it."""
+    import collections
+    import contextlib
+    import io
+    import types
+    from unittest import mock
+
+    from ray_tpu._private.lint import core
+
+    visits = collections.Counter()
+
+    def counted_passes(select=None, real=core.all_passes):
+        passes = real(select)
+        for p in passes:
+            def check(mod, p=p, inner=p.check_module):
+                visits[p.name, mod.relpath] += 1
+                return inner(mod)
+            p.check_module = check
+        return passes
+
+    out = io.StringIO()
+    t0 = time.process_time()
+    with mock.patch.object(core, "all_passes", counted_passes), \
+            contextlib.redirect_stdout(out):
+        rc = lint_main([])
+    t1 = time.process_time()
+    modules = core.iter_modules([os.path.join(REPO, "ray_tpu")], rel_to=REPO)
+    for _ in registered_passes():
+        nodes = sum(1 for m in modules
+                    if getattr(m, "parse_error", None) is None
+                    for _ in ast.walk(m.tree))
+    t2 = time.process_time()
+    return types.SimpleNamespace(
+        rc=rc, out=out.getvalue(), visits=visits, modules=modules,
+        nodes=nodes, lint_cpu_s=t1 - t0, parse_and_walk_cpu_s=t2 - t1)
+
+
 def _lint(fixture, passname, **kw):
     return run_lint([os.path.join(FIXTURES, fixture)],
                     select=[passname], **kw)
@@ -202,11 +246,9 @@ class TestBaseline:
 class TestRepoGate:
     """The tier-1 gate: the repo itself lints clean at HEAD."""
 
-    def test_repo_lints_clean(self, capsys):
-        rc = lint_main([])
-        out = capsys.readouterr().out
-        assert rc == 0, out
-        assert "graftlint: OK" in out
+    def test_repo_lints_clean(self, package_run):
+        assert package_run.rc == 0, package_run.out
+        assert "graftlint: OK" in package_run.out
 
     def test_baseline_entries_are_justified(self):
         path = os.path.join(REPO, ".graftlint-baseline.json")
@@ -702,14 +744,26 @@ class TestCLI:
 
 
 class TestLintBudget:
-    def test_full_package_run_under_30s(self):
-        # CPU time, not wall clock: the suite runs tests in parallel
-        # and a contended box would fail a wall-clock budget for
-        # reasons that have nothing to do with the lint.
-        t0 = time.process_time()
-        run_lint([os.path.join(REPO, "ray_tpu")], rel_to=REPO)
-        elapsed = time.process_time() - t0
-        assert elapsed < 30.0, f"lint took {elapsed:.1f}s CPU"
+    def test_full_package_run_under_30s(self, package_run):
+        """The budget as work, not seconds (30 s of CPU held alone, where
+        the run takes 21; under six workers the same run read 39.9): a
+        full run shows each module to each pass once, and costs under 5
+        times what parsing the same sources once and walking them once a
+        pass costs in this process under this load (3.2 times alone,
+        21 s against 6.3: the bound leaves what 30 s left)."""
+        run = package_run
+        passes = {p for p, _ in run.visits}
+        seen = {m for _, m in run.visits}
+        assert passes == set(registered_passes()) and len(passes) >= 16
+        assert seen == {m.relpath for m in run.modules
+                        if getattr(m, "parse_error", None) is None}
+        assert len(seen) > 200 and run.nodes > 300_000
+        assert set(run.visits.values()) == {1}
+        assert len(run.visits) == len(passes) * len(seen)
+        ratio = run.lint_cpu_s / run.parse_and_walk_cpu_s
+        assert ratio < 5.0, (
+            f"lint took {run.lint_cpu_s:.1f}s CPU, {ratio:.1f} times a "
+            f"parse and a walk a pass of its sources")
 
 
 class TestCheckMetricsShim:

@@ -24,13 +24,14 @@ Tolerances and their reasons
   matrix products around a reference row); 2e-5 where strong and weak
   tokens alternate inside a chunk of 64 (reads 7.9e-6, with the
   `[C, C, dk]` reduction as with the products: the 64-row solve).
-* The engine tests serve greedy tokens in float32; each served token's
-  reference logit lies within 1e-4 of the reference maximum (0 unless
-  two logits tie to within the sums' reordering).
+* The engine tests (`test_kimi_linear_engine.py`, a file of its own so
+  that `--dist loadfile` can give it another worker) serve greedy tokens
+  in float32; each served token's reference logit lies within 1e-4 of
+  the reference maximum (0 unless two logits tie to within the sums'
+  reordering).
 """
 
-import os
-import sys
+import functools
 
 import numpy as np
 import pytest
@@ -39,65 +40,31 @@ import jax
 import jax.numpy as jnp
 
 import latent_walk
-
-BENCH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmarks")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
-
-TOL = 5e-6
-# two periods less one layer: KDA KDA KDA MLA KDA, layer 1 dense
-C = dict(hidden_size=64, num_attention_heads=4, kv_lora_rank=32,
-         qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
-         intermediate_size=128, moe_intermediate_size=32,
-         num_experts=2, num_experts_per_token=2, num_shared_experts=1,
-         routed_scaling_factor=2.446, moe_renormalize=True,
-         mla_use_nope=True, vocab_size=512, num_hidden_layers=5,
-         first_k_dense_replace=1, rms_norm_eps=1e-5,
-         router_bias_scale=0.1, initializer_range=0.02,
-         linear_attn_config=dict(
-             full_attn_layers=[4, 8], kda_layers=[1, 2, 3, 5, 6, 7],
-             num_heads=4, head_dim=16, short_conv_kernel_size=4),
-         deployment=dict(num_experts=8, rank=1),
-         precision=dict(recurrent_state="float32"))
-BS = 4            # rows a block
-BUCKET = 16       # one prefill bucket
+from kimi_linear_tiny import (     # noqa: F401  (`model`: a fixture)
+    BS, BUCKET, C, KERNEL_BS, TOL, _build, _drawn_at_a_tenth,
+    _reference_logits, _tiling, _tokens, model,
+)
 
 
-def _build(c, **overrides):
-    from families import kda_hybrid_decoder as F
-    from reference import kda_hybrid_decoder as R
+@functools.cache
+def _jitted(name):
+    """A program function of `models/kimi_linear.py` under `jax.jit`,
+    its configuration static: one compile a shape for the whole module
+    where op-by-op dispatch compiled every primitive of every layer."""
+    from ray_tpu.models import kimi_linear as KL
 
-    mc = F.model_config(c, max_seq_len=64, compute_dtype="float32",
-                        param_dtype="float32", **overrides)
-    weights = R.init_weights(c, 11, jnp.float32)
-    return R, mc, weights, F.program_params(weights)
-
-
-@pytest.fixture(scope="module")
-def model():
-    return _build(C)
-
-
-def _tokens(n, seed=0):
-    return [int(t) for t in np.random.RandomState(seed).randint(0, 512, n)]
-
-
-def _reference_logits(R, weights, toks, start, n, c=C):
-    return np.asarray(R.logits_for_positions(weights, c, toks, start, n,
-                                             pad_to=64))
+    return jax.jit(getattr(KL, name), static_argnames=("config",))
 
 
 # ------------------------------------------------ (a) no cache, whole model
 
 def test_forward_matches_reference(model):
-    from ray_tpu.models.kimi_linear import forward
-
     R, mc, weights, params = model
     assert (mc.n_kda_layers, mc.n_mla_layers, mc.n_held_experts,
             mc.expert_rank, mc.expert_shards) == (4, 1, 2, 1, 4)
     toks = _tokens(50)
-    got = np.asarray(forward(params, jnp.asarray(toks)[None], mc)[0])
+    got = np.asarray(_jitted("forward")(params, jnp.asarray(toks)[None],
+                                        mc)[0])
     want = _reference_logits(R, weights, toks, 0, 50)
     assert np.abs(want).max() > 0.3
     assert np.abs(got - want).max() < TOL
@@ -110,17 +77,15 @@ def _prefill(mc, params, pools, state, slot, table, toks, start,
     """One bucket-padded chunk of `toks` at `start` into the blocks of
     `table` and the state row of `slot`, as the engine's insert program
     does it."""
-    from ray_tpu.models.kimi_linear import prefill_paged
-
     S_pad = table.shape[0] * BS
     hist = {k: v[:, table].reshape((v.shape[0], S_pad) + v.shape[3:])
             for k, v in pools.items()}
     padded = np.zeros((bucket,), np.int32)
     padded[:len(toks)] = toks
     mine = {k: jnp.where(start > 0, v[:, slot], 0) for k, v in state.items()}
-    x, rows, mine = prefill_paged(params, jnp.asarray(padded)[None],
-                                  jnp.int32(start), hist, mc,
-                                  jnp.int32(len(toks)), mine)
+    x, rows, mine = _jitted("prefill_paged")(
+        params, jnp.asarray(padded)[None], jnp.int32(start), hist, mc,
+        jnp.int32(len(toks)), mine)
     ids = table[start // BS: start // BS + bucket // BS]
     pools = {k: v.at[:, ids].set(rows[k].reshape(
         (v.shape[0], bucket // BS, BS) + v.shape[3:]))
@@ -135,8 +100,7 @@ def test_paged_prefill_and_decode_match_reference(model, case):
     and state) and then 10 decode steps through the paged latent pool
     and the slot's recurrent state: logits at every position against
     the reference's full forward."""
-    from ray_tpu.models.kimi_linear import (LM, decode_step_paged,
-                                            init_paged_pool,
+    from ray_tpu.models.kimi_linear import (LM, init_paged_pool,
                                             init_slot_state)
 
     R, mc, weights, params = model
@@ -160,7 +124,7 @@ def test_paged_prefill_and_decode_match_reference(model, case):
     active = jnp.asarray([False, False, True])
     before = jax.tree.map(lambda x: np.asarray(x[:, :2]), state)
     for t in range(n_prompt, n_prompt + 10):
-        logits, pools, counts, state = decode_step_paged(
+        logits, pools, counts, state = _jitted("decode_step_paged")(
             params, pools, jnp.asarray(tables),
             jnp.asarray([0, 0, toks[t]]), jnp.asarray([0, 0, t]), mc,
             active, state)
@@ -238,6 +202,13 @@ def test_prefill_hand_off(model, case):
 
 # ---------------- (d) the chunkwise form against the one-token recurrence
 
+def _kda_chunked(*args, chunk):
+    """`ops.kda.kda_chunked` under `jax.jit`, the chunk static."""
+    from ray_tpu.ops.kda import kda_chunked
+
+    return jax.jit(kda_chunked, static_argnames="chunk")(*args, chunk=chunk)
+
+
 def _token_by_token(q, k, v, g, beta, S):
     """`kda_step` over the sequence: (outputs [B, T, H, dv], the state
     after every token)."""
@@ -260,8 +231,6 @@ def test_chunkwise_kda_equals_recurrence(lo, hi, width):
     """`width` a_head: `g` [.., H, 1], one decay a head, keys of another
     size than values and write strengths up to 2 (the gated delta rule
     of `models/gdn_hybrid.py`)."""
-    from ray_tpu.ops.kda import kda_chunked
-
     B, T, H, dk, dv = 2, 50, 3, 16, 8
     if width == "a_head":
         dk, dv = 12, 24
@@ -277,7 +246,7 @@ def test_chunkwise_kda_equals_recurrence(lo, hi, width):
     S0 = jax.random.normal(ks[5], (B, H, dk, dv))
     n_real = jnp.asarray([50, 37])
     want, states = _token_by_token(q, k, v, g, beta, S0)
-    got, S_got = kda_chunked(q, k, v, g, beta, S0, n_real, chunk=16)
+    got, S_got = _kda_chunked(q, k, v, g, beta, S0, n_real, chunk=16)
     assert jnp.abs(want).max() > 0.5
     assert jnp.abs(got[0] - want[0]).max() < 2e-6
     assert jnp.abs(got[1, :37] - want[1, :37]).max() < 2e-6
@@ -315,8 +284,6 @@ def test_chunkwise_kda_by_sub_blocks_equals_recurrence(case, tol, T, n_real):
     reduction they replace, 1.1e-6 at chunks of 16: the 64-row solve's
     rounding, where weak rows keep `A` near 1 between strong ones; the
     products themselves are held to float64 in the next test.)"""
-    from ray_tpu.ops.kda import kda_chunked
-
     B, H, dk, dv = 2, 3, 16, 8
     ks = jax.random.split(jax.random.key(1), 7)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
@@ -327,8 +294,8 @@ def test_chunkwise_kda_by_sub_blocks_equals_recurrence(case, tol, T, n_real):
     g = _decays(case, ks[4], (B, T, H, dk))
     S0 = jax.random.normal(ks[5], (B, H, dk, dv))
     want, states = _token_by_token(q, k, v, g, beta, S0)
-    got, S_got = kda_chunked(q, k, v, g, beta, S0, jnp.asarray(n_real),
-                             chunk=64)
+    got, S_got = _kda_chunked(q, k, v, g, beta, S0, jnp.asarray(n_real),
+                              chunk=64)
     assert jnp.abs(want).max() > 0.5
     for row, n in enumerate(n_real):
         assert jnp.abs(got[row, :n] - want[row, :n]).max() < tol
@@ -453,8 +420,6 @@ def test_lower_precision_is_caught(model, what):
     int8 (its first), fails it."""
     from families import kda_hybrid_decoder as F
 
-    from ray_tpu.models.kimi_linear import forward
-
     R, mc, weights, params = model
     toks = _tokens(50)
     want = _reference_logits(R, weights, toks, 0, 50)
@@ -463,7 +428,7 @@ def test_lower_precision_is_caught(model, what):
         # last: make that one this test's own, not the fixture's
         _, _, weights, _ = _build(C)
         params = jax.jit(F.lower_precision_params)(weights)
-        got = forward(params, jnp.asarray(toks)[None], mc)[0]
+        got = _jitted("forward")(params, jnp.asarray(toks)[None], mc)[0]
     else:
         c = dict(C, precision=dict(recurrent_state="bfloat16"))
         _, mc16, _, _ = _build(c)
@@ -475,8 +440,7 @@ def test_lower_precision_is_caught(model, what):
 def _served_logits(mc, params, toks):
     """Logits of every position through the serving path: the first
     bucket prefilled, the rest decoded a token at a time."""
-    from ray_tpu.models.kimi_linear import (LM, decode_step_paged,
-                                            init_paged_pool,
+    from ray_tpu.models.kimi_linear import (LM, init_paged_pool,
                                             init_slot_state)
 
     pools, state = init_paged_pool(mc, 20, BS), init_slot_state(mc, 1)
@@ -485,7 +449,7 @@ def _served_logits(mc, params, toks):
                                toks[:BUCKET], 0)
     got = [LM._head(mc, params, x)]
     for t in range(BUCKET, len(toks)):
-        logits, pools, _, state = decode_step_paged(
+        logits, pools, _, state = _jitted("decode_step_paged")(
             params, pools, jnp.asarray(table[None]), jnp.asarray([toks[t]]),
             jnp.asarray([t]), mc, jnp.asarray([True]), state)
         got.append(logits)
@@ -502,172 +466,7 @@ def test_float32_state_through_the_serving_path(model):
         < TOL
 
 
-# ------------------------------------------------------- (g) the engine
-
-def _engine(mc, params, **over):
-    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
-
-    cfg = dict(num_slots=2, max_seq_len=64, prefill_buckets=(8, 16),
-               kv_block_size=BS, num_kv_blocks=40, prefix_cache=False)
-    cfg.update(over)
-    return LLMEngine(params, mc, EngineConfig(**cfg), rng_seed=3)
-
-
-def test_engine_serves_chunked_prompts_and_recycles_slots(model):
-    """Seven requests through two slots, prompts from one token to three
-    chunks: every slot is freed and re-admitted, every served token is
-    the reference's choice given the served prefix (so a re-admitted
-    slot started from a zero state: a leak would change its logits),
-    and a prompt under way keeps its slot inactive until its last
-    chunk."""
-    from ray_tpu.serve.llm.engine import Request
-
-    R, mc, weights, params = model
-    engine = _engine(mc, params)
-    engine.warmup()
-    assert engine.stats()["traces"] == {"tick": 1, "insert": 2,
-                                        "export": 0, "adopt": 0}
-    prompts = [_tokens(n, seed=20 + n) for n in (1, 16, 37, 9, 45, 17, 3)]
-    handles = [engine.submit(Request(
-        prompt=p, max_tokens=6, chunked_prefill=len(p) > 16))
-        for p in prompts]
-    seen_under_way = 0
-    while engine.has_work():
-        engine.step()
-        for slot in engine._chunking:
-            seen_under_way += 1
-            assert not engine._active[slot]
-            assert engine._slots[slot].handle is not None
-    assert seen_under_way > 0
-    st = engine.stats()
-    assert st["slot_reuses"] >= 5 and st["trace_count"] == 3
-    assert st["slot_state"]["prompts_under_way"] == 0
-    assert st["kv"]["used_blocks"] == 0
-    for p, h in zip(prompts, handles):
-        assert h.finish_reason == "length" and len(h.tokens) == 6
-        assert h.prefilled_tokens == len(p)
-        d = R.served_token_deficits(weights, C, p, h.tokens)
-        assert d.max() < 1e-4, (len(p), d)
-    ctr = st["counters"]
-    assert int(ctr["ticks"]) > 0
-    assert int(ctr["live_slots"]) <= 2 * int(ctr["ticks"])
-    assert int(ctr["pairs_local"]) == int(ctr["expert_tokens"].sum())
-    assert int(ctr["pairs_total"]) == int(ctr["live_slots"]) * 2 * 4
-
-
-def test_slot_state_is_what_the_reference_carries(model):
-    """`LLMEngine.slot_state`: after a chunked prompt and six tokens the
-    slot's recurrent state is the reference recurrence's over the prompt
-    and the first five, and a model without per-slot state has none."""
-    from ray_tpu.serve.llm.engine import Request
-
-    R, mc, weights, params = model
-    engine = _engine(mc, params, num_slots=1)
-    p = _tokens(37, seed=9)
-    h = engine.submit(Request(prompt=p, max_tokens=6, chunked_prefill=True))
-    while engine.has_work():
-        engine.step()
-    got = engine.slot_state(0)
-    assert got["S"].shape == (4, 4, 16, 16) and got["S"].dtype == np.float32
-    assert got["conv"].shape == (4, 3, 3 * 64)
-    want = R.kda_states(weights, C, p + h.tokens[:-1])
-    # states of 8e-3 at these sizes; float32 sums in another order read
-    # 1e-6 of that, a state kept in bf16 between tokens 4e-3 of it
-    assert np.abs(want).max() > 1e-3
-    assert np.abs(got["S"] - want).max() < 1e-4 * np.abs(want).max()
-
-    from ray_tpu.models.latent_moe import LatentMoEConfig, init_params
-    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
-
-    lc = LatentMoEConfig.tiny()
-    plain = LLMEngine(init_params(lc, jax.random.key(0)), lc, EngineConfig(
-        num_slots=1, max_seq_len=64, prefill_buckets=(8,), kv_block_size=BS))
-    assert plain.slot_state(0) is None
-
-
-def test_cancel_between_chunks_frees_the_slot(model):
-    from ray_tpu.serve.llm.engine import Request
-
-    _, mc, _, params = model
-    engine = _engine(mc, params)
-    h = engine.submit(Request(prompt=_tokens(45), max_tokens=4,
-                              chunked_prefill=True))
-    engine.step()                       # first chunk: slot and blocks taken
-    assert len(engine._chunking) == 1 and not engine._active.any()
-    assert engine.stats()["kv"]["used_blocks"] > 0
-    assert h.cancel()
-    engine.drain()
-    assert h.finish_reason == "cancelled"
-    assert not engine._chunking and len(engine._free) == 2
-    assert engine.stats()["kv"]["used_blocks"] == 0
-    # and the slot serves the next request from a zero state
-    p = _tokens(20, seed=9)
-    h2 = engine.submit(Request(prompt=p, max_tokens=3, chunked_prefill=True))
-    engine.drain()
-    want = engine_free = _engine(mc, params)
-    h3 = want.submit(Request(prompt=p, max_tokens=3, chunked_prefill=True))
-    engine_free.drain()
-    assert h2.tokens == h3.tokens and len(h2.tokens) == 3
-
-
-@pytest.mark.parametrize("what", ["prefix_cache", "export_prefix",
-                                  "adopt", "prefill_only", "preempt",
-                                  "speculative_verify"])
-def test_engine_refuses_by_name_what_would_lose_the_state(model, what):
-    """Whatever moves rows without the recurrent state is refused, and
-    the refusal names the model."""
-    from ray_tpu.serve.llm.engine import (EngineConfig, LLMEngine, Request)
-    from ray_tpu.serve.llm.kv_cache import KVState
-
-    _, mc, _, params = model
-    name = "models/kimi_linear.py"
-    with pytest.raises(ValueError, match=name):
-        if what == "prefix_cache":
-            _engine(mc, params, prefix_cache=True)
-        elif what == "speculative_verify":
-            LLMEngine(params, mc, EngineConfig(
-                num_slots=2, max_seq_len=64, prefill_buckets=(8,),
-                kv_block_size=BS, prefix_cache=False),
-                draft_params=params, draft_config=mc)
-        else:
-            engine = _engine(mc, params)
-            if what == "export_prefix":
-                engine.export_prefix(_tokens(8))
-            elif what == "prefill_only":
-                engine.submit(Request(prompt=_tokens(5), max_tokens=2,
-                                      prefill_only=True))
-            elif what == "preempt":
-                engine.submit(Request(prompt=_tokens(5), max_tokens=4))
-                engine.step()
-                engine.preempt(0)
-            else:
-                engine.submit_adopted(
-                    Request(prompt=[1, 2], max_tokens=4),
-                    KVState(prompt=[1, 2], tokens=[3], next_tok=3, pos=2,
-                            temperature=0.0, block_size=BS, blocks={}))
-
-
 # ------------------------- (h) the decode tick's two attention paths
-
-KERNEL_BS = 16    # rows a block: a whole packed tile, so the kernel engages
-
-
-def _tiling():
-    """bf16, a latent of one lane tile in a row of two (128 ‖ 8 ‖ zeros
-    to 256): the shapes `ops.paged_attention.engages` asks for."""
-    from ray_tpu.models import kimi_linear as KL
-
-    c = KL.KimiLinearConfig.tiny(kv_lora_rank=128)
-    assert c.cache_row == 256 and c.dtype == jnp.bfloat16
-    return KL, c
-
-
-def _drawn_at_a_tenth(KL, c, seed):
-    """Matrices at 0.1, not `init_params`' 0.02: at 0.02 the tiny
-    model's best two logits lie closer than bf16 rounding moves them
-    and greedy tokens say nothing about the path."""
-    return jax.tree.map(lambda x: 5 * x if x.ndim >= 2 else x,
-                        KL.init_params(c, jax.random.key(seed)))
 
 
 def test_decode_step_agrees_on_both_attention_paths(monkeypatch):
@@ -715,112 +514,3 @@ def test_decode_step_agrees_on_both_attention_paths(monkeypatch):
             np.testing.assert_array_equal(
                 np.asarray(x[:, 1], np.float32),
                 np.asarray(state[leaf][:, 1], np.float32))
-
-
-@pytest.mark.parametrize("seed", [0, 3])
-def test_engine_serves_the_same_greedy_tokens_on_both_attention_paths(
-        monkeypatch, seed):
-    """Three prompts of different lengths beside each other, a free
-    slot: the tokens through the kernel equal the gather path's, and
-    `stats()` names the path.  The two paths' logits differ by a
-    hundredth of their size, each as far from float32 throughout as the
-    other (the kernel keeps scores in float32, the gather path rounds
-    them), so the seeds are ones at which no served token's best two
-    logits lie closer than that: a flip at another seed is that
-    rounding, which `test_decode_step_agrees_on_both_attention_paths`
-    bounds, and not a wrong row."""
-    from ray_tpu.ops import attention
-    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine, Request
-
-    KL, c = _tiling()
-    params = _drawn_at_a_tenth(KL, c, seed)
-    rng = np.random.default_rng(seed)
-    prompts = [rng.integers(1, c.vocab_size, n).tolist() for n in (5, 19, 40)]
-
-    def serve():
-        eng = LLMEngine(params, c, EngineConfig(
-            num_slots=4, max_seq_len=128, prefill_buckets=(16, 32, 64),
-            kv_block_size=KERNEL_BS, prefix_cache=False))
-        handles = [eng.submit(Request(prompt=p, max_tokens=6))
-                   for p in prompts]
-        for _ in range(200):
-            if all(h.finished_at is not None for h in handles):
-                break
-            eng.step()
-        return [h.tokens for h in handles], eng.stats()
-
-    gather_tokens, gather_stats = serve()
-    monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", True)
-    kernel_tokens, kernel_stats = serve()
-    assert kernel_tokens == gather_tokens
-    assert all(len(t) == 6 for t in kernel_tokens)
-    assert kernel_stats["paged_attention"] == "kernel"
-    assert gather_stats["paged_attention"] == "gather"
-    assert 0 < kernel_stats["live_rows"] == gather_stats["live_rows"] \
-        < kernel_stats["padded_rows"]
-
-
-# -------------------- (i) the recurrence's live step through the engine
-
-def test_engine_steps_live_states_in_place_on_both_kda_paths(monkeypatch):
-    """A float32 model with KDA heads of 128 through `LLMEngine`, two
-    slots: a prompt in one bucket, one in three chunks, ticks, the first
-    slot freed, left free while the other ticks on, then taken by a
-    third request.  With `ops.kda.kda_step_live` forced through the
-    interpreter against the plain `kda_step` path: the same tokens, the
-    slots' states within 2e-6 of their size, a freed slot's rows the
-    same BITS after the ticks that follow (the kernel never writes a
-    dead slot; the plain form writes back what it read), and
-    `kda_rows_stepped / (live_slots x KDA layers)` 1.0 against 0.0."""
-    from ray_tpu.models import kimi_linear as KL
-    from ray_tpu.ops import attention
-    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine, Request
-
-    c = KL.KimiLinearConfig.tiny(kda_head_dim=128, kda_heads=2,
-                                 dtype=jnp.float32,
-                                 param_dtype=jnp.float32)
-    params = _drawn_at_a_tenth(KL, c, 2)
-    first, long, third = (_tokens(n, seed=70 + n) for n in (9, 41, 20))
-
-    def serve():
-        eng = LLMEngine(params, c, EngineConfig(
-            num_slots=2, max_seq_len=64, prefill_buckets=(8, 16),
-            kv_block_size=BS, num_kv_blocks=40, prefix_cache=False),
-            rng_seed=3)
-        a = eng.submit(Request(prompt=first, max_tokens=3,
-                               chunked_prefill=True))
-        b = eng.submit(Request(prompt=long, max_tokens=16,
-                               chunked_prefill=True))
-        eng.step()
-        slot, = (i for i, s in enumerate(eng._slots) if s.handle is a)
-        while a.finished_at is None:
-            eng.step()
-        freed = eng.slot_state(slot)
-        assert np.abs(freed["S"]).max() > 1e-3
-        for _ in range(4):                      # the other slot ticks on
-            eng.step()
-        assert b.finished_at is None and len(b.tokens) >= 4
-        kept = eng.slot_state(slot)
-        for leaf in freed:
-            np.testing.assert_array_equal(kept[leaf], freed[leaf])
-        d = eng.submit(Request(prompt=third, max_tokens=5,
-                               chunked_prefill=True))
-        while eng.has_work():
-            eng.step()
-        assert eng.stats()["slot_reuses"] >= 1
-        ctr = eng.stats()["counters"]
-        return ([h.tokens for h in (a, b, d)],
-                [eng.slot_state(s)["S"] for s in range(2)],
-                int(ctr["kda_rows_stepped"])
-                / (int(ctr["live_slots"]) * c.n_kda_layers))
-
-    plain_tokens, plain_states, plain_share = serve()
-    monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", True)
-    assert KL.kda.engages(c.kda_head_dim, c.kda_head_dim, c.state_dtype)
-    kernel_tokens, kernel_states, kernel_share = serve()
-    assert kernel_tokens == plain_tokens
-    assert [len(t) for t in kernel_tokens] == [3, 16, 5]
-    for got, want in zip(kernel_states, plain_states):
-        assert np.abs(want).max() > 1e-3
-        assert np.abs(got - want).max() <= 2e-6 * np.abs(want).max()
-    assert (plain_share, kernel_share) == (0.0, 1.0)

@@ -19,6 +19,7 @@ Tolerances and their reasons
   two logits tie to within the sums' reordering).
 """
 
+import functools
 import os
 import sys
 
@@ -58,6 +59,16 @@ def model():
     return R, mc, weights, F.program_params(weights)
 
 
+@functools.cache
+def _jitted(name):
+    """A program function of `models/latent_moe.py` under `jax.jit`, its
+    configuration static: one compile a shape for the whole module where
+    op-by-op dispatch compiled every primitive of every layer."""
+    from ray_tpu.models import latent_moe
+
+    return jax.jit(getattr(latent_moe, name), static_argnames=("config",))
+
+
 def _tokens(n, seed=0):
     return [int(t) for t in np.random.RandomState(seed).randint(0, 512, n)]
 
@@ -70,11 +81,10 @@ def _reference_logits(R, weights, toks, start, n):
 # ------------------------------------------------ (a) no cache, whole model
 
 def test_forward_matches_reference(model):
-    from ray_tpu.models.latent_moe import forward
-
     R, mc, weights, params = model
     toks = _tokens(50)
-    got = np.asarray(forward(params, jnp.asarray(toks)[None], mc)[0])
+    got = np.asarray(_jitted("forward")(params, jnp.asarray(toks)[None],
+                                        mc)[0])
     want = _reference_logits(R, weights, toks, 0, 50)
     assert np.abs(want).max() > 0.3
     assert np.abs(got - want).max() < TOL
@@ -85,16 +95,14 @@ def test_forward_matches_reference(model):
 def _prefill(mc, params, pools, table, toks, start):
     """One bucket-padded chunk of `toks` at `start` into the blocks of
     `table`, as the engine's insert program does it."""
-    from ray_tpu.models.latent_moe import prefill_paged
-
     S_pad = table.shape[0] * BS
     hist = {k: v[:, table].reshape((v.shape[0], S_pad) + v.shape[3:])
             for k, v in pools.items()}
     padded = np.zeros((BUCKET,), np.int32)
     padded[:len(toks)] = toks
-    x, rows = prefill_paged(params, jnp.asarray(padded)[None],
-                            jnp.int32(start), hist, mc,
-                            jnp.int32(len(toks)))
+    x, rows = _jitted("prefill_paged")(
+        params, jnp.asarray(padded)[None], jnp.int32(start), hist, mc,
+        jnp.int32(len(toks)))
     ids = table[start // BS: start // BS + BUCKET // BS]
     pools = {k: v.at[:, ids].set(rows[k].reshape(
         (v.shape[0], BUCKET // BS, BS) + v.shape[3:]))
@@ -108,8 +116,7 @@ def test_paged_prefill_and_decode_match_reference(model, case):
     history; a suffix over ANOTHER sequence's cached blocks) and then 10
     decode steps through the paged latent pool: logits at every position
     against the reference's full forward."""
-    from ray_tpu.models.latent_moe import (_head, decode_step_paged,
-                                           init_paged_pool)
+    from ray_tpu.models.latent_moe import _head, init_paged_pool
 
     R, mc, weights, params = model
     n_prompt = {"one_bucket": 13, "chunked": 27, "prefix_hit": 24}[case]
@@ -137,7 +144,7 @@ def test_paged_prefill_and_decode_match_reference(model, case):
     want = _reference_logits(R, weights, toks, n_prompt, 10)
     for i in range(10):
         pos = n_prompt + i
-        logits, pools, counts = decode_step_paged(
+        logits, pools, counts = _jitted("decode_step_paged")(
             params, pools, tables, jnp.asarray([toks[pos], 7]),
             jnp.asarray([pos, 0]), mc, active=jnp.asarray([True, False]))
         assert np.abs(np.asarray(logits[0]) - want[i]).max() < TOL
@@ -231,7 +238,6 @@ def test_routing_drops_nothing_under_total_imbalance(model):
     """A selection bias that sends EVERY token to experts 0 and 1 (a
     capacity-dispatch layer at any capacity factor under E / k = 4 would
     drop most of them): the model still equals the reference."""
-    from ray_tpu.models.latent_moe import forward
     from ray_tpu.models.moe import dropless_moe, sigmoid_bias_top_k
 
     R, mc, weights, params = model
@@ -243,7 +249,8 @@ def test_routing_drops_nothing_under_total_imbalance(model):
             for w in tree["layers"]])
 
     toks = _tokens(40, seed=9)
-    got = np.asarray(forward(skew(params), jnp.asarray(toks)[None], mc)[0])
+    got = np.asarray(_jitted("forward")(
+        skew(params), jnp.asarray(toks)[None], mc)[0])
     want = _reference_logits(R, skew(weights), toks, 0, 40)
     assert np.abs(got - want).max() < TOL
     x = jax.random.normal(jax.random.key(2), (40, 64))
@@ -278,8 +285,9 @@ def test_lower_precision_is_caught(model, what, monkeypatch):
             latent_moe, "_masked_softmax",
             lambda s, qpos, n, dt: real(bf16(s), qpos, n, dt))
     toks = _tokens(50)
-    got = np.asarray(latent_moe.forward(params, jnp.asarray(toks)[None],
-                                        mc)[0])
+    # jitted anew: the rounding is there as it traces
+    got = np.asarray(jax.jit(lambda p, t: latent_moe.forward(p, t, mc))(
+        params, jnp.asarray(toks)[None])[0])
     want = _reference_logits(R, weights, toks, 0, 50)
     assert np.abs(got - want).max() > 2 * TOL
 
@@ -547,7 +555,7 @@ def test_decode_step_agrees_on_both_attention_paths(monkeypatch):
 
 @pytest.mark.parametrize("seed", [0, 1])
 def test_engine_serves_the_same_greedy_tokens_on_both_attention_paths(
-        monkeypatch, seed):
+        monkeypatch, shared_engine, seed):
     """Three prompts of different lengths beside each other, a free
     slot: the tokens through the kernel equal the gather path's, and
     `stats()` names the path.  The two paths' logits differ by a
@@ -556,7 +564,8 @@ def test_engine_serves_the_same_greedy_tokens_on_both_attention_paths(
     them), so the seeds are ones at which no served token's best two
     logits lie closer than that: a flip at another seed is that
     rounding, which `test_decode_step_agrees_on_both_attention_paths`
-    bounds, and not a wrong row."""
+    bounds, and not a wrong row.  One engine a path serves both seeds:
+    the programs take the parameters as an argument."""
     from ray_tpu.ops import attention
     from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine, Request
 
@@ -565,21 +574,20 @@ def test_engine_serves_the_same_greedy_tokens_on_both_attention_paths(
     rng = np.random.default_rng(seed)
     prompts = [rng.integers(1, c.vocab_size, n).tolist() for n in (5, 19, 40)]
 
-    def serve():
-        eng = LLMEngine(params, c, EngineConfig(
-            num_slots=4, max_seq_len=128, prefill_buckets=(16, 32, 64),
-            kv_block_size=KERNEL_BS, prefix_cache=False))
+    def serve(path):
+        eng = shared_engine(("whole tiles", path), lambda: LLMEngine(
+            params, c, EngineConfig(
+                num_slots=4, max_seq_len=128, prefill_buckets=(16, 32, 64),
+                kv_block_size=KERNEL_BS, prefix_cache=False)))
+        eng.params = params
         handles = [eng.submit(Request(prompt=p, max_tokens=6))
                    for p in prompts]
-        for _ in range(200):
-            if all(h.finished_at is not None for h in handles):
-                break
-            eng.step()
+        eng.drain()
         return [h.tokens for h in handles], eng.stats()
 
-    gather_tokens, gather_stats = serve()
+    gather_tokens, gather_stats = serve("gather")
     monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", True)
-    kernel_tokens, kernel_stats = serve()
+    kernel_tokens, kernel_stats = serve("kernel")
     assert kernel_tokens == gather_tokens
     assert all(len(t) == 6 for t in kernel_tokens)
     assert kernel_stats["paged_attention"] == "kernel"

@@ -27,6 +27,7 @@ Tolerances and their reasons
   reference logit lies within 1e-4 of the reference maximum.
 """
 
+import functools
 import os
 import sys
 
@@ -73,6 +74,16 @@ def model():
     return _build(C)
 
 
+@functools.cache
+def _jitted(name):
+    """A program function of `models/shortcut_moe.py` under `jax.jit`,
+    its configuration static: one compile a shape for the whole module
+    where op-by-op dispatch compiled every primitive of every layer."""
+    from ray_tpu.models import shortcut_moe
+
+    return jax.jit(getattr(shortcut_moe, name), static_argnames=("config",))
+
+
 def _tokens(n, seed=0):
     return [int(t) for t in np.random.RandomState(seed).randint(0, 512, n)]
 
@@ -85,13 +96,12 @@ def _reference_logits(R, weights, toks, start, n, c=C):
 # ------------------------------------------------ (a) no cache, whole model
 
 def test_forward_matches_reference(model):
-    from ray_tpu.models.shortcut_moe import forward
-
     R, mc, weights, params = model
     assert (mc.n_held_experts, mc.expert_rank, mc.expert_shards,
             mc.router_width) == (4, 1, 2, 12)
     toks = _tokens(50)
-    got = np.asarray(forward(params, jnp.asarray(toks)[None], mc)[0])
+    got = np.asarray(_jitted("forward")(params, jnp.asarray(toks)[None],
+                                        mc)[0])
     want = _reference_logits(R, weights, toks, 0, 50)
     assert np.abs(want).max() > 1.0
     assert np.abs(got - want).max() < TOL
@@ -147,7 +157,9 @@ def test_what_the_tolerance_catches(model, what, monkeypatch):
         monkeypatch.setattr(M.LM, "latent_attention", early)
         monkeypatch.setattr(M, "shortcut_experts", experts)
     toks = _tokens(50)
-    got = np.asarray(M.forward(params, jnp.asarray(toks)[None], mc)[0])
+    # jitted anew: what was taken away is away as it traces
+    got = np.asarray(jax.jit(lambda p, t: M.forward(p, t, mc))(
+        params, jnp.asarray(toks)[None])[0])
     want = _reference_logits(R, weights, toks, 0, 50, c)
     assert np.abs(got - want).max() > 100 * TOL
 
@@ -157,16 +169,14 @@ def test_what_the_tolerance_catches(model, what, monkeypatch):
 def _prefill(mc, params, pools, table, toks, start):
     """One bucket-padded chunk of `toks` at `start` into the blocks of
     `table`, as the engine's insert program does it."""
-    from ray_tpu.models.shortcut_moe import prefill_paged
-
     S_pad = table.shape[0] * BS
     hist = {k: v[:, table].reshape((v.shape[0], S_pad) + v.shape[3:])
             for k, v in pools.items()}
     padded = np.zeros((BUCKET,), np.int32)
     padded[:len(toks)] = toks
-    x, rows = prefill_paged(params, jnp.asarray(padded)[None],
-                            jnp.int32(start), hist, mc,
-                            jnp.int32(len(toks)))
+    x, rows = _jitted("prefill_paged")(
+        params, jnp.asarray(padded)[None], jnp.int32(start), hist, mc,
+        jnp.int32(len(toks)))
     ids = table[start // BS: start // BS + BUCKET // BS]
     pools = {k: v.at[:, ids].set(rows[k].reshape(
         (v.shape[0], BUCKET // BS, BS) + v.shape[3:]))
@@ -180,8 +190,7 @@ def test_paged_prefill_and_decode_match_reference(model, case):
     rows) and then 10 decode steps through the paged latent pool, TWO
     pool layers a layer: logits at every position against the
     reference's full forward, and the tick's counters."""
-    from ray_tpu.models.shortcut_moe import (LM, decode_step_paged,
-                                             init_paged_pool)
+    from ray_tpu.models.shortcut_moe import LM, init_paged_pool
 
     R, mc, weights, params = model
     n_prompt = {"one_bucket": 13, "chunked": 27}[case]
@@ -199,7 +208,7 @@ def test_paged_prefill_and_decode_match_reference(model, case):
     want = _reference_logits(R, weights, toks, n_prompt, 10)
     for i in range(10):
         pos = n_prompt + i
-        logits, pools, counts = decode_step_paged(
+        logits, pools, counts = _jitted("decode_step_paged")(
             params, pools, tables, jnp.asarray([toks[pos], 7]),
             jnp.asarray([pos, 0]), mc, active=jnp.asarray([True, False]))
         assert np.abs(np.asarray(logits[0]) - want[i]).max() < TOL

@@ -1,6 +1,7 @@
 """Ray-Train-equivalent e2e: JaxTrainer data-parallel training on a fake
 2-host x 4-device CPU mesh — THE e2e milestone from SURVEY §7 M5."""
 
+import json
 import os
 import time
 
@@ -225,17 +226,25 @@ class TestJaxTrainer:
 
 def ingestion_train_loop(config):
     """Consumes a streaming_split Data shard (Train<->Data ingestion,
-    reference `train/_internal/data_config.py`)."""
+    reference `train/_internal/data_config.py`).  Every worker lays
+    aside what it was fed: the rows' indices an epoch and the loss of
+    each batch, in the order it took them."""
+    import json
+    import os
+
     import numpy as np
 
     from ray_tpu import train
 
     it = train.get_dataset_shard("train")
     assert it is not None, "dataset shard missing"
+    rank = train.get_context().get_world_rank()
     w = np.zeros(4, np.float32)
+    fed = {"rows": [], "batch_losses": []}
     for epoch in range(config.get("epochs", 2)):
         n_rows = 0
         loss_sum = 0.0
+        fed["rows"].append([])
         for batch in it.iter_batches(batch_size=16):
             x = np.stack(batch["x"]).astype(np.float32)
             y = np.asarray(batch["y"], np.float32)
@@ -243,9 +252,13 @@ def ingestion_train_loop(config):
             err = pred - y
             loss_sum += float((err ** 2).sum())
             n_rows += len(y)
+            fed["rows"][-1] += [int(i) for i in batch["i"]]
+            fed["batch_losses"].append(float((err ** 2).mean()))
             w -= 0.05 * (x.T @ err) / max(len(y), 1)  # SGD on the shard
         train.report({"loss": loss_sum / max(n_rows, 1), "rows": n_rows,
                       "epoch": epoch})
+    with open(os.path.join(config["fed_dir"], f"rank{rank}.json"), "w") as f:
+        json.dump(fed, f)
 
 
 class TestTrainDataIngestion:
@@ -257,26 +270,42 @@ class TestTrainDataIngestion:
         w_true = np.array([1.0, -2.0, 0.5, 3.0], np.float32)
         ys = xs @ w_true
         ds = rdata.from_items(
-            [{"x": xs[i], "y": float(ys[i])} for i in range(256)],
+            [{"i": i, "x": xs[i], "y": float(ys[i])} for i in range(256)],
             override_num_blocks=8,
         ).map_batches(lambda b: b)  # exercise a fused transform stage
 
         trainer = JaxTrainer(
             ingestion_train_loop,
-            train_loop_config={"epochs": 2},
+            train_loop_config={"epochs": 2, "fed_dir": str(tmp_path)},
             datasets={"train": ds},
             scaling_config=ScalingConfig(num_workers=2),
             jax_config=JaxConfig(platform="cpu", num_cpu_devices=1),
             run_config=RunConfig(name="ingest", storage_path=str(tmp_path)),
         )
         result = trainer.fit()
-        history = result.metrics_dataframe
         # Both epochs ran and the split streamed every row exactly once
-        # per epoch across the two workers (rank-0 metrics are recorded;
-        # totals are per-worker so just check rows > 0 and loss decreased).
+        # per epoch across the two workers.  Who takes how many is first
+        # come, first served: under six loaded workers one rank can take a
+        # whole epoch before the other asks, so the rows are counted over
+        # both ranks (until PR 46: rank 0's `rows > 0` every epoch and its
+        # loss lower in the second, which fails then), and the loss must
+        # fall for whoever was fed enough to learn.
         assert result.metrics["epoch"] == 1
-        assert all(m["rows"] > 0 for m in history)
-        assert history[-1]["loss"] < history[0]["loss"]
+        assert len(result.metrics_dataframe) == 2
+        fed = []
+        for rank in range(2):
+            with open(tmp_path / f"rank{rank}.json") as f:
+                fed.append(json.load(f))
+        for epoch in range(2):
+            rows = fed[0]["rows"][epoch] + fed[1]["rows"][epoch]
+            assert sorted(rows) == list(range(256)), epoch
+        learned = 0
+        for losses in (f["batch_losses"] for f in fed):
+            if len(losses) >= 8:         # of 32 batches: one rank at least
+                q = len(losses) // 4
+                assert np.mean(losses[-q:]) < 0.5 * np.mean(losses[:q])
+                learned += 1
+        assert learned >= 1
 
 
 # ------------------------------------------------------------ torch tier

@@ -30,6 +30,7 @@ Tolerances and their reasons
   reference logit lies within 1e-4 relative of the reference maximum.
 """
 
+import functools
 import os
 import sys
 
@@ -85,6 +86,16 @@ def model():
     return _build(C)
 
 
+@functools.cache
+def _jitted(name):
+    """A program function of `models/window_moe.py` under `jax.jit`, its
+    configuration static: one compile a shape for the whole module where
+    op-by-op dispatch compiled every primitive of every layer."""
+    from ray_tpu.models import window_moe
+
+    return jax.jit(getattr(window_moe, name), static_argnames=("config",))
+
+
 def _tokens(n, seed=0):
     return [int(t) for t in np.random.RandomState(seed).randint(0, 512, n)]
 
@@ -105,15 +116,13 @@ def _off(got, want):
 # ------------------------------------------------ (a) no cache, whole model
 
 def test_forward_matches_reference(model):
-    from ray_tpu.models.window_moe import forward
-
     R, mc, weights, params = model
     assert (mc.n_window_layers, mc.n_full_layers, mc.n_moe_layers,
             mc.full_layers, mc.window) == (4, 1, 4, (2,), 8)
     assert [mc.kind(i) for i in range(5)] == [
         "window", "window", "full", "window", "window"]
     toks = _tokens(48)
-    got = forward(params, jnp.asarray(toks)[None], mc)[0]
+    got = _jitted("forward")(params, jnp.asarray(toks)[None], mc)[0]
     assert _off(got, _reference_logits(R, weights, toks, 0, 48)) < RTOL
 
 
@@ -131,7 +140,9 @@ def test_a_mutilated_program_fails(model, what, monkeypatch):
         mc = dataclasses.replace(mc, window=10 ** 6)
     else:
         monkeypatch.setattr(M, "ROTARY_KINDS", ("window", "full"))
-    got = M.forward(params, jnp.asarray(toks)[None], mc)[0]
+    # jitted anew: the mutilation is there as it traces
+    got = jax.jit(lambda p, t: M.forward(p, t, mc))(
+        params, jnp.asarray(toks)[None])[0]
     assert _off(got, _reference_logits(R, weights, toks, 0, 48)) \
         > 100 * RTOL
 
@@ -145,11 +156,9 @@ def test_a_mutilated_program_fails(model, what, monkeypatch):
 def test_the_program_has_each_piece(model, piece):
     """The program equals the whole reference (above); a reference
     WITHOUT the piece is far from it."""
-    from ray_tpu.models.window_moe import forward
-
     R, mc, weights, params = model
     toks = _tokens(48)
-    got = forward(params, jnp.asarray(toks)[None], mc)[0]
+    got = _jitted("forward")(params, jnp.asarray(toks)[None], mc)[0]
     short = _reference_logits(R, weights, toks, 0, 48, without=(piece,))
     assert _off(got, short) > 100 * RTOL
 
@@ -159,8 +168,6 @@ def test_lower_precision_is_caught(model):
     cell's control) fail it by a factor of two at least."""
     from families import window_moe_decoder as F
 
-    from ray_tpu.models.window_moe import forward
-
     R, mc, weights, _ = model
     toks = _tokens(48)
     want = _reference_logits(R, weights, toks, 0, 48)
@@ -168,7 +175,7 @@ def test_lower_precision_is_caught(model):
     # last: make that one this test's own, not the fixture's
     _, _, mine, _ = _build(C)
     params = jax.jit(F.lower_precision_params)(mine)
-    got = forward(params, jnp.asarray(toks)[None], mc)[0]
+    got = _jitted("forward")(params, jnp.asarray(toks)[None], mc)[0]
     assert _off(got, want) > 2 * RTOL
 
 
@@ -178,7 +185,7 @@ def _prefill(mc, params, pools, table, ring, toks, start, bucket=BUCKET):
     """One bucket-padded chunk of `toks` at `start`, as the engine's
     insert program does it: the full kind's history by position, the
     window kind's as its ring; rows scattered through each table."""
-    from ray_tpu.models.window_moe import WINDOW_LEAVES, prefill_paged
+    from ray_tpu.models.window_moe import WINDOW_LEAVES
 
     def row(name):
         return ring if name in WINDOW_LEAVES else table
@@ -188,8 +195,9 @@ def _prefill(mc, params, pools, table, ring, toks, start, bucket=BUCKET):
         for k, v in pools.items()}
     padded = np.zeros((bucket,), np.int32)
     padded[:len(toks)] = toks
-    x, rows = prefill_paged(params, jnp.asarray(padded)[None],
-                            jnp.int32(start), hist, mc, jnp.int32(len(toks)))
+    x, rows = _jitted("prefill_paged")(
+        params, jnp.asarray(padded)[None], jnp.int32(start), hist, mc,
+        jnp.int32(len(toks)))
     at = start // BS + np.arange(bucket // BS)
     ids = {k: (ring[at % len(ring)] if k in WINDOW_LEAVES else table[at])
            for k in pools}
@@ -206,8 +214,7 @@ def test_paged_prefill_and_decode_match_reference(model, n_prompt):
     that the ring wraps in decode too: logits at every position against
     the reference's full forward; the blocks no table names stand as
     they were."""
-    from ray_tpu.models.window_moe import (_head, decode_step_paged,
-                                           init_paged_pool)
+    from ray_tpu.models.window_moe import _head, init_paged_pool
 
     R, mc, weights, params = model
     toks = _tokens(N_TOK, seed=3)
@@ -230,7 +237,7 @@ def test_paged_prefill_and_decode_match_reference(model, n_prompt):
     tables = jax.tree.map(jnp.asarray, tables)
     active = jnp.asarray([False, False, True])
     for t in range(n_prompt, N_TOK):
-        logits, pools, counts = decode_step_paged(
+        logits, pools, counts = _jitted("decode_step_paged")(
             params, pools, tables, jnp.asarray([0, 0, toks[t]]),
             jnp.asarray([0, 0, t]), mc, active)
         got.append(np.asarray(logits[2:3]))
@@ -267,13 +274,14 @@ def test_decode_step_agrees_on_both_paths(monkeypatch):
                   2, ring), jnp.int32)}
     tok = jnp.asarray([5, 9], jnp.int32)
     pos = jnp.asarray([20, 201], jnp.int32)
+    # jitted anew: the path is chosen as it traces
+    step = lambda: jax.jit(lambda: M.decode_step_paged(       # noqa: E731
+        params, pools, tables, tok, pos, mc))()
     assert M._paged_attention(pools) == "gather"
-    want, pools_g, _ = M.decode_step_paged(params, pools, tables, tok, pos,
-                                           mc)
+    want, pools_g, _ = step()
     monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", True)
     assert M._paged_attention(pools) == "kernel"
-    got, pools_k, _ = M.decode_step_paged(params, pools, tables, tok, pos,
-                                          mc)
+    got, pools_k, _ = step()
     for name in M.WINDOW_LEAVES:    # the first layer's rows: the same
         assert jnp.array_equal(pools_g[name][0], pools_k[name][0]), name
     scale = float(jnp.abs(want).max())
@@ -333,7 +341,15 @@ def _engine(mc, params, **over):
     return LLMEngine(params, mc, EngineConfig(**cfg), rng_seed=3)
 
 
-def test_engine_serves_through_both_kinds_past_a_ring_wrap(model):
+@pytest.fixture
+def engine(model, shared_engine):
+    """The module's one engine at `_engine`'s own configuration: drained
+    when a case takes it and when it leaves it."""
+    _, mc, _, params = model
+    return shared_engine("three slots", lambda: _engine(mc, params))
+
+
+def test_engine_serves_through_both_kinds_past_a_ring_wrap(model, engine):
     """Six requests through three slots and a window pool that holds
     two rings and a little: prompts from three tokens to four chunks,
     answers that take the longer streams past ring + window rows.  Every
@@ -343,7 +359,6 @@ def test_engine_serves_through_both_kinds_past_a_ring_wrap(model):
     from ray_tpu.serve.llm.engine import Request
 
     R, mc, weights, params = model
-    engine = _engine(mc, params)
     assert engine.stats()["kv"]["window"]["ring_blocks"] == RING
     prompts = [_tokens(n, seed=n) for n in (3, 40, 16, 55, 9, 30)]
     handles = [engine.submit(Request(prompt=p, max_tokens=30,
@@ -380,11 +395,12 @@ class _RecordingTick:
             len(e._flying)))
         return self.tick(params, pools, tables, tok, pos, active, *rest)
 
-    def take_sample(self):
-        return self.tick.take_sample()
+    def __getattr__(self, name):        # `take_sample`, `record_wall`
+        return getattr(self.tick, name)
 
 
-def test_ring_covers_the_row_a_tick_behind_one_in_flight_writes(model):
+def test_ring_covers_the_row_a_tick_behind_one_in_flight_writes(
+        engine, monkeypatch):
     """One tick in flight (PR 41): when tick k goes out the handles are
     one token short of the rows dispatched, and the ring still has to
     hold, owned and distinct, the block of the row tick k WRITES (a
@@ -392,9 +408,8 @@ def test_ring_covers_the_row_a_tick_behind_one_in_flight_writes(model):
     the dispatches themselves) and of the window before it."""
     from ray_tpu.serve.llm.engine import Request
 
-    R, mc, weights, params = model
-    engine = _engine(mc, params)
-    rec = engine._jit_tick = _RecordingTick(engine)
+    rec = _RecordingTick(engine)
+    monkeypatch.setattr(engine, "_jit_tick", rec)
     ring, W = engine._ring, C["sliding_window"]
     prompts = [_tokens(n, seed=n) for n in (5, 40, 21)]
     handles = [engine.submit(Request(prompt=p, max_tokens=40,
