@@ -8,8 +8,13 @@ one `latent` row (latent ‖ rotary key) for latent attention.  The engine
 and the cache manager move whole blocks of every leaf (insert scatter,
 export, adopt, spill, promote) and never look inside a row.
 
+A pool layer is not a model layer: `L` is what the model says it is.
+`models/sambay.py` keeps ONE layer of the full kind that its one full
+attention layer writes and eight of its layers read (the kernel's layer
+index is 0 for all of them, and `plan` runs once a kind a tick).
+
 Leaves are of one of two KINDS.  `full` (every leaf of every model but
-one): a sequence holds a block for each `bs` of its rows, in a table as
+two): a sequence holds a block for each `bs` of its rows, in a table as
 wide as `max_seq_len`, block `t // bs` for position t.  `window`
 (`window_kind(config) -> (W, leaf names)`, None for a model that has
 none): leaves of layers whose query at p reads the keys p - W + 1 .. p
@@ -38,7 +43,10 @@ longer than a bucket goes into its slot chunk by chunk.
         -> (normed hidden [1, Pb, D], rows {leaf: [L, Pb, ...]})
         `hist` {leaf: [L, S_pad, ...]} is the slot's gathered history
         (rows >= start are stale); the Pb tokens sit at start..; only
-        the first `n_real` of them are real.
+        the first `n_real` of them are real.  The engine reads row
+        `n_real - 1` of the hidden and nothing else of it, so a model
+        may hand back that row ALONE, [1, 1, D] (the shape says which;
+        `models/sambay.py` runs its second half for that one row).
     decode(params, pools, tables, tok [B], pos [B], config, active)
         -> (logits [B, V], pools, counts)
         `counts` is a dict of per-call integer counters that the engine
@@ -53,7 +61,8 @@ longer than a bucket goes into its slot chunk by chunk.
     length: a row a slot a layer that keeps one (the delta-rule state
     and convolution tail of `models/kimi_linear.py` and
     `models/gdn_hybrid.py`, the convolution tail of
-    `models/conv_moe.py`).
+    `models/conv_moe.py`, the selective scan's state and tail of
+    `models/sambay.py`).
     A model that has it takes and returns it beside the pool:
         prefill(..., n_real, state) -> (hidden, rows, state), `state`
         {leaf: [L', ...]} ONE slot's rows, as they stood after the
@@ -64,7 +73,11 @@ longer than a bucket goes into its slot chunk by chunk.
     The engine keeps such a model's state by slot and a sequence in the
     slot it was admitted to: nothing that moves rows without the state
     (prefix reuse, spill, export, adopt, preemption, speculation) is
-    offered for it.
+    offered for it.  A model may have window leaves AND a state by slot
+    (`models/sambay.py`): it gets both tables and the state wherever
+    this says so, a chunk of its prompt hands ring, state and tails on
+    in the slot, and a slot's release gives the ring back and leaves
+    the state for the next admission to zero.
     quantize_int8(params); verify(params, pools, tables, tok [B, K],
     pos [B], config, active) -> (logits [B, K, V], pools), the
     speculative target's step; draft, what a model needs to BE a

@@ -190,28 +190,30 @@ def _seen(qpos, kpos, window):
     return ok if window is None else ok & (k > q - window)
 
 
-def _masked_attention(q, k, v, mask):
+def _masked_attention(q, k, v, mask, scale=None):
     B, Q, H, hd = q.shape
     kvh = k.shape[2]
     qg = q.reshape(B, Q, kvh, H // kvh, hd)
     s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k).astype(jnp.float32) \
-        * (1.0 / math.sqrt(hd))
+        * (scale or 1.0 / math.sqrt(hd))
     s = jnp.where(mask[:, None, None], s, _MASK)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bgrqk,bkgd->bqgrd", p, v).reshape(B, Q, H, hd)
 
 
-def blockwise_attention(q, k, v, qpos, kpos0, lo, hi, window, block):
+def blockwise_attention(q, k, v, qpos, kpos0, lo, hi, window, block,
+                        scale=None):
     """ONE sequence: q [Q, H, hd] at positions qpos [Q] against k, v
     [S, kvH, hd] whose row i is the key of position kpos0 + i, taken
     `block` rows a step over steps lo .. hi - 1 (traced: rows outside
     them are never read) under `_seen`'s mask, in an online softmax
-    (float32 running max, sum and accumulator).  [Q, H, hd]."""
+    (float32 running max, sum and accumulator).  [Q, H, hd].  `scale`:
+    the scores' factor where it is not `hd ** -0.5`."""
     Q, H, hd = q.shape
     S, kvh = k.shape[:2]
     kb = math.gcd(S, block)
     qg = q.reshape(Q, kvh, H // kvh, hd)
-    scale = 1.0 / math.sqrt(hd)
+    scale = scale or 1.0 / math.sqrt(hd)
 
     def step(i, carry):
         m, l, acc = carry

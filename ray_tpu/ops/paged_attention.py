@@ -389,7 +389,8 @@ def _call(q, pools, layer, scalars, *, kvh, n_heads, n_q, scale,
 def paged_attention(q: jax.Array, k_pool: jax.Array,
                     v_pool: Optional[jax.Array], layer: jax.Array,
                     scalars, *, chunk: int = CHUNK_BLOCKS,
-                    window: Optional[int] = None) -> jax.Array:
+                    window: Optional[int] = None,
+                    scale: Optional[float] = None) -> jax.Array:
     """q [B, Q, H, D] (rotated) against layer `layer` of the stacked
     pools [L, NB, bs, kvH, D], through `scalars` = `plan(tables, qpos,
     active, bs, chunk)`: [B, Q, H, D], zeros for a dead sequence.  Query
@@ -404,8 +405,13 @@ def paged_attention(q: jax.Array, k_pool: jax.Array,
     side by side in one row (the module's docstring, "Few KV heads").
 
     `window`: query b sees keys in (qpos[b] - window, qpos[b]] alone and
-    the table is a ring (`plan(..., window=window)` made the scalars)."""
+    the table is a ring (`plan(..., window=window)` made the scalars).
+
+    `scale`: the scores' factor where it is not `D ** -0.5`
+    (`models/sambay.py`: a pair of heads of 64 laid as one head of 128,
+    each query in the lanes of its own key of a zero row)."""
     B, Q, H, D = q.shape
+    scale = scale or 1.0 / math.sqrt(D)
     if k_pool.ndim == 4:
         W = k_pool.shape[-1]
         kvh = W // D
@@ -415,7 +421,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array,
                            q.reshape(B, Q, kvh, H // kvh, D), place)
         out = _call(
             q_row.reshape(B, Q * H, W), [k_pool, v_pool], layer, scalars,
-            kvh=1, n_heads=H, n_q=Q, scale=1.0 / math.sqrt(D), out_width=W,
+            kvh=1, n_heads=H, n_q=Q, scale=scale, out_width=W,
             chunk=chunk, window=window)
         return jnp.einsum(
             "bqgrkd,gk->bqgrd",
@@ -433,7 +439,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array,
     out = _call(
         q.reshape(B, Q * H, W),
         [pool.reshape(L, NB, bs * kvh, W) for pool in pools], layer,
-        scalars, kvh=kvh, n_heads=H, n_q=Q, scale=1.0 / math.sqrt(D),
+        scalars, kvh=kvh, n_heads=H, n_q=Q, scale=scale,
         out_width=W, chunk=chunk, window=window, heads_major=heads_major)
     return out.reshape(B, Q, H, W)[..., W - D:]
 

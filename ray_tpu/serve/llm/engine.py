@@ -789,8 +789,10 @@ class LLMEngine:
             rows[name].astype(pool.dtype).reshape(
                 (pool.shape[0], Pb // bs, bs) + pool.shape[3:]))
             for name, pool in pools.items()}
-        x_last = jax.lax.dynamic_index_in_dim(
-            hidden[0], suffix_len - 1, axis=0, keepdims=False)
+        # [1, Pb, D], or the last real row alone (models/serving.py)
+        x_last = hidden[0, 0] if hidden.shape[1] == 1 else \
+            jax.lax.dynamic_index_in_dim(
+                hidden[0], suffix_len - 1, axis=0, keepdims=False)
         logits = jax.lax.dot_general(
             x_last[None], self._model.head_weight(params, c),
             (((1,), (0,)), ((), ())),

@@ -176,7 +176,10 @@ def program(key, build):
 # `assistant-decode-moe`'s tick are PR 42's tree's (efba6a6), kanana's
 # insert PR 45's (`_History.attend` walks the history in tiles); the rest
 # were pinned by PR 46 at PR 45's tree, where each equal-text guard that a
-# pin replaced was run one last time and held (CHANGES.md, PR 46).
+# pin replaced was run one last time and held (CHANGES.md, PR 46);
+# `think-decode-ssm-yoco`'s two are PR 47's, which brought the cell (and
+# left every other text as it was: the engine's one new branch is on a
+# shape).
 PROGRAM_TEXT_SHA256 = {
     ("chat-decode", "tick"):
         "48a91f54a548addd9d951f33258125cd66601f6eb5de512b9f23800388b2ae93",
@@ -206,6 +209,10 @@ PROGRAM_TEXT_SHA256 = {
         "cde3ce8aace9a4c1aced034762e8612cbc39f0080091b150a14a97330fa938b7",
     ("longform-decode-zero-moe", "insert"):
         "a11a11354453fee562dd3d3b4e8f8f721faf28d29ff147724b0828cd39aaf7b5",
+    ("think-decode-ssm-yoco", "tick"):
+        "99a77e55f9f092c0eb144ba97df9bcc5d437802ead9bc1c5f5e8432a6194d87a",
+    ("think-decode-ssm-yoco", "insert"):
+        "5c14d60424211c58a2426a2ca8413dc184c1048c046f547d6516cdf605860e2b",
     ("two small layers", "train step, scope names apart"):
         "1d700902d1c682aaec9e4b41afc84286eb99ba0c4758f7046d1be38b1848714c",
     ("two small layers", "train step, its kernels"):

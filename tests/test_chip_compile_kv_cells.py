@@ -1,6 +1,7 @@
 """Deviceless v5e compiles of the benchmark's serving cells that keep K
 and V heads in paged pools (`compose-decode-conv-moe`,
-`mixed-decode-window-moe`, `reason-decode-gdn-hybrid`): the decode tick
+`mixed-decode-window-moe`, `reason-decode-gdn-hybrid`,
+`think-decode-ssm-yoco`): the decode tick
 and the largest insert of each, as the chip runs them, at the geometry
 its files state.  `chip_programs.py` has the rules these files keep, the
 fixtures, the one compile a program (`cell_program`, which also holds
@@ -209,3 +210,58 @@ def test_gdn_hybrid_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
 def test_delta_rule_inserts_hold_no_chunk_by_chunk_by_channel_tensor(
         one_chip, on_tpu, cell, dk, temp_gib):
     delta_rule_insert_holds_no_channel_tensor(cell, dk, temp_gib)
+
+
+@pytest.mark.parametrize("program", ["tick", "insert"])
+def test_sambay_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
+    """The engine's decode tick (96 slots x 12288) and its largest
+    insert (1024) at the published widths and the WHOLE depth: they
+    compile for v5e; `paged_attention` answers "kernel" for both kinds
+    of pool and the scan's state engages its kernels; the tick holds
+    three paged-attention call sites (the window layers' inside the
+    self pairs' scan, the full layer's, the cross layers' inside
+    theirs) and two of the scan step (the self pairs', the middle
+    pair's), the insert as many of the scan kernel and no paged
+    attention; neither builds a padded view of a pool; both kinds of
+    pool AND the slots' state are updated in place and nothing is
+    copied to be re-tiled (temporaries under 0.15 and 0.5 GiB beside
+    3.1 + 2.5 GB of pools and 0.31 of state); and arguments +
+    temporaries fit HBM with the 7.7 GB of weights."""
+    from ray_tpu.ops import selective_scan
+
+    eng = serving_cell("think-decode-ssm-yoco")
+    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+                                eng.published)
+    pools = eng.pools
+    assert (published["num_hidden_layers"], published["hidden_size"],
+            published["vocab_size"], published["reduced"], mc.n_self_pairs,
+            mc.n_cross_pairs, mc.n_ssm_layers, mc.window, mc.kv_width,
+            ec.num_slots, ec.max_seq_len, eng._ring.ring) \
+        == (32, 2560, 200064, {}, 8, 7, 9, 512, 1280, 96, 12288, 96)
+    assert model.paged_attention(pools) == "kernel"
+    assert pools["k"].shape == (1, ec.pool_blocks, 16, 1280)
+    assert pools["v_w"].shape == (8, ec.num_window_blocks, 16, 1280)
+    assert ec.pool_blocks * 16 >= 600_000
+    state, = slot_state(eng)
+    assert state["h"].shape == (9, 96, 16, 40, 128)     # no padded lane
+    assert selective_scan.engages(state["h"])
+    compiled = cell_program(eng.name, program)
+    text = compiled.text
+    if program == "tick":
+        assert compiled.plain.count("paged_attention") >= 3
+        assert text.count("ssm_step") >= 2 and "ssm_scan" not in text
+        row = pools["k"].shape[3:]
+        padded = {(ec.num_slots, n * ec.kv_block_size) + row
+                  for n in (ec.max_blocks_per_slot, eng._ring.ring)}
+        assert not any(padded & shapes for _, shapes in results_of(text))
+    else:
+        assert text.count("ssm_scan") >= 2 and "ssm_step" not in text
+        assert "paged_attention" not in compiled.plain
+    m = compiled.memory
+    kept = sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in list(pools.values()) + list(state.values()))
+    print(program, "GiB", compiled.hbm_gib, "temp",
+          m.temp_size_in_bytes / GIB, "args", m.argument_size_in_bytes / GIB)
+    assert m.alias_size_in_bytes >= kept                # all in place
+    assert m.temp_size_in_bytes < (0.15 if program == "tick" else 0.5) * GIB
+    assert compiled.hbm_gib < V5E_HBM_GIB - 0.5
