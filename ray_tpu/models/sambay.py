@@ -75,6 +75,7 @@ from ray_tpu.models.llama import embed_lookup, rms_norm
 from ray_tpu.models.serving import ServingFns
 from ray_tpu.models.window_moe import (
     WINDOW_LEAVES, _masked_attention, _seen, blockwise_attention,
+    piece_walk, walk_tiles,
 )
 from ray_tpu.ops import kda
 from ray_tpu.ops import paged_attention as paged
@@ -356,9 +357,12 @@ class _History:
     full kind's [1, S_pad, W] by position, the window kind's [Lw, R, W]
     a RING in which position t lies at row t % R.  The chunk sits at
     `start`..; `kv` holds the window layers' new rows for the engine to
-    scatter, and this keeps the full layer's.  Both kinds attend a block
-    of keys at a time; the full layer and the cross layers for the last
-    real row alone."""
+    scatter, and this keeps the full layer's.  Both kinds attend through
+    `window_moe.blockwise_attention`: a window layer's whole piece by
+    the kernel's tiles where it engages (a block of keys at a time
+    elsewhere, the published window of 512 among it:
+    `ops.attention.PREFILL_MIN_K`), the full layer and the cross layers
+    for the last real row alone, which is the loop everywhere."""
 
     def __init__(self, c, hist, start, Pb, n_real):
         self.c, self.hist, self.start, self.n_real = c, hist, start, n_real
@@ -389,11 +393,11 @@ class _History:
             # chunk's own
             before = (start - W + jnp.arange(W)) % ring.shape[1]
             keys.append(self._heads(jnp.concatenate([ring[l][before], x])))
-        step = math.gcd(W + Pb, c.prefill_key_block)
+        kpos0, lo, hi = piece_walk("window", start, Pb, W + Pb, W,
+                                   c.prefill_key_block, most=jnp.maximum)
         out = blockwise_attention(
-            q[0], *keys, self.qpos, start - W,
-            jnp.maximum(W - start, 0) // step, (W + Pb) // step, W,
-            c.prefill_key_block, c.scale)
+            q[0], *keys, self.qpos, kpos0, lo, hi, W, c.prefill_key_block,
+            c.scale)
         return out[None], kv
 
     def shared(self, kv, q, k, v):
@@ -777,6 +781,17 @@ def _paged_attention(pools) -> str:
     return "kernel" if both else "gather"
 
 
+def insert_attention(config: SambaYConfig, start: int, bucket: int,
+                     max_seq_len: int):
+    """`window_moe.walk_tiles` of one piece through the window layers
+    (heads laid as pairs of 128 lanes); the full layer and the cross
+    layers attend for one row, which is the loop's."""
+    c = config
+    return walk_tiles({"window": c.n_self_pairs}, start, bucket,
+                      max_seq_len, c.window, c.prefill_key_block,
+                      2 * c.head_dim)
+
+
 _SERVING = ServingFns(
     name="Mamba + differential window attention, one shared full layer, "
          "gated memory units (models/sambay.py)",
@@ -784,4 +799,5 @@ _SERVING = ServingFns(
     prefill=prefill_paged, decode=decode_step_paged,
     head_weight=lm_head_weight, init_counts=init_counts,
     init_slot_state=init_slot_state, window_kind=window_kind,
-    quantize_int8=quantize_int8, paged_attention=_paged_attention)
+    quantize_int8=quantize_int8, paged_attention=_paged_attention,
+    insert_attention=insert_attention)

@@ -86,7 +86,15 @@ longer than a bucket goes into its slot chunk by chunk.
     these pools on this backend (`engine.stats()` shows it);
     grouped_matmul(config, num_slots) -> "kernel" | "xla", the same for
     the experts' grouped products at the tick's shape
-    (`models/moe.py::serving_grouped_path`).
+    (`models/moe.py::serving_grouped_path`);
+    insert_attention(config, start, bucket, max_seq_len) -> (form,
+    tiles run, tiles dense), host arithmetic for a model whose
+    `prefill` walks its history through
+    `models/window_moe.py::blockwise_attention`: "kernel" | "loop",
+    which form a piece of `bucket` rows compiles its attention to on
+    this backend, and of a piece at `start` the (query, key) tiles the
+    kernel's bounds let through over those of the rectangles the loop
+    multiplies (`window_moe.walk_tiles`; `engine.stats()` sums them).
 """
 
 from __future__ import annotations
@@ -134,3 +142,4 @@ class ServingFns(NamedTuple):
     verify: Optional[Callable[..., Any]] = None
     paged_attention: Optional[Callable[..., str]] = None
     grouped_matmul: Optional[Callable[..., str]] = None
+    insert_attention: Optional[Callable[..., Any]] = None

@@ -25,11 +25,16 @@ the head is its own matrix and the logits are not scaled.
   that kind whatever its length.  The decode tick plans
   `ops/paged_attention.py` twice, once a kind (the window form for the
   ring), outside the layer loop.
-- **Prefill attends blockwise over the keys** (an online-softmax loop in
-  XLA): float32 scores `[H, Pb, S_pad]` over a long history would not
-  fit beside the weights.  A full layer walks its gathered history up to
-  the chunk's end; a window layer walks the `window` rows before the
-  chunk, taken out of its ring, and the chunk's own.
+- **Prefill attends blockwise over the keys** under an online softmax:
+  float32 scores `[H, Pb, S_pad]` over a long history would not fit
+  beside the weights.  A full layer walks its gathered history up to the
+  chunk's end; a window layer walks the `window` rows before the chunk,
+  taken out of its ring, and the chunk's own (`piece_walk`).  By one of
+  two paths, chosen by backend and shape alone
+  (`ops.attention.prefill_engages`, inside `blockwise_attention`): the
+  Pallas forward `ops.attention.flash_prefill`, whose scores stay on
+  the chip and which skips every (query, key) tile no query sees, or
+  the XLA loop over key blocks, which is also the kernel's reference.
 - **Feed-forward**: `n_dense_layers` leading SwiGLU layers, then ONE
   shared SwiGLU every token passes through plus
   `models/moe.py::dropless_moe` over all `n_experts` under the
@@ -59,6 +64,7 @@ from ray_tpu.models.moe import (
     dropless_moe, serving_grouped_path, sigmoid_bias_top_k,
 )
 from ray_tpu.models.serving import ServingFns
+from ray_tpu.ops import attention as flash
 from ray_tpu.ops import paged_attention as paged
 
 _MASK = -1e30
@@ -208,12 +214,25 @@ def blockwise_attention(q, k, v, qpos, kpos0, lo, hi, window, block,
     `block` rows a step over steps lo .. hi - 1 (traced: rows outside
     them are never read) under `_seen`'s mask, in an online softmax
     (float32 running max, sum and accumulator).  [Q, H, hd].  `scale`:
-    the scores' factor where it is not `hd ** -0.5`."""
+    the scores' factor where it is not `hd ** -0.5`.
+
+    Several queries are consecutive positions from qpos[0] (every
+    caller's are), and where `ops.attention.prefill_engages` says so
+    they go through the kernel, the same steps' rows as its bounds:
+    this is the one place that chooses, for every model that walks a
+    history so.  The loop below is the path everywhere else (off TPU,
+    the tiny models' heads, one query) and what the kernel is tested
+    against."""
     Q, H, hd = q.shape
     S, kvh = k.shape[:2]
     kb = math.gcd(S, block)
-    qg = q.reshape(Q, kvh, H // kvh, hd)
     scale = scale or 1.0 / math.sqrt(hd)
+    if flash.prefill_engages(Q, hd, S):
+        # a position before the sequence's first is no key
+        return flash.flash_prefill(
+            q, k, v, qpos[0] - kpos0, jnp.maximum(lo * kb, -kpos0), hi * kb,
+            window=window, scale=scale)
+    qg = q.reshape(Q, kvh, H // kvh, hd)
 
     def step(i, carry):
         m, l, acc = carry
@@ -240,6 +259,42 @@ def blockwise_attention(q, k, v, qpos, kpos0, lo, hi, window, block,
     return jnp.moveaxis(acc / l, 2, 0).reshape(Q, H, hd).astype(q.dtype)
 
 
+def piece_walk(kind, start, Pb, S, window, block, most=max):
+    """What `blockwise_attention` is told of a piece of Pb rows at
+    `start` over a `kind` layer's S key rows (the full kind's gathered
+    history by position; the window kind's `window` rows before the
+    piece, then the piece's own): (the position of key row 0, the first
+    step, one past the last), steps of gcd(S, block) rows.  `start` a
+    Python integer, or traced with `most=jnp.maximum`."""
+    step = math.gcd(S, block)
+    if kind == "full":
+        return 0, 0, -(-(start + Pb) // step)
+    return start - window, most(window - start, 0) // step, S // step
+
+
+def walk_tiles(layers, start, Pb, S_pad, window, block, hd):
+    """Host arithmetic for `engine.stats()`: a piece of Pb rows at
+    `start` through `layers` ({kind: how many}; S_pad rows of gathered
+    history a full layer; heads `hd` wide) -> (which form its attention
+    compiled to, "kernel" | "loop"; the (query, key) tiles
+    `flash_prefill`'s bounds let through; the tiles of the rectangles
+    the loop multiplies, every query against every row of its steps),
+    summed over the layers."""
+    run = dense = 0
+    kernel = True
+    for kind, n in layers.items():
+        S = S_pad if kind == "full" else window + Pb
+        kpos0, lo, hi = piece_walk(kind, start, Pb, S, window, block)
+        step = math.gcd(S, block)
+        bq, bk = flash._prefill_blocks(Pb, S)
+        run += n * flash.prefill_tiles(
+            Pb, S, start - kpos0, max(lo * step, -kpos0), hi * step,
+            window if kind == "window" else None)
+        dense += n * (Pb // bq) * -(-(hi - lo) * step // bk)
+        kernel = kernel and flash.prefill_engages(Pb, hd, S)
+    return "kernel" if kernel else "loop", run, dense
+
+
 # ---------------------------------------------------------------------------
 # The layers' caches: where a layer's new K and V rows go and which
 # rows its queries see.  `attend(c, kind, l, q, k, v)` for layer l OF
@@ -263,7 +318,9 @@ class _History:
     full kind's [Lf, S_pad, kvH hd] by position, the window kind's
     [Lw, R, kvH hd] a RING in which position t lies at row t % R.  The
     chunk sits at `start`..; its rows are kept for the engine to
-    scatter.  Both kinds attend a block of keys at a time."""
+    scatter.  Both kinds attend through `blockwise_attention`: the
+    kernel's tiles where it engages, a block of keys at a time
+    elsewhere."""
 
     def __init__(self, hist, start, qpos):
         self.hist, self.start, self.qpos = hist, start, qpos
@@ -276,33 +333,31 @@ class _History:
         new = [x[0].reshape(Pb, -1).astype(dt) for x in (k, v)]
         for name, x in zip(names, new):
             self.rows[name].append(x)
-        kb = c.prefill_key_block
 
         def heads(x):
             return x.reshape(x.shape[0], c.n_kv_heads, c.head_dim).astype(
                 c.dtype)
 
         if kind == "full":
-            keys = [heads(lax.dynamic_update_slice(
-                self.hist[name][l], x, (start, 0)))
+            keys = [lax.dynamic_update_slice(
+                self.hist[name][l], x, (start, 0))
                 for name, x in zip(names, new)]
-            S = keys[0].shape[0]
-            out = blockwise_attention(
-                q[0], *keys, self.qpos, 0, 0,
-                -(-(start + Pb) // math.gcd(S, kb)), None, kb)
+            window = None
         else:
             # the `window` rows before the chunk, out of the ring (a
             # position before the sequence's first is masked), then the
             # chunk's own
-            W = c.window
+            window = c.window
             R = self.hist[names[0]].shape[1]
-            before = (start - W + jnp.arange(W)) % R
-            keys = [heads(jnp.concatenate([self.hist[name][l][before], x]))
+            before = (start - window + jnp.arange(window)) % R
+            keys = [jnp.concatenate([self.hist[name][l][before], x])
                     for name, x in zip(names, new)]
-            step = math.gcd(W + Pb, kb)
-            out = blockwise_attention(
-                q[0], *keys, self.qpos, start - W,
-                jnp.maximum(W - start, 0) // step, (W + Pb) // step, W, kb)
+        kpos0, lo, hi = piece_walk(kind, start, Pb, keys[0].shape[0],
+                                   c.window, c.prefill_key_block,
+                                   most=jnp.maximum)
+        out = blockwise_attention(
+            q[0], *map(heads, keys), self.qpos, kpos0, lo, hi, window,
+            c.prefill_key_block)
         return out[None]
 
     def stacked(self):
@@ -538,6 +593,15 @@ def init_counts(config: WindowMoEConfig) -> Dict[str, jax.Array]:
             "ticks": jnp.zeros((), jnp.int32)}
 
 
+def insert_attention(config: WindowMoEConfig, start: int, bucket: int,
+                     max_seq_len: int):
+    """`walk_tiles` of one piece through every layer."""
+    c = config
+    return walk_tiles({"full": c.n_full_layers, "window": c.n_window_layers},
+                      start, bucket, max_seq_len, c.window,
+                      c.prefill_key_block, c.head_dim)
+
+
 def _paged_attention(pools) -> str:
     both = paged.engages(pools["k"]) and paged.engages(pools["k_w"])
     return "kernel" if both else "gather"
@@ -550,4 +614,4 @@ _SERVING = ServingFns(
     prefill=prefill_paged, decode=decode_step_paged,
     head_weight=lm_head_weight, init_counts=init_counts,
     window_kind=window_kind, paged_attention=_paged_attention,
-    grouped_matmul=serving_grouped_path)
+    grouped_matmul=serving_grouped_path, insert_attention=insert_attention)

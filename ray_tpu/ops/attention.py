@@ -1,4 +1,5 @@
-"""Flash attention — pallas TPU kernels, forward + backward.
+"""Flash attention — pallas TPU kernels: the training pair (forward +
+backward) and the serving insert's forward over a gathered history.
 
 The hot op of the flagship model (net-new vs the reference, which has no
 in-repo kernels — SURVEY §5 long-context). FlashAttention-2 style:
@@ -12,6 +13,15 @@ in-repo kernels — SURVEY §5 long-context). FlashAttention-2 style:
       blocks. Peak memory stays O(S * D) — this is what lets batch and
       sequence scale on a 16G v5e chip (the XLA fallback's O(S^2) f32
       probabilities OOM first).
+- `flash_prefill`: the forward alone for ONE sequence whose queries sit
+  at an offset into a longer key array (a piece of a prompt against the
+  slot's gathered history), under a causal mask and, for a
+  sliding-window layer, a window.  Grouped-query heads share a key tile
+  as rows of one query tile, the bounds are traced scalars (a bucket is
+  one program whatever the piece's start), and a (query, key) tile pair
+  that no query of the block can see is neither copied nor multiplied.
+  `models/window_moe.py::blockwise_attention` is its XLA form, its
+  reference, and the path wherever `prefill_engages` says no.
 
 Layout: [B, S, H, D] public API (matches models/llama.py); kernels run in
 [B, H, S, D]. Non-TPU platforms fall back to the XLA path end to end.
@@ -26,6 +36,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 NEG_INF = -1e30
 _LANE = 128
@@ -441,3 +452,224 @@ flash_attention.defvjp(
     lambda q, k, v, causal: _flash_fwd(q, k, v, causal),
     _flash_bwd,
 )
+
+
+# ---------------------------------------------------------------------------
+# Prefill over a gathered history: one sequence, a query offset, a window
+# ---------------------------------------------------------------------------
+
+# Queries and keys a tile, the most (PERF.md section 6, PR 48: swept on
+# the chip at Trinity-Mini's widths, 8 heads a group over 2048 queries).
+# The kernel multiplies a head's [queries, hd] against the key tile at a
+# time, so the queries are the matrix unit's rows a key tile load: 512
+# took 0.82-0.86 of 256's time and 128 1.19-1.26 of it.  Keys: 1024 took
+# 0.59-0.76 of 512's time (a step pays for the rescaling of the
+# accumulators whatever its keys); 2048 is 9% faster over 16 k keys and
+# 15-20% slower under a window of 2048, whose edge it skips more coarsely.
+PREFILL_BLOCK_Q = 512
+PREFILL_BLOCK_K = 1024
+
+
+def _prefill_blocks(Q: int, S: int) -> Tuple[int, int]:
+    """(queries, keys) a tile for Q queries over S key rows: a power of
+    two of queries that divides Q, the largest whole number of lane rows
+    of keys that divides S (shapes that do not engage are still counted
+    by tiles: of what divides them)."""
+    bk = max((b for b in range(_LANE, PREFILL_BLOCK_K + 1, _LANE)
+              if S % b == 0), default=math.gcd(S, PREFILL_BLOCK_K))
+    return math.gcd(Q, PREFILL_BLOCK_Q), bk
+
+
+# The least queries and key rows a call has to have.  Under them the XLA
+# loop's score blocks are small enough that it runs as fast (on the chip,
+# a layer alone: Trinity-Mini's 512 bucket 0.27-0.46 ms as a loop and
+# 0.35-0.49 as the kernel, its 256 bucket 0.21 both ways;
+# Phi-4-mini-flash's 1024 queries over 1536 rows 0.31-0.41 against 0.50),
+# and every (bucket, kind) the kernel engages for costs each process
+# half a second of tracing and lowering at its start, compile cache
+# warm or not (PERF.md section 6, PR 48).
+PREFILL_MIN_Q = 1024
+PREFILL_MIN_K = 2048
+
+
+def prefill_engages(Q: int, hd: int, S: int) -> bool:
+    """Whether Q queries of heads `hd` wide over S key rows go through
+    `flash_prefill`: `_use_kernel`'s rule for the backend (a TPU always,
+    off TPU only when a test forces the interpreter) and shapes that
+    tile and pay, heads of whole lane rows and at least `PREFILL_MIN_Q`
+    queries and `PREFILL_MIN_K` key rows in whole tiles of 128.  One
+    query (a cross layer's last row), the small buckets and the tiny
+    models' heads of 16 keep the loop."""
+    tiles = hd % _LANE == 0 and Q % _LANE == 0 and S % _LANE == 0
+    pays = Q >= PREFILL_MIN_Q and S >= PREFILL_MIN_K
+    return tiles and pays and (_on_tpu() or FORCE_PALLAS_INTERPRET)
+
+
+def _prefill_span(qi, off, lo, hi, *, bq, bk, window, least=min, most=max):
+    """(first, last) key tile that some query of block `qi` sees, none
+    where last < first.  Query row q sits at key row q + off and sees
+    key row i when lo <= i < hi, i <= q + off and, under a window,
+    i > q + off - window.  Python integers, or traced ones with
+    `jnp.minimum` / `jnp.maximum` (the kernel, its index maps)."""
+    top = least(qi * bq + bq - 1 + off, hi - 1)
+    low = lo if window is None else most(lo, qi * bq + off - window + 1)
+    return low // bk, top // bk
+
+
+def prefill_tiles(Q: int, S: int, off: int, lo: int, hi: int,
+                  window: Optional[int]) -> int:
+    """The (query block, key tile) pairs `flash_prefill`'s bounds let
+    through at these sizes: host arithmetic over the kernel's own
+    `_prefill_span`."""
+    bq, bk = _prefill_blocks(Q, S)
+    spans = (_prefill_span(qi, off, max(lo, 0), min(hi, S), bq=bq, bk=bk,
+                           window=window) for qi in range(Q // bq))
+    return sum(max(last - first + 1, 0) for first, last in spans)
+
+
+def _prefill_kernel(s_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                    acc_ref, *, scale, window, bq, bk):
+    """grid (KV head, query block, key step): the query tile holds the
+    group's heads, [r, bq, hd]; step ki takes key tile first + ki of the
+    block's span and folds it into each head's running maximum, sum and
+    accumulator, a head a loop step (a head's `[bq, bk]` scores at a
+    time; the r heads share the tile's mask).  All r heads as rows of
+    ONE product read the same at start 0 and up to 14% faster over 16 k
+    keys on the chip, for eight times the code: 3-5 s of compile a
+    (bucket, kind) against under 1 (PERF.md section 6, PR 48)."""
+    from jax.experimental import pallas as pl
+
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    off, lo, hi = s_ref[0], s_ref[1], s_ref[2]
+    first, last = _prefill_span(qi, off, lo, hi, bq=bq, bk=bk, window=window,
+                                least=jnp.minimum, most=jnp.maximum)
+    tile = first + ki
+    r, _, hd = acc_ref.shape
+
+    @pl.when(ki == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # the key rows the block's first query and the tile's first key sit at
+    q0, k0 = qi * bq + off, tile * bk
+    # every query of the block sees every key of the tile: no mask
+    whole = (k0 + bk - 1 <= q0) & (k0 >= lo) & (k0 + bk <= hi)
+    if window is not None:
+        whole = whole & (k0 > q0 + bq - 1 - window)
+
+    def fold(masked):
+        k, v = k_ref[...], v_ref[...]
+        seen = None
+        if masked:
+            qrow = q0 + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            krow = k0 + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            seen = (krow <= qrow) & (krow >= lo) & (krow < hi)
+            if window is not None:
+                seen = seen & (krow > qrow - window)
+
+        def head(j, _):
+            s = lax.dot_general(
+                q_ref[j], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if masked:
+                s = jnp.where(seen, s, NEG_INF)
+            m_prev = m_ref[j][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            if masked:
+                # a row that has seen no key yet: s - m_new is 0 there
+                p = jnp.where(seen, p, 0.0)
+            l_new = alpha * l_ref[j][:, :1] + jnp.sum(
+                p, axis=1, keepdims=True)
+            m_ref[j] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[j] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+            acc_ref[j] = acc_ref[j] * alpha + lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        lax.fori_loop(0, r, head, None)
+
+    run = tile <= last
+    pl.when(run & whole)(lambda: fold(False))
+    pl.when(run & jnp.logical_not(whole))(lambda: fold(True))
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finalize():
+        l = l_ref[...][:, :, :1]
+        o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+
+# Jitted so that the call sites of an unrolled layer loop lower the kernel
+# once a kind (`ops/paged_attention.py::paged_latent_attention`).
+@functools.partial(jax.jit,
+                   static_argnames=("window", "scale", "interpret"))
+def _flash_prefill(q, k, v, off, lo, hi, *, window, scale, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    Q, H, hd = q.shape
+    S, kvh = k.shape[:2]
+    r = H // kvh
+    bq, bk = _prefill_blocks(Q, S)
+    tiles = S // bk
+    # key tiles a query block may take, the grid's static bound: all of
+    # them, or the most that window + bq - 1 consecutive rows straddle
+    steps = tiles if window is None else min(
+        tiles, (window + bq - 2) // bk + 2)
+    span = functools.partial(_prefill_span, bq=bq, bk=bk, window=window,
+                             least=jnp.minimum, most=jnp.maximum)
+
+    def key_tile(g, qi, ki, s):
+        # past the block's last tile the index stands still: no copy
+        first, last = span(qi, s[0], s[1], s[2])
+        return jnp.clip(jnp.minimum(first + ki, last), 0, tiles - 1), g
+
+    def query_tile(g, qi, ki, s):
+        return g, 0, qi, 0
+
+    bounds = jnp.stack([off, jnp.maximum(lo, 0), jnp.minimum(hi, S)]
+                       ).astype(jnp.int32)
+    # [Q, kvH r, hd] -> [kvH, r, Q, hd]; K and V rows as they lie, a KV
+    # head a block of `hd` lanes
+    heads = jnp.transpose(q.reshape(Q, kvh, r, hd), (1, 2, 0, 3))
+    out = pl.pallas_call(
+        functools.partial(_prefill_kernel, scale=scale, window=window,
+                          bq=bq, bk=bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(kvh, Q // bq, steps),
+            in_specs=[pl.BlockSpec((None, r, bq, hd), query_tile),
+                      pl.BlockSpec((bk, hd), key_tile),
+                      pl.BlockSpec((bk, hd), key_tile)],
+            out_specs=pl.BlockSpec((None, r, bq, hd), query_tile),
+            scratch_shapes=[pltpu.VMEM((r, bq, _LANE), jnp.float32),
+                            pltpu.VMEM((r, bq, _LANE), jnp.float32),
+                            pltpu.VMEM((r, bq, hd), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(heads.shape, q.dtype),
+        interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 2 ** 20),
+        name="flash_prefill",
+    )(bounds, heads, k.reshape(S, kvh * hd), v.reshape(S, kvh * hd))
+    return jnp.transpose(out, (2, 0, 1, 3)).reshape(Q, H, hd)
+
+
+def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, off, lo, hi, *,
+                  window: Optional[int] = None,
+                  scale: Optional[float] = None) -> jax.Array:
+    """ONE sequence: q [Q, H, hd], query j at key row j + `off`, against
+    k, v [S, kvH, hd]; head h reads KV head h // (H / kvH).  Query j sees
+    key row i when lo <= i < hi, i <= j + off and, with `window`,
+    i > j + off - window; `off`, `lo`, `hi` are traced scalars.  bf16 (or
+    whatever the operands are) products, float32 scores, softmax and
+    accumulation; [Q, H, hd], zeros for a query that sees no key.
+    Shapes as `prefill_engages` asks."""
+    return _flash_prefill(
+        q, k, v, off, lo, hi, window=window,
+        scale=scale or 1.0 / math.sqrt(q.shape[-1]),
+        interpret=not _on_tpu())

@@ -583,6 +583,12 @@ class LLMEngine:
         # and the rows of the padded history the insert is handed
         self._insert_keys_walked = 0
         self._insert_keys_padded = 0
+        # and, of a model whose inserts attend by tiles
+        # (`ServingFns.insert_attention`), the (query, key) tiles its
+        # kernel's bounds let through and those of the rectangles its
+        # loop multiplies
+        self._insert_attn_tiles_run = 0
+        self._insert_attn_tiles_dense = 0
         self._slot_reuses = 0
         self._cancelled: set = set()    # request ids, guarded by _lock
         self._admit_blocked = False     # interactive admission starved
@@ -1304,6 +1310,11 @@ class LLMEngine:
         self._insert_keys_walked += min(
             -(-(start + bucket) // HISTORY_TILE) * HISTORY_TILE, S)
         self._insert_keys_padded += S
+        if self._model.insert_attention:
+            _, run, dense = self._model.insert_attention(
+                self.model_config, start, bucket, S)
+            self._insert_attn_tiles_run += run
+            self._insert_attn_tiles_dense += dense
         handle.prefilled_tokens += n
         handle._prompt_rows = end
         if self._prefix is not None and end >= bs:
@@ -2558,6 +2569,17 @@ class LLMEngine:
             # history) against the padded history's rows
             "insert_keys_walked": self._insert_keys_walked,
             "insert_keys_padded": self._insert_keys_padded,
+            # which form the largest bucket's attention compiled to, the
+            # model says (by backend and shape alone; "plain" for one
+            # that has one form), and what the kernel's bounds let
+            # through of the (query, key) tiles the loop multiplies
+            "insert_attention": (
+                self._model.insert_attention(
+                    self.model_config, 0, self.config.prefill_buckets[-1],
+                    self.config.max_seq_len)[0]
+                if self._model.insert_attention else "plain"),
+            "insert_attn_tiles_run": self._insert_attn_tiles_run,
+            "insert_attn_tiles_dense": self._insert_attn_tiles_dense,
             # the scheduler thread's seconds and calls by phase
             "loop": self._loop.stats(),
             "traces": traces,

@@ -179,7 +179,10 @@ def program(key, build):
 # pin replaced was run one last time and held (CHANGES.md, PR 46);
 # `think-decode-ssm-yoco`'s two are PR 47's, which brought the cell (and
 # left every other text as it was: the engine's one new branch is on a
-# shape).
+# shape); `mixed-decode-window-moe`'s insert is PR 48's (its pieces
+# attend through `ops.attention.flash_prefill`; the other seventeen
+# texts stood, `think-decode-ssm-yoco`'s insert among them: its window
+# layers' 1536 key rows stay under `prefill_engages`' sizes).
 PROGRAM_TEXT_SHA256 = {
     ("chat-decode", "tick"):
         "48a91f54a548addd9d951f33258125cd66601f6eb5de512b9f23800388b2ae93",
@@ -200,7 +203,7 @@ PROGRAM_TEXT_SHA256 = {
     ("mixed-decode-window-moe", "tick"):
         "ab3d09efdab6c14edbb8e0cc678b75f5d1d1df6032fe7931641e9b39880fe1dd",
     ("mixed-decode-window-moe", "insert"):
-        "5a10c0de6c4e48c235608d2a198cbf8c7f32efdc6d7861d4be3fb80b7fc7bc79",
+        "dc7b87d48f9694f7cef7e9a84f1cf93771805234e2822c3e0e39972faa714ae6",
     ("reason-decode-gdn-hybrid", "tick"):
         "7db8f02c0133bc1b6757a1eda4a18cfbf6f2ae756478d598ac520e07ef1199b1",
     ("reason-decode-gdn-hybrid", "insert"):

@@ -63,6 +63,35 @@ def test_flash_attention_compiles_for_v5e(one_chip, on_tpu, shape,
         1 if direction == "forward" else 3)
 
 
+@pytest.mark.parametrize("kind, keys", [("window", 2048 + 1024),
+                                        ("full", 18432)])
+def test_flash_prefill_compiles_at_the_1024_bucket(one_chip, on_tpu, kind,
+                                                   keys):
+    """`mixed-decode-window-moe` engages `ops.attention.flash_prefill`
+    at two buckets: the 2048 bucket's two kernels compile inside the
+    cell's largest insert (`test_chip_compile_kv_cells.py`), the 1024
+    bucket's here, a window layer's piece over 3072 key rows and the
+    full layer's over the slot's 18,432; the two small buckets stay
+    under `prefill_engages`' sizes."""
+    from ray_tpu.ops import attention
+
+    assert attention._prefill_blocks(1024, keys) == (512, 1024)
+    assert attention.prefill_engages(1024, 128, keys)
+    assert not attention.prefill_engages(512, 128, 2048 + 512)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(functools.partial(
+        attention.flash_prefill,
+        window=2048 if kind == "window" else None)).lower(
+            arg(jnp.bfloat16, 1024, 32, 128),
+            arg(jnp.bfloat16, keys, 4, 128), arg(jnp.bfloat16, keys, 4, 128),
+            arg(jnp.int32), arg(jnp.int32), arg(jnp.int32)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") >= 1
+
+
 # ------------------------------------------------------------ ring collectives
 
 def _ring_fns(n):

@@ -24,6 +24,25 @@ from chip_programs import (     # noqa: F401  (fixtures)
 )
 
 
+def insert_attends_through_the_kernel(compiled, calls, Pb):
+    """A compiled insert whose pieces attend through
+    `ops.attention.flash_prefill`: at least `calls` of the kernel's
+    custom calls, each under the scope `attn`, and no float32 result,
+    fused computations' own included, of a block of scores `[KV heads,
+    heads a group, Pb, keys]` (the XLA loop's, 268 MB a step at the 2048
+    bucket), nor of its running maximum, sum or accumulator."""
+    kernels = [line for line in compiled.text.splitlines()
+               if "custom-call(" in line and "flash_prefill" in line]
+    assert len(kernels) >= calls
+    assert all(re.search(r'op_name="[^"]*/attn/[^"]*flash_prefill', line)
+               for line in kernels)
+    f32 = {tuple(int(d) for d in dims.split(","))
+           for dims in re.findall(r"f32\[([\d,]+)\]", compiled.text)}
+    assert any(len(s) == 3 and Pb in s for s in f32)        # parsed
+    assert not sorted(s for s in f32 if len(s) == 4 and s[-2] == Pb
+                      and s[-1] != 128)
+
+
 @pytest.mark.parametrize("program", ["tick", "insert"])
 def test_conv_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     """The engine's decode tick and its largest insert at the geometry of
@@ -88,9 +107,11 @@ def test_window_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     side by side in one row) are updated in place and NOT copied to be
     re-tiled (as `[bs, 4, 128]` blocks each insert copied every pool in
     and out: 2.39 GiB of temporaries),
-    the insert at 2048 over an 18,432-row history keeps its temporaries
-    under 1.5 GiB (float32 scores over the whole history would be 4.5),
-    and arguments + temporaries fit HBM."""
+    the insert at 2048 over an 18,432-row history attends through five
+    `flash_prefill` calls under `attn` and holds no block of float32
+    scores, its temporaries under 0.35 GiB (0.31; 0.45 with the XLA
+    loop's score blocks; float32 scores over the whole history would be
+    4.5), and arguments + temporaries fit HBM."""
     eng = serving_cell("mixed-decode-window-moe")
     ec, mc, model, published = (eng.config, eng.model_config, eng._model,
                                 eng.published)
@@ -118,13 +139,17 @@ def test_window_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
         assert not any(padded & shapes for _, shapes in results_of(text))
     else:
         compiled = cell_program(eng.name, "insert")
+        assert model.insert_attention(
+            mc, 0, ec.prefill_buckets[-1], ec.max_seq_len)[0] == "kernel"
+        insert_attends_through_the_kernel(compiled, mc.n_layers,
+                                          ec.prefill_buckets[-1])
     grouped_products_are_the_kernel(compiled.text, mc.n_moe_layers)
     m = compiled.memory
     kept = sum(math.prod(x.shape) * x.dtype.itemsize for x in pools.values())
     print(program, "GiB", compiled.hbm_gib, "temp",
           m.temp_size_in_bytes / GIB, "args", m.argument_size_in_bytes / GIB)
     assert m.alias_size_in_bytes >= kept                # both in place
-    assert m.temp_size_in_bytes < 1.5 * GIB
+    assert m.temp_size_in_bytes < (1.5 if program == "tick" else 0.35) * GIB
     assert compiled.hbm_gib < V5E_HBM_GIB - 0.5
 
 
@@ -222,8 +247,10 @@ def test_sambay_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     self pairs' scan, the full layer's, the cross layers' inside
     theirs) and two of the scan step (the self pairs', the middle
     pair's), the insert as many of the scan kernel and no paged
-    attention; neither builds a padded view of a pool; both kinds of
-    pool AND the slots' state are updated in place and nothing is
+    attention (its window layers' 1024 queries over 1536 key rows stay
+    under `ops.attention.prefill_engages`' sizes: the loop, no
+    `flash_prefill`); neither builds a padded view of a pool; both kinds
+    of pool AND the slots' state are updated in place and nothing is
     copied to be re-tiled (temporaries under 0.15 and 0.5 GiB beside
     3.1 + 2.5 GB of pools and 0.31 of state); and arguments +
     temporaries fit HBM with the 7.7 GB of weights."""
@@ -257,6 +284,9 @@ def test_sambay_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     else:
         assert text.count("ssm_scan") >= 2 and "ssm_step" not in text
         assert "paged_attention" not in compiled.plain
+        assert model.insert_attention(
+            mc, 0, ec.prefill_buckets[-1], ec.max_seq_len)[0] == "loop"
+        assert "flash_prefill" not in compiled.plain
     m = compiled.memory
     kept = sum(math.prod(x.shape) * x.dtype.itemsize
                for x in list(pools.values()) + list(state.values()))

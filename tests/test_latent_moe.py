@@ -432,6 +432,9 @@ def test_engine_counts_the_keys_its_inserts_walk(model):
     assert st["insert_keys_padded"] == len(ends) * S \
         >= st["insert_keys_walked"]
     assert st["traces"]["insert"] == 3         # a program a bucket
+    # no tiles to count: this model's inserts attend by one form
+    assert (st["insert_attention"], st["insert_attn_tiles_dense"]) \
+        == ("plain", 0)
     deficits = np.concatenate([
         R.served_token_deficits(weights, C, p, list(h.tokens))
         for p, h in zip(prompts, hs)])

@@ -71,13 +71,25 @@ def _drawn(weights):
     return jax.tree_util.tree_map_with_path(leaf, weights)
 
 
-def _build(c, **overrides):
+# The same layers at shapes where a window layer's piece goes through
+# the kernel (`ops.attention.prefill_engages`: pairs of heads of 64 laid
+# 128 lanes wide, pieces and key rows in whole tiles of 128): a window
+# of 128, buckets of 128, blocks of 16, a ring of 256 rows; `d_inner`
+# stays under the scan kernels' whole tiles, so that the interpreter
+# forced changes the insert's attention and nothing else
+C_KERNEL = dict(C, hidden_size=512, sliding_window=128, mamba_expand=1,
+                mamba_dt_rank=32)
+GEOMETRY = {"loop": (BS, BUCKET, RING),
+            "kernel": (16, 128, (128 + 128) // 16)}
+
+
+def _build(c, max_seq_len=64, **overrides):
     from families import sambay_decoder as F
     from reference import sambay_decoder as R
 
-    mc = F.model_config(c, max_seq_len=64, compute_dtype="float32",
-                        param_dtype="float32", prefill_key_block=8,
-                        **overrides)
+    mc = F.model_config(c, max_seq_len=max_seq_len,
+                        compute_dtype="float32", param_dtype="float32",
+                        prefill_key_block=8, **overrides)
     weights = _drawn(R.init_weights(c, 11, jnp.float32))
     return R, mc, weights, F.program_params(weights)
 
@@ -102,7 +114,7 @@ def _tokens(n, seed=0):
 
 def _reference_logits(R, weights, toks, start, n, c=C, **kw):
     return np.asarray(R.logits_for_positions(weights, c, toks, start, n,
-                                             pad_to=16, **kw))
+                                             **{"pad_to": 16, **kw}))
 
 
 def _off(got, want):
@@ -152,6 +164,8 @@ def _prefill(mc, params, pools, state, slot, tables, toks, start,
     Returns the ONE row the model hands back."""
     from ray_tpu.models.window_moe import WINDOW_LEAVES
 
+    BS = pools["k"].shape[2]
+
     kind = lambda name: "window" if name in WINDOW_LEAVES else "full"
     hist = {k: v[:, tables[kind(k)]].reshape((v.shape[0], -1) + v.shape[3:])
             for k, v in pools.items()}
@@ -172,16 +186,18 @@ def _prefill(mc, params, pools, state, slot, tables, toks, start,
     return x[0], pools, state
 
 
-def _fresh(mc, n_blocks, slots=3):
+def _fresh(mc, n_blocks, slots=3, geometry="loop"):
     from ray_tpu.models.sambay import init_paged_pool, init_slot_state
 
+    BS, _, RING = GEOMETRY[geometry]
     pools = init_paged_pool(mc, n_blocks + 9, BS, window_blocks=RING + 5)
     tables = {"full": np.arange(n_blocks, dtype=np.int32) + 5,
               "window": np.arange(RING, dtype=np.int32)[::-1] + 2}
     return pools, init_slot_state(mc, slots), tables
 
 
-def _served_logits(mc, params, toks, n_prompt=None, slots=3, slot=2):
+def _served_logits(mc, params, toks, n_prompt=None, slots=3, slot=2,
+                   geometry="loop"):
     """Logits at the LAST row of every chunk of the prompt and at every
     later position of `toks` through the serving path: the prompt in
     chunks of BUCKET (state, tails and ring handed on in the slot), the
@@ -190,16 +206,17 @@ def _served_logits(mc, params, toks, n_prompt=None, slots=3, slot=2):
     slots' state stands."""
     from ray_tpu.models.sambay import _head
 
+    BS, BUCKET, _ = GEOMETRY[geometry]
     n_prompt = n_prompt or len(toks) - 10
     n_blocks = -(-len(toks) // BUCKET) * BUCKET // BS
-    pools, state, table = _fresh(mc, n_blocks, slots)
+    pools, state, table = _fresh(mc, n_blocks, slots, geometry)
     # the slot holds another sequence's garbage: admission must clear it
     state = jax.tree.map(lambda x: x.at[:, slot].set(1.0), state)
     at, got = [], []
     for start in range(0, n_prompt, BUCKET):
         end = min(start + BUCKET, n_prompt)
         x, pools, state = _prefill(mc, params, pools, state, slot, table,
-                                   toks[start:end], start)
+                                   toks[start:end], start, BUCKET)
         at.append(end - 1)
         got.append(np.asarray(_head(mc, params, x)))
     tables = {k: np.zeros((slots, len(v)), np.int32)
@@ -230,23 +247,51 @@ def _served_logits(mc, params, toks, n_prompt=None, slots=3, slot=2):
     return np.asarray(at), np.concatenate(got)
 
 
-@pytest.mark.parametrize("case", ["one_bucket", "chunked"])
-def test_paged_prefill_and_decode_match_reference(model, case):
+@functools.cache
+def _kernel_model():
+    return _build(C_KERNEL, max_seq_len=640)
+
+
+@pytest.mark.parametrize("path, case", [
+    ("loop", "one_bucket"), ("loop", "chunked"),
+    ("kernel", "one_bucket"), ("kernel", "chunked")])
+def test_paged_prefill_and_decode_match_reference(model, path, case,
+                                                  monkeypatch):
     """Prefill (one bucket; three chunks across the window and round the
     ring of 24 rows, each over the rows, ring, state and tails before
     it) and then 10 decode steps through both kinds of pool and the
     slot's state, dead slots beside the live one: logits at every served
-    position against the reference's full forward."""
-    R, mc, weights, params = model
-    pools, state, _ = _fresh(mc, 8)
-    assert pools["k"].shape == pools["v"].shape == (1, 17, BS, 32)
-    assert pools["k_w"].shape == pools["v_w"].shape == (2, RING + 5, BS, 32)
-    assert state["h"].shape == (3, 3, 4, 1, 128)    # channels on the lanes
-    assert state["tail"].shape == (3, 3, 3, 128)
-    n_prompt = {"one_bucket": 13, "chunked": 43}[case]
+    position against the reference's full forward.  `kernel`: the same
+    at pairs of 128 lanes and pieces of 128 rows (one bucket; five
+    chunks round a ring of 256 rows) with the interpreter forced, so
+    that every window layer's piece attends through
+    `ops.attention.flash_prefill` and nothing else changes (float32
+    pools, `d_inner` of 512: the tick keeps its gathers and the scan its
+    plain forms)."""
+    from ray_tpu.models.sambay import insert_attention
+    from ray_tpu.ops import attention
+
+    if path == "kernel":
+        monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", True)
+        # from 128 queries and keys on, not the chip's 1024 and 2048
+        monkeypatch.setattr(attention, "PREFILL_MIN_Q", 128)
+        monkeypatch.setattr(attention, "PREFILL_MIN_K", 128)
+        R, mc, weights, params = _kernel_model()
+        c, n_prompt = C_KERNEL, {"one_bucket": 100, "chunked": 530}[case]
+    else:
+        R, mc, weights, params = model
+        c, n_prompt = C, {"one_bucket": 13, "chunked": 43}[case]
+        pools, state, _ = _fresh(mc, 8)
+        assert pools["k"].shape == pools["v"].shape == (1, 17, BS, 32)
+        assert pools["k_w"].shape == pools["v_w"].shape \
+            == (2, RING + 5, BS, 32)
+        assert state["h"].shape == (3, 3, 4, 1, 128)    # channels on lanes
+        assert state["tail"].shape == (3, 3, 3, 128)
+    assert insert_attention(mc, 0, GEOMETRY[path][1], mc.max_seq_len)[0] \
+        == path
     toks = _tokens(n_prompt + 10, seed=3)
-    at, got = _served_logits(mc, params, toks, n_prompt)
-    want = _reference_logits(R, weights, toks, 0, len(toks))[at]
+    at, got = _served_logits(mc, params, toks, n_prompt, geometry=path)
+    want = _reference_logits(R, weights, toks, 0, len(toks), c=c)[at]
     assert _off(got, want) < RTOL
 
 

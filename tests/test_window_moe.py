@@ -65,11 +65,20 @@ RING = (8 + BUCKET) // BS   # blocks: the window before a chunk + the chunk
 N_TOK = 70        # longer than ring + window (24 + 8 rows)
 
 
-def _build(c, dtype="float32", **overrides):
+# The same layers at shapes where an insert's attention goes through the
+# kernel (`ops.attention.prefill_engages`: heads of 128, pieces and key
+# rows in whole tiles of 128): a window of 128, buckets of 128, blocks
+# of 16, a ring of 256 rows
+C_KERNEL = dict(C, head_dim=128, sliding_window=128)
+BS_K, BUCKET_K = 16, 128
+RING_K = (128 + BUCKET_K) // BS_K
+
+
+def _build(c, dtype="float32", max_seq_len=96, **overrides):
     from families import window_moe_decoder as F
     from reference import window_moe_decoder as R
 
-    mc = F.model_config(c, max_seq_len=96, compute_dtype=dtype,
+    mc = F.model_config(c, max_seq_len=max_seq_len, compute_dtype=dtype,
                         param_dtype=dtype, prefill_key_block=8, **overrides)
     weights = R.init_weights(c, 11, getattr(jnp, dtype))
     # norms that are not all ones, so that a missing one shows
@@ -101,7 +110,9 @@ def _tokens(n, seed=0):
 
 
 def _reference_logits(R, weights, toks, start, n, c=C, without=()):
-    Tp = -(-len(toks) // 16) * 16
+    # whole blocks of the reference's 256 queries, past one block
+    pad = 16 if len(toks) <= 256 else 256
+    Tp = -(-len(toks) // pad) * pad
     return np.asarray(R.logits_for_positions(
         weights, c, toks, start, n, pad_to=Tp, without=without))
 
@@ -187,6 +198,8 @@ def _prefill(mc, params, pools, table, ring, toks, start, bucket=BUCKET):
     window kind's as its ring; rows scattered through each table."""
     from ray_tpu.models.window_moe import WINDOW_LEAVES
 
+    BS = pools["k"].shape[2]
+
     def row(name):
         return ring if name in WINDOW_LEAVES else table
 
@@ -207,44 +220,77 @@ def _prefill(mc, params, pools, table, ring, toks, start, bucket=BUCKET):
     return x[0, :len(toks)], pools
 
 
-@pytest.mark.parametrize("n_prompt", [13, 27, 55])
-def test_paged_prefill_and_decode_match_reference(model, n_prompt):
+@functools.cache
+def _kernel_model():
+    return _build(C_KERNEL, max_seq_len=640)
+
+
+@pytest.mark.parametrize("path, n_prompt", [
+    ("loop", 13), ("loop", 27), ("loop", 55),
+    ("kernel", 100), ("kernel", 250), ("kernel", 500)])
+def test_paged_prefill_and_decode_match_reference(model, path, n_prompt,
+                                                  monkeypatch):
     """Prefill (one bucket; two chunks; four, the ring wrapping inside
     the prefill) and then decode to 70 tokens, past ring + window rows so
     that the ring wraps in decode too: logits at every position against
     the reference's full forward; the blocks no table names stand as
-    they were."""
-    from ray_tpu.models.window_moe import _head, init_paged_pool
+    they were.  `kernel`: the same at heads of 128 and pieces of 128
+    rows with the interpreter forced, so that every piece attends
+    through `ops.attention.flash_prefill` (the pools are float32: the
+    tick keeps its gathers), then 20 decode steps."""
+    from ray_tpu.models.window_moe import (
+        _head, init_paged_pool, insert_attention,
+    )
+    from ray_tpu.ops import attention
 
-    R, mc, weights, params = model
-    toks = _tokens(N_TOK, seed=3)
-    pools = init_paged_pool(mc, 40, BS, window_blocks=20)
-    assert pools["k"].shape == (1, 40, BS, 2 * 16)      # the full layer
-    assert pools["v_w"].shape == (4, 20, BS, 2 * 16)    # the window layers
+    if path == "kernel":
+        monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", True)
+        # from 128 queries and keys on, not the chip's 1024 and 2048
+        monkeypatch.setattr(attention, "PREFILL_MIN_Q", 128)
+        monkeypatch.setattr(attention, "PREFILL_MIN_K", 128)
+        R, mc, weights, params = _kernel_model()
+        c, bs, bucket, n_ring, n_tok = (C_KERNEL, BS_K, BUCKET_K, RING_K,
+                                        n_prompt + 20)
+        ring = np.asarray([17, 3, 11, 8, 14, 2, 0, 19, 5, 9, 1, 16, 7, 12,
+                           4, 18], np.int32)
+    else:
+        R, mc, weights, params = model
+        c, bs, bucket, n_ring, n_tok = C, BS, BUCKET, RING, N_TOK
+        ring = np.asarray([17, 3, 11, 8, 14, 2], np.int32)
+    n_table = mc.max_seq_len // bs
+    assert insert_attention(mc, 0, bucket, mc.max_seq_len)[0] == path
+    toks = _tokens(n_tok, seed=3)
+    pools = init_paged_pool(mc, n_table + 16, bs, window_blocks=20)
+    row = 2 * mc.head_dim
+    assert pools["k"].shape == (1, n_table + 16, bs, row)   # the full layer
+    assert pools["v_w"].shape == (4, 20, bs, row)       # the window layers
     pools = jax.tree.map(lambda x: x + 7.0, pools)
-    table = np.arange(24, dtype=np.int32) + 5
-    ring = np.asarray([17, 3, 11, 8, 14, 2], np.int32)
-    assert len(ring) == RING and N_TOK > RING * BS + 8
+    table = np.arange(n_table, dtype=np.int32) + 5
+    assert len(ring) == n_ring
+    # the longest prompt of each path wraps its ring
+    assert max(n_tok, {"loop": N_TOK, "kernel": 500}[path]) \
+        > n_ring * bs + 8
     hidden = []
-    for start in range(0, n_prompt, BUCKET):
+    for start in range(0, n_prompt, bucket):
         x, pools = _prefill(mc, params, pools, table, ring,
-                            toks[start:min(start + BUCKET, n_prompt)], start)
+                            toks[start:min(start + bucket, n_prompt)], start,
+                            bucket)
         hidden.append(x)
     got = [np.asarray(_head(mc, params, jnp.concatenate(hidden)))]
-    tables = {"full": np.zeros((3, 24), np.int32),
-              "window": np.zeros((3, RING), np.int32)}
+    tables = {"full": np.zeros((3, n_table), np.int32),
+              "window": np.zeros((3, n_ring), np.int32)}
     tables["full"][2], tables["window"][2] = table, ring
     tables = jax.tree.map(jnp.asarray, tables)
     active = jnp.asarray([False, False, True])
-    for t in range(n_prompt, N_TOK):
+    for t in range(n_prompt, n_tok):
         logits, pools, counts = _jitted("decode_step_paged")(
             params, pools, tables, jnp.asarray([0, 0, toks[t]]),
             jnp.asarray([0, 0, t]), mc, active)
         got.append(np.asarray(logits[2:3]))
-    want = _reference_logits(R, weights, toks, 0, N_TOK)
+    want = _reference_logits(R, weights, toks, 0, n_tok, c=c)
     assert _off(np.concatenate(got), want) < RTOL
     assert np.all(np.asarray(pools["k"][:, np.setdiff1d(
-        np.arange(40), table)]) == 7.0)
+        np.arange(n_table + 16), table)]) == 7.0)
     assert np.all(np.asarray(pools["k_w"][:, np.setdiff1d(
         np.arange(20), ring)]) == 7.0)
     assert int(counts["ticks"]) == 1
@@ -355,11 +401,17 @@ def test_engine_serves_through_both_kinds_past_a_ring_wrap(model, engine):
     answers that take the longer streams past ring + window rows.  Every
     served token is the reference's choice given the served prefix; a
     stream never holds more than a ring of window blocks; every block
-    of both kinds is given back."""
+    of both kinds is given back.  `stats()` says which form the inserts'
+    attention compiled to and sums, over the admitted pieces, the
+    (query, key) tiles the kernel's bounds would let through beside
+    those of the rectangles the loop multiplies."""
+    from ray_tpu.models.window_moe import insert_attention
     from ray_tpu.serve.llm.engine import Request
 
     R, mc, weights, params = model
-    assert engine.stats()["kv"]["window"]["ring_blocks"] == RING
+    before = engine.stats()
+    assert before["kv"]["window"]["ring_blocks"] == RING
+    assert before["insert_attention"] == "loop"         # heads of 16
     prompts = [_tokens(n, seed=n) for n in (3, 40, 16, 55, 9, 30)]
     handles = [engine.submit(Request(prompt=p, max_tokens=30,
                                      chunked_prefill=len(p) > BUCKET))
@@ -376,8 +428,18 @@ def test_engine_serves_through_both_kinds_past_a_ring_wrap(model, engine):
         lg = _reference_logits(R, weights, p + h.tokens[:-1], len(p) - 1, 30)
         chosen = lg[np.arange(30), h.tokens]
         assert np.all(lg.max(-1) - chosen <= RTOL * np.abs(lg).max())
-    kv = engine.stats()["kv"]
+    stats = engine.stats()
+    kv = stats["kv"]
     assert kv["used_blocks"] == 0 and kv["window"]["used_blocks"] == 0
+    # a piece a bucket of 16, the last in the bucket that holds it
+    pieces = [(start, 8 if min(n - start, BUCKET) <= 8 else BUCKET)
+              for n in map(len, prompts) for start in range(0, n, BUCKET)]
+    tiles = [insert_attention(mc, start, bucket, 96)[1:]
+             for start, bucket in pieces]
+    run, dense = (stats[k] - before[k] for k in (
+        "insert_attn_tiles_run", "insert_attn_tiles_dense"))
+    assert (run, dense) == tuple(map(sum, zip(*tiles)))
+    assert 0 < run <= dense     # tiles of 16 x 32: nothing to skip here
 
 
 class _RecordingTick:
