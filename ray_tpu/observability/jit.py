@@ -10,14 +10,21 @@ catch that. :class:`TrackedJit` generalizes it onto a shared API:
     out = tick(params, state)           # drop-in for jax.jit(tick_fn)
     tick.traces                         # programs traced by THIS wrapper
 
-Each new trace increments the ``jit_traces_total`` / ``jit_compiles_total``
-counters (tagged by function name), observes the first-call wall time —
+Each new trace increments the ``jit_traces_total`` counter (tagged by
+function name), observes the first-call wall time —
 trace + lower + compile + first execute, the cost a user actually waits
 for — into the ``jit_compile_seconds`` histogram, and records a
 ``jit_compile`` span so ``ray_tpu.timeline()`` shows compiles inline
-with the run. When an instance re-traces past ``trace_budget`` it warns
-ONCE with :class:`RecompileWarning` naming the function and the
-argument signature that caused the re-trace.
+with the run. That wall is also split by stage, from what
+``_private/compile_cache`` heard of JAX's own compile events on the
+calling thread during the call: ``jit_stats()[name]`` keeps
+``trace_seconds``, ``lower_seconds``, ``backend_seconds`` (the compiler
+on a persistent-cache miss, loading the executable on a hit) beside
+``compile_seconds_total``, and what that total holds over the three is
+the first dispatch and run. When an instance
+re-traces past ``trace_budget`` it warns ONCE with
+:class:`RecompileWarning` naming the function and the argument
+signature that caused the re-trace.
 
 Budgets are per-instance (a fresh engine legitimately re-traces its own
 programs); the counters aggregate per function name across instances
@@ -45,9 +52,17 @@ import time
 import warnings
 from typing import Any, Callable, Dict, Optional
 
+from ray_tpu._private import compile_cache
+
 _lock = threading.Lock()
-# name -> {"traces": int, "compiles": int, "compile_seconds_total": float}
+# name -> the counts of `jit_stats()`
 _stats: Dict[str, Dict[str, float]] = {}
+
+
+def _new_stats() -> Dict[str, float]:
+    return {"traces": 0, "compiles": 0, "compile_seconds_total": 0.0,
+            "trace_seconds": 0.0, "lower_seconds": 0.0,
+            "backend_seconds": 0.0}
 
 _metrics = None
 
@@ -67,10 +82,6 @@ def _jit_metrics():
             "traces": Counter(
                 "jit_traces_total",
                 description="XLA traces of tracked jitted functions.",
-                tag_keys=("fn",)),
-            "compiles": Counter(
-                "jit_compiles_total",
-                description="XLA compiles of tracked jitted functions.",
                 tag_keys=("fn",)),
             "compile_seconds": Histogram(
                 "jit_compile_seconds",
@@ -132,8 +143,11 @@ class TrackedJit:
         self._compiled_cache: Dict[str, Any] = {}
         # While the attribution hook lowers through the jit wrapper the
         # probe still runs under tracing; this re-entrancy flag keeps
-        # those internal traces out of the user-facing counters.
+        # those internal traces out of the user-facing counters. Beside
+        # it `at_trace`: the thread's compile totals when its last
+        # counted trace began.
         self._suppress = threading.local()
+        compile_cache.listen()
         from ray_tpu.observability import xla as _xla
 
         self._sample_every = _xla.wall_sample_every() \
@@ -145,10 +159,13 @@ class TrackedJit:
             # per call, which is exactly what a retrace counter wants.
             if not getattr(self._suppress, "on", False):
                 self.traces += 1  # graftlint: disable=jit-global-mutation
+                # the outermost trace is open and not yet counted: the
+                # totals are those of before the call (nothing on the
+                # hot path reads them); once a trace, as the count above
+                at = compile_cache.thread_totals()
+                self._suppress.at_trace = at  # graftlint: disable=jit-global-mutation
                 with _lock:
-                    st = _stats.setdefault(self.name, {
-                        "traces": 0, "compiles": 0,
-                        "compile_seconds_total": 0.0})
+                    st = _stats.setdefault(self.name, _new_stats())
                     st["traces"] += 1
             return fn(*args, **kwargs)
 
@@ -167,7 +184,7 @@ class TrackedJit:
         out = self._jitted(*args, **kwargs)
         if self.traces > before:
             dt = time.perf_counter() - t0
-            self._on_compile(dt, args, kwargs)
+            self._on_compile(dt, args, kwargs, self._stages())
         elif sample:
             due = (_arg_signature(args, kwargs), exposed0)
             if self._fence_samples:
@@ -176,15 +193,30 @@ class TrackedJit:
                 self._sample_due = due
         return out
 
-    def _on_compile(self, seconds: float, args, kwargs) -> None:
+    def _stages(self):
+        """(trace, lower, backend) seconds the calling thread spent
+        since the probe ran in this call: the call's own stages. All
+        zero for a call traced inside another program's trace, whose
+        seconds hold it."""
+        at = getattr(self._suppress, "at_trace", None)
+        if at is None:                  # traced on another thread
+            return 0.0, 0.0, 0.0
+        self._suppress.at_trace = None
+        return tuple(b - a for a, b in
+                     zip(at, compile_cache.thread_totals()))
+
+    def _on_compile(self, seconds: float, args, kwargs, stages) -> None:
+        trace_s, lower_s, backend_s = stages
         with _lock:
             st = _stats[self.name]
             st["compiles"] += 1
             st["compile_seconds_total"] += seconds
+            st["trace_seconds"] += trace_s
+            st["lower_seconds"] += lower_s
+            st["backend_seconds"] += backend_s
         try:
             m = _jit_metrics()
             tags = {"fn": self.name}
-            m["compiles"].inc(1.0, tags=tags)
             m["traces"].inc(1.0, tags=tags)
             m["compile_seconds"].observe(seconds, tags=tags)
         except Exception:
@@ -386,7 +418,21 @@ def tracked_jit(fn: Optional[Callable] = None, *,
 
 
 def jit_stats() -> Dict[str, Dict[str, float]]:
-    """Per-function aggregate {traces, compiles, compile_seconds_total}
-    for every tracked function in this process."""
+    """Per-function aggregate {traces, compiles, compile_seconds_total,
+    trace_seconds, lower_seconds, backend_seconds} for every tracked
+    function in this process."""
     with _lock:
         return {k: dict(v) for k, v in _stats.items()}
+
+
+def jit_stats_since(before: Dict[str, Dict[str, float]]
+                    ) -> Dict[str, Dict[str, float]]:
+    """What `jit_stats()` gained since the reading `before`: the rows of
+    the programs traced since, as differences (what an owner's set-up
+    compiled, whatever the process compiled under those names earlier)."""
+    rows = {}
+    for name, row in jit_stats().items():
+        was = before.get(name, {})
+        if row["traces"] > was.get("traces", 0):
+            rows[name] = {k: v - was.get(k, 0) for k, v in row.items()}
+    return rows

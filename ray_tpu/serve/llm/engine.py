@@ -605,6 +605,7 @@ class LLMEngine:
                                          List[Tuple[int, ...]]]] = []
         self._in_step = False
         self._loop = _LoopClock()
+        self._warmup: Optional[Dict[str, Any]] = None
         self._spill_lands = 0           # landings, and those that had
         self._spill_lands_waited = 0    # to wait for the transfer
         self._tier_seen = {t: {"hits": 0, "misses": 0, "spills": 0,
@@ -2467,7 +2468,15 @@ class LLMEngine:
         the padded suffix to a
         SMALLER bucket, leaving the larger bucket's insert uncompiled
         until a cache-miss request pays the compile inside its own
-        latency. Synchronous; call before starting a run() thread."""
+        latency. Synchronous; call before starting a run() thread.
+        What it cost stays in `stats()["warmup"]`: `seconds`, its wall,
+        and `programs`, what it added to `jit_stats()`, by program
+        (first-call walls by stage; what any engine of the process
+        compiled before or compiles later under the same names is not
+        in them)."""
+        from ray_tpu.observability.jit import jit_stats, jit_stats_since
+
+        t0, before = time.monotonic(), jit_stats()
         prefix, self._prefix = self._prefix, None
         draft, self._draft = self._draft, None
         try:
@@ -2496,10 +2505,11 @@ class LLMEngine:
         self._loop = _LoopClock()
         import jax
 
-        if self._pinned:
-            return                      # exports nothing (models/serving.py)
-        for n in self.config.export_rows:       # one row alive at a time
-            jax.block_until_ready(self._export_blocks([0] * n))
+        if not self._pinned:    # else exports nothing (models/serving.py)
+            for n in self.config.export_rows:   # one row alive at a time
+                jax.block_until_ready(self._export_blocks([0] * n))
+        self._warmup = {"seconds": time.monotonic() - t0,
+                        "programs": jit_stats_since(before)}
 
     # ------------------------------------------------------------ inspection
 
@@ -2582,6 +2592,12 @@ class LLMEngine:
             "insert_attn_tiles_dense": self._insert_attn_tiles_dense,
             # the scheduler thread's seconds and calls by phase
             "loop": self._loop.stats(),
+            # `warmup`'s wall and the programs it compiled, as copies
+            # (None until it has run)
+            "warmup": self._warmup and {
+                "seconds": self._warmup["seconds"],
+                "programs": {k: dict(v) for k, v in
+                             self._warmup["programs"].items()}},
             "traces": traces,
             "trace_count": sum(traces.values()),
             # the full kind's blocks; a model's window kind under

@@ -127,6 +127,26 @@ def _free_port_on_worker() -> int:
 # directly so both exercise the identical code path.
 # ---------------------------------------------------------------------------
 
+# `summary["setup_process"]`: what this process's `run_pod_training`
+# calls spent setting up, all of them together
+_setup_process = {"calls": 0, "seconds": {}, "programs": {}}
+
+
+def _add_setup(seconds, programs):
+    """Adds one call's set-up phases and the rows it added to
+    `jit_stats()` to the process's; returns a copy of those."""
+    total = _setup_process
+    total["calls"] += 1
+    for phase, s in seconds.items():
+        total["seconds"][phase] = total["seconds"].get(phase, 0.0) + s
+    for name, row in programs.items():
+        mine = total["programs"].setdefault(name, dict.fromkeys(row, 0))
+        for k, v in row.items():
+            mine[k] += v
+    return {"calls": total["calls"], "seconds": dict(total["seconds"]),
+            "programs": {k: dict(v) for k, v in total["programs"].items()}}
+
+
 def run_pod_training(model_config=None, mesh_axes=None, steps: int = 4,
                      batch_size: Optional[int] = None, seq_len: int = 33,
                      weight_update: str = "replicated",
@@ -161,8 +181,20 @@ def run_pod_training(model_config=None, mesh_axes=None, steps: int = 4,
     returned dict then carries ``goodput`` (the worker ledger
     snapshot) and ``phase_seconds`` (per-phase sums over the timed
     steps).
+
+    ``setup_seconds`` in the summary says what the call spent before its
+    first timed step: ``init_params`` (the parameters drawn from the
+    seed), ``place`` (parameters and optimizer state put where they
+    live), ``h2d`` (the batch) and ``compile_warmup`` (the first step:
+    trace, lower, compile or load, run). ``setup_process`` says the
+    same of ALL this process's calls so far, this one included: their
+    number, the phases' sums, and what the calls added to
+    ``jit_stats()``, by program (a later call finds the process's jit
+    cache warm and costs a fraction of the first; a program traced
+    again in a timed step is in these rows and in no phase).
     """
     import time
+    from contextlib import contextmanager
 
     import jax
     import numpy as np
@@ -174,6 +206,7 @@ def run_pod_training(model_config=None, mesh_axes=None, steps: int = 4,
         GoodputLedger, StepPhases, goodput_metrics, publish_train_done,
         set_active_ledger,
     )
+    from ray_tpu.observability.jit import jit_stats, jit_stats_since
 
     from ray_tpu.models.llama import LlamaConfig, init_params, loss_fn
     from ray_tpu.parallel import (
@@ -201,7 +234,17 @@ def run_pod_training(model_config=None, mesh_axes=None, steps: int = 4,
                 "flat parameter vector over 'data'")
         weight_update = "sharded"
 
-    params = init_params(model_config, jax.random.key(seed))
+    setup_seconds, jit_before = {}, jit_stats()
+
+    @contextmanager
+    def setup(phase):
+        t = time.perf_counter()
+        yield
+        setup_seconds[phase] = time.perf_counter() - t
+
+    with setup("init_params"):
+        params = init_params(model_config, jax.random.key(seed))
+        jax.block_until_ready(params)
     shardings = llama_param_shardings(model_config, mesh)
     bsh = batch_sharding(mesh)
     optimizer = optax.adamw(learning_rate)
@@ -213,7 +256,9 @@ def run_pod_training(model_config=None, mesh_axes=None, steps: int = 4,
             lambda p, b: loss_fn(p, b, model_config), optimizer, mesh,
             axis_name="data", collective=collective, overlap=True,
             n_chunks=n_chunks)
-        state = create_zero_state(params, optimizer, mesh)
+        with setup("place"):
+            state = jax.block_until_ready(
+                create_zero_state(params, optimizer, mesh))
     else:
         # A Mosaic kernel is not partitioned by GSPMD: on more than one
         # device the flash kernel runs under shard_map over batch/heads.
@@ -224,8 +269,9 @@ def run_pod_training(model_config=None, mesh_axes=None, steps: int = 4,
             lambda p, b: loss_fn(p, b, model_config, attn), optimizer,
             mesh, shardings, bsh, weight_update=weight_update,
             params_shape=params_shape)
-        state = create_train_state(shard_params(params, shardings),
-                                   optimizer)
+        with setup("place"):
+            state = jax.block_until_ready(create_train_state(
+                shard_params(params, shardings), optimizer))
 
     # Batch must divide evenly over the data-like axes.
     data_shards = 1
@@ -246,24 +292,24 @@ def run_pod_training(model_config=None, mesh_axes=None, steps: int = 4,
     rng = np.random.RandomState(seed)
     host_tokens = rng.randint(0, model_config.vocab_size,
                               (batch_size, seq_len)).astype("int32")
-    t_h2d = time.perf_counter()
-    batch = {"tokens": jax.device_put(host_tokens, bsh)}
+    with setup("h2d"):
+        batch = {"tokens": jax.device_put(host_tokens, bsh)}
     if ledger is not None:
         # One-off input transfer: an h2d histogram sample + stalled
         # ledger time (a real input pipeline pays this per step).
-        h2d_s = time.perf_counter() - t_h2d
+        h2d_s = setup_seconds["h2d"]
         goodput_metrics().step_phase_seconds.observe(
             h2d_s, {"phase": "h2d"})
         ledger.book_phases({"h2d": h2d_s})
     tokens_per_step = batch_size * (seq_len - 1)  # next-token targets
 
-    t_compile = time.perf_counter()
-    state, metrics = step(state, batch)  # compile + warmup
-    jax.block_until_ready(metrics["loss"])
+    with setup("compile_warmup"):
+        state, metrics = step(state, batch)  # compile + warmup
+        jax.block_until_ready(metrics["loss"])
     if ledger is not None:
         # The compile+warmup step is wall time the pod spent not
         # training — exactly what a preemption/resume re-pays.
-        ledger.lose("recompiling", time.perf_counter() - t_compile)
+        ledger.lose("recompiling", setup_seconds["compile_warmup"])
 
     step_rows = []
     t0 = time.perf_counter()
@@ -322,6 +368,9 @@ def run_pod_training(model_config=None, mesh_axes=None, steps: int = 4,
         "batch_size": batch_size,
         "seq_len": seq_len,
         "loss": loss,
+        "setup_seconds": setup_seconds,
+        "setup_process": _add_setup(setup_seconds,
+                                    jit_stats_since(jit_before)),
         "train_seconds": elapsed,
         "tokens_per_sec": tokens_per_sec,
         "tokens_per_sec_per_chip": tokens_per_sec / max(n_devices, 1),

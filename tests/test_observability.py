@@ -359,6 +359,51 @@ def test_tracked_jit_counts_and_warns():
     assert st["compile_seconds_total"] > 0
 
 
+@pytest.mark.parametrize("case", ["stages", "again", "nested"])
+def test_tracked_jit_splits_its_first_call(case):
+    """`jit_stats()[name]` splits the first-call wall it always took:
+    trace, lower and backend seconds of the CALLING thread during the
+    call that traced (what is left over them is the first dispatch and
+    run)."""
+    import jax.numpy as jnp
+
+    from ray_tpu.observability import jit_stats, tracked_jit
+
+    name = f"obs_stage_fn_{case}"
+    f = tracked_jit(lambda x: jnp.tanh(x @ x.T).sum() + jnp.arange(3.0)[1],
+                    name=name)
+    x = jnp.ones((32, 32))
+    if case == "stages":
+        f(x).block_until_ready()
+        st = jit_stats()[name]
+        parts = [st[k] for k in ("trace_seconds", "lower_seconds",
+                                 "backend_seconds")]
+        assert all(p > 0 for p in parts)
+        assert sum(parts) <= st["compile_seconds_total"]
+        assert st["traces"] == st["compiles"] == 1
+    elif case == "again":
+        f(x).block_until_ready()
+        first = jit_stats()[name]
+        f(x).block_until_ready()            # the same shapes: no stage
+        assert jit_stats()[name] == first
+        f(jnp.ones((8, 8)))                 # new shapes: they add
+        second = jit_stats()[name]
+        assert second["traces"] == 2
+        assert second["trace_seconds"] > first["trace_seconds"]
+        assert (second["trace_seconds"] + second["lower_seconds"]
+                + second["backend_seconds"]
+                <= second["compile_seconds_total"])
+    else:
+        # a tracked program traced inside another's trace: its seconds
+        # are the outer program's, not counted twice
+        outer = tracked_jit(lambda y: f(y) * 2, name=name + "_outer")
+        outer(x).block_until_ready()
+        inner_st, outer_st = jit_stats()[name], jit_stats()[name + "_outer"]
+        assert inner_st["traces"] == 1
+        assert inner_st["trace_seconds"] == inner_st["backend_seconds"] == 0
+        assert outer_st["trace_seconds"] > 0
+
+
 def test_engine_recompile_detector_fires():
     """Deliberately violating the engine's prefill bucket guard (a pad
     length that is not a configured bucket) re-traces the insert program
@@ -417,8 +462,8 @@ def test_serve_telemetry_end_to_end(ray_start_regular):
     assert "rtpu_serve_e2e_seconds_bucket" in text
     assert 'rtpu_serve_requests_total{finish_reason="length"}' in text
     assert "rtpu_serve_tokens_total" in text
-    assert 'rtpu_jit_compiles_total{fn="llm_engine_tick"}' in text
-    assert 'rtpu_jit_compiles_total{fn="llm_engine_insert"}' in text
+    assert 'rtpu_jit_traces_total{fn="llm_engine_tick"}' in text
+    assert 'rtpu_jit_traces_total{fn="llm_engine_insert"}' in text
     assert "rtpu_jit_compile_seconds_bucket" in text
     # Gauges export per-process with a pid label.
     assert 'rtpu_serve_queue_depth{pid="' in text

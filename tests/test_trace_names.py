@@ -42,7 +42,15 @@ def test_tracked_jit_lowers_under_its_own_name(name, module):
 
 # ------------------------------------------------------------ host spans
 
-def _host_events(trace_dir, prefixes):
+MINE = "trace_names.mine"
+
+
+def _host_events(trace_dir, prefixes, marker=None):
+    """The host's events named by `prefixes`; with `marker`, those of
+    the threads alone that wrote an event of that name (a worker that
+    ran other files before this one may still hold their engines'
+    scheduler threads, which idle and write `llm_engine.idle` and
+    `llm_engine.step` of their own into any trace of the process)."""
     from jax.profiler import ProfileData
 
     path = sorted(glob.glob(os.path.join(
@@ -51,6 +59,8 @@ def _host_events(trace_dir, prefixes):
     for plane in ProfileData.from_file(path).planes:
         if plane.name.startswith("/host:"):
             for line in plane.lines:
+                if marker and not any(e.name == marker for e in line.events):
+                    continue
                 out += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
                          dict(e.stats)) for e in line.events
                         if e.name.startswith(prefixes)]
@@ -79,7 +89,8 @@ def engine_traces(tmp_path_factory):
     to give blocks back (evict + spill); then `run` on the empty engine,
     traced ("idle").  Before them one untraced drain with the engine's
     phase clock read on either side ("loop": before, after, the
-    drain's wall seconds)."""
+    drain's wall seconds).  First of all `warmup()`, which compiles
+    ("warmup": `stats()` after it, its wall)."""
     import jax
 
     from ray_tpu.models.llama import LlamaConfig, init_params
@@ -102,25 +113,32 @@ def engine_traces(tmp_path_factory):
         assert all(h.finish_reason == "length" for h in hs)
         return wall
 
-    serve(0, 1)                                    # compiles, untraced
+    t0 = time.monotonic()
+    engine.warmup()                                # compiles, untraced
+    out = {"warmup": (engine, engine.stats(), time.monotonic() - t0)}
     before = engine.stats()["loop"]
     wall = serve(25, 2)
-    out = {"loop": (before, engine.stats()["loop"], wall)}
+    out["loop"] = (before, engine.stats()["loop"], wall)
     for case, base, n in (("roomy", 50, 2), ("evicting", 100, 8)):
         d = tmp_path_factory.mktemp(case)
         ev0 = engine.stats()["prefix_cache"]["evictions"]
-        with _profiled(d):
+        with _profiled(d), jax.profiler.TraceAnnotation(MINE):
             serve(base, n)
-        out[case] = (_host_events(str(d), ("llm_engine.",)),
+        out[case] = (_host_events(str(d), ("llm_engine.",), MINE),
                      engine.stats()["prefix_cache"]["evictions"] - ev0)
     d, stop = tmp_path_factory.mktemp("idle"), threading.Event()
+
+    def run():
+        with jax.profiler.TraceAnnotation(MINE):
+            engine.run(stop)
+
     with _profiled(d):
-        th = threading.Thread(target=engine.run, args=(stop,))
+        th = threading.Thread(target=run)
         th.start()
         time.sleep(0.1)
         stop.set()
         th.join()
-    out["idle"] = _host_events(str(d), ("llm_engine.",))
+    out["idle"] = _host_events(str(d), ("llm_engine.",), MINE)
     return out
 
 
@@ -289,6 +307,45 @@ def test_engine_keeps_a_phase_clock(engine_traces, key):
         total = sum(d["seconds"].values())
         assert all(v >= 0 for v in d["seconds"].values())
         assert 0.8 * wall <= total <= wall, (total, wall)
+
+
+@pytest.mark.parametrize("key", ["seconds", "programs", "anew", "copies"])
+def test_warmup_keeps_what_it_measured(engine_traces, key):
+    """`stats()["warmup"]`: the wall of `warmup()` and the rows it added
+    to `jit_stats()`; the engine's phase clock starts anew behind it."""
+    engine, st, wall = engine_traces["warmup"]
+    w = st["warmup"]
+    if key == "seconds":
+        assert set(w) == {"seconds", "programs"}
+        assert 0.5 * wall < w["seconds"] <= wall
+    elif key == "programs":
+        # the engine's own programs' first calls, by stage, as the
+        # warm-up left them: under its wall together
+        rows = w["programs"]
+        assert {"llm_engine_tick", "llm_engine_insert"} <= set(rows)
+        assert rows["llm_engine_tick"]["traces"] == st["traces"]["tick"] == 1
+        for r in rows.values():
+            stages = (r["trace_seconds"], r["lower_seconds"],
+                      r["backend_seconds"])
+            assert all(s > 0 for s in stages)
+            assert sum(stages) <= r["compile_seconds_total"]
+        assert sum(r["compile_seconds_total"] for r in rows.values()) \
+            <= w["seconds"]
+    elif key == "anew":
+        loop = st["loop"]
+        assert loop["steps"] == loop["ticks"] == 0
+        assert not any(loop["seconds"].values())
+        assert not any(loop["calls"].values())
+    else:
+        # what a reader does to the dict it was handed changes nothing,
+        # nor does what the engine serves after its warm-up
+        mine = engine.stats()["warmup"]
+        mine["programs"]["llm_engine_tick"]["trace_seconds"] += 1e6
+        mine["seconds"] = -1.0
+        again = engine.stats()["warmup"]
+        assert again == w
+        assert again["seconds"] > 0
+        assert again["programs"]["llm_engine_tick"]["trace_seconds"] < 1e5
 
 
 @pytest.mark.parametrize("who", ["fence", "owner", "engine"])

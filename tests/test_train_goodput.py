@@ -191,12 +191,23 @@ def _tiny_config():
                        n_kv_heads=2, hidden_dim=128, max_seq_len=64)
 
 
-def test_run_pod_training_emits_goodput_block():
+@pytest.fixture(scope="module")
+def pod_summary():
+    """One tiny job's summary, the wall of the call, and the summary of
+    the same job run once more in this process."""
+    import time
+
     from ray_tpu.train.jax_backend import run_pod_training
 
-    summary = run_pod_training(model_config=_tiny_config(),
-                               mesh_axes={"data": -1}, steps=3,
-                               weight_update="sharded")
+    job = dict(model_config=_tiny_config(), mesh_axes={"data": -1},
+               steps=3, weight_update="sharded")
+    t0 = time.perf_counter()
+    summary = run_pod_training(**job)
+    return summary, time.perf_counter() - t0, run_pod_training(**job)
+
+
+def test_run_pod_training_emits_goodput_block(pod_summary):
+    summary = pod_summary[0]
     g = summary["goodput"]
     assert g["worker"] == "train-0"
     assert 0.0 < g["goodput_ratio"] <= 1.0
@@ -208,6 +219,53 @@ def test_run_pod_training_emits_goodput_block():
     assert len(summary["step_walls"]) == 3
     assert summary["phase_seconds"]["compute"] == pytest.approx(
         sum(summary["step_walls"]), rel=0.05)
+
+
+@pytest.mark.parametrize("phase", ["init_params", "place", "h2d",
+                                   "compile_warmup", "sum"])
+def test_run_pod_training_says_what_its_setup_was(pod_summary, phase):
+    """`summary["setup_seconds"]`: what the call spent before its first
+    timed step, by phase."""
+    summary, wall, _ = pod_summary
+    got = summary["setup_seconds"]
+    if phase == "sum":
+        assert list(got) == ["init_params", "place", "h2d",
+                             "compile_warmup"]
+        assert sum(got.values()) + summary["train_seconds"] < wall
+        # the first step is what the goodput ledger books as lost to
+        # recompiling
+        assert summary["goodput"]["lost_s"]["recompiling"] >= \
+            got["compile_warmup"]
+    else:
+        assert got[phase] >= 0.0
+        if phase != "h2d":              # a batch of a few KB
+            assert got[phase] > 0.0
+
+
+@pytest.mark.parametrize("key", ["calls", "seconds", "programs"])
+def test_setup_process_adds_up_the_processs_calls(pod_summary, key):
+    """`summary["setup_process"]`: the set-up of ALL `run_pod_training`
+    calls of the process so far: a driver that calls twice and keeps
+    the second summary still reads what the first, cold call cost."""
+    first, _, second = pod_summary
+    a, b = first["setup_process"], second["setup_process"]
+    if key == "calls":
+        assert b["calls"] == a["calls"] + 1 >= 2
+    elif key == "seconds":
+        assert list(b["seconds"]) == list(second["setup_seconds"])
+        for phase, s in second["setup_seconds"].items():
+            assert b["seconds"][phase] == pytest.approx(
+                a["seconds"][phase] + s)
+    else:
+        # the step's rows as `jit_stats()` keeps them, the calls' own
+        row = b["programs"]["train_step"]
+        assert row["traces"] >= a["programs"]["train_step"]["traces"] >= 1
+        assert row["trace_seconds"] > 0 and row["backend_seconds"] > 0
+        assert (row["trace_seconds"] + row["lower_seconds"]
+                + row["backend_seconds"]) <= row["compile_seconds_total"]
+        b["programs"]["train_step"]["traces"] = -1      # a copy
+        assert first["setup_process"]["programs"]["train_step"][
+            "traces"] >= 1
 
 
 def test_run_pod_training_knob_off_is_clean():
