@@ -51,31 +51,31 @@ Three forms of that one function:
   `A[r, i] = sum_c k_r[c] k_i[c] exp(G_r[c] - G_i[c])` (i < r) and
   `u = (I + A Diag(beta))^-1 (v - (k exp(G)) S_0)` are the
   pseudo-values every row writes, found for all chunks at once by
-  forward substitution (`_unit_lower_solve`); a scan over chunks then
-  carries `S`.  With one decay a head the exponential comes out of the
-  sum over channels, and `A` and its twin for the queries are two
-  `[C, dk] x [dk, C]` matrix products under the `[C, C]` decays, the
-  differences masked to `i <= r` BEFORE the exponential, where they are
-  <= 0.  With one a channel it cannot come out, and the terms are taken
-  by sub-blocks of `_SOLVE_BLOCK` rows (`_decayed_products`).  For a
-  sub-block of keys whose last row is R and a row r below the
-  sub-block, `exp(G_r - G_i) = exp(G_r - G_R) exp(G_R - G_i)`: the
-  reference row lies BETWEEN the two sides (`i <= R < r`) and G does
-  not increase down the rows, so both exponents are <= 0, nothing
-  overflows, and a factor underflows only where the whole term does.
-  The rows below, decayed back to R, times the sub-block's keys,
-  decayed forward to R, are then ONE `[rows, dk] x [dk, 16]` matrix
-  product, three a chunk of 64 (for the k rows and the q rows
-  together).  Only the diagonal sub-blocks (`i` and `r` in one
-  sub-block) keep the masked difference itself, `[16, 16, dk]` for the
-  k rows and again for the q rows, each exponential inside the sum it
-  feeds: no tensor has both chunk axes and the channel axis (`[C, C,
-  dk]` is 2.1 GB a layer at a 2048-token insert of 32 heads of 128, and
-  was written out and read back).  This is not the factoring from the
-  chunk's START: `exp(-G_i)` alone overflows float32 after a few
-  strongly decayed tokens.  Tokens at and past `n_real` (padding) get
-  `g = 0`, `beta = 0`: they leave the state as it is, so the state
-  handed back is the one after the last REAL token.
+  forward substitution in blocks of `_SOLVE_BLOCK` rows whose inverses
+  are taken together, `v` and `k exp(G)` two right-hand sides through
+  them (`_unit_lower_solve`); a scan over chunks then carries `S`.
+  With one decay a head the exponential comes out of the sum over
+  channels, and `A` and its twin for the queries are two `[C, dk] x
+  [dk, C]` matrix products under the `[C, C]` decays, the differences
+  masked to `i <= r` BEFORE the exponential, where they are <= 0.  With
+  one a channel it cannot come out, and the terms are taken by
+  sub-blocks of the same 16 rows (`_decayed_products`).  For a
+  sub-block of keys whose last row is R and a row r below it, `exp(G_r
+  - G_i) = exp(G_r - G_R) exp(G_R - G_i)`: the reference row lies
+  BETWEEN the two sides (`i <= R < r`) and G does not increase down the
+  rows, so both exponents are <= 0, nothing overflows, and a factor
+  underflows only where the whole term does.  The rows below, decayed
+  back to R, times the sub-block's keys, decayed forward to R, are then
+  ONE `[rows, dk] x [dk, 16]` matrix product, three a chunk of 64 (for
+  the k rows and the q rows together).  Only the diagonal sub-blocks
+  keep the masked difference itself, `[16, 16, dk]` for the k rows and
+  again for the q rows, each exponential inside the sum it feeds: no
+  tensor has both chunk axes and the channel axis (`[C, C, dk]` is 2.1
+  GB a layer at a 2048-token insert of 32 heads of 128).  This is not
+  the factoring from the chunk's START: `exp(-G_i)` alone overflows
+  float32 after a few strongly decayed tokens.  Tokens at and past
+  `n_real` (padding) get `g = 0`, `beta = 0`: they leave the state as
+  it is, so what is handed back is the state after the last REAL token.
 
 The stack's layout.  The kernel wants a slot's state of a layer in whole
 (8, 128) float32 tiles, or HBM stores and every copy moves padding: a
@@ -346,31 +346,48 @@ def kda_step_live(S: jax.Array, layer, q: jax.Array, k: jax.Array,
 _SOLVE_BLOCK = 16
 
 
-def _unit_lower_solve(N: jax.Array, rhs: jax.Array) -> jax.Array:
-    """X with (I + N) X = rhs for strictly lower-triangular N [..., C, C]
-    and rhs [..., C, W], by forward substitution in float32 on the
-    vector unit: blocks of `_SOLVE_BLOCK` rows, each first relieved of the
-    blocks before it (one product), then solved row by row.  (The
-    chip's own triangular solve multiplies in one bf16 pass; a Neumann
-    product of powers of N cancels badly when keys repeat.)"""
+def _unit_lower_solve(N: jax.Array, *rhs: jax.Array) -> list:
+    """X with (I + N) X = rhs, for each rhs [..., C, W], for strictly
+    lower-triangular N [..., C, C], by forward substitution in float32,
+    in blocks of `_SOLVE_BLOCK` rows.  The diagonal blocks' inverses
+    `T_m = (I + N_mm)^-1` depend on no block before them, so all of
+    them, of every chunk and head, are taken in one pass on the vector
+    unit, the blocks side by side in the lanes (`[row, column, n]`: a
+    block of 16 x 16 fills an eighth of its tiles, n blocks a row of
+    them all): row i is `e_i - N_mm[i, :i] T[:i]`, its coefficients one
+    slice, the sum over the rows before it a sum over the major axis,
+    the 15 steps written out over the list of rows so far (no row is
+    written into a block every step rewrites, none still zero is
+    read).  The rows of a right-hand side then go block by block as
+    they did, each block relieved of the blocks before it (one product)
+    and multiplied by its inverse (another, `[block, block] x [block,
+    W]`); several right-hand sides go through the same inverses apart,
+    never joined and split again.  (The chip's own triangular solve
+    multiplies in one bf16 pass; a Neumann product of powers of N
+    cancels badly when keys repeat.)"""
     C, b = N.shape[-2], _SOLVE_BLOCK
     assert C % b == 0, (C, b)
-    out = []
-    for s in range(0, C, b):
-        r = rhs[..., s:s + b, :]
-        if out:
-            r = r - jnp.einsum("...ri,...iw->...rw", N[..., s:s + b, :s],
-                               jnp.concatenate(out, -2), precision=_HI)
-        n = N[..., s:s + b, s:s + b]
+    D = jnp.stack([N[..., s:s + b, s:s + b] for s in range(0, C, b)], -3)
+    D = jnp.moveaxis(D.reshape((-1, b, b)), 0, -1)          # [i, m, n]
+    eye = jnp.eye(b, dtype=N.dtype)[:, :, None]
+    rows = [jnp.broadcast_to(eye[0], D.shape[1:])]
+    for i in range(1, b):
+        rows.append(eye[i] - jnp.sum(D[i, :i, None] * jnp.stack(rows), 0))
+    T = jnp.moveaxis(jnp.stack(rows), -1, 0).reshape(
+        N.shape[:-2] + (C // b, b, b))
 
-        def row(i, x, n=n):
-            ni = lax.dynamic_index_in_dim(n, i, -2, keepdims=False)
-            xi = lax.dynamic_index_in_dim(x, i, -2, keepdims=False) \
-                - jnp.sum(ni[..., None] * x, axis=-2)        # rows >= i: n 0
-            return lax.dynamic_update_index_in_dim(x, xi, i, -2)
+    def blocks(x):
+        out = []
+        for m, s in enumerate(range(0, C, b)):
+            r = x[..., s:s + b, :]
+            if out:
+                r = r - jnp.einsum("...ri,...iw->...rw", N[..., s:s + b, :s],
+                                   jnp.concatenate(out, -2), precision=_HI)
+            out.append(jnp.einsum("...ri,...iw->...rw", T[..., m, :, :], r,
+                                  precision=_HI))
+        return jnp.concatenate(out, -2)
 
-        out.append(lax.fori_loop(1, b, row, r))
-    return jnp.concatenate(out, -2)
+    return [blocks(x) for x in rhs]
 
 
 def _decayed_products(x: jax.Array, k: jax.Array, G: jax.Array
@@ -465,9 +482,7 @@ def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     A = jnp.where(jnp.tril(lower, -1), A, 0.0)               # i <  r
     # (I + A Diag(beta)) u = v - (k exp(G)) S_0: solve for both terms
     k_in = k * jnp.exp(G)
-    W = _unit_lower_solve(A * beta[..., None, :],
-                          jnp.concatenate([v, k_in], -1))
-    Wv, Wk = W[..., :dv], W[..., dv:]
+    Wv, Wk = _unit_lower_solve(A * beta[..., None, :], v, k_in)
     Aq = Aq * beta[..., None, :]
     q_in = q * jnp.exp(G)
     G_end = G[..., -1:, :]

@@ -195,7 +195,7 @@ PROGRAM_TEXT_SHA256 = {
     ("agent-decode-hybrid", "tick"):
         "2211d4c0ca524e4343cc7997c80ca3f11d2216fe85bb322cc9d86730d0d1dee7",
     ("agent-decode-hybrid", "insert"):
-        "16968ac98f4fa7a9e2410480aaf3a25b69ce8608a92787dbe40a36aabb73d9ca",
+        "066f6804f2b815eecdd1d5a0e6b0f9eccb64cbca6aaba16f7159554a7342156c",
     ("compose-decode-conv-moe", "tick"):
         "eb5930149d004d30510feb1230c29d9b3055bcea6b41be400a885904f1a6af07",
     ("compose-decode-conv-moe", "insert"):
@@ -207,7 +207,7 @@ PROGRAM_TEXT_SHA256 = {
     ("reason-decode-gdn-hybrid", "tick"):
         "7db8f02c0133bc1b6757a1eda4a18cfbf6f2ae756478d598ac520e07ef1199b1",
     ("reason-decode-gdn-hybrid", "insert"):
-        "e019023848c69523dced14ed9ddba01bd1006fe4338d20b640381aa55409729d",
+        "a71791eff8285121834af1b0c9f4e8fa126ddecc0c6c26257cbec9d5793fc5d0",
     ("longform-decode-zero-moe", "tick"):
         "cde3ce8aace9a4c1aced034762e8612cbc39f0080091b150a14a97330fa938b7",
     ("longform-decode-zero-moe", "insert"):
@@ -368,7 +368,7 @@ def grouped_products_are_the_kernel(text, n_moe_layers):
     assert "ragged" not in without_metadata(text)
 
 
-def delta_rule_insert_holds_no_channel_tensor(cell, dk, temp_gib):
+def delta_rule_insert_holds_no_channel_tensor(cell, dk, dv, temp_gib):
     """The largest insert of a delta-rule cell (Kimi at its 2048 bucket,
     one decay a key channel; Olmo-Hybrid at 512, one a head): no float32
     result, fused computations' own included, has both of
@@ -380,18 +380,27 @@ def delta_rule_insert_holds_no_channel_tensor(cell, dk, temp_gib):
     the a-head arm never had one, its decays are `[.., 64, 64]`).
     Temporaries: 1.30 GiB against the 2.41 the `[C, C, dk]` form took
     for Kimi (the history's softmax holds them now), 0.45 for
-    Olmo-Hybrid.  The two cells' cases live in two files, each beside
-    the other readers of its cell's insert."""
+    Olmo-Hybrid.  Nor does a row loop walk the solve's right-hand
+    sides again: no `dynamic-update-slice`, a loop body's or a fusion's
+    own, writes into a `[.., 16, dv + dk]` block (`_unit_lower_solve`
+    wrote 60 rows a chunk that way until PR 50, each a pass over every
+    tile of the block) nor into one side's `[.., 16, dv]` or `[.., 16,
+    dk]`.  The two cells' cases live in two files, each beside the
+    other readers of its cell's insert."""
     from ray_tpu.ops import kda
 
     eng = serving_cell(cell)
     C, b = kda.CHUNK, kda._SOLVE_BLOCK
     assert eng.config.prefill_buckets[-1] % C == 0
     compiled = cell_program(eng.name, "insert")
-    shapes = set().union(*(
-        shapes for _, shapes in results_of(compiled.text)))
+    results = results_of(compiled.text)
+    shapes = set().union(*(shapes for _, shapes in results))
     assert any(s[-2:] == (C, C) for s in shapes)            # parsed
     if dk == 128:
         assert any(s[-3:] == (2 * b, b, dk) for s in shapes)
     assert not sorted(s for s in shapes if s[-3:] == (C, C, dk))
+    assert "dynamic-update-slice" in {op for op, _ in results}   # parsed
+    assert not sorted(s for op, found in results for s in found
+                      if op == "dynamic-update-slice"
+                      and s[-2:-1] == (b,) and s[-1] in (dv + dk, dv, dk))
     assert compiled.memory.temp_size_in_bytes < temp_gib * GIB

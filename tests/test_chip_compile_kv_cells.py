@@ -231,10 +231,10 @@ def test_gdn_hybrid_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     assert m.temp_size_in_bytes < 0.5 * GIB
 
 
-@pytest.mark.parametrize("cell, dk, temp_gib", [("reason-decode-gdn-hybrid", 96, 0.5)])
+@pytest.mark.parametrize("cell, dk, dv, temp_gib", [("reason-decode-gdn-hybrid", 96, 192, 0.5)])
 def test_delta_rule_inserts_hold_no_chunk_by_chunk_by_channel_tensor(
-        one_chip, on_tpu, cell, dk, temp_gib):
-    delta_rule_insert_holds_no_channel_tensor(cell, dk, temp_gib)
+        one_chip, on_tpu, cell, dk, dv, temp_gib):
+    delta_rule_insert_holds_no_channel_tensor(cell, dk, dv, temp_gib)
 
 
 @pytest.mark.parametrize("program", ["tick", "insert"])

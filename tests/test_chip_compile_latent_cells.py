@@ -219,10 +219,10 @@ def test_hybrid_tick_steps_live_states_where_they_lie(one_chip, on_tpu):
     assert "kda_step" not in cell_program(eng.name, "insert").plain  # pinned
 
 
-@pytest.mark.parametrize("cell, dk, temp_gib", [("agent-decode-hybrid", 128, 1.5)])
+@pytest.mark.parametrize("cell, dk, dv, temp_gib", [("agent-decode-hybrid", 128, 128, 1.5)])
 def test_delta_rule_inserts_hold_no_chunk_by_chunk_by_channel_tensor(
-        one_chip, on_tpu, cell, dk, temp_gib):
-    delta_rule_insert_holds_no_channel_tensor(cell, dk, temp_gib)
+        one_chip, on_tpu, cell, dk, dv, temp_gib):
+    delta_rule_insert_holds_no_channel_tensor(cell, dk, dv, temp_gib)
 
 
 @pytest.mark.parametrize("cell, temp_gib", [

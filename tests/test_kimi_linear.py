@@ -22,8 +22,10 @@ Tolerances and their reasons
   token (where a factored exp(-G) would overflow after 8 tokens), at
   chunks of 16 (one sub-block) and of 64 (four, the terms between them
   matrix products around a reference row); 2e-5 where strong and weak
-  tokens alternate inside a chunk of 64 (reads 7.9e-6, with the
-  `[C, C, dk]` reduction as with the products: the 64-row solve).
+  tokens alternate inside a chunk of 64 (reads 6.4e-6 on outputs and
+  1.5e-5 on a state, with the `[C, C, dk]` reduction as with the
+  products and with either solve: the float32 `cumsum` of the
+  log-decay, see the test's own words).
 * The engine tests (`test_kimi_linear_engine.py`, a file of its own so
   that `--dist loadfile` can give it another worker) serve greedy tokens
   in float32; each served token's reference logit lies within 1e-4 of
@@ -280,10 +282,17 @@ def test_chunkwise_kda_by_sub_blocks_equals_recurrence(case, tol, T, n_real):
     so the in-chunk terms between sub-blocks are the matrix products
     around a reference row (`ops.kda._decayed_products`), several chunks
     from a non-zero state, the last one ragged, one row short of T.
-    (`mixed` reads 7.9e-6 with the products as with the `[C, C, dk]`
-    reduction they replace, 1.1e-6 at chunks of 16: the 64-row solve's
-    rounding, where weak rows keep `A` near 1 between strong ones; the
-    products themselves are held to float64 in the next test.)"""
+    (`mixed` reads 6.4e-6 on the outputs and 1.5e-5 on the state, with
+    the products as with the `[C, C, dk]` reduction they replace and
+    with either form of the solve, to the last digit; 1.1e-6 at chunks
+    of 16.  It is the cumulative log-decay `G` in float32: by a chunk's
+    64th row it reaches -365, where float32 steps by 3e-5, and a
+    difference `G_r - G_i` between two weak rows, itself 1e-6 to 1e-4,
+    carries that step into its `exp`.  With the `cumsum` alone taken in
+    float64 the case reads 1.7e-7 / 4.9e-8 against a float64 recurrence,
+    with the solve alone in float64 the same 9.9e-6 / 6.7e-6 as with
+    nothing (PR 50).  The products themselves are held to float64 in
+    the next test, the solve in the one after.)"""
     B, H, dk, dv = 2, 3, 16, 8
     ks = jax.random.split(jax.random.key(1), 7)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
@@ -334,6 +343,64 @@ def test_decayed_products_neither_overflow_nor_lose_a_term(per_token):
     assert np.abs(want[..., 40:, :16]).max() > 1e-2     # across sub-blocks
     assert np.abs(got - want).max() < 1e-5 * np.abs(want).max()
     assert not got[..., ~lower].any()
+
+
+def _repeated_keys(key, C, strength):
+    """`A Diag(beta)` of a chunk whose keys all but repeat (one direction
+    a head, 5% of noise a row) at write strengths of 0.9-1.0 times
+    `strength`: entries of 0.85-1.0 (times `strength`) down whole
+    columns."""
+    ks = jax.random.split(key, 3)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    k = unit(unit(jax.random.normal(ks[0], (3, 1, 16)))
+             + 0.05 * jax.random.normal(ks[1], (3, C, 16)))
+    beta = strength * jax.random.uniform(ks[2], (3, C), minval=0.9,
+                                         maxval=1.0)
+    N = jnp.tril(jnp.einsum("hrc,hic->hri", k, k) * beta[:, None, :], -1)
+    assert N[:, 1:, 0].min() > 0.85 * strength
+    return N
+
+
+@pytest.mark.parametrize("keys, C, W", [
+    ("random", 64, 256), ("random", 64, 288), ("random", 16, 256),
+    ("random", 16, 288), ("repeated", 64, 256), ("repeated_twice", 64, 288),
+    ("repeated", 16, 288)])
+def test_unit_lower_solve_is_forward_substitution(keys, C, W):
+    """`_unit_lower_solve` against forward substitution in float64, row
+    by row: at a chunk of 64 (four diagonal blocks inverted together,
+    then the block recurrence) and of 16 (one block, no recurrence), at
+    the two delta-rule cells' right-hand sides (`dv + dk` = 128 + 128,
+    192 + 96: two sides through the same inverses),
+    on random strictly-lower matrices and on the hard ones: keys that
+    repeat, at write strengths near 1 and (`repeated_twice`, the gated
+    delta rule's) near 2, where the product of `I + N^(2^j)` that is
+    the same inverse on paper cancels to nothing in float32."""
+    from ray_tpu.ops.kda import _unit_lower_solve
+
+    ks = jax.random.split(jax.random.key(3), 3)
+    if keys == "random":
+        N = jnp.tril(0.3 * jax.random.normal(ks[0], (2, 3, C, C)), -1)
+    else:
+        N = _repeated_keys(ks[0], C, 2.0 if keys == "repeated_twice" else 1.0)
+    tol = 4e-6 if keys == "repeated_twice" else 1e-6
+    rhs = jax.random.normal(ks[1], N.shape[:-1] + (W,))
+    want = np.array(rhs, np.float64)
+    N64 = np.asarray(N, np.float64)
+    for i in range(1, C):
+        want[..., i, :] -= np.einsum("...m,...mw->...w", N64[..., i, :i],
+                                     want[..., :i, :])
+    dv = {256: 128, 288: 192}[W]        # the values' side, then the keys'
+    got = np.concatenate(jax.jit(_unit_lower_solve)(
+        N, rhs[..., :dv], rhs[..., dv:]), -1)
+    assert np.abs(got - want).max() < tol * np.abs(want).max()
+    if keys != "random" and C == 64:
+        eye, hi = jnp.eye(C), jax.lax.Precision.HIGHEST
+        series, power = eye - N, N
+        for _ in range(5):              # (I - N)(I + N^2) .. (I + N^32)
+            power = jnp.matmul(power, power, precision=hi)
+            series = jnp.matmul(series, eye + power, precision=hi)
+        lost = np.asarray(jnp.matmul(series, rhs, precision=hi)) - want
+        assert np.abs(lost).max() > 1e3 * np.abs(want).max()
 
 
 # ----------------------------------- (e) the shares add up to the layer
