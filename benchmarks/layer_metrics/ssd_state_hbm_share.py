@@ -1,0 +1,36 @@
+"""The Mamba-2 decode step against the bandwidth roofline: the LEAST the
+ticks of the traced interval had to move of state (the live slots of
+those ticks, as the engine writes them on every
+`llm_engine.tick_dispatch` span inside the interval (`live=`), x every
+Mamba-2 layer's state of one sequence read once and written once,
+`counts_ssd_moe.step_state_traffic`: the MATHEMATICS' heads x head_dim
+x state size float32, whatever layout the program keeps; the tail, dt,
+X, B and C are not counted) over the chip's peak bandwidth, over the
+device time the interval's ticks spent under `ssd/state` (the step
+kernel, the decay in front of it and `D X` behind it).  It cannot pass
+100: a state cannot be read and written faster than the peak.
+
+Both sides are of the traced interval, as in `ssm_state_hbm_share`: the
+mean of `live=` over the dispatches is laid on the executions' number."""
+import counts_ssd_moe as K
+import program_spans as PS
+import scope_paths as SP
+
+DISPATCH = "llm_engine.tick_dispatch"
+
+
+def read(run):
+    if run["trace"] is None or "ssm_state_size" not in run["config"]:
+        return None
+    prog = PS.load(run)
+    got = SP.program_seconds(run, "jit_llm_engine_tick", "ssd", "state")
+    if prog is None or got is None or not got[0]:
+        return None
+    live = [int(sp[3]["live"]) for sp in PS.in_window(
+        prog, run["window"], DISPATCH) if "live" in sp[3]]
+    if not live:
+        return None
+    seconds, _, n_ticks = got
+    need = (sum(live) / len(live)) * n_ticks \
+        * K.step_state_traffic(run["config"])
+    return 100.0 * need / run["peaks"]["hbm_bytes_per_s"] / seconds

@@ -230,28 +230,41 @@ def serving_grouped_path(config, slots: int) -> str:
         (held, config.dim, config.expert_hidden_dim), config.dtype)
 
 
-def _grouped_product(sizes, rows, w_shape, dtype):
+def _grouped_product(sizes, rows, w_shape, dtype, layer=None):
     """The ONE call site of a grouped product: (xs [rows, K], w [E, K,
     N]) -> [rows, N] over `sizes`, by the Pallas kernel (its walk
-    planned once here, shared by the three products) or by
-    `lax.ragged_dot`."""
+    planned once here, shared by the layer's products) or by
+    `lax.ragged_dot`; `by_rows=True` for a w [E, N, K], and with `layer`
+    w is a stack of banks of which that one is multiplied
+    (`ops.grouped_matmul.grouped_matmul`)."""
     if grouped_path(rows, w_shape, dtype) == "kernel":
         scalars = grouped_matmul.plan(sizes, rows)
-        return lambda xs, w: grouped_matmul.grouped_matmul(
-            xs, w, sizes, scalars)
-    return lambda xs, w: jax.lax.ragged_dot(xs, w, sizes)
+        return lambda xs, w, by_rows=False: grouped_matmul.grouped_matmul(
+            xs, w, sizes, scalars, by_rows, layer)
+    return lambda xs, w, by_rows=False: grouped_matmul.ragged(
+        xs, w if layer is None else w[layer], sizes, by_rows)
 
 
 def dropless_moe(x: jax.Array, params: Dict[str, jax.Array],
                  routing: Routing, live: Optional[jax.Array] = None,
-                 share: Optional[Tuple[int, int]] = None, n_zero: int = 0
+                 share: Optional[Tuple[int, int]] = None, n_zero: int = 0,
+                 layer: Optional[jax.Array] = None
                  ) -> Tuple[jax.Array, jax.Array]:
     """x [T, D] -> (y [T, D], tokens routed to each HELD expert [E]
     int32; with `n_zero`, one more entry: the zero picks).
 
     params: `router` [D, R], what `routing` reads beside it, and the
     held experts' SwiGLU weights `w_gate` / `w_up` [E, D, F], `w_down`
-    [E, F, D].  Router logits, scores and selection are float32 (a
+    [E, F, D].  The expert's FORM is data: where `params` has no
+    `w_gate` an expert is `w_down relu(w_up x)^2`, two grouped products
+    and no gate, and `w_up` lies [E, F, D], its output channels down the
+    rows as `w_down`'s input channels do (a width F of half lane rows,
+    1856, then stores no padded lane: `ops.grouped_matmul`'s `by_rows`).
+    With `layer` (a traced index: the repeat of a `lax.scan` over
+    stacked layers) the expert weights are STACKS [L, E, ..] handed in
+    whole and this layer's bank is read where it lies (a bank cut out
+    of the stack by the scan is copied for every product).
+    Router logits, scores and selection are float32 (a
     float32 matrix product, not the chip's one-pass default); the expert
     products run in x's dtype.  No capacity: the T * k assignments are
     sorted by expert, each expert's rows are one group of three grouped
@@ -291,7 +304,8 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array],
     accumulation, one rounding."""
     T, D = x.shape
     R = params["router"].shape[-1]
-    E = params["w_gate"].shape[0]
+    gated = "w_gate" in params
+    E = params["w_down"].shape[-3]
     shards = 1 if share is None else share[1]
     if R != shards * E + n_zero:
         raise ValueError(
@@ -323,10 +337,16 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array],
         sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
         xs = x[order // k]                                  # [T * k, D]
         dt = x.dtype
-        product = _grouped_product(sizes, T * k, params["w_gate"].shape, dt)
-        gate = product(xs, params["w_gate"].astype(dt))
-        up = product(xs, params["w_up"].astype(dt))
-        ys = product(jax.nn.silu(gate) * up, params["w_down"].astype(dt))
+        F = params["w_down"].shape[-2]
+        product = _grouped_product(sizes, T * k, (E, D, F), dt, layer)
+        if gated:
+            gate = product(xs, params["w_gate"].astype(dt))
+            up = product(xs, params["w_up"].astype(dt))
+            ys = product(jax.nn.silu(gate) * up, params["w_down"].astype(dt))
+        else:
+            up = product(xs, params["w_up"].astype(dt), by_rows=True)
+            ys = product(jnp.square(jax.nn.relu(up)),
+                         params["w_down"].astype(dt))
         # rows past the last group belong to no expert: whatever the
         # grouped product left there must not reach a token
         ys = jnp.where((jnp.arange(T * k) < sizes.sum())[:, None], ys, 0)

@@ -62,7 +62,8 @@ longer than a bucket goes into its slot chunk by chunk.
     and convolution tail of `models/kimi_linear.py` and
     `models/gdn_hybrid.py`, the convolution tail of
     `models/conv_moe.py`, the selective scan's state and tail of
-    `models/sambay.py`).
+    `models/sambay.py`, the Mamba-2 state and tail of
+    `models/nemotron_h.py`: each but sambay's beside a `full` pool).
     A model that has it takes and returns it beside the pool:
         prefill(..., n_real, state) -> (hidden, rows, state), `state`
         {leaf: [L', ...]} ONE slot's rows, as they stood after the
@@ -86,7 +87,10 @@ longer than a bucket goes into its slot chunk by chunk.
     these pools on this backend (`engine.stats()` shows it);
     grouped_matmul(config, num_slots) -> "kernel" | "xla", the same for
     the experts' grouped products at the tick's shape
-    (`models/moe.py::serving_grouped_path`);
+    (`models/moe.py::serving_grouped_path`; what the products ARE is the
+    parameters' to say: where an expert layer's tree has no `w_gate`,
+    `dropless_moe` reads `w_up` [E, F, D] and `w_down` [E, F, D] and
+    runs `w_down relu(w_up x)^2`, two products, `models/nemotron_h.py`);
     insert_attention(config, start, bucket, max_seq_len) -> (form,
     tiles run, tiles dense), host arithmetic for a model whose
     `prefill` walks its history through

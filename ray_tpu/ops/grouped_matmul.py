@@ -69,7 +69,12 @@ def col_tile(k: int, n: int) -> int:
     whose weight block `[k, tn]` of bf16 stays under 8 MiB a buffer,
     which is all of `n` at every expert the cells hold (3.1 to 7.3 MB:
     x is then read once and a group's matrix in one contiguous copy;
-    blocks of 256 columns lost 7-26%, PERF.md section 6, PR 33)."""
+    blocks of 256 columns lost 7-26%, PERF.md section 6, PR 33).  An
+    `n` that is no whole lane rows (1856 = 14.5 of them) has ONE legal
+    block, all of it: a block as wide as the array needs no lane
+    multiple, any narrower one does."""
+    if n % _LANE:
+        return n
     best = _LANE
     for tn in range(_LANE, n + 1, _LANE):
         if n % tn == 0 and k * tn * 2 <= 8 * 2 ** 20:
@@ -77,16 +82,25 @@ def col_tile(k: int, n: int) -> int:
     return best
 
 
+def _tiles(width: int) -> bool:
+    """Whole lane rows; or, wider than one, whole half rows (the tiny
+    models' widths of 64 stay with `lax.ragged_dot`)."""
+    return width % _LANE == 0 or (width > _LANE and width % (_LANE // 2) == 0)
+
+
 def engages(m: int, g: int, k: int, n: int, dtype) -> bool:
     """Whether `dropless_moe` runs the kernel for `[m, k] x [g, k, n]`:
     `ops.attention`'s rule for the backend (a TPU always, off TPU only
     when a test forces the interpreter), bf16 operands, and `k` and `n`
-    in whole lane rows.  No bound on `m` or `m / g`: on a v5e the kernel
+    in whole lane rows, or past one lane row in whole HALF rows (`_tiles`:
+    an expert width of 1856 = 29 x 64 is walked as one whole-width
+    block, the weights as they are stored).  No bound on `m` or `m / g`:
+    on a v5e the kernel
     took 0.41 to 0.65 of `lax.ragged_dot`'s time at every shape the
     cells compile, 384 x 128 experts to 16384 x 64 (PERF.md section 6,
     PR 33)."""
     del m, g
-    tiles = (dtype == jnp.bfloat16 and k % _LANE == 0 and n % _LANE == 0)
+    tiles = dtype == jnp.bfloat16 and _tiles(k) and _tiles(n)
     return tiles and (_attention._on_tpu()
                       or _attention.FORCE_PALLAS_INTERPRET)
 
@@ -122,15 +136,23 @@ def plan(sizes: jax.Array, m: int, tm: Optional[int] = None):
             pick(starts[None, :]), pick(ends[None, :]))
 
 
-def _kernel(n_ref, group_ref, tile_ref, lo_ref, hi_ref, x_ref, w_ref,
-            o_ref, *, tm):
+def _kernel(n_ref, group_ref, tile_ref, lo_ref, hi_ref, *refs, tm,
+            by_rows=False):
+    # refs: x, w, o; in front of them the layer's index where w is a
+    # stack of banks (only the index maps read it)
     del n_ref, group_ref
+    x_ref, w_ref, o_ref = refs[-3:]
     v = pl.program_id(1)
     t = tile_ref[v]
     row = t * tm + lax.broadcasted_iota(jnp.int32, o_ref.shape, 0)
     own = (row >= lo_ref[v]) & (row < hi_ref[v])
-    y = jnp.dot(x_ref[...], w_ref[...],
-                preferred_element_type=jnp.float32).astype(o_ref.dtype)
+    if by_rows:     # the block is [tn, k]: both operands' lanes contract
+        y = lax.dot_general(x_ref[...], w_ref[...], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    else:
+        y = jnp.dot(x_ref[...], w_ref[...],
+                    preferred_element_type=jnp.float32)
+    y = y.astype(o_ref.dtype)
     fresh = (v == 0) | (tile_ref[jnp.maximum(v - 1, 0)] != t)
 
     @pl.when(fresh)
@@ -142,25 +164,31 @@ def _kernel(n_ref, group_ref, tile_ref, lo_ref, hi_ref, x_ref, w_ref,
         o_ref[...] = jnp.where(own, y, o_ref[...])
 
 
-def _call(xs, w, scalars, tm, tn):
+def _call(xs, w, scalars, tm, tn, by_rows=False):
     m, k = xs.shape
-    n = w.shape[2]
+    n = w.shape[-2] if by_rows else w.shape[-1]
     interpret = not _attention._on_tpu()
+    # a group's block of w: [tn, k] of its rows, or [k, tn] of its columns
+    block = (None, tn, k) if by_rows else (None, k, tn)
+    at = (lambda g, j: (g, j, 0)) if by_rows else (lambda g, j: (g, 0, j))
+    if w.ndim == 4:     # a stack of banks: the sixth scalar is the layer
+        block = (None,) + block
+        w_map = lambda j, v, n_, grp, til, lo, hi, lay: \
+            (lay[0],) + at(grp[v], j)
+    else:
+        w_map = lambda j, v, n_, grp, til, lo, hi: at(grp[v], j)
     return pl.pallas_call(
-        functools.partial(_kernel, tm=tm),
+        functools.partial(_kernel, tm=tm, by_rows=by_rows),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=len(scalars),
             grid=(n // tn, scalars[0][0]),
             in_specs=[
-                pl.BlockSpec((tm, k),
-                             lambda j, v, n_, grp, til, lo, hi: (til[v], 0)),
-                pl.BlockSpec((None, k, tn),
-                             lambda j, v, n_, grp, til, lo, hi:
-                             (grp[v], 0, j)),
+                pl.BlockSpec((tm, k), lambda j, v, n_, grp, til, *_:
+                             (til[v], 0)),
+                pl.BlockSpec(block, w_map),
             ],
             out_specs=pl.BlockSpec(
-                (tm, tn),
-                lambda j, v, n_, grp, til, lo, hi: (til[v], j))),
+                (tm, tn), lambda j, v, n_, grp, til, *_: (til[v], j))),
         out_shape=jax.ShapeDtypeStruct((m, n), xs.dtype),
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
@@ -170,20 +198,28 @@ def _call(xs, w, scalars, tm, tn):
     )(*scalars, xs, w)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _product(xs, w, sizes, scalars, tm, tn):
-    return _call(xs, w, scalars, tm, tn)
+def ragged(xs, w, sizes, by_rows=False):
+    """`lax.ragged_dot`, the reference and the path wherever the kernel
+    does not engage; `by_rows` as `grouped_matmul` takes it."""
+    return lax.ragged_dot(xs, jnp.swapaxes(w, 1, 2) if by_rows else w, sizes)
 
 
-def _product_fwd(xs, w, sizes, scalars, tm, tn):
-    return _call(xs, w, scalars, tm, tn), (xs, w, sizes)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _product(xs, w, sizes, scalars, tm, tn, by_rows=False):
+    return _call(xs, w, scalars, tm, tn, by_rows)
 
 
-def _product_bwd(tm, tn, res, ct):
+def _product_fwd(xs, w, sizes, scalars, tm, tn, by_rows=False):
+    return _call(xs, w, scalars, tm, tn, by_rows), (xs, w, sizes)
+
+
+def _product_bwd(tm, tn, by_rows, res, ct):
     # nobody differentiates the serving programs; a derivative that is
     # asked for is `lax.ragged_dot`'s, not silently something else
     xs, w, sizes = res
-    _, vjp = jax.vjp(lambda a, b: lax.ragged_dot(a, b, sizes), xs, w)
+    if w.ndim == 4:
+        raise NotImplementedError("no derivative through a stack of banks")
+    _, vjp = jax.vjp(lambda a, b: ragged(a, b, sizes, by_rows), xs, w)
     return (*vjp(ct), None, None)
 
 
@@ -192,15 +228,36 @@ _product.defvjp(_product_fwd, _product_bwd)
 # kernel once a shape, not once a call site: the call sites of one
 # shape share one function of the lowered module (8 s of a warm
 # set-up otherwise, PERF.md section 6, PR 33).  XLA inlines it.
-_jit_product = jax.jit(_product, static_argnums=(4, 5))
+_jit_product = jax.jit(_product, static_argnums=(4, 5, 6))
 
 
 def grouped_matmul(xs: jax.Array, w: jax.Array, sizes: jax.Array,
-                   scalars=None) -> jax.Array:
+                   scalars=None, by_rows: bool = False,
+                   layer: Optional[jax.Array] = None) -> jax.Array:
     """`lax.ragged_dot(xs, w, sizes)` through the kernel.  `scalars` =
-    `plan(sizes, M)`, computed here when the caller has none to share."""
+    `plan(sizes, M)`, computed here when the caller has none to share.
+
+    `by_rows`: w is [G, N, K], a group's matrix with its OUTPUT
+    channels down the rows (as a checkpoint stores a projection), and
+    the product is `xs w[g]^T`: both operands contract over their lanes,
+    which the matrix unit takes as it takes the other form.  For an N
+    that is no whole lane rows this is the form that stores no padding:
+    `[G, K, 1856]` lies in HBM with every row padded to 1920 lanes, and
+    the deviceless v5e compile of the kernel over it copies the whole
+    bank into that layout first (330 MB a product at 32 x 2688 x 1856);
+    `[G, 1856, K]` lies as published.
+
+    `layer`: w is a STACK of banks [L, G, ..] (the layers of a
+    `lax.scan`) and the product is bank `layer`'s (traced).  The stack
+    stays whole in HBM and the index is one more scalar the kernel adds
+    to its block addresses, never a slice: cut out by the scan, a bank
+    of 320 MB was COPIED for each product of each layer of each tick
+    (1.9 ms a copy, twelve a tick: my chip runs, PR 52)."""
     m, k = xs.shape
     if scalars is None:
         scalars = plan(sizes, m)
-    return _jit_product(xs, w, sizes, scalars, row_tile(m),
-                        col_tile(k, w.shape[2]))
+    if layer is not None:
+        scalars = (*scalars, jnp.reshape(layer, (1,)).astype(jnp.int32))
+    n = w.shape[-2] if by_rows else w.shape[-1]
+    return _jit_product(xs, w, sizes, scalars, row_tile(m), col_tile(k, n),
+                        by_rows)

@@ -222,6 +222,25 @@ def plan(tables: jax.Array, qpos: jax.Array, active, block_size: int,
             qpos.reshape(-1), tables.reshape(-1).astype(jnp.int32))
 
 
+# Scalar memory a call's prefetched operands may take together: the
+# chip has 1 MiB, the compiler keeps some of it.
+_SMEM_BUDGET = 768 * 2 ** 10
+
+
+def slot_parts(slots: int, table_width: int,
+               chunk: int = CHUNK_BLOCKS) -> int:
+    """Into how many equal parts a caller cuts its `slots` sequences so
+    that ONE call's scalars (`plan`: a sequence's table row, its chunks'
+    two lists, its length and position) fit scalar memory: 1 at every
+    geometry but a very wide one (384 slots x 768 blocks of table are
+    1.18 MB, which the v5e compiler refuses; two calls of 192 fit).  A
+    part is planned and attended on its own; the pool is one."""
+    chunks = -(-table_width // min(chunk, table_width))
+    a_slot = 4 * (table_width + 2 * chunks + 2)
+    return next(p for p in range(1, slots + 1)
+                if slots % p == 0 and slots // p * a_slot <= _SMEM_BUDGET)
+
+
 def _block_copy(pool, layer, phys, buf, slot, t, rows, sem):
     """One block of the flat pool, (layer, phys), to rows [t * rows,
     (t + 1) * rows) of half `slot` of a chunk buffer."""

@@ -182,7 +182,11 @@ def program(key, build):
 # shape); `mixed-decode-window-moe`'s insert is PR 48's (its pieces
 # attend through `ops.attention.flash_prefill`; the other seventeen
 # texts stood, `think-decode-ssm-yoco`'s insert among them: its window
-# layers' 1536 key rows stay under `prefill_engages`' sizes).
+# layers' 1536 key rows stay under `prefill_engages`' sizes);
+# `swarm-decode-ssd-moe`'s two are PR 52's, which brought the cell (and
+# left the other eighteen texts as they were: `dropless_moe` reads the
+# expert's form off its parameters and the grouped kernel's second form
+# is another static branch).
 PROGRAM_TEXT_SHA256 = {
     ("chat-decode", "tick"):
         "48a91f54a548addd9d951f33258125cd66601f6eb5de512b9f23800388b2ae93",
@@ -216,6 +220,10 @@ PROGRAM_TEXT_SHA256 = {
         "99a77e55f9f092c0eb144ba97df9bcc5d437802ead9bc1c5f5e8432a6194d87a",
     ("think-decode-ssm-yoco", "insert"):
         "5c14d60424211c58a2426a2ca8413dc184c1048c046f547d6516cdf605860e2b",
+    ("swarm-decode-ssd-moe", "tick"):
+        "c41b7dff23ade0c8c0b49724b4a7b1d7a2d7d27d324953aee70311284b16defe",
+    ("swarm-decode-ssd-moe", "insert"):
+        "a229313f2e5a7f3c7a4e24e98f969829f08105dd9157cc2449df3fb04b3f79b7",
     ("two small layers", "train step, scope names apart"):
         "1d700902d1c682aaec9e4b41afc84286eb99ba0c4758f7046d1be38b1848714c",
     ("two small layers", "train step, its kernels"):

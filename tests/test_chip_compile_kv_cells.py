@@ -1,7 +1,7 @@
 """Deviceless v5e compiles of the benchmark's serving cells that keep K
 and V heads in paged pools (`compose-decode-conv-moe`,
 `mixed-decode-window-moe`, `reason-decode-gdn-hybrid`,
-`think-decode-ssm-yoco`): the decode tick
+`think-decode-ssm-yoco`, `swarm-decode-ssd-moe`): the decode tick
 and the largest insert of each, as the chip runs them, at the geometry
 its files state.  `chip_programs.py` has the rules these files keep, the
 fixtures, the one compile a program (`cell_program`, which also holds
@@ -294,4 +294,79 @@ def test_sambay_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
           m.temp_size_in_bytes / GIB, "args", m.argument_size_in_bytes / GIB)
     assert m.alias_size_in_bytes >= kept                # all in place
     assert m.temp_size_in_bytes < (0.15 if program == "tick" else 0.5) * GIB
+    assert compiled.hbm_gib < V5E_HBM_GIB - 0.5
+
+
+@pytest.mark.parametrize("program", ["tick", "insert"])
+def test_ssd_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
+    """The engine's decode tick and its largest insert at the geometry of
+    the benchmark's `swarm-decode-ssd-moe` cell (6 Mamba-2 layers whose
+    state of 64 heads x 64 x 128 float32 the engine keeps by slot, 6
+    layers of non-gated experts of width 1856 of which 32 of 128 are
+    held, 2 attention layers of 2 K/V heads in a `full` pool; `MEMEM*E`
+    twice, one scan of two repeats; 384 slots x 12288 rows, buckets to
+    2048): every kernel engages, the grouped product at a width of HALF
+    lane rows among them and the paged kernel in two parts of 192 slots
+    (768 blocks of table a slot: all 384 do not fit scalar memory), the
+    4.9 GB state stack and both pools are donated and updated in place
+    (a second stack would show as +4.6 GiB of temporaries), no padded
+    `[slots, max_seq_len, 256]` view of K or V is built, the weights of
+    an expert lie at their published bytes (no padded lane), and the
+    arguments and temporaries fit HBM."""
+    from ray_tpu.ops import grouped_matmul, paged_attention, ssd
+
+    eng = serving_cell("swarm-decode-ssd-moe")
+    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+                                eng.published)
+    pools = eng.pools
+    assert (published["num_hidden_layers"], published["hidden_size"],
+            published["vocab_size"], sorted(published["reduced"]),
+            mc.layout, mc.n_ssm_layers, mc.n_moe_layers, mc.n_attn_layers,
+            mc.n_held_experts, mc.n_experts, mc.top_k, mc.expert_hidden_dim,
+            ec.num_slots, ec.max_seq_len) \
+        == (14, 2688, 32768,
+            ["n_routed_experts", "num_hidden_layers", "vocab_size"],
+            ("MEMEM*E", 2, ""), 6, 6, 2, 32, 128, 6, 1856, 384, 12288)
+    assert model.paged_attention(pools) == "kernel"
+    assert model.grouped_matmul(mc, ec.num_slots) == "kernel"
+    assert pools["k"].shape == (2, ec.pool_blocks, 16, 256)
+    assert ec.pool_blocks * 16 >= 900_000
+    assert paged_attention.slot_parts(ec.num_slots,
+                                      ec.max_blocks_per_slot) == 2
+    state, = slot_state(eng)
+    assert state["S"].shape == (6, 384, 32, 128, 128)   # no padded lane
+    assert state["tail"].shape == (6, 384, 3, 6144)
+    assert ssd.engages(state["S"])
+    experts = eng.params["blocks"][1]
+    assert experts["w_up"].shape == experts["w_down"].shape \
+        == (2, 32, 1856, 2688) and "w_gate" not in experts
+    assert grouped_matmul.col_tile(2688, 1856) == 1856
+    compiled = cell_program(eng.name, program)
+    text = compiled.text
+    # two grouped products an expert layer, in the scan's body once a
+    # layer of the block
+    assert text.count("grouped_matmul") >= 2 * 3
+    assert "ragged" not in compiled.plain
+    # a repeat's bank is read through the stack, never cut out of it
+    # (a copy of 320 MB a product: 23 of a tick's 40 ms on the chip)
+    bank = experts["w_up"].shape[1:]
+    assert not any(bank in shapes for _, shapes in results_of(text))
+    if program == "tick":
+        assert compiled.plain.count("paged_attention") >= 2
+        assert text.count("ssd_step") >= 3
+        padded = (ec.num_slots, ec.max_seq_len) + pools["k"].shape[3:]
+        assert not any(padded in shapes for _, shapes in results_of(text))
+    else:
+        assert "ssd_step" not in text
+        assert "paged_attention" not in compiled.plain
+        assert "flash_prefill" in compiled.plain
+    m = compiled.memory
+    kept = sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in list(pools.values()) + list(state.values()))
+    assert sum(math.prod(x.shape) for x in jax.tree.leaves(eng.params)) \
+        == published["constants"]["total_params"]
+    print(program, "GiB", compiled.hbm_gib, "temp",
+          m.temp_size_in_bytes / GIB, "args", m.argument_size_in_bytes / GIB)
+    assert m.alias_size_in_bytes >= kept                # all in place
+    assert m.temp_size_in_bytes < (0.5 if program == "tick" else 2.0) * GIB
     assert compiled.hbm_gib < V5E_HBM_GIB - 0.5
