@@ -17,13 +17,18 @@ P("expert", ...); token tensors data-sharded; jit with those out/in
 shardings and GSPMD places dispatch/combine all-to-alls on the ICI ring.
 
 Two expert layers live here.  `dropless_moe` is THE dropless layer, the
-one every served model runs: the assignments are sorted by expert and
-three grouped matrix products (`ops/grouped_matmul.py` on a TPU in
+one every served model runs: the assignments are laid in expert order
+and three grouped matrix products (`ops/grouped_matmul.py` on a TPU in
 bf16, `jax.lax.ragged_dot` elsewhere) run over the experts this chip
 holds (`share`), so every token gets all of its held experts whatever
 the load and an expert nobody picked is not read; an assignment to a
 ZERO-COMPUTE expert (`n_zero`: the router's last columns) is in no
 group, reads no weight and adds its weight times the token itself.
+Where the chip holds a small share of the router's columns, only the
+picks that have a group here are gathered, multiplied and added back
+(`_held_picks`: passes of `compact_rows` rows); where it holds a quarter
+or all of them, all `tokens x top_k` are sorted and walked at once
+(`_all_picks`).
 Its routing rule is an argument (`softmax_top_k`, `sigmoid_bias_top_k`,
 `softmax_bias_top_k`).
 `moe_layer` below is the older capacity-dispatch layer that
@@ -155,7 +160,7 @@ def moe_layer(x: jax.Array, params: Dict[str, jax.Array], cfg: MoEConfig
 
 
 # ---------------------------------------------------------------------------
-# The dropless layer: sort by expert, grouped matrix products, unsort
+# The dropless layer: rows by expert, grouped matrix products, back to tokens
 # ---------------------------------------------------------------------------
 
 Routing = Callable[[jax.Array, Dict[str, jax.Array]],
@@ -221,9 +226,13 @@ def serving_grouped_path(config, slots: int) -> str:
     """`ServingFns.grouped_matmul` of a model whose expert layers are
     `dropless_moe`: `grouped_path` at the decode tick's shape, `slots`
     tokens of `top_k` assignments over the experts this chip holds.
-    `slots * top_k` is the rows the products are COMPILED for, an upper
-    bound on the rows a tick fills: dead slots, assignments to experts
-    held elsewhere and zero-compute picks are in no group."""
+    The path hangs on dtype and widths alone; the rows the products are
+    COMPILED for are `compact_rows`': where a small share of the router
+    is held, M, one pass over the picks that have a group here
+    (assignments to experts held elsewhere and zero-compute picks are
+    never rows); else `slots * top_k`, an upper bound on the rows a tick
+    fills (dead slots, and picks of no group, sort last and are in
+    none)."""
     held = getattr(config, "n_held_experts", config.n_experts)
     return grouped_path(
         slots * config.top_k,
@@ -243,6 +252,112 @@ def _grouped_product(sizes, rows, w_shape, dtype, layer=None):
             xs, w, sizes, scalars, by_rows, layer)
     return lambda xs, w, by_rows=False: grouped_matmul.ragged(
         xs, w if layer is None else w[layer], sizes, by_rows)
+
+
+_SKEW = 2      # `compact_rows`: rows a pass over a uniform router's
+
+
+def compact_rows(rows: int, held: int, routed: int) -> int:
+    """M, the rows one pass of `dropless_moe` walks of `rows`
+    assignments where the layer holds `held` of the router's `routed`
+    columns; `rows` itself where all of them are walked at once.
+
+    M is what a uniform router would send here times `_SKEW`, in whole
+    row tiles of the grouped product: from shapes alone, so one M a
+    bucket, shared by a layer's products and by all layers.  The walk of
+    the held picks has a fixed cost the walk of all picks has not (a
+    cumulative sum, a scatter of `rows` scalars, a loop, a scatter-add
+    that XLA sorts for) and its added row costs about three gathered
+    ones, so it engages where M is a QUARTER of the rows or less, a
+    share of an eighth: on a v5e at a share of 1/48, 1/32, 1/16, 1/8 a
+    1024- to 2048-row call took 0.46, 0.46, 0.49-0.91, 0.64-0.99 of the
+    all-picks walk's time and a tick's 0.89-0.98; at a quarter (M half
+    the rows) 0.84 to 1.19, and every tick 1.00-1.03 (PERF.md section
+    6, PR 53)."""
+    m = min(rows, _SKEW * -(-rows * held // routed))
+    tile = grouped_matmul.row_tile(m)
+    m = -(-m // tile) * tile
+    return m if 4 * m <= rows else rows
+
+
+def walk_counts(sizes: jax.Array, rows: int, routed: int
+                ) -> Dict[str, jax.Array]:
+    """What `dropless_moe`'s walk cost, from what it returns: `sizes`
+    [L, E] the held experts' counts of L calls of `rows` assignments
+    each under a router `routed` wide -> `moe_rows_walked` (passes x M:
+    the rows gathered, multiplied and added back; `rows` a call where
+    all picks are walked at once), `moe_rows_dense` (`rows` a call) and
+    `moe_extra_passes` (passes beyond a call's first: a router that
+    sends more than M rows here)."""
+    m = compact_rows(rows, sizes.shape[-1], routed)
+    passes = -(-jnp.sum(sizes, axis=-1, dtype=jnp.int32) // m)
+    if m == rows:                   # one walk of all rows, whatever is held
+        passes = jnp.ones_like(passes)
+    return {"moe_rows_walked": jnp.sum(passes) * m,
+            "moe_rows_dense": jnp.asarray(sizes.shape[0] * rows, jnp.int32),
+            "moe_extra_passes": jnp.sum(jnp.maximum(passes - 1, 0))}
+
+
+def _all_picks(x, idx, w, E, experts):
+    """Every pick has a group (but a dead token's, `idx` == E): the
+    T * k assignments sorted by expert, one walk of all of them, back by
+    the inverse permutation."""
+    T, k = idx.shape
+    flat = idx.reshape(T * k)
+    order = jnp.argsort(flat)                               # stable
+    sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
+    xs = x[order // k]                                      # [T * k, D]
+    ys = experts(xs, sizes)
+    # rows past the last group belong to no expert: whatever the
+    # grouped product left there must not reach a token
+    ys = jnp.where((jnp.arange(T * k) < sizes.sum())[:, None], ys, 0)
+    back = jnp.zeros((T * k,), jnp.int32).at[order].set(
+        jnp.arange(T * k, dtype=jnp.int32))
+    y = jnp.einsum("tkd,tk->td",
+                   ys[back].reshape(T, k, x.shape[-1]).astype(jnp.float32),
+                   w)
+    return y, sizes
+
+
+def _held_picks(x, idx, w, E, M, experts):
+    """A small share of the router's columns is held: only the picks
+    with a group here (`idx` < E) are gathered, multiplied and added
+    back, M of them a PASS and as many passes as they fill, so
+    nothing is dropped whatever the router does and a call costs what
+    its held picks cost.  No sort: a pick's row is its expert's first
+    row plus its rank among that expert's picks, both from one
+    cumulative sum over the [T * k, E] comparison."""
+    T, k = idx.shape
+    N = T * k
+    P = -(-N // M)                                          # passes at most
+    flat = idx.reshape(N)
+    mine = flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :]
+    upto = jnp.cumsum(mine, axis=0, dtype=jnp.int32)        # [N, E]
+    sizes = upto[-1]
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    row = jnp.where(mine, starts[None, :] + upto - 1, 0).sum(-1)
+    # a pick in no group lands past the last row, each on a row of its own
+    row = jnp.where(flat < E, row, P * M + jnp.arange(N))
+    picks = jnp.zeros((P * M,), jnp.int32).at[row].set(
+        jnp.arange(N, dtype=jnp.int32), mode="drop",
+        unique_indices=True).reshape(P, M)
+    w = w.reshape(N)
+
+    def one_pass(p, y):
+        at = picks[p]                                       # [M], by expert
+        real = p * M + jnp.arange(M) < ends[-1]
+        token = at // k
+        ys = experts(x[token], jnp.clip(ends - p * M, 0, M)
+                     - jnp.clip(starts - p * M, 0, M))
+        # rows past the last group belong to no expert: whatever the
+        # grouped product left there must not reach a token
+        ys = jnp.where(real[:, None], ys.astype(jnp.float32), 0)
+        return y.at[token].add(ys * w[at][:, None])
+
+    y = jax.lax.fori_loop(0, -(-ends[-1] // M), one_pass,
+                          jnp.zeros((T, x.shape[-1]), jnp.float32))
+    return y, sizes
 
 
 def dropless_moe(x: jax.Array, params: Dict[str, jax.Array],
@@ -266,12 +381,29 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array],
     of the stack by the scan is copied for every product).
     Router logits, scores and selection are float32 (a
     float32 matrix product, not the chip's one-pass default); the expert
-    products run in x's dtype.  No capacity: the T * k assignments are
-    sorted by expert, each expert's rows are one group of three grouped
-    products, and the results go back to their tokens by the inverse
-    permutation.  `live` [T] bool takes rows out altogether (padding, a
+    products run in x's dtype.  No capacity: the assignments are laid in
+    expert order, each expert's rows are one group of the grouped
+    products, and every row's result is added to its token, weighted, in
+    float32.  `live` [T] bool takes rows out altogether (padding, a
     dead decode slot): they are in no group, add nothing to the counts
     and get y = 0, so an expert only they picked is not read.
+
+    Which assignments become ROWS is decided by how much of the router
+    the layer holds, a static branch on shapes (`compact_rows`: E
+    against the router's R).  Holding a small share (an eighth of the
+    columns or less), only the picks that have a group here are rows
+    (`_held_picks`): M = `compact_rows(T * k, E, R)` of them a pass,
+    twice what a uniform router would send here, in as many passes as
+    the held picks fill (`ceil(held / M)`, a loop whose trip count is
+    data: one where the router is anywhere near uniform, `T * k / M`
+    where it sends everything here), so the layer stays dropless and
+    exact for every routing and costs what its held picks cost.
+    Holding more (a quarter; every column, where `share` is None or of
+    one shard and `n_zero` 0), all T * k are sorted, gathered and walked
+    at once (`_all_picks`), the picks of no group sorting last.  The
+    arithmetic is the same either way (a token's at most k float32 terms
+    may be added in another order); `walk_counts` says from the counts
+    what a call walked.
 
     The router's R columns are the routed experts, then `n_zero`
     ZERO-COMPUTE experts (identities): an assignment to one of those is
@@ -330,30 +462,29 @@ def dropless_moe(x: jax.Array, params: Dict[str, jax.Array],
         if live is not None:
             idx = jnp.where(live[:, None], idx, E)          # sorts last
             w = jnp.where(live[:, None], w, 0.0)
-    k = idx.shape[-1]
-    with jax.named_scope("experts"):
-        flat = idx.reshape(T * k)
-        order = jnp.argsort(flat)                           # stable
-        sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
-        xs = x[order // k]                                  # [T * k, D]
-        dt = x.dtype
-        F = params["w_down"].shape[-2]
-        product = _grouped_product(sizes, T * k, (E, D, F), dt, layer)
+    dt = x.dtype
+    F = params["w_down"].shape[-2]
+
+    def experts(xs, sizes):
+        """xs [rows, D] sorted by expert, `sizes` [E] rows each -> what
+        the experts make of them [rows, D] (rows past the last group
+        hold anything)."""
+        product = _grouped_product(sizes, xs.shape[0], (E, D, F), dt, layer)
         if gated:
             gate = product(xs, params["w_gate"].astype(dt))
             up = product(xs, params["w_up"].astype(dt))
-            ys = product(jax.nn.silu(gate) * up, params["w_down"].astype(dt))
+            return product(jax.nn.silu(gate) * up,
+                           params["w_down"].astype(dt))
+        up = product(xs, params["w_up"].astype(dt), by_rows=True)
+        return product(jnp.square(jax.nn.relu(up)),
+                       params["w_down"].astype(dt))
+
+    with jax.named_scope("experts"):
+        M = compact_rows(idx.size, E, R)
+        if M == idx.size:
+            y, sizes = _all_picks(x, idx, w, E, experts)
         else:
-            up = product(xs, params["w_up"].astype(dt), by_rows=True)
-            ys = product(jnp.square(jax.nn.relu(up)),
-                         params["w_down"].astype(dt))
-        # rows past the last group belong to no expert: whatever the
-        # grouped product left there must not reach a token
-        ys = jnp.where((jnp.arange(T * k) < sizes.sum())[:, None], ys, 0)
-        back = jnp.zeros((T * k,), jnp.int32).at[order].set(
-            jnp.arange(T * k, dtype=jnp.int32))
-        y = jnp.einsum("tkd,tk->td",
-                       ys[back].reshape(T, k, D).astype(jnp.float32), w)
+            y, sizes = _held_picks(x, idx, w, E, M, experts)
     if n_zero:
         with jax.named_scope("zero"):
             y = y + w_zero[:, None] * x.astype(jnp.float32)

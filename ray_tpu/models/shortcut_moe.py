@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from ray_tpu.models import latent_moe as LM
 from ray_tpu.models.llama import embed_lookup, rms_norm
 from ray_tpu.models.moe import (
-    dropless_moe, serving_grouped_path, softmax_bias_top_k,
+    dropless_moe, serving_grouped_path, softmax_bias_top_k, walk_counts,
 )
 from ray_tpu.models.serving import ServingFns
 
@@ -241,7 +241,9 @@ def decode_step_paged(params, pools, tables, tokens, positions,
               "ticks": jnp.ones((), jnp.int32),
               "zero_picks": zero,
               "real_picks": n_live * (c.top_k * c.n_layers) - zero,
-              "held_picks": jnp.sum(held)}
+              "held_picks": jnp.sum(held),
+              **walk_counts(held, tokens.shape[0] * c.top_k,
+                            c.router_width)}
     return LM._head(c, params, x[:, 0]), {"latent": cache.pool}, counts
 
 
@@ -250,12 +252,14 @@ def init_counts(config: ShortcutMoEConfig) -> Dict[str, jax.Array]:
     HELD expert of each layer, the distinct ones touched, ticks, and the
     live tokens' assignments: to zero-compute experts, to routed experts
     (held anywhere: `zero_picks + real_picks` = live tokens x top_k x
-    layers) and to the held ones."""
+    layers) and to the held ones; and what the expert layers' walk of
+    the held picks cost (`models/moe.py::walk_counts`)."""
     z = jnp.zeros((), jnp.int32)
     return {"expert_tokens": jnp.zeros(
                 (config.n_layers, config.n_held_experts), jnp.int32),
             "experts_touched": z, "ticks": z, "zero_picks": z,
-            "real_picks": z, "held_picks": z}
+            "real_picks": z, "held_picks": z, "moe_rows_walked": z,
+            "moe_rows_dense": z, "moe_extra_passes": z}
 
 
 _SERVING = ServingFns(

@@ -186,7 +186,15 @@ def program(key, build):
 # `swarm-decode-ssd-moe`'s two are PR 52's, which brought the cell (and
 # left the other eighteen texts as they were: `dropless_moe` reads the
 # expert's form off its parameters and the grouped kernel's second form
-# is another static branch).
+# is another static branch); `longform-decode-zero-moe`'s two are PR
+# 53's: holding 16 of the router's 768 columns, its expert layers walk
+# the picks that have a group here in passes of 64 (tick) and 512 (the
+# 1024 bucket) rows (`models/moe.py::_held_picks`).  The other nineteen
+# texts stood: `agent-decode-hybrid` and `swarm-decode-ssd-moe` hold a
+# quarter of their routers, where `compact_rows` keeps the walk of all
+# picks (the issue expected their four to change too; on the chip the
+# compact walk lost there), and the whole-bank cells take the same
+# static branch.
 PROGRAM_TEXT_SHA256 = {
     ("chat-decode", "tick"):
         "48a91f54a548addd9d951f33258125cd66601f6eb5de512b9f23800388b2ae93",
@@ -213,9 +221,9 @@ PROGRAM_TEXT_SHA256 = {
     ("reason-decode-gdn-hybrid", "insert"):
         "a71791eff8285121834af1b0c9f4e8fa126ddecc0c6c26257cbec9d5793fc5d0",
     ("longform-decode-zero-moe", "tick"):
-        "cde3ce8aace9a4c1aced034762e8612cbc39f0080091b150a14a97330fa938b7",
+        "6d77af0f4785b5e86580c1d79abfcf881ee9ed1c86a99dae09123978d048b902",
     ("longform-decode-zero-moe", "insert"):
-        "a11a11354453fee562dd3d3b4e8f8f721faf28d29ff147724b0828cd39aaf7b5",
+        "f2fffc5a59fcb63a9959f73e5139a961d1c09b180708d110378b7eff2cf0f76c",
     ("think-decode-ssm-yoco", "tick"):
         "99a77e55f9f092c0eb144ba97df9bcc5d437802ead9bc1c5f5e8432a6194d87a",
     ("think-decode-ssm-yoco", "insert"):

@@ -227,7 +227,7 @@ def test_delta_rule_inserts_hold_no_chunk_by_chunk_by_channel_tensor(
 
 @pytest.mark.parametrize("cell, temp_gib", [
     ("assistant-decode-moe", 0.7), ("agent-decode-hybrid", 0.9),
-    ("longform-decode-zero-moe", 1.1)])
+    ("longform-decode-zero-moe", 0.55)])
 def test_latent_inserts_hold_no_padded_score_tensor(
         one_chip, on_tpu, cell, temp_gib):
     """The largest insert of the three latent-attention cells (kanana
@@ -239,8 +239,9 @@ def test_latent_inserts_hold_no_padded_score_tensor(
     GB a layer; `(Pb, S_pad)` alone is also kanana's `[2048, 4096]`
     attention output), and a tile's `[1, H, Pb, HISTORY_TILE]` are
     there.  Temporaries, deviceless, parent → PR 45: 0.917 → 0.430 GiB,
-    1.297 → 0.594, 1.247 → 0.952 (LongCat's rest is the grouped
-    products' 12,288 rows and the dense feed-forwards); the bounds lie
+    1.297 → 0.594, 1.247 → 0.952 (LongCat's rest was the grouped
+    products' 12,288 rows and the dense feed-forwards; since PR 53 its
+    expert layers walk 512 rows a pass, 0.952 → 0.486); the bounds lie
     between."""
     from ray_tpu.models.serving import HISTORY_TILE
 
@@ -266,12 +267,15 @@ def test_shortcut_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     files state): they compile for v5e, both kernel paths answer
     "kernel" (the paged latent kernel at 64 query heads on a 640-wide
     row, one call a SUBLAYER; the grouped products at 6144 x 2048 over
-    1536 rows of which the router's real, held picks are filled), the
+    a pass of 64 rows of the router's real, held picks, 512 in the
+    1024 bucket, where they walked all 1536 and 12288 until PR 53), the
     pool of 8 latent layers is updated in place, and arguments +
     temporaries fit HBM beside the 10.35 GB of weights.  These readings
     chose the top bucket, 1024 (the insert's temporaries at 512 / 1024 /
     2048: 0.92 / 1.25 / 1.84 GiB over 11.51 of arguments; the tick's
     0.02)."""
+    from ray_tpu.models.moe import compact_rows
+
     eng = serving_cell("longform-decode-zero-moe")
     ec, mc, model, published = (eng.config, eng.model_config, eng._model,
                                 eng.published)
@@ -294,3 +298,23 @@ def test_shortcut_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
           m.temp_size_in_bytes / GIB, "args", m.argument_size_in_bytes / GIB)
     assert m.alias_size_in_bytes >= pool_bytes          # in place
     assert compiled.hbm_gib < V5E_HBM_GIB
+    # the expert layers walk the picks that have a group here (PR 53):
+    # products over one pass's rows, no value with a row for every pick,
+    # no copy of a bank into the loop, and the temporaries that leaves
+    # (insert 0.952 → 0.486 GiB, tick 0.0200 → 0.0144)
+    rows = (ec.num_slots if program == "tick"
+            else ec.prefill_buckets[-1]) * mc.top_k
+    M = compact_rows(rows, mc.n_held_experts, mc.router_width)
+    assert (rows, M) == ((1536, 64) if program == "tick" else (12288, 512))
+    results = results_of(text)
+    shapes = set().union(*(found for _, found in results))
+    assert (M, mc.expert_hidden_dim) in shapes and (M, mc.dim) in shapes
+    # ((rows, dim) would be no evidence: a dense `w_down` is [12288, 6144])
+    assert not {(rows, mc.expert_hidden_dim),
+                (rows // mc.top_k, mc.top_k, mc.dim)} & shapes
+    bank = {(mc.n_held_experts, mc.dim, mc.expert_hidden_dim),
+            (mc.n_held_experts, mc.expert_hidden_dim, mc.dim)}
+    assert not [found for op, found in results
+                if op in ("copy", "copy-start") and found & bank]
+    assert m.temp_size_in_bytes < (0.016 if program == "tick"
+                                   else 0.55) * GIB

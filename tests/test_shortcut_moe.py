@@ -427,5 +427,13 @@ def test_engine_serves_chunked_prompts_and_counts_its_picks(model):
     assert int(ctr["held_picks"]) == int(ctr["expert_tokens"].sum())
     assert 0 < int(ctr["zero_picks"]) and 0 < int(ctr["held_picks"]) \
         < int(ctr["real_picks"])
+    # the walk of the held picks (`moe.walk_counts`): two slots x top 3
+    # are under a row tile, so a pass is all six rows and never a second
+    assert int(ctr["moe_rows_dense"]) \
+        == int(ctr["ticks"]) * 2 * mc.top_k * mc.n_layers
+    assert 0 < int(ctr["moe_rows_walked"]) <= int(ctr["moe_rows_dense"])
+    assert int(ctr["moe_extra_passes"]) == 0
     assert set(ctr) == {"expert_tokens", "experts_touched", "ticks",
-                        "zero_picks", "real_picks", "held_picks"}
+                        "zero_picks", "real_picks", "held_picks",
+                        "moe_rows_walked", "moe_rows_dense",
+                        "moe_extra_passes"}
