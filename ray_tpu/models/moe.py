@@ -167,12 +167,13 @@ Routing = Callable[[jax.Array, Dict[str, jax.Array]],
                    Tuple[jax.Array, jax.Array]]
 
 
-def softmax_top_k(k: int) -> Routing:
-    """The k largest softmax probabilities, as they are."""
+def softmax_top_k(k: int, norm: bool = False) -> Routing:
+    """The k largest softmax probabilities, as they are, or (`norm`)
+    over their sum."""
 
     def route(logits, params):
         w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-        return idx, w
+        return idx, w / w.sum(-1, keepdims=True) if norm else w
 
     return route
 

@@ -99,6 +99,40 @@ longer than a bucket goes into its slot chunk by chunk.
     this backend, and of a piece at `start` the (query, key) tiles the
     kernel's bounds let through over those of the rectangles the loop
     multiplies (`window_moe.walk_tiles`; `engine.stats()` sums them).
+
+A model that GENERATES BY BLOCKS (diffusion over blocks of L positions,
+`models/blockdiff_moe.py`) hands the engine `block`, a `BlockFns`, and
+the engine then runs no `decode`:
+    block.spec(config) -> BlockSpec(length L, steps, remasking,
+        confidence_threshold, mask_token_id): L a power of two that
+        divides the pool's block; `steps` denoising steps fix L / steps
+        positions each (the remainder to the first), by `remasking`:
+        `low_confidence_dynamic` (every masked position whose confidence
+        passes the threshold if those are at least the step's share,
+        else the share of largest confidence), `low_confidence_static`
+        (always the share of largest confidence), `sequential` (the
+        leftmost).
+    block.denoise(params, pools, tables, tok [B, L], pos0 [B], config,
+        active, write) -> (logits [B, L, V], pools, counts): ONE forward
+        over the block at positions pos0 .. pos0 + L - 1, which writes
+        the block's K/V rows there (`write` [B]: which slots do) and
+        attends with every row seeing every key <= pos0 + L - 1.  The
+        logits at a position are of the token AT it (no shift).
+    prefill(...) is handed the prompt's WHOLE blocks alone (`start` and
+        `n_real` multiples of L) under the block-causal mask, key j
+        visible to query i iff j // L <= i // L; its hidden is not read:
+        an insert yields no token, and the prompt's trailing P mod L
+        tokens open the slot's first block as fixed positions.
+The engine's tick for such a model is one `denoise` over every live
+slot whatever each slot's step, then a slot EITHER fixes positions by
+the rule (its block had a masked one) OR commits (it had none: the rows
+just written are final, pos0 += L, the block is masked anew); a block's
+tokens are emitted together when the tick that fixed its last position
+lands.  A slot holds an OPEN block between ticks (tokens, which are
+fixed, the step), on the device, that nothing but the tick carries, so
+the engine refuses for such a model, by name: `decode_block > 1`, a
+draft (speculation), `prefill_only` and export, adopting a KVState,
+preemption, and prefix reuse with the spill that rides it.
 """
 
 from __future__ import annotations
@@ -131,6 +165,22 @@ class DraftFns(NamedTuple):
     decode: Callable[..., Any]
 
 
+class BlockSpec(NamedTuple):
+    """How a model generates by blocks (the module's docstring)."""
+    length: int
+    steps: int
+    remasking: str
+    confidence_threshold: float
+    mask_token_id: int
+
+
+class BlockFns(NamedTuple):
+    """What a model that generates by blocks hands the engine beside
+    `prefill` (the module's docstring)."""
+    spec: Callable[..., BlockSpec]
+    denoise: Callable[..., Any]
+
+
 class ServingFns(NamedTuple):
     name: str
     init_params: Callable[..., Any]
@@ -147,3 +197,4 @@ class ServingFns(NamedTuple):
     paged_attention: Optional[Callable[..., str]] = None
     grouped_matmul: Optional[Callable[..., str]] = None
     insert_attention: Optional[Callable[..., Any]] = None
+    block: Optional[BlockFns] = None

@@ -208,13 +208,15 @@ def _masked_attention(q, k, v, mask, scale=None):
 
 
 def blockwise_attention(q, k, v, qpos, kpos0, lo, hi, window, block,
-                        scale=None):
+                        scale=None, causal_block=1):
     """ONE sequence: q [Q, H, hd] at positions qpos [Q] against k, v
     [S, kvH, hd] whose row i is the key of position kpos0 + i, taken
     `block` rows a step over steps lo .. hi - 1 (traced: rows outside
     them are never read) under `_seen`'s mask, in an online softmax
     (float32 running max, sum and accumulator).  [Q, H, hd].  `scale`:
-    the scores' factor where it is not `hd ** -0.5`.
+    the scores' factor where it is not `hd ** -0.5`.  `causal_block` L
+    (a power of two; `models/blockdiff_moe.py`): a query sees the keys
+    up to the last position of its own block of L, `qpos | (L - 1)`.
 
     Several queries are consecutive positions from qpos[0] (every
     caller's are), and where `ops.attention.prefill_engages` says so
@@ -231,8 +233,10 @@ def blockwise_attention(q, k, v, qpos, kpos0, lo, hi, window, block,
         # a position before the sequence's first is no key
         return flash.flash_prefill(
             q, k, v, qpos[0] - kpos0, jnp.maximum(lo * kb, -kpos0), hi * kb,
-            window=window, scale=scale)
+            window=window, scale=scale, causal_block=causal_block)
     qg = q.reshape(Q, kvh, H // kvh, hd)
+    if causal_block > 1:
+        qpos = qpos | (causal_block - 1)
 
     def step(i, carry):
         m, l, acc = carry

@@ -528,7 +528,7 @@ def prefill_tiles(Q: int, S: int, off: int, lo: int, hi: int,
 
 
 def _prefill_kernel(s_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                    acc_ref, *, scale, window, bq, bk):
+                    acc_ref, *, scale, window, bq, bk, causal_block=1):
     """grid (KV head, query block, key step): the query tile holds the
     group's heads, [r, bq, hd]; step ki takes key tile first + ki of the
     block's span and folds it into each head's running maximum, sum and
@@ -565,6 +565,8 @@ def _prefill_kernel(s_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         if masked:
             qrow = q0 + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             krow = k0 + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            if causal_block > 1:    # a query sees its whole block
+                qrow = qrow | (causal_block - 1)
             seen = (krow <= qrow) & (krow >= lo) & (krow < hi)
             if window is not None:
                 seen = seen & (krow > qrow - window)
@@ -605,9 +607,10 @@ def _prefill_kernel(s_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
 # Jitted so that the call sites of an unrolled layer loop lower the kernel
 # once a kind (`ops/paged_attention.py::paged_latent_attention`).
-@functools.partial(jax.jit,
-                   static_argnames=("window", "scale", "interpret"))
-def _flash_prefill(q, k, v, off, lo, hi, *, window, scale, interpret):
+@functools.partial(jax.jit, static_argnames=(
+    "window", "scale", "interpret", "causal_block"))
+def _flash_prefill(q, k, v, off, lo, hi, *, window, scale, interpret,
+                   causal_block=1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -638,7 +641,7 @@ def _flash_prefill(q, k, v, off, lo, hi, *, window, scale, interpret):
     heads = jnp.transpose(q.reshape(Q, kvh, r, hd), (1, 2, 0, 3))
     out = pl.pallas_call(
         functools.partial(_prefill_kernel, scale=scale, window=window,
-                          bq=bq, bk=bk),
+                          bq=bq, bk=bk, causal_block=causal_block),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(kvh, Q // bq, steps),
@@ -661,15 +664,26 @@ def _flash_prefill(q, k, v, off, lo, hi, *, window, scale, interpret):
 
 def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, off, lo, hi, *,
                   window: Optional[int] = None,
-                  scale: Optional[float] = None) -> jax.Array:
+                  scale: Optional[float] = None,
+                  causal_block: int = 1) -> jax.Array:
     """ONE sequence: q [Q, H, hd], query j at key row j + `off`, against
     k, v [S, kvH, hd]; head h reads KV head h // (H / kvH).  Query j sees
     key row i when lo <= i < hi, i <= j + off and, with `window`,
     i > j + off - window; `off`, `lo`, `hi` are traced scalars.  bf16 (or
     whatever the operands are) products, float32 scores, softmax and
     accumulation; [Q, H, hd], zeros for a query that sees no key.
-    Shapes as `prefill_engages` asks."""
+    Shapes as `prefill_engages` asks.
+
+    `causal_block` L > 1 (a power of two that divides `off` and a tile
+    of queries; no window): the mask is BLOCK-causal, query j sees key
+    row i <= (j + off) | (L - 1), the last row of its own block of L.
+    The tile bounds stand: a block of queries ends where its tile
+    does."""
+    if causal_block > 1 and (window is not None
+                             or causal_block & (causal_block - 1)):
+        raise ValueError("a block-causal mask takes a power of two and "
+                         "no window")
     return _flash_prefill(
         q, k, v, off, lo, hi, window=window,
         scale=scale or 1.0 / math.sqrt(q.shape[-1]),
-        interpret=not _on_tpu())
+        interpret=not _on_tpu(), causal_block=causal_block)

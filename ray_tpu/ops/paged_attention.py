@@ -227,18 +227,31 @@ def plan(tables: jax.Array, qpos: jax.Array, active, block_size: int,
 _SMEM_BUDGET = 768 * 2 ** 10
 
 
+# Vector memory a call's queries and outputs, which lie there whole, may
+# take together beside the chunk buffers (of the call's 64 MiB).
+_VMEM_QUERY_BUDGET = 24 * 2 ** 20
+
+
 def slot_parts(slots: int, table_width: int,
-               chunk: int = CHUNK_BLOCKS) -> int:
+               chunk: int = CHUNK_BLOCKS, query_bytes: int = 0) -> int:
     """Into how many equal parts a caller cuts its `slots` sequences so
     that ONE call's scalars (`plan`: a sequence's table row, its chunks'
     two lists, its length and position) fit scalar memory: 1 at every
     geometry but a very wide one (384 slots x 768 blocks of table are
     1.18 MB, which the v5e compiler refuses; two calls of 192 fit).  A
-    part is planned and attended on its own; the pool is one."""
+    part is planned and attended on its own; the pool is one.
+
+    `query_bytes`: what ONE sequence's queries take as the kernel is
+    handed them (`Q * H` rows as wide as a pool row); a call holds them
+    and as many bytes of output whole in vector memory, so several
+    queries a sequence over a side-by-side pool are cut too (4 queries
+    x 32 heads x 512 lanes are 128 KiB a sequence: 256 slots go in 4
+    parts)."""
     chunks = -(-table_width // min(chunk, table_width))
     a_slot = 4 * (table_width + 2 * chunks + 2)
     return next(p for p in range(1, slots + 1)
-                if slots % p == 0 and slots // p * a_slot <= _SMEM_BUDGET)
+                if slots % p == 0 and slots // p * a_slot <= _SMEM_BUDGET
+                and 2 * (slots // p) * query_bytes <= _VMEM_QUERY_BUDGET)
 
 
 def _block_copy(pool, layer, phys, buf, slot, t, rows, sem):

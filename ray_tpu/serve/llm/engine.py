@@ -283,6 +283,8 @@ class RequestHandle:
         # None until it has one (`LLMEngine._admit_piece`).
         self._piece_ends: List[int] = []
         self._prompt_rows: Optional[int] = None
+        # the rows its inserts put in (`LLMEngine._rows_in`)
+        self._rows_in = len(request.prompt)
         self._adopted_submit = False   # arrived via submit_adopted
 
     def done(self) -> bool:
@@ -469,6 +471,14 @@ class LLMEngine:
         # Such a sequence, like one with a state by slot, stays in the
         # slot it was admitted to and is moved by nothing.
         self._pinned = self._stateful or window is not None
+        # How a model that generates by blocks does (models/serving.py
+        # `BlockSpec`), else None. A slot of such a model holds an open
+        # block between ticks that nothing but the tick carries: it is
+        # pinned too, and what does not fit the form is refused here.
+        self._block = model.block.spec(model_config) if model.block else None
+        if self._block is not None:
+            self._pinned = True
+            self._refuse_for_blocks(draft_params is not None)
         if c.prefix_cache:
             self._refuse_if_pinned(
                 "prefix reuse (and the spill that rides it; set "
@@ -537,6 +547,18 @@ class LLMEngine:
         self._chunking: deque = deque()
         self._tok = jnp.zeros((B,), jnp.int32)
         self._pos = jnp.zeros((B,), jnp.int32)
+        # A slot's OPEN block (a model that generates by blocks): its
+        # tokens, which of them are fixed (a flag, never `token == mask`:
+        # a prompt may hold the mask's id), the denoising step, and the
+        # block's first position. Carried from tick to tick on the device.
+        self._blk = None
+        if self._block is not None:
+            L = self._block.length
+            self._blk = {
+                "tok": jnp.full((B, L), self._block.mask_token_id, jnp.int32),
+                "fixed": jnp.zeros((B, L), bool),
+                "step": jnp.zeros((B,), jnp.int32),
+                "pos0": jnp.zeros((B,), jnp.int32)}
         self._key = jax.random.key(rng_seed)
         # What the model's decode step counts (models/serving.py),
         # summed on the device tick by tick. Not donated: `stats()`
@@ -544,6 +566,9 @@ class LLMEngine:
         # back (`_counters_read`), while the next tick takes them on.
         self._counters = (model.init_counts(model_config)
                           if model.init_counts else {})
+        if self._block is not None:     # what the block tick counts itself
+            self._counters = dict(self._counters, **{
+                name: jnp.zeros((), jnp.int32) for name in _BLOCK_COUNTERS})
         self._counters_read = self._counters
         # Host-side mirrors fed into each program call (tiny transfers).
         # `_active`: the slot holds a decoding sequence (from its last
@@ -559,6 +584,18 @@ class LLMEngine:
         # counted from `_rows`.
         self._rows = np.zeros((B,), np.int64)
         self._row_limit = np.zeros((B,), np.int64)
+        # A model that generates by blocks: a tick forwards L rows a
+        # live slot and yields 0 or up to L tokens, so the two are
+        # counted apart (`stats()["block"]`). Which blocks complete is
+        # known when a tick lands, not before: `_rows` is then the rows
+        # a slot holds, its open block among them, as of the last tick
+        # read back, and a slot ends at a landing alone (`_row_limit`
+        # never binds). `_blk_skip`: positions of a slot's FIRST block
+        # that the prompt's tail fixed, which are not emitted.
+        self._blk_skip = np.zeros((B,), np.int64)
+        self._slot_forwards = 0
+        self._blocks_emitted = 0
+        self._tokens_emitted = 0
         # Dispatched, not read back: at most one between two steps
         # (oldest first). Scheduler thread only.
         self._flying: deque = deque()
@@ -654,13 +691,21 @@ class LLMEngine:
         # No fence inside a dispatch (it would drain the pipeline and
         # time two ticks as one): a sampled tick's wall is taken where
         # `_read_back` waits for it anyway (`_land_tick`).
-        self._jit_tick = tracked_jit(
-            self._tick_fn, name="llm_engine_tick", fence_samples=False,
-            trace_budget=1, donate_argnums=(1, 3, 4, 9))
-        self._jit_insert = tracked_jit(
-            self._insert_fn, name="llm_engine_insert",
-            trace_budget=len(c.prefill_buckets),
-            donate_argnums=(1, 2, 3, 12))
+        if self._block is None:
+            self._jit_tick = tracked_jit(
+                self._tick_fn, name="llm_engine_tick", fence_samples=False,
+                trace_budget=1, donate_argnums=(1, 3, 4, 9))
+            self._jit_insert = tracked_jit(
+                self._insert_fn, name="llm_engine_insert",
+                trace_budget=len(c.prefill_buckets),
+                donate_argnums=(1, 2, 3, 12))
+        else:       # the same two families of programs, in their block form
+            self._jit_tick = tracked_jit(
+                self._block_tick_fn, name="llm_engine_tick",
+                fence_samples=False, trace_budget=1, donate_argnums=(1, 3))
+            self._jit_insert = tracked_jit(
+                self._block_insert_fn, name="llm_engine_insert",
+                trace_budget=len(c.prefill_buckets), donate_argnums=(1, 2))
         # KV migration programs: block counts are data (padded
         # ids, out-of-bounds scatters dropped), so the adopt is ONE
         # trace and the export one per row length of `export_rows`.
@@ -811,6 +856,95 @@ class LLMEngine:
         out = (pools, tok, pos, key)
         return out if state is None else out + (state,)
 
+    def _block_tick_fn(self, params, pools, tables, blk, active, temp, key,
+                       counters):
+        """The tick of a model that generates by blocks: ONE forward
+        over the L rows of every live slot's open block
+        (`ServingFns.block.denoise`), the same program whatever each
+        slot's step. It writes the block's K/V rows into the pool at
+        the block's positions and attends with every row seeing every
+        key up to the block's last; then, a slot, EITHER fixes
+        positions by the rule (the block had a masked position:
+        `_block_predict`, `_block_choose`; its rows were provisional
+        and the next tick overwrites them) OR commits (it had none: the
+        rows just written are final, the block moves on by L and is
+        masked anew). Returns the block's tokens as the tick leaves
+        them [B, L] and which slots' blocks this tick COMPLETED (fixed
+        their last masked position) [B]: those the host emits."""
+        import jax
+        import jax.numpy as jnp
+
+        spec = self._block
+        L, S = spec.length, self.config.max_seq_len
+        tok, fixed, step, pos0 = (blk[k] for k in
+                                  ("tok", "fixed", "step", "pos0"))
+        masked = ~fixed
+        is_open = masked.any(-1)                # else: nothing left to fix
+        logits, pools, counts = self._model.block.denoise(
+            params, pools, tables, tok, pos0, self.model_config, active,
+            _block_writes(active, is_open))
+        with jax.named_scope("unmask"):
+            key, sub = jax.random.split(key)
+            x0, conf = _block_predict(logits, temp, sub, spec.mask_token_id)
+            share = _block_share(step, spec)
+            pick = _block_choose(conf, masked, share, spec)
+            fixing, committing = active & is_open, active & ~is_open
+            pick = pick & fixing[:, None]
+            tok = jnp.where(pick, x0, tok)
+            fixed = fixed | pick
+            done = fixing & fixed.all(-1)
+            out, mine = tok, committing[:, None]
+            blk = {"tok": jnp.where(mine, spec.mask_token_id, tok),
+                   "fixed": jnp.where(mine, False, fixed),
+                   "step": jnp.where(committing, 0, step + fixing),
+                   "pos0": jnp.where(committing,
+                                     jnp.minimum(pos0 + L, S - L), pos0)}
+            n_pick = pick.sum(-1, dtype=jnp.int32)
+            counts = dict(
+                counts,
+                block_forwards=active.sum(dtype=jnp.int32),
+                block_commits=committing.sum(dtype=jnp.int32),
+                block_tokens_fixed=n_pick.sum(),
+                block_threshold_fixes=jnp.where(
+                    fixing, n_pick - jnp.minimum(
+                        share, masked.sum(-1, dtype=jnp.int32)), 0).sum())
+        counters = jax.tree.map(jnp.add, counters, counts)
+        return pools, blk, key, out, done, counters
+
+    def _block_insert_fn(self, params, pools, blk, table_row, hist_len,
+                         padded_suffix, suffix_len, new_block_ids, slot,
+                         tail, tail_len):
+        """The insert of a model that generates by blocks: the prompt's
+        WHOLE blocks (a piece of them: `padded_suffix` [Pb], the first
+        `suffix_len` real, at `hist_len`..; both multiples of the block
+        length) go through the model's block-causal prefill and their
+        rows into the slot's blocks, as `_insert_fn` puts them. It
+        yields NO token: the slot's open block is set to the prompt's
+        trailing tokens (`tail` [L], the first `tail_len` of them),
+        fixed, and mask tokens behind them, at step 0 and at the
+        position behind the rows now in."""
+        import jax.numpy as jnp
+
+        bs = self.config.kv_block_size
+        Pb = padded_suffix.shape[0]
+        hist = {name: pool[:, table_row].reshape(
+            (pool.shape[0], -1) + pool.shape[3:])
+            for name, pool in pools.items()}
+        _, rows = self._model.prefill(
+            params, padded_suffix[None], hist_len, hist, self.model_config,
+            suffix_len)
+        pools = {name: pool.at[:, new_block_ids].set(
+            rows[name].astype(pool.dtype).reshape(
+                (pool.shape[0], Pb // bs, bs) + pool.shape[3:]))
+            for name, pool in pools.items()}
+        ours = jnp.arange(self._block.length) < tail_len
+        blk = {"tok": blk["tok"].at[slot].set(
+                   jnp.where(ours, tail, self._block.mask_token_id)),
+               "fixed": blk["fixed"].at[slot].set(ours),
+               "step": blk["step"].at[slot].set(0),
+               "pos0": blk["pos0"].at[slot].set(hist_len + suffix_len)}
+        return pools, blk
+
     def _export_fn(self, pools, table_row):
         """Gather the blocks `table_row` names into dense {leaf: [L,
         len(table_row), bs, ...]} arrays (the host slices the valid
@@ -918,6 +1052,8 @@ class LLMEngine:
         if request.prefill_only:
             self._refuse_if_pinned("prefill_only", "an exported KVState")
         handle = RequestHandle(next(self._ids), request)
+        # the rows an insert puts in: the prompt, or its whole blocks
+        handle._rows_in = rows_in = self._rows_in(P)
         if P > top:
             if not request.chunked_prefill:
                 raise ValueError(
@@ -928,7 +1064,7 @@ class LLMEngine:
                     f"prompt length {P} cannot be chunk-prefilled: "
                     f"ceil({P}/{top}) bucket-sized chunks exceed "
                     f"max_seq_len {c.max_seq_len}")
-        handle._piece_ends = list(range(top, P, top)) + [P]
+        handle._piece_ends = list(range(top, rows_in, top)) + [rows_in]
         # A request the pool can never hold must fail loudly at
         # submit — queuing it would deadlock admission forever.
         worst = self._blocks_to_take(handle, 0)
@@ -952,10 +1088,41 @@ class LLMEngine:
         self._work.set()
         return handle
 
+    def _rows_in(self, prompt_len: int) -> int:
+        """Rows of a prompt that its inserts put into the pool: all of
+        it, or for a model that generates by blocks its whole blocks
+        (the trailing `P mod L` tokens open the slot's first block)."""
+        if self._block is None:
+            return prompt_len
+        return prompt_len - prompt_len % self._block.length
+
+    def _refuse_for_blocks(self, draft: bool) -> None:
+        """What the block form of the tick does not offer, by the
+        model's name (models/serving.py)."""
+        c, L = self.config, self._block.length
+        refused = {
+            f"decode_block {c.decode_block} (a tick is ONE forward over "
+            f"a block)": c.decode_block != 1,
+            "a draft model (speculation)": draft,
+            f"kv_block_size {c.kv_block_size}, which holds no whole "
+            f"blocks of {L}": c.kv_block_size % L != 0,
+        }
+        if any(refused.values()):
+            raise ValueError(
+                f"{self._model.name} generates by blocks of {L}: "
+                + ", ".join(k for k, v in refused.items() if v)
+                + " is not offered")
+
     def _refuse_if_pinned(self, what: str, carrier: str) -> None:
         """Whatever moves a sequence's rows without its per-slot state,
-        or without telling a window kind's ring from a full kind's
-        table, would lose rows: refused, by the model's name."""
+        without its open block, or without telling a window kind's ring
+        from a full kind's table, would lose rows: refused, by the
+        model's name."""
+        if self._block is not None:
+            raise ValueError(
+                f"{self._model.name} generates by blocks, and a slot "
+                f"holds an open block that {carrier} does not carry: "
+                f"{what} is not offered")
         if self._stateful:
             raise ValueError(
                 f"{self._model.name} keeps a state by slot that {carrier} "
@@ -1096,6 +1263,13 @@ class LLMEngine:
         spec_k rows) speculative writes after the stop condition,
         capped at the sequence limit (positions clamp at S - 1)."""
         c = self.config
+        if self._block is not None:
+            # whole blocks of L, and one more for the tick dispatched
+            # behind the one that finished the request
+            L = self._block.length
+            top = min(-(-(prompt_len + max_tokens) // L) * L + L,
+                      c.max_seq_len)
+            return -(-top // c.kv_block_size)
         over = max(c.decode_block,
                    c.spec_k if self._draft is not None else 1)
         top = min(prompt_len + max_tokens + over - 1, c.max_seq_len)
@@ -1174,7 +1348,7 @@ class LLMEngine:
                   else self._admit_adopted(handle, slot))
         if not ok:
             return False
-        prompt_in = not fresh or handle._prompt_rows == len(req.prompt)
+        prompt_in = not fresh or handle._prompt_rows == handle._rows_in
         if prompt_in and fresh and self._draft is not None:
             self._draft_admit(list(req.prompt), slot)
         if handle.meter is not None:
@@ -1196,10 +1370,18 @@ class LLMEngine:
         Its rows so far are the prompt's and its tokens', the pending
         one's own among them (a fresh insert's first token is sampled
         and not yet on the handle)."""
+        import numpy as np
+
         req = handle.request
         P = len(req.prompt)
         self._active[slot] = True
         self._temp[slot] = req.temperature
+        if self._block is not None:
+            L = self._block.length
+            self._rows[slot] = handle._rows_in + L
+            self._row_limit[slot] = np.iinfo(np.int64).max
+            self._blk_skip[slot] = P % L
+            return
         self._rows[slot] = P + len(handle.tokens) + int(fresh)
         self._row_limit[slot] = min(P + req.max_tokens,
                                     self.config.max_seq_len)
@@ -1250,10 +1432,22 @@ class LLMEngine:
         return blocks
 
     def _insert(self, slot: int, row, hist_len: int, padded, suffix_len: int,
-                scatter_ids, temperature: float) -> None:
-        """Dispatch the insert program of `padded`'s bucket."""
+                scatter_ids, temperature: float, tail=()) -> None:
+        """Dispatch the insert program of `padded`'s bucket. `tail`: for
+        a model that generates by blocks, the prompt's tokens behind
+        the rows this piece completes (none before the last piece)."""
         import numpy as np
 
+        if self._block is not None:
+            fixed = np.zeros((self._block.length,), np.int32)
+            fixed[:len(tail)] = tail
+            with trace_span("llm_engine.insert_dispatch",
+                            bucket=len(padded), tokens=int(suffix_len)):
+                self._cache, self._blk = self._jit_insert(
+                    self.params, self._cache, self._blk, row,
+                    np.int32(hist_len), padded, np.int32(suffix_len),
+                    scatter_ids, np.int32(slot), fixed, np.int32(len(tail)))
+            return
         if self._ring is not None:      # a table row and ids a kind
             row = {"full": row, "window": self._ring.tables[slot].copy()}
             scatter_ids = {"full": scatter_ids,
@@ -1298,7 +1492,9 @@ class LLMEngine:
                 not self._take_prompt_blocks(handle, slot, span):
             return False
         start = handle._prompt_rows
-        end = next(e for e in handle._piece_ends if e > start)
+        # (a prompt shorter than a block has no row to put in: its
+        # insert still runs, over padding alone, and opens the block)
+        end = next((e for e in handle._piece_ends if e > start), start)
         n = end - start
         bucket = self._bucket_for(n)
         padded = np.zeros((bucket,), np.int32)
@@ -1306,7 +1502,8 @@ class LLMEngine:
         row = self._tables[slot].copy()
         self._insert(slot, row, start, padded, n,
                      row[start // bs:(start + bucket) // bs],
-                     req.temperature)
+                     req.temperature,
+                     tail=req.prompt[end:] if end == handle._rows_in else ())
         S = self.config.max_seq_len
         self._insert_keys_walked += min(
             -(-(start + bucket) // HISTORY_TILE) * HISTORY_TILE, S)
@@ -2114,7 +2311,7 @@ class LLMEngine:
             sp.set_metadata(admitted=len(inserted))
             mask, live = self._tick_slots()
         did_ctrl = did_ctrl or bool(self._chunking)   # a piece went out
-        if inserted:
+        if inserted and self._block is None:
             # First generated token per freshly-prefilled slot (before
             # the tick below overwrites it with the second). Adopted
             # slots skip this: their pending token was emitted by the
@@ -2155,6 +2352,15 @@ class LLMEngine:
             self._loop.overlapped += bool(self._flying)
             if spec:
                 outs = self._spec_dispatch(mask)
+            elif self._block is not None:
+                (self._cache, self._blk, self._key, *outs,
+                 self._counters) = self._jit_tick(
+                    self.params, self._cache, self._tick_tables(),
+                    self._blk, mask, self._temp.copy(), self._key,
+                    self._counters)
+                outs = tuple(outs)          # tokens [B, L], completed [B]
+                sample = self._jit_tick.take_sample()
+                self._slot_forwards += len(live)
             else:
                 (self._cache, self._tok, self._pos, self._key, out,
                  self._counters, *state) = self._jit_tick(
@@ -2219,6 +2425,8 @@ class LLMEngine:
         with trace_span("llm_engine.tick_wait"):
             if tick.spec:
                 toks_host, n_emit = self._spec_wait(*tick.outs)
+            elif self._block is not None:
+                toks_host, done = self._read_back(*tick.outs)   # [B, L], [B]
             else:
                 toks_host, = self._read_back(*tick.outs)    # [K, B]
         with self._loop.phase("llm_engine.emit") as sp:
@@ -2244,6 +2452,10 @@ class LLMEngine:
                 self._jit_tick.record_wall(tick.sample, wall)
             self._credit_decode(tick.handles, wall)
             self._counters_read = tick.counters
+            if self._block is not None:
+                self._emit_blocks(tick, toks_host, done, sp)
+                del tick, toks_host
+                return
             for slot, handle in zip(map(int, tick.live), tick.handles):
                 n = int(n_emit[slot]) if tick.spec else toks_host.shape[0]
                 if tick.spec:
@@ -2261,6 +2473,30 @@ class LLMEngine:
             # the tick's outputs and their host view are let go under
             # this phase, not between two
             del tick, toks_host
+
+    def _emit_blocks(self, tick: "_Tick", toks_host, done, span) -> None:
+        """A landed block tick's tokens: a slot whose block this tick
+        COMPLETED hands its L tokens to `_emit` in position order (its
+        first block less the positions the prompt's tail fixed); `eos`,
+        a stop token or `max_tokens` inside a block drop the block's
+        rest. Every other live slot yields nothing this tick."""
+        L = self._block.length
+        n_blocks = n_tokens = 0
+        for slot, handle in zip(map(int, tick.live), tick.handles):
+            if not done[slot] or self._slots[slot].handle is not handle:
+                continue        # mid-block, or released while in flight
+            first = int(self._blk_skip[slot])
+            self._blk_skip[slot] = 0
+            self._rows[slot] += L
+            n_blocks += 1
+            for k in range(first, L):
+                if self._slots[slot].handle is not handle:
+                    break       # ended inside the block
+                self._emit(slot, int(toks_host[slot, k]))
+                n_tokens += 1
+        self._blocks_emitted += n_blocks
+        self._tokens_emitted += n_tokens
+        span.set_metadata(tokens=n_tokens, blocks=n_blocks)
 
     def _read_back(self, *outs):
         """`llm_engine.tick_wait`'s two halves: wait until the tick's
@@ -2622,6 +2858,15 @@ class LLMEngine:
                 promote_skips=self._promote_skips,
                 spill_lands=self._spill_lands,
                 spill_lands_waited=self._spill_lands_waited)
+        if self._block is not None:
+            # rows forwarded and tokens emitted, apart: a tick forwards
+            # L rows a live slot and emits a block when it completes
+            out["block"] = {
+                "length": self._block.length,
+                "slot_forwards": self._slot_forwards,
+                "rows_forwarded": self._slot_forwards * self._block.length,
+                "blocks_emitted": self._blocks_emitted,
+                "tokens_emitted": self._tokens_emitted}
         if self._stateful:
             out["slot_state"] = {
                 "bytes": sum(int(x.nbytes)
@@ -2695,6 +2940,90 @@ def _padded_blocks(blocks, n_blocks):
         out[name] = np.zeros((x.shape[0], n_blocks) + x.shape[2:], x.dtype)
         out[name][:, :x.shape[1]] = x
     return out
+
+
+# What the block tick counts beside the model's own counters: live
+# slot-forwards, those that committed a block, positions fixed by a
+# denoising step, and those of them that the confidence threshold fixed
+# beyond the step's share.
+_BLOCK_COUNTERS = ("block_forwards", "block_commits", "block_tokens_fixed",
+                   "block_threshold_fixes")
+
+
+def _block_writes(active, is_open):
+    """Which slots' forwards write their block's rows into the pool:
+    every live one. A denoising step's rows are provisional (the next
+    tick overwrites them, and no query reads past its own block); the
+    commit's, computed from the block's final tokens, are the ones that
+    stay."""
+    del is_open
+    return active
+
+
+def _block_share(step, spec):
+    """Positions a denoising step fixes at least [B]: L / steps spread
+    evenly, the remainder to the first steps (the family's
+    `get_num_transfer_tokens`)."""
+    import jax.numpy as jnp
+
+    base, rem = divmod(spec.length, spec.steps)
+    table = jnp.asarray([base + (i < rem) for i in range(spec.steps)],
+                        jnp.int32)
+    return table[jnp.minimum(step, spec.steps - 1)]
+
+
+def _block_predict(logits, temp, key, mask_id):
+    """logits [B, L, V] float32 -> (x0 [B, L] int32, its probability
+    [B, L] float32 under the float32 softmax over the vocabulary):
+    the argmax where temp [B] is 0, else a sample at that temperature
+    and its probability under the softmax at that temperature. The
+    mask token's own column is never predicted (-inf)."""
+    import jax
+    import jax.numpy as jnp
+
+    V = logits.shape[-1]
+    logits = jnp.where(jnp.arange(V) == mask_id, -jnp.inf, logits)
+
+    def greedy(logits):
+        top = jnp.max(logits, axis=-1)
+        x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        z = jnp.sum(jnp.exp(logits - top[..., None]), axis=-1)
+        return x0, 1.0 / z
+
+    def sampled(logits):
+        t = jnp.maximum(temp, 1e-6)[:, None, None]
+        scaled = logits / t
+        x = jax.random.categorical(key, scaled).astype(jnp.int32)
+        lp = jnp.take_along_axis(jax.nn.log_softmax(scaled, axis=-1),
+                                 x[..., None], axis=-1)[..., 0]
+        g, c = greedy(logits)
+        hot = (temp > 0)[:, None]
+        return jnp.where(hot, x, g), jnp.where(hot, jnp.exp(lp), c)
+
+    return jax.lax.cond(jnp.any(temp > 0), sampled, greedy, logits)
+
+
+def _block_choose(conf, masked, share, spec):
+    """Which masked positions a denoising step fixes [B, L] bool, by
+    `spec.remasking`: `sequential` the leftmost `share`;
+    `low_confidence_static` the `share` of largest confidence;
+    `low_confidence_dynamic` every masked position whose confidence
+    passes the threshold if those are at least `share`, else as
+    static. Ties go to the left."""
+    import jax.numpy as jnp
+
+    L = conf.shape[-1]
+    at = jnp.arange(L)
+    score = (-at.astype(jnp.float32) * jnp.ones_like(conf)
+             if spec.remasking == "sequential" else conf)
+    score = jnp.where(masked, score, -jnp.inf)
+    a, b = score[..., :, None], score[..., None, :]     # a: mine, b: other
+    ahead = (b > a) | ((b == a) & (at[None, :] < at[:, None]))
+    pick = masked & (ahead.sum(-1) < share[:, None])
+    if spec.remasking == "low_confidence_dynamic":
+        high = masked & (conf > spec.confidence_threshold)
+        pick = jnp.where((high.sum(-1) >= share)[:, None], high, pick)
+    return pick
 
 
 def _sample(logits, temp, key):

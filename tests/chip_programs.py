@@ -194,7 +194,11 @@ def program(key, build):
 # quarter of their routers, where `compact_rows` keeps the walk of all
 # picks (the issue expected their four to change too; on the chip the
 # compact walk lost there), and the whole-bank cells take the same
-# static branch.
+# static branch.  `solve-decode-blockdiff-moe`'s two are PR 55's, which
+# brought the cell and the block form of the tick and the insert; the
+# other twenty-one texts stood (`flash_prefill`'s block-causal mask and
+# `slot_parts`' query budget are static branches, `softmax_top_k`'s
+# renormalisation an argument).
 PROGRAM_TEXT_SHA256 = {
     ("chat-decode", "tick"):
         "48a91f54a548addd9d951f33258125cd66601f6eb5de512b9f23800388b2ae93",
@@ -232,6 +236,10 @@ PROGRAM_TEXT_SHA256 = {
         "c41b7dff23ade0c8c0b49724b4a7b1d7a2d7d27d324953aee70311284b16defe",
     ("swarm-decode-ssd-moe", "insert"):
         "a229313f2e5a7f3c7a4e24e98f969829f08105dd9157cc2449df3fb04b3f79b7",
+    ("solve-decode-blockdiff-moe", "tick"):
+        "902646d8494453a50c7916d527e3ca244a116ad168bae0f8ad87e355af5cf66e",
+    ("solve-decode-blockdiff-moe", "insert"):
+        "07bb25384c7a15ab6eaed0fa8e18c697a73584806187d659fc03c0656337a63e",
     ("two small layers", "train step, scope names apart"):
         "1d700902d1c682aaec9e4b41afc84286eb99ba0c4758f7046d1be38b1848714c",
     ("two small layers", "train step, its kernels"):
@@ -293,6 +301,7 @@ def serving_cell(cell):
     return types.SimpleNamespace(
         name=cell, _model=model, model_config=mc, config=ec,
         published=published, _ring=ring, _window_leaves=leaves,
+        _block=model.block.spec(mc) if model.block else None,
         one_chip=one_chip,
         params=placed(jax.eval_shape(
             lambda: model.init_params(mc, jax.random.key(0))), one_chip),
@@ -317,6 +326,17 @@ def slot_state(eng):
         if model.init_slot_state else []
 
 
+def open_blocks(eng):
+    """The slots' open blocks of a model that generates by blocks, as
+    `LLMEngine.__init__` has them, as shapes on the chip."""
+    B, L = eng.config.num_slots, eng._block.length
+    return placed({
+        "tok": jax.ShapeDtypeStruct((B, L), jnp.int32),
+        "fixed": jax.ShapeDtypeStruct((B, L), jnp.bool_),
+        "step": jax.ShapeDtypeStruct((B,), jnp.int32),
+        "pos0": jax.ShapeDtypeStruct((B,), jnp.int32)}, eng.one_chip)
+
+
 def _compiled_insert(eng):
     """`LLMEngine._insert_fn` at the cell's largest bucket, the slots'
     state donated beside the pools where the model keeps one."""
@@ -329,6 +349,14 @@ def _compiled_insert(eng):
     B, Pb = ec.num_slots, ec.prefill_buckets[-1]
     state = slot_state(eng)
     ids = arg(jnp.int32, Pb // ec.kv_block_size)
+    if eng._block is not None:      # a model that generates by blocks
+        return jax.jit(
+            functools.partial(LLMEngine._block_insert_fn, eng),
+            donate_argnums=(1, 2)).lower(
+            eng.params, eng.pools, open_blocks(eng),
+            arg(jnp.int32, ec.max_blocks_per_slot), arg(jnp.int32),
+            arg(jnp.int32, Pb), arg(jnp.int32), ids, arg(jnp.int32),
+            arg(jnp.int32, eng._block.length), arg(jnp.int32)).compile()
     return jax.jit(
         functools.partial(LLMEngine._insert_fn, eng),
         donate_argnums=(1, 2, 3) + ((12,) if state else ())).lower(
@@ -352,6 +380,19 @@ def _compiled_tick(eng):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=eng.one_chip)
 
     B = ec.num_slots
+    if eng._block is not None:      # a model that generates by blocks
+        from ray_tpu.serve.llm.engine import _BLOCK_COUNTERS
+
+        counters = placed(jax.eval_shape(lambda: dict(
+            model.init_counts(mc), **{name: jnp.zeros((), jnp.int32)
+                                      for name in _BLOCK_COUNTERS})),
+            eng.one_chip)
+        return jax.jit(
+            functools.partial(LLMEngine._block_tick_fn, eng),
+            donate_argnums=(1, 3)).lower(
+            eng.params, eng.pools, arg(jnp.int32, B, ec.max_blocks_per_slot),
+            open_blocks(eng), arg(jnp.bool_, B), arg(jnp.float32, B),
+            eng.key, counters).compile()
     extra = ([placed(jax.eval_shape(lambda: model.init_counts(mc)),
                      eng.one_chip)] if model.init_counts else []) \
         + slot_state(eng)
