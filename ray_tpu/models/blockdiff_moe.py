@@ -17,11 +17,13 @@ Qwen3-MoE lineage, generating by DIFFUSION OVER BLOCKS.
   one opens with the prompt's trailing `P mod L` tokens fixed).  ONE
   forward over the block's L rows (`denoise_paged`) writes their K/V
   rows into the pool at the block's positions and attends, every row
-  seeing every key up to the block's last; the engine then fixes
-  positions by the rule or, where none is masked, takes the rows just
-  written as final and moves on.  A denoising step's rows are
-  provisional and the next forward overwrites them; no query reads past
-  its own block, so this equals the published loop's `store_kv=False`.
+  seeing every key up to the block's last, and hands back the normed
+  hidden rows; the engine multiplies those of them that still hold a
+  masked position by the head, fixes positions by the rule or, where
+  none is masked, takes the rows just written as final and moves on.
+  A denoising step's rows are provisional and the next forward
+  overwrites them; no query reads past its own block, so this equals
+  the published loop's `store_kv=False`.
 - **One kind of pool**, a row a token holding its 4 KV heads side by
   side (`[L, NB, bs, kvH hd]`, `ops/paged_attention.py` "Few KV heads"),
   read by the paged kernel at Q = L queries a sequence, all of them at
@@ -374,10 +376,12 @@ def denoise_paged(params, pools, tables, tokens, pos0,
     rows are written at those positions (`write` [B] bool, `active`
     where None: a slot that does not write drops them) and every row
     attends to every key up to the block's last.  A dead slot goes
-    through no expert.  Returns (logits [B, L, V] float32, pools,
-    counts): tokens routed to each held expert of each layer, the
-    distinct held experts touched summed over the layers, what the
-    walks cost (`models/moe.py::walk_counts`), and 1 for the tick."""
+    through no expert.  Returns (normed hidden [B, L, D], pools,
+    counts): the engine multiplies by the head the rows its rule reads
+    (`serve/llm/engine.py::_block_tick_fn`); the counts are the tokens
+    routed to each held expert of each layer, the distinct held experts
+    touched summed over the layers, what the walks cost
+    (`models/moe.py::walk_counts`), and 1 for the tick."""
     c = config
     B, L = tokens.shape
     qpos = pos0[:, None] + jnp.arange(L)
@@ -392,7 +396,7 @@ def denoise_paged(params, pools, tables, tokens, pos0,
         expert_tokens=routed,
         experts_touched=jnp.sum(routed > 0, dtype=jnp.int32),
         ticks=jnp.ones((), jnp.int32))
-    return _head(c, params, x), cache.pools, counts
+    return x, cache.pools, counts
 
 
 def init_counts(config: BlockDiffMoEConfig) -> Dict[str, jax.Array]:

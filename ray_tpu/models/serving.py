@@ -113,26 +113,32 @@ the engine then runs no `decode`:
         (always the share of largest confidence), `sequential` (the
         leftmost).
     block.denoise(params, pools, tables, tok [B, L], pos0 [B], config,
-        active, write) -> (logits [B, L, V], pools, counts): ONE forward
-        over the block at positions pos0 .. pos0 + L - 1, which writes
-        the block's K/V rows there (`write` [B]: which slots do) and
-        attends with every row seeing every key <= pos0 + L - 1.  The
-        logits at a position are of the token AT it (no shift).
+        active, write) -> (normed hidden [B, L, D], pools, counts): ONE
+        forward over the block at positions pos0 .. pos0 + L - 1, which
+        writes the block's K/V rows there (`write` [B]: which slots do)
+        and attends with every row seeing every key <= pos0 + L - 1.
+        It applies NO head: the engine multiplies by `head_weight` the
+        rows its rule can read, those still masked in a live slot whose
+        block is open, R = three eighths of the B x L rows a pass and as
+        many passes as they fill (`serve/llm/engine.py::
+        _block_predict_rows`, `_block_pass_rows`).  The logits of a row
+        are of the token AT its position (no shift).
     prefill(...) is handed the prompt's WHOLE blocks alone (`start` and
         `n_real` multiples of L) under the block-causal mask, key j
         visible to query i iff j // L <= i // L; its hidden is not read:
         an insert yields no token, and the prompt's trailing P mod L
         tokens open the slot's first block as fixed positions.
 The engine's tick for such a model is one `denoise` over every live
-slot whatever each slot's step, then a slot EITHER fixes positions by
-the rule (its block had a masked one) OR commits (it had none: the rows
-just written are final, pos0 += L, the block is masked anew); a block's
-tokens are emitted together when the tick that fixed its last position
-lands.  A slot holds an OPEN block between ticks (tokens, which are
-fixed, the step), on the device, that nothing but the tick carries, so
-the engine refuses for such a model, by name: `decode_block > 1`, a
-draft (speculation), `prefill_only` and export, adopting a KVState,
-preemption, and prefix reuse with the spill that rides it.
+slot whatever each slot's step, the head over the rows still masked,
+then a slot EITHER fixes positions by the rule (its block had a masked
+one) OR commits (it had none: the rows just written are final, pos0 +=
+L, the block is masked anew); a block's tokens are emitted together
+when the tick that fixed its last position lands.  A slot holds an OPEN
+block between ticks (tokens, which are fixed, the step), on the device,
+that nothing but the tick carries, so the engine refuses for such a
+model, by name: `decode_block > 1`, a draft (speculation),
+`prefill_only` and export, adopting a KVState, preemption, and prefix
+reuse with the spill that rides it.
 """
 
 from __future__ import annotations

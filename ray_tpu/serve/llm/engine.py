@@ -865,12 +865,18 @@ class LLMEngine:
         the block's positions and attends with every row seeing every
         key up to the block's last; then, a slot, EITHER fixes
         positions by the rule (the block had a masked position:
-        `_block_predict`, `_block_choose`; its rows were provisional
-        and the next tick overwrites them) OR commits (it had none: the
-        rows just written are final, the block moves on by L and is
-        masked anew). Returns the block's tokens as the tick leaves
-        them [B, L] and which slots' blocks this tick COMPLETED (fixed
-        their last masked position) [B]: those the host emits."""
+        `_block_predict_rows`, `_block_choose`; its rows were
+        provisional and the next tick overwrites them) OR commits (it
+        had none: the rows just written are final, the block moves on
+        by L and is masked anew). The head's product and the softmax
+        run over the rows the rule can read and over no other: those
+        still masked in a live slot whose block is open, known from the
+        tick's arguments before the forward, `_block_pass_rows` of them
+        a pass and as many passes as they fill (none on a tick where
+        every live slot commits). Returns the block's tokens as the
+        tick leaves them [B, L] and which slots' blocks this tick
+        COMPLETED (fixed their last masked position) [B]: those the
+        host emits."""
         import jax
         import jax.numpy as jnp
 
@@ -880,15 +886,17 @@ class LLMEngine:
                                   ("tok", "fixed", "step", "pos0"))
         masked = ~fixed
         is_open = masked.any(-1)                # else: nothing left to fix
-        logits, pools, counts = self._model.block.denoise(
+        fixing, committing = active & is_open, active & ~is_open
+        hidden, pools, counts = self._model.block.denoise(
             params, pools, tables, tok, pos0, self.model_config, active,
             _block_writes(active, is_open))
+        key, sub = jax.random.split(key)
+        x0, conf, passes = _block_predict_rows(
+            hidden, self._model.head_weight(params, self.model_config),
+            masked & fixing[:, None], temp, sub, spec.mask_token_id)
         with jax.named_scope("unmask"):
-            key, sub = jax.random.split(key)
-            x0, conf = _block_predict(logits, temp, sub, spec.mask_token_id)
             share = _block_share(step, spec)
             pick = _block_choose(conf, masked, share, spec)
-            fixing, committing = active & is_open, active & ~is_open
             pick = pick & fixing[:, None]
             tok = jnp.where(pick, x0, tok)
             fixed = fixed | pick
@@ -907,7 +915,10 @@ class LLMEngine:
                 block_tokens_fixed=n_pick.sum(),
                 block_threshold_fixes=jnp.where(
                     fixing, n_pick - jnp.minimum(
-                        share, masked.sum(-1, dtype=jnp.int32)), 0).sum())
+                        share, masked.sum(-1, dtype=jnp.int32)), 0).sum(),
+                head_passes=passes,
+                head_rows_walked=passes * _block_pass_rows(*masked.shape),
+                head_rows_dense=jnp.asarray(masked.size, jnp.int32))
         counters = jax.tree.map(jnp.add, counters, counts)
         return pools, blk, key, out, done, counters
 
@@ -2945,9 +2956,13 @@ def _padded_blocks(blocks, n_blocks):
 # What the block tick counts beside the model's own counters: live
 # slot-forwards, those that committed a block, positions fixed by a
 # denoising step, and those of them that the confidence threshold fixed
-# beyond the step's share.
+# beyond the step's share; the passes of the head over the rows still
+# masked (`_block_predict_rows`), the rows they multiplied (passes x
+# `_block_pass_rows`) and the rows a head over every slot's block would
+# have (slots x L a tick).
 _BLOCK_COUNTERS = ("block_forwards", "block_commits", "block_tokens_fixed",
-                   "block_threshold_fixes")
+                   "block_threshold_fixes", "head_passes",
+                   "head_rows_walked", "head_rows_dense")
 
 
 def _block_writes(active, is_open):
@@ -2972,12 +2987,86 @@ def _block_share(step, spec):
     return table[jnp.minimum(step, spec.steps - 1)]
 
 
+def _block_pass_rows(slots, length):
+    """R, the rows one pass of the block tick's head multiplies of the
+    `slots x length` a tick forwards: THREE EIGHTHS of them in whole
+    tiles of 128 rows (all of them where they are fewer than a tile),
+    from shapes alone.  A live slot holds a masked position in half its
+    rows over a block's steps (L / 2), so an engine three quarters
+    full or less needs one pass, a full one two, and every slot at step
+    0 at once three, which cost less than a head over all rows.  A pass
+    costs a fixed part (the head's weight is read once a pass) and a
+    part by the row, so few large passes beat many small ones until a
+    pass is mostly spare rows: on a v5e at 1,024 rows x 2,048 x 151,936,
+    head + unmask a tick read 2.66, 2.71, 1.98, 2.50 ms at R = 128, 256,
+    384, 512 with 134-146 of 256 slots live and 3.74, 3.21, 3.93, 2.78
+    with 237 (6.47 and 6.40 over all rows: PERF.md section 6, PR 56)."""
+    rows = slots * length
+    return min(rows, -(-3 * rows // (8 * 128)) * 128)
+
+
+def _block_predict_rows(hidden, head, need, temp, key, mask_id):
+    """The head's product and `_block_predict` over the rows of hidden
+    [B, L, D] that `need` [B, L] names, and over no other: their flat
+    indices in row order, R = `_block_pass_rows(B, L)` of them a PASS
+    and `ceil(needed / R)` passes (a loop whose trip count is data: none
+    where nothing is needed), each gathering R rows, multiplying them
+    by head [D, V] into float32 [R, V] and scattering what
+    `_block_predict` makes of them, at the temperature temp [B] of each
+    row's slot, back to [B, L].  A row of a matrix product does not
+    depend on the rows beside it, so a needed row's x0 and confidence
+    are those of a product over all B x L rows; a row not needed keeps
+    x0 0 and confidence 0, which `_block_choose` never reads (`need`
+    holds every masked position of every slot that fixes).  Returns
+    (x0 [B, L] int32, confidence [B, L] float32, passes)."""
+    import jax
+    import jax.numpy as jnp
+
+    B, L, D = hidden.shape
+    N, R = B * L, _block_pass_rows(B, L)
+    P = -(-N // R)                                          # passes at most
+    hidden, need = hidden.reshape(N, D), need.reshape(N)
+    rank = jnp.cumsum(need, dtype=jnp.int32)
+    # a row not needed lands past the last pass; a pass's spare rows
+    # name row N, which is no row
+    rows = jnp.full((P * R,), N, jnp.int32).at[
+        jnp.where(need, rank - 1, P * R)].set(
+        jnp.arange(N, dtype=jnp.int32), mode="drop",
+        unique_indices=True).reshape(P, R)
+
+    def one_pass(p, out):
+        x0, conf = out
+        at = rows[p]
+        with jax.named_scope("head"):
+            logits = jax.lax.dot_general(
+                hidden[jnp.minimum(at, N - 1)], head,
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)         # [R, V]
+        with jax.named_scope("unmask"):
+            # a spare row is greedy: it sends no pass into the sampler
+            mine, sure = _block_predict(
+                logits,
+                jnp.where(at < N, temp[jnp.minimum(at // L, B - 1)], 0),
+                jax.random.fold_in(key, p), mask_id)
+            return (x0.at[at].set(mine, mode="drop"),
+                    conf.at[at].set(sure, mode="drop"))
+
+    passes = -(-rank[-1] // R)
+    x0, conf = jax.lax.fori_loop(
+        0, passes, one_pass,
+        (jnp.zeros((N,), jnp.int32), jnp.zeros((N,), jnp.float32)))
+    return x0.reshape(B, L), conf.reshape(B, L), passes
+
+
 def _block_predict(logits, temp, key, mask_id):
-    """logits [B, L, V] float32 -> (x0 [B, L] int32, its probability
-    [B, L] float32 under the float32 softmax over the vocabulary):
-    the argmax where temp [B] is 0, else a sample at that temperature
+    """logits [R, V] float32 -> (x0 [R] int32, its probability [R]
+    float32 under the float32 softmax over the vocabulary): a row's
+    argmax where its temp [R] is 0, else a sample at that temperature
     and its probability under the softmax at that temperature. The
-    mask token's own column is never predicted (-inf)."""
+    mask token's own column is never predicted (-inf). A row's draw
+    comes from `key` over the [R, V] rows it is handed with (a pass of
+    `_block_predict_rows`): the distribution of a draw over all slots'
+    rows at once, not the same draw."""
     import jax
     import jax.numpy as jnp
 
@@ -2991,13 +3080,12 @@ def _block_predict(logits, temp, key, mask_id):
         return x0, 1.0 / z
 
     def sampled(logits):
-        t = jnp.maximum(temp, 1e-6)[:, None, None]
-        scaled = logits / t
+        scaled = logits / jnp.maximum(temp, 1e-6)[:, None]
         x = jax.random.categorical(key, scaled).astype(jnp.int32)
         lp = jnp.take_along_axis(jax.nn.log_softmax(scaled, axis=-1),
                                  x[..., None], axis=-1)[..., 0]
         g, c = greedy(logits)
-        hot = (temp > 0)[:, None]
+        hot = temp > 0
         return jnp.where(hot, x, g), jnp.where(hot, jnp.exp(lp), c)
 
     return jax.lax.cond(jnp.any(temp > 0), sampled, greedy, logits)

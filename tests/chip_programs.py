@@ -198,7 +198,11 @@ def program(key, build):
 # brought the cell and the block form of the tick and the insert; the
 # other twenty-one texts stood (`flash_prefill`'s block-causal mask and
 # `slot_parts`' query budget are static branches, `softmax_top_k`'s
-# renormalisation an argument).
+# renormalisation an argument).  `solve-decode-blockdiff-moe`'s tick is
+# PR 56's: the head and the softmax over the rows still masked, 384 a
+# pass (`engine._block_predict_rows`); the other twenty-two stood, that
+# cell's insert among them: only a model with `ServingFns.block` runs the
+# block tick.
 PROGRAM_TEXT_SHA256 = {
     ("chat-decode", "tick"):
         "48a91f54a548addd9d951f33258125cd66601f6eb5de512b9f23800388b2ae93",
@@ -237,7 +241,7 @@ PROGRAM_TEXT_SHA256 = {
     ("swarm-decode-ssd-moe", "insert"):
         "a229313f2e5a7f3c7a4e24e98f969829f08105dd9157cc2449df3fb04b3f79b7",
     ("solve-decode-blockdiff-moe", "tick"):
-        "902646d8494453a50c7916d527e3ca244a116ad168bae0f8ad87e355af5cf66e",
+        "6f0a3fc2d3c46bc92d3053eb3bb10a3f709a1e880c8dda7cf30a4c51bd15d0a1",
     ("solve-decode-blockdiff-moe", "insert"):
         "07bb25384c7a15ab6eaed0fa8e18c697a73584806187d659fc03c0656337a63e",
     ("two small layers", "train step, scope names apart"):

@@ -194,6 +194,7 @@ _FILE_SECONDS = {
     "test_podracer.py": 147,
     "test_paged_attention.py": 142,
     "test_conv_moe.py": 140,
+    "test_blockdiff_moe.py": 135,
     "test_gdn_hybrid.py": 132,
     "test_models.py": 126,
     "test_window_moe.py": 124,

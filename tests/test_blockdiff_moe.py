@@ -147,8 +147,8 @@ def test_prefill_then_denoise_through_the_pool_is_the_references(built,
     """The prompt's whole blocks through `prefill_paged`, then every
     state of three blocks through `denoise_paged` over the paged pool
     (the second block crosses into a new pool block of 8 rows): the
-    block's logits are the reference's full forward of [prompt ‖
-    committed blocks ‖ state] at the block's rows."""
+    block's hidden rows times the head are the reference's full forward
+    of [prompt ‖ committed blocks ‖ state] at the block's rows."""
     from ray_tpu.models import blockdiff_moe as M
 
     _, R = _modules()
@@ -177,9 +177,10 @@ def test_prefill_then_denoise_through_the_pool_is_the_references(built,
     for _ in range(3):
         b = len(final) // L
         while True:
-            lg, pools, _ = M.denoise_paged(
+            x, pools, _ = M.denoise_paged(
                 params, pools, table, jnp.asarray([toks], jnp.int32),
                 jnp.asarray([b * L], jnp.int32), mc, active)
+            lg = x @ M.lm_head_weight(params, mc)   # the engine's part
             ids, pos, blk, state = R._rows_of(final, L, [(b, toks)])
             want = R.logits_of_rows(w, C, ids, pos, blk, state, len(final),
                                     L)
@@ -434,6 +435,170 @@ def test_what_a_block_model_does_not_offer_is_refused_by_name(built, what):
         else:
             with pytest.raises(ValueError, match=name):
                 engine.export_prefix([1] * 32)
+
+
+# ------------------------------------------------- the head over compact rows
+
+WIDE = 256          # slots: 1,024 rows a tick, so R = 384 and up to 3 passes
+R = 384
+
+
+@pytest.fixture(scope="module")
+def wide(built):
+    """An engine of 256 slots, its tick jitted as a plain function (no
+    donation: the cases hand it states of their own), a pool of random
+    rows and a table of its own blocks a slot."""
+    from ray_tpu.serve.llm import engine as E
+
+    _, params, mc = built
+    engine = _engine(params, mc, slots=WIDE)
+    assert E._block_pass_rows(WIDE, L) == R
+    pools = {k: 0.1 * jax.random.normal(jax.random.key(i), p.shape, p.dtype)
+             for i, (k, p) in enumerate(sorted(engine._cache.items()))}
+    nb = engine.config.max_blocks_per_slot
+    tables = jnp.arange(WIDE * nb, dtype=jnp.int32).reshape(WIDE, nb)
+    return types.SimpleNamespace(
+        engine=engine, params=params, mc=mc, pools=pools, tables=tables,
+        tick=jax.jit(engine._block_tick_fn))
+
+
+def _state(n_fixed, seed=0):
+    """The open blocks of 256 slots, slot i with its `n_fixed[i]` of
+    lowest index fixed (any set will do: the rule fixes by confidence),
+    at the step that many fixes make and at a position of its own."""
+    rng = np.random.RandomState(seed)
+    fixed = np.arange(L)[None, :] < np.asarray(n_fixed)[:, None]
+    tok = np.where(fixed, rng.randint(0, 511, size=(WIDE, L)),
+                   C["mask_token_id"])
+    return {"tok": jnp.asarray(tok, jnp.int32), "fixed": jnp.asarray(fixed),
+            "step": jnp.asarray(n_fixed, jnp.int32),
+            "pos0": jnp.asarray(L * (2 + rng.randint(0, 6, size=(WIDE,))),
+                                jnp.int32)}
+
+
+def _counters(engine):
+    return jax.tree.map(jnp.zeros_like, engine._counters)
+
+
+def _dense_tick(w, blk, active, temp):
+    """What the tick leaves, with the head over ALL slots x L rows and
+    the softmax over all of its logits: (pools, blk, out, done)."""
+    from ray_tpu.models import blockdiff_moe as M
+    from ray_tpu.serve.llm import engine as E
+
+    spec = w.engine._block
+    tok, fixed, step, pos0 = (blk[k] for k in ("tok", "fixed", "step",
+                                                "pos0"))
+    masked = ~fixed
+    is_open = masked.any(-1)
+    hidden, pools, _ = M.denoise_paged(
+        w.params, w.pools, w.tables, tok, pos0, w.mc, active, active)
+    logits = jnp.dot(hidden, M.lm_head_weight(w.params, w.mc),
+                     preferred_element_type=jnp.float32)
+    logits = logits.at[..., spec.mask_token_id].set(-jnp.inf)
+    x0 = jnp.argmax(logits, -1).astype(jnp.int32)
+    conf = jnp.max(jax.nn.softmax(logits, -1), -1)
+    fixing, committing = active & is_open, active & ~is_open
+    pick = E._block_choose(conf, masked, E._block_share(step, spec), spec) \
+        & fixing[:, None]
+    tok = jnp.where(pick, x0, tok)
+    fixed = fixed | pick
+    S = w.engine.config.max_seq_len
+    mine = committing[:, None]
+    return pools, {
+        "tok": jnp.where(mine, spec.mask_token_id, tok),
+        "fixed": jnp.where(mine, False, fixed),
+        "step": jnp.where(committing, 0, step + fixing),
+        "pos0": jnp.where(committing, jnp.minimum(pos0 + L, S - L), pos0),
+    }, tok, fixing & fixed.all(-1), x0
+
+
+# name -> (positions fixed a slot, live slots, passes of R = 384 rows)
+_EVERY = np.ones((WIDE,), bool)
+_AT = np.arange(WIDE)
+_MIX = _AT % 5                  # steps 0..4 side by side: 2 rows a slot
+_MIX_LIVE = _AT % 7 != 3        # a dead slot between live ones: 439 rows
+STATES = {
+    "every_position_masked": (np.zeros(WIDE, int), _EVERY, 3),
+    "all_committing": (np.full(WIDE, L), _EVERY, 0),
+    "all_dead": (np.zeros(WIDE, int), ~_EVERY, 0),
+    # 96 slots at step 0: 384 rows; one more slot with one masked: 385
+    "exactly_a_pass": (np.where(_AT % 8 < 3, 0, L), _EVERY, 1),
+    "a_pass_and_a_row": (np.where(_AT % 8 < 3, 0,
+                                  np.where(_AT == 77, 3, L)), _EVERY, 2),
+    "steps_mixed_dead_between": (_MIX, _MIX_LIVE, 2),
+}
+
+
+@pytest.mark.parametrize("name", list(STATES))
+def test_the_tick_over_compact_rows_is_the_dense_evaluation(wide, name):
+    """`_block_tick_fn` multiplies by the head only the rows still
+    masked in a live slot whose block is open, 384 a pass: tokens,
+    flags, steps, positions, `done`, the emitted block and the pool are
+    what a head over all 1,024 rows and a softmax over all of its
+    logits leave, and the passes are as many as the needed rows fill
+    (with every row masked, the slots x L / R there are, rounded up)."""
+    n_fixed, live, passes = STATES[name]
+    blk, active = _state(n_fixed, seed=len(name)), jnp.asarray(live)
+    needed = int(((L - n_fixed) * live).sum())
+    assert passes == -(-needed // R)
+    temp = jnp.zeros((WIDE,), jnp.float32)
+    pools, got, _, out, done, ctr = wide.tick(
+        wide.params, wide.pools, wide.tables, blk, active, temp,
+        jax.random.key(1), _counters(wide.engine))
+    want_pools, want, want_out, want_done, _ = _dense_tick(
+        wide, blk, active, temp)
+    for k in ("tok", "fixed", "step", "pos0"):
+        assert np.array_equal(got[k], want[k]), k
+    assert np.array_equal(out, want_out) and np.array_equal(done, want_done)
+    for k in pools:     # two compilations of one forward: float32 rounding
+        assert np.allclose(pools[k], want_pools[k], rtol=0, atol=1e-5), k
+    assert int(ctr["head_passes"]) == passes
+    assert int(ctr["head_rows_walked"]) == R * passes
+    assert int(ctr["head_rows_dense"]) == WIDE * L
+    if needed:          # something was fixed, and only where it was live
+        moved = np.asarray(got["fixed"] != blk["fixed"]).any(-1)
+        assert moved.any() and not (moved & ~live).any()
+
+
+def test_a_greedy_slot_beside_a_sampling_one_gets_its_argmax(wide):
+    """Every other slot samples at a high temperature in the SAME tick:
+    a pass gathers each row's temperature by the row's slot, so the
+    greedy slots fix the dense argmax and the others do not."""
+    n_fixed = np.zeros(WIDE, int)
+    blk = _state(n_fixed, seed=3)
+    hot = np.arange(WIDE) % 2 == 0
+    temp = jnp.asarray(np.where(hot, 50.0, 0.0), jnp.float32)
+    active = jnp.ones((WIDE,), bool)
+    _, got, _, _, _, _ = wide.tick(
+        wide.params, wide.pools, wide.tables, blk, active, temp,
+        jax.random.key(2), _counters(wide.engine))
+    _, want, _, _, x0 = _dense_tick(wide, blk, active, jnp.zeros_like(temp))
+    got_fixed, got_tok = np.asarray(got["fixed"]), np.asarray(got["tok"])
+    assert (got_fixed.sum(-1) == 1).all()       # a step's share: one of 4
+    cold = ~hot
+    assert np.array_equal(got_fixed[cold], np.asarray(want["fixed"])[cold])
+    assert np.array_equal(got_tok[cold], np.asarray(want["tok"])[cold])
+    # at a temperature of 50 a draw is all but uniform over 511 tokens
+    drawn = got_tok[hot][got_fixed[hot]]
+    assert (drawn != np.asarray(x0)[hot][got_fixed[hot]]).mean() > 0.9
+    assert C["mask_token_id"] not in drawn
+
+
+def test_the_heads_rows_are_counted(wide):
+    """Three requests through 256 slots: a tick's head walks one pass
+    of 384 rows (none where all three commit) of the 1,024 it forwards."""
+    engine = wide.engine
+    before = {k: int(v) for k, v in engine.stats()["counters"].items()
+              if np.ndim(v) == 0}
+    hs = _serve(engine, [(_prompt(n), m) for n, m in JOBS[:3]])
+    assert all(len(h.tokens) == m for h, (_, m) in zip(hs, JOBS[:3]))
+    ctr = {k: int(v) - before[k] for k, v in
+           engine.stats()["counters"].items() if k in before}
+    assert ctr["head_rows_walked"] == R * ctr["head_passes"] > 0
+    assert ctr["head_rows_dense"] == ctr["ticks"] * WIDE * L
+    assert ctr["head_passes"] <= ctr["ticks"]
+    assert ctr["head_rows_walked"] < ctr["head_rows_dense"] / 2
 
 
 # ------------------------------------------------------------ the rule alone
