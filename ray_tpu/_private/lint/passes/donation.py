@@ -18,8 +18,9 @@ callables are recognized three ways:
   a literal ``donate_argnums`` in any lexically enclosing scope
   (``fn = jax.jit(step, donate_argnums=(0,)); fn(state, batch)``);
 - ``self.X`` attributes assigned such a result anywhere in the class
-  (the serve engine's ``self._jit_tick`` pattern: wrapped in
-  ``__init__``, called in ``step()``);
+  (the serve engine's pattern, ``serve/llm/programs.py``: the object
+  that wraps ``self._jit_tick`` owns the arrays it donates, and its
+  ``tick()`` hands ``self._cache`` in and rebinds it from the result);
 - one level of interprocedural summary: a function whose *parameter*
   flows into a donated position poisons its callers' arguments too
   (resolved through the package call graph, ambiguity → silence).

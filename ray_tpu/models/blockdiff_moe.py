@@ -378,7 +378,7 @@ def denoise_paged(params, pools, tables, tokens, pos0,
     attends to every key up to the block's last.  A dead slot goes
     through no expert.  Returns (normed hidden [B, L, D], pools,
     counts): the engine multiplies by the head the rows its rule reads
-    (`serve/llm/engine.py::_block_tick_fn`); the counts are the tokens
+    (`serve/llm/programs.py::_block_tick_fn`); the counts are the tokens
     routed to each held expert of each layer, the distinct held experts
     touched summed over the layers, what the walks cost
     (`models/moe.py::walk_counts`), and 1 for the tick."""

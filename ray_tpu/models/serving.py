@@ -120,7 +120,7 @@ the engine then runs no `decode`:
         It applies NO head: the engine multiplies by `head_weight` the
         rows its rule can read, those still masked in a live slot whose
         block is open, R = three eighths of the B x L rows a pass and as
-        many passes as they fill (`serve/llm/engine.py::
+        many passes as they fill (`serve/llm/programs.py::
         _block_predict_rows`, `_block_pass_rows`).  The logits of a row
         are of the token AT its position (no shift).
     prefill(...) is handed the prompt's WHOLE blocks alone (`start` and

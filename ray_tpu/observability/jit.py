@@ -362,6 +362,14 @@ class TrackedJit:
     def lower(self, *args, **kwargs):
         return self._jitted.lower(*args, **kwargs)
 
+    def unprobed(self):
+        """`jax.jit` of the raw function under this wrapper's options:
+        the same program under the function's own name, which no
+        counter sees (lowering on shapes, serve/llm/programs.py)."""
+        import jax
+
+        return jax.jit(self._fn, **self._jit_kwargs)
+
     def eval_shape(self, *args, **kwargs):
         """Shape evaluation against the RAW function: never traces the
         probe, so speculative shape queries cannot inflate the

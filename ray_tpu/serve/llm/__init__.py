@@ -1,7 +1,8 @@
 """ray_tpu.serve.llm — continuous-batching LLM serving on TPU.
 
-The engine (`engine.py`) keeps a fixed pool of decode slots inside a
-bounded set of compiled XLA programs; the deployment (`deployment.py`)
+The engine (`engine.py`, the host scheduler) keeps a fixed pool of
+decode slots inside a bounded set of compiled XLA programs
+(`programs.py`, its device half); the deployment (`deployment.py`)
 exposes it as a Serve replica; `kv_cache.py` pages the KV pool and
 reuses shared prompt prefixes; `router.py` spreads requests across N
 replicas on probed queue depth, SLO lane, and expected prefix-cache

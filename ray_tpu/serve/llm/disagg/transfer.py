@@ -2,7 +2,7 @@
 
 Both halves are thin: the device work (gather-to-dense on export,
 padded scatter on adopt) lives in the engine's two migration programs
-(`LLMEngine._export_fn`, one trace per row length of
+(`Programs._export_fn`, one trace per row length of
 `EngineConfig.export_rows`, and `_adopt_fn`, ONE trace), and the wire
 format is :class:`~ray_tpu.serve.llm.kv_cache.KVState` — plain
 ndarrays plus resume bookkeeping, chosen so a task returning it hits

@@ -432,11 +432,13 @@ class LLMEngine:
     parameters; the model's functions come from its config object
     (`model_config.serving()`, models/serving.py).
 
-    Host-side scheduler + two families of jitted device programs
-    (`_insert` per prefill bucket, `_tick` for the decode step). Thread
-    model: `submit()` is thread-safe; `step()`/`run()` must be driven by
-    a single scheduler thread (serve/llm/deployment.py runs one per
-    replica).
+    The host-side scheduler. The jitted device programs (an insert per
+    prefill bucket, ONE tick for the decode step, ...) and the arrays
+    they donate are its `Programs` object's (serve/llm/programs.py),
+    called by what each call means; `params` stay here and are handed
+    to each call. Thread model: `submit()` is thread-safe;
+    `step()`/`run()` must be driven by a single scheduler thread
+    (serve/llm/deployment.py runs one per replica).
     """
 
     def __init__(self, params: Any, model_config: Any,
@@ -444,11 +446,10 @@ class LLMEngine:
                  rng_seed: int = 0,
                  draft_params: Any = None,
                  draft_config: Any = None):
-        import jax
-        import jax.numpy as jnp
         import numpy as np
 
         from ray_tpu._private import compile_cache
+        from ray_tpu.serve.llm.programs import programs_for
 
         compile_cache.configure()      # before this engine's first compile
         self.params = params
@@ -462,20 +463,24 @@ class LLMEngine:
         if draft_params is not None and model.verify is None:
             raise ValueError(
                 f"{model.name} has no speculative verify step")
+        if draft_params is not None and draft_config is None:
+            raise ValueError("draft_params given without draft_config")
         self._stateful = model.init_slot_state is not None
-        # (W, leaf names) of a model with a window kind of pool leaves
-        # (models/serving.py), else None
-        window = (model.window_kind(model_config)
-                  if model.window_kind else None)
-        self._window_leaves = window[1] if window else ()
-        # Such a sequence, like one with a state by slot, stays in the
-        # slot it was admitted to and is moved by nothing.
-        self._pinned = self._stateful or window is not None
+        # The device half (serve/llm/programs.py): the jitted programs
+        # and the arrays they donate, in the form the model's way of
+        # generating asks for. It places nothing before `allocate`.
+        self._programs = programs = programs_for(
+            model, model_config, c,
+            draft_config if draft_params is not None else None)
+        # A sequence with window leaves (models/serving.py), like one
+        # with a state by slot, stays in the slot it was admitted to
+        # and is moved by nothing.
+        self._pinned = self._stateful or programs.window is not None
         # How a model that generates by blocks does (models/serving.py
         # `BlockSpec`), else None. A slot of such a model holds an open
         # block between ticks that nothing but the tick carries: it is
         # pinned too, and what does not fit the form is refused here.
-        self._block = model.block.spec(model_config) if model.block else None
+        self._block = programs.block
         if self._block is not None:
             self._pinned = True
             self._refuse_for_blocks(draft_params is not None)
@@ -484,39 +489,22 @@ class LLMEngine:
                 "prefix reuse (and the spill that rides it; set "
                 "prefix_cache=False)", "a cached block")
 
-        # Device state (fixed shapes for the engine's whole lifetime).
         from ray_tpu.serve.llm.kv_cache import (
             BlockAllocator, KVTierManager, PrefixCache, PromoteCostModel,
             WindowRing)
 
-        # The pool: a flat dict of [L, NB, bs, ...] leaves that the
-        # model names; the engine moves whole blocks of every leaf
-        # and never looks inside a row.
+        programs.allocate(rng_seed)
         bs = c.kv_block_size
-        if window is None:
-            self._cache = model.init_pool(model_config, c.pool_blocks, bs)
-        else:
-            # a ring holds the window before a chunk and the chunk
-            ring = min(-(-(window[0] + c.prefill_buckets[-1]) // bs),
-                       c.max_blocks_per_slot)
-            n_w = c.num_window_blocks or c.num_slots * ring
-            self._cache = model.init_pool(model_config, c.pool_blocks, bs,
-                                          window_blocks=n_w)
-        # HBM bytes per block (every leaf's rows across all layers)
-        # — the byte-accounting basis for allocator/prefix/tier stats.
-        block_bytes = sum(
-            int(x.nbytes) for name, x in self._cache.items()
-            if name not in self._window_leaves) // c.pool_blocks
-        self._allocator = BlockAllocator(c.pool_blocks, bs,
-                                         block_bytes=block_bytes)
+        self._allocator = BlockAllocator(
+            c.pool_blocks, bs, block_bytes=programs.block_bytes("full"))
         # The window kind's blocks and its ring of a table a slot; None
         # for a model all of whose leaves are of the full kind.
         self._ring = None
-        if window is not None:
-            self._ring = WindowRing(window[0], ring, BlockAllocator(
-                n_w, bs, block_bytes=sum(
-                    int(self._cache[name].nbytes)
-                    for name in self._window_leaves) // n_w), B,
+        if programs.window is not None:
+            self._ring = WindowRing(
+                programs.window, programs.ring_blocks, BlockAllocator(
+                    programs.window_blocks, bs,
+                    block_bytes=programs.block_bytes("window")), B,
                 lookahead=c.decode_block)
         self._prefix = (PrefixCache(self._allocator)
                         if c.prefix_cache else None)
@@ -537,39 +525,9 @@ class LLMEngine:
                 c.kv_host_tier_bytes, c.kv_block_size,
                 put_fn=_tier_store_put, get_fn=_tier_store_get)
             self._prefix.spill_fn = self._spill_evicted
-        # The second kind of state (models/serving.py): a row a slot a
-        # layer that keeps one, {leaf: [L', B, ...]}; None for a model
-        # whose whole state is rows in the pool.
-        self._slot_state = (model.init_slot_state(model_config, B)
-                            if self._stateful else None)
         # The slots whose prompts are under way, a piece a step,
         # inactive until their last piece (`_admit`).
         self._chunking: deque = deque()
-        self._tok = jnp.zeros((B,), jnp.int32)
-        self._pos = jnp.zeros((B,), jnp.int32)
-        # A slot's OPEN block (a model that generates by blocks): its
-        # tokens, which of them are fixed (a flag, never `token == mask`:
-        # a prompt may hold the mask's id), the denoising step, and the
-        # block's first position. Carried from tick to tick on the device.
-        self._blk = None
-        if self._block is not None:
-            L = self._block.length
-            self._blk = {
-                "tok": jnp.full((B, L), self._block.mask_token_id, jnp.int32),
-                "fixed": jnp.zeros((B, L), bool),
-                "step": jnp.zeros((B,), jnp.int32),
-                "pos0": jnp.zeros((B,), jnp.int32)}
-        self._key = jax.random.key(rng_seed)
-        # What the model's decode step counts (models/serving.py),
-        # summed on the device tick by tick. Not donated: `stats()`
-        # reads, maybe from another thread, those of the last tick read
-        # back (`_counters_read`), while the next tick takes them on.
-        self._counters = (model.init_counts(model_config)
-                          if model.init_counts else {})
-        if self._block is not None:     # what the block tick counts itself
-            self._counters = dict(self._counters, **{
-                name: jnp.zeros((), jnp.int32) for name in _BLOCK_COUNTERS})
-        self._counters_read = self._counters
         # Host-side mirrors fed into each program call (tiny transfers).
         # `_active`: the slot holds a decoding sequence (from its last
         # insert until it is released).
@@ -662,68 +620,17 @@ class LLMEngine:
 
         # Speculative decoding: a small draft model proposing
         # spec_k - 1 greedy tokens per round, verified in one paged
-        # K-token target step (the model's `verify`). The draft keeps
-        # a cache of its own, one [S] stripe a slot (models/serving.py
-        # `DraftFns`) — it is tiny, so paging it would buy nothing.
+        # K-token target step (the model's `verify`).
         self._draft = draft_params
         self.draft_config = draft_config
         self._spec_ok = np.zeros((B,), bool)
         self._spec_rounds = 0
         self._spec_proposed = 0
         self._spec_accepted = 0
-        if draft_params is not None:
-            if draft_config is None:
-                raise ValueError("draft_params given without "
-                                 "draft_config")
-            self._draft_model = draft_config.serving().draft
-            self._draft_cache = self._draft_model.init_cache(
-                draft_config, B, c.max_seq_len)
 
-        # Compile tracking through the shared telemetry plane: the
-        # TrackedJit probe runs ONLY when jax traces a new program, so
-        # .traces counts compiled engine programs — the compile-guard
-        # test asserts trace_count <= n_buckets + 1, and the recompile
-        # detector warns if either program family exceeds its budget
-        # (ONE tick, one insert per prefill bucket).
-        from ray_tpu.observability import serve_metrics, tracked_jit
+        from ray_tpu.observability import serve_metrics
         from ray_tpu.observability.device import ensure_sampler_registered
 
-        # No fence inside a dispatch (it would drain the pipeline and
-        # time two ticks as one): a sampled tick's wall is taken where
-        # `_read_back` waits for it anyway (`_land_tick`).
-        if self._block is None:
-            self._jit_tick = tracked_jit(
-                self._tick_fn, name="llm_engine_tick", fence_samples=False,
-                trace_budget=1, donate_argnums=(1, 3, 4, 9))
-            self._jit_insert = tracked_jit(
-                self._insert_fn, name="llm_engine_insert",
-                trace_budget=len(c.prefill_buckets),
-                donate_argnums=(1, 2, 3, 12))
-        else:       # the same two families of programs, in their block form
-            self._jit_tick = tracked_jit(
-                self._block_tick_fn, name="llm_engine_tick",
-                fence_samples=False, trace_budget=1, donate_argnums=(1, 3))
-            self._jit_insert = tracked_jit(
-                self._block_insert_fn, name="llm_engine_insert",
-                trace_budget=len(c.prefill_buckets), donate_argnums=(1, 2))
-        # KV migration programs: block counts are data (padded
-        # ids, out-of-bounds scatters dropped), so the adopt is ONE
-        # trace and the export one per row length of `export_rows`.
-        self._jit_export = tracked_jit(
-            self._export_fn, name="llm_engine_export",
-            trace_budget=len(c.export_rows))
-        self._jit_adopt = tracked_jit(
-            self._adopt_fn, name="llm_engine_adopt",
-            trace_budget=1, donate_argnums=(0, 1, 2))
-        if self._draft is not None:
-            self._jit_spec = tracked_jit(
-                self._spec_fn, name="llm_engine_spec",
-                trace_budget=1, donate_argnums=(2, 3, 5, 6))
-            self._jit_draft_insert = tracked_jit(
-                self._draft_insert_fn,
-                name="llm_engine_draft_insert",
-                trace_budget=len(c.prefill_buckets),
-                donate_argnums=(1,))
         self._metrics = serve_metrics()
         ensure_sampler_registered()
 
@@ -737,314 +644,6 @@ class LLMEngine:
         self._model_label = (
             f"llama_d{getattr(mc, 'dim', 0)}"
             f"_l{getattr(mc, 'n_layers', 0)}")
-
-    # ------------------------------------------------------------ programs
-
-    def _tick_fn(self, params, pools, tables, tok, pos, active, temp,
-                 key, counters=None, state=None):
-        """`decode_block` decode steps for all B slots in one dispatch
-        (lax.scan — still ONE compiled program; the KV write/read goes
-        through the block tables, which are data). Inactive slots are
-        computed but masked: no KV write, token/pos parked. Positions
-        clamp at S-1 so a slot finishing mid-block can speculate ahead
-        without ever attending past rows it wrote itself; the host
-        discards post-stop tokens. What the model's step counts is
-        added to `counters` (an empty tree for a model that counts
-        nothing: no operation, no argument). `state` is the per-slot
-        state of a model that keeps one (None otherwise: no argument),
-        advanced for the live slots."""
-        import jax
-        import jax.numpy as jnp
-
-        decode = self._model.decode
-        S = self.config.max_seq_len
-
-        def body(carry, _):
-            pools, tok, pos, key, counters, state = carry
-            if state is None:
-                logits, pools, counts = decode(
-                    params, pools, tables, tok, pos, self.model_config,
-                    active)
-            else:
-                logits, pools, counts, state = decode(
-                    params, pools, tables, tok, pos, self.model_config,
-                    active, state)
-            counters = jax.tree.map(jnp.add, counters, counts)
-            with jax.named_scope("sample"):
-                key, sub = jax.random.split(key)
-                nxt = _sample(logits, temp, sub)
-                tok = jnp.where(active, nxt, tok)
-                pos = jnp.where(active, jnp.minimum(pos + 1, S - 1), pos)
-            return (pools, tok, pos, key, counters, state), tok
-
-        (pools, tok, pos, key, counters, state), toks = jax.lax.scan(
-            body, (pools, tok, pos, key, counters or {}, state), None,
-            length=self.config.decode_block)
-        out = (pools, tok, pos, key, toks, counters)         # toks [K, B]
-        return out if state is None else out + (state,)
-
-    def _insert_fn(self, params, pools, tok, pos, table_row, hist_len,
-                   padded_suffix, suffix_len, new_block_ids, slot,
-                   temperature, key, state=None):
-        """Prefill the (possibly prefix-truncated) suffix of one prompt
-        and scatter its KV into the slot's freshly-allocated blocks;
-        sample the first generated token from the logits at the last
-        REAL prompt position.
-
-        The prefix-hit path IS the miss path: ``hist_len`` (dynamic
-        data) tells the model's prefill where the suffix starts; a miss
-        is just hist_len = 0 over an all-zero history. One trace per
-        suffix bucket — the only static shapes are ``padded_suffix``
-        [Pb] and ``new_block_ids`` [Pb / block_size], both functions of
-        the bucket — so compile count stays <= len(prefill_buckets).
-
-        A model with per-slot `state` gets this slot's rows as they
-        stand after the `hist_len` tokens already inserted — zeros when
-        there are none, which is how a slot is cleared at admission —
-        and its rows after the last real token of this call are put
-        back: the hand-off between the chunks of one prompt.
-        """
-        import jax
-        import jax.numpy as jnp
-
-        c = self.model_config
-        bs = self.config.kv_block_size
-        Pb = padded_suffix.shape[0]
-
-        def of_kind(x, name):
-            # a model with a window kind hands a table row and block
-            # ids a kind (models/serving.py)
-            if self._ring is None:
-                return x
-            return x["window" if name in self._window_leaves else "full"]
-
-        # History view: this slot's dense [S_pad] gather of every leaf
-        # (a window leaf's: its ring as it lies). Rows at and past
-        # hist_len are stale — masked inside the model's prefill.
-        hist = {name: pool[:, of_kind(table_row, name)].reshape(
-            (pool.shape[0], -1) + pool.shape[3:])
-            for name, pool in pools.items()}
-        if state is None:
-            hidden, rows = self._model.prefill(
-                params, padded_suffix[None], hist_len, hist, c, suffix_len)
-        else:
-            hidden, rows, mine = self._model.prefill(
-                params, padded_suffix[None], hist_len, hist, c, suffix_len,
-                {name: jnp.where(hist_len > 0, x[:, slot], 0)
-                 for name, x in state.items()})
-            state = {name: x.at[:, slot].set(mine[name].astype(x.dtype))
-                     for name, x in state.items()}
-        # rows: {leaf: [L, Pb, ...]} -> whole blocks into the pool at
-        # the slot's new physical ids (padding rows ride along; decode
-        # overwrites each before attending).
-        pools = {name: pool.at[:, of_kind(new_block_ids, name)].set(
-            rows[name].astype(pool.dtype).reshape(
-                (pool.shape[0], Pb // bs, bs) + pool.shape[3:]))
-            for name, pool in pools.items()}
-        # [1, Pb, D], or the last real row alone (models/serving.py)
-        x_last = hidden[0, 0] if hidden.shape[1] == 1 else \
-            jax.lax.dynamic_index_in_dim(
-                hidden[0], suffix_len - 1, axis=0, keepdims=False)
-        logits = jax.lax.dot_general(
-            x_last[None], self._model.head_weight(params, c),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [1, V]
-        key, sub = jax.random.split(key)
-        first = _sample(logits, temperature[None], sub)[0]
-        tok = tok.at[slot].set(first)
-        pos = pos.at[slot].set(hist_len + suffix_len)
-        out = (pools, tok, pos, key)
-        return out if state is None else out + (state,)
-
-    def _block_tick_fn(self, params, pools, tables, blk, active, temp, key,
-                       counters):
-        """The tick of a model that generates by blocks: ONE forward
-        over the L rows of every live slot's open block
-        (`ServingFns.block.denoise`), the same program whatever each
-        slot's step. It writes the block's K/V rows into the pool at
-        the block's positions and attends with every row seeing every
-        key up to the block's last; then, a slot, EITHER fixes
-        positions by the rule (the block had a masked position:
-        `_block_predict_rows`, `_block_choose`; its rows were
-        provisional and the next tick overwrites them) OR commits (it
-        had none: the rows just written are final, the block moves on
-        by L and is masked anew). The head's product and the softmax
-        run over the rows the rule can read and over no other: those
-        still masked in a live slot whose block is open, known from the
-        tick's arguments before the forward, `_block_pass_rows` of them
-        a pass and as many passes as they fill (none on a tick where
-        every live slot commits). Returns the block's tokens as the
-        tick leaves them [B, L] and which slots' blocks this tick
-        COMPLETED (fixed their last masked position) [B]: those the
-        host emits."""
-        import jax
-        import jax.numpy as jnp
-
-        spec = self._block
-        L, S = spec.length, self.config.max_seq_len
-        tok, fixed, step, pos0 = (blk[k] for k in
-                                  ("tok", "fixed", "step", "pos0"))
-        masked = ~fixed
-        is_open = masked.any(-1)                # else: nothing left to fix
-        fixing, committing = active & is_open, active & ~is_open
-        hidden, pools, counts = self._model.block.denoise(
-            params, pools, tables, tok, pos0, self.model_config, active,
-            _block_writes(active, is_open))
-        key, sub = jax.random.split(key)
-        x0, conf, passes = _block_predict_rows(
-            hidden, self._model.head_weight(params, self.model_config),
-            masked & fixing[:, None], temp, sub, spec.mask_token_id)
-        with jax.named_scope("unmask"):
-            share = _block_share(step, spec)
-            pick = _block_choose(conf, masked, share, spec)
-            pick = pick & fixing[:, None]
-            tok = jnp.where(pick, x0, tok)
-            fixed = fixed | pick
-            done = fixing & fixed.all(-1)
-            out, mine = tok, committing[:, None]
-            blk = {"tok": jnp.where(mine, spec.mask_token_id, tok),
-                   "fixed": jnp.where(mine, False, fixed),
-                   "step": jnp.where(committing, 0, step + fixing),
-                   "pos0": jnp.where(committing,
-                                     jnp.minimum(pos0 + L, S - L), pos0)}
-            n_pick = pick.sum(-1, dtype=jnp.int32)
-            counts = dict(
-                counts,
-                block_forwards=active.sum(dtype=jnp.int32),
-                block_commits=committing.sum(dtype=jnp.int32),
-                block_tokens_fixed=n_pick.sum(),
-                block_threshold_fixes=jnp.where(
-                    fixing, n_pick - jnp.minimum(
-                        share, masked.sum(-1, dtype=jnp.int32)), 0).sum(),
-                head_passes=passes,
-                head_rows_walked=passes * _block_pass_rows(*masked.shape),
-                head_rows_dense=jnp.asarray(masked.size, jnp.int32))
-        counters = jax.tree.map(jnp.add, counters, counts)
-        return pools, blk, key, out, done, counters
-
-    def _block_insert_fn(self, params, pools, blk, table_row, hist_len,
-                         padded_suffix, suffix_len, new_block_ids, slot,
-                         tail, tail_len):
-        """The insert of a model that generates by blocks: the prompt's
-        WHOLE blocks (a piece of them: `padded_suffix` [Pb], the first
-        `suffix_len` real, at `hist_len`..; both multiples of the block
-        length) go through the model's block-causal prefill and their
-        rows into the slot's blocks, as `_insert_fn` puts them. It
-        yields NO token: the slot's open block is set to the prompt's
-        trailing tokens (`tail` [L], the first `tail_len` of them),
-        fixed, and mask tokens behind them, at step 0 and at the
-        position behind the rows now in."""
-        import jax.numpy as jnp
-
-        bs = self.config.kv_block_size
-        Pb = padded_suffix.shape[0]
-        hist = {name: pool[:, table_row].reshape(
-            (pool.shape[0], -1) + pool.shape[3:])
-            for name, pool in pools.items()}
-        _, rows = self._model.prefill(
-            params, padded_suffix[None], hist_len, hist, self.model_config,
-            suffix_len)
-        pools = {name: pool.at[:, new_block_ids].set(
-            rows[name].astype(pool.dtype).reshape(
-                (pool.shape[0], Pb // bs, bs) + pool.shape[3:]))
-            for name, pool in pools.items()}
-        ours = jnp.arange(self._block.length) < tail_len
-        blk = {"tok": blk["tok"].at[slot].set(
-                   jnp.where(ours, tail, self._block.mask_token_id)),
-               "fixed": blk["fixed"].at[slot].set(ours),
-               "step": blk["step"].at[slot].set(0),
-               "pos0": blk["pos0"].at[slot].set(hist_len + suffix_len)}
-        return pools, blk
-
-    def _export_fn(self, pools, table_row):
-        """Gather the blocks `table_row` names into dense {leaf: [L,
-        len(table_row), bs, ...]} arrays (the host slices the valid
-        prefix). Read-only on the pool; the ids are data and the row's
-        length is a shape: one trace per length of
-        `EngineConfig.export_rows` (see `_export_blocks`)."""
-        return {name: pool[:, table_row] for name, pool in pools.items()}
-
-    def _adopt_fn(self, pools, tok, pos, blocks, scatter_ids, slot,
-                  new_tok, new_pos):
-        """Scatter an imported KVState's blocks into the pool at this
-        engine's freshly-allocated ids and seed the slot's token /
-        position. ``scatter_ids`` is padded to max_blocks with the pool
-        size (out-of-bounds scatters are dropped under jit), so ONE
-        compiled program serves every valid-block count."""
-        pools = {name: pool.at[:, scatter_ids].set(blocks[name])
-                 for name, pool in pools.items()}
-        tok = tok.at[slot].set(new_tok)
-        pos = pos.at[slot].set(new_pos)
-        return pools, tok, pos
-
-    def _draft_insert_fn(self, draft_params, dcache, padded_prompt,
-                         slot):
-        """Prefill the draft model's cache stripe for one admitted slot
-        (always the FULL padded prompt — the draft has no prefix cache;
-        padding rows are stale but masked, and overwritten before they
-        are attended). One trace per prompt bucket."""
-        from jax import lax
-
-        dc = self.draft_config
-        _, ks, vs = self._draft_model.prefill(
-            draft_params, padded_prompt[None], dc)
-        return {
-            "k": lax.dynamic_update_slice(
-                dcache["k"], ks.astype(dc.dtype), (0, slot, 0, 0, 0)),
-            "v": lax.dynamic_update_slice(
-                dcache["v"], vs.astype(dc.dtype), (0, slot, 0, 0, 0)),
-        }
-
-    def _spec_fn(self, params, draft_params, pools, dcache, tables,
-                 tok, pos, active):
-        """One speculative round (greedy lanes only): the draft
-        proposes spec_k - 1 tokens from its own cache, ONE paged
-        verify step scores all spec_k inputs on the target, and the
-        longest draft prefix agreeing with the target argmax is
-        accepted. Every emitted token IS the target's argmax given
-        correct inputs, so a round is token-identical to 1..spec_k
-        plain ticks — a zero-accept round still emits the one token a
-        plain tick would have. Rejected inputs leave stale rows past
-        the new position in both caches; both are overwritten before
-        ever being attended (the recycled-slot invariant)."""
-        import jax.numpy as jnp
-        from jax import lax
-
-        decode_step = self._draft_model.decode
-        verify_kv_paged = self._model.verify
-        c = self.config
-        K = c.spec_k
-        S = c.max_seq_len
-        B = tok.shape[0]
-
-        def draft_body(carry, _):
-            dcache, dtok, dpos = carry
-            dlogits, dcache = decode_step(
-                draft_params, dcache, dtok, dpos, self.draft_config,
-                active=active)
-            nxt = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
-            dtok = jnp.where(active, nxt, dtok)
-            dpos = jnp.where(active, jnp.minimum(dpos + 1, S - 1), dpos)
-            return (dcache, dtok, dpos), dtok
-
-        (dcache, _, _), drafts = lax.scan(
-            draft_body, (dcache, tok, pos), None, length=K - 1)
-        # Verify inputs: the accepted stream so far ends at `tok`
-        # (sampled, unconsumed); the draft continues it. [B, K]
-        inputs = jnp.concatenate([tok[None], drafts], axis=0).T
-        logits, pools = verify_kv_paged(
-            params, pools, tables, inputs, pos, self.model_config,
-            active=active)
-        t = jnp.argmax(logits, axis=-1).astype(jnp.int32)    # [B, K]
-        # Draft token j+1 survives iff the target's argmax after input
-        # j equals it; acceptance is the leading run of agreements.
-        agree = (t[:, :-1] == drafts.T).astype(jnp.int32)    # [B, K-1]
-        acc = jnp.cumprod(agree, axis=1).sum(axis=1)         # 0..K-1
-        n_emit = jnp.where(active, acc + 1, 0)
-        new_tok = t[jnp.arange(B), jnp.maximum(n_emit, 1) - 1]
-        tok = jnp.where(active, new_tok, tok)
-        pos = jnp.where(active, jnp.minimum(pos + n_emit, S - 1), pos)
-        return pools, dcache, tok, pos, t, n_emit
 
     # ----------------------------------------------------------- submission
 
@@ -1138,7 +737,7 @@ class LLMEngine:
             raise ValueError(
                 f"{self._model.name} keeps a state by slot that {carrier} "
                 f"does not carry: {what} is not offered")
-        if self._window_leaves:
+        if self._programs.window is not None:
             raise ValueError(
                 f"{self._model.name} keeps its window layers' rows in a "
                 f"ring of blocks of their own kind that {carrier} does "
@@ -1447,35 +1046,20 @@ class LLMEngine:
         """Dispatch the insert program of `padded`'s bucket. `tail`: for
         a model that generates by blocks, the prompt's tokens behind
         the rows this piece completes (none before the last piece)."""
-        import numpy as np
-
-        if self._block is not None:
-            fixed = np.zeros((self._block.length,), np.int32)
-            fixed[:len(tail)] = tail
-            with trace_span("llm_engine.insert_dispatch",
-                            bucket=len(padded), tokens=int(suffix_len)):
-                self._cache, self._blk = self._jit_insert(
-                    self.params, self._cache, self._blk, row,
-                    np.int32(hist_len), padded, np.int32(suffix_len),
-                    scatter_ids, np.int32(slot), fixed, np.int32(len(tail)))
-            return
         if self._ring is not None:      # a table row and ids a kind
             row = {"full": row, "window": self._ring.tables[slot].copy()}
             scatter_ids = {"full": scatter_ids,
                            "window": self._ring.block_ids(
                                slot, hist_len, len(padded))}
         said = {"bucket": len(padded)}
-        if self._stateful:      # what the chunk is, for the trace's readers
-            said.update(tokens=int(suffix_len), state_in=int(hist_len > 0))
+        # what the chunk is, for the trace's readers
+        if self._stateful or self._block is not None:
+            said["tokens"] = int(suffix_len)
+        if self._stateful:
+            said["state_in"] = int(hist_len > 0)
         with trace_span("llm_engine.insert_dispatch", **said):
-            (self._cache, self._tok, self._pos, self._key,
-             *state) = self._jit_insert(
-                self.params, self._cache, self._tok, self._pos, row,
-                np.int32(hist_len), padded, np.int32(suffix_len),
-                scatter_ids, np.int32(slot), np.float32(temperature),
-                self._key, self._slot_state)
-        if state:
-            self._slot_state, = state
+            self._programs.insert(self.params, slot, row, hist_len, padded,
+                                  suffix_len, scatter_ids, temperature, tail)
 
     def _admit_piece(self, handle: RequestHandle, slot: int, span) -> bool:
         """The next piece of this request's prompt into this slot: the
@@ -1653,14 +1237,10 @@ class LLMEngine:
         if handle.meter is not None:
             handle.meter.blocks_acquired(len(blocks))
 
-        nb = c.max_blocks_per_slot
         # Padding rows scatter to pool_blocks (out of bounds → dropped).
-        ids = np.full((nb,), c.pool_blocks, np.int32)
+        ids = np.full((c.max_blocks_per_slot,), c.pool_blocks, np.int32)
         ids[:n_valid] = blocks[:n_valid]
-        self._cache, self._tok, self._pos = self._jit_adopt(
-            self._cache, self._tok, self._pos,
-            _padded_blocks(st.blocks, nb), ids,
-            np.int32(slot), np.int32(st.next_tok), np.int32(st.pos))
+        self._programs.adopt(st.blocks, ids, slot, st.next_tok, st.pos)
         if self._prefix is not None:
             # Shared prompts stay warm across the migration: register
             # the prompt's FULL blocks exactly like a fresh admission.
@@ -1704,8 +1284,7 @@ class LLMEngine:
         bucket = self._bucket_for(n)
         padded = np.zeros((bucket,), np.int32)
         padded[:n] = np.asarray(consumed, np.int32)
-        self._draft_cache = self._jit_draft_insert(
-            self._draft, self._draft_cache, padded, np.int32(slot))
+        self._programs.draft_insert(self._draft, padded, slot)
         self._spec_ok[slot] = True
 
     def _release_slot(self, slot: int, donate: bool = False) -> None:
@@ -1827,10 +1406,10 @@ class LLMEngine:
         handle = self._slots[slot].handle
         req = handle.request
         bs = self.config.kv_block_size
-        pos = int(np.asarray(self._pos)[slot])
-        next_tok = int(np.asarray(self._tok)[slot])
+        pos = int(self._programs.positions()[slot])
+        next_tok = int(self._programs.tokens()[slot])
         n_valid = -(-pos // bs)
-        row = self._export_blocks(self._tables[slot, :n_valid])
+        row = self._programs.export(self._tables[slot, :n_valid])
         state = KVState(
             prompt=list(req.prompt),
             tokens=list(handle.tokens),
@@ -1845,19 +1424,6 @@ class LLMEngine:
         return state
 
     # ------------------------------------------------------- KV tiering
-
-    def _export_blocks(self, ids: Sequence[int]) -> Dict[str, Any]:
-        """Dispatch the export gather over `ids` (at most
-        `max_blocks_per_slot` of them), padded with block 0 to the
-        smallest row of `export_rows` that holds them. Returns the
-        device row {leaf: [L, row, bs, ...]}; its first len(ids)
-        blocks are the ones asked for."""
-        import numpy as np
-
-        n = next(r for r in self.config.export_rows if r >= len(ids))
-        row = np.zeros((n,), np.int32)
-        row[:len(ids)] = ids
-        return self._jit_export(self._cache, row)
 
     def _spill_evicted(self, victims: List[Any]) -> int:
         """PrefixCache eviction hook: gather the victims' HBM rows
@@ -1883,7 +1449,7 @@ class LLMEngine:
         with trace_span("llm_engine.spill", evicted_blocks=len(ents)) as sp:
             for i in range(0, len(ents), nb):
                 chunk = ents[i:i + nb]
-                row = self._export_blocks([e.block for e in chunk])
+                row = self._programs.export([e.block for e in chunk])
                 for x in row.values():
                     x.copy_to_host_async()
                     exported += x.nbytes
@@ -1941,17 +1507,13 @@ class LLMEngine:
 
         t_pro = time.time()
         c = self.config
-        nb = c.max_blocks_per_slot
-        ids = np.full((nb,), c.pool_blocks, np.int32)
+        ids = np.full((c.max_blocks_per_slot,), c.pool_blocks, np.int32)
         ids[:len(dst_blocks)] = dst_blocks
         # each hit carries its chain link as its payload's LAST block
         last = {name: np.concatenate(
             [h.prefix.blocks[name][:, -1:] for h in hits], axis=1)
             for name in hits[0].prefix.blocks}
-        self._cache, self._tok, self._pos = self._jit_adopt(
-            self._cache, self._tok, self._pos,
-            _padded_blocks(last, nb), ids,
-            np.int32(slot), np.int32(0), np.int32(0))
+        self._programs.adopt(last, ids, slot, 0, 0)
         self._tiers.pop(hits)
         self._promoted_blocks += len(hits)
         if handle is not None:
@@ -2024,7 +1586,7 @@ class LLMEngine:
         if hit:
             n = min(len(hit), c.max_blocks_per_slot)
             got = {name: np.asarray(x) for name, x in
-                   self._export_blocks(hit[:n]).items()}
+                   self._programs.export(hit[:n]).items()}
             out = _block_prefixes(
                 got, [tuple(tokens[: (j + 1) * bs]) for j in range(n)], bs)
             self._allocator.free(hit)       # match increfed for us
@@ -2308,8 +1870,6 @@ class LLMEngine:
         and after a round's settle, which can end any), and a round's
         last condition is read under `tick_dispatch`: no wait of the
         host's lies between two phases."""
-        import numpy as np
-
         phase = self._loop.phase
         with phase("llm_engine.ctrl"):
             did_cancel = bool(self._cancelled)
@@ -2329,7 +1889,7 @@ class LLMEngine:
             # exporting engine already. The insert ran behind the tick
             # in flight, whose tokens go out after this step's dispatch.
             with phase("llm_engine.first_token_wait"):
-                tok_host = np.asarray(self._tok)
+                tok_host = self._programs.tokens()
                 for slot, fresh in inserted:
                     if not fresh:
                         continue
@@ -2353,7 +1913,7 @@ class LLMEngine:
                 self._update_gauges()
             return bool(inserted) or did_cancel or did_ctrl or settled
         with phase("llm_engine.tick_dispatch") as sp:
-            at, sample = self._loop.now, None
+            at = self._loop.now
             spec = want_spec and self._spec_fits(live)
             if self._ring is not None:
                 self._cover_rings(live)
@@ -2362,31 +1922,20 @@ class LLMEngine:
             self._loop.ticks += 1
             self._loop.overlapped += bool(self._flying)
             if spec:
-                outs = self._spec_dispatch(mask)
-            elif self._block is not None:
-                (self._cache, self._blk, self._key, *outs,
-                 self._counters) = self._jit_tick(
-                    self.params, self._cache, self._tick_tables(),
-                    self._blk, mask, self._temp.copy(), self._key,
-                    self._counters)
-                outs = tuple(outs)          # tokens [B, L], completed [B]
-                sample = self._jit_tick.take_sample()
-                self._slot_forwards += len(live)
+                outs, sample, counters = self._programs.spec(
+                    self.params, self._draft, self._tables.copy(), mask)
             else:
-                (self._cache, self._tok, self._pos, self._key, out,
-                 self._counters, *state) = self._jit_tick(
-                    self.params, self._cache, self._tick_tables(),
-                    self._tok, self._pos, mask,
-                    self._temp.copy(), self._key, self._counters,
-                    self._slot_state)
-                if state:
-                    self._slot_state, = state
-                outs = (out,)                           # [K, B]
-                sample = self._jit_tick.take_sample()
-                self._rows[live] += self.config.decode_block
+                # tokens [K, B]; or, of blocks, tokens [B, L], completed [B]
+                outs, sample, counters = self._programs.tick(
+                    self.params, self._tick_tables(), mask,
+                    self._temp.copy())
+                if self._block is not None:
+                    self._slot_forwards += len(live)
+                else:
+                    self._rows[live] += self.config.decode_block
             self._flying.append(_Tick(
                 outs, live, [self._slots[s].handle for s in live], at, spec,
-                sample, self._counters))
+                sample, counters))
         # What this step's admissions evicted lands while the chip
         # runs their inserts and the tick.
         self._land_spills()
@@ -2459,10 +2008,8 @@ class LLMEngine:
             # (between two ticks of a full pipeline: the interval
             # between their `tick_ready` ends).
             wall = self._ready_at - max(tick.at, ready_before)
-            if tick.sample is not None:
-                self._jit_tick.record_wall(tick.sample, wall)
+            self._programs.landed(tick.counters, tick.sample, wall)
             self._credit_decode(tick.handles, wall)
-            self._counters_read = tick.counters
             if self._block is not None:
                 self._emit_blocks(tick, toks_host, done, sp)
                 del tick, toks_host
@@ -2608,19 +2155,9 @@ class LLMEngine:
     def _spec_fits(self, live) -> bool:
         """`_spec_wanted`'s last condition, from `_pos` read on the
         host (settled: nothing is in flight that would move it)."""
-        import numpy as np
-
-        pos_host = np.asarray(self._pos)
+        pos_host = self._programs.positions()
         return bool((pos_host[live] <= self.config.max_seq_len
                      - self.config.spec_k).all())
-
-    def _spec_dispatch(self, mask):
-        """Dispatch one speculative round; `_spec_wait` reads it."""
-        (self._cache, self._draft_cache, self._tok, self._pos,
-         t, n_emit) = self._jit_spec(
-            self.params, self._draft, self._cache, self._draft_cache,
-            self._tables.copy(), self._tok, self._pos, mask)
-        return t, n_emit
 
     def _spec_wait(self, t, n_emit):
         """Read a speculative round back: (tokens [K, B] host, n_emit
@@ -2730,7 +2267,7 @@ class LLMEngine:
             # max_tokens=2: a 1-token request finishes AT insert and the
             # decode tick would never trace. Draft disabled: phase one
             # compiles the PLAIN tick (the spec gate would otherwise
-            # route every greedy warmup batch through _jit_spec).
+            # route every greedy warmup batch through a round).
             handles = [self.submit(Request(prompt=[1] * b, max_tokens=2))
                        for b in self.config.prefill_buckets]
             while any(h.finished_at is None for h in handles):
@@ -2754,7 +2291,7 @@ class LLMEngine:
 
         if not self._pinned:    # else exports nothing (models/serving.py)
             for n in self.config.export_rows:   # one row alive at a time
-                jax.block_until_ready(self._export_blocks([0] * n))
+                jax.block_until_ready(self._programs.export([0] * n))
         self._warmup = {"seconds": time.monotonic() - t0,
                         "programs": jit_stats_since(before)}
 
@@ -2767,14 +2304,7 @@ class LLMEngine:
         len(buckets) inserts + 1 tick, plus at most len(export_rows)
         exports, 1 adopt, 1 spec round, and len(buckets) draft inserts
         when wired)."""
-        return sum(self._traces().values())
-
-    def _traces(self) -> Dict[str, int]:
-        """Traces by program family (the last two exist with a draft)."""
-        return {name: getattr(self, f"_jit_{name}").traces
-                for name in ("tick", "insert", "export", "adopt", "spec",
-                             "draft_insert")
-                if hasattr(self, f"_jit_{name}")}
+        return sum(self._programs.traces().values())
 
     def slot_state(self, slot: int) -> Optional[Dict[str, Any]]:
         """One slot's rows of the model's per-slot state, on the host
@@ -2783,18 +2313,13 @@ class LLMEngine:
         left them until the next admission into the slot zeroes them.
         For tests and for the benchmark's audit of the state's
         precision; call it between steps."""
-        if not self._stateful:
-            return None
-        import numpy as np
-
-        return {name: np.asarray(x[:, slot])
-                for name, x in self._slot_state.items()}
+        return self._programs.slot_state(slot)
 
     def stats(self) -> Dict[str, Any]:
         with self._lock:
             queued_by_lane = {lane: len(q)
                               for lane, q in self._queues.items()}
-        traces = self._traces()
+        device = self._programs.stats()
         out = {
             "num_slots": self.config.num_slots,
             "active_slots": int(self._active.sum()),
@@ -2804,12 +2329,8 @@ class LLMEngine:
             "slot_reuses": self._slot_reuses,
             "preempted": self._preempted,
             "kv_layout": self.config.kv_layout,
-            # which path the tick's attention compiled to: the model
-            # says (by backend and shape alone); "gather" for a model
-            # that has only that one
-            "paged_attention": (
-                self._model.paged_attention(self._cache)
-                if self._model.paged_attention else "gather"),
+            # which path the tick's attention compiled to
+            "paged_attention": device["paged_attention"],
             # and the experts' grouped products, at the tick's shape
             # ("xla" also for a model that has none)
             "grouped_matmul": (
@@ -2845,8 +2366,8 @@ class LLMEngine:
                 "seconds": self._warmup["seconds"],
                 "programs": {k: dict(v) for k, v in
                              self._warmup["programs"].items()}},
-            "traces": traces,
-            "trace_count": sum(traces.values()),
+            "traces": device["traces"],
+            "trace_count": sum(device["traces"].values()),
             # the full kind's blocks; a model's window kind under
             # "window" (models/serving.py), and the bytes of both kinds
             # the live slots held, summed over all ticks as `live_rows`
@@ -2880,18 +2401,13 @@ class LLMEngine:
                 "tokens_emitted": self._tokens_emitted}
         if self._stateful:
             out["slot_state"] = {
-                "bytes": sum(int(x.nbytes)
-                             for x in self._slot_state.values()),
+                "bytes": device["slot_state_bytes"],
                 "prompts_under_way": len(self._chunking)}
-        if self._counters_read:
-            # the model's own counters, summed on the device since
-            # start, as the last tick READ BACK left them: they agree
-            # with the tokens emitted, and no caller (a metrics thread,
-            # a load generator) waits for the tick in flight
-            import numpy as np
-
-            out["counters"] = {name: np.asarray(x) for name, x in
-                               self._counters_read.items()}
+        counters = self._programs.counters()
+        if counters:
+            # the model's own, as the last tick READ BACK left them:
+            # they agree with the tokens emitted
+            out["counters"] = counters
         if self._draft is not None or self._spec_rounds:
             denom = max(self._spec_proposed, 1)
             out["spec"] = {
@@ -2939,189 +2455,3 @@ def _block_prefixes(got, keys, block_size):
                              for name, x in got.items()})
             for j, tokens in enumerate(keys)]
 
-
-def _padded_blocks(blocks, n_blocks):
-    """{leaf: [L, n, bs, ...]} host blocks -> the adopt program's fixed
-    shape {leaf: [L, n_blocks, bs, ...]}, zeros after the n that are
-    there (their scatter ids point past the pool)."""
-    import numpy as np
-
-    out = {}
-    for name, x in blocks.items():
-        out[name] = np.zeros((x.shape[0], n_blocks) + x.shape[2:], x.dtype)
-        out[name][:, :x.shape[1]] = x
-    return out
-
-
-# What the block tick counts beside the model's own counters: live
-# slot-forwards, those that committed a block, positions fixed by a
-# denoising step, and those of them that the confidence threshold fixed
-# beyond the step's share; the passes of the head over the rows still
-# masked (`_block_predict_rows`), the rows they multiplied (passes x
-# `_block_pass_rows`) and the rows a head over every slot's block would
-# have (slots x L a tick).
-_BLOCK_COUNTERS = ("block_forwards", "block_commits", "block_tokens_fixed",
-                   "block_threshold_fixes", "head_passes",
-                   "head_rows_walked", "head_rows_dense")
-
-
-def _block_writes(active, is_open):
-    """Which slots' forwards write their block's rows into the pool:
-    every live one. A denoising step's rows are provisional (the next
-    tick overwrites them, and no query reads past its own block); the
-    commit's, computed from the block's final tokens, are the ones that
-    stay."""
-    del is_open
-    return active
-
-
-def _block_share(step, spec):
-    """Positions a denoising step fixes at least [B]: L / steps spread
-    evenly, the remainder to the first steps (the family's
-    `get_num_transfer_tokens`)."""
-    import jax.numpy as jnp
-
-    base, rem = divmod(spec.length, spec.steps)
-    table = jnp.asarray([base + (i < rem) for i in range(spec.steps)],
-                        jnp.int32)
-    return table[jnp.minimum(step, spec.steps - 1)]
-
-
-def _block_pass_rows(slots, length):
-    """R, the rows one pass of the block tick's head multiplies of the
-    `slots x length` a tick forwards: THREE EIGHTHS of them in whole
-    tiles of 128 rows (all of them where they are fewer than a tile),
-    from shapes alone.  A live slot holds a masked position in half its
-    rows over a block's steps (L / 2), so an engine three quarters
-    full or less needs one pass, a full one two, and every slot at step
-    0 at once three, which cost less than a head over all rows.  A pass
-    costs a fixed part (the head's weight is read once a pass) and a
-    part by the row, so few large passes beat many small ones until a
-    pass is mostly spare rows: on a v5e at 1,024 rows x 2,048 x 151,936,
-    head + unmask a tick read 2.66, 2.71, 1.98, 2.50 ms at R = 128, 256,
-    384, 512 with 134-146 of 256 slots live and 3.74, 3.21, 3.93, 2.78
-    with 237 (6.47 and 6.40 over all rows: PERF.md section 6, PR 56)."""
-    rows = slots * length
-    return min(rows, -(-3 * rows // (8 * 128)) * 128)
-
-
-def _block_predict_rows(hidden, head, need, temp, key, mask_id):
-    """The head's product and `_block_predict` over the rows of hidden
-    [B, L, D] that `need` [B, L] names, and over no other: their flat
-    indices in row order, R = `_block_pass_rows(B, L)` of them a PASS
-    and `ceil(needed / R)` passes (a loop whose trip count is data: none
-    where nothing is needed), each gathering R rows, multiplying them
-    by head [D, V] into float32 [R, V] and scattering what
-    `_block_predict` makes of them, at the temperature temp [B] of each
-    row's slot, back to [B, L].  A row of a matrix product does not
-    depend on the rows beside it, so a needed row's x0 and confidence
-    are those of a product over all B x L rows; a row not needed keeps
-    x0 0 and confidence 0, which `_block_choose` never reads (`need`
-    holds every masked position of every slot that fixes).  Returns
-    (x0 [B, L] int32, confidence [B, L] float32, passes)."""
-    import jax
-    import jax.numpy as jnp
-
-    B, L, D = hidden.shape
-    N, R = B * L, _block_pass_rows(B, L)
-    P = -(-N // R)                                          # passes at most
-    hidden, need = hidden.reshape(N, D), need.reshape(N)
-    rank = jnp.cumsum(need, dtype=jnp.int32)
-    # a row not needed lands past the last pass; a pass's spare rows
-    # name row N, which is no row
-    rows = jnp.full((P * R,), N, jnp.int32).at[
-        jnp.where(need, rank - 1, P * R)].set(
-        jnp.arange(N, dtype=jnp.int32), mode="drop",
-        unique_indices=True).reshape(P, R)
-
-    def one_pass(p, out):
-        x0, conf = out
-        at = rows[p]
-        with jax.named_scope("head"):
-            logits = jax.lax.dot_general(
-                hidden[jnp.minimum(at, N - 1)], head,
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)         # [R, V]
-        with jax.named_scope("unmask"):
-            # a spare row is greedy: it sends no pass into the sampler
-            mine, sure = _block_predict(
-                logits,
-                jnp.where(at < N, temp[jnp.minimum(at // L, B - 1)], 0),
-                jax.random.fold_in(key, p), mask_id)
-            return (x0.at[at].set(mine, mode="drop"),
-                    conf.at[at].set(sure, mode="drop"))
-
-    passes = -(-rank[-1] // R)
-    x0, conf = jax.lax.fori_loop(
-        0, passes, one_pass,
-        (jnp.zeros((N,), jnp.int32), jnp.zeros((N,), jnp.float32)))
-    return x0.reshape(B, L), conf.reshape(B, L), passes
-
-
-def _block_predict(logits, temp, key, mask_id):
-    """logits [R, V] float32 -> (x0 [R] int32, its probability [R]
-    float32 under the float32 softmax over the vocabulary): a row's
-    argmax where its temp [R] is 0, else a sample at that temperature
-    and its probability under the softmax at that temperature. The
-    mask token's own column is never predicted (-inf). A row's draw
-    comes from `key` over the [R, V] rows it is handed with (a pass of
-    `_block_predict_rows`): the distribution of a draw over all slots'
-    rows at once, not the same draw."""
-    import jax
-    import jax.numpy as jnp
-
-    V = logits.shape[-1]
-    logits = jnp.where(jnp.arange(V) == mask_id, -jnp.inf, logits)
-
-    def greedy(logits):
-        top = jnp.max(logits, axis=-1)
-        x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        z = jnp.sum(jnp.exp(logits - top[..., None]), axis=-1)
-        return x0, 1.0 / z
-
-    def sampled(logits):
-        scaled = logits / jnp.maximum(temp, 1e-6)[:, None]
-        x = jax.random.categorical(key, scaled).astype(jnp.int32)
-        lp = jnp.take_along_axis(jax.nn.log_softmax(scaled, axis=-1),
-                                 x[..., None], axis=-1)[..., 0]
-        g, c = greedy(logits)
-        hot = temp > 0
-        return jnp.where(hot, x, g), jnp.where(hot, jnp.exp(lp), c)
-
-    return jax.lax.cond(jnp.any(temp > 0), sampled, greedy, logits)
-
-
-def _block_choose(conf, masked, share, spec):
-    """Which masked positions a denoising step fixes [B, L] bool, by
-    `spec.remasking`: `sequential` the leftmost `share`;
-    `low_confidence_static` the `share` of largest confidence;
-    `low_confidence_dynamic` every masked position whose confidence
-    passes the threshold if those are at least `share`, else as
-    static. Ties go to the left."""
-    import jax.numpy as jnp
-
-    L = conf.shape[-1]
-    at = jnp.arange(L)
-    score = (-at.astype(jnp.float32) * jnp.ones_like(conf)
-             if spec.remasking == "sequential" else conf)
-    score = jnp.where(masked, score, -jnp.inf)
-    a, b = score[..., :, None], score[..., None, :]     # a: mine, b: other
-    ahead = (b > a) | ((b == a) & (at[None, :] < at[:, None]))
-    pick = masked & (ahead.sum(-1) < share[:, None])
-    if spec.remasking == "low_confidence_dynamic":
-        high = masked & (conf > spec.confidence_threshold)
-        pick = jnp.where((high.sum(-1) >= share)[:, None], high, pick)
-    return pick
-
-
-def _sample(logits, temp, key):
-    """Per-row sampling: greedy where temp == 0, else temperature
-    categorical. Both branches are computed (fixed shape); `where`
-    selects."""
-    import jax
-    import jax.numpy as jnp
-
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits / jnp.maximum(temp, 1e-6)[:, None]
-    sampled = jax.random.categorical(key, scaled).astype(jnp.int32)
-    return jnp.where(temp > 0, sampled, greedy)

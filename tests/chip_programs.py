@@ -37,11 +37,11 @@ import os
 import re
 import sys
 import types
+import typing
 
 import pytest
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 GIB = 2 ** 30
@@ -266,14 +266,30 @@ def is_the_pinned_text(name, which, plain):
 
 # ------------------------------------------------ the benchmark's serving cells
 
+class Cell(typing.NamedTuple):
+    """One of the benchmark's serving cells on the described chip: the
+    engine's own `Programs` for it (serve/llm/programs.py: built,
+    nothing placed), the configuration's file, and as shapes on the
+    chip the model's parameters and the programs' device values by
+    attribute (`state["_cache"]` the pools, `state["_slot_state"]` the
+    state by slot or None, ...)."""
+    name: str
+    programs: typing.Any
+    published: dict
+    one_chip: typing.Any
+    params: typing.Any
+    state: dict
+
+    config = property(lambda self: self.programs.config)
+    model_config = property(lambda self: self.programs.model_config)
+    model = property(lambda self: self.programs.model)
+    pools = property(lambda self: self.state["_cache"])
+
+
 @functools.cache
 def serving_cell(cell):
-    """The engine of one of the benchmark's serving cells as shapes on
-    the described chip: what `LLMEngine`'s program functions read of
-    `self` (`_model`, `model_config`, `config`), and beside it the
-    configuration's file (`published`) and the model's parameters,
-    pool and key."""
     from ray_tpu.serve.llm.engine import EngineConfig
+    from ray_tpu.serve.llm.programs import programs_for
 
     one_chip = SingleDeviceSharding(_described().devices[0])
     if BENCH not in sys.path:
@@ -293,131 +309,30 @@ def serving_cell(cell):
                              compute_dtype="bfloat16",
                              param_dtype="bfloat16")
     model = mc.serving()
-    # a model with a window kind of pool leaves (models/serving.py): the
-    # ring's width and that pool's blocks, as `LLMEngine.__init__` has them
-    ring, leaves, extra = None, (), {}
-    if model.window_kind:
-        window, leaves = model.window_kind(mc)
-        ring = types.SimpleNamespace(ring=min(
-            -(-(window + ec.prefill_buckets[-1]) // ec.kv_block_size),
-            ec.max_blocks_per_slot))
-        extra = {"window_blocks": ec.num_window_blocks}
-    return types.SimpleNamespace(
-        name=cell, _model=model, model_config=mc, config=ec,
-        published=published, _ring=ring, _window_leaves=leaves,
-        _block=model.block.spec(mc) if model.block else None,
-        one_chip=one_chip,
+    programs = programs_for(model, mc, ec)
+    return Cell(
+        cell, programs, published, one_chip,
         params=placed(jax.eval_shape(
             lambda: model.init_params(mc, jax.random.key(0))), one_chip),
-        pools=placed(jax.eval_shape(lambda: model.init_pool(
-            mc, ec.pool_blocks, ec.kv_block_size, **extra)), one_chip),
-        key=placed(jax.eval_shape(lambda: jax.random.key(0)), one_chip))
-
-
-def _by_kind(eng, full, window):
-    """An argument the engine hands a kind for a model with a window
-    kind of pool (`{"full": .., "window": ..}`), else the full kind's."""
-    return full if eng._ring is None else {"full": full,
-                                           "window": window(eng._ring.ring)}
-
-
-def slot_state(eng):
-    """The model's per-slot state as shapes on the chip, in a list (none
-    for a model that keeps none)."""
-    model = eng._model
-    return [placed(jax.eval_shape(lambda: model.init_slot_state(
-        eng.model_config, eng.config.num_slots)), eng.one_chip)] \
-        if model.init_slot_state else []
-
-
-def open_blocks(eng):
-    """The slots' open blocks of a model that generates by blocks, as
-    `LLMEngine.__init__` has them, as shapes on the chip."""
-    B, L = eng.config.num_slots, eng._block.length
-    return placed({
-        "tok": jax.ShapeDtypeStruct((B, L), jnp.int32),
-        "fixed": jax.ShapeDtypeStruct((B, L), jnp.bool_),
-        "step": jax.ShapeDtypeStruct((B,), jnp.int32),
-        "pos0": jax.ShapeDtypeStruct((B,), jnp.int32)}, eng.one_chip)
-
-
-def _compiled_insert(eng):
-    """`LLMEngine._insert_fn` at the cell's largest bucket, the slots'
-    state donated beside the pools where the model keeps one."""
-    from ray_tpu.serve.llm.engine import LLMEngine
-
-    def arg(dtype, *shape):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=eng.one_chip)
-
-    ec = eng.config
-    B, Pb = ec.num_slots, ec.prefill_buckets[-1]
-    state = slot_state(eng)
-    ids = arg(jnp.int32, Pb // ec.kv_block_size)
-    if eng._block is not None:      # a model that generates by blocks
-        return jax.jit(
-            functools.partial(LLMEngine._block_insert_fn, eng),
-            donate_argnums=(1, 2)).lower(
-            eng.params, eng.pools, open_blocks(eng),
-            arg(jnp.int32, ec.max_blocks_per_slot), arg(jnp.int32),
-            arg(jnp.int32, Pb), arg(jnp.int32), ids, arg(jnp.int32),
-            arg(jnp.int32, eng._block.length), arg(jnp.int32)).compile()
-    return jax.jit(
-        functools.partial(LLMEngine._insert_fn, eng),
-        donate_argnums=(1, 2, 3) + ((12,) if state else ())).lower(
-        eng.params, eng.pools, arg(jnp.int32, B), arg(jnp.int32, B),
-        _by_kind(eng, arg(jnp.int32, ec.max_blocks_per_slot),
-                 lambda ring: arg(jnp.int32, ring)), arg(jnp.int32),
-        arg(jnp.int32, Pb), arg(jnp.int32),
-        _by_kind(eng, ids, lambda ring: ids), arg(jnp.int32),
-        arg(jnp.float32), eng.key, *state).compile()
-
-
-def _compiled_tick(eng):
-    """`LLMEngine._tick_fn` of a serving cell, with the model's counters
-    and per-slot state where it has them, donated as `_jit_tick`
-    donates."""
-    from ray_tpu.serve.llm.engine import LLMEngine
-
-    ec, mc, model = eng.config, eng.model_config, eng._model
-
-    def arg(dtype, *shape):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=eng.one_chip)
-
-    B = ec.num_slots
-    if eng._block is not None:      # a model that generates by blocks
-        from ray_tpu.serve.llm.engine import _BLOCK_COUNTERS
-
-        counters = placed(jax.eval_shape(lambda: dict(
-            model.init_counts(mc), **{name: jnp.zeros((), jnp.int32)
-                                      for name in _BLOCK_COUNTERS})),
-            eng.one_chip)
-        return jax.jit(
-            functools.partial(LLMEngine._block_tick_fn, eng),
-            donate_argnums=(1, 3)).lower(
-            eng.params, eng.pools, arg(jnp.int32, B, ec.max_blocks_per_slot),
-            open_blocks(eng), arg(jnp.bool_, B), arg(jnp.float32, B),
-            eng.key, counters).compile()
-    extra = ([placed(jax.eval_shape(lambda: model.init_counts(mc)),
-                     eng.one_chip)] if model.init_counts else []) \
-        + slot_state(eng)
-    return jax.jit(
-        functools.partial(LLMEngine._tick_fn, eng),
-        donate_argnums=(1, 3, 4) + ((9,) if model.init_slot_state else ())
-    ).lower(
-        eng.params, eng.pools,
-        _by_kind(eng, arg(jnp.int32, B, ec.max_blocks_per_slot),
-                 lambda ring: arg(jnp.int32, B, ring)),
-        arg(jnp.int32, B), arg(jnp.int32, B), arg(jnp.bool_, B),
-        arg(jnp.float32, B), eng.key, *extra).compile()
+        state=programs.shapes(one_chip)[0])
 
 
 def cell_program(cell, which):
     """A serving cell's decode tick (`"tick"`) or its largest insert
-    (`"insert"`) as the chip runs it, compiled once a process, and held
-    to its pin."""
-    build = {"tick": _compiled_tick, "insert": _compiled_insert}[which]
-    compiled = program((cell, which), lambda: build(serving_cell(cell)))
-    is_the_pinned_text(cell, which, compiled.plain)
+    (`"insert"`) as the chip runs it — lowered by the engine's own
+    `Programs`, donation and all — compiled once a process, and held to
+    its pin."""
+    eng = serving_cell(cell)
+    bucket = {"tick": None,
+              "insert": eng.programs.config.prefill_buckets[-1]}[which]
+    compiled = program((cell, which), lambda: eng.programs.lower(
+        eng.params, bucket, eng.one_chip).compile())
+    # The pins are of modules lowered through a `functools.partial`, which
+    # jax names `jit__unknown`; the engine's own lowering names a module
+    # after its body (`jit__tick_fn`).  That name apart, letter for letter.
+    is_the_pinned_text(cell, which, re.sub(
+        r"^HloModule jit_\w+,", "HloModule jit__unknown,", compiled.plain,
+        count=1))
     return compiled
 
 
@@ -450,10 +365,9 @@ def delta_rule_insert_holds_no_channel_tensor(cell, dk, dv, temp_gib):
     other readers of its cell's insert."""
     from ray_tpu.ops import kda
 
-    eng = serving_cell(cell)
     C, b = kda.CHUNK, kda._SOLVE_BLOCK
-    assert eng.config.prefill_buckets[-1] % C == 0
-    compiled = cell_program(eng.name, "insert")
+    assert serving_cell(cell).programs.config.prefill_buckets[-1] % C == 0
+    compiled = cell_program(cell, "insert")
     results = results_of(compiled.text)
     shapes = set().union(*(shapes for _, shapes in results))
     assert any(s[-2:] == (C, C) for s in shapes)            # parsed

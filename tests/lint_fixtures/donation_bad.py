@@ -54,12 +54,18 @@ def run_step(state, batch):
     return _train(state, batch)
 
 
-class Engine:
-    def __init__(self, tick_fn):
+class Programs:
+    """The serve engine's pattern (serve/llm/programs.py): the object
+    that wraps a program owns the arrays the program donates."""
+
+    def __init__(self, tick_fn, kv_cache, slots):
+        self._cache, self._slots = kv_cache, slots
         self._jit_tick = jax.jit(tick_fn, donate_argnums=(1, 2))
 
-    def step(self, params, kv_cache, slots, tokens):
-        # donation-use-after: kv_cache was donated to the bound jit
-        # attribute; reading it afterwards reads reused HBM.
-        out = self._jit_tick(params, kv_cache, slots, tokens)
-        return out, kv_cache.shape
+    def tick(self, params, tokens):
+        # donation-use-after: self._cache was donated to the bound jit
+        # attribute and only self._slots is rebound from the result;
+        # reading the pool afterwards reads reused HBM.
+        _, self._slots, out = self._jit_tick(params, self._cache,
+                                             self._slots, tokens)
+        return out, self._cache["k"].shape

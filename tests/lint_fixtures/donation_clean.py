@@ -45,12 +45,16 @@ def both_paths_rebind(state, batch, fast):
     return state.loss
 
 
-class Engine:
-    def __init__(self, tick_fn):
+class Programs:
+    """The serve engine's pattern (serve/llm/programs.py): the object
+    that wraps a program owns the arrays the program donates."""
+
+    def __init__(self, tick_fn, kv_cache, slots):
+        self._cache, self._slots = kv_cache, slots
         self._jit_tick = jax.jit(tick_fn, donate_argnums=(1, 2))
 
-    def step(self, params, kv_cache, slots, tokens):
-        # Donated buffers are rebound from the result tuple.
-        kv_cache, slots = self._jit_tick(params, kv_cache, slots,
-                                         tokens)
-        return kv_cache, slots
+    def tick(self, params, tokens):
+        # Both donated attributes are rebound from the result tuple.
+        self._cache, self._slots, out = self._jit_tick(
+            params, self._cache, self._slots, tokens)
+        return out, self._cache["k"].shape

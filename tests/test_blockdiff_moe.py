@@ -295,7 +295,7 @@ def test_two_slots_at_different_steps_share_one_tick(built):
     hb = engine.submit(Request(prompt=b, max_tokens=12))
     engine.step()
     engine._settle("ctrl")
-    steps = np.asarray(engine._blk["step"])
+    steps = np.asarray(engine._programs._blk["step"])
     assert steps[0] != steps[1], steps
     engine.drain()
     assert engine.stats()["traces"]["tick"] == 1
@@ -340,13 +340,13 @@ def _causal_inside_the_block(mp):
 
 
 def _commit_keeps_the_last_steps_rows(mp):
-    from ray_tpu.serve.llm import engine as E
+    from ray_tpu.serve.llm import programs as E
 
     mp.setattr(E, "_block_writes", lambda active, is_open: active & is_open)
 
 
 def _rule_ignores_confidence(mp):
-    from ray_tpu.serve.llm import engine as E
+    from ray_tpu.serve.llm import programs as E
 
     choose = E._block_choose
     mp.setattr(E, "_block_choose", lambda conf, *a: choose(
@@ -448,18 +448,18 @@ def wide(built):
     """An engine of 256 slots, its tick jitted as a plain function (no
     donation: the cases hand it states of their own), a pool of random
     rows and a table of its own blocks a slot."""
-    from ray_tpu.serve.llm import engine as E
+    from ray_tpu.serve.llm import programs as E
 
     _, params, mc = built
     engine = _engine(params, mc, slots=WIDE)
     assert E._block_pass_rows(WIDE, L) == R
     pools = {k: 0.1 * jax.random.normal(jax.random.key(i), p.shape, p.dtype)
-             for i, (k, p) in enumerate(sorted(engine._cache.items()))}
+             for i, (k, p) in enumerate(sorted(engine._programs._cache.items()))}
     nb = engine.config.max_blocks_per_slot
     tables = jnp.arange(WIDE * nb, dtype=jnp.int32).reshape(WIDE, nb)
     return types.SimpleNamespace(
         engine=engine, params=params, mc=mc, pools=pools, tables=tables,
-        tick=jax.jit(engine._block_tick_fn))
+        tick=jax.jit(engine._programs._block_tick_fn))
 
 
 def _state(n_fixed, seed=0):
@@ -477,14 +477,14 @@ def _state(n_fixed, seed=0):
 
 
 def _counters(engine):
-    return jax.tree.map(jnp.zeros_like, engine._counters)
+    return jax.tree.map(jnp.zeros_like, engine._programs._counters)
 
 
 def _dense_tick(w, blk, active, temp):
     """What the tick leaves, with the head over ALL slots x L rows and
     the softmax over all of its logits: (pools, blk, out, done)."""
     from ray_tpu.models import blockdiff_moe as M
-    from ray_tpu.serve.llm import engine as E
+    from ray_tpu.serve.llm import programs as E
 
     spec = w.engine._block
     tok, fixed, step, pos0 = (blk[k] for k in ("tok", "fixed", "step",
@@ -606,7 +606,7 @@ def test_the_heads_rows_are_counted(wide):
 @pytest.mark.parametrize("rule", RULES)
 def test_the_rule_fixes_what_the_family_says(rule):
     from ray_tpu.models.serving import BlockSpec
-    from ray_tpu.serve.llm import engine as E
+    from ray_tpu.serve.llm import programs as E
 
     spec = BlockSpec(4, 2, rule, 0.5, 511)      # two steps of two
     conf = jnp.asarray([[0.1, 0.4, 0.3, 0.2],

@@ -250,7 +250,7 @@ def test_benchmark_decode_tick_builds_no_repeated_kv(one_chip):
 
 
 def test_benchmark_decode_tick_keeps_one_kv_pool(one_chip, on_tpu):
-    """`LLMEngine._tick_fn` at the `chat-decode` cell's geometry, pools,
+    """`Programs._tick_fn` at the `chat-decode` cell's geometry, pools,
     tokens and positions donated as `_jit_tick` donates them: the stacked
     pools ride in the layer scan's carry and each layer writes its rows
     at its own index, so the donated pool is the only pool.  No

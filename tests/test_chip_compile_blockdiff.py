@@ -31,13 +31,13 @@ def test_blockdiff_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     not fit vector memory whole: `paged.slot_parts`), builds no padded
     view of the pool and holds NO [256, 4, 151936] float32 logits: the
     head runs over the rows still masked, R = 384 of the 1,024 a pass
-    (`engine._block_pass_rows`), so the widest result is [384, 151936];
+    (`programs._block_pass_rows`), so the widest result is [384, 151936];
     the insert at 2048 attends through `flash_prefill` under the
     block-causal mask in every layer but the last, whose attention
     nothing reads; both update the pool in place and arguments +
     temporaries fit HBM."""
     eng = serving_cell("solve-decode-blockdiff-moe")
-    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+    ec, mc, model, published = (eng.config, eng.model_config, eng.model,
                                 eng.published)
     pools = eng.pools
     assert (published["num_hidden_layers"], published["hidden_size"],
@@ -45,7 +45,8 @@ def test_blockdiff_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
             mc.n_experts, mc.n_held_experts, mc.n_kv_heads, mc.block_length,
             ec.num_slots, ec.max_seq_len, ec.pool_blocks) \
         == (12, 2048, 32, 151936, 128, 32, 4, 4, 256, 3072, 20480)
-    assert tuple(eng._block) == (4, 4, "low_confidence_dynamic", 0.9, 151669)
+    assert tuple(eng.programs.block) \
+        == (4, 4, "low_confidence_dynamic", 0.9, 151669)
     assert model.paged_attention(pools) == "kernel"
     assert model.grouped_matmul(mc, ec.num_slots) == "kernel"
     assert pools["k"].shape == (12, 20480, 16, 4 * 128)
@@ -62,7 +63,7 @@ def test_blockdiff_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
         padded = {(B, ec.max_seq_len) + pools["k"].shape[3:]}
         assert not any(padded & shapes for _, shapes in results_of(text))
         assert not kernel_calls("flash_prefill")
-        from ray_tpu.serve.llm.engine import _block_pass_rows
+        from ray_tpu.serve.llm.programs import _block_pass_rows
 
         L, V = mc.block_length, mc.vocab_size
         R = _block_pass_rows(B, L)

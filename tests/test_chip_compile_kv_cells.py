@@ -20,7 +20,7 @@ import jax
 from chip_programs import (     # noqa: F401  (fixtures)
     GIB, V5E_HBM_GIB, cell_program, delta_rule_insert_holds_no_channel_tensor,
     grouped_products_are_the_kernel, on_tpu, one_chip, results_of,
-    serving_cell, slot_state, topo,
+    serving_cell, topo,
 )
 
 
@@ -57,7 +57,7 @@ def test_conv_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     the slots' tails are updated in place, and arguments + temporaries
     fit HBM."""
     eng = serving_cell("compose-decode-conv-moe")
-    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+    ec, mc, model, published = (eng.config, eng.model_config, eng.model,
                                 eng.published)
     pools = eng.pools
     assert (published["num_hidden_layers"], published["hidden_size"],
@@ -68,7 +68,7 @@ def test_conv_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     assert model.grouped_matmul(mc, ec.num_slots) == "kernel"
     kv = pools["kv"]
     assert math.prod(kv.shape[3:]) * kv.dtype.itemsize == 2048
-    state, = slot_state(eng)
+    state = eng.state["_slot_state"]
     B, nb = ec.num_slots, ec.max_blocks_per_slot
     if program == "tick":
         compiled = cell_program(eng.name, "tick")
@@ -113,15 +113,15 @@ def test_window_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     loop's score blocks; float32 scores over the whole history would be
     4.5), and arguments + temporaries fit HBM."""
     eng = serving_cell("mixed-decode-window-moe")
-    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+    ec, mc, model, published = (eng.config, eng.model_config, eng.model,
                                 eng.published)
     pools = eng.pools
     assert (published["num_hidden_layers"], published["hidden_size"],
             published["num_experts"], published["vocab_size"],
             mc.n_window_layers, mc.n_full_layers, mc.n_moe_layers,
             mc.window, mc.n_kv_heads, ec.num_slots, ec.max_seq_len,
-            eng._ring.ring) == (5, 2048, 128, 200192, 4, 1, 4, 2048, 4, 64,
-                                18432, 256)
+            eng.programs.ring_blocks) \
+        == (5, 2048, 128, 200192, 4, 1, 4, 2048, 4, 64, 18432, 256)
     assert model.paged_attention(pools) == "kernel"
     assert model.grouped_matmul(mc, ec.num_slots) == "kernel"
     assert pools["k"].shape == (1, ec.pool_blocks, 16, 4 * 128)
@@ -135,7 +135,7 @@ def test_window_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
             >= 3 * mc.n_moe_layers + mc.n_layers
         row = pools["k"].shape[3:]
         padded = {(B, n * ec.kv_block_size) + row
-                  for n in (ec.max_blocks_per_slot, eng._ring.ring)}
+                  for n in (ec.max_blocks_per_slot, eng.programs.ring_blocks)}
         assert not any(padded & shapes for _, shapes in results_of(text))
     else:
         compiled = cell_program(eng.name, "insert")
@@ -171,7 +171,7 @@ def test_gdn_hybrid_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     padded lane: the stack's argument is exactly that many); pools and
     state are updated in place, and arguments + temporaries fit HBM."""
     eng = serving_cell("reason-decode-gdn-hybrid")
-    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+    ec, mc, model, published = (eng.config, eng.model_config, eng.model,
                                 eng.published)
     pools = eng.pools
     assert (published["num_hidden_layers"], published["hidden_size"],
@@ -182,7 +182,7 @@ def test_gdn_hybrid_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     assert model.paged_attention(pools) == "kernel"
     pool = pools["k"].shape
     assert pool == (2, ec.pool_blocks, 16, 30, 128) == pools["v"].shape
-    state, = slot_state(eng)
+    state = eng.state["_slot_state"]
     stack = state["S"].shape
     assert stack == (6, 128, 15, 96, 384)
     from ray_tpu.ops import kda
@@ -257,19 +257,19 @@ def test_sambay_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     from ray_tpu.ops import selective_scan
 
     eng = serving_cell("think-decode-ssm-yoco")
-    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+    ec, mc, model, published = (eng.config, eng.model_config, eng.model,
                                 eng.published)
     pools = eng.pools
     assert (published["num_hidden_layers"], published["hidden_size"],
             published["vocab_size"], published["reduced"], mc.n_self_pairs,
             mc.n_cross_pairs, mc.n_ssm_layers, mc.window, mc.kv_width,
-            ec.num_slots, ec.max_seq_len, eng._ring.ring) \
+            ec.num_slots, ec.max_seq_len, eng.programs.ring_blocks) \
         == (32, 2560, 200064, {}, 8, 7, 9, 512, 1280, 96, 12288, 96)
     assert model.paged_attention(pools) == "kernel"
     assert pools["k"].shape == (1, ec.pool_blocks, 16, 1280)
     assert pools["v_w"].shape == (8, ec.num_window_blocks, 16, 1280)
     assert ec.pool_blocks * 16 >= 600_000
-    state, = slot_state(eng)
+    state = eng.state["_slot_state"]
     assert state["h"].shape == (9, 96, 16, 40, 128)     # no padded lane
     assert selective_scan.engages(state["h"])
     compiled = cell_program(eng.name, program)
@@ -279,7 +279,7 @@ def test_sambay_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
         assert text.count("ssm_step") >= 2 and "ssm_scan" not in text
         row = pools["k"].shape[3:]
         padded = {(ec.num_slots, n * ec.kv_block_size) + row
-                  for n in (ec.max_blocks_per_slot, eng._ring.ring)}
+                  for n in (ec.max_blocks_per_slot, eng.programs.ring_blocks)}
         assert not any(padded & shapes for _, shapes in results_of(text))
     else:
         assert text.count("ssm_scan") >= 2 and "ssm_step" not in text
@@ -316,7 +316,7 @@ def test_ssd_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     from ray_tpu.ops import grouped_matmul, paged_attention, ssd
 
     eng = serving_cell("swarm-decode-ssd-moe")
-    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+    ec, mc, model, published = (eng.config, eng.model_config, eng.model,
                                 eng.published)
     pools = eng.pools
     assert (published["num_hidden_layers"], published["hidden_size"],
@@ -333,7 +333,7 @@ def test_ssd_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     assert ec.pool_blocks * 16 >= 900_000
     assert paged_attention.slot_parts(ec.num_slots,
                                       ec.max_blocks_per_slot) == 2
-    state, = slot_state(eng)
+    state = eng.state["_slot_state"]
     assert state["S"].shape == (6, 384, 32, 128, 128)   # no padded lane
     assert state["tail"].shape == (6, 384, 3, 6144)
     assert ssd.engages(state["S"])

@@ -9,7 +9,6 @@ case that reads one of these cells' programs is in this file, the other
 three cells are in `test_chip_compile_kv_cells.py`.
 """
 
-import functools
 import math
 
 import pytest
@@ -20,7 +19,7 @@ import jax.numpy as jnp
 from chip_programs import (     # noqa: F401  (fixtures)
     GIB, V5E_HBM_GIB, cell_program, delta_rule_insert_holds_no_channel_tensor,
     grouped_products_are_the_kernel, on_tpu, one_chip, results_of,
-    serving_cell, slot_state, topo,
+    serving_cell, topo,
 )
 
 
@@ -60,7 +59,7 @@ def test_latent_ticks_read_the_pool_through_the_block_table(
     eng = serving_cell(cell)
     ec, latent = eng.config, eng.pools["latent"]
     assert latent.shape == pool
-    assert eng._model.paged_attention(eng.pools) == "kernel"
+    assert eng.model.paged_attention(eng.pools) == "kernel"
     compiled = cell_program(eng.name, "tick")
     text = compiled.text
     assert text.count("paged_attention") >= pool[0]
@@ -98,14 +97,12 @@ def test_export_rows_compile_and_fit_beside_the_insert(one_chip, cell, rows):
     the largest export row still alive beside it (a spill's row is
     pending while the admission's insert runs): all compile for v5e and
     fit its HBM."""
-    from ray_tpu.serve.llm.engine import LLMEngine
-
     eng = serving_cell(cell)
     ec = eng.config
     assert ec.export_rows == rows and len(rows) <= 6
     block_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
                       for x in eng.pools.values()) // ec.pool_blocks
-    export = jax.jit(functools.partial(LLMEngine._export_fn, eng))
+    export = jax.jit(eng.programs._export_fn)
     for n in rows:
         m = export.lower(eng.pools, jax.ShapeDtypeStruct(
             (n,), jnp.int32, sharding=one_chip)).compile().memory_analysis()
@@ -133,7 +130,7 @@ def test_latent_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     assert (published["num_hidden_layers"], published["hidden_size"],
             published["n_routed_experts"], published["vocab_size"]) \
         == (8, 2048, 128, 128256)
-    assert eng._model.grouped_matmul(mc, eng.config.num_slots) == "kernel"
+    assert eng.model.grouped_matmul(mc, eng.config.num_slots) == "kernel"
 
     compiled = cell_program(eng.name, program)
     grouped_products_are_the_kernel(compiled.text,
@@ -161,14 +158,14 @@ def test_hybrid_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     These readings sized the configuration's depth and the cell's
     slots and pool."""
     eng = serving_cell("agent-decode-hybrid")
-    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+    ec, mc, model, published = (eng.config, eng.model_config, eng.model,
                                 eng.published)
     pools = eng.pools
     assert (published["num_hidden_layers"], published["hidden_size"],
             published["num_experts"], published["vocab_size"],
             mc.n_experts, mc.n_kda_layers, mc.n_mla_layers) \
         == (8, 2304, 64, 40960, 256, 6, 2)
-    state, = slot_state(eng)
+    state = eng.state["_slot_state"]
     assert model.grouped_matmul(mc, ec.num_slots) == "kernel"
     compiled = cell_program(eng.name, program)
     grouped_products_are_the_kernel(compiled.text, mc.n_moe_layers)
@@ -195,7 +192,7 @@ def test_hybrid_tick_steps_live_states_where_they_lie(one_chip, on_tpu):
     from ray_tpu.ops import kda
 
     eng = serving_cell("agent-decode-hybrid")
-    state, = slot_state(eng)
+    state = eng.state["_slot_state"]
     stack = state["S"].shape
     assert stack == (6, 128, 32, 128, 128)
     assert kda.engages(*stack[-2:], state["S"].dtype)
@@ -277,7 +274,7 @@ def test_shortcut_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     from ray_tpu.models.moe import compact_rows
 
     eng = serving_cell("longform-decode-zero-moe")
-    ec, mc, model, published = (eng.config, eng.model_config, eng._model,
+    ec, mc, model, published = (eng.config, eng.model_config, eng.model,
                                 eng.published)
     assert (published["num_layers"], published["hidden_size"],
             published["n_routed_experts"], published["zero_expert_num"],

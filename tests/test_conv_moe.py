@@ -651,10 +651,7 @@ def test_programs_carry_the_scopes_the_readers_read(model, engine, program,
     _, mc, _, params = model
     e = engine
     if program == "tick":
-        lowered = e._jit_tick.lower(
-            e.params, e._cache, e._tables.copy(), e._tok, e._pos,
-            e._active.copy(), e._temp.copy(), e._key, e._counters,
-            e._slot_state)
+        lowered = e._programs.lower(e.params)
         want = ["conv/in_proj", "conv/mix", "conv/out_proj", "attn/qk_norm",
                 "attn/kv_write", "attn/kv_gather", "moe/router",
                 "moe/experts", "mlp", "lm_head", "sample"]

@@ -143,6 +143,14 @@ class TestPassFixtures:
         assert result.findings == [], \
             [f.render() for f in result.findings]
 
+    def test_donation_pass_reads_the_engines_programs_pattern(self):
+        """`serve/llm/programs.py`: an attribute of the object that
+        wraps the program, donated and not rebound, then read."""
+        found = [f for f in _lint("donation_bad.py",
+                                  "donation-use-after").findings
+                 if "'self._cache'" in f.message]
+        assert len(found) == 1 and "in tick()" in found[0].message
+
     def test_at_least_five_passes_registered(self):
         assert len(registered_passes()) >= 5
 

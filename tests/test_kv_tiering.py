@@ -205,9 +205,9 @@ def _random_pool(eng, seed=0):
     import numpy as np
 
     rng = np.random.RandomState(seed)
-    eng._cache = {name: jnp.asarray(rng.standard_normal(x.shape), x.dtype)
-                  for name, x in eng._cache.items()}
-    return {name: np.asarray(x) for name, x in eng._cache.items()}
+    eng._programs._cache = {name: jnp.asarray(rng.standard_normal(x.shape), x.dtype)
+                  for name, x in eng._programs._cache.items()}
+    return {name: np.asarray(x) for name, x in eng._programs._cache.items()}
 
 
 class _Spans:
@@ -329,7 +329,7 @@ def test_insert_over_evicted_blocks_lands_what_they_held(monkeypatch, kind):
 
     eng = _engine_of(kind, **_TIGHT_GEO)
     _fill_tight(eng)
-    before = {name: np.asarray(x) for name, x in eng._cache.items()}
+    before = {name: np.asarray(x) for name, x in eng._programs._cache.items()}
     held = {e.tokens: e.block for e in eng._prefix._entries.values()}
     spans = _Spans(monkeypatch)
     eng.submit(Request(prompt=_prompt16(9), max_tokens=4))
@@ -341,7 +341,7 @@ def test_insert_over_evicted_blocks_lands_what_they_held(monkeypatch, kind):
         "spill", "insert_dispatch", "tick_dispatch", "spill_land",
         "tick_wait")] == ["spill", "insert_dispatch", "tick_dispatch",
                           "spill_land"]
-    after = {name: np.asarray(x) for name, x in eng._cache.items()}
+    after = {name: np.asarray(x) for name, x in eng._programs._cache.items()}
     # 3 blocks for 16 + 4 tokens, one free: both links of prompt 0 go
     victims = [t for t in held if t not in
                {e.tokens for e in eng._prefix._entries.values()}]

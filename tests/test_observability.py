@@ -418,17 +418,13 @@ def test_engine_recompile_detector_fires():
     h = engine.submit(Request(prompt=[1, 2, 3], max_tokens=2))
     engine.drain()
     assert h.finish_reason == "length"
-    assert engine._jit_insert.traces == 1
+    assert engine._programs.traces()["insert"] == 1
     with pytest.warns(RecompileWarning, match="llm_engine_insert"):
         # a 16-token suffix into two fresh blocks of 8: no such bucket
-        engine._cache, engine._tok, engine._pos, engine._key = \
-            engine._jit_insert(
-                engine.params, engine._cache, engine._tok, engine._pos,
-                engine._tables[0].copy(), np.int32(0),
-                np.zeros((16,), np.int32), np.int32(3),
-                np.asarray([0, 1], np.int32), np.int32(0),
-                np.float32(0.0), engine._key)
-    assert engine._jit_insert.traces == 2
+        engine._programs.insert(
+            engine.params, 0, engine._tables[0].copy(), 0,
+            np.zeros((16,), np.int32), 3, np.asarray([0, 1], np.int32), 0.0)
+    assert engine._programs.traces()["insert"] == 2
 
 
 def test_serve_telemetry_end_to_end(ray_start_regular):

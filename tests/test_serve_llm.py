@@ -974,3 +974,96 @@ def test_dispatch_and_admission_spans_say_both_kinds():
     assert span.said == {"blocks_full": 9, "blocks_window": 6}
     assert set(_shared_engine()._live_rows([])) == {"rows"}
     assert "live_bytes" not in _shared_engine().stats()["kv"]
+
+
+# ---------------------------------------------------------------------------
+# The engine's device half is ONE object (serve/llm/programs.py): the
+# scheduler holds no array of the device's and donates nothing itself
+# ---------------------------------------------------------------------------
+
+def _tiny_of(form):
+    """(model config, fresh parameters, engine options) of a tiny model
+    of each form the engine's programs take."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = dict(dtype=jnp.float32, param_dtype=jnp.float32)
+    if form == "token":
+        from ray_tpu.models.llama import LlamaConfig as Config, init_params
+
+        config, options = Config.tiny(), {}
+    elif form == "slot state":
+        from ray_tpu.models.conv_moe import ConvMoEConfig, init_params
+
+        config, options = ConvMoEConfig.tiny(**f32), dict(prefix_cache=False)
+    elif form == "window kind":
+        from ray_tpu.models.window_moe import WindowMoEConfig, init_params
+
+        config, options = WindowMoEConfig.tiny(**f32), dict(
+            prefix_cache=False, num_window_blocks=14)
+    else:
+        from ray_tpu.models.blockdiff_moe import (BlockDiffMoEConfig,
+                                                  init_params)
+
+        config, options = BlockDiffMoEConfig(
+            vocab_size=512, dim=64, n_layers=2, n_heads=4, n_kv_heads=2,
+            head_dim=16, expert_hidden_dim=32, n_experts=8, top_k=2,
+            max_seq_len=128, mask_token_id=511, prefill_key_block=16,
+            **f32), dict(prefix_cache=False)
+    return config, init_params(config, jax.random.key(0)), options
+
+
+def _device_values_of(engine):
+    """The attributes of `engine`, `params` and `_draft` apart, that are
+    or hold a `jax.Array`."""
+    import collections
+
+    import jax
+
+    def holds(v):
+        if isinstance(v, (collections.deque, set, frozenset)):
+            v = list(v)
+        return any(isinstance(x, jax.Array) for x in jax.tree.leaves(v))
+
+    return sorted(name for name, v in vars(engine).items()
+                  if name not in ("params", "_draft") and holds(v))
+
+
+@pytest.mark.parametrize("form", ["token", "slot state", "window kind",
+                                  "block"])
+def test_scheduler_holds_no_device_value_and_donates_nothing(form):
+    """Whatever the form of the model's programs: after `LLMEngine(...)`
+    and after a request served, no attribute of the engine but `params`
+    and `_draft` is or holds a `jax.Array` (they are the `Programs`
+    object's, serve/llm/programs.py), `engine.py` donates nothing, and
+    the weights are the engine's `params` alone: `engine.params = None`
+    and the caller's reference gone leave nothing that keeps them (the
+    benchmark's driver frees its control's weights so)."""
+    import gc
+    import inspect
+    import weakref
+
+    import jax
+
+    from ray_tpu.serve.llm import engine as E
+    from ray_tpu.serve.llm import programs as P
+
+    config, params, options = _tiny_of(form)
+    engine = E.LLMEngine(params, config, E.EngineConfig(
+        num_slots=2, max_seq_len=64, prefill_buckets=(8, 16),
+        kv_block_size=4, **options))
+    assert isinstance(engine._programs, P.BlockPrograms) == (form == "block")
+    assert type(engine._programs) in (P.Programs, P.BlockPrograms)
+    assert _device_values_of(engine) == []
+    handle = engine.submit(E.Request(prompt=[3, 1, 4, 1, 5, 9, 2, 6, 5],
+                                     max_tokens=6))
+    engine.drain()
+    assert handle.finish_reason == "length" and len(handle.tokens) == 6
+    assert _device_values_of(engine) == []
+    source = inspect.getsource(E)
+    assert "donate_argnums" not in source and "tracked_jit(" not in source
+    weights = [weakref.ref(x) for x in jax.tree.leaves(params)]
+    engine.params = None
+    del params
+    gc.collect()
+    assert weights and all(ref() is None for ref in weights)

@@ -142,6 +142,39 @@ def engine_traces(tmp_path_factory):
     return out
 
 
+def test_engine_source_writes_the_spans_the_benchmark_looks_for():
+    """Two readers of the benchmark open `serve/llm/engine.py` and look
+    for a span's name in its SOURCE, double-quoted, to tell a program
+    that writes the span from a window that holds none
+    (`benchmarks/tick_gap.py::program_writes`,
+    `benchmarks/layer_metrics/spill_land_ms.py`): a span whose literal
+    leaves that file turns `spill_land_ms` and `engine_idle_share` to
+    `null` in silence. Every span name those files read is there, as
+    they look for it."""
+    import sys
+
+    from ray_tpu.serve.llm import engine
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    for d in (bench, os.path.join(bench, "layer_metrics")):
+        if d not in sys.path:
+            sys.path.insert(0, d)
+    import program_spans as PS
+    import spill_land_ms
+    import tick_gap as TG
+
+    with open(engine.__file__) as f:
+        source = f.read()
+    names = {PS.STEP, TG.READY, TG.IDLE, spill_land_ms.SPAN} | {
+        n for n in TG.QUIET if n.startswith(TG.P)}
+    assert len(names) >= 11 and all(n.startswith("llm_engine.")
+                                    for n in names)
+    assert not sorted(n for n in names if f'"{n}"' not in source)
+    assert spill_land_ms._program_lands()
+    assert all(TG.program_writes(n) for n in names)
+
+
 @pytest.mark.parametrize("case", ["roomy", "evicting"])
 def test_engine_step_spans_nest_and_cover(engine_traces, case):
     events, evicted = engine_traces[case]
@@ -377,7 +410,7 @@ def test_sampled_fence_stands_in_the_trace(tmp_path, who):
                                         prefill_buckets=(8,), kv_block_size=8))
         engine.submit(Request(prompt=[1, 2, 3], max_tokens=2))
         engine.drain()                  # compiles: never a sample
-        f = engine._jit_tick
+        f = engine._programs._jit_tick
         f._sample_every, f.calls = 2, 0
         walls = []
         record = f.record_wall
@@ -692,9 +725,10 @@ def test_engine_tick_carries_the_sample_scope():
     e = LLMEngine(init_params(c, jax.random.key(0)), c, EngineConfig(
         num_slots=2, max_seq_len=32, prefill_buckets=(8,),
         kv_layout="paged", kv_block_size=8))
-    lowered = e._jit_tick.lower(
-        e.params, e._cache, e._tables.copy(), e._tok, e._pos,
-        e._active.copy(), e._temp.copy(), e._key)
+    p = e._programs
+    lowered = p._jit_tick.lower(
+        e.params, p._cache, e._tables.copy(), p._tok, p._pos,
+        e._active.copy(), e._temp.copy(), p._key)
     assert "module @jit_llm_engine_tick " in lowered.as_text()
     names = _op_names(lowered.compile().as_text())
     for scope in ("sample", "layers", "kv_gather", "attn"):

@@ -443,11 +443,12 @@ def test_engine_serves_through_both_kinds_past_a_ring_wrap(model, engine):
 
 
 class _RecordingTick:
-    """Stands in for `engine._jit_tick`: lays each dispatch's live slots
-    and window table aside, then calls through."""
+    """Stands in for the engine's `Programs._jit_tick`: lays each
+    dispatch's live slots and window table aside, then calls through."""
 
     def __init__(self, engine):
-        self.engine, self.tick, self.seen = engine, engine._jit_tick, []
+        self.engine, self.seen = engine, []
+        self.tick = engine._programs._jit_tick
 
     def __call__(self, params, pools, tables, tok, pos, active, *rest):
         e = self.engine
@@ -471,7 +472,7 @@ def test_ring_covers_the_row_a_tick_behind_one_in_flight_writes(
     from ray_tpu.serve.llm.engine import Request
 
     rec = _RecordingTick(engine)
-    monkeypatch.setattr(engine, "_jit_tick", rec)
+    monkeypatch.setattr(engine._programs, "_jit_tick", rec)
     ring, W = engine._ring, C["sliding_window"]
     prompts = [_tokens(n, seed=n) for n in (5, 40, 21)]
     handles = [engine.submit(Request(prompt=p, max_tokens=40,
