@@ -27,8 +27,11 @@ Qwen3-MoE lineage, generating by DIFFUSION OVER BLOCKS.
 - **One kind of pool**, a row a token holding its 4 KV heads side by
   side (`[L, NB, bs, kvH hd]`, `ops/paged_attention.py` "Few KV heads"),
   read by the paged kernel at Q = L queries a sequence, all of them at
-  the block's last position; the slots go in as many parts as keep a
-  call's queries in vector memory (`paged.slot_parts`).
+  the block's last position.  At 4 queries of 8 heads a KV head the
+  call walks its KV groups (`paged.walks_groups`): a slot's queries are
+  L x H rows of head_dim lanes (`paged.query_bytes`), all slots'
+  queries and outputs fit one call's vector memory, and a layer is one
+  call; `paged.slot_parts` still cuts the slots where they would not.
 - **The experts this chip holds** are `expert_rank` of `expert_shards`
   of the router's `n_experts` columns (`models/moe.py::dropless_moe`'s
   `share`): routing, the k chosen and their renormalised weights are
@@ -235,14 +238,16 @@ class _Paged:
         self.seen = _block_end(qpos, L)
         self.plans = None
         if _paged_attention(pools) == "kernel":
-            row = L * c.n_heads * pools["k"].shape[-1] \
-                * pools["k"].dtype.itemsize
-            n = B // paged.slot_parts(*tables.shape, query_bytes=row)
+            shape = (L, c.n_heads, c.n_kv_heads)
+            self.chunk = paged.chunk_blocks(*shape)
+            n = B // paged.slot_parts(
+                *tables.shape, self.chunk, query_bytes=paged.query_bytes(
+                    *shape, c.head_dim, pools["k"].dtype.itemsize))
             self.cuts = [slice(i, i + n) for i in range(0, B, n)]
             with jax.named_scope("attn"), jax.named_scope("paged"):
                 self.plans = [paged.plan(
                     tables[s], self.seen[s],
-                    None if active is None else active[s], bs)
+                    None if active is None else active[s], bs, self.chunk)
                     for s in self.cuts]
 
     def attend(self, c, l, q, k, v):
@@ -256,7 +261,7 @@ class _Paged:
         with jax.named_scope("paged"):
             if self.plans is not None:
                 return jnp.concatenate([paged.paged_attention(
-                    q[s], k_pool, v_pool, l, plan)
+                    q[s], k_pool, v_pool, l, plan, chunk=self.chunk)
                     for s, plan in zip(self.cuts, self.plans)])
             rows = self.tables.shape[1] * k_pool.shape[2]
             dense = [pool[l, self.tables].reshape(

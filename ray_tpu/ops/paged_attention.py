@@ -67,14 +67,36 @@ TPU compiler, and an insert's scatter of whole blocks into it is then
 compiled as a re-tiling copy of the WHOLE pool in and another out
 (deviceless v5e compile, PERF.md section 6, PR 38).  A model with fewer
 than 8 KV heads keeps a token's KV heads SIDE BY SIDE in one row instead
-(`[L, NB, bs, kvH * D]`, 512 lanes at 4 heads of 128), and
-`paged_attention` reads such a pool as the latent pool is read: one
-"KV head" whose row every query head scores whole, a query laid into
-the lanes of its own group of a zero row (so `q_row . row` is `q . k` of
-that group), the value product over the whole row, and each head's
-output taken from its group's lanes.  As many multiplications as the
-group mask costs the `[bs, kvH, D]` form, no mask, and a block is a
-`[bs, kvH * D]` matrix as it lies.
+(`[L, NB, bs, kvH * D]`, 512 lanes at 4 heads of 128), a block is a
+`[bs, kvH * D]` matrix as it lies, and `paged_attention` reads such a
+pool in one of two forms, chosen by shapes alone (`walks_groups`: the
+query rows a KV group, `Q * H / kvH`):
+
+- Few rows a group (under 32: one query a sequence at 16, 8 or 4 heads
+  a KV head): as the latent pool is read.  One "KV head" whose row
+  every query head scores whole, a query laid into the lanes of its own
+  group of a zero row (so `q_row . row` is `q . k` of that group), the
+  value product over the whole row, and each head's output taken from
+  its group's lanes.  As many multiplications as the group mask costs
+  the `[bs, kvH, D]` form, no mask.
+- 32 rows a group or more (a block of 4 queries a sequence at 8 heads a
+  KV head): the call WALKS ITS GROUPS.  The query goes in D lanes wide,
+  a group's rows together (`[B, kvH, Q * H / kvH, D]`, one relayout in
+  XLA on the way in and one on the way out), and a trip multiplies each
+  group's rows against lanes `[g D, (g + 1) D)` of the same chunk
+  buffers, whole lane tiles that cost nothing to slice: the scores that
+  a zero row's other lanes added nothing to, a running max, sum and
+  accumulator a group, the position mask computed once a trip for all
+  of them.  The copies, the work list, `plan` and the double buffer are
+  the other form's.  What it saves is around the trips, not in them:
+  laid 512 lanes wide, 4 queries x 32 heads were 128 KiB a sequence of
+  query and as much of output, three quarters of it zeros written and
+  read back by XLA and carried through vector memory, and 256
+  sequences took four calls (`slot_parts`); 128 lanes wide they take
+  one.  A trip itself is as long in both forms (a key or value tile
+  passes through the matrix unit once either way, and a trip's chain of
+  products and reductions is latency), so this form takes its keys 64
+  blocks a trip (`chunk_blocks`; PERF.md section 6, PR 58).
 
 Many KV heads that are no whole tile.  A pool `[L, NB, bs, 30, 128]`
 (as many K/V heads as query heads, 30 of them) is laid by the TPU
@@ -242,16 +264,61 @@ def slot_parts(slots: int, table_width: int,
     part is planned and attended on its own; the pool is one.
 
     `query_bytes`: what ONE sequence's queries take as the kernel is
-    handed them (`Q * H` rows as wide as a pool row); a call holds them
-    and as many bytes of output whole in vector memory, so several
-    queries a sequence over a side-by-side pool are cut too (4 queries
-    x 32 heads x 512 lanes are 128 KiB a sequence: 256 slots go in 4
-    parts)."""
+    handed them (`query_bytes`); a call holds them and as many bytes of
+    output whole in vector memory, so many query rows a sequence, each
+    as wide as a side-by-side pool's row, are cut too (4 queries x 32
+    heads x 512 lanes would be 128 KiB a sequence and 256 slots 4
+    parts; walking the KV groups they are 32 KiB and the slots one)."""
     chunks = -(-table_width // min(chunk, table_width))
     a_slot = 4 * (table_width + 2 * chunks + 2)
     return next(p for p in range(1, slots + 1)
                 if slots % p == 0 and slots // p * a_slot <= _SMEM_BUDGET
                 and 2 * (slots // p) * query_bytes <= _VMEM_QUERY_BUDGET)
+
+
+# Query rows a KV group (Q * H / kvH) from which a call over a
+# side-by-side pool walks its groups (PERF.md section 6, PR 58: read on
+# the chip at 32 and at 16 rows a group).
+_GROUP_ROWS = 32
+
+
+def walks_groups(n_q: int, n_heads: int, kv_heads: int) -> bool:
+    """Which of its two forms `paged_attention` takes over a pool that
+    holds `kv_heads` KV heads side by side in a row, for `n_q` queries a
+    sequence of `n_heads` heads: True, a KV group's `n_q * n_heads /
+    kv_heads` query rows against that group's own lanes; False, every
+    row laid as wide as the pool's row against whole rows (the module
+    docstring, "Few KV heads").  Shapes alone: the rows of a group are
+    whole packed bf16 tiles (16) and enough of them that the narrower
+    products pay for a loop over the groups."""
+    rows = n_q * n_heads // kv_heads
+    return kv_heads > 1 and rows % 16 == 0 and rows >= _GROUP_ROWS
+
+
+# Blocks a chunk where a call walks its KV groups: a trip's chain of
+# two products and two reductions a group is latency, 0.8 us of a trip
+# whatever its width, so twice the keys a trip are fewer trips for the
+# same copies (PERF.md section 6, PR 58: read on the chip at 16, 32, 64
+# and 128 blocks).
+_GROUPS_CHUNK_BLOCKS = 64
+
+
+def chunk_blocks(n_q: int, n_heads: int, kv_heads: int) -> int:
+    """The blocks a chunk that a caller over a side-by-side pool plans
+    with and calls with (`plan`, `slot_parts`, `paged_attention`)."""
+    return _GROUPS_CHUNK_BLOCKS if walks_groups(n_q, n_heads, kv_heads) \
+        else CHUNK_BLOCKS
+
+
+def query_bytes(n_q: int, n_heads: int, kv_heads: int, head_dim: int,
+                itemsize: int = 2) -> int:
+    """What ONE sequence's queries take as the kernel over a
+    side-by-side pool is handed them (`slot_parts`' `query_bytes`):
+    `n_q * n_heads` rows, `head_dim` lanes wide where the call walks
+    its groups, as wide as the pool's row where it does not."""
+    lanes = head_dim if walks_groups(n_q, n_heads, kv_heads) \
+        else kv_heads * head_dim
+    return n_q * n_heads * lanes * itemsize
 
 
 def _block_copy(pool, layer, phys, buf, slot, t, rows, sem):
@@ -263,18 +330,22 @@ def _block_copy(pool, layer, phys, buf, slot, t, rows, sem):
 
 def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
             tab_ref, q_ref, *refs, nb, bs, kvh, n_heads, n_q, chunk,
-            scale, window, heads_major):
+            scale, window, heads_major, groups=1):
     # refs: the pools in HBM, the output, a chunk buffer a pool, the
     # semaphores.  Two pools (K, V) or one whose rows hold both (K ‖ V,
     # or the latent row).  The output keeps the value product's first
     # `d` lanes: all of a row, or the latent's whole lane tiles.
+    # `groups` > 1: a row of the pools holds that many KV heads of `d`
+    # lanes side by side, q_ref and o_ref are [B, groups, rows, d], and
+    # a trip multiplies each group's rows against its own lanes of the
+    # chunk buffers (`n_heads` is then the heads of ONE group).
     n_pools = (len(refs) - 2) // 2
     o_ref, sems = refs[n_pools], refs[-1]
     pools = tuple(zip(refs[:n_pools], refs[n_pools + 1:-1]))
     kbuf, vbuf = pools[0][1], pools[-1][1]
     layer, n_items = layer_ref[0], n_ref[0]
     rows = bs * kvh                         # of a block in the flat view
-    qh, d = o_ref.shape[1:]
+    qh, d = o_ref.shape[-2:]
     width = chunk * rows
 
     # A dead slot's row, and a chunk's rows past its last live block:
@@ -313,9 +384,10 @@ def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
     def _():
         copies(0, 0, True)
 
-    # row = query index * H + head; column = token in chunk * kvH + group,
-    # or, where a block lies head by head, block * rows + group * bs +
-    # token in block
+    # row = query index * H + head (H: the heads of ONE group where the
+    # call walks its groups); column = token in chunk * kvH + group, or,
+    # where a block lies head by head, block * rows + group * bs + token
+    # in block
     row = lax.broadcasted_iota(jnp.int32, (qh, width), 0)
     col = lax.broadcasted_iota(jnp.int32, (qh, width), 1)
     if heads_major:
@@ -328,8 +400,13 @@ def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
         (row % n_heads) // (n_heads // kvh) == group
     row1 = lax.broadcasted_iota(jnp.int32, (qh, 1), 0)
 
+    def of_group(b, g):             # where q_ref and o_ref hold group g
+        return (b, g) if groups > 1 else b
+
+    def lanes_of(buf, slot, g):     # the group's lanes of a chunk buffer
+        return buf[slot, :, pl.ds(g * d, d)]
+
     def step(i, carry):
-        m, l, acc = carry
         slot = i % 2
 
         @pl.when(i + 1 < n_items)
@@ -340,9 +417,8 @@ def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
         b, j, _ = item_of(i)
         fresh = j == (0 if window is None
                       else first_key(b) // (chunk * bs))
-        m = jnp.where(fresh, _MASK, m)
-        l = jnp.where(fresh, 0.0, l)
-        acc = jnp.where(fresh, 0.0, acc)
+        carry = [(jnp.where(fresh, _MASK, m), jnp.where(fresh, 0.0, l),
+                  jnp.where(fresh, 0.0, acc)) for m, l, acc in carry]
 
         qpos = jnp.full((qh, 1), qpos_ref[b * n_q], jnp.int32)
         for t in range(1, n_q):
@@ -354,38 +430,57 @@ def _kernel(layer_ref, n_ref, seq_ref, chunk_ref, len_ref, qpos_ref,
         if own_group is not None:
             seen = own_group & seen
 
-        s = lax.dot_general(
-            q_ref[b], kbuf[slot], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = jnp.where(seen, s, _MASK)
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        l = alpha * l + p.sum(axis=-1, keepdims=True)
-        values = vbuf[slot] if d == vbuf.shape[-1] else vbuf[slot, :, :d]
-        acc = alpha * acc + jnp.dot(
-            p.astype(vbuf.dtype), values,
-            preferred_element_type=jnp.float32)
+        def fold(g, m, l, acc):     # the chunk into group g's softmax
+            rows_q = q_ref[of_group(b, g)]
+            keys = lanes_of(kbuf, slot, g) if groups > 1 else kbuf[slot]
+            s = lax.dot_general(
+                rows_q, keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(seen, s, _MASK)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = alpha * l + p.sum(axis=-1, keepdims=True)
+            if groups > 1:
+                values = lanes_of(vbuf, slot, g)
+            else:
+                values = vbuf[slot] if d == vbuf.shape[-1] \
+                    else vbuf[slot, :, :d]
+            acc = alpha * acc + jnp.dot(
+                p.astype(vbuf.dtype), values,
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        carry = [fold(g, *state) for g, state in enumerate(carry)]
 
         @pl.when((j + 1) * (chunk * bs) >= len_ref[b])
         def _():
-            o_ref[b] = (acc / l).astype(o_ref.dtype)
+            for g, (_, l, acc) in enumerate(carry):
+                o_ref[of_group(b, g)] = (acc / l).astype(o_ref.dtype)
 
-        return m_new, l, acc
+        return tuple(carry)
 
-    lax.fori_loop(0, n_items, step, (
+    # the online softmax's running max, sum and accumulator, a KV group
+    lax.fori_loop(0, n_items, step, ((
         jnp.full((qh, 1), _MASK, jnp.float32),
-        jnp.zeros((qh, 1), jnp.float32), jnp.zeros((qh, d), jnp.float32)))
+        jnp.zeros((qh, 1), jnp.float32),
+        jnp.zeros((qh, d), jnp.float32)),) * groups)
 
 
 def _call(q, pools, layer, scalars, *, kvh, n_heads, n_q, scale,
-          out_width, chunk, window=None, heads_major=False):
+          out_width, chunk, window=None, heads_major=False, groups=1):
     """The kernel over `pools` (flat: [L, NB, bs * kvH, W] each, a
     block's rows token by token or, `heads_major`, head by head) for q
     [B, Q * H, W], laid as the first pool's rows are: [B, Q * H,
     out_width], the first lanes of the value product over the last
-    pool's rows."""
-    B, qh, W = q.shape
+    pool's rows.
+
+    `groups` > 1 (then `kvh` is 1 and `n_heads` the heads of one
+    group): a pool row holds `groups` KV heads of `out_width` lanes
+    side by side and q is [B, groups, Q * n_heads, out_width], a
+    group's rows together, query by query: [B, groups, Q * n_heads,
+    out_width], each group's rows against its own lanes."""
+    B, W = q.shape[0], pools[0].shape[-1]
     rows = pools[0].shape[2]
     nb = scalars[-1].shape[0] // B
     chunk = min(chunk, nb)
@@ -398,7 +493,8 @@ def _call(q, pools, layer, scalars, *, kvh, n_heads, n_q, scale,
     buf = pltpu.VMEM((2, chunk * rows, W), pools[0].dtype)
     kernel = functools.partial(
         _kernel, nb=nb, bs=rows // kvh, kvh=kvh, n_heads=n_heads, n_q=n_q,
-        chunk=chunk, scale=scale, window=window, heads_major=heads_major)
+        chunk=chunk, scale=scale, window=window, heads_major=heads_major,
+        groups=groups)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -409,7 +505,7 @@ def _call(q, pools, layer, scalars, *, kvh, n_heads, n_q, scale,
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             scratch_shapes=[buf] * len(pools)
             + [pltpu.SemaphoreType.DMA((2, len(pools)))]),
-        out_shape=jax.ShapeDtypeStruct((B, qh, out_width), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape[:-1] + (out_width,), q.dtype),
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -447,6 +543,9 @@ def paged_attention(q: jax.Array, k_pool: jax.Array,
     if k_pool.ndim == 4:
         W = k_pool.shape[-1]
         kvh = W // D
+        if walks_groups(Q, H, kvh):
+            return _over_groups(q, k_pool, v_pool, layer, scalars,
+                                chunk=chunk, window=window, scale=scale)
         # head h of group g into lanes [g D, (g + 1) D) of a zero row
         place = jnp.eye(kvh, dtype=q.dtype)
         q_row = jnp.einsum("bqgrd,gk->bqgrkd",
@@ -474,6 +573,28 @@ def paged_attention(q: jax.Array, k_pool: jax.Array,
         scalars, kvh=kvh, n_heads=H, n_q=Q, scale=scale,
         out_width=W, chunk=chunk, window=window, heads_major=heads_major)
     return out.reshape(B, Q, H, W)[..., W - D:]
+
+
+# Jitted as `paged_latent_attention` below is, and for the same reason:
+# the layers of an unrolled stack lower the kernel once.
+@functools.partial(jax.jit, static_argnames=("chunk", "window", "scale"))
+def _over_groups(q, k_pool, v_pool, layer, scalars, *, chunk, window,
+                 scale):
+    """`paged_attention` over side-by-side pools [L, NB, bs, kvH * D]
+    where `walks_groups`: a group's rows together, query by query, D
+    lanes wide ([B, kvH, Q * H / kvH, D]: one relayout of the queries
+    and one of the result, in XLA, and no lane of either is a zero)."""
+    B, Q, H, D = q.shape
+    kvh = k_pool.shape[-1] // D
+
+    def regrouped(x, outer, inner):
+        return jnp.swapaxes(x.reshape(B, outer, inner, H // kvh, D), 1, 2)
+
+    out = _call(
+        regrouped(q, Q, kvh).reshape(B, kvh, Q * H // kvh, D),
+        [k_pool, v_pool], layer, scalars, kvh=1, n_heads=H // kvh, n_q=Q,
+        scale=scale, out_width=D, chunk=chunk, window=window, groups=kvh)
+    return regrouped(out, kvh, Q).reshape(B, Q, H, D)
 
 
 # Jitted so that the call sites of an unrolled layer loop (one a latent

@@ -202,7 +202,13 @@ def program(key, build):
 # PR 56's: the head and the softmax over the rows still masked, 384 a
 # pass (`engine._block_predict_rows`); the other twenty-two stood, that
 # cell's insert among them: only a model with `ServingFns.block` runs the
-# block tick.
+# block tick.  `solve-decode-blockdiff-moe`'s tick is PR 58's: the paged
+# kernel over its side-by-side pool walks the 4 KV groups, one call a
+# layer in chunks of 64 blocks (`ops.paged_attention.walks_groups`: 32
+# query rows a group; `chunk_blocks`); the
+# other twenty-two stood, the three other cells with such a pool among
+# them (`swarm` 16 rows a group, `mixed` 8, `think` 4: below the rule,
+# they keep the whole-row form).
 PROGRAM_TEXT_SHA256 = {
     ("chat-decode", "tick"):
         "48a91f54a548addd9d951f33258125cd66601f6eb5de512b9f23800388b2ae93",
@@ -241,7 +247,7 @@ PROGRAM_TEXT_SHA256 = {
     ("swarm-decode-ssd-moe", "insert"):
         "a229313f2e5a7f3c7a4e24e98f969829f08105dd9157cc2449df3fb04b3f79b7",
     ("solve-decode-blockdiff-moe", "tick"):
-        "6f0a3fc2d3c46bc92d3053eb3bb10a3f709a1e880c8dda7cf30a4c51bd15d0a1",
+        "a787579becbeb61a966f2d3cb31927c6a496e18ccc6a6ec0efafa2d58b62483d",
     ("solve-decode-blockdiff-moe", "insert"):
         "07bb25384c7a15ab6eaed0fa8e18c697a73584806187d659fc03c0656337a63e",
     ("two small layers", "train step, scope names apart"):

@@ -627,10 +627,63 @@ def test_the_rule_fixes_what_the_family_says(rule):
     assert E._block_share(jnp.arange(4), odd).tolist() == [2, 1, 1, 1]
 
 
+@pytest.mark.parametrize("heads,kv_heads", [(32, 4), (8, 4)])
+def test_denoise_agrees_on_both_paged_paths(monkeypatch, heads, kv_heads):
+    """A tick's forward in bf16 at heads of 128 over a pool block of 16
+    (shapes at which the paged kernel engages), through the gather and
+    through the kernel under the interpreter: at 4 x 32 heads over 4 KV
+    heads the call walks its KV groups (32 query rows a group, SDAR's
+    own), at 8 heads it lays every row as wide as the pool's; a dead
+    slot between live ones, a slot in its first block and one further
+    in.  The same rows in the pool but for what the first layer's
+    attention rounds; hidden rows a bf16 rounding or two of an O(1)
+    value apart."""
+    from ray_tpu.models import blockdiff_moe as M
+    from ray_tpu.ops import attention, paged_attention as paged
+
+    mc = M.BlockDiffMoEConfig(
+        vocab_size=512, dim=64, n_layers=2, n_heads=heads,
+        n_kv_heads=kv_heads, head_dim=128, expert_hidden_dim=32,
+        n_experts=4, top_k=2, max_seq_len=128, mask_token_id=511)
+    assert paged.walks_groups(L, heads, kv_heads) == (heads == 32)
+    params = M.init_params(mc, jax.random.key(2), std=0.1)
+    pools = jax.tree.map(
+        lambda x: jax.random.normal(jax.random.key(3), x.shape, x.dtype),
+        M.init_paged_pool(mc, 24, 16))
+    tables = jnp.asarray(np.random.RandomState(4).permutation(24)
+                         .reshape(3, 8), jnp.int32)
+    tok = jnp.asarray(np.random.RandomState(5).randint(0, 511, (3, L)),
+                      jnp.int32)
+    pos0 = jnp.asarray([0, 40, 100], jnp.int32)
+    active = jnp.asarray([True, False, True])
+
+    def run():
+        return jax.jit(lambda *a: M.denoise_paged(*a, mc, active))(
+            params, pools, tables, tok, pos0)
+
+    assert M._paged_attention(pools) == "gather"
+    want, want_pools, _ = run()
+    monkeypatch.setattr(attention, "FORCE_PALLAS_INTERPRET", True)
+    assert M._paged_attention(pools) == "kernel"
+    got, got_pools, _ = run()
+    for name in ("k", "v"):     # layer 0's rows come before any attention
+        a, b = (np.asarray(x[name], np.float32)
+                for x in (got_pools, want_pools))
+        np.testing.assert_array_equal(a[0], b[0])
+        np.testing.assert_allclose(a[1], b[1], atol=2 ** -4, rtol=0)
+    live = np.asarray(active)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[live], np.asarray(want, np.float32)[live],
+        atol=2 ** -5, rtol=0)
+
+
 def test_queries_of_a_block_cut_the_slots_to_fit_vector_memory():
     from ray_tpu.ops import paged_attention as paged
 
     assert paged.slot_parts(256, 192) == 1
-    # 4 queries x 32 heads x 512 lanes of bf16 a sequence
+    # 4 queries x 32 heads x 512 lanes of bf16 a sequence, as every row
+    # was laid until PR 58; 128 lanes wide, walked a KV group: one call
     assert paged.slot_parts(256, 192, query_bytes=4 * 32 * 512 * 2) == 4
+    assert paged.slot_parts(
+        256, 192, query_bytes=paged.query_bytes(4, 32, 4, 128)) == 1
     assert paged.slot_parts(384, 768) == 2      # scalar memory, as before

@@ -26,11 +26,16 @@ def test_blockdiff_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     experts at SDAR-30B-A3B's published widths, a head 151,936 wide, 256
     slots x 3072 rows, 20,480 blocks of 16): they compile for v5e,
     `paged_attention` and `grouped_matmul` answer "kernel"; the tick is
-    ONE program that holds the paged kernel at 4 queries a sequence,
-    the slots in 4 parts a layer (128 query rows x 512 lanes a slot do
-    not fit vector memory whole: `paged.slot_parts`), builds no padded
-    view of the pool and holds NO [256, 4, 151936] float32 logits: the
-    head runs over the rows still masked, R = 384 of the 1,024 a pass
+    ONE program that holds the paged kernel at 4 queries a sequence
+    ONCE a layer, all 256 slots in one call: the call walks its 4 KV
+    groups (`paged.walks_groups`) in chunks of 64 blocks
+    (`paged.chunk_blocks`), so a slot's queries are 4 x 32 rows of 128
+    lanes (`paged.query_bytes`: 32 KiB, 16 MiB of query and output a
+    call, which `paged.slot_parts` leaves whole) and no query or output
+    row is laid 512 lanes wide ([256, 128, 512] or a part's [64, 128,
+    512]); it builds no padded view of the pool and holds NO [256, 4,
+    151936] float32 logits: the head runs over the rows still masked,
+    R = 384 of the 1,024 a pass
     (`programs._block_pass_rows`), so the widest result is [384, 151936];
     the insert at 2048 attends through `flash_prefill` under the
     block-causal mask in every layer but the last, whose attention
@@ -59,13 +64,26 @@ def test_blockdiff_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
                 if "custom-call(" in line and name in line]
 
     if program == "tick":
-        assert len(kernel_calls("paged_attention")) == 4 * mc.n_layers
+        from ray_tpu.ops import paged_attention as paged
+
+        L, H, kvh, hd = mc.block_length, mc.n_heads, mc.n_kv_heads, \
+            mc.head_dim
+        assert paged.walks_groups(L, H, kvh)
+        a_slot = paged.query_bytes(L, H, kvh, hd)
+        assert a_slot == L * H * hd * 2
+        assert paged.slot_parts(B, ec.max_seq_len // ec.kv_block_size,
+                                paged.chunk_blocks(L, H, kvh),
+                                query_bytes=a_slot) == 1
+        assert len(kernel_calls("paged_attention")) == mc.n_layers
+        found = set().union(*(shapes for _, shapes in results_of(text)))
+        assert (B, kvh, L * H // kvh, hd) in found      # parsed
         padded = {(B, ec.max_seq_len) + pools["k"].shape[3:]}
-        assert not any(padded & shapes for _, shapes in results_of(text))
+        lane_placed = {(n, L * H, kvh * hd) for n in (B, B // 4)}
+        assert not (padded | lane_placed) & found
         assert not kernel_calls("flash_prefill")
         from ray_tpu.serve.llm.programs import _block_pass_rows
 
-        L, V = mc.block_length, mc.vocab_size
+        V = mc.vocab_size
         R = _block_pass_rows(B, L)
         assert R == 384
         wide = {shape for _, shapes in results_of(text) for shape in shapes

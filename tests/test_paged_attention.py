@@ -515,24 +515,85 @@ def test_window_plan_is_bounded_by_the_window_not_the_length():
         pa.plan(tables, jnp.zeros((3, 2), jnp.int32), None, 16, window=2048)
 
 
-@pytest.mark.parametrize("window", [None, W_KEYS])
-@pytest.mark.parametrize("heads,kv_heads", [(8, 4), (8, 2)])
-def test_kv_heads_side_by_side_in_a_row(heads, kv_heads, window):
+# (queries a sequence, heads, KV heads): 2 to 32 query rows a KV group,
+# both sides of `pa.walks_groups`; the window form takes one query
+SIDE_BY_SIDE = [(n_q, heads, kv_heads, window)
+                for n_q, heads, kv_heads in (
+                    (1, 8, 4), (1, 8, 2), (1, 16, 2), (1, 32, 2),
+                    (1, 64, 2), (1, 128, 4), (4, 4, 4), (4, 8, 4),
+                    (4, 16, 4), (4, 32, 4), (4, 16, 2))
+                for window in (None, W_KEYS) if n_q == 1 or window is None]
+
+
+@pytest.mark.parametrize("n_q,heads,kv_heads,window", SIDE_BY_SIDE)
+def test_kv_heads_side_by_side_in_a_row(monkeypatch, n_q, heads, kv_heads,
+                                        window):
     """Pools of four axes, a token's KV heads side by side in one row
     [L, NB, bs, kvH * D] (few KV heads: `ops/paged_attention.py`), give
-    what the same rows give as [L, NB, bs, kvH, D]: both forms of the
-    walk, against the masked gather."""
-    q, kp, vp, tables, qpos, lengths = _window_case(heads, kv_heads, 13)
-    if window is None:                  # a table by position: no wrap
-        lengths = np.minimum(lengths, RING * BS)
-        qpos = np.maximum(lengths - 1, 0).astype(np.int32)[:, None]
+    what the same rows give as [L, NB, bs, kvH, D], in BOTH forms of
+    the call (every query row as wide as the pool's row; a KV group's
+    rows against that group's lanes), whichever of them the rule takes
+    at this geometry: both forms of the walk, against the masked
+    gather, and one against the other."""
+    if n_q == 1:
+        q, kp, vp, tables, qpos, lengths = _window_case(heads, kv_heads, 13)
+        chunk = 2
+        if window is None:              # a table by position: no wrap
+            lengths = np.minimum(lengths, RING * BS)
+            qpos = np.maximum(lengths - 1, 0).astype(np.int32)[:, None]
+        want = _window_reference(q, kp, vp, tables, qpos, window)
+    else:                               # query j sees the keys up to its own
+        q, kp, vp, tables, qpos, lengths = _case(heads, kv_heads, n_q, 13)
+        chunk = CHUNK
+        want = _reference(q, kp, vp, tables, qpos)
     active = lengths > 0
     flat = [x.reshape(x.shape[:3] + (-1,)) for x in (kp, vp)]
     scalars = pa.plan(jnp.asarray(tables), jnp.asarray(qpos),
-                      jnp.asarray(active), BS, 2, window=window)
-    got = np.asarray(pa.paged_attention(
-        q, *flat, jnp.int32(LAYER), scalars, chunk=2, window=window),
-        np.float32)
-    want = _window_reference(q, kp, vp, tables, qpos, window)
-    assert np.all(got[~active] == 0.0)
-    np.testing.assert_allclose(got[active], want[active], atol=ATOL, rtol=0)
+                      jnp.asarray(active), BS, chunk, window=window)
+    rule = pa.walks_groups(n_q, heads, kv_heads)
+    assert rule == (n_q * heads // kv_heads >= 32)
+    got = {}
+    for grouped in (False, True):
+        monkeypatch.setattr(pa, "walks_groups", lambda *_: grouped)
+        got[grouped] = np.asarray(pa.paged_attention(
+            q, *flat, jnp.int32(LAYER), scalars, chunk=chunk,
+            window=window), np.float32)
+        assert np.all(got[grouped][~active] == 0.0)
+        np.testing.assert_allclose(got[grouped][active], want[active],
+                                   atol=ATOL, rtol=0)
+    # the same float32 sums over the same bf16 operands, but for the
+    # zeros of the other groups' lanes: a rounding of the result apart
+    np.testing.assert_allclose(got[True], got[False], atol=2 ** -8, rtol=0)
+
+
+@pytest.mark.parametrize("cell,shape,grouped", [
+    ("solve-decode-blockdiff-moe", (4, 32, 4), True),       # 32 rows a group
+    ("swarm-decode-ssd-moe", (1, 32, 2), False),            # 16
+    ("mixed-decode-window-moe", (1, 32, 4), False),         # 8
+    ("think-decode-ssm-yoco", (1, 40, 10), False),          # 4 (K/V pairs)
+])
+def test_which_cells_walk_their_kv_groups(cell, shape, grouped):
+    """`walks_groups` on the (queries a sequence, heads, KV heads) of
+    the four benchmark cells whose pools hold KV heads side by side: a
+    function of shapes, static a program; the blocks a chunk go with
+    it."""
+    assert pa.walks_groups(*shape) is grouped
+    assert pa.chunk_blocks(*shape) == (64 if grouped else pa.CHUNK_BLOCKS)
+    # and what a sequence's queries take follows it
+    n_q, heads, kv_heads = shape
+    lanes = 128 if grouped else kv_heads * 128
+    assert pa.query_bytes(n_q, heads, kv_heads, 128) \
+        == n_q * heads * lanes * 2
+
+
+@pytest.mark.parametrize("form,parts", [("groups", 1), ("whole_rows", 4)])
+def test_slots_a_call_by_the_queries_it_holds(form, parts):
+    """`solve`'s 256 slots x 192 blocks of table at 4 queries of 32 heads
+    over 4 KV heads of 128: walked by groups a sequence's queries are 32
+    KiB (16 MiB of query + output a call: one part); laid as wide as the
+    pool's row they would be 128 KiB (4 parts).  Host arithmetic."""
+    a_slot = {"groups": pa.query_bytes(4, 32, 4, 128),
+              "whole_rows": 4 * 32 * 4 * 128 * 2}[form]
+    assert a_slot == {"groups": 32, "whole_rows": 128}[form] * 2 ** 10
+    assert pa.slot_parts(256, 192, query_bytes=a_slot) == parts
+    assert 2 * 256 // parts * a_slot <= pa._VMEM_QUERY_BUDGET
