@@ -527,10 +527,10 @@ def feed_forward(c: SambaYConfig, p, x):
         return x + (jax.nn.silu(g) * h) @ p["w2"].astype(dt)
 
 
-def ssm_mixer(c: SambaYConfig, j, p, u, rec, st):
+def ssm_mixer(c, j, p, u, rec, st, normed=lambda p, dbc: dbc):
     """u [B, S, D], normed -> (the Mamba mixer's output [B, S, D], its
-    scan output before the gate [B, S, d_inner], the states), layer j of
-    `st` going through `rec`."""
+    scan output before the gate, the states), layer j of `st` through
+    `rec`; `normed(p, [dt | B | C])`: models/jamba.py's three norms."""
     dt, C, N, R = c.dtype, c.d_inner, c.d_state, c.dt_rank
     with jax.named_scope("ssm"):
         with jax.named_scope("proj"):
@@ -539,8 +539,8 @@ def ssm_mixer(c: SambaYConfig, j, p, u, rec, st):
             y, st = rec.conv(st, j, xs, p["conv_w"])
             xc = jax.nn.silu(y + p["conv_b"].astype(dt))
         with jax.named_scope("proj"):
-            dbc = jnp.dot(xc, p["w_x"].astype(dt),
-                          preferred_element_type=_F32)
+            dbc = normed(p, jnp.dot(xc, p["w_x"].astype(dt),
+                                    preferred_element_type=_F32))
             delta = jax.nn.softplus(jnp.dot(
                 dbc[..., :R].astype(dt), p["w_dt"].astype(dt),
                 preferred_element_type=_F32) + p["b_dt"].astype(_F32))

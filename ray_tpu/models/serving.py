@@ -62,8 +62,9 @@ longer than a bucket goes into its slot chunk by chunk.
     and convolution tail of `models/kimi_linear.py` and
     `models/gdn_hybrid.py`, the convolution tail of
     `models/conv_moe.py`, the selective scan's state and tail of
-    `models/sambay.py`, the Mamba-2 state and tail of
-    `models/nemotron_h.py`: each but sambay's beside a `full` pool).
+    `models/sambay.py` and `models/jamba.py`, the Mamba-2 state and
+    tail of `models/nemotron_h.py`: each but sambay's beside a `full`
+    pool).
     A model that has it takes and returns it beside the pool:
         prefill(..., n_real, state) -> (hidden, rows, state), `state`
         {leaf: [L', ...]} ONE slot's rows, as they stood after the

@@ -208,7 +208,11 @@ def program(key, build):
 # query rows a group; `chunk_blocks`); the
 # other twenty-two stood, the three other cells with such a pool among
 # them (`swarm` 16 rows a group, `mixed` 8, `think` 4: below the rule,
-# they keep the whole-row form).
+# they keep the whole-row form).  `tutor-decode-mamba-mqa`'s two are PR
+# 59's, which brought the cell; the other twenty-three stood,
+# `think-decode-ssm-yoco`'s two among them (`sambay.ssm_mixer` took a
+# hook between `W_x` and `W_dt` whose default hands `[dt | B | C]` back
+# as it came).
 PROGRAM_TEXT_SHA256 = {
     ("chat-decode", "tick"):
         "48a91f54a548addd9d951f33258125cd66601f6eb5de512b9f23800388b2ae93",
@@ -250,6 +254,10 @@ PROGRAM_TEXT_SHA256 = {
         "a787579becbeb61a966f2d3cb31927c6a496e18ccc6a6ec0efafa2d58b62483d",
     ("solve-decode-blockdiff-moe", "insert"):
         "07bb25384c7a15ab6eaed0fa8e18c697a73584806187d659fc03c0656337a63e",
+    ("tutor-decode-mamba-mqa", "tick"):
+        "f446c2e5baa871657dd9952d2770041663dc40d8680dc37bb42b8adb894632cb",
+    ("tutor-decode-mamba-mqa", "insert"):
+        "dcc4557205e707ed9f10351ec6575c38c9ea35831069ff7cb4526cc7ae558237",
     ("two small layers", "train step, scope names apart"):
         "1d700902d1c682aaec9e4b41afc84286eb99ba0c4758f7046d1be38b1848714c",
     ("two small layers", "train step, its kernels"):

@@ -370,3 +370,70 @@ def test_ssd_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     assert m.alias_size_in_bytes >= kept                # all in place
     assert m.temp_size_in_bytes < (0.5 if program == "tick" else 2.0) * GIB
     assert compiled.hbm_gib < V5E_HBM_GIB - 0.5
+
+
+@pytest.mark.parametrize("program", ["tick", "insert"])
+def test_mamba_mqa_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
+    """The engine's decode tick (512 slots x 8192) and its largest
+    insert (2048) at the geometry of the benchmark's
+    `tutor-decode-mamba-mqa` cell (26 Mamba-1 layers whose 16 x 5120
+    float32 state the engine keeps by slot beside 2 attention layers of
+    ONE K/V head in a `full` pool, at AI21-Jamba2-3B's published widths
+    and WHOLE depth; the slots, row length, buckets, block and pool its
+    files state): they compile for v5e; `paged_attention` answers
+    "kernel" over pools `[2, NB, bs, 128]` in ONE call a layer (the
+    table of a slot is narrow enough at the cell's block that all 512
+    slots' scalars fit scalar memory) and the scan's state engages its
+    kernels; 28 layers lower as three loops of Mamba layers and two
+    attention layers (three call sites of the scan step, two of the
+    paged kernel; the insert as many of the scan kernel and two
+    `flash_prefill`s); the 4.4 GB state stack `[26, 512, 16, 40, 128]`,
+    the tails and both pools are donated and updated in place (a second
+    stack would show as +4.1 GiB of temporaries) and no padded
+    `[slots, max_seq_len, 128]` view of K or V is built; the parameters
+    count to the published 3.03 B; arguments + temporaries fit HBM:
+    12.1 GiB, 77 % of the chip."""
+    from ray_tpu.ops import paged_attention, selective_scan
+
+    eng = serving_cell("tutor-decode-mamba-mqa")
+    ec, mc, model, published = (eng.config, eng.model_config, eng.model,
+                                eng.published)
+    pools = eng.pools
+    bs = ec.kv_block_size
+    assert (published["num_hidden_layers"], published["hidden_size"],
+            published["vocab_size"], published["reduced"], mc.mamba_runs,
+            mc.n_ssm_layers, mc.n_attn_layers, mc.n_heads, mc.n_kv_heads,
+            ec.num_slots, ec.max_seq_len, ec.prefill_buckets[-1]) \
+        == (28, 2560, 65536, {}, [7, 13, 6], 26, 2, 20, 1, 512, 8192, 2048)
+    assert model.paged_attention(pools) == "kernel"
+    assert pools["k"].shape == pools["v"].shape \
+        == (2, ec.pool_blocks, bs, 128)
+    assert ec.pool_blocks * bs >= 1_400_000
+    assert paged_attention.slot_parts(ec.num_slots,
+                                      ec.max_blocks_per_slot) == 1
+    assert not paged_attention.walks_groups(1, mc.n_heads, mc.n_kv_heads)
+    state = eng.state["_slot_state"]
+    assert state["h"].shape == (26, 512, 16, 40, 128)   # no padded lane
+    assert state["tail"].shape == (26, 512, 3, 5120)
+    assert selective_scan.engages(state["h"])
+    assert sum(math.prod(x.shape) for x in jax.tree.leaves(eng.params)) \
+        == published["constants"]["total_params"] == 3_029_337_472
+    compiled = cell_program(eng.name, program)
+    text = compiled.text
+    if program == "tick":
+        assert compiled.plain.count("paged_attention") >= 2
+        assert text.count("ssm_step") >= 3 and "ssm_scan" not in text
+        padded = (ec.num_slots, ec.max_seq_len) + pools["k"].shape[3:]
+        assert not any(padded in shapes for _, shapes in results_of(text))
+    else:
+        assert text.count("ssm_scan") >= 3 and "ssm_step" not in text
+        assert "paged_attention" not in compiled.plain
+        assert compiled.plain.count("flash_prefill") >= 2
+    m = compiled.memory
+    kept = sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in list(pools.values()) + list(state.values()))
+    print(program, "GiB", compiled.hbm_gib, "temp",
+          m.temp_size_in_bytes / GIB, "args", m.argument_size_in_bytes / GIB)
+    assert m.alias_size_in_bytes >= kept                # all in place
+    assert m.temp_size_in_bytes < (0.8 if program == "tick" else 0.5) * GIB
+    assert 0.25 * V5E_HBM_GIB < compiled.hbm_gib < V5E_HBM_GIB - 0.5

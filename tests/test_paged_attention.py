@@ -571,12 +571,13 @@ def test_kv_heads_side_by_side_in_a_row(monkeypatch, n_q, heads, kv_heads,
     ("swarm-decode-ssd-moe", (1, 32, 2), False),            # 16
     ("mixed-decode-window-moe", (1, 32, 4), False),         # 8
     ("think-decode-ssm-yoco", (1, 40, 10), False),          # 4 (K/V pairs)
+    ("tutor-decode-mamba-mqa", (1, 20, 1), False),          # ONE K/V head
 ])
 def test_which_cells_walk_their_kv_groups(cell, shape, grouped):
     """`walks_groups` on the (queries a sequence, heads, KV heads) of
-    the four benchmark cells whose pools hold KV heads side by side: a
+    the five benchmark cells whose pools hold KV heads side by side: a
     function of shapes, static a program; the blocks a chunk go with
-    it."""
+    it (one K/V head has no groups to walk, whatever its query rows)."""
     assert pa.walks_groups(*shape) is grouped
     assert pa.chunk_blocks(*shape) == (64 if grouped else pa.CHUNK_BLOCKS)
     # and what a sequence's queries take follows it
