@@ -167,15 +167,16 @@ def lm_head_weight(params: Dict[str, Any], config: ConvMoEConfig):
 def init_slot_state(config: ConvMoEConfig, num_slots: int
                     ) -> Dict[str, jax.Array]:
     """A row a slot a convolution layer (models/serving.py): the last
-    `conv_size - 1` rows of `z`, zeros."""
+    `conv_size - 1` rows of `z` side by side in the lanes of one
+    (`ops/short_conv.py`), zeros."""
     c = config
-    return {"tail": jnp.zeros((c.n_conv_layers, num_slots, c.conv_size - 1,
-                               c.dim), c.dtype)}
+    return {"tail": jnp.zeros((c.n_conv_layers, num_slots,
+                               (c.conv_size - 1) * c.dim), c.dtype)}
 
 
 class _Sequences:
     """Whole (padded) sequences, each from the tail handed in
-    [Lc, B, K-1, D]; the tails after the first `n_real` tokens are kept
+    [Lc, B, (K-1) D]; the tails after the first `n_real` tokens are kept
     for the caller."""
 
     def __init__(self, state, n_real):
@@ -183,8 +184,9 @@ class _Sequences:
         self.tails: List[jax.Array] = []
 
     def conv(self, j, z, w):
-        y, tail = short_conv.short_conv(z, w, self.inp[j], self.n_real)
-        self.tails.append(tail.astype(self.inp.dtype))
+        y, tail = short_conv.short_conv(
+            z, w, short_conv.rows(self.inp[j], w), self.n_real)
+        self.tails.append(short_conv.flat(tail).astype(self.inp.dtype))
         return y
 
     def state(self):
@@ -193,18 +195,15 @@ class _Sequences:
 
 class _Step:
     """One token a slot: each layer's rows of the whole tree shifted at
-    a static layer index, in place; a dead slot keeps its."""
+    a static layer index where they lie (`short_conv.step_in_place`);
+    a dead slot keeps its."""
 
     def __init__(self, state, active):
         self.tails, self.active = state["tail"], active
 
     def conv(self, j, z, w):
-        old = self.tails[j]
-        y, tail = short_conv.short_conv_step(z[:, 0], w, old)
-        tail = tail.astype(old.dtype)
-        if self.active is not None:
-            tail = jnp.where(self.active[:, None, None], tail, old)
-        self.tails = self.tails.at[j].set(tail)
+        y, self.tails = short_conv.step_in_place(
+            self.tails, j, z[:, 0], w, self.active)
         return y[:, None]
 
     def state(self):
@@ -404,7 +403,7 @@ def init_paged_pool(config: ConvMoEConfig, num_blocks: int,
 def prefill_paged(params, tokens, start, hist, config: ConvMoEConfig,
                   n_real, state):
     """Suffix prefill of ONE sequence: tokens [1, Pb] at start.., the
-    first `n_real` real; `state` {tail: [Lc, K-1, D]} the slot's tails
+    first `n_real` real; `state` {tail: [Lc, (K-1) D]} the slot's tails
     after its first `start` tokens.  Padding goes through no expert and
     leaves the tails where the last real token put them."""
     Pb = tokens.shape[1]

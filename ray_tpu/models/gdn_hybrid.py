@@ -167,14 +167,16 @@ def init_slot_state(config: GdnHybridConfig, num_slots: int
     """A row a slot a delta-rule layer (models/serving.py): the state,
     `ops.kda.pack`ed (`heads_a_row` heads side by side: at 30 heads of
     96 x 192, `[15, 96, 384]`, 2,211,840 B a slot a layer in float32
-    with no padded lane), and the convolution's tail, zeros."""
+    with no padded lane), and the convolution's tail, its
+    `conv_size - 1` rows of q ‖ k ‖ v side by side in the lanes of one
+    (`ops/short_conv.py`), zeros."""
     c = config
     p = c.heads_a_row
     return {
         "S": jnp.zeros((c.n_gdn_layers, num_slots, c.gdn_heads // p,
                         c.gdn_key_dim, p * c.gdn_value_dim), c.state_dtype),
-        "conv": jnp.zeros((c.n_gdn_layers, num_slots, c.conv_size - 1,
-                           2 * c.key_width + c.value_width), c.dtype)}
+        "conv": jnp.zeros((c.n_gdn_layers, num_slots, (c.conv_size - 1)
+                           * (2 * c.key_width + c.value_width)), c.dtype)}
 
 
 class _Layers:

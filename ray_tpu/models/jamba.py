@@ -20,7 +20,8 @@ elsewhere.  No positions anywhere: the recurrence carries order.
   -exp(A_log)`, `+ D xc`, the `silu(z)` gate, `W_out`.  What it keeps
   for a sequence is of a fixed size whatever the length: `h` `[d_state,
   d_inner / 128, 128]` in `state_dtype` and the last `d_conv - 1` rows
-  of `xs`, held by the engine by SLOT (`init_slot_state`;
+  of `xs` (side by side in the lanes of one row: 30,720 B a slot a
+  layer), held by the engine by SLOT (`init_slot_state`;
   models/serving.py): 26 layers x 327,680 B = 8.5 MB of float32 state a
   slot at the published sizes, against 1,024 B of K and V a token.
 - **Attention** (`models/nemotron_h.py::attention_mixer` and its three

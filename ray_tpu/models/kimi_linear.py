@@ -183,13 +183,15 @@ def init_params(config: KimiLinearConfig, key: jax.Array,
 def init_slot_state(config: KimiLinearConfig, num_slots: int
                     ) -> Dict[str, jax.Array]:
     """A row a slot a KDA layer (models/serving.py): the delta-rule
-    state and the convolution's tail, zeros."""
+    state and the convolution's tail, its `conv_size - 1` rows of
+    q ‖ k ‖ v side by side in the lanes of one (`ops/short_conv.py`),
+    zeros."""
     c = config
     return {
         "S": jnp.zeros((c.n_kda_layers, num_slots, c.kda_heads,
                         c.kda_head_dim, c.kda_head_dim), c.state_dtype),
-        "conv": jnp.zeros((c.n_kda_layers, num_slots, c.conv_size - 1,
-                           3 * c.kda_width), c.dtype)}
+        "conv": jnp.zeros((c.n_kda_layers, num_slots,
+                           (c.conv_size - 1) * 3 * c.kda_width), c.dtype)}
 
 
 class _Sequences:
@@ -205,9 +207,10 @@ class _Sequences:
         self.tails: List[jax.Array] = []
 
     def conv(self, j, x, w):
-        y, tail = short_conv.short_conv(x, w, self.inp["conv"][j],
-                                        self.n_real)
-        self.tails.append(tail.astype(self.inp["conv"].dtype))
+        y, tail = short_conv.short_conv(
+            x, w, short_conv.rows(self.inp["conv"][j], w), self.n_real)
+        self.tails.append(short_conv.flat(tail).astype(
+            self.inp["conv"].dtype))
         return y
 
     def recur(self, j, q, k, v, g, beta):
@@ -231,7 +234,8 @@ class _Step:
     they lie and a dead slot's never (`plan`: the live slots' indices,
     made here once a tick for all its layers), or `kda_step` on the
     layer's rows of all slots and `_keep`.  The convolution's tail
-    (`[conv_size - 1, 3 W]` a slot) always goes the second way."""
+    (`[(conv_size - 1) 3 W]` a slot) is shifted where it lies by
+    `short_conv.step_in_place`, which chooses its form the same way."""
 
     def __init__(self, state, active):
         self.tree, self.active = dict(state), active
@@ -246,10 +250,8 @@ class _Step:
         return jnp.where(live, new.astype(old.dtype), old)
 
     def conv(self, j, x, w):
-        old = self.tree["conv"][j]
-        y, tail = short_conv.short_conv_step(x[:, 0], w, old)
-        self.tree["conv"] = self.tree["conv"].at[j].set(
-            self._keep(tail, old))
+        y, self.tree["conv"] = short_conv.step_in_place(
+            self.tree["conv"], j, x[:, 0], w, self.active)
         return y[:, None]
 
     def recur(self, j, q, k, v, g, beta):

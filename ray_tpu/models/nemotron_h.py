@@ -252,14 +252,15 @@ def init_slot_state(config: NemotronHConfig, num_slots: int
     """A row a slot a Mamba-2 layer (models/serving.py): the state,
     `ops.kda.pack`ed two heads a row (`[32, 128, 128]` float32 at 64
     heads of 64 x 128, 2,097,152 B a slot a layer with no padded lane),
-    and the convolution's tail, zeros."""
+    and the convolution's tail, its `conv_size - 1` rows side by side
+    in the lanes of one (`ops/short_conv.py`), zeros."""
     c = config
     p = c.heads_a_row
     return {
         "S": jnp.zeros((c.n_ssm_layers, num_slots, c.ssm_heads // p,
                         c.ssm_state, p * c.ssm_head_dim), c.state_dtype),
-        "tail": jnp.zeros((c.n_ssm_layers, num_slots, c.conv_size - 1,
-                           c.conv_width), c.dtype)}
+        "tail": jnp.zeros((c.n_ssm_layers, num_slots,
+                           (c.conv_size - 1) * c.conv_width), c.dtype)}
 
 
 # ---------------------------------------------------------------------------
@@ -277,9 +278,10 @@ class _Sequences:
         self.p, self.n_real = c.heads_a_row, n_real
 
     def conv(self, st, j, x, w):
-        y, tail = short_conv.short_conv(x, w, st["tail"][j], self.n_real)
+        y, tail = short_conv.short_conv(
+            x, w, short_conv.rows(st["tail"][j], w), self.n_real)
         return y, dict(st, tail=st["tail"].at[j].set(
-            tail.astype(st["tail"].dtype)))
+            short_conv.flat(tail).astype(st["tail"].dtype)))
 
     def recur(self, st, j, x, dt, A, Bm, Cm):
         S0 = st["S"][j]
@@ -296,7 +298,8 @@ class _Step:
     reads and writes each LIVE slot's rows once where they lie (`plan`:
     the live slots, made here once a tick for all its layers), or
     `ssd_step` on the layer's rows of all slots and a `where`.  The
-    tail always goes the second way."""
+    tail's layer of the stack is shifted where it lies by
+    `short_conv.step_in_place`, which chooses its form the same way."""
 
     def __init__(self, c, state, active):
         self.p, self.active = c.heads_a_row, active
@@ -311,10 +314,9 @@ class _Step:
         return jnp.where(live, new.astype(old.dtype), old)
 
     def conv(self, st, j, x, w):
-        old = st["tail"][j]
-        y, tail = short_conv.short_conv_step(x[:, 0], w, old)
-        return y[:, None], dict(st, tail=st["tail"].at[j].set(
-            self._keep(tail, old)))
+        y, tails = short_conv.step_in_place(st["tail"], j, x[:, 0], w,
+                                            self.active)
+        return y[:, None], dict(st, tail=tails)
 
     def recur(self, st, j, x, dt, A, Bm, Cm):
         now = (x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0])

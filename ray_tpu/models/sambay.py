@@ -30,8 +30,8 @@ compile as three pairs' worth.
   `+ Dskip * xc`; gate; `W_out`.  What it keeps for a sequence is of a
   fixed size whatever the length: `h` `[d_state, d_inner / 128, 128]`
   in `state_dtype` (channels on the lanes) and the last `d_conv - 1`
-  rows of `xs`, held by the engine by SLOT (`init_slot_state`;
-  models/serving.py).
+  rows of `xs` side by side in the lanes of one, held by the engine by
+  SLOT (`init_slot_state`; models/serving.py).
 - **Differential attention** (`_lay_queries`, `_differ`): consecutive
   heads pair; query pair j reads K/V pair j // 2, whose row is `k1 ‖ k2`
   (128 lanes) and `v1 ‖ v2`; `a1 - lam a2` of the two softmaxes, a
@@ -245,13 +245,14 @@ def init_slot_state(config: SambaYConfig, num_slots: int
     """A row a slot a Mamba layer (models/serving.py): the scan's state
     with its channels in whole lane rows (`ops.selective_scan.fold`:
     `[16, 40, 128]` float32 at 5120 channels, 327,680 B a slot a layer
-    with no padded lane) and the convolution's tail, zeros."""
+    with no padded lane) and the convolution's tail, its `d_conv - 1`
+    rows side by side in the lanes of one (`ops/short_conv.py`), zeros."""
     c = config
     return {
         "h": jnp.zeros((c.n_ssm_layers, num_slots, c.d_state,
                         c.d_inner // ssm.LANES, ssm.LANES), c.state_dtype),
-        "tail": jnp.zeros((c.n_ssm_layers, num_slots, c.d_conv - 1,
-                           c.d_inner), c.dtype)}
+        "tail": jnp.zeros((c.n_ssm_layers, num_slots,
+                           (c.d_conv - 1) * c.d_inner), c.dtype)}
 
 
 # ---------------------------------------------------------------------------
@@ -269,9 +270,10 @@ class _Sequences:
         self.n_real = n_real
 
     def conv(self, st, j, xs, w):
-        y, tail = short_conv.short_conv(xs, w, st["tail"][j], self.n_real)
+        y, tail = short_conv.short_conv(
+            xs, w, short_conv.rows(st["tail"][j], w), self.n_real)
         return y, dict(st, tail=st["tail"].at[j].set(
-            tail.astype(st["tail"].dtype)))
+            short_conv.flat(tail).astype(st["tail"].dtype)))
 
     def recur(self, st, j, delta, x, b, c, a):
         h0 = st["h"][j]
@@ -290,7 +292,8 @@ class _Step:
     stack, which reads and writes each LIVE slot's rows once where they
     lie (`plan`: the live slots, made here once a tick for all its
     layers), or `ssm_step` on the layer's rows of all slots and a
-    `where`.  The tail always goes the second way."""
+    `where`.  The tail's layer of the stack is shifted where it lies
+    by `short_conv.step_in_place`, which chooses its form the same way."""
 
     def __init__(self, state, active):
         self.active = active
@@ -305,10 +308,9 @@ class _Step:
         return jnp.where(live, new.astype(old.dtype), old)
 
     def conv(self, st, j, xs, w):
-        old = st["tail"][j]
-        y, tail = short_conv.short_conv_step(xs[:, 0], w, old)
-        return y[:, None], dict(st, tail=st["tail"].at[j].set(
-            self._keep(tail, old)))
+        y, tails = short_conv.step_in_place(st["tail"], j, xs[:, 0], w,
+                                            self.active)
+        return y[:, None], dict(st, tail=tails)
 
     def recur(self, st, j, delta, x, b, c, a):
         now = (ssm.fold(delta[:, 0]), ssm.fold(x[:, 0]), b[:, 0], c[:, 0], a)

@@ -64,7 +64,9 @@ longer than a bucket goes into its slot chunk by chunk.
     `models/conv_moe.py`, the selective scan's state and tail of
     `models/sambay.py` and `models/jamba.py`, the Mamba-2 state and
     tail of `models/nemotron_h.py`: each but sambay's beside a `full`
-    pool).
+    pool).  Every convolution tail lies `[L', B, (K-1) C]`, a slot's
+    K-1 rows side by side in the lanes of its one row, and one step
+    shifts a layer's rows where they lie (`ops/short_conv.py`).
     A model that has it takes and returns it beside the pool:
         prefill(..., n_real, state) -> (hidden, rows, state), `state`
         {leaf: [L', ...]} ONE slot's rows, as they stood after the

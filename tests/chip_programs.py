@@ -212,7 +212,19 @@ def program(key, build):
 # 59's, which brought the cell; the other twenty-three stood,
 # `think-decode-ssm-yoco`'s two among them (`sambay.ssm_mixer` took a
 # hook between `W_x` and `W_dt` whose default hands `[dt | B | C]` back
-# as it came).
+# as it came).  The tick AND the insert of the six cells whose model
+# keeps a convolution's tail are PR 60's, which laid the tail
+# `[L', B, (K-1) C]` (a program's argument changed shape, so both texts
+# did) and gave the tick ONE step over it, `ops.short_conv.
+# step_in_place`, a Pallas call a call site: `tutor-decode-mamba-mqa`
+# (3 call sites, one a loop of Mamba layers; the 409 MB stack's two
+# copies a tick gone), `think-decode-ssm-yoco` (2), `swarm-decode-ssd-moe`
+# (3), `agent-decode-hybrid` (6, unrolled), `reason-decode-gdn-hybrid`
+# (6), `compose-decode-conv-moe` (11); their inserts reshape one
+# sequence's `[1, (K-1) C]` at `_Sequences.conv`'s edge and keep
+# `short_conv`.  The other ten serving texts (`chat`, `assistant`,
+# `mixed`, `longform`, `solve`: no tail in the model) and the three
+# train texts stood.
 PROGRAM_TEXT_SHA256 = {
     ("chat-decode", "tick"):
         "48a91f54a548addd9d951f33258125cd66601f6eb5de512b9f23800388b2ae93",
@@ -223,41 +235,41 @@ PROGRAM_TEXT_SHA256 = {
     ("assistant-decode-moe", "insert"):
         "7ea6d60d46bd647067feef60f4dff765f30aa43261cc52d3564b29c2b43221de",
     ("agent-decode-hybrid", "tick"):
-        "2211d4c0ca524e4343cc7997c80ca3f11d2216fe85bb322cc9d86730d0d1dee7",
+        "4a6c5f43af2db5c326cff16cf02c6451d58030885a79f1c519924105c0e11f80",
     ("agent-decode-hybrid", "insert"):
-        "066f6804f2b815eecdd1d5a0e6b0f9eccb64cbca6aaba16f7159554a7342156c",
+        "d7b820618fb515d45417e333ec51a5455ee250854ea2e1f4d38eb93cbce88e77",
     ("compose-decode-conv-moe", "tick"):
-        "eb5930149d004d30510feb1230c29d9b3055bcea6b41be400a885904f1a6af07",
+        "6542306df3583c652c2fcc31f9520cda66b422a6d4749850b087a45e8ab07843",
     ("compose-decode-conv-moe", "insert"):
-        "a8c25196b640bfcb5d9253af8ac9decf3223ed4d38b7152b7d16005de95957f0",
+        "b4f906823df9986bd12936b15c7079d5c9340044bda13bfc5c4bc74784111879",
     ("mixed-decode-window-moe", "tick"):
         "ab3d09efdab6c14edbb8e0cc678b75f5d1d1df6032fe7931641e9b39880fe1dd",
     ("mixed-decode-window-moe", "insert"):
         "dc7b87d48f9694f7cef7e9a84f1cf93771805234e2822c3e0e39972faa714ae6",
     ("reason-decode-gdn-hybrid", "tick"):
-        "7db8f02c0133bc1b6757a1eda4a18cfbf6f2ae756478d598ac520e07ef1199b1",
+        "33132b67c38992b9d0401d1d6e722b2ec9ff0a3aee975c1abb4a2e25082fa429",
     ("reason-decode-gdn-hybrid", "insert"):
-        "a71791eff8285121834af1b0c9f4e8fa126ddecc0c6c26257cbec9d5793fc5d0",
+        "0a0e4a1834ebfeb0b980ac25f54340ea3e55c5f79a38b08b22286d20adc7d775",
     ("longform-decode-zero-moe", "tick"):
         "6d77af0f4785b5e86580c1d79abfcf881ee9ed1c86a99dae09123978d048b902",
     ("longform-decode-zero-moe", "insert"):
         "f2fffc5a59fcb63a9959f73e5139a961d1c09b180708d110378b7eff2cf0f76c",
     ("think-decode-ssm-yoco", "tick"):
-        "99a77e55f9f092c0eb144ba97df9bcc5d437802ead9bc1c5f5e8432a6194d87a",
+        "f8b9e647a21ffe686e9d134794fdee242273b99d7cf5b9fb71528935984c942f",
     ("think-decode-ssm-yoco", "insert"):
-        "5c14d60424211c58a2426a2ca8413dc184c1048c046f547d6516cdf605860e2b",
+        "51d5cd58703adf5a7628c6533cd9461eec2a0363f73316dddebe72679e3f2ed9",
     ("swarm-decode-ssd-moe", "tick"):
-        "c41b7dff23ade0c8c0b49724b4a7b1d7a2d7d27d324953aee70311284b16defe",
+        "52ce9761db240de430b5d066204becea8eeb4486661638acf10cfeb9267a530d",
     ("swarm-decode-ssd-moe", "insert"):
-        "a229313f2e5a7f3c7a4e24e98f969829f08105dd9157cc2449df3fb04b3f79b7",
+        "6e41bf1f47650079a336151447011a7f96910ab872243f17fdfed517e6639b4f",
     ("solve-decode-blockdiff-moe", "tick"):
         "a787579becbeb61a966f2d3cb31927c6a496e18ccc6a6ec0efafa2d58b62483d",
     ("solve-decode-blockdiff-moe", "insert"):
         "07bb25384c7a15ab6eaed0fa8e18c697a73584806187d659fc03c0656337a63e",
     ("tutor-decode-mamba-mqa", "tick"):
-        "f446c2e5baa871657dd9952d2770041663dc40d8680dc37bb42b8adb894632cb",
+        "e548add18e94e22b880c5822eb94d06582cb659424237654a27c9a8cb7b9a934",
     ("tutor-decode-mamba-mqa", "insert"):
-        "dcc4557205e707ed9f10351ec6575c38c9ea35831069ff7cb4526cc7ae558237",
+        "1b1126de44e3fd33bd073ae096f51825856455e4c93fa71f64fec900514612c0",
     ("two small layers", "train step, scope names apart"):
         "1d700902d1c682aaec9e4b41afc84286eb99ba0c4758f7046d1be38b1848714c",
     ("two small layers", "train step, its kernels"):
@@ -356,6 +368,44 @@ def grouped_products_are_the_kernel(text, n_moe_layers):
     (`ragged-dot` custom calls, `ragged_dot_tiling` in their config)."""
     assert text.count("grouped_matmul") >= 3 * n_moe_layers
     assert "ragged" not in without_metadata(text)
+
+
+def tails_are_shifted_where_they_lie(cell, leaf, layers, taps, channels):
+    """`cell`'s tick on the chip and the convolution's tails (PR 60): the
+    leaf `[L', B, (K-1) C]` of a configuration whose C is whole lane
+    tiles, so `ops.short_conv.step_in_place` is the kernel, one call a
+    call site; NOTHING else touches the stack (no `copy` or `copy-start`
+    of it, which is how the `[L', B, K-1, C]` leaf was re-laid at both
+    ends of a tick, no fusion that hands it on, no array of its shape
+    in fast memory) and no `scatter` stands under a `conv` scope; the
+    stack lies in HBM at its logical bytes (rows of whole (8, 128)
+    tiles: no padded row, where 3 rows lay in a tile of 4)."""
+    from ray_tpu.ops import short_conv
+
+    eng = serving_cell(cell)
+    stack = eng.state["_slot_state"][leaf]
+    L, B, W = stack.shape
+    assert (L, W) == (layers, (taps - 1) * channels)
+    assert channels % 128 == 0 and B % 16 == 0
+    assert B % short_conv._block_rows(B, W, stack.dtype.itemsize) == 0
+    text = cell_program(cell, "tick").text
+    shape = r"bf16\[%d,%d,%d\]" % stack.shape
+    layouts = set(re.findall(shape + r"\{([^}]*)\}", text))
+    assert layouts == {"2,1,0:T(8,128)(2,1)", "2,1,0"}, layouts
+    made = collections.Counter(
+        op for op, shapes in results_of(text) if stack.shape in shapes)
+    calls = len(re.findall(
+        r"= \([^=]*" + shape + r"[^=]*\) custom-call\([^\n]*short_conv_step",
+        text))
+    assert calls >= 1 and set(made) <= {
+        "parameter", "get-tuple-element", "custom-call", "tuple", "while",
+        "conditional", "call", "bitcast"}, made
+    assert made["custom-call"] == calls
+    under_conv = [line for line in text.splitlines()
+                  if re.search(r'op_name="[^"]*/conv/', line)]
+    assert under_conv and not [
+        line for line in under_conv if re.search(r" scatter\(", line)]
+    return calls
 
 
 def delta_rule_insert_holds_no_channel_tensor(cell, dk, dv, temp_gib):

@@ -20,7 +20,7 @@ import jax
 from chip_programs import (     # noqa: F401  (fixtures)
     GIB, V5E_HBM_GIB, cell_program, delta_rule_insert_holds_no_channel_tensor,
     grouped_products_are_the_kernel, on_tpu, one_chip, results_of,
-    serving_cell, topo,
+    serving_cell, tails_are_shifted_where_they_lie, topo,
 )
 
 
@@ -335,7 +335,7 @@ def test_ssd_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
                                       ec.max_blocks_per_slot) == 2
     state = eng.state["_slot_state"]
     assert state["S"].shape == (6, 384, 32, 128, 128)   # no padded lane
-    assert state["tail"].shape == (6, 384, 3, 6144)
+    assert state["tail"].shape == (6, 384, 3 * 6144)    # taps on lanes
     assert ssd.engages(state["S"])
     experts = eng.params["blocks"][1]
     assert experts["w_up"].shape == experts["w_down"].shape \
@@ -392,7 +392,8 @@ def test_mamba_mqa_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     stack would show as +4.1 GiB of temporaries) and no padded
     `[slots, max_seq_len, 128]` view of K or V is built; the parameters
     count to the published 3.03 B; arguments + temporaries fit HBM:
-    12.1 GiB, 77 % of the chip."""
+    11.7 GiB, 74 % of the chip (12.1 while the tails lay `[26, 512, 3,
+    5120]`, 3 rows in a tile of 4 and re-laid inside the tick: PR 60)."""
     from ray_tpu.ops import paged_attention, selective_scan
 
     eng = serving_cell("tutor-decode-mamba-mqa")
@@ -414,7 +415,7 @@ def test_mamba_mqa_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     assert not paged_attention.walks_groups(1, mc.n_heads, mc.n_kv_heads)
     state = eng.state["_slot_state"]
     assert state["h"].shape == (26, 512, 16, 40, 128)   # no padded lane
-    assert state["tail"].shape == (26, 512, 3, 5120)
+    assert state["tail"].shape == (26, 512, 3 * 5120)   # taps on lanes
     assert selective_scan.engages(state["h"])
     assert sum(math.prod(x.shape) for x in jax.tree.leaves(eng.params)) \
         == published["constants"]["total_params"] == 3_029_337_472
@@ -437,3 +438,18 @@ def test_mamba_mqa_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
     assert m.alias_size_in_bytes >= kept                # all in place
     assert m.temp_size_in_bytes < (0.8 if program == "tick" else 0.5) * GIB
     assert 0.25 * V5E_HBM_GIB < compiled.hbm_gib < V5E_HBM_GIB - 0.5
+
+
+@pytest.mark.parametrize("cell, leaf, layers, taps, channels, calls", [
+    ("tutor-decode-mamba-mqa", "tail", 26, 4, 5120, 3),     # three loops
+    ("think-decode-ssm-yoco", "tail", 9, 4, 5120, 2),
+    ("swarm-decode-ssd-moe", "tail", 6, 4, 6144, 3),
+    ("reason-decode-gdn-hybrid", "conv", 6, 4, 11520, 6),   # unrolled
+    ("compose-decode-conv-moe", "tail", 11, 3, 2048, 11)])
+def test_convolution_tails_are_shifted_where_they_lie(
+        one_chip, on_tpu, cell, leaf, layers, taps, channels, calls):
+    """A family a case (`agent-decode-hybrid`'s is beside its tick, in
+    `test_chip_compile_latent_cells.py`): `chip_programs.
+    tails_are_shifted_where_they_lie`."""
+    assert tails_are_shifted_where_they_lie(
+        cell, leaf, layers, taps, channels) == calls

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from chip_programs import (     # noqa: F401  (fixtures)
     GIB, V5E_HBM_GIB, cell_program, delta_rule_insert_holds_no_channel_tensor,
     grouped_products_are_the_kernel, on_tpu, one_chip, results_of,
-    serving_cell, topo,
+    serving_cell, tails_are_shifted_where_they_lie, topo,
 )
 
 
@@ -315,3 +315,12 @@ def test_shortcut_moe_cell_programs_fit_one_v5e(one_chip, on_tpu, program):
                 if op in ("copy", "copy-start") and found & bank]
     assert m.temp_size_in_bytes < (0.016 if program == "tick"
                                    else 0.55) * GIB
+
+
+def test_convolution_tails_are_shifted_where_they_lie(one_chip, on_tpu):
+    """`agent-decode-hybrid`'s q ‖ k ‖ v tails (the other five families'
+    are in `test_chip_compile_kv_cells.py`): six unrolled KDA layers,
+    one call of the step each (`chip_programs.
+    tails_are_shifted_where_they_lie`)."""
+    assert tails_are_shifted_where_they_lie(
+        "agent-decode-hybrid", "conv", 6, 4, 3 * 4096) == 6

@@ -180,7 +180,7 @@ def test_paged_prefill_and_decode_match_reference(model, case):
     pools = init_paged_pool(mc, 40, BS)
     assert pools["kv"].shape == (2, 40, BS, 2, 32)   # attention layers only
     state = init_slot_state(mc, 3)
-    assert state["tail"].shape == (6, 3, 2, 64)      # conv layers only
+    assert state["tail"].shape == (6, 3, 2 * 64)     # conv layers only
     # every slot holds another sequence's garbage: admission must clear
     # slot 2's, and nothing may touch the others'
     state = jax.tree.map(lambda x: x + 1.0, state)
@@ -272,9 +272,9 @@ def test_short_conv_is_one_module_for_both_models():
         zeros = jnp.zeros((2, K - 1, 5))
         y, tail = sc.short_conv(x, w, zeros, jnp.asarray([9, 9]))
         y8, tail8 = sc.short_conv(x[:, :8], w, zeros, jnp.asarray([8, 8]))
-        y1, tail1 = sc.short_conv_step(x[:, 8], w, tail8)
+        y1, tail1 = sc.step_in_place(sc.flat(tail8)[None], 0, x[:, 8], w)
         assert jnp.abs(y1 - y[:, 8]).max() < 1e-6
-        assert jnp.array_equal(tail1, tail)
+        assert jnp.array_equal(sc.rows(tail1[0], w), tail)
         # written out: zeros before the sequence, w[K-1] on the current row
         want = sum(w[j] * jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))[:, j:j + 9]
                    for j in range(K))
@@ -585,7 +585,7 @@ def test_slot_tail_is_the_last_two_rows(model, engine):
     while engine.has_work():
         engine.step()
     got = engine.slot_state(slot)["tail"]
-    assert got.shape == (6, 2, 64)
+    assert got.shape == (6, 2 * 64)             # taps on lanes
     seen = p + h.tokens[:-1]
     w = weights["layers"][0]
     with jax.default_matmul_precision("highest"):
@@ -594,7 +594,7 @@ def test_slot_tail_is_the_last_two_rows(model, engine):
         b, _, xin = jnp.split(u @ w["w_in"], 3, axis=-1)
     want = np.asarray(b * xin)
     assert np.abs(want).max() > 1e-6
-    assert np.abs(np.asarray(got[0]) - want).max() \
+    assert np.abs(np.asarray(got[0]).reshape(want.shape) - want).max() \
         < 1e-4 * np.abs(want).max()
 
 

@@ -292,7 +292,7 @@ def test_paged_prefill_and_decode_match_reference(model, case):
     pools, state = init_paged_pool(mc, 8, BS), init_slot_state(mc, 3)
     assert pools["k"].shape == pools["v"].shape == (1, 8, BS, 4, 16)
     assert state["S"].shape == (5, 3, 1, 32, 128)   # two heads a row
-    assert state["conv"].shape == (5, 3, 3, 2 * 64 + 128)
+    assert state["conv"].shape == (5, 3, 3 * (2 * 64 + 128))
     n_prompt = {"one_bucket": 13, "chunked": 27}[case]
     toks = _tokens(n_prompt + 10, seed=3)
     got = _served_logits(mc, params, toks, n_prompt)
@@ -344,9 +344,11 @@ def test_conv_tail_is_the_last_three_real_rows(model):
     rows = x @ np.concatenate([np.asarray(p[k]) for k in ("wq", "wk", "wv")],
                               -1)
     assert np.abs(rows[8:11]).max() > 0.1
-    np.testing.assert_allclose(out["padded"][0], rows[8:11], atol=1e-5)
-    np.testing.assert_allclose(out["whole"][0], rows[13:16], atol=1e-5)
-    assert np.abs(out["padded"][0] - rows[13:16]).max() > 0.1
+    # a slot's three rows lie side by side in the lanes of one
+    flat = lambda r: r.reshape(-1)
+    np.testing.assert_allclose(out["padded"][0], flat(rows[8:11]), atol=1e-5)
+    np.testing.assert_allclose(out["whole"][0], flat(rows[13:16]), atol=1e-5)
+    assert np.abs(out["padded"][0] - flat(rows[13:16])).max() > 0.1
 
 
 @pytest.mark.parametrize("case", ["chunked_equals_whole",

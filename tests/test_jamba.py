@@ -251,7 +251,7 @@ def test_paged_prefill_and_decode_match_reference(model, case):
     pools, state, _ = _fresh(mc, 8)
     assert pools["k"].shape == pools["v"].shape == (2, 17, BS, 16)
     assert state["h"].shape == (4, 3, 4, 1, 128)    # channels on lanes
-    assert state["tail"].shape == (4, 3, 3, 128)
+    assert state["tail"].shape == (4, 3, 3 * 128)   # taps on lanes
     n_prompt = {"one_bucket": 13, "chunked": 43}[case]
     toks = _tokens(n_prompt + 10, seed=3)
     at, got = _served_logits(mc, params, toks, n_prompt)
@@ -282,8 +282,8 @@ def test_slot_state_and_tails_are_what_the_reference_carries(model):
     u = x / np.sqrt((x ** 2).mean(-1, keepdims=True) + 1e-6) * p["norm_in"]
     rows = (u @ p["w_in"])[:, :128]
     assert np.abs(rows[24:27]).max() > 0.1
-    np.testing.assert_allclose(np.asarray(state["tail"][0, 1]), rows[24:27],
-                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(state["tail"][0, 1]),
+                               rows[24:27].reshape(-1), atol=1e-5)
 
 
 @pytest.mark.parametrize("case", ["chunked_equals_whole",
@@ -323,8 +323,9 @@ def test_prefill_hand_off(model, case):
 def _tail_after_padding(self, st, j, xs, w):
     from ray_tpu.ops import short_conv
 
-    y, tail = short_conv.short_conv(xs, w, st["tail"][j], xs.shape[1])
-    return y, dict(st, tail=st["tail"].at[j].set(tail))
+    y, tail = short_conv.short_conv(
+        xs, w, short_conv.rows(st["tail"][j], w), xs.shape[1])
+    return y, dict(st, tail=st["tail"].at[j].set(short_conv.flat(tail)))
 
 
 def _norms_left_out(c, p, dbc):

@@ -98,7 +98,7 @@ def test_slot_state_is_what_the_reference_carries(model, engine):
         engine.step()
     got = engine.slot_state(slot)
     assert got["S"].shape == (4, 4, 16, 16) and got["S"].dtype == np.float32
-    assert got["conv"].shape == (4, 3, 3 * 64)
+    assert got["conv"].shape == (4, 3 * 3 * 64)     # taps on lanes
     want = R.kda_states(weights, C, p + h.tokens[:-1])
     # states of 8e-3 at these sizes; float32 sums in another order read
     # 1e-6 of that, a state kept in bf16 between tokens 4e-3 of it

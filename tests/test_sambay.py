@@ -286,7 +286,7 @@ def test_paged_prefill_and_decode_match_reference(model, path, case,
         assert pools["k_w"].shape == pools["v_w"].shape \
             == (2, RING + 5, BS, 32)
         assert state["h"].shape == (3, 3, 4, 1, 128)    # channels on lanes
-        assert state["tail"].shape == (3, 3, 3, 128)
+        assert state["tail"].shape == (3, 3, 3 * 128)   # taps on lanes
     assert insert_attention(mc, 0, GEOMETRY[path][1], mc.max_seq_len)[0] \
         == path
     toks = _tokens(n_prompt + 10, seed=3)
@@ -320,8 +320,8 @@ def test_slot_state_and_tails_are_what_the_reference_carries(model):
         * p["ln_in_w"] + p["ln_in_b"]
     rows = (u @ p["w_in"])[:, :128]
     assert np.abs(rows[24:27]).max() > 0.1
-    np.testing.assert_allclose(np.asarray(state["tail"][0, 1]), rows[24:27],
-                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(state["tail"][0, 1]),
+                               rows[24:27].reshape(-1), atol=1e-5)
 
 
 @pytest.mark.parametrize("case", ["chunked_equals_whole",
@@ -410,8 +410,9 @@ def test_insert_runs_its_second_half_for_one_row(model):
 def _tail_after_padding(self, st, j, xs, w):
     from ray_tpu.ops import short_conv
 
-    y, tail = short_conv.short_conv(xs, w, st["tail"][j], xs.shape[1])
-    return y, dict(st, tail=st["tail"].at[j].set(tail))
+    y, tail = short_conv.short_conv(
+        xs, w, short_conv.rows(st["tail"][j], w), xs.shape[1])
+    return y, dict(st, tail=st["tail"].at[j].set(short_conv.flat(tail)))
 
 
 # what the reference leaves out or changes, and the program then has
